@@ -45,6 +45,7 @@ def test_decision_flips_at_analytic_crossover():
 
 def test_roofline_overrides_defaults():
     class FakeRoofline:
+        has_peaks = True
         peak_flops = 10.0e12
 
         def flops_per_token(self):

@@ -280,6 +280,34 @@ def test_roofline_model_math():
     assert RooflineModel.from_dict(m.to_dict()) == m
 
 
+def test_unknown_device_kind_has_no_peak():
+    """A device that is not in the peak table has no peak: the math
+    raises a KeyError naming the kind, and a capture publishes no
+    estimate for it (never a share of some other chip's peak)."""
+    from vllm_tpu.metrics.perfwatch import PerfWatch
+    from vllm_tpu.metrics.roofline import RooflineModel
+
+    m = RooflineModel(weight_bytes=1_000, active_params=1_000,
+                      kv_tok_bytes=8, device_kind="cpu")
+    assert not m.has_peaks
+    with pytest.raises(KeyError, match="cpu"):
+        m.mfu(100.0)
+    with pytest.raises(KeyError, match="cpu"):
+        m.hbm_bw_util(10.0, 100)
+    clock = FakeClock()
+    pw = PerfWatch(interval_s=0.0, capture_steps=1, clock=clock)
+    pw.begin_capture("/tmp/x", None,
+                     {"launch_sampled_tokens": 0, "step_launches": 0})
+    pw.note_step()
+    clock.t += 1.0
+    snap = pw.finish_capture(
+        None, {"launch_sampled_tokens": 10, "step_launches": 10},
+        ctx_tokens=0, roofline=m)
+    assert snap["tok_per_s"] == 10.0
+    assert snap["mfu_est"] is None and snap["hbm_bw_util_est"] is None
+    assert "device_kind" not in snap
+
+
 def test_roofline_param_helpers():
     import numpy as np
 
@@ -397,10 +425,22 @@ def llm():
         max_position_embeddings=2048, tie_word_embeddings=False,
     )
     cfg.architectures = ["LlamaForCausalLM"]
-    return LLM(
+    llm = LLM(
         model="dummy-llama", hf_config=cfg, load_format="dummy",
         max_model_len=512, max_num_batched_tokens=256, max_num_seqs=4,
+        num_gpu_blocks_override=512,
     )
+    # The CPU has no published peak, so a CPU engine publishes no
+    # utilization estimate (test_unknown_device_kind_has_no_peak). These
+    # tests are about the capture -> stats -> /metrics path: give the
+    # engine the model's roofline under a kind that has peaks.
+    from vllm_tpu.metrics.roofline import RooflineModel
+
+    core = llm.llm_engine.engine_core.engine_core
+    info = core.executor.collective_rpc("roofline_info")[0]
+    core._perf_roofline = RooflineModel.from_dict(
+        {**info, "device_kind": "TPU v5e"})
+    return llm
 
 
 def _core(llm):

@@ -10,6 +10,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from vllm_tpu.ops.attention import (
@@ -310,8 +311,8 @@ def test_dispatch_mixed_batch_takes_general_kernel(
     )
     md = dataclasses.replace(md, decode_only=False)
     decode_calls = _spy(monkeypatch, dk, "decode_paged_attention")
-    # Routing-only: this jax's interpret mode can't discharge the general
-    # kernel's ref-closing while_loop, so don't execute it.
+    # Routing only: the general kernel's numerics are
+    # tests/models/test_ragged_paged_attention.py's business.
     general_calls = _spy(
         monkeypatch, rk, "ragged_paged_attention", call_real=False
     )
@@ -386,3 +387,53 @@ def test_dispatch_token_row_mismatch_takes_general_kernel(
     )
     _dispatch(q_wide, kv_cache, md, d ** -0.5)
     assert general_calls and not decode_calls
+
+
+@pytest.mark.parametrize("path", ["decode", "ragged", "ragged_lse"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_dispatch_under_tp_mesh_runs_kernels_per_shard(
+    pallas_interpret_env, path, d
+):
+    """Under tp > 1 the kernels run inside a shard_map over the mesh (a
+    Mosaic call cannot be partitioned by GSPMD; ``tests/
+    test_tpu_aot_compile.py`` asks the chip's compiler): each shard gets
+    its own q heads and its own cache rows, and the result — and the LSE —
+    equals the unsharded reference."""
+    import dataclasses
+
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    tp = 2
+    mesh = Mesh(
+        np.asarray(jax.devices()[:tp]).reshape(1, 1, 1, tp),
+        ("dp", "pp", "cp", "tp"),
+    )
+    rng = np.random.default_rng(11)
+    kh, h, bs = 2, 4, 8
+    q, kv_cache, md = _decode_case(
+        rng, [9, 22, 3], kh, h, d, bs, num_blocks=32, r_pad=4
+    )
+    scale = d ** -0.5
+    lse = path == "ragged_lse"
+    md = dataclasses.replace(md, decode_only=path == "decode")
+    want = ref_ragged_paged_attention(
+        q, kv_cache, jnp.int32(0), md, scale, return_lse=lse,
+        sliding_window=16,
+    )
+    md_tp = dataclasses.replace(md, tp_mesh=mesh)
+    q_sh = jax.device_put(q, NamedSharding(mesh, P(None, "tp", None)))
+    kv_sh = jax.device_put(
+        kv_cache, NamedSharding(mesh, P(None, None, None, "tp", None))
+    )
+    got = jax.jit(
+        lambda q, kv, md: _dispatch(
+            q, kv, md, scale, return_lse=lse, sliding_window=16
+        )
+    )(q_sh, kv_sh, md_tp)
+    n = 3
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(g)[:n], np.asarray(w)[:n], rtol=2e-4, atol=2e-4
+        )
+    assert jax.tree.leaves(got)[0].sharding.spec == P(None, "tp", None)

@@ -13,7 +13,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tests.pallas_compat import requires_interpret_while_discharge
 
 
 def tiny_deepseek_config(**overrides):
@@ -63,7 +62,6 @@ def tiny_deepseek(tmp_path_factory):
     return str(path)
 
 
-@requires_interpret_while_discharge  # runs the MLA kernel in interpret mode
 def test_mla_absorbed_matches_naive_expansion():
     """Absorbed attention (latent-space scores, W_uv after the softmax)
     must equal the naive per-head K/V expansion."""
@@ -189,7 +187,6 @@ def test_deepseek_routing_noaux_tc_matches_hf_semantics():
             np.testing.assert_allclose(got[int(e)], w, rtol=1e-5)
 
 
-@requires_interpret_while_discharge  # e2e decode runs the MLA kernel
 @pytest.mark.parametrize("budget", [128, 16])  # 16 forces chunked prefill
 def test_deepseek_e2e_greedy_matches_hf(tiny_deepseek, budget):
     import torch
@@ -244,7 +241,6 @@ def test_deepseek_latent_cache_geometry(tiny_deepseek):
     assert spec.page_size_bytes == 16 * (32 + 16) * 4
 
 
-@requires_interpret_while_discharge  # e2e decode runs the MLA kernel
 def test_deepseek_v3_e2e_greedy_matches_hf(tmp_path_factory):
     """V3: q-LoRA + sigmoid noaux_tc routing, tiny config."""
     import torch

@@ -12,12 +12,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from tests.pallas_compat import requires_interpret_while_discharge
 from vllm_tpu.ops.mla_kernel import mla_ragged_paged_attention
-
-# Every test here drives the kernel in interpret mode; its page loop
-# early-exits on a scalar-prefetch ref, which this jax can't discharge.
-pytestmark = requires_interpret_while_discharge
 
 
 def _dense_reference(q, lat_rows, kv_len, q_len, scale, value_dim):

@@ -13,10 +13,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tests.pallas_compat import (
-    requires_bundled_rpa,
-    requires_interpret_while_discharge,
-)
 from vllm_tpu.ops.attention import (
     AttentionMetadata,
     kv_cache_shape,
@@ -109,7 +105,6 @@ CASES = [
 ]
 
 
-@requires_bundled_rpa
 @pytest.mark.parametrize("q_lens,kv_lens", CASES)
 @pytest.mark.parametrize("kh,h", [(2, 4), (1, 1)])
 def test_ref_matches_bundled_kernel_reference(q_lens, kv_lens, kh, h):
@@ -128,7 +123,6 @@ def test_ref_matches_bundled_kernel_reference(q_lens, kv_lens, kh, h):
     )
 
 
-@requires_bundled_rpa
 @pytest.mark.parametrize("q_lens,kv_lens", [([1, 5], [40, 25])])
 def test_sliding_window(q_lens, kv_lens):
     rng = np.random.default_rng(1)
@@ -149,7 +143,6 @@ def test_sliding_window(q_lens, kv_lens):
     )
 
 
-@requires_bundled_rpa
 def test_ref_layer_indexing():
     """The layer argument selects the right slice of the stacked cache."""
     rng = np.random.default_rng(2)
@@ -189,7 +182,6 @@ def _run_kernel(q, kv_cache, layer, md, scale, **kw):
     )
 
 
-@requires_interpret_while_discharge
 @pytest.mark.parametrize("q_lens,kv_lens", CASES)
 @pytest.mark.parametrize("d", [64, 128])
 def test_pallas_kernel_interpret(q_lens, kv_lens, d):
@@ -207,7 +199,6 @@ def test_pallas_kernel_interpret(q_lens, kv_lens, d):
     )
 
 
-@requires_interpret_while_discharge
 def test_pallas_kernel_layer_indexing():
     rng = np.random.default_rng(4)
     kh, h, d, bs = 2, 4, 64, 8
@@ -223,7 +214,6 @@ def test_pallas_kernel_layer_indexing():
     )
 
 
-@requires_interpret_while_discharge
 def test_pallas_kernel_sliding_window():
     rng = np.random.default_rng(5)
     kh, h, d, bs = 2, 4, 128, 8
@@ -241,7 +231,6 @@ def test_pallas_kernel_sliding_window():
     )
 
 
-@requires_interpret_while_discharge
 def test_pallas_kernel_lse():
     """LSE output equals log-sum-exp of the masked scaled scores."""
     rng = np.random.default_rng(6)
@@ -273,7 +262,6 @@ def test_pallas_kernel_lse():
     )
 
 
-@requires_interpret_while_discharge
 @pytest.mark.parametrize("cp", [2, 4])
 @pytest.mark.parametrize("d", [64, 128])
 def test_pallas_kernel_striped_context(cp, d):

@@ -14,7 +14,6 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from tests.pallas_compat import requires_native_shard_map
 from vllm_tpu.core.block_pool import BlockPool, _count_for_color
 from vllm_tpu.core.kv_cache_manager import KVCacheManager
 
@@ -104,12 +103,7 @@ def tiny_llama(tmp_path_factory):
 
 @pytest.mark.parametrize("cp_kw", [
     dict(context_parallel_size=2),
-    # cp manual region composed with a sharded tp axis needs native
-    # jax.shard_map partial-auto support.
-    pytest.param(
-        dict(context_parallel_size=2, tensor_parallel_size=2),
-        marks=requires_native_shard_map,
-    ),
+    dict(context_parallel_size=2, tensor_parallel_size=2),
 ])
 def test_llm_generate_cp_parity(tiny_llama, cp_kw):
     """Long multi-block contexts under cp=2 (and cp x tp) produce the

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from tests.pallas_compat import requires_native_shard_map
 from vllm_tpu.layers.moe import _dense_moe, ep_moe, select_experts
 
 
@@ -73,7 +72,6 @@ def test_ep_moe_extreme_skew(cpu_devices):
     )
 
 
-@requires_native_shard_map  # dp-sharded inputs outside the manual region
 def test_ep_moe_under_dp_mesh(cpu_devices):
     """Partial-manual shard_map composes with an outer dp axis: tokens
     arrive dp-sharded, the EP region is manual over tp only."""

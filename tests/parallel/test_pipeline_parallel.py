@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 
 from tests.models.utils import tiny_llama_dir
-from tests.pallas_compat import requires_native_shard_map
 from vllm_tpu import LLM, SamplingParams
 
 
@@ -59,9 +58,7 @@ def ref_tokens(tiny_llama, prompts):
 @pytest.mark.parametrize("pp,tp", [
     (2, 1),
     (4, 1),
-    # pp manual region composed with a sharded tp axis needs native
-    # jax.shard_map partial-auto support.
-    pytest.param(2, 2, marks=requires_native_shard_map),
+    (2, 2),
 ])
 def test_pp_greedy_parity(tiny_llama, prompts, ref_tokens, pp, tp):
     got = _generate(

@@ -33,11 +33,6 @@ os.environ.setdefault("VLLM_TPU_PALLAS_INTERPRET", "1")
 os.environ.setdefault("VLLM_TPU_NO_USAGE_STATS", "1")
 import jax
 jax.config.update("jax_platforms", "cpu")
-cache = os.environ.get("VLLM_TPU_COMPILE_CACHE_DIR")
-if cache:
-    os.makedirs(cache, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 from vllm_tpu.engine.arg_utils import AsyncEngineArgs
 from vllm_tpu.entrypoints.openai.api_server import run_server
@@ -91,14 +86,15 @@ def test_sigterm_drains_gracefully(tmp_path_factory):
     script = tmp_path_factory.mktemp("drain_server") / "server.py"
     script.write_text(_SERVER)
 
+    # JAX_COMPILATION_CACHE_DIR, if set, rides along in os.environ; unset,
+    # the child's worker uses the same fixed directory as this process.
     env = dict(os.environ, PYTHONPATH=os.getcwd())
-    env.setdefault(
-        "VLLM_TPU_COMPILE_CACHE_DIR",
-        os.path.expanduser("~/.cache/vllm_tpu/xla_cache_tests"),
-    )
+    # A file, not a pipe nobody reads while the test runs (a full pipe
+    # blocks the server in write()).
+    log = open(script.with_name("server.log"), "w+")
     proc = subprocess.Popen(
         [sys.executable, str(script), ckpt, str(port)], env=env,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        stdout=log, stderr=subprocess.STDOUT, text=True,
     )
     try:
         _wait_ready(base, time.monotonic() + 180)
@@ -166,6 +162,8 @@ def test_sigterm_drains_gracefully(tmp_path_factory):
         if proc.poll() is None:
             proc.kill()
             proc.wait(timeout=10)
-        out = proc.stdout.read() if proc.stdout else ""
+        log.seek(0)
+        out = log.read()
+        log.close()
         if proc.returncode != 0:
             print(out[-4000:])

@@ -54,11 +54,6 @@ os.environ.setdefault("VLLM_TPU_NO_USAGE_STATS", "1")
 def main():
     import jax
     jax.config.update("jax_platforms", "cpu")
-    cache = os.environ.get("VLLM_TPU_COMPILE_CACHE_DIR")
-    if cache:
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     from vllm_tpu.engine.arg_utils import AsyncEngineArgs
     from vllm_tpu.entrypoints.openai.api_server import run_server
@@ -137,17 +132,19 @@ def topo(tmp_path_factory):
     script = tmp_path_factory.mktemp("topo_server") / "server.py"
     script.write_text(_SERVER)
 
+    # JAX_COMPILATION_CACHE_DIR, if set, rides along in os.environ; unset,
+    # the child's worker uses the same fixed directory as this process.
     env = dict(os.environ, PYTHONPATH=os.getcwd())
-    env.setdefault(
-        "VLLM_TPU_COMPILE_CACHE_DIR",
-        os.path.expanduser("~/.cache/vllm_tpu/xla_cache_tests"),
-    )
-    # Own session: the launcher's frontends are non-daemon children that
-    # inherit the stdout pipe, so teardown must be able to kill the WHOLE
-    # tree (killpg) or reading the pipe blocks forever.
+    # Own session: the launcher's frontends are non-daemon children, so
+    # teardown must be able to kill the WHOLE tree (killpg).
+    # Output goes to a file, not a pipe nobody reads while the tests run:
+    # a full pipe (64 KiB; XLA:CPU logs two long lines per executable it
+    # loads from the compilation cache) blocks every server process in
+    # write() and the next request times out.
+    log = open(script.with_name("server.log"), "w+")
     proc = subprocess.Popen(
         [sys.executable, str(script), ckpt, str(port), journal], env=env,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        stdout=log, stderr=subprocess.STDOUT, text=True,
         start_new_session=True,
     )
     t = _Topology(proc, port, journal)
@@ -184,7 +181,9 @@ def topo(tmp_path_factory):
             pass
         if proc.poll() is None:
             proc.wait(timeout=10)
-        out = proc.stdout.read() if proc.stdout else ""
+        log.seek(0)
+        out = log.read()
+        log.close()
         if proc.returncode not in (0, -signal.SIGKILL.value):
             print(out[-6000:])
 

@@ -15,7 +15,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tests.pallas_compat import requires_interpret_while_discharge
 from tests.spec_decode.test_ngram_spec import _sampling_md
 from vllm_tpu.spec_decode.tree import build_tree
 
@@ -122,7 +121,6 @@ def _tree_rig(rng, tree, kv_lens, kh=2, h=4, d=64, bs=8, num_blocks=64):
     return q, k, v_new, kv_cache, md
 
 
-@requires_interpret_while_discharge  # verify attention kernel in interpret
 @pytest.mark.parametrize("spec", ["1x1x1", "2x2", "3x2x1"])
 def test_tree_attention_matches_per_path_chain(spec):
     """For every root-to-leaf path, the tree tokens' outputs equal plain

@@ -1,0 +1,213 @@
+"""Compile the serving path's Pallas kernels for a described (not attached)
+TPU v5e at Llama-3-8B widths.
+
+Interpret mode cannot see what the chip's compiler refuses: a block that
+breaks the (8, 128) tiling rule, more scoped VMEM than a kernel may use,
+a Mosaic call that GSPMD is asked to partition. The TPU compiler is
+installed here and compiles for a described topology, so each case below
+asks it directly. Nothing runs; a pass says "compiles", never "is right"
+or "is fast" (``chip_smoke.py`` holds the results to the XLA reference on
+the chip).
+
+The topology is described inside the module-scoped fixture and nowhere
+else: only one process may hold libtpu, so this must not happen at import,
+in ``conftest.py``, in a ``skipif`` or in a ``parametrize`` (every xdist
+worker imports every test file), and the compiles stay in this one file
+and in the test's own process.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+# Llama-3-8B: 32 q heads, 8 KV heads, head_dim 128, vocab 128256, page 16.
+H, KH, D, V, PAGE = 32, 8, 128, 128256, 16
+LAYERS, NUM_BLOCKS = 32, 2048
+PAGES_PER_SEQ = 128  # 2048-token context
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp4_mesh(topo):
+    return Mesh(
+        np.asarray(topo.devices).reshape(1, 1, 1, 4), ("dp", "pp", "cp", "tp")
+    )
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def tuned_table_sees_chip(monkeypatch, topo):
+    """The tuned block-size table asks ``jax.devices()`` for the TPU
+    generation; here that is the CPU. Show its module the described
+    devices so the program picks what it would pick on the chip."""
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
+        tuned_block_sizes,
+    )
+
+    monkeypatch.setattr(
+        tuned_block_sizes, "jax",
+        types.SimpleNamespace(devices=lambda: topo.devices),
+    )
+
+
+def _attn_shapes(sharding, t, r, kv_dtype, kv_sharding=None, q_sharding=None):
+    def s(shape, dtype, sh=sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    return dict(
+        q=s((t, H, D), jnp.bfloat16, q_sharding or sharding),
+        kv=s((LAYERS, NUM_BLOCKS, PAGE, 2 * KH, D), kv_dtype,
+             kv_sharding or sharding),
+        layer=s((1,), jnp.int32),
+        kv_lens=s((r,), jnp.int32),
+        pages=s((r, PAGES_PER_SEQ), jnp.int32),
+        cu_q_lens=s((r + 1,), jnp.int32),
+        num_seqs=s((1,), jnp.int32),
+    )
+
+
+def _kv_scale(kv_dtype):
+    return 1.0 if kv_dtype == jnp.float8_e4m3fn else None
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.float8_e4m3fn],
+                         ids=["kv_bf16", "kv_fp8"])
+def test_decode_kernel_compiles(one_chip, kv_dtype):
+    from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
+
+    a = _attn_shapes(one_chip, 128, 128, kv_dtype)
+    scale = _kv_scale(kv_dtype)
+    fn = functools.partial(
+        decode_paged_attention, sm_scale=D**-0.5, k_scale=scale, v_scale=scale
+    )
+    jax.jit(fn).lower(
+        a["q"], a["kv"], a["layer"], a["kv_lens"], a["pages"], a["num_seqs"]
+    ).compile()
+
+
+@pytest.mark.parametrize(
+    "t,r,kv_dtype",
+    [(512, 128, jnp.bfloat16), (2048, 64, jnp.bfloat16),
+     (512, 128, jnp.float8_e4m3fn)],
+    ids=["T512_R128", "T2048_R64", "T512_R128_kv_fp8"],
+)
+def test_ragged_kernel_compiles_with_own_block_choice(
+    one_chip, tuned_table_sees_chip, t, r, kv_dtype
+):
+    from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
+
+    a = _attn_shapes(one_chip, t, r, kv_dtype)
+    scale = _kv_scale(kv_dtype)
+    fn = functools.partial(
+        ragged_paged_attention, sm_scale=D**-0.5, k_scale=scale, v_scale=scale
+    )
+    jax.jit(fn).lower(
+        a["q"], a["kv"], a["layer"], a["kv_lens"], a["pages"],
+        a["cu_q_lens"], a["num_seqs"],
+    ).compile()
+
+
+@pytest.mark.parametrize(
+    "penalties,top_k,top_p",
+    [(False, False, False), (False, True, True), (True, True, True)],
+    ids=["plain", "top_k_top_p", "penalties"],
+)
+def test_sampler_kernel_compiles(one_chip, penalties, top_k, top_p):
+    from vllm_tpu.ops.sampler_kernel import fused_sample
+
+    r = 128
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = (r, V) if penalties else (1, 128)
+    fn = functools.partial(
+        fused_sample, needs_penalties=penalties, needs_top_k=top_k,
+        needs_top_p_min_p=top_p,
+    )
+    jax.jit(fn).lower(
+        s((r, V), jnp.float32), s((r, 128), jnp.float32),
+        s((r, 128), jnp.int32), s(state, jnp.int32), s(state, jnp.int8),
+    ).compile()
+
+
+@pytest.mark.parametrize("decode_only", [True, False],
+                         ids=["decode", "ragged"])
+def test_attention_dispatch_compiles_under_tp4(
+    tp4_mesh, tuned_table_sees_chip, monkeypatch, decode_only
+):
+    """Through ``dispatch_ragged_attention`` with q sharded over heads and
+    the cache over its KV rows, as ``models/llama.py`` shards them: the
+    kernel must sit inside the tp shard_map (GSPMD refuses to partition a
+    Mosaic call) and the compiled step must still hold it."""
+    from vllm_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    rep = NamedSharding(tp4_mesh, P())
+    t = r = 128
+    if not decode_only:
+        t = 512
+    a = _attn_shapes(
+        rep, t, r, jnp.bfloat16,
+        kv_sharding=NamedSharding(tp4_mesh, P(None, None, None, "tp", None)),
+        q_sharding=NamedSharding(tp4_mesh, P(None, "tp", None)),
+    )
+
+    def step(q, kv, layer, kv_lens, pages, cu_q_lens, num_seqs):
+        md = attention.AttentionMetadata(
+            positions=None, slot_mapping=None, block_tables=pages,
+            seq_lens=kv_lens, query_start_loc=cu_q_lens, token_req_idx=None,
+            logits_indices=None, num_seqs=num_seqs, decode_only=decode_only,
+            tp_mesh=tp4_mesh,
+        )
+        return attention.dispatch_ragged_attention(
+            q, kv, layer[0], md, D**-0.5
+        )
+
+    compiled = jax.jit(step).lower(
+        a["q"], a["kv"], a["layer"], a["kv_lens"], a["pages"],
+        a["cu_q_lens"], a["num_seqs"],
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    kernel = "rpa_decode_kernel" if decode_only else "rpa_kernel"
+    assert kernel in text

@@ -99,6 +99,7 @@ def run_smoke(base_only: bool = False) -> int:
     llm = LLM(
         model="dummy-llama", hf_config=cfg, load_format="dummy",
         max_model_len=512, max_num_batched_tokens=256, max_num_seqs=4,
+        num_gpu_blocks_override=512,
         # Multi-step on so the A/B also exercises its dynamic-decode
         # (device while_loop) on/off variant.
         num_decode_steps=4,
@@ -134,6 +135,7 @@ def run_smoke(base_only: bool = False) -> int:
     llm2 = LLM(
         model="dummy-llama", hf_config=cfg, load_format="dummy",
         max_model_len=256, max_num_batched_tokens=128, max_num_seqs=2,
+        num_gpu_blocks_override=512,
         speculative_method="ngram", num_speculative_tokens=3,
         spec_adaptive=True,
     )

@@ -11,8 +11,8 @@ Two sweeps on TPU:
 
 On CPU (or ``--smoke``) the decode kernel runs in Pallas interpret mode
 at a tiny shape against the XLA reference — numerics-only smoke coverage
-of every sweep point (the general kernel's while_loop cannot run under
-this jax's interpret mode, so it is skipped there).
+of every sweep point (the general kernel is left to its own tests
+there).
 
 The grouped-decode comparison that used to live here concluded in round
 5: grouped measured slower and was deleted.

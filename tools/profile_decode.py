@@ -49,7 +49,7 @@ def build_llm():
             hidden_size=128, intermediate_size=512, num_hidden_layers=2,
             num_attention_heads=4, num_key_value_heads=4, vocab_size=1024,
         )
-        extra = {}
+        extra = dict(num_gpu_blocks_override=512)
         n_req, prompt_len, out_len = 4, 8, 8
     cfg = LlamaConfig(
         max_position_embeddings=4096, tie_word_embeddings=False, **shape

@@ -125,7 +125,6 @@ _readers: dict[str, Callable[[], Any]] = {
     # Sweep with tools/probe_sampler.py before changing the defaults.
     "VLLM_TPU_SAMPLER_ROW_BLOCK": _int("VLLM_TPU_SAMPLER_ROW_BLOCK", 0),
     "VLLM_TPU_SAMPLER_LOGITS_TILE": _int("VLLM_TPU_SAMPLER_LOGITS_TILE", 0),
-    "VLLM_TPU_COMPILE_CACHE_DIR": _str("VLLM_TPU_COMPILE_CACHE_DIR", None),
     # LRU size bound for the persistent compilation cache directory.
     "VLLM_TPU_COMPILE_CACHE_MAX_GB": _int("VLLM_TPU_COMPILE_CACHE_MAX_GB", 32),
     # Unroll the layer loop instead of lax.scan (scan's xs layout

@@ -128,7 +128,8 @@ class FetchCostModel:
     def recompute_time_s(self, n_tokens: int) -> float:
         if self.roofline is not None:
             flops_tok = self.roofline.flops_per_token()
-            peak = self.roofline.peak_flops
+            peak = (self.roofline.peak_flops if self.roofline.has_peaks
+                    else DEFAULT_PEAK_FLOPS)
         else:
             flops_tok = DEFAULT_FLOPS_PER_TOKEN
             peak = DEFAULT_PEAK_FLOPS

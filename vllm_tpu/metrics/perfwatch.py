@@ -216,7 +216,9 @@ class PerfWatch:
             "mfu_est": None,
             "hbm_bw_util_est": None,
         }
-        if roofline is not None:
+        # A device without published peaks (the CPU in tests) gets no
+        # utilization estimate, not a share of some other chip's.
+        if roofline is not None and roofline.has_peaks:
             snapshot["mfu_est"] = round(roofline.mfu(tok_per_s), 4)
             snapshot["hbm_bw_util_est"] = round(
                 roofline.hbm_bw_util(steps_per_s, ctx_tokens), 4)

@@ -18,15 +18,14 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Mapping
 
-# Per-chip peaks by ``device_kind``. v5e: 197 TFLOP/s bf16, ~819 GB/s.
+# Per-chip published peaks by ``device_kind`` (Google Cloud TPU docs).
+# v5e: 197 TFLOP/s bf16, 819 GB/s. A kind that is not here has no peak:
+# ``mfu`` / ``hbm_bw_util`` raise a KeyError naming it, and the live
+# gauges publish nothing for it (``has_peaks``).
 PEAK_FLOPS = {"TPU v5 lite": 197e12, "TPU v5e": 197e12,
               "TPU v4": 275e12, "TPU v6 lite": 918e12}
 PEAK_HBM = {"TPU v5 lite": 819e9, "TPU v5e": 819e9,
             "TPU v4": 1200e9, "TPU v6 lite": 1640e9}
-# Unknown device kinds (CPU backend, future chips) fall back to the v5e
-# numbers — estimates stay comparable to the BENCH_rxx trajectory.
-DEFAULT_PEAK_FLOPS = 197e12
-DEFAULT_PEAK_HBM = 819e9
 
 
 def weight_bytes(params: Any) -> int:
@@ -72,12 +71,16 @@ class RooflineModel:
     device_kind: str = ""
 
     @property
+    def has_peaks(self) -> bool:
+        return self.device_kind in PEAK_FLOPS
+
+    @property
     def peak_flops(self) -> float:
-        return PEAK_FLOPS.get(self.device_kind, DEFAULT_PEAK_FLOPS)
+        return PEAK_FLOPS[self.device_kind]
 
     @property
     def peak_hbm(self) -> float:
-        return PEAK_HBM.get(self.device_kind, DEFAULT_PEAK_HBM)
+        return PEAK_HBM[self.device_kind]
 
     def flops_per_token(self) -> float:
         return 2.0 * self.active_params

@@ -184,6 +184,10 @@ class LlamaForCausalLM:
         )
         keys = jax.random.split(rng, 12)
 
+        # One fused program per weight: eagerly, the f32 draw and its
+        # scaled copy are both live (7 GiB for a 16-layer 8B-width wup)
+        # next to the weights already placed on a 16 GiB chip.
+        @partial(jax.jit, static_argnums=(1, 2))
         def init(key, shape, fan_in):
             return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)).astype(dtype)
 
@@ -196,9 +200,9 @@ class LlamaForCausalLM:
                 if shape[0] >= 8 and math.prod(shape) >= 2**28:
                     # Big stacks quantize LAYER-BY-LAYER: the bf16
                     # transient shrinks from the full [L, ...] stack
-                    # (3.5 GiB for an 8B wup) to one layer — on the
-                    # shared bench chip that headroom decides whether
-                    # the 8B rungs fit at all.
+                    # (3.5 GiB for an 8B wup) to one layer — on a
+                    # 16 GiB chip that headroom decides whether the
+                    # quantized 8B fits at all.
                     subkeys = jax.random.split(key, shape[0])
                     per = []
                     for i in range(shape[0]):
@@ -638,7 +642,7 @@ class LlamaForCausalLM:
         from jax.sharding import PartitionSpec as P
 
         from vllm_tpu.ops.attention import ref_ragged_paged_attention
-        from vllm_tpu.parallel.mesh import pcast_varying, shard_map
+        from vllm_tpu.parallel.mesh import shard_map
 
         S = self.pp_size
         mesh = self.pp_mesh
@@ -673,7 +677,7 @@ class LlamaForCausalLM:
         def run(layers_local, kv_local, chunks, pos_m, slot_m, tri_m,
                 block_tables, seq_lens, qsl, logits_idx, num_seqs):
             stage = jax.lax.axis_index("pp")
-            varying = _partial(pcast_varying, axis_name=("pp",))
+            varying = _partial(jax.lax.pcast, axis_name=("pp",), to="varying")
             buf = varying(jnp.zeros((tm, d), x.dtype))
             outs = varying(jnp.zeros((m, tm, d), x.dtype))
             li_local = jnp.arange(ls, dtype=jnp.int32)

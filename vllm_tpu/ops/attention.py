@@ -27,7 +27,9 @@ per block-table entry.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +70,13 @@ class AttentionMetadata:
     # jit, and the runner forces ``t_pad == r_pad`` when setting it, so
     # the extra trace count is bounded by the request buckets.
     decode_only: bool = field(default=False, metadata=dict(static=True))
+    # The runner's device mesh when it has one (else None). STATIC. A
+    # Mosaic kernel cannot be partitioned by GSPMD, so under tp > 1 the
+    # dispatch runs the Pallas kernels per tp shard inside a shard_map
+    # (``_call_attention_kernel``): q is sharded over heads and the cache
+    # over its KV-head rows, as every model's ``kv_cache_sharding`` lays
+    # them out.
+    tp_mesh: Any = field(default=None, metadata=dict(static=True))
     # Hybrid attention+SSM models (Jamba/Bamba-class): per-request state
     # slot for the constant-size Mamba caches ([R] i32; None for pure
     # attention models). Reference: HybridKVCacheCoordinator per-type
@@ -183,6 +192,47 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _call_attention_kernel(kernel, mesh, q, kv_cache, replicated, traced_kw,
+                           return_lse: bool = False):
+    """Run a Pallas attention kernel; per tp shard when ``mesh`` has
+    tp > 1. Each shard sees its own q heads ``[T, H/tp, D]`` and its own
+    rows of the cache ``[L, NB, BS, rows/tp, lanes]`` (K/V of one head are
+    adjacent rows, or one packed row, so a head never straddles shards);
+    the row metadata and the traced scalars are replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    from vllm_tpu.parallel.mesh import AXIS_TP, shard_map
+
+    tp = 1 if mesh is None else mesh.shape[AXIS_TP]
+    if tp == 1:
+        return kernel(q, kv_cache, *replicated, **traced_kw)
+    num_kv_heads = kv_cache.shape[3] // (1 if packed_kv_layout(q.shape[-1])
+                                         else 2)
+    if q.shape[1] % tp or num_kv_heads % tp:
+        raise ValueError(
+            f"tensor parallel size {tp} must divide the q heads "
+            f"({q.shape[1]}) and the KV heads ({num_kv_heads}) for the "
+            "Pallas attention kernels"
+        )
+    # Python ints (a static window, the unstriped ctx view) become arrays:
+    # shard_map takes array arguments only.
+    traced_kw = {
+        k: None if v is None else jnp.asarray(v, jnp.int32)
+        for k, v in traced_kw.items()
+    }
+    heads = P(None, AXIS_TP, None)
+    return shard_map(
+        lambda q, kv, rep, kw: kernel(q, kv, *rep, **kw),
+        mesh=mesh,
+        in_specs=(heads, P(None, None, None, AXIS_TP, None), P(), P()),
+        out_specs=(heads, P(None, AXIS_TP)) if return_lse else heads,
+        # Mosaic wants every mesh axis manual, not only tp: take all that
+        # an enclosing shard_map (context parallelism) has not taken.
+        axis_names=set(mesh.axis_names)
+        - set(jax.sharding.get_abstract_mesh().manual_axes),
+    )(q, kv_cache, replicated, traced_kw)
+
+
 def dispatch_ragged_attention(
     q: jnp.ndarray,
     kv_cache: jnp.ndarray,
@@ -223,15 +273,18 @@ def dispatch_ragged_attention(
         and q.shape[0] == md.seq_lens.shape[0]
         and not envs.VLLM_TPU_DISABLE_DECODE_KERNEL
     )
-    if (
-        decode_ok
-        and not envs.VLLM_TPU_DISABLE_PALLAS
-        and kernel_ok
-        and (on_tpu or interpret)
-    ):
+    use_kernel = (
+        not envs.VLLM_TPU_DISABLE_PALLAS and kernel_ok and (on_tpu or interpret)
+    )
+    run_interpret = interpret and not on_tpu
+    layer1 = jnp.asarray(layer, jnp.int32).reshape(1)
+    static_kw = dict(
+        sm_scale=scale, soft_cap=soft_cap, k_scale=k_scale, v_scale=v_scale,
+        interpret=run_interpret,
+    )
+    if use_kernel and decode_ok:
         from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
 
-        run_interpret = interpret and not on_tpu
         if run_interpret:
             blk_kw = dict(num_seqs_per_block=2, num_kv_pages_per_block=2)
         else:
@@ -244,29 +297,17 @@ def dispatch_ragged_attention(
                 blk_kw["num_kv_pages_per_block"] = (
                     envs.VLLM_TPU_DECODE_KV_PAGES_PER_BLOCK
                 )
-        return decode_paged_attention(
+        return _call_attention_kernel(
+            functools.partial(decode_paged_attention, **static_kw, **blk_kw),
+            md.tp_mesh,
             q,
             kv_cache,
-            jnp.asarray(layer, jnp.int32).reshape(1),
-            md.seq_lens,
-            md.block_tables,
-            md.num_seqs,
-            sm_scale=scale,
-            sliding_window=sliding_window,
-            soft_cap=soft_cap,
-            k_scale=k_scale,
-            v_scale=v_scale,
-            interpret=run_interpret,
-            **blk_kw,
+            (layer1, md.seq_lens, md.block_tables, md.num_seqs),
+            dict(sliding_window=sliding_window),
         )
-    if (
-        not envs.VLLM_TPU_DISABLE_PALLAS
-        and kernel_ok
-        and (on_tpu or interpret)
-    ):
+    if use_kernel:
         from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
 
-        run_interpret = interpret and not on_tpu
         # The tuned-block-size table is keyed by TPU generation; off-TPU
         # interpret runs pick explicit small blocks instead.
         blk_kw = (
@@ -274,24 +315,19 @@ def dispatch_ragged_attention(
             if run_interpret
             else {}
         )
-        return ragged_paged_attention(
+        return _call_attention_kernel(
+            functools.partial(
+                ragged_paged_attention, return_lse=return_lse,
+                **static_kw, **blk_kw,
+            ),
+            md.tp_mesh,
             q,
             kv_cache,
-            jnp.asarray(layer, jnp.int32).reshape(1),
-            md.seq_lens,
-            md.block_tables,
-            md.query_start_loc,
-            md.num_seqs,
-            sm_scale=scale,
-            sliding_window=sliding_window,
-            soft_cap=soft_cap,
-            k_scale=k_scale,
-            v_scale=v_scale,
+            (layer1, md.seq_lens, md.block_tables, md.query_start_loc,
+             md.num_seqs),
+            dict(sliding_window=sliding_window, ctx_stride=ctx_stride,
+                 ctx_phase=ctx_phase),
             return_lse=return_lse,
-            interpret=run_interpret,
-            ctx_stride=ctx_stride,
-            ctx_phase=ctx_phase,
-            **blk_kw,
         )
     return ref_ragged_paged_attention(
         q, kv_cache, layer, md, scale, sliding_window=sliding_window,

@@ -38,10 +38,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vllm_tpu.ops.rpa_kernel import CompilerParams
-
-from vllm_tpu.ops.rpa_kernel import store_with_mask
-
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.dtype("float32")).max)
 
 
@@ -162,8 +158,8 @@ def _mla_kernel(
 
             def masked_store(ref, val, start, end, group=1):
                 iota = lax.broadcasted_iota(jnp.int32, ref.shape, 0) // group
-                store_with_mask(
-                    ref, val, jnp.logical_and(iota >= start, iota < end)
+                pltpu.store(
+                    ref, val, mask=jnp.logical_and(iota >= start, iota < end)
                 )
 
             def load_with_init(ref, init_val):
@@ -381,7 +377,7 @@ def mla_ragged_paged_attention(
             grid=grid,
             scratch_shapes=scratch_shapes,
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=vmem_limit_bytes,
         ),

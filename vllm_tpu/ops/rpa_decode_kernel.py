@@ -60,7 +60,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from vllm_tpu.ops.rpa_kernel import (
     DEFAULT_MASK_VALUE,
-    CompilerParams,
     _dtype_packing,
     _min_heads_per_blk,
     fold_on_2nd_minor,
@@ -539,7 +538,7 @@ def decode_paged_attention(
             grid=grid,
             scratch_shapes=scratch_shapes,
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem_limit_bytes,
         ),

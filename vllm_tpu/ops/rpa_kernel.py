@@ -37,50 +37,19 @@ from jax import lax
 from jax._src import dtypes
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-try:
-    from jax.experimental.pallas.ops.tpu.ragged_paged_attention.tuned_block_sizes import (  # noqa: E501
-        get_tuned_block_sizes,
-    )
-except ImportError:
-    # Older jax wheels don't bundle the ragged-paged-attention tuning
-    # tables; fall back to one serviceable block shape so the module
-    # stays importable (CPU interpret tests, older TPU images). Callers
-    # that care about peak performance pass explicit block sizes or env
-    # overrides.
-    def get_tuned_block_sizes(
-        q_dtype, kv_dtype, num_q_heads_per_blk, num_kv_heads_per_blk,
-        head_dim, page_size, max_num_tokens, pages_per_seq,
-    ):
-        del q_dtype, kv_dtype, num_q_heads_per_blk, num_kv_heads_per_blk
-        del head_dim, pages_per_seq
-        num_kv_pages_per_blk = max(1, 128 // page_size)
-        num_queries_per_blk = max(8, min(32, max_num_tokens))
-        return num_kv_pages_per_blk, num_queries_per_blk
+from jax.experimental.pallas.ops.tpu.ragged_paged_attention.tuned_block_sizes import (  # noqa: E501
+    get_tuned_block_sizes,
+)
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.dtype("float32")).max)
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
-
-def store_with_mask(ref, val, mask):
-    """Whole-ref masked store; older jax lacks ``pltpu.store`` (and its
-    interpret mode can't discharge ``pl.store(mask=)``), so fall back to
-    a read-modify-write select, which Mosaic fuses anyway."""
-    if hasattr(pltpu, "store"):
-        pltpu.store(ref, val, mask=mask)
-    else:
-        ref[...] = jnp.where(mask, val, ref[...])
+# Mosaic's scoped-VMEM budget for one kernel when ``vmem_limit_bytes`` is
+# not given (v5e; the chip's compiler reports it as "limit 16.00M").
+_DEFAULT_SCOPED_VMEM_BYTES = 16 << 20
 
 
 def _dtype_packing(dtype) -> int:
-    # dtypes.itemsize_bits is absent on older jax; byte-sized dtypes
-    # (every KV cache dtype we support) make itemsize*8 equivalent.
-    if hasattr(dtypes, "itemsize_bits"):
-        return 32 // dtypes.itemsize_bits(dtype)
-    return 32 // (jnp.dtype(dtype).itemsize * 8)
+    return 32 // dtypes.itemsize_bits(dtype)
 
 
 def strided_load_kv(ref, start, step):
@@ -326,8 +295,8 @@ def _rpa_kernel(
 
             def masked_store(ref, val, start, end, group=1):
                 iota = lax.broadcasted_iota(jnp.int32, ref.shape, 0) // group
-                store_with_mask(
-                    ref, val, jnp.logical_and(iota >= start, iota < end)
+                pltpu.store(
+                    ref, val, mask=jnp.logical_and(iota >= start, iota < end)
                 )
 
             def load_with_init(ref, init_val):
@@ -587,6 +556,54 @@ def _min_heads_per_blk(num_q_heads, num_combined_kv_heads, q_dtype, kv_dtype):
     return num_q_heads, num_combined_kv_heads
 
 
+def _fit_kv_pages_to_vmem(
+    num_kv_pages_per_blk: int,
+    vmem_budget_bytes: int,
+    *,
+    num_q_per_blk: int,
+    num_q_heads_per_blk: int,
+    num_combined_kv_heads_per_blk: int,
+    head_dim: int,
+    page_size: int,
+    q_itemsize: int,
+    kv_itemsize: int,
+) -> int:
+    """Halve the KV block until the kernel's VMEM need fits the budget.
+
+    The tuned table's entries (and its untuned (128, 32) default, which
+    is what Llama-3-8B's 32q/8kv heads get) assume nothing about this
+    kernel's extra scratch, and at 8B widths the default overflows the
+    chip's 16 MiB scoped limit. The need is estimated from the block
+    shapes as ``1.5 * kv double buffer + 2 * (q, out, acc, l, m blocks)
+    + the per-kv-head f32 score tiles``: the coefficients bound from
+    above the smallest ``vmem_limit_bytes`` the v5e compiler accepted
+    (MiB, bf16 q, 32q/16 combined heads, D=128, page 16; pages x queries):
+    8x32: 5, 32x32: 11, 64x32: 17, 128x32: 28, 8x128: 14, 32x64: 18;
+    fp8 KV 32x32: 7, 64x32: 10; a tp=4 shard (8q/4) 128x32: 7.
+    ``tests/test_tpu_aot_compile.py`` holds the result to the compiler.
+    """
+    num_kv_heads_per_blk = num_combined_kv_heads_per_blk // 2
+    ratio = num_q_heads_per_blk // num_kv_heads_per_blk
+    q_blk_elems = num_q_per_blk * num_q_heads_per_blk * head_dim
+    lm_bytes = num_kv_heads_per_blk * num_q_per_blk * ratio * 128 * 4
+    q_side = q_blk_elems * (2 * q_itemsize + 4) + 2 * lm_bytes
+
+    def need(pages: int) -> int:
+        kv_tokens = pages * page_size
+        kv_double_buf = (
+            2 * kv_tokens * num_combined_kv_heads_per_blk * head_dim
+            * kv_itemsize
+        )
+        scores = num_kv_heads_per_blk * num_q_per_blk * ratio * kv_tokens * 4
+        return kv_double_buf * 3 // 2 + 2 * q_side + scores
+
+    while num_kv_pages_per_blk > 1 and need(num_kv_pages_per_blk) > (
+        vmem_budget_bytes
+    ):
+        num_kv_pages_per_blk //= 2
+    return num_kv_pages_per_blk
+
+
 @functools.partial(
     jax.jit,
     static_argnames=[
@@ -663,7 +680,17 @@ def ragged_paged_attention(
             num_q_tokens,
             pages_per_seq,
         )
-        num_kv_pages_per_blk = min(num_kv_pages_per_blk, pages_per_seq)
+        num_kv_pages_per_blk = _fit_kv_pages_to_vmem(
+            min(num_kv_pages_per_blk, pages_per_seq),
+            vmem_limit_bytes or _DEFAULT_SCOPED_VMEM_BYTES,
+            num_q_per_blk=num_q_per_blk,
+            num_q_heads_per_blk=num_q_heads_per_blk,
+            num_combined_kv_heads_per_blk=num_combined_kv_heads_per_blk,
+            head_dim=head_dim,
+            page_size=page_size,
+            q_itemsize=q.dtype.itemsize,
+            kv_itemsize=kv_pages.dtype.itemsize,
+        )
     num_q_heads_per_kv_head = num_q_heads // num_kv_heads
     num_q_blks = pl.cdiv(num_q_tokens, num_q_per_blk)
     num_kv_heads_per_blk = num_combined_kv_heads_per_blk // 2
@@ -743,7 +770,7 @@ def ragged_paged_attention(
             grid=grid,
             scratch_shapes=scratch_shapes,
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem_limit_bytes,
         ),

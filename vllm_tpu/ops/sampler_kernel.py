@@ -48,8 +48,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vllm_tpu.ops.rpa_kernel import CompilerParams
-
 # Masked-out tokens (matches sample/sampler.py's _NEG_INF): large-negative
 # but finite, so downstream adds can't produce inf - inf NaNs. Vocab
 # padding uses -inf: exp() maps it to exactly 0.0 and argmax ties resolve
@@ -489,7 +487,7 @@ def fused_sample(
     needs_penalties: bool = False,
     needs_top_k: bool = True,
     needs_top_p_min_p: bool = True,
-    row_block: int = 4,
+    row_block: int = 8,
     logits_tile: int = _LOGITS_TILE,
     vmem_limit_bytes: int | None = None,
     interpret: bool = False,
@@ -507,8 +505,17 @@ def fused_sample(
     if needs_penalties and counts.shape != (num_rows, vocab):
         raise ValueError(f"{counts.shape=} != {(num_rows, vocab)}")
     v2 = padded_vocab(vocab)
+    # row_block: a multiple of 8, or at least num_rows — Mosaic tiles the
+    # [R, 128] parameter blocks (8, 128).
     row_blk = max(1, min(row_block, num_rows))
     lt = min(logits_tile, v2)
+    if vmem_limit_bytes is None:
+        # The epilogue keeps the [row_blk, V2] f32 scratch plus a handful
+        # of same-shaped temporaries (weights, ordinals, noise, masks)
+        # live; 8x the scratch covers what the v5e compiler asked for at
+        # R=128, V=128256 (16.06 MiB with penalties at row_blk 8, past
+        # the 16 MiB default).
+        vmem_limit_bytes = max(16 << 20, 8 * row_blk * v2 * 4)
 
     # Pad rows up to the block grid; pad rows sample into the void and
     # are sliced off below.
@@ -562,7 +569,7 @@ def fused_sample(
             grid=(r2 // row_blk,),
             scratch_shapes=scratch_shapes,
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=vmem_limit_bytes,
         ),

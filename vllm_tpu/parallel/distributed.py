@@ -48,7 +48,7 @@ _world: tuple[str, int, int] | None = None  # (coordinator, nproc, pid)
 def _jax_client_live() -> bool:
     from jax._src import distributed as _dist
 
-    return getattr(_dist.global_state, "client", None) is not None
+    return _dist.global_state.client is not None
 
 
 def init_distributed(
@@ -120,23 +120,19 @@ def _bootstrap_explicit(coordinator: str, nproc: int, pid: int) -> None:
     racing a peer death fails fast instead of wedging recovery.
     """
     from jax._src import distributed as _dist
-    from jax._src.lib import xla_extension
+    from jax._src.lib import _jax
 
     state = _dist.global_state
     if pid == 0 and state.service is None:
         bind = "[::]:" + coordinator.rsplit(":", 1)[1]
-        state.service = xla_extension.get_distributed_runtime_service(
-            bind, nproc)
+        state.service = _jax.get_distributed_runtime_service(bind, nproc)
 
     # Cross-process collectives on the CPU backend default to "none" —
     # any multi-host computation on the 2-process CPU rig then fails at
     # dispatch. Gloo ships with jaxlib; enable it before the backend is
     # created. TPU backends ignore this flag entirely.
-    try:
-        if "cpu" in (jax.config.jax_platforms or ""):
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception as exc:
-        logger.warning("could not enable gloo cpu collectives: %s", exc)
+    if "cpu" in (jax.config.jax_platforms or ""):
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     def _on_missed_heartbeat(status) -> None:
         logger.error(
@@ -144,7 +140,7 @@ def _bootstrap_explicit(coordinator: str, nproc: int, pid: int) -> None:
             "likely dead; mesh recovery will re-form the world): %s",
             status)
 
-    client = xla_extension.get_distributed_runtime_client(
+    client = _jax.get_distributed_runtime_client(
         coordinator, pid, init_timeout=120, shutdown_timeout=10,
         missed_heartbeat_callback=_on_missed_heartbeat,
         shutdown_on_destruction=False, use_compression=True)
@@ -193,14 +189,11 @@ def _clear_device_keyed_caches() -> None:
     Teardown is a rare, already-expensive path; the scan cost is noise."""
     import functools
 
-    try:
-        # Mesh.__new__ interns every Mesh in a module-level dict keyed on
-        # its device tuple; deleting the Mesh object does not evict it.
-        from jax._src import mesh as _jmesh
+    # Mesh.__new__ interns every Mesh in a module-level dict keyed on
+    # its device tuple; deleting the Mesh object does not evict it.
+    from jax._src import mesh as _jmesh
 
-        _jmesh._mesh_object_dict.clear()
-    except Exception:
-        pass
+    _jmesh._mesh_object_dict.clear()
     for obj in gc.get_objects():
         if type(obj) is not functools._lru_cache_wrapper:
             continue
@@ -259,29 +252,18 @@ def shutdown_distributed(force: bool = False) -> None:
     # clear_backends() resets xla_bridge._backends, which is also the
     # sentinel jax.distributed.initialize() checks before allowing a
     # re-bootstrap — without it the smaller world can never form.
-    try:
-        jax.clear_caches()
-    except Exception:
-        pass
-    try:
-        # _backends must be emptied IN PLACE before _clear_backends
-        # rebinds it: the deprecated jax.lib.xla_bridge shim holds a
-        # reference to the old dict object, and a populated orphan dict
-        # pins the old client forever.
-        from jax._src import xla_bridge as _xb
+    jax.clear_caches()
+    # _backends must be emptied IN PLACE before clear_backends rebinds
+    # it: anything still holding the old dict object would, with it
+    # populated, pin the old client forever.
+    from jax._src import api as _jax_api
+    from jax._src import xla_bridge as _xb
 
-        _xb._backends.clear()
-    except Exception:
-        pass
-    try:
-        # Removed from the public namespace in jax 0.4.36 but still the
-        # only complete backend reset (clears xla_bridge._backends and
-        # every pjit/dispatch cache pinned to the old clients).
-        from jax._src import api as _jax_api
-
-        _jax_api.clear_backends()
-    except Exception as exc:
-        logger.warning("backend cache clear failed: %s", exc)
+    _xb._backends.clear()
+    # Not in the public namespace, but the only complete backend reset
+    # (clears xla_bridge._backends and every pjit/dispatch cache pinned
+    # to the old clients).
+    _jax_api.clear_backends()
     _clear_device_keyed_caches()
     # Collect NOW so the old coordination client actually dies before a
     # new world forms on the same coordinator port: cycle-held backend

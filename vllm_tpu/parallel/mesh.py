@@ -27,36 +27,17 @@ AXIS_CP = "cp"
 AXIS_TP = "tp"
 
 
-# jax promoted jax.experimental.shard_map.shard_map to jax.shard_map and
-# renamed its knobs (auto -> axis_names complement, check_rep ->
-# check_vma). One adapter so every manual-region call site works on both.
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma=False):
-    if hasattr(jax, "shard_map"):
-        kwargs = {"check_vma": check_vma}
-        if axis_names is not None:
-            kwargs["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    auto = frozenset()
+    """``jax.shard_map`` with this repo's defaults: manual over
+    ``axis_names`` only (None = every mesh axis), replication unchecked."""
+    kwargs = {}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    # Legacy replication checking predates pvary/pcast annotations — off.
-    return _legacy(
-        f, mesh, in_specs, out_specs, check_rep=False, auto=auto
+        kwargs["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma, **kwargs,
     )
-
-
-def pcast_varying(x, axis_name):
-    """``jax.lax.pcast(..., to="varying")`` where available; identity on
-    jax builds without VMA tracking (legacy shard_map runs unchecked)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axis_name=axis_name, to="varying")
 
 
 def build_mesh(parallel_config: ParallelConfig, devices=None) -> Mesh:

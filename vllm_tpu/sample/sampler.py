@@ -165,15 +165,20 @@ def sampler_kernel_eligible(
     needs_gumbel: bool,
     enable_kernel: bool = True,
     allow_interpret: bool = False,
+    tp_size: int = 1,
 ) -> tuple[bool, bool]:
     """(use_kernel, interpret) for a batch shape — the single eligibility
     rule, shared by ``dispatch_sample`` (trace time) and the runner's
     launch/fallback counters (host side). All-greedy batches
     (``needs_gumbel=False``) are NOT kernel work: the XLA argmax path is
-    already a single fused reduction behind the logits matmul."""
+    already a single fused reduction behind the logits matmul. Neither is
+    a tensor-parallel step (``tp_size > 1``): ``lm_head`` is sharded over
+    the vocabulary and the kernel needs whole vocabulary rows (and, being
+    a Mosaic call, cannot be partitioned by GSPMD), so the fused XLA
+    epilogue, which GSPMD partitions, serves those."""
     import vllm_tpu.envs as envs
 
-    if not needs_gumbel or not enable_kernel:
+    if not needs_gumbel or not enable_kernel or tp_size > 1:
         return False, False
     if envs.VLLM_TPU_DISABLE_PALLAS or envs.VLLM_TPU_DISABLE_SAMPLER_KERNEL:
         return False, False
@@ -205,6 +210,7 @@ def dispatch_sample(
     needs_gumbel: bool = True,
     enable_kernel: bool = True,
     allow_interpret: bool = False,
+    tp_size: int = 1,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Kernel-vs-reference dispatch for the sampling epilogue (the
     ``dispatch_ragged_attention`` pattern): the fused Pallas kernel when
@@ -218,6 +224,7 @@ def dispatch_sample(
         needs_gumbel=needs_gumbel,
         enable_kernel=enable_kernel,
         allow_interpret=allow_interpret,
+        tp_size=tp_size,
     )
     if not use_kernel:
         return sample(
