@@ -1,0 +1,411 @@
+"""One run of one cell of the benchmark.
+
+    python benchmark/run_cell.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+This process never touches JAX. It writes the configuration's model
+directory (``config.json`` only), starts the OpenAI server as a child with
+the README's ``serve`` entry point, drives it over HTTP from an asyncio
+client, stops it, and then starts the checker (the float32 reference on the
+chip) and, with ``--trace 1``, the trace reducer. The last line printed is
+the result object of the benchmark's contract; everything else worth
+keeping goes on earlier lines and into ``bench_out/<workload>/``.
+
+Set-up, all of it counted in ``setup_s``: server start; a warm-up that
+walks the cell's ladder of step programs, the same requests in every run;
+the correctness requests; a ramp in which the cell's arrivals already run.
+Then the window, in which no step program should be used for the first
+time: every run logs how many were.
+"""
+
+from __future__ import annotations
+
+import time
+
+T_PROCESS_START = time.perf_counter()
+
+import argparse  # noqa: E402
+import asyncio  # noqa: E402
+import json  # noqa: E402
+import shutil  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+from benchmark.harness import score as scoring  # noqa: E402
+from benchmark.harness import traffic as traffic_mod  # noqa: E402
+from benchmark.harness.client import Client  # noqa: E402
+from benchmark.harness.manifest import (  # noqa: E402
+    BENCH_DIR, ROOT, Cell, kernel_counts, load_manifest, peaks_for,
+)
+from benchmark.harness.server import NO_CHIP, Server, child_env  # noqa: E402
+
+WARM_SEED = 7_000_003  # the warm-up's traffic never depends on --seed
+COMPILES = ("vllm:step_bucket_compiles_total", "vllm:step_bucket_compiles")
+STEP_COUNT = 'vllm:engine_step_duration_seconds_count{phase="dispatch"}'
+
+
+class RunFailed(Exception):
+    pass
+
+
+def log(msg: str) -> None:
+    print(f"[run_cell {time.perf_counter() - T_PROCESS_START:7.1f}s] {msg}", flush=True)
+
+
+def first(m: dict, *names: str):
+    for n in names:
+        if n in m:
+            return m[n]
+    return None
+
+
+async def wait_ready(client: Client, server: Server, limit_s: float) -> None:
+    deadline = time.perf_counter() + limit_s
+    while not await client.ready():
+        code = server.exited()
+        if code is not None:
+            raise RunFailed(f"the server exited with code {code} before it was ready"
+                            + (" (no TPU)" if code == NO_CHIP else ""))
+        if time.perf_counter() > deadline:
+            raise RunFailed(f"the server was not ready after {limit_s} s")
+        await asyncio.sleep(0.25)
+
+
+async def warm_up(client: Client, cell: Cell, vocab: int) -> dict:
+    """Walk the cell's ladder of step programs: the waves of
+    ``cells/<cell>.json``, one after the other, each sent at once and
+    awaited, with the traffic's own sampling (the sampler's settings are
+    static arguments of the step program). A wave is a list of
+    ``[count, prompt tokens, answer tokens]``; what each is there to reach
+    is said beside it in the file. The same requests in every run: nothing
+    here depends on ``--seed``."""
+    t_begin = time.perf_counter()
+    before = first(await client.metrics(), *COMPILES) or 0.0
+    new_by_wave = []
+    for i, wave in enumerate(cell.numbers["warmup_waves"]):
+        plan = traffic_mod.wave_plan(wave, cell.traffic["sampling"], WARM_SEED + i, vocab)
+        client.t0 = time.perf_counter()
+        recs = await client.run_plan(plan, drain_limit_s=900.0)  # a cold run compiles here
+        bad = [r for r in recs if not r.ok]
+        if bad:
+            raise RunFailed(f"warm-up wave {i}: {len(bad)} of {len(recs)} requests failed: "
+                            f"{bad[0].error or bad[0].status}")
+        after = first(await client.metrics(), *COMPILES) or 0.0
+        new_by_wave.append(after - before)
+        before = after
+    return {"waves": len(new_by_wave), "new_programs_by_wave": new_by_wave,
+            "seconds": time.perf_counter() - t_begin, "programs_after": before}
+
+
+async def check_served(client: Client, cell: Cell, seed: int, vocab: int, positions: int) -> list[dict]:
+    reqs, together = traffic_mod.check_requests(cell.traffic, seed, vocab, positions)
+    if together:
+        outs = await asyncio.gather(*(client.complete(q) for q in reqs))
+    else:
+        outs = [await client.complete(q) for q in reqs]
+    samples = []
+    for q, out in zip(reqs, outs):
+        lp = out["choices"][0]["logprobs"]
+        tokens = [int(t) for t in lp["tokens"]]
+        if len(tokens) != q.max_tokens:
+            raise RunFailed(f"check request: asked {q.max_tokens} tokens, got {len(tokens)}")
+        samples.append({"prompt": q.prompt, "tokens": tokens,
+                        "top_logprobs": [{str(k): v for k, v in top.items()}
+                                         for top in lp["top_logprobs"]]})
+    return samples
+
+
+def has_device_plane(trace_dir: Path) -> bool:
+    """Whether the profiler's file holds a device plane with operations, by
+    the names the file stores as plain strings (no JAX in this process)."""
+    for f in trace_dir.rglob("*.xplane.pb"):
+        data = f.read_bytes()
+        return b"/device:TPU:" in data and b"XLA Ops" in data
+    return False
+
+
+async def traced(client: Client, at_s: float, window_s: float, until_s: float, for_s: float,
+                 every_s: float, most: int, trace_root: Path, info: dict) -> None:
+    """Profile ``for_s`` seconds through ``/start_profile``, again every
+    ``every_s`` seconds, and keep each slice that holds the device (on the
+    v5e most traces started from the HTTP thread of a busy server come back
+    without a device plane; PERF.md, PR 25): at most ``most`` slices, spread
+    over the window. Past the window's end only for as long as there is none
+    at all, and never past ``until_s``."""
+    await asyncio.sleep(max(0.0, at_s - client.now()))
+    slices, profiled, n = [], [], 0
+    while len(slices) < most and client.now() < (until_s if not slices else window_s - for_s):
+        began = client.now()
+        d = trace_root / f"attempt{n}"
+        n += 1
+        await client.post_json("/start_profile", {"trace_dir": str(d)})
+        lo = client.now()
+        await asyncio.sleep(for_s)
+        hi = client.now()
+        await client.post_json("/stop_profile")
+        profiled.append((began, client.now()))
+        if has_device_plane(d):
+            slices.append({"lo": lo, "hi": hi, "dir": str(d), "stopped": client.now()})
+        else:
+            shutil.rmtree(d, ignore_errors=True)
+        if len(slices) < most:
+            await asyncio.sleep(max(0.5, began + every_s - client.now()))
+    info.update(attempts=n, slices=slices, profiled=profiled)
+    client.enough.set()
+
+
+async def scrape_at(client: Client, at_s: float, into: dict, key: str) -> None:
+    await asyncio.sleep(max(0.0, at_s - client.now()))
+    into[key] = (client.now(), await client.metrics())
+
+
+async def drive(cell: Cell, server: Server, args, out_dir: Path) -> dict:
+    hf = cell.hf_config
+    vocab, positions = hf["vocab_size"], hf["max_position_embeddings"]
+    rate = args.rate if args.rate is not None else cell.numbers.get("rate_per_s", 0.0)
+    res: dict = {}
+    async with Client(server.port, str(server.model_dir)) as client:
+        await wait_ready(client, server, 1100.0)
+        res["ready_s"] = time.perf_counter() - T_PROCESS_START
+        log(f"server ready after {res['ready_s']:.1f} s")
+        if not args.check_only:
+            res["warmup"] = await warm_up(client, cell, vocab)
+            log(f"warm-up: {res['warmup']}")
+        res["samples"] = await check_served(client, cell, args.seed, vocab, positions)
+        log(f"check requests served: {len(res['samples'])}")
+        if args.check_only:
+            return res
+
+        ramp_s = float(cell.traffic["ramp_s"])
+        plan = traffic_mod.build_plan(cell.traffic, rate, ramp_s, args.seconds,
+                                      args.seed, vocab, positions)
+        client.t0 = time.perf_counter() + ramp_s + 0.5
+        res["setup_s"] = client.t0 - T_PROCESS_START
+        scrapes: dict = {}
+        trace_info: dict = {}
+        side = [asyncio.create_task(scrape_at(client, 0.0, scrapes, "m0")),
+                asyncio.create_task(scrape_at(client, args.seconds, scrapes, "m1"))]
+        if args.trace:
+            # The same mix goes on after the window, unscored, for as long
+            # as the profiler needs to give a device trace.
+            more = traffic_mod.build_plan(cell.traffic, rate, 0.0, args.trace_extension,
+                                          args.seed + 1, vocab, positions)
+            plan = traffic_mod.extend(plan, more, args.trace_extension)
+            shutil.rmtree(out_dir / "trace", ignore_errors=True)
+            side.append(asyncio.create_task(traced(
+                client, 0.1 * args.seconds, args.seconds,
+                args.seconds + args.trace_extension - 5.0, args.trace_seconds,
+                args.seconds / (args.trace_slices + 1.0), args.trace_slices,
+                out_dir / "trace", trace_info)))
+        else:
+            client.enough.set()
+        recs = await client.run_plan(plan, float(cell.traffic["drain_limit_s"]))
+        await asyncio.gather(*side)
+        res.update(records=recs, scrapes=scrapes, trace_info=trace_info,
+                   drained_s=client.now() - args.seconds,
+                   waiting_end=first(scrapes["m1"][1], "vllm:num_requests_waiting"),
+                   waiting_start=first(scrapes["m0"][1], "vllm:num_requests_waiting"))
+    return res
+
+
+def run_child(cmd: list[str], env: dict, log_path: Path, limit_s: float) -> int:
+    with open(log_path, "w") as f:
+        proc = subprocess.Popen(cmd, cwd=str(ROOT), env=env, stdout=f,
+                                stderr=subprocess.STDOUT, start_new_session=True)
+        try:
+            return proc.wait(limit_s)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait()
+            return -9
+
+
+def decide_correct(cell: Cell, check: dict) -> tuple[bool, list[str]]:
+    """Each number compared, beside its limit (``benchmark/cells/``)."""
+    lines, ok = [], True
+    for key, limit in cell.numbers["correct_limits"].items():
+        got = check[key]
+        good = got <= limit
+        ok &= good
+        lines.append(f"correct: {key} = {got:.6g} (limit {limit:g}) {'ok' if good else 'OVER'}")
+    return ok, lines
+
+
+def layer_context(cell: Cell, res: dict, scored: dict, trace: dict | None,
+                  device_kind: str, seconds: float) -> dict:
+    (t0, m0), (t1, m1) = res["scrapes"]["m0"], res["scrapes"]["m1"]
+
+    def delta(*names: str):
+        for n in names:
+            if n in m0 and n in m1:
+                return m1[n] - m0[n]
+        return None
+
+    def steps():
+        return delta(STEP_COUNT)
+
+    slices = res["trace_info"].get("slices", [])
+
+    def decode_ctx():
+        # (decode tokens, sum of their context lengths) in the traced
+        # slices. Tokens computed just before a slice began arrive inside
+        # it and those computed at its end arrive after it; 0.15 s are
+        # dropped at each start so that the count stays on the low side.
+        parts = [scoring.decode_context_tokens(res["records"], sl["lo"] + 0.15, sl["hi"])
+                 for sl in slices]
+        return sum(p[0] for p in parts), sum(p[1] for p in parts)
+
+    return {
+        "cell": cell, "config": cell.hf_config, "facts": scored["facts"],
+        "service": scored["metrics"],
+        "m0": m0, "m1": m1, "scrape_dt": t1 - t0, "delta": delta, "steps": steps,
+        "records": res["records"], "seconds": seconds, "trace": trace,
+        "trace_slices": [(sl["lo"], sl["hi"]) for sl in slices],
+        # Every attempt, kept or not, from its start to the return of
+        # /stop_profile, which stalls the server for seconds.
+        "profiled": res["trace_info"].get("profiled", []),
+        "decode_context_tokens": decode_ctx, "kernel_counts": kernel_counts,
+        "peaks": peaks_for(device_kind),
+    }
+
+
+def main(argv: list[str]) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    # For the builder of a cell, not for the driver:
+    ap.add_argument("--rate", type=float, default=None, help="offered rate, for the sweep")
+    ap.add_argument("--engine-arg", action="append", default=[],
+                    help="key=value: an engine argument for a control run (fp8 KV, int8 weights)")
+    ap.add_argument("--check-only", action="store_true",
+                    help="serve the check requests and compare; no warm-up, no window")
+    ap.add_argument("--trace-seconds", type=float, default=1.5)
+    ap.add_argument("--trace-slices", type=int, default=3,
+                    help="with --trace 1: how many slices of --trace-seconds, spread over the window")
+    ap.add_argument("--trace-extension", type=float, default=60.0,
+                    help="with --trace 1: how long the traffic may go on after the window")
+    args = ap.parse_args(argv)
+
+    manifest = load_manifest()
+    cell = Cell(manifest, args.workload)
+    extra = {}
+    for kv in args.engine_arg:
+        k, _, v = kv.partition("=")
+        extra[k] = {"true": True, "false": False}.get(v.lower(), v)
+
+    tag = f"seed{args.seed}-trace{args.trace}" + ("-control" if extra else "")
+    out_dir = ROOT / "bench_out" / args.workload / tag
+    out_dir.mkdir(parents=True, exist_ok=True)
+    cache_dir = ROOT / ".jax_cache"
+    server = Server(cell, args.seed, out_dir, cache_dir, extra)
+    log(f"cell {cell.name}: config {cell.config_name}, traffic {cell.traffic_name}, "
+        f"seed {args.seed}, {args.seconds} s, trace {args.trace}")
+    server.start()
+    facts = None
+    try:
+        res = asyncio.run(drive(cell, server, args, out_dir))
+    except RunFailed as e:
+        log(f"FAILED: {e}\n--- server log ---\n{server.log_tail()}")
+        return 1
+    except Exception:
+        log(f"--- server log ---\n{server.log_tail()}")
+        raise
+    finally:
+        facts = server.stop()
+    if not facts:
+        log(f"FAILED: the server left no device facts\n{server.log_tail()}")
+        return 1
+    if facts["platform"] != "tpu":
+        log(f"FAILED: the server ran on {facts}")
+        return 1
+    log(f"server stopped; device {facts}")
+
+    # The reference, on the chip the server has just freed.
+    env = child_env(cache_dir)
+    job = {"config": cell.hf_config, "family": cell.family, "seed": args.seed,
+           "chips": cell.chips, "samples": res["samples"],
+           "dtype": {**cell.engine_args, **extra}.get("dtype", "bfloat16")}
+    (out_dir / "check_job.json").write_text(json.dumps(job))
+    rc = run_child([sys.executable, str(BENCH_DIR / "harness" / "checker.py"),
+                    str(out_dir / "check_job.json"), str(out_dir / "check.json")],
+                   env, out_dir / "checker.log", 600.0)
+    if rc != 0:
+        log(f"FAILED: the checker exited with {rc}\n{(out_dir / 'checker.log').read_text()[-3000:]}")
+        return 1
+    check = json.loads((out_dir / "check.json").read_text())
+    correct, lines = decide_correct(cell, check)
+    for line in lines:
+        log(line)
+    log(f"check: {json.dumps({k: v for k, v in check.items() if k != 'per_sample'})}")
+    if args.check_only:
+        print(json.dumps({"correct": correct, "check": check, "device": facts}))
+        return 0
+
+    scored = scoring.score(res["records"], args.seconds, cell.chips)
+    (out_dir / "requests.jsonl").write_text("".join(json.dumps({
+        "due": r.due, "sent": r.sent, "prompt_tokens": r.prompt_tokens,
+        "max_tokens": r.max_tokens, "first": r.chunks[0] if r.chunks else None,
+        "last": r.chunks[-1] if r.chunks else None, "failed": scoring.failed(r),
+    }) + "\n" for r in res["records"] if r.scored))
+    log(f"facts: {json.dumps(scored['facts'])}")
+    log(f"drained {res['drained_s']:.1f} s after the window; requests waiting at its "
+        f"start {res['waiting_start']} and end {res['waiting_end']}")
+    seen = [first(m, *COMPILES) or 0.0 for m in
+            (res["scrapes"]["m0"][1], res["scrapes"]["m1"][1])]
+    log(f"first uses of a step program: {res['warmup']['programs_after']:.0f} in the warm-up, "
+        f"{seen[0] - res['warmup']['programs_after']:.0f} in the check and the ramp, "
+        f"{seen[1] - seen[0]:.0f} IN THE WINDOW"
+        + (" -- each stalls the running requests: this window is not steady"
+           if seen[1] > seen[0] else ""))
+    e2e = dict(scored["metrics"], setup_s=res["setup_s"])
+    log(f"end to end: {json.dumps(e2e)}")
+    units = {m["name"]: m["unit"] for m in manifest["end_to_end"] + manifest["per_layer"]}
+    device = dict(facts)
+    result: dict = {"correct": correct, "attempted": scored["attempted"],
+                    "failed": scored["failed"]}
+
+    if args.trace:
+        slices = res["trace_info"].get("slices", [])
+        log(f"profiler: {res['trace_info'].get('attempts')} attempt(s), slices with the device: "
+            f"{[(round(sl['lo'], 2), round(sl['hi'], 2)) for sl in slices]}; from an attempt's "
+            f"start to the return of its /stop_profile: "
+            f"{[(round(lo, 2), round(hi, 2)) for lo, hi in res['trace_info'].get('profiled', [])]}")
+        if not slices:
+            log("FAILED: no trace with a device plane")
+            return 1
+        env_cpu = dict(env, JAX_PLATFORMS="cpu")
+        rc = run_child([sys.executable, str(BENCH_DIR / "harness" / "trace_reduce.py"),
+                        str(out_dir / "trace.json"), *(sl["dir"] for sl in slices)],
+                       env_cpu, out_dir / "trace_reduce.log", 300.0)
+        if rc != 0:
+            log(f"FAILED: trace reduction exited with {rc}\n"
+                f"{(out_dir / 'trace_reduce.log').read_text()[-3000:]}")
+            return 1
+        trace = json.loads((out_dir / "trace.json").read_text())
+        ctx = layer_context(cell, res, scored, trace, facts["kind"], args.seconds)
+        values = {}
+        for m in cell.per_layer:
+            v = cell.layer_metric(m["name"]).read(ctx)
+            if v is not None:
+                values[m["name"]] = {"value": v, "unit": m["unit"]}
+        device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
+        result["metrics"] = values
+        result["breakdown"] = {"device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"]}
+        log(f"end to end in this TRACED run (not a measurement): {json.dumps(e2e)}")
+    else:
+        result["metrics"] = {
+            m["name"]: {"value": e2e[m["name"]], "unit": units[m["name"]]}
+            for m in cell.end_to_end if m["name"] in e2e
+        }
+    result["device"] = device
+    (out_dir / "result.json").write_text(json.dumps(result))
+    print(json.dumps(result))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
