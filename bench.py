@@ -41,8 +41,6 @@ os.environ.setdefault("VLLM_TPU_LOG_LEVEL", "WARNING")
 # The bench model is synthetic; never touch the HF hub (no network here —
 # the retry loop alone wastes ~40s).
 os.environ.setdefault("HF_HUB_OFFLINE", "1")
-# Step-time breakdown rides the JSON output.
-os.environ.setdefault("VLLM_TPU_STEP_TIMING", "1")
 
 BASELINE_TOK_S_PER_CHIP = 2000.0
 

@@ -1,14 +1,22 @@
 """One run of one cell of the benchmark.
 
-    python benchmark/run_cell.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python benchmark/run_cell.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 This process never touches JAX. It writes the configuration's model
 directory (``config.json`` only), starts the OpenAI server as a child with
 the README's ``serve`` entry point, drives it over HTTP from an asyncio
 client, stops it, and then starts the checker (the float32 reference on the
-chip) and, with ``--trace 1``, the trace reducer. The last line printed is
-the result object of the benchmark's contract; everything else worth
-keeping goes on earlier lines and into ``bench_out/<workload>/``.
+chip) and, with ``--trace 1`` or ``2``, the trace reducer. The last line
+printed is the result object of the benchmark's contract; everything else
+worth keeping goes on earlier lines and into ``bench_out/<workload>/``.
+
+``--trace 2`` measures first and traces afterwards, in one server: it is a
+``--trace 0`` run up to the moment the window's plan has returned and the
+two scrapes are in, and every end-to-end number comes from that clean
+window. Only then does it touch the profiler: a second plan of the same
+mix and rate, and slices through ``/start_profile`` once its ramp is over
+(``traced_segment``). ``--trace 1`` (slices INSIDE the window, nothing of
+the run a measurement) stays for a tree without ``trace_in_run``.
 
 Set-up, all of it counted in ``setup_s``: server start; a warm-up that
 walks the cell's ladder of step programs, the same requests in every run;
@@ -44,6 +52,11 @@ from benchmark.harness.server import NO_CHIP, Server, child_env  # noqa: E402
 WARM_SEED = 7_000_003  # the warm-up's traffic never depends on --seed
 COMPILES = ("vllm:step_bucket_compiles_total", "vllm:step_bucket_compiles")
 STEP_COUNT = 'vllm:engine_step_duration_seconds_count{phase="dispatch"}'
+# --trace 2: arrivals of the traced segment after its ramp, and for how much
+# longer they and the profiler's attempts go on while no slice holds the
+# device.
+SEGMENT_S = 8.0
+SEGMENT_MORE_S = 12.0
 
 
 class RunFailed(Exception):
@@ -156,6 +169,61 @@ async def traced(client: Client, at_s: float, window_s: float, until_s: float, f
     client.enough.set()
 
 
+async def profiled_slice(client: Client, d: Path, for_s: float) -> dict:
+    """One slice through the server's profiler control, with a ``/metrics``
+    scrape just AFTER the start has returned and one just BEFORE the stop is
+    sent: a counter's delta covers no more than the trace does, so a
+    roofline share read from the two is under-read, never over-read."""
+    await client.post_json("/start_profile", {"trace_dir": str(d)})
+    lo, m_lo = client.now(), await client.metrics()
+    await asyncio.sleep(for_s)
+    m_hi, hi = await client.metrics(), client.now()
+    stop = await client.post_json("/stop_profile")
+    return {"lo": lo, "hi": hi, "dir": str(d), "m_lo": m_lo, "m_hi": m_hi,
+            "stop_s": stop.get("stop_s")}
+
+
+async def traced_segment(client: Client, plan, for_s: float, most: int,
+                         trace_root: Path) -> dict:
+    """``--trace 2``, after the window has closed: drive ``plan`` (the same
+    mix and rate from the next seed, its own ramp, then ``SEGMENT_S``) and
+    take profiler slices once the ramp is over. The first start and stop of
+    the profiler in a process cost more than the others, so one throw-away
+    pair goes first, inside the ramp. Up to ``most`` slices that hold the
+    device are kept; while there is none the attempts go on, for at most
+    ``SEGMENT_MORE_S`` more. Then whatever of the plan is still running is
+    dropped: nothing here is scored."""
+    shutil.rmtree(trace_root, ignore_errors=True)
+    client.enough.clear()
+    plan.score_until = plan.start_s - 1.0  # no request of it has to finish
+    client.t0 = time.perf_counter() - plan.start_s + 0.5
+    info: dict = {"attempts": 0, "slices": [], "profiled": []}
+
+    async def slices() -> None:
+        try:
+            first = await profiled_slice(client, trace_root / "first", 0.2)
+            info["first_stop_s"] = first["stop_s"]
+            shutil.rmtree(trace_root / "first", ignore_errors=True)
+            await asyncio.sleep(max(0.0, -client.now()))
+            kept = info["slices"]
+            while len(kept) < most and client.now() < (
+                    SEGMENT_S + (0.0 if kept else SEGMENT_MORE_S) - for_s):
+                d = trace_root / f"attempt{info['attempts']}"
+                info["attempts"] += 1
+                sl = await profiled_slice(client, d, for_s)
+                if has_device_plane(d):
+                    kept.append(sl)
+                else:
+                    shutil.rmtree(d, ignore_errors=True)
+        finally:
+            client.enough.set()
+
+    side = asyncio.create_task(slices())
+    info["records"] = await client.run_plan(plan, 0.0)
+    await side
+    return info
+
+
 async def scrape_at(client: Client, at_s: float, into: dict, key: str) -> None:
     await asyncio.sleep(max(0.0, at_s - client.now()))
     into[key] = (client.now(), await client.metrics())
@@ -187,7 +255,7 @@ async def drive(cell: Cell, server: Server, args, out_dir: Path) -> dict:
         trace_info: dict = {}
         side = [asyncio.create_task(scrape_at(client, 0.0, scrapes, "m0")),
                 asyncio.create_task(scrape_at(client, args.seconds, scrapes, "m1"))]
-        if args.trace:
+        if args.trace == 1:
             # The same mix goes on after the window, unscored, for as long
             # as the profiler needs to give a device trace.
             more = traffic_mod.build_plan(cell.traffic, rate, 0.0, args.trace_extension,
@@ -207,6 +275,16 @@ async def drive(cell: Cell, server: Server, args, out_dir: Path) -> dict:
                    drained_s=client.now() - args.seconds,
                    waiting_end=first(scrapes["m1"][1], "vllm:num_requests_waiting"),
                    waiting_start=first(scrapes["m0"][1], "vllm:num_requests_waiting"))
+        if args.trace == 2:
+            # The window is closed and everything scored is in `res`. The
+            # plan was NOT extended: itl_p95_ms pools the gaps of the drain
+            # too, and a drain under continued arrivals reads differently.
+            more = traffic_mod.build_plan(cell.traffic, rate, ramp_s,
+                                          SEGMENT_S + SEGMENT_MORE_S,
+                                          args.seed + 1, vocab, positions)
+            res["trace_info"] = await traced_segment(
+                client, more, args.trace_seconds, args.trace_slices,
+                out_dir / "trace")
     return res
 
 
@@ -247,15 +325,27 @@ def layer_context(cell: Cell, res: dict, scored: dict, trace: dict | None,
         return delta(STEP_COUNT)
 
     slices = res["trace_info"].get("slices", [])
+    # --trace 2 traces a segment of its own, after the window: the slices'
+    # times are on that segment's clock, as are these records.
+    traced_records = res["trace_info"].get("records", res["records"])
 
     def decode_ctx():
         # (decode tokens, sum of their context lengths) in the traced
         # slices. Tokens computed just before a slice began arrive inside
         # it and those computed at its end arrive after it; 0.15 s are
         # dropped at each start so that the count stays on the low side.
-        parts = [scoring.decode_context_tokens(res["records"], sl["lo"] + 0.15, sl["hi"])
+        parts = [scoring.decode_context_tokens(traced_records, sl["lo"] + 0.15, sl["hi"])
                  for sl in slices]
         return sum(p[0] for p in parts), sum(p[1] for p in parts)
+
+    def slice_delta(*names: str):
+        """A counter's deltas between the two scrapes inside each kept
+        slice, summed; None where the slices carry no scrapes (--trace 1)
+        or the counter is missing."""
+        for n in names:
+            if slices and all(n in sl.get(k, ()) for sl in slices for k in ("m_lo", "m_hi")):
+                return sum(sl["m_hi"][n] - sl["m_lo"][n] for sl in slices)
+        return None
 
     return {
         "cell": cell, "config": cell.hf_config, "facts": scored["facts"],
@@ -266,7 +356,8 @@ def layer_context(cell: Cell, res: dict, scored: dict, trace: dict | None,
         # Every attempt, kept or not, from its start to the return of
         # /stop_profile, which stalls the server for seconds.
         "profiled": res["trace_info"].get("profiled", []),
-        "decode_context_tokens": decode_ctx, "kernel_counts": kernel_counts,
+        "decode_context_tokens": decode_ctx, "slice_delta": slice_delta,
+        "kernel_counts": kernel_counts,
         "peaks": peaks_for(device_kind),
     }
 
@@ -276,7 +367,7 @@ def main(argv: list[str]) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     # For the builder of a cell, not for the driver:
     ap.add_argument("--rate", type=float, default=None, help="offered rate, for the sweep")
     ap.add_argument("--engine-arg", action="append", default=[],
@@ -285,7 +376,8 @@ def main(argv: list[str]) -> int:
                     help="serve the check requests and compare; no warm-up, no window")
     ap.add_argument("--trace-seconds", type=float, default=1.5)
     ap.add_argument("--trace-slices", type=int, default=3,
-                    help="with --trace 1: how many slices of --trace-seconds, spread over the window")
+                    help="how many slices of --trace-seconds: with --trace 1 spread over the "
+                         "window, with --trace 2 taken after it")
     ap.add_argument("--trace-extension", type=float, default=60.0,
                     help="with --trace 1: how long the traffic may go on after the window")
     args = ap.parse_args(argv)
@@ -367,13 +459,21 @@ def main(argv: list[str]) -> int:
     device = dict(facts)
     result: dict = {"correct": correct, "attempted": scored["attempted"],
                     "failed": scored["failed"]}
+    # The end-to-end metrics: all a --trace 0 line holds, and under
+    # --trace 2 (clean window first) side by side with the per-layer ones.
+    measured = {
+        m["name"]: {"value": e2e[m["name"]], "unit": units[m["name"]]}
+        for m in cell.end_to_end if m["name"] in e2e
+    }
 
     if args.trace:
         slices = res["trace_info"].get("slices", [])
         log(f"profiler: {res['trace_info'].get('attempts')} attempt(s), slices with the device: "
             f"{[(round(sl['lo'], 2), round(sl['hi'], 2)) for sl in slices]}; from an attempt's "
             f"start to the return of its /stop_profile: "
-            f"{[(round(lo, 2), round(hi, 2)) for lo, hi in res['trace_info'].get('profiled', [])]}")
+            f"{[(round(lo, 2), round(hi, 2)) for lo, hi in res['trace_info'].get('profiled', [])]}"
+            + (f"; /stop_profile took {[sl.get('stop_s') for sl in slices]} s, the throw-away "
+               f"first {res['trace_info'].get('first_stop_s')} s" if args.trace == 2 else ""))
         if not slices:
             log("FAILED: no trace with a device plane")
             return 1
@@ -386,6 +486,8 @@ def main(argv: list[str]) -> int:
                 f"{(out_dir / 'trace_reduce.log').read_text()[-3000:]}")
             return 1
         trace = json.loads((out_dir / "trace.json").read_text())
+        if args.trace == 2:
+            shutil.rmtree(out_dir / "trace", ignore_errors=True)  # reduced: not kept
         ctx = layer_context(cell, res, scored, trace, facts["kind"], args.seconds)
         values = {}
         for m in cell.per_layer:
@@ -393,14 +495,21 @@ def main(argv: list[str]) -> int:
             if v is not None:
                 values[m["name"]] = {"value": v, "unit": m["unit"]}
         device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
-        result["metrics"] = values
+        traced_steps = ctx["slice_delta"](STEP_COUNT)
+        if traced_steps:
+            # What tracing costs while it is ON, for PERF.md.
+            log(f"step interval inside the traced slices: "
+                f"{1e3 * sum(sl['hi'] - sl['lo'] for sl in slices) / traced_steps:.3f} ms over "
+                f"{traced_steps:.0f} steps; in the clean window "
+                f"{1e3 * ctx['scrape_dt'] / (ctx['steps']() or float('nan')):.3f} ms")
         result["breakdown"] = {"device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"]}
-        log(f"end to end in this TRACED run (not a measurement): {json.dumps(e2e)}")
+        if args.trace == 2:
+            result["metrics"] = {**measured, **values}
+        else:
+            result["metrics"] = values
+            log(f"end to end in this TRACED run (not a measurement): {json.dumps(e2e)}")
     else:
-        result["metrics"] = {
-            m["name"]: {"value": e2e[m["name"]], "unit": units[m["name"]]}
-            for m in cell.end_to_end if m["name"] in e2e
-        }
+        result["metrics"] = measured
     result["device"] = device
     (out_dir / "result.json").write_text(json.dumps(result))
     print(json.dumps(result))

@@ -21,7 +21,9 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 def test_top_level_keys_and_limits():
     assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs", "workloads",
-                             "end_to_end", "per_layer"}
+                             "end_to_end", "per_layer", "trace_in_run"}
+    # Per-layer metrics come from the run that measures: `--trace 2`.
+    assert MANIFEST["trace_in_run"] is True
     assert 1 <= MANIFEST["run_seconds"] <= 51 and isinstance(MANIFEST["run_seconds"], int)
     assert MANIFEST["paths"] == ["benchmark", "tests/benchmark"]
     assert MANIFEST["command"][:2] == ["python3", "benchmark/run_cell.py"]

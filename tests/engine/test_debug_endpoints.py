@@ -12,11 +12,14 @@ class StubCore:
     def __init__(self):
         self.calls = []
 
-    def start_profile(self, trace_dir=None):
+    def start_profile(self, trace_dir=None, python_tracer_level=0):
         self.calls.append(("start", trace_dir))
+        self.tracer_levels = getattr(self, "tracer_levels", [])
+        self.tracer_levels.append(python_tracer_level)
 
     def stop_profile(self):
         self.calls.append(("stop",))
+        return {"trace_dir": "/tmp/p", "traced_s": 1.5, "stop_s": 0.25}
 
 
 class StubEngine:
@@ -102,7 +105,18 @@ def test_start_profile_rejects_bad_body():
     status, _ = _request(engine, "POST", "/start_profile",
                          json={"trace_dir": 42})
     assert status == 400
+    status, _ = _request(engine, "POST", "/start_profile",
+                         json={"python_tracer_level": 7})
+    assert status == 400
     assert engine.engine_core.calls == []
+
+
+def test_start_profile_python_tracer_is_off_unless_asked_for():
+    engine = StubEngine()
+    _request(engine, "POST", "/start_profile", json={"trace_dir": "/tmp/p"})
+    _request(engine, "POST", "/start_profile",
+             json={"python_tracer_level": 1})
+    assert engine.engine_core.tracer_levels == [0, 1]
 
 
 def test_stop_profile_roundtrip():
@@ -112,5 +126,7 @@ def test_stop_profile_roundtrip():
     assert status == 200
     status, body = _request(engine, "POST", "/stop_profile")
     assert status == 200
-    assert body == {"status": "profiling stopped"}
+    # The stop answers with the seconds it took and the trace directory.
+    assert body == {"status": "profiling stopped", "trace_dir": "/tmp/p",
+                    "traced_s": 1.5, "stop_s": 0.25}
     assert engine.engine_core.calls == [("start", "/tmp/p"), ("stop",)]

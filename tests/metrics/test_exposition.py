@@ -6,6 +6,8 @@ from __future__ import annotations
 
 import re
 
+import pytest
+
 from vllm_tpu.core.sched_output import SchedulerStats
 from vllm_tpu.metrics.prometheus import PrometheusRegistry
 from vllm_tpu.metrics.stats import IterationStats
@@ -45,7 +47,14 @@ def _populated_registry() -> PrometheusRegistry:
         bucket_compiles=1, bucket_hits=9, pipeline_stall_s=0.1,
         step_schedule_times=[0.0002, 0.0009],
         step_dispatch_times=[0.004],
+        step_prepare_times=[0.001, 0.002, 0.003],
         step_finalize_times=[0.0001],
+        step_fetch_times=[0.012, 0.013],
+        step_update_times=[0.0003],
+        steps_by_kind={"decode": 9, "mixed": 1},
+        step_tokens_by_kind={"decode": 18, "mixed": 10},
+        attn_context_tokens_by_kind={"decode": 180, "mixed": 10},
+        step_first_use_s=7.5,
         batch_num_tokens=96, batch_num_reqs=3, batch_occupancy=0.75,
         step_interval_s=0.006,
         perfwatch_captures=2, perfwatch_captures_aborted=1,
@@ -58,6 +67,7 @@ def _populated_registry() -> PrometheusRegistry:
         num_generation_tokens=12, num_prompt_tokens=7,
         ttfts=[0.05], inter_token_latencies=[0.01, 0.02],
         e2e_latencies=[0.4], finished_reasons=["stop", "length"],
+        output_s=0.0005,
     )
     reg.record(stats, it)
     return reg
@@ -169,6 +179,124 @@ def test_step_phase_family_renders_per_phase():
     assert "vllm:engine_batch_requests 3" in text
     assert "vllm:engine_batch_occupancy 0.75" in text
     assert "vllm:engine_step_interval_seconds 0.006" in text
+
+
+@pytest.mark.parametrize("phase,count,total", [
+    ("prepare", 3, 0.006), ("fetch", 2, 0.025), ("update", 1, 0.0003),
+    ("output", 1, 0.0005),
+])
+def test_step_phase_family_has_the_host_and_device_wait_phases(
+        phase, count, total):
+    """prepare nests inside dispatch, fetch inside finalize; update and
+    output (the frontend's, from IterationStats) follow them. A reader
+    divides a phase's _sum by a step count, so both must be there."""
+    reg = _populated_registry()
+    text = reg.render()
+    name = "vllm:engine_step_duration_seconds"
+    assert f'{name}_count{{phase="{phase}"}} {count}' in text
+    [line] = [l for l in text.splitlines()
+              if l.startswith(f'{name}_sum{{phase="{phase}"}}')]
+    assert float(line.rsplit(" ", 1)[1]) == pytest.approx(total)
+    help_line = next(l for l in text.splitlines()
+                     if l.startswith(f"# HELP {name} "))
+    assert phase in help_line and "inside" in help_line
+    # A record without iteration stats (or an older one without the
+    # field's value) adds no output sample.
+    reg.record(SchedulerStats(), IterationStats())
+    assert f'{name}_count{{phase="output"}} 1' in reg.render()
+
+
+@pytest.mark.parametrize("family,decode,mixed", [
+    ("vllm:engine_steps_total", 9, 1),
+    ("vllm:engine_step_tokens_total", 18, 10),
+    ("vllm:attn_context_tokens_total", 180, 10),
+])
+def test_step_kind_counters_are_cumulative_and_never_decrease(
+        family, decode, mixed):
+    reg = _populated_registry()
+    text = reg.render()
+    assert f'{family}{{kind="decode"}} {float(decode)}' in text
+    assert f'{family}{{kind="mixed"}} {float(mixed)}' in text
+    assert f"# TYPE {family} counter" in text
+    # The runner's counters are cumulative: the registry adopts the larger
+    # value, and a snapshot from a respawned engine (zeros) lowers nothing.
+    field = {"vllm:engine_steps_total": "steps_by_kind",
+             "vllm:engine_step_tokens_total": "step_tokens_by_kind",
+             "vllm:attn_context_tokens_total":
+                 "attn_context_tokens_by_kind"}[family]
+    reg.record(SchedulerStats(**{field: {"decode": decode + 5, "mixed": 0}}))
+    reg.record(SchedulerStats())
+    text = reg.render()
+    assert f'{family}{{kind="decode"}} {float(decode + 5)}' in text
+    assert f'{family}{{kind="mixed"}} {float(mixed)}' in text
+
+
+def test_first_use_seconds_ratchet_beside_their_count():
+    reg = _populated_registry()
+    assert "vllm:step_first_use_seconds_total 7.5" in reg.render()
+    reg.record(SchedulerStats(step_first_use_s=3.0))  # a respawned engine
+    assert "vllm:step_first_use_seconds_total 7.5" in reg.render()
+    reg.record(SchedulerStats(step_first_use_s=9.25))
+    assert "vllm:step_first_use_seconds_total 9.25" in reg.render()
+
+
+def test_runner_counts_decode_only_against_mixed_on_a_hand_made_batch(
+        tmp_path):
+    """Two prompts of 3 and 7 tokens, 8 tokens each, greedy: ONE mixed step
+    (the prefill of both, 10 tokens, each row attending to its prompt) and
+    then decode-only steps of 2 rows whose contexts grow by one a step.
+    The counters ride SchedulerStats to /metrics, cumulative."""
+    from tests.models.utils import tiny_llama_dir
+    from vllm_tpu import LLM, SamplingParams
+
+    llm = LLM(model=tiny_llama_dir(tmp_path), dtype="float32",
+              max_model_len=128, block_size=16, max_num_batched_tokens=128,
+              max_num_seqs=4, num_gpu_blocks_override=64,
+              # One step at a time: the lag pipeline would dispatch two
+              # steps past the last token, real work that is counted too.
+              async_scheduling=False)
+    engine = llm.llm_engine
+    reg = PrometheusRegistry()
+    params = SamplingParams(temperature=0.0, max_tokens=8, ignore_eos=True)
+
+    def run_batch(tag):
+        engine.add_request(f"{tag}-a", {"prompt_token_ids": [11, 24, 9]},
+                           params)
+        engine.add_request(
+            f"{tag}-b", {"prompt_token_ids": [1, 2, 3, 4, 5, 6, 7]}, params)
+        while engine.has_unfinished_requests():
+            outputs = engine.engine_core.get_output()
+            processed = engine.output_processor.process_outputs(
+                outputs.outputs)
+            reg.record(outputs.scheduler_stats, processed.iteration_stats)
+
+    run_batch("one")
+    runner = engine.engine_core.engine_core.executor.worker.runner
+    # The first tokens come from the prefill; 7 more decode steps each.
+    decode_ctx = sum(3 + j for j in range(1, 8)) + sum(
+        7 + j for j in range(1, 8))
+    assert runner.steps_by_kind == {"decode": 7, "mixed": 1}
+    assert runner.step_tokens_by_kind == {"decode": 14, "mixed": 10}
+    assert runner.attn_context_tokens_by_kind == {
+        "decode": decode_ctx, "mixed": 10}
+    assert runner.decode_only_launches == 7
+    assert runner.timing["steps"] == 8 and runner.timing["prep_s"] > 0
+    assert runner.first_use_s > 0 and runner.bucket_compiles == 2
+    text = reg.render()
+    assert (f'vllm:attn_context_tokens_total{{kind="decode"}} '
+            f'{float(decode_ctx)}') in text
+    assert 'vllm:engine_steps_total{kind="mixed"} 1.0' in text
+    assert f"vllm:step_first_use_seconds_total {runner.first_use_s}" in text
+    for phase in ("prepare", "fetch", "update", "output"):
+        assert ('vllm:engine_step_duration_seconds_count'
+                f'{{phase="{phase}"}} 8') in text, phase
+    # Cumulative: a second batch adds to every counter, and uses no new
+    # step program.
+    run_batch("two")
+    assert runner.steps_by_kind == {"decode": 14, "mixed": 2}
+    assert runner.attn_context_tokens_by_kind["decode"] == 2 * decode_ctx
+    assert runner.bucket_compiles == 2
+    assert f'kind="decode"}} {float(2 * decode_ctx)}' in reg.render()
 
 
 def test_perfwatch_family_renders():

@@ -76,6 +76,21 @@ REQUIRED_DECODE_METRICS = {
     "vllm:decode_early_exits_total",
 }
 
+# Documented in the README ("Metrics"); the benchmark's per-layer readers
+# (benchmark/layer_metrics/) read these names, and every phase below as a
+# label of vllm:engine_step_duration_seconds.
+REQUIRED_STEP_METRICS = {
+    "vllm:engine_step_duration_seconds",
+    "vllm:engine_steps_total",
+    "vllm:engine_step_tokens_total",
+    "vllm:attn_context_tokens_total",
+    "vllm:step_bucket_compiles",
+    "vllm:step_first_use_seconds_total",
+}
+REQUIRED_STEP_PHASES = ("schedule", "dispatch", "prepare", "finalize",
+                        "fetch", "update", "output")
+STEP_KINDS = ("decode", "mixed")
+
 # Documented in the README ("Sampling performance"); the A/B protocol
 # reads these to confirm the fused sampler actually ran.
 REQUIRED_SAMPLER_METRICS = {
@@ -173,7 +188,7 @@ REQUIRED_UPGRADE_METRICS = {
 # Floor on the registry size: a refactor that silently drops metrics
 # from the render list must fail the lint even if no required-set name
 # is among the casualties. Bump when adding metrics.
-MIN_METRICS = 97
+MIN_METRICS = 101
 
 
 def check() -> list[str]:
@@ -252,6 +267,11 @@ def check() -> list[str]:
         errors.append(
             f"required decode metric {name} is missing from "
             f"the registry (documented in README)")
+    for name in sorted(REQUIRED_STEP_METRICS - set(seen)):
+        errors.append(
+            f"required step metric {name} is missing from "
+            f"the registry (documented in README)")
+    errors += check_step_exposition()
     for name in sorted(REQUIRED_SAMPLER_METRICS - set(seen)):
         errors.append(
             f"required sampler metric {name} is missing from "
@@ -296,6 +316,36 @@ def check() -> list[str]:
             f"from the render list")
 
     return errors
+
+
+def check_step_exposition() -> list[str]:
+    """One engine step's stats through a fresh registry: every required
+    phase and both kinds of step must come out on /metrics."""
+    from vllm_tpu.core.sched_output import SchedulerStats
+    from vllm_tpu.metrics.prometheus import PrometheusRegistry
+    from vllm_tpu.metrics.stats import IterationStats
+
+    phases = {f"step_{p}_times": [0.001]
+              for p in REQUIRED_STEP_PHASES if p != "output"}
+    by_kind = {k: 1 for k in STEP_KINDS}
+    reg = PrometheusRegistry()
+    try:
+        reg.record(
+            SchedulerStats(**phases, steps_by_kind=by_kind,
+                           step_tokens_by_kind=by_kind,
+                           attn_context_tokens_by_kind=by_kind),
+            IterationStats(output_s=0.001))
+    except (TypeError, AttributeError) as e:
+        return [f"the step stats no longer reach the registry: {e}"]
+    text = reg.render()
+    wanted = [f'vllm:engine_step_duration_seconds_count{{phase="{p}"}} 1'
+              for p in REQUIRED_STEP_PHASES]
+    wanted += [f'{name}{{kind="{k}"}} 1.0' for k in STEP_KINDS
+               for name in ("vllm:engine_steps_total",
+                            "vllm:engine_step_tokens_total",
+                            "vllm:attn_context_tokens_total")]
+    return [f"/metrics lacks the sample {w!r}" for w in wanted
+            if w not in text]
 
 
 def main() -> int:

@@ -167,6 +167,12 @@ class EngineCoreOutput:
     kv_blocks_held: int = 0
 
 
+# The phases of vllm:engine_step_duration_seconds that EngineCore times,
+# in the order of a step; "output" (the frontend's) is IterationStats'.
+STEP_PHASES = ("schedule", "dispatch", "prepare", "finalize", "fetch",
+               "update")
+
+
 @dataclass
 class SchedulerStats:
     """Per-step snapshot (reference: v1/metrics/stats.py)."""
@@ -215,6 +221,16 @@ class SchedulerStats:
     step_launches: int = 0
     decode_only_launches: int = 0
     launch_sampled_tokens: int = 0
+    # By kind of step ("decode" = the decode-only step that runs the
+    # decode attention kernel, "mixed" = every other), cumulative, counted
+    # where the runner builds the step: steps, scheduled tokens, and
+    # context tokens attended (sum over the step's rows of the positions
+    # each row attends to). Seconds spent in first uses of a step program
+    # (beside bucket_compiles, their count).
+    steps_by_kind: dict[str, int] = field(default_factory=dict)
+    step_tokens_by_kind: dict[str, int] = field(default_factory=dict)
+    attn_context_tokens_by_kind: dict[str, int] = field(default_factory=dict)
+    step_first_use_s: float = 0.0
     prep_fallback_rows: int = 0
     # Sampling-epilogue routing: in-jit sample() calls routed to the
     # fused sort-free kernel vs sampling rows that fell back to the XLA
@@ -228,12 +244,17 @@ class SchedulerStats:
     # loop before exhausting their claimed budget (a row stopped early).
     decode_step_lengths: list[int] = field(default_factory=list)
     decode_early_exits: int = 0
-    # Engine-step phase durations (drained each snapshot, seconds) —
-    # attached by EngineCore from the schedule/dispatch/finalize sites;
-    # feed the vllm:engine_step_duration_seconds histogram family.
+    # Engine-step phase durations (drained each snapshot, seconds), one
+    # list a phase of STEP_PHASES — attached by EngineCore from its
+    # schedule/dispatch/finalize/update sites and from the runner's
+    # always-on step timing (prepare inside dispatch, fetch inside
+    # finalize); feed the vllm:engine_step_duration_seconds family.
     step_schedule_times: list[float] = field(default_factory=list)
     step_dispatch_times: list[float] = field(default_factory=list)
+    step_prepare_times: list[float] = field(default_factory=list)
     step_finalize_times: list[float] = field(default_factory=list)
+    step_fetch_times: list[float] = field(default_factory=list)
+    step_update_times: list[float] = field(default_factory=list)
     # Last dispatched batch occupancy (tokens, requests, and the fraction
     # of the token budget used) + wall time between step completions.
     batch_num_tokens: int = 0

@@ -42,6 +42,7 @@ from vllm_tpu.resilience import (
     vet_live_config,
 )
 from vllm_tpu.sampling_params import RequestOutputKind, SamplingParams
+from vllm_tpu.tracing import trace_span
 
 logger = init_logger(__name__)
 
@@ -744,7 +745,12 @@ class AsyncLLM:
 
     def _drain_input_queue(self, block: bool) -> None:
         try:
-            op, payload = self._input_queue.get(timeout=0.1 if block else 0)
+            if block:
+                # Nothing runs (or nothing can): the loop waits for input.
+                with trace_span("wait_input"):
+                    op, payload = self._input_queue.get(timeout=0.1)
+            else:
+                op, payload = self._input_queue.get_nowait()
         except queue.Empty:
             return
         while True:
@@ -786,16 +792,18 @@ class AsyncLLM:
                             rid, state, bypass_retry_budget=True)
                 elif op == "abort":
                     self.engine_core.abort_requests(payload)
-                elif op == "set_config":
-                    # Live-config push: the client's utility sockets
+                elif op == "call":
+                    # A call of the engine client made for another
+                    # thread (live-config push, profiler start/stop): the
+                    # client's utility sockets and the engine's step
                     # belong to this thread; the API handler waits on
                     # the future. An engine death mid-broadcast must
                     # still reach the busy loop's recovery path.
-                    updates, fut = payload
+                    method, args, fut = payload
                     if fut.set_running_or_notify_cancel():
                         try:
                             fut.set_result(
-                                self.engine_core.set_config(updates))
+                                getattr(self.engine_core, method)(*args))
                         except EngineRestartedError as e:
                             fut.set_exception(e)
                             raise
@@ -1285,14 +1293,29 @@ class AsyncLLM:
 
     def _engine_set_config(self, updates: dict,
                            timeout_s: float) -> dict:
-        client = self.engine_core
-        if not hasattr(client, "set_config"):
+        if not hasattr(self.engine_core, "set_config"):
             return {"applied": [], "inert": sorted(updates)}
-        if self._thread is None or not self._thread.is_alive():
-            return client.set_config(updates)
+        return self.call_on_engine_thread(
+            "set_config", updates).result(timeout=timeout_s)
+
+    def call_on_engine_thread(self, method: str, *args) -> Future:
+        """Queue ``engine_core.<method>(*args)`` for the engine-loop
+        thread, which runs it between two steps, and return the future of
+        its result. That thread owns the client's utility sockets and is
+        the only one that may step the engine or run the profiler
+        (``/start_profile`` and ``/stop_profile`` ARM their call here).
+        With no loop running (tests, a sync embedding) the call is made
+        at once, on the caller's thread."""
         fut: Future = Future()
-        self._input_queue.put(("set_config", (updates, fut)))
-        return fut.result(timeout=timeout_s)
+        if self._thread is None or not self._thread.is_alive():
+            try:
+                fut.set_result(getattr(self.engine_core, method)(*args))
+            except Exception as e:
+                fut.set_exception(e)
+        else:
+            self._input_queue.put(("call", (method, args, fut)))
+        return fut
+
 
     # -- QoS: brownout ladder + FIFO-vs-QoS A/B ------------------------
 

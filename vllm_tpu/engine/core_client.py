@@ -170,10 +170,11 @@ class InprocClient:
     def list_loras(self) -> list[str]:
         return self.engine_core.list_loras()
 
-    def start_profile(self, trace_dir: str | None = None) -> bool:
-        return self.engine_core.start_profile(trace_dir)
+    def start_profile(self, trace_dir: str | None = None,
+                      python_tracer_level: int = 0) -> dict:
+        return self.engine_core.start_profile(trace_dir, python_tracer_level)
 
-    def stop_profile(self) -> bool:
+    def stop_profile(self) -> dict:
         return self.engine_core.stop_profile()
 
     def perf_status(self) -> dict:
@@ -616,10 +617,12 @@ class _ZMQClientBase:
     def list_loras(self) -> list[str]:
         return self._utility("list_loras", timeout_ms=30_000)
 
-    def start_profile(self, trace_dir: str | None = None) -> bool:
-        return self._utility("start_profile", trace_dir, timeout_ms=30_000)
+    def start_profile(self, trace_dir: str | None = None,
+                      python_tracer_level: int = 0) -> dict:
+        return self._utility("start_profile", trace_dir, python_tracer_level,
+                             timeout_ms=30_000)
 
-    def stop_profile(self) -> bool:
+    def stop_profile(self) -> dict:
         return self._utility("stop_profile", timeout_ms=60_000)
 
     def perf_status(self) -> dict:

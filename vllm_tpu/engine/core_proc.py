@@ -14,6 +14,7 @@ import pickle
 import traceback
 
 from vllm_tpu.logger import init_logger
+from vllm_tpu.tracing import trace_span
 
 # Wire message types (frame 0).
 MSG_ADD = b"ADD"
@@ -245,6 +246,13 @@ def run_engine_core(config_bytes: bytes, input_addr: str,
                     }),
                 ])
 
+        def poll_input(timeout_ms: int) -> bool:
+            if not timeout_ms:
+                return bool(inp.poll(0))
+            # The idle wait for input, a span of a running profile.
+            with trace_span("wait_input"):
+                return bool(inp.poll(timeout_ms))
+
         last_mesh_epoch = None
         if core.mesh_recovery is not None:
             # Initial report so frontends render /health mesh state
@@ -275,7 +283,7 @@ def run_engine_core(config_bytes: bytes, input_addr: str,
             # Mid-wave idle ranks poll non-blocking: they must keep pace
             # with the busy ranks' step rate, not the 5 Hz idle tick.
             timeout = 0 if busy or (lockstep and global_unfinished) else 200
-            while inp.poll(timeout):
+            while poll_input(timeout):
                 frames = inp.recv_multipart()
                 kind = frames[0]
                 if kind == MSG_ADD:

@@ -13,7 +13,7 @@ from typing import Iterable
 
 from vllm_tpu.config import EngineConfig
 from vllm_tpu.core.kv_cache_utils import make_block_hasher
-from vllm_tpu.core.sched_output import EngineCoreOutputs
+from vllm_tpu.core.sched_output import STEP_PHASES, EngineCoreOutputs
 from vllm_tpu.core.scheduler import Scheduler
 from vllm_tpu.engine.executor import Executor
 from vllm_tpu.logger import init_logger
@@ -64,7 +64,7 @@ class EngineCore:
         # Per-phase step durations accumulated since the last stats
         # snapshot (drained into SchedulerStats by _attach_engine_stats).
         self._phase_times: dict[str, list[float]] = {
-            "schedule": [], "dispatch": [], "finalize": [],
+            phase: [] for phase in STEP_PHASES
         }
         # Last dispatched batch occupancy + step-completion timestamps
         # (step-interval gauge).
@@ -323,6 +323,7 @@ class EngineCore:
             ):
                 handle = self.executor.dispatch(scheduler_output)
             self._phase_times["dispatch"].append(time.monotonic() - t0)
+            self._note_runner_phase("prepare", handle, "prep_s")
             self._last_batch = (
                 scheduler_output.total_num_scheduled_tokens,
                 scheduler_output.num_reqs,
@@ -345,9 +346,13 @@ class EngineCore:
             self._stall_s += stall
         self._inflight.popleft()
         self._phase_times["finalize"].append(stall)
-        outputs = self.scheduler.update_from_output(
-            scheduler_output, runner_output
-        )
+        self._note_runner_phase("fetch", handle, "wait_s")
+        t0 = time.monotonic()
+        with trace_span("update"):
+            outputs = self.scheduler.update_from_output(
+                scheduler_output, runner_output
+            )
+        self._phase_times["update"].append(time.monotonic() - t0)
         # Disaggregated handoffs flush IN this step, not at the top of
         # the next one: the decode engine is stalled on the push, so its
         # latency is on the request's critical path — unlike ordinary
@@ -377,6 +382,14 @@ class EngineCore:
                     finish_reason=str(o.finish_reason),
                 )
         return outputs
+
+    def _note_runner_phase(self, phase: str, handle, key: str) -> None:
+        """The runner's own timing of this step, left on its handle
+        (``prep_s`` inside dispatch, ``wait_s`` inside finalize), as a
+        sample of ``phase``."""
+        seconds = getattr(handle, key, None)
+        if seconds is not None:
+            self._phase_times[phase].append(seconds)
 
     def _trace_request_progress(self, o) -> None:
         """Advance a request's async lifecycle span on its outputs: first
@@ -409,10 +422,9 @@ class EngineCore:
         stats.pipeline_stall_s = self._stall_s
         # Drain the per-phase step durations accumulated since the last
         # snapshot into this one (exactly-once export).
-        stats.step_schedule_times = self._phase_times["schedule"]
-        stats.step_dispatch_times = self._phase_times["dispatch"]
-        stats.step_finalize_times = self._phase_times["finalize"]
-        self._phase_times = {"schedule": [], "dispatch": [], "finalize": []}
+        for phase in STEP_PHASES:
+            setattr(stats, f"step_{phase}_times", self._phase_times[phase])
+        self._phase_times = {phase: [] for phase in STEP_PHASES}
         stats.batch_num_tokens, stats.batch_num_reqs = self._last_batch
         budget = self.config.scheduler_config.max_num_batched_tokens
         stats.batch_occupancy = (
@@ -432,6 +444,10 @@ class EngineCore:
             stats.launch_sampled_tokens = getattr(
                 runner, "launch_sampled_tokens", 0
             )
+            for name in ("steps_by_kind", "step_tokens_by_kind",
+                         "attn_context_tokens_by_kind"):
+                setattr(stats, name, dict(getattr(runner, name, {})))
+            stats.step_first_use_s = getattr(runner, "first_use_s", 0.0)
             stats.prep_fallback_rows = getattr(
                 runner, "prep_fallback_rows", 0
             )
@@ -898,13 +914,21 @@ class EngineCore:
     def list_loras(self) -> list[str]:
         return self.executor.collective_rpc("list_loras")[0]
 
-    def start_profile(self, trace_dir: str | None = None) -> bool:
-        self.executor.collective_rpc("start_profile", trace_dir)
-        return True
+    def start_profile(self, trace_dir: str | None = None,
+                      python_tracer_level: int = 0) -> dict:
+        """Start a JAX profile (and with it the engine's own spans on the
+        profile's clock). With ``stop_profile`` the one way to profile:
+        HTTP ``/start_profile``, the multiprocess utility call and
+        perfwatch's captures all come through here, and only on the thread
+        that calls ``step()``, between two steps. Raises
+        ``ProfilerBusyError`` while a profile runs."""
+        return self.executor.collective_rpc(
+            "start_profile", trace_dir, python_tracer_level)[0]
 
-    def stop_profile(self) -> bool:
-        self.executor.collective_rpc("stop_profile")
-        return True
+    def stop_profile(self) -> dict:
+        """Stop the running profile: its directory, the seconds traced and
+        the seconds the stop itself took."""
+        return self.executor.collective_rpc("stop_profile")[0]
 
     # ------------------------------------------------------------------
     # Perfwatch: live roofline telemetry + quiet-window kernel A/B
@@ -1021,7 +1045,7 @@ class EngineCore:
         pw = self.perfwatch
         trace_dir = tempfile.mkdtemp(prefix="perfwatch-")
         try:
-            self.executor.collective_rpc("start_profile", trace_dir)
+            self.start_profile(trace_dir)
         except Exception as exc:
             logger.warning("perfwatch: start_profile failed: %s", exc)
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -1039,7 +1063,7 @@ class EngineCore:
             return None
         trace_dir = sess["trace_dir"]
         try:
-            self.executor.collective_rpc("stop_profile")
+            self.stop_profile()
         except Exception as exc:
             logger.warning("perfwatch: stop_profile failed: %s", exc)
             pw.abort_capture(str(exc))
@@ -1069,7 +1093,7 @@ class EngineCore:
         if sess is None:
             return
         try:
-            self.executor.collective_rpc("stop_profile")
+            self.stop_profile()
         except Exception:
             pass
         shutil.rmtree(sess["trace_dir"], ignore_errors=True)
@@ -1236,8 +1260,7 @@ class EngineCore:
                     for _ in range(self._max_inflight + 1):
                         self.step()
                     trace_dir = tempfile.mkdtemp(prefix="perfwatch-ab-")
-                    self.executor.collective_rpc(
-                        "start_profile", trace_dir)
+                    self.start_profile(trace_dir)
                     t0 = time.monotonic()
                     done = 0
                     for _ in range(steps):
@@ -1248,7 +1271,7 @@ class EngineCore:
                         self.step()
                         done += 1
                     wall_s = time.monotonic() - t0
-                    self.executor.collective_rpc("stop_profile")
+                    self.stop_profile()
                     if aborted_reason:
                         break
                     stream = OpSplitStream()
@@ -1271,7 +1294,7 @@ class EngineCore:
             # loop that hosts the replay.
             logger.warning("perfwatch A/B failed: %s", exc)
             try:
-                self.executor.collective_rpc("stop_profile")
+                self.stop_profile()
             except Exception:
                 pass
             aborted_reason = f"error: {exc}"

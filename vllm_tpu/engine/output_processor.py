@@ -215,6 +215,21 @@ class OutputProcessor:
         engine_core_outputs: list[EngineCoreOutput],
         logprobs_lists=None,
     ) -> ProcessedOutputs:
+        """Detokenise and deliver one step's outputs. Timed as the
+        ``output`` phase of ``vllm:engine_step_duration_seconds`` and, in a
+        running profile, ONE span for the step (the per-request
+        ``detokenize`` spans are the chrome trace's alone)."""
+        t0 = time.perf_counter()
+        with trace_span("output", "frontend", reqs=len(engine_core_outputs)):
+            result = self._process_outputs(engine_core_outputs, logprobs_lists)
+        result.iteration_stats.output_s = time.perf_counter() - t0
+        return result
+
+    def _process_outputs(
+        self,
+        engine_core_outputs: list[EngineCoreOutput],
+        logprobs_lists=None,
+    ) -> ProcessedOutputs:
         from vllm_tpu.metrics.stats import IterationStats
 
         result = ProcessedOutputs()

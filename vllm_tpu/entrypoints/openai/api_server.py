@@ -576,9 +576,36 @@ async def handle_models(request: web.Request) -> web.Response:
     })
 
 
+async def _profile_call(engine, status: str, method: str, *args,
+                        **echo) -> web.Response:
+    """Run ``start_profile`` / ``stop_profile`` OFF the event loop and
+    answer with what it returns: the handler only ARMS the call, the
+    engine-loop thread executes it between two steps
+    (``AsyncLLM.call_on_engine_thread``), and the loop keeps streaming
+    tokens while the profiler starts or writes its trace. An engine without
+    a loop of its own gets a thread of the default pool."""
+    try:
+        if hasattr(engine, "call_on_engine_thread"):
+            info = await asyncio.wrap_future(
+                engine.call_on_engine_thread(method, *args))
+        else:
+            info = await asyncio.get_running_loop().run_in_executor(
+                None, getattr(engine.engine_core, method), *args)
+    except Exception as e:
+        error = f"{type(e).__name__}: {e}"
+        return web.json_response(
+            {"error": error},
+            status=409 if "ProfilerBusyError" in error else 500)
+    return web.json_response(
+        {"status": status, **echo,
+         **(info if isinstance(info, dict) else {})})
+
+
 async def handle_start_profile(request: web.Request) -> web.Response:
+    """``{"trace_dir": str, "python_tracer_level": 0|1}``, both optional.
+    The python tracer is off unless asked for."""
     engine: AsyncLLM = request.app[ENGINE_KEY]
-    trace_dir = None
+    trace_dir, python_tracer_level = None, 0
     if request.can_read_body:
         try:
             body = await request.json()
@@ -590,15 +617,19 @@ async def handle_start_profile(request: web.Request) -> web.Response:
             if trace_dir is not None and not isinstance(trace_dir, str):
                 return web.json_response(
                     {"error": "trace_dir must be a string"}, status=400)
-    engine.engine_core.start_profile(trace_dir=trace_dir)
-    return web.json_response(
-        {"status": "profiling started", "trace_dir": trace_dir})
+            python_tracer_level = body.get("python_tracer_level", 0)
+            if python_tracer_level not in (0, 1):
+                return web.json_response(
+                    {"error": "python_tracer_level must be 0 or 1"},
+                    status=400)
+    return await _profile_call(
+        engine, "profiling started", "start_profile", trace_dir,
+        python_tracer_level, trace_dir=trace_dir)
 
 
 async def handle_stop_profile(request: web.Request) -> web.Response:
     engine: AsyncLLM = request.app[ENGINE_KEY]
-    engine.engine_core.stop_profile()
-    return web.json_response({"status": "profiling stopped"})
+    return await _profile_call(engine, "profiling stopped", "stop_profile")
 
 
 async def handle_debug_perf(request: web.Request) -> web.Response:

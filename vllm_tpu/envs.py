@@ -138,8 +138,6 @@ _readers: dict[str, Callable[[], Any]] = {
     "VLLM_TPU_GRAMMAR_MAX_DEPTH": _int("VLLM_TPU_GRAMMAR_MAX_DEPTH", 6),
     # Profiling
     "VLLM_TPU_PROFILER_DIR": _str("VLLM_TPU_PROFILER_DIR", None),
-    # Per-step host/device time breakdown accumulated in ModelRunner.timing.
-    "VLLM_TPU_STEP_TIMING": _bool("VLLM_TPU_STEP_TIMING", False),
     # Count NaNs in the step logits and log an error when any appear
     # (reference: _get_nans_in_logits, gpu_model_runner.py:5193).
     "VLLM_TPU_NAN_CHECK": _bool("VLLM_TPU_NAN_CHECK", False),

@@ -10,7 +10,7 @@ from __future__ import annotations
 import time
 from typing import Any
 
-from vllm_tpu.core.sched_output import SchedulerStats
+from vllm_tpu.core.sched_output import STEP_PHASES, SchedulerStats
 
 
 class Counter:
@@ -296,6 +296,24 @@ class PrometheusRegistry:
             "Jitted-step bucket cache misses (new (tokens,reqs,blocks))")
         self.bucket_hits = Counter(
             "vllm:step_bucket_hits", "Jitted-step bucket cache hits")
+        self.first_use_seconds = Counter(
+            "vllm:step_first_use_seconds_total",
+            "Seconds spent in first uses of a step program (traced, "
+            "lowered, compiled or loaded inside the step's dispatch); "
+            "vllm:step_bucket_compiles counts them")
+        # By kind of step: "decode" = the decode-only step that runs the
+        # decode attention kernel, "mixed" = every other. Counted where
+        # the runner builds the step.
+        self.steps_by_kind = LabeledCounter(
+            "vllm:engine_steps_total",
+            "Engine steps dispatched, by kind of step", "kind")
+        self.step_tokens_by_kind = LabeledCounter(
+            "vllm:engine_step_tokens_total",
+            "Tokens scheduled in dispatched steps, by kind of step", "kind")
+        self.attn_context_tokens = LabeledCounter(
+            "vllm:attn_context_tokens_total",
+            "Context tokens attended: the sum over a step's rows of the "
+            "positions each row attends to, by kind of step", "kind")
         self.pipeline_stall = Counter(
             "vllm:pipeline_stall_seconds",
             "Seconds the async lag-N pipeline blocked on device results")
@@ -347,7 +365,11 @@ class PrometheusRegistry:
         # labeled by phase, plus batch-occupancy / step-interval gauges.
         self.step_duration = LabeledHistogram(
             "vllm:engine_step_duration_seconds",
-            "Engine step phase duration (schedule / dispatch / finalize)",
+            "Engine step phase duration, in the order of a step: schedule, "
+            "dispatch (prepare, the host building the step's inputs, nests "
+            "inside it), finalize (fetch, the host waiting for the device, "
+            "nests inside it), update (the scheduler folding the outputs "
+            "in) and output (the frontend's detokenise-and-deliver)",
             "phase",
             [0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
              0.05, 0.1, 0.25, 0.5, 1.0, 2.5])
@@ -697,7 +719,9 @@ class PrometheusRegistry:
             self.queue_time, self.accept_length,
             self.spec_acceptance_rate, self.spec_draft_len,
             self.spec_suspended, self.spec_suspensions,
-            self.bucket_compiles, self.bucket_hits, self.pipeline_stall,
+            self.bucket_compiles, self.bucket_hits, self.first_use_seconds,
+            self.steps_by_kind, self.step_tokens_by_kind,
+            self.attn_context_tokens, self.pipeline_stall,
             self.decode_batch_ratio, self.tokens_per_launch,
             self.prep_fallback_rows,
             self.sampler_kernel_launches, self.sampler_fallback_rows,
@@ -808,12 +832,18 @@ class PrometheusRegistry:
             self.decode_early_exits.inc(
                 max(0, s.decode_early_exits - self._last_decode_early_exits))
             self._last_decode_early_exits = s.decode_early_exits
-            for t in s.step_schedule_times:
-                self.step_duration.observe("schedule", t)
-            for t in s.step_dispatch_times:
-                self.step_duration.observe("dispatch", t)
-            for t in s.step_finalize_times:
-                self.step_duration.observe("finalize", t)
+            for phase in STEP_PHASES:
+                for t in getattr(s, f"step_{phase}_times"):
+                    self.step_duration.observe(phase, t)
+            # Runner-side cumulative counters: ratchet, never assign.
+            self.first_use_seconds.inc_to(s.step_first_use_s)
+            for counter, by_kind in (
+                (self.steps_by_kind, s.steps_by_kind),
+                (self.step_tokens_by_kind, s.step_tokens_by_kind),
+                (self.attn_context_tokens, s.attn_context_tokens_by_kind),
+            ):
+                for kind, n in by_kind.items():
+                    counter.inc_to(kind, float(n))
             self.batch_tokens.set(s.batch_num_tokens)
             self.batch_requests.set(s.batch_num_reqs)
             self.batch_occupancy.set(s.batch_occupancy)
@@ -857,6 +887,9 @@ class PrometheusRegistry:
                 self.disagg_push_bytes.inc_to(
                     float(fab.get("push_bytes", 0)))
         if iteration_stats is not None:
+            if iteration_stats.output_s is not None:
+                self.step_duration.observe(
+                    "output", iteration_stats.output_s)
             self.generation_tokens.inc(iteration_stats.num_generation_tokens)
             self.prompt_tokens.inc(iteration_stats.num_prompt_tokens)
             for t in iteration_stats.ttfts:

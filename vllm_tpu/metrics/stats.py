@@ -27,6 +27,10 @@ class IterationStats:
     # "length", "abort", ...) — exported as the labeled
     # vllm:request_success_total counter family.
     finished_reasons: list[str] = field(default_factory=list)
+    # Seconds OutputProcessor.process_outputs took for this batch of
+    # outputs (detokenise and deliver): the "output" phase of
+    # vllm:engine_step_duration_seconds.
+    output_s: float | None = None
 
 
 @dataclass

@@ -505,9 +505,13 @@ class LlamaForCausalLM:
             # reusing the same weight leaves.
             h = self._norm(x, lp, "input_norm") if self.pre_norm else x
 
-            q = proj(h, lp, "wq")
-            k = proj(h, lp, "wk")
-            v = proj(h, lp, "wv")
+            # The named scopes reach the profiler's device events (the
+            # op_name of every operation below them), so that a trace can
+            # tell the layer's parts apart where XLA prints only "fusion".
+            with jax.named_scope("qkv_proj"):
+                q = proj(h, lp, "wq")
+                k = proj(h, lp, "wk")
+                v = proj(h, lp, "wv")
             if bias:
                 q = q + lp["bq"]
                 k = k + lp["bk"]
@@ -555,13 +559,15 @@ class LlamaForCausalLM:
                     k_scale=kv_scale, v_scale=kv_scale,
                 )
             else:
-                kv = write_kv(kv, li, k, v, md.slot_mapping)
-                attn = attn_fn(
-                    q, kv, li, md, self.scale,
-                    sliding_window=self.sliding_window,
-                    k_scale=kv_scale, v_scale=kv_scale,
-                )
-            attn_out = proj(attn.reshape(t, H * Dh), lp, "wo")
+                with jax.named_scope("attn"):
+                    kv = write_kv(kv, li, k, v, md.slot_mapping)
+                    attn = attn_fn(
+                        q, kv, li, md, self.scale,
+                        sliding_window=self.sliding_window,
+                        k_scale=kv_scale, v_scale=kv_scale,
+                    )
+            with jax.named_scope("o_proj"):
+                attn_out = proj(attn.reshape(t, H * Dh), lp, "wo")
             if self.attention_out_bias:
                 attn_out = attn_out + lp["bo"]
             if not self.pre_norm:
@@ -574,31 +580,32 @@ class LlamaForCausalLM:
                 x = x + self.residual_multiplier * attn_out
                 h2 = self._norm(x, lp, "post_norm") if self.pre_norm else x
 
-            if self.mlp_type == "gated_silu":
-                gate = proj(h2, lp, "wgate")
-                up = proj(h2, lp, "wup")
-                ffn_out = proj(
-                    silu_and_mul(jnp.concatenate([gate, up], axis=-1)),
-                    lp, "wdown",
-                )
-            else:
-                up = proj(h2, lp, "wup")
-                if self.mlp_bias:
-                    up = up + lp["b_up"]
-                act = {
-                    "gelu": lambda v: jax.nn.gelu(
-                        v.astype(jnp.float32), approximate=False
-                    ).astype(v.dtype),
-                    "gelu_new": lambda v: jax.nn.gelu(
-                        v.astype(jnp.float32), approximate=True
-                    ).astype(v.dtype),
-                    "relu": lambda v: jax.nn.relu(v),
-                    # Nemotron/Persimmon squared ReLU.
-                    "relu2": lambda v: jnp.square(jax.nn.relu(v)),
-                }[self.mlp_act]
-                ffn_out = proj(act(up), lp, "wdown")
-                if self.mlp_bias:
-                    ffn_out = ffn_out + lp["b_down"]
+            with jax.named_scope("mlp"):
+                if self.mlp_type == "gated_silu":
+                    gate = proj(h2, lp, "wgate")
+                    up = proj(h2, lp, "wup")
+                    ffn_out = proj(
+                        silu_and_mul(jnp.concatenate([gate, up], axis=-1)),
+                        lp, "wdown",
+                    )
+                else:
+                    up = proj(h2, lp, "wup")
+                    if self.mlp_bias:
+                        up = up + lp["b_up"]
+                    act = {
+                        "gelu": lambda v: jax.nn.gelu(
+                            v.astype(jnp.float32), approximate=False
+                        ).astype(v.dtype),
+                        "gelu_new": lambda v: jax.nn.gelu(
+                            v.astype(jnp.float32), approximate=True
+                        ).astype(v.dtype),
+                        "relu": lambda v: jax.nn.relu(v),
+                        # Nemotron/Persimmon squared ReLU.
+                        "relu2": lambda v: jnp.square(jax.nn.relu(v)),
+                    }[self.mlp_act]
+                    ffn_out = proj(act(up), lp, "wdown")
+                    if self.mlp_bias:
+                        ffn_out = ffn_out + lp["b_down"]
             if not self.pre_norm:
                 ffn_out = self._norm(ffn_out, lp, "post_norm")
             x = x + self.residual_multiplier * ffn_out
@@ -731,6 +738,7 @@ class LlamaForCausalLM:
         hidden = self._norm(hidden, params, "final_norm")
         return hidden, new_kv
 
+    @jax.named_scope("lm_head")
     def compute_logits(self, params: dict, hidden: jnp.ndarray) -> jnp.ndarray:
         from vllm_tpu.layers.quant import embedding_logits
 

@@ -200,6 +200,7 @@ def sampler_kernel_eligible(
     return True, interpret
 
 
+@jax.named_scope("sampler")
 def dispatch_sample(
     logits: jnp.ndarray,
     md: SamplingMetadata,

@@ -20,6 +20,15 @@ timeline with a flow per request.
 Timestamps are ``time.perf_counter_ns`` (CLOCK_MONOTONIC on Linux), the
 same epoch in every process on a host — cross-process spans line up in
 the merged view without clock translation.
+
+Second sink: while a JAX profile runs in this process (between
+``start_profiler`` and ``stop_profiler``, the one pair that ever touches
+``jax.profiler``), every ``trace_span`` outside the per-request category
+also opens a ``jax.profiler.TraceAnnotation`` named
+``vllm_tpu.<category>.<name>``, so the engine's step phases sit on the
+profiler's clock beside the device's operations. JAX is imported by
+``start_profiler`` only: a process that never profiles (the frontend of
+the multiprocess client) never imports it here.
 """
 
 from __future__ import annotations
@@ -36,6 +45,13 @@ _lock = threading.Lock()
 _file = None
 _enabled: bool | None = None
 _wrote_any = False
+
+# The profiler sink. ``_annotation`` is ``jax.profiler.TraceAnnotation``
+# while a profile runs in this process and None otherwise: the one flag
+# ``trace_span`` checks. ``_profile`` describes the running profile.
+_annotation = None
+_profile: dict | None = None
+DEFAULT_PROFILE_DIR = "/tmp/vllm-tpu-trace"
 
 
 def _trace_file():
@@ -118,27 +134,96 @@ def _base(name: str, category: str, ph: str, **attrs) -> dict:
     }
 
 
+class ProfilerBusyError(RuntimeError):
+    """A profile is already running (or none is, on a stop)."""
+
+
+def profiler_running() -> dict | None:
+    """The running profile (``trace_dir``, ``thread``, ``started``) or None."""
+    return _profile
+
+
+def start_profiler(trace_dir: str | None = None,
+                   python_tracer_level: int = 0) -> dict:
+    """Start the JAX profiler and turn the program's own spans on.
+
+    With ``stop_profiler`` the ONLY caller of ``jax.profiler`` in the
+    serving path: ``Worker.start_profile`` is the RPC that reaches it, and
+    ``EngineCore.start_profile`` (HTTP, the multiprocess utility call,
+    perfwatch) is the only caller of that. It runs on the thread that
+    steps the engine, between two steps. The python tracer is off unless
+    asked for: its frames swamp the host plane and make the stop slow.
+    """
+    global _annotation, _profile
+    if _profile is not None:
+        raise ProfilerBusyError(
+            f"a profile is already running -> {_profile['trace_dir']}")
+    import jax
+
+    from vllm_tpu import envs
+
+    trace_dir = trace_dir or envs.VLLM_TPU_PROFILER_DIR or DEFAULT_PROFILE_DIR
+    options = jax.profiler.ProfileOptions()  # host tracer on (its default)
+    options.python_tracer_level = int(python_tracer_level)
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    _profile = {"trace_dir": trace_dir, "started": time.monotonic(),
+                "thread": threading.current_thread().name}
+    _annotation = jax.profiler.TraceAnnotation
+    return {"trace_dir": trace_dir, "thread": _profile["thread"]}
+
+
+def stop_profiler() -> dict:
+    """Stop the profile ``start_profiler`` began and turn the spans off.
+    Returns its description with ``stop_s``, the seconds the stop took
+    (the profiler writes the trace inside it), and ``traced_s``."""
+    global _annotation, _profile
+    if _profile is None:
+        raise ProfilerBusyError("no profile is running")
+    import jax
+
+    info, _profile, _annotation = _profile, None, None
+    t0 = time.monotonic()
+    jax.profiler.stop_trace()
+    now = time.monotonic()
+    return {"trace_dir": info["trace_dir"], "thread": info["thread"],
+            "stop_thread": threading.current_thread().name,
+            "traced_s": t0 - info["started"], "stop_s": now - t0}
+
+
 @contextmanager
 def trace_span(name: str, category: str = "engine", **attrs):
-    """Complete-event span; no-op (near-zero cost) when tracing is off."""
-    if not trace_enabled():
+    """Complete-event span; no-op (near-zero cost) when both sinks are
+    off. Chrome sink: ``name`` as given. Profiler sink (while a profile
+    runs): ``vllm_tpu.<category>.<name>``; per-request spans (category
+    ``request``) stay out of it, one a step is what a gap is read by."""
+    annotate = _annotation if category != "request" else None
+    chrome = trace_enabled()
+    if annotate is None and not chrome:
         yield
         return
+    attrs = {k: v for k, v in attrs.items() if v is not None}
+    span = None
+    if annotate is not None:
+        span = annotate(f"vllm_tpu.{category}.{name}", **attrs)
+        span.__enter__()
     t0 = time.perf_counter_ns() // 1000  # chrome trace wants microseconds
     try:
         yield
     finally:
-        t1 = time.perf_counter_ns() // 1000
-        _emit({
-            "name": name,
-            "cat": category,
-            "ph": "X",
-            "ts": t0,
-            "dur": t1 - t0,
-            "pid": os.getpid(),
-            "tid": threading.get_ident() % 2**31,
-            "args": {k: v for k, v in attrs.items() if v is not None},
-        })
+        if span is not None:
+            span.__exit__(None, None, None)
+        if chrome:
+            t1 = time.perf_counter_ns() // 1000
+            _emit({
+                "name": name,
+                "cat": category,
+                "ph": "X",
+                "ts": t0,
+                "dur": t1 - t0,
+                "pid": os.getpid(),
+                "tid": threading.get_ident() % 2**31,
+                "args": attrs,
+            })
 
 
 def trace_instant(name: str, category: str = "request", **attrs) -> None:

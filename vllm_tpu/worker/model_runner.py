@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import bisect
 import time
+from contextlib import nullcontext
 from typing import Any
 
 import jax
@@ -32,6 +33,7 @@ from vllm_tpu.core.sched_output import (
 from vllm_tpu.logger import init_logger
 from vllm_tpu.ops.attention import AttentionMetadata
 from vllm_tpu.resilience.failpoints import fail_point
+from vllm_tpu.tracing import trace_span
 from vllm_tpu.sample.sampler import (
     SamplingMetadata,
     dispatch_sample,
@@ -87,6 +89,10 @@ class StepHandle:
         # are garbage and the scheduler must reschedule them (reference:
         # invalid-block recovery, scheduler.py:2123).
         self.failed_loads: set[str] = set()
+        # The runner's timing of this step: host preparation of its inputs
+        # (dispatch) and the wait for its results (finalize), in seconds.
+        self.prep_s: float | None = None
+        self.wait_s: float | None = None
 
 
 def _bucket(value: int, buckets: list[int]) -> int:
@@ -322,9 +328,14 @@ class ModelRunner:
             self.draft_kv = self._alloc_draft_kv()
 
         # kv_cache (arg 1) and the draft KV (arg 2, when present) are
-        # donated back as outputs (in-place reuse).
+        # donated back as outputs (in-place reuse). The function's name is
+        # the name of the program in a profiler trace (jit_vllm_tpu_step),
+        # whatever the method behind it is called.
+        def vllm_tpu_step(*args, **flags):
+            return self._step(*args, **flags)
+
         self._step_fn = jax.jit(
-            self._step,
+            vllm_tpu_step,
             static_argnames=(
                 "t_pad",
                 "r_pad",
@@ -349,8 +360,6 @@ class ModelRunner:
             ),
             donate_argnums=(1, 2) if self.draft_model is not None else (1,),
         )
-        # Step-time breakdown (host prep / dispatch / finalize wait), enabled
-        # by VLLM_TPU_STEP_TIMING=1; read via .timing after a run.
         from vllm_tpu import envs
 
         # Per-request state slots: hybrid attention+SSM Mamba state
@@ -387,7 +396,6 @@ class ModelRunner:
         else:
             self._encode_fn = None
 
-        self._timing_enabled = envs.VLLM_TPU_STEP_TIMING
         self._nan_check = envs.VLLM_TPU_NAN_CHECK
         # Execution-layer fault containment (resilience config / env):
         # per-row isfinite guard on the step logits (rides the existing
@@ -437,8 +445,25 @@ class ModelRunner:
         # Deferred sampler accounting for the in-flight dynamic launch
         # (set by _prepare_inputs, moved onto the StepHandle by dispatch).
         self._dyn_sampler_acct = None
+        # Step-time breakdown, always on and cumulative: host preparation
+        # of the step's inputs, the call that enqueues the jitted step, and
+        # the wait for its results in finalize (the host waiting for the
+        # device). EngineCore exports each step's share as the prepare /
+        # fetch phases of vllm:engine_step_duration_seconds.
         self.timing = {"prep_s": 0.0, "dispatch_s": 0.0, "wait_s": 0.0,
                        "steps": 0}
+        # By kind of step, cumulative ("decode" = the decode-only step
+        # that runs the decode attention kernel, "mixed" = every other):
+        # steps, scheduled tokens, and context tokens attended (the sum
+        # over the step's rows of the positions each row attends to).
+        self.steps_by_kind = {"decode": 0, "mixed": 0}
+        self.step_tokens_by_kind = {"decode": 0, "mixed": 0}
+        self.attn_context_tokens_by_kind = {"decode": 0, "mixed": 0}
+        # Seconds spent in first uses of a step program (tracing, lowering
+        # and compiling or loading it), beside bucket_compiles, their
+        # count; the key of the first use now due, set by _prepare_inputs.
+        self.first_use_s = 0.0
+        self._first_use_key: tuple | None = None
 
     # ------------------------------------------------------------------
     # Jitted step
@@ -1504,6 +1529,7 @@ class ModelRunner:
         else:
             self._seen_buckets.add(bkey)
             self.bucket_compiles += 1
+            self._first_use_key = bkey
         # Perfwatch batch-shape retention: the quiet-window A/B replays
         # a synthetic batch mirroring the last real traffic shape.
         # ctx proxy = the widest request's block footprint (what the
@@ -1983,6 +2009,11 @@ class ModelRunner:
         self.step_launches += 1
         if flags["decode_only"]:
             self.decode_only_launches += 1
+        kind = "decode" if flags["decode_only"] else "mixed"
+        self.steps_by_kind[kind] += 1
+        self.step_tokens_by_kind[kind] += t_live
+        self.attn_context_tokens_by_kind[kind] += int(
+            seq_lens[:r_live].sum())
         # launch_sampled_tokens counts REALIZED emissions — finalize
         # accumulates the per-row token runs it actually folds, which is
         # exact for every path (fixed K, dynamic, spec, prefill).
@@ -2274,24 +2305,27 @@ class ModelRunner:
         """Upload inputs and enqueue the jitted step; returns immediately
         with device-array handles (no host sync). The async engine pipeline
         dispatches step N+1 before finalizing step N."""
-        t0 = time.perf_counter() if self._timing_enabled else 0.0
-        self._update_states(so)
-        if so.total_num_scheduled_tokens == 0:
-            return StepHandle(empty=True)
-        failed_loads: set[str] = set()
-        if so.kv_connector_load:
-            failed_loads = self._kv_connector_loads(so.kv_connector_load)
-        if self.is_mm or self.is_encdec:
-            self._run_encoders(so)
-        (arrays, req_order, do_sample, flags,
-         prompt_rows, mm_arrays) = self._prepare_inputs(so)
-        mask_table = None
-        if flags["needs_grammar"]:
-            self._sync_grammar_table()
-            mask_table = self._mask_table
-        if self._timing_enabled:
-            t1 = time.perf_counter()
-            self.timing["prep_s"] += t1 - t0
+        t0 = time.perf_counter()
+        with trace_span("prepare", "runner",
+                        tokens=so.total_num_scheduled_tokens,
+                        reqs=so.num_reqs):
+            self._update_states(so)
+            if so.total_num_scheduled_tokens == 0:
+                return StepHandle(empty=True)
+            failed_loads: set[str] = set()
+            if so.kv_connector_load:
+                failed_loads = self._kv_connector_loads(so.kv_connector_load)
+            if self.is_mm or self.is_encdec:
+                self._run_encoders(so)
+            (arrays, req_order, do_sample, flags,
+             prompt_rows, mm_arrays) = self._prepare_inputs(so)
+            mask_table = None
+            if flags["needs_grammar"]:
+                self._sync_grammar_table()
+                mask_table = self._mask_table
+        t1 = time.perf_counter()
+        prep_s = t1 - t0
+        self.timing["prep_s"] += prep_s
         prev = self._last_sampled if self._last_sampled is not None else self._zero_sampled
         mm_kwargs = {}
         if mm_arrays is not None:
@@ -2313,17 +2347,30 @@ class ModelRunner:
         forced_nan = fail_point(
             "model_runner.step", lambda: f"reqs={req_order}"
         ) == "nan"
-        # The TraceAnnotation is a step marker for perfwatch profiling
-        # windows (an unstarted profiler makes it a no-op TraceMe).
-        with jax.profiler.TraceAnnotation("vllm_tpu.step_dispatch"):
+        step = dict(tokens=so.total_num_scheduled_tokens, reqs=len(req_order),
+                    kind="decode" if flags["decode_only"] else "mixed")
+        first_use, self._first_use_key = self._first_use_key, None
+        # A first use of a step program is traced, lowered and compiled
+        # (or loaded from the persistent cache) inside this call, with
+        # every running request waiting.
+        with (trace_span("first_use", "runner", key=str(first_use), **step)
+              if first_use is not None else nullcontext()), \
+                trace_span("dispatch", "runner", **step):
             (self.kv_cache, self.draft_kv, sampled, lp, drafts, pooled,
              nan_count, prompt_lp, moe_counts, row_bad) = self._step_fn(
                 self.params, self.kv_cache, self.draft_kv, *arrays, prev,
                 mask_table, **mm_kwargs, **flags,
             )
-        if self._timing_enabled:
-            self.timing["dispatch_s"] += time.perf_counter() - t1
-            self.timing["steps"] += 1
+        dispatch_s = time.perf_counter() - t1
+        self.timing["dispatch_s"] += dispatch_s
+        self.timing["steps"] += 1
+        if first_use is not None:
+            self.first_use_s += dispatch_s
+            logger.info(
+                "first use of step program (t_pad, r_pad, b_pad)=%s (%s "
+                "step): %.2f s; %d request(s) in the step, %d held by the "
+                "runner (running or in prefill)", first_use, step["kind"],
+                dispatch_s, len(req_order), self.input_batch.num_reqs)
         is_spec = flags["num_spec"] > 0
         is_dynamic = bool(flags.get("dynamic_decode"))
         if not is_spec:
@@ -2372,6 +2419,7 @@ class ModelRunner:
             row_states=[self.input_batch.req_states[r] for r in req_order],
             spec=is_spec, dynamic=is_dynamic,
         )
+        handle.prep_s = prep_s
         handle.dyn_sampler_acct = self._dyn_sampler_acct
         self._dyn_sampler_acct = None
         handle.spec_suspended = so.spec_suspended
@@ -2394,32 +2442,34 @@ class ModelRunner:
         host state (the only host<->device sync of the step)."""
         if handle.empty:
             return ModelRunnerOutput()
-        t0 = time.perf_counter() if self._timing_enabled else 0.0
+        t0 = time.perf_counter()
         req_order, do_sample = handle.req_order, handle.do_sample
-        if handle.spec or handle.dynamic:
-            out_tokens = np.asarray(jax.device_get(handle.sampled[0]))
-            num_out = np.asarray(jax.device_get(handle.sampled[1]))
-        else:
-            sampled_np = np.asarray(jax.device_get(handle.sampled))
-        lp_np = None
-        if handle.lp is not None:
-            lp_np = [np.asarray(jax.device_get(x)) for x in handle.lp]
-        drafts_np = (
-            np.asarray(jax.device_get(handle.drafts))
-            if handle.drafts is not None
-            else None
-        )
-        if handle.prompt_lp is not None and handle.prompt_rows:
-            pk_vals, pk_ids, tok_lp, tok_rank = (
-                np.asarray(jax.device_get(x)) for x in handle.prompt_lp
+        # The host waits for the device here and nowhere else in a step.
+        with trace_span("fetch", "runner", reqs=len(req_order)):
+            if handle.spec or handle.dynamic:
+                out_tokens = np.asarray(jax.device_get(handle.sampled[0]))
+                num_out = np.asarray(jax.device_get(handle.sampled[1]))
+            else:
+                sampled_np = np.asarray(jax.device_get(handle.sampled))
+            lp_np = None
+            if handle.lp is not None:
+                lp_np = [np.asarray(jax.device_get(x)) for x in handle.lp]
+            drafts_np = (
+                np.asarray(jax.device_get(handle.drafts))
+                if handle.drafts is not None
+                else None
             )
-        pooled_np = (
-            tuple(np.asarray(jax.device_get(x)) for x in handle.pooled)
-            if handle.pooled is not None
-            else None
-        )
-        if self._timing_enabled:
-            self.timing["wait_s"] += time.perf_counter() - t0
+            if handle.prompt_lp is not None and handle.prompt_rows:
+                pk_vals, pk_ids, tok_lp, tok_rank = (
+                    np.asarray(jax.device_get(x)) for x in handle.prompt_lp
+                )
+            pooled_np = (
+                tuple(np.asarray(jax.device_get(x)) for x in handle.pooled)
+                if handle.pooled is not None
+                else None
+            )
+        handle.wait_s = time.perf_counter() - t0
+        self.timing["wait_s"] += handle.wait_s
         if handle.nan_count is not None:
             n_nan = int(jax.device_get(handle.nan_count))
             if n_nan:

@@ -946,24 +946,26 @@ class Worker:
         assert self.runner is not None and self.runner.lora_manager is not None
         return self.runner.lora_manager.list_loras()
 
-    def start_profile(self, trace_dir: str | None = None) -> None:
+    def start_profile(self, trace_dir: str | None = None,
+                      python_tracer_level: int = 0) -> dict:
         """JAX profiler (xplane/TensorBoard) start — reference:
-        ``gpu_worker.py profile :866`` torch-profiler RPC."""
-        import jax
+        ``gpu_worker.py profile :866`` torch-profiler RPC. The profiler
+        itself lives in ``vllm_tpu/tracing.py``, which also turns the
+        program's own spans on for as long as the profile runs."""
+        from vllm_tpu.tracing import start_profiler
 
-        from vllm_tpu import envs
+        info = start_profiler(trace_dir, python_tracer_level)
+        logger.info("profiler started -> %s", info["trace_dir"])
+        return info
 
-        trace_dir = (
-            trace_dir or envs.VLLM_TPU_PROFILER_DIR or "/tmp/vllm-tpu-trace"
-        )
-        jax.profiler.start_trace(trace_dir)
-        logger.info("profiler started -> %s", trace_dir)
+    def stop_profile(self) -> dict:
+        from vllm_tpu.tracing import stop_profiler
 
-    def stop_profile(self) -> None:
-        import jax
-
-        jax.profiler.stop_trace()
-        logger.info("profiler stopped")
+        info = stop_profiler()
+        logger.info("profiler stopped after %.2f s of trace; the stop took "
+                    "%.2f s -> %s", info["traced_s"], info["stop_s"],
+                    info["trace_dir"])
+        return info
 
     def set_kernel_flags(self, flags: dict) -> dict:
         """Flip the runner's runtime kernel-dispatch toggles (perfwatch
