@@ -19,7 +19,9 @@ and in the test's own process.
 from __future__ import annotations
 
 import functools
+import math
 import os
+import re
 import types
 
 import jax
@@ -211,3 +213,114 @@ def test_attention_dispatch_compiles_under_tp4(
     assert "tpu_custom_call" in text
     kernel = "rpa_decode_kernel" if decode_only else "rpa_kernel"
     assert kernel in text
+
+
+# Mistral-7B-v0.3 at the depth the benchmark's cell runs it (16 of 32
+# layers), with the cell's KV cache.
+MISTRAL = dict(
+    num_hidden_layers=16, hidden_size=4096, num_attention_heads=32,
+    num_key_value_heads=8, intermediate_size=14336, vocab_size=32768,
+    rms_norm_eps=1e-5, tie_word_embeddings=False,
+    max_position_embeddings=12288, rope_theta=1e6, rope_scaling=None,
+)
+MISTRAL_KV_BLOCKS, MISTRAL_PAGES = 6698, 64
+
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+              "u32": 4, "f32": 4}
+
+
+def _while_body_instructions(hlo: str):
+    """(name, opcode, bytes of the result) of every instruction that sits
+    directly in the body of a ``while`` of the optimised program: the layer
+    scan. A fused computation's inside is another computation and is not
+    listed: what it holds never reaches HBM."""
+    bodies = set(re.findall(r"\bbody=%([\w.\-]+)", hlo))
+    assert bodies, "the step program holds no while loop: is the scan gone?"
+    current = None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            current = head.group(1)
+            continue
+        if current not in bodies:
+            continue
+        m = re.match(
+            r"\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", line
+        )
+        if m:
+            name, dtype, dims, opcode = m.groups()
+            n = math.prod(int(d) for d in dims.split(",") if d)
+            yield name, opcode, n * _HLO_BYTES.get(dtype, 4)
+
+
+@pytest.mark.parametrize("tree", ["bf16", "int8"])
+@pytest.mark.parametrize("t,r,decode_only", [(128, 128, True), (512, 128, False)],
+                         ids=["decode_T128_R128", "mixed_T512_R128"])
+def test_layer_scan_reads_each_weight_once_in_place(
+    one_chip, tuned_table_sees_chip, monkeypatch, t, r, decode_only, tree
+):
+    """The step program of the benchmark's cell, compiled for the v5e: no
+    layer may slice or relay a WEIGHT out of its ``[L, ...]`` stack into a
+    temporary. PR 27 found three such pairs a layer (``wq``, ``wk``, ``wv``:
+    a ``dynamic-slice`` fusion and a transposing ``copy`` each, 50 MB read
+    and written twice, 9.9 % of the chip's step), because XLA folded the
+    head split into the three projections.
+
+    The limit is the smallest of those weights, one layer of ``wk`` in
+    bf16 (8 MiB), and not lower: the relayout of q to the attention
+    kernel's [T, heads, head_dim] tiling is an activation of 1 MiB at 128
+    tokens and 4 MiB at 512. The int8 tree (native int8 matmuls, as on the
+    chip) never had the pairs; it is here so that it stays so, held to the
+    same limit: a dequantised layer of any weight, or an int8 copy of any
+    but ``wk`` and ``wv``.
+    """
+    from vllm_tpu.layers import quant
+    from vllm_tpu.models.llama import LlamaForCausalLM
+    from vllm_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(quant, "_use_w8a8", lambda: True)
+    model = LlamaForCausalLM(types.SimpleNamespace(**MISTRAL), dtype=jnp.bfloat16)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(model.init_dummy_params, jax.random.PRNGKey(0)),
+    )
+    layers = params["layers"]
+    if tree == "int8":
+        for key in model.QUANT_KEYS:
+            w = layers[key].shape
+            layers[key] = quant.QuantizedLinear(
+                q=s(w, jnp.int8), scale=s(w[:-2] + w[-1:], jnp.float32)
+            )
+    md = attention.AttentionMetadata(
+        positions=s((t,), jnp.int32), slot_mapping=s((t,), jnp.int32),
+        block_tables=s((r, MISTRAL_PAGES), jnp.int32),
+        seq_lens=s((r,), jnp.int32), query_start_loc=s((r + 1,), jnp.int32),
+        token_req_idx=s((t,), jnp.int32), logits_indices=s((r,), jnp.int32),
+        num_seqs=s((1,), jnp.int32), decode_only=decode_only,
+    )
+    kv = s(attention.kv_cache_shape(model.num_layers, MISTRAL_KV_BLOCKS, PAGE,
+                                    model.num_kv_heads, model.head_dim),
+           jnp.bfloat16)
+    compiled = jax.jit(model.apply, donate_argnums=(1,)).lower(
+        params, kv, s((t,), jnp.int32), md
+    ).compile()
+    text = compiled.as_text()
+
+    assert ("rpa_decode_kernel" if decode_only else "rpa_kernel") in text
+    limit = 2 * model.hidden_size * model.num_kv_heads * model.head_dim
+    moved = [
+        (name, opcode, nbytes)
+        for name, opcode, nbytes in _while_body_instructions(text)
+        if nbytes >= limit
+        and (opcode in ("copy", "transpose", "dynamic-slice")
+             or "dynamic-slice" in name)
+    ]
+    assert not moved, (
+        f"the layer scan moves weight-sized temporaries (>= {limit} bytes): "
+        f"{moved}"
+    )

@@ -126,10 +126,12 @@ class LlamaForCausalLM:
     # EAGLE-3: layer indices whose OUTPUT hidden states feed the draft
     # (set by the worker; apply() then returns (hidden, kv, aux_concat)).
     aux_hidden_layers = None
-    # lax.scan over the stacked layer weights vs an unrolled Python loop.
-    # Scan compiles fast and is the default; its xs layout assignment can
-    # materialize a run-time copy of the WHOLE weight stack, so large
-    # quantized models flip this off (see apply()).
+    # lax.scan over the stacked layer weights vs an unrolled Python loop
+    # (VLLM_TPU_UNROLL_LAYERS). Scan compiles fast and is the default. What
+    # scan was measured to cost (v5e, Mistral-7B widths, PR 27): the bf16
+    # program sliced AND transposed the three attention projections out of
+    # their stacks a layer, never the whole stack; the head-split barrier
+    # in _make_layer_fn ended that, and an int8 tree never did it.
     scan_layers = True
 
     def __init__(self, hf_config: Any, dtype=jnp.bfloat16,
@@ -419,11 +421,11 @@ class LlamaForCausalLM:
                  jnp.arange(self.num_layers, dtype=jnp.int32)),
             )
         else:
-            # Unrolled: scan's xs layout assignment materializes a COPY of
-            # the whole weight stack at run time — a transient the size of
-            # the model, which OOMs large quantized models that otherwise
-            # fit. The unrolled loop slices one layer at a time (bigger
-            # HLO, slower compile; the persistent cache amortizes it).
+            # Unrolled: one layer sliced at a time in a program L times
+            # the size (slower first use; the persistent cache amortizes
+            # the compile only). Under scan no weight-sized temporary is
+            # left to avoid (tests/test_tpu_aot_compile.py holds that for
+            # the bf16 and the int8 tree).
             carry = (x, kv_cache) if aux_idx is None else (x, kv_cache, aux0)
             for i in range(self.num_layers):
                 lp = jax.tree.map(lambda a: a[i], params["layers"])
@@ -525,6 +527,14 @@ class LlamaForCausalLM:
                 # pre-head-split (vs Qwen3's per-head norm below).
                 q = rms_norm(q, lp["q_norm"], self.rms_eps)
                 k = rms_norm(k, lp["k_norm"], self.rms_eps)
+            # The projections end here, as [T, heads * head_dim] rows. Left
+            # free, XLA folds the head split below into the three dots, lays
+            # their outputs out token-minor, and then slices AND transposes
+            # wq/wk/wv out of the [L, ...] stacks into temporaries a layer
+            # (9.9 % of the v5e's step at Mistral-7B widths, PERF.md PR 27);
+            # behind the barrier each dot reads its stack in place, as
+            # wo and the MLP weights are read.
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
             q = q.reshape(t, H, Dh)
             k = k.reshape(t, KH, Dh)
             v = v.reshape(t, KH, Dh)
