@@ -340,3 +340,62 @@ def test_resilience_counters_never_decrease():
     text = reg.render()
     assert 'vllm:engine_restarts_total{engine_id="0"} 2.0' in text
     assert 'vllm:engine_restarts_total{engine_id="1"} 4.0' in text
+
+
+def test_diffusion_and_moe_counters_are_cumulative_and_never_decrease():
+    """The five counters generation by diffusion over blocks and the
+    mixture of experts add (the fifth is a kind of an existing family):
+    counted by the runner, ratcheted by the registry."""
+    reg = PrometheusRegistry()
+    reg.record(SchedulerStats(
+        diffusion_passes_by_kind={"denoise": 20, "store": 10},
+        diffusion_committed_tokens=40, moe_assignments=4480,
+        moe_experts_hit=896, attn_context_tokens_by_kind={"block": 16000}))
+    reg.record(SchedulerStats())  # a respawned engine lowers nothing
+    reg.record(SchedulerStats(diffusion_passes_by_kind={"denoise": 22},
+                              moe_experts_hit=900))
+    text = reg.render()
+    for sample in ('vllm:diffusion_passes_total{kind="denoise"} 22.0',
+                   'vllm:diffusion_passes_total{kind="store"} 10.0',
+                   "vllm:diffusion_committed_tokens_total 40.0",
+                   "vllm:moe_assignments_total 4480.0",
+                   "vllm:moe_experts_hit_total 900.0",
+                   'vllm:attn_context_tokens_total{kind="block"} 16000.0'):
+        assert sample in text, sample
+    for family in ("vllm:diffusion_passes_total",
+                   "vllm:diffusion_committed_tokens_total",
+                   "vllm:moe_assignments_total", "vllm:moe_experts_hit_total"):
+        assert f"# TYPE {family} counter" in text
+
+
+def test_runner_counts_passes_commits_and_experts_on_a_hand_made_batch(
+        tmp_path):
+    """One prompt of 9 tokens, 7 more under the static rule at two steps,
+    one step at a time: the prefill of 8, then the first block (1 prompt
+    token, 3 masks: passes of 2 and 1, then its store), then a block of
+    four masks (2 + 2), after whose last token the request ends."""
+    from tests.benchmark.tiny_sdar_moe import TINY_SDAR, model_dir
+    from vllm_tpu import LLM, SamplingParams
+
+    llm = LLM(model=model_dir(tmp_path), dtype="float32", load_format="dummy",
+              max_model_len=128, block_size=16, max_num_batched_tokens=64,
+              max_num_seqs=4, num_gpu_blocks_override=64,
+              async_scheduling=False)
+    out = llm.generate(
+        [{"prompt_token_ids": list(range(1, 10))}],
+        SamplingParams(temperature=0.0, max_tokens=7, ignore_eos=True,
+                       remasking="low_confidence_static",
+                       denoising_steps=2))[0].outputs[0]
+    assert out.commit_passes[-1] == 4 and len(out.token_ids) == 7
+    runner = llm.llm_engine.engine_core.engine_core.executor.worker.runner
+    assert runner.diffusion_passes_by_kind == {"denoise": 4, "store": 1}
+    assert runner.diffusion_committed_tokens == 7
+    # The context a block row reads, once a row: 12 for the three passes of
+    # the first block, 16 for the two of the second.
+    assert runner.attn_context_tokens_by_kind["block"] == 3 * 12 + 2 * 16
+    layers, top = TINY_SDAR["num_hidden_layers"], TINY_SDAR["num_experts_per_tok"]
+    tokens = 8 + 5 * 4
+    assert runner.moe_assignments == tokens * top * layers
+    # Each step hits at least its 2 experts a layer and at most all 8.
+    assert 6 * 2 * layers <= runner.moe_experts_hit <= 6 * 8 * layers
+    llm.shutdown()

@@ -1,0 +1,534 @@
+"""Generation by diffusion over blocks (``models/generation.py``), held on
+the CPU at a tiny size against the benchmark's plain reference
+(``benchmark/reference/sdar_moe.py``): the model's forward pass, the engine
+end to end (offline and over the OpenAI server), the ragged kernel's mask
+over blocks, the sampler's probability of the drawn token, and what the
+mechanism refuses."""
+
+from __future__ import annotations
+
+import asyncio
+import itertools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.harness.manifest import BENCH_DIR, load_module
+from tests.benchmark.tiny_sdar_moe import TINY_SDAR, model_dir
+from vllm_tpu.ops.attention import AttentionMetadata, kv_cache_shape, packed_kv_layout
+
+REF = load_module(BENCH_DIR / "reference" / "sdar_moe.py")
+SEED = 3
+B = TINY_SDAR["block_length"]
+# float32 program against float32 reference on the CPU: the order of
+# float32 sums only (the program pages and batches), on logits of size 1-3.
+TOL_F32 = 5e-4
+# bfloat16 program (bf16 activations and matmuls) against the float32
+# reference over the same bf16-held weights, on logits of size 1-3: the
+# median, and a far looser bound on the worst number, because where two
+# experts' router weights are nearly equal bf16 picks the other one and
+# that row moves by the difference of two experts' outputs.
+TOL_BF16 = 0.05
+TOL_BF16_WORST = 1.5
+
+
+def close(got, want, dtype):
+    diff = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
+    if dtype == jnp.float32:
+        return float(diff.max()) < TOL_F32
+    return float(np.median(diff)) < TOL_BF16 and float(diff.max()) < TOL_BF16_WORST
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return REF.seeded_weights(TINY_SDAR, SEED, jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# (a) SDARMoeForCausalLM.apply against block_forward
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+def model_and_params(request):
+    from transformers import PretrainedConfig
+
+    from vllm_tpu.models.registry import get_model_class
+
+    dtype = jnp.dtype(request.param)
+    cfg = PretrainedConfig.from_dict(TINY_SDAR)
+    model = get_model_class(cfg)(cfg, dtype=dtype)
+    assert model.generation == "block_diffusion"
+    return model, model.init_dummy_params(jax.random.PRNGKey(SEED)), dtype
+
+
+def run_apply(model, params, dtype, ids):
+    """One sequence through ``apply`` with the mask over blocks, on the XLA
+    path: logits [T, V] and the paged cache it wrote."""
+    t, page = len(ids), 16
+    pages = -(-t // page)
+    pos = np.arange(t, dtype=np.int32)
+    table = np.arange(1, pages + 1, dtype=np.int32)
+    md = AttentionMetadata(
+        positions=jnp.asarray(pos),
+        slot_mapping=jnp.asarray(table[pos // page] * page + pos % page),
+        block_tables=jnp.asarray(table[None, :]),
+        seq_lens=jnp.asarray([t], jnp.int32),
+        query_start_loc=jnp.asarray([0, t], jnp.int32),
+        token_req_idx=jnp.zeros(t, jnp.int32),
+        logits_indices=jnp.asarray([t - 1], jnp.int32),
+        num_seqs=jnp.asarray([1], jnp.int32), block_len=B,
+    )
+    kv = jnp.zeros(kv_cache_shape(model.num_layers, pages + 1, page,
+                                  model.num_kv_heads, model.head_dim), dtype)
+    hidden, kv = model.apply(params, kv, jnp.asarray(ids, jnp.int32), md)
+    return model.compute_logits(params, hidden), kv
+
+
+# Every shape of the open block (the 16 masks of 4 positions) behind two
+# clean blocks.
+MASKS = list(itertools.product([True, False], repeat=B))
+
+
+@pytest.mark.parametrize("mask", MASKS, ids=lambda m: "".join("c" if c else "m" for c in m))
+def test_apply_matches_block_forward_for_every_shape_of_the_block(model_and_params, mask):
+    model, params, dtype = model_and_params
+    rng = np.random.default_rng(17)
+    tokens = rng.integers(0, 240, 3 * B).tolist()
+    committed = [True] * (2 * B) + list(mask)
+    weights = REF.seeded_weights(TINY_SDAR, SEED, dtype)
+    want, want_k, want_v = REF.block_forward(TINY_SDAR, weights, tokens, committed, return_kv=True)
+    ids = np.where(committed, tokens, TINY_SDAR["mask_token_id"])
+    got, kv = run_apply(model, params, dtype, ids)
+    assert close(got, want, dtype)
+    # The K/V written: page 1 of every layer holds the 12 positions.
+    page = np.asarray(kv[:, 1, : 3 * B], np.float32)
+    d = model.head_dim
+    if packed_kv_layout(d):  # a head of 16: K beside V on the lane axis
+        got_k, got_v = page[..., :d], page[..., d:]
+    else:
+        got_k, got_v = page[:, :, 0::2], page[:, :, 1::2]
+    assert close(got_k, want_k, dtype) and close(got_v, want_v, dtype)
+
+
+# ---------------------------------------------------------------------------
+# (b) the engine end to end against generate()
+# ---------------------------------------------------------------------------
+
+
+def make_llm(tmp_path, **kw):
+    from vllm_tpu import LLM
+
+    args = dict(model=model_dir(tmp_path), dtype="float32", load_format="dummy", seed=SEED,
+                max_model_len=128, block_size=16, num_gpu_blocks_override=64,
+                max_num_seqs=8, max_num_batched_tokens=64)
+    return LLM(**{**args, **kw})
+
+
+@pytest.fixture(scope="module", params=[True, False], ids=["lag_pipeline", "one_step_at_a_time"])
+def llm(request, tmp_path_factory):
+    # 22 tokens a step: a prompt's chunks would end inside a block (22, 44)
+    # were they not cut back to a multiple of 4.
+    engine = make_llm(tmp_path_factory.mktemp("sdar"), async_scheduling=request.param,
+                      max_num_batched_tokens=22)
+    yield engine
+    engine.shutdown()
+
+
+def params_for(rule="sequential", steps=4, **kw):
+    from vllm_tpu import SamplingParams
+
+    return SamplingParams(temperature=0.0, ignore_eos=True, remasking=rule,
+                          denoising_steps=steps, confidence_threshold=0.02,
+                          **{"max_tokens": 10, **kw})
+
+
+def prompt_of(n, seed=0):
+    return np.random.default_rng(seed + n).integers(0, 240, n).tolist()
+
+
+def check_against_generate(weights, prompt, out, rule, steps, max_tokens=10):
+    want = REF.generate(TINY_SDAR, weights, prompt, max_tokens, rule, steps, 0.02)
+    assert list(out.token_ids) == want["tokens"]
+    assert out.commit_passes == want["commit_passes"]
+    if out.logprobs:
+        for tok, top, row in zip(out.token_ids, out.logprobs, want["logits"]):
+            logp = np.asarray(jax.nn.log_softmax(jnp.asarray(row)))
+            for t, lp in top.items():
+                assert abs(float(logp[t]) - lp.logprob) < TOL_F32
+            assert tok in top
+
+
+RULES = [("sequential", 4), ("sequential", 2), ("low_confidence_static", 2),
+         ("low_confidence_static", 1), ("low_confidence_dynamic", 4)]
+
+
+@pytest.mark.parametrize("rule,steps", RULES)
+def test_engine_generates_as_the_reference_does(llm, weights, rule, steps):
+    """Prompts with P mod 4 in 0..3, shorter than a block, and longer than
+    the 22 tokens a step holds (chunked prefill)."""
+    prompts = [prompt_of(n) for n in (8, 9, 10, 11, 1, 3, 50)]
+    outs = llm.generate([{"prompt_token_ids": p} for p in prompts],
+                        params_for(rule, steps, logprobs=3))
+    for p, o in zip(prompts, outs):
+        check_against_generate(weights, p, o.outputs[0], rule, steps)
+
+
+@pytest.mark.parametrize("max_tokens", [1, 2, 5, 7])
+def test_max_tokens_cuts_inside_a_block(llm, weights, max_tokens):
+    p = prompt_of(9)
+    out = llm.generate([{"prompt_token_ids": p}],
+                       params_for("low_confidence_static", 2, max_tokens=max_tokens))[0].outputs[0]
+    assert out.finish_reason == "length" and len(out.token_ids) == max_tokens
+    check_against_generate(weights, p, out, "low_confidence_static", 2, max_tokens)
+
+
+def test_a_stop_token_inside_a_block_ends_the_answer_there(llm, weights):
+    p = prompt_of(10)
+    want = REF.generate(TINY_SDAR, weights, p, 10, "sequential", 2, 0.02)["tokens"]
+    cut = next(i for i in range(1, 10) if (len(p) + i) % B and want[i] not in want[:i])
+    out = llm.generate([{"prompt_token_ids": p}],
+                       params_for("sequential", 2, stop_token_ids=[want[cut]]))[0].outputs[0]
+    assert list(out.token_ids) == want[: cut + 1]
+    assert out.finish_reason == "stop" and out.stop_reason == want[cut]
+
+
+def test_requests_under_different_rules_share_a_step(llm, weights):
+    prompts = [prompt_of(n, 7) for n in (6, 13, 21, 9, 30)]
+    ps = [params_for(r, s) for r, s in RULES]
+    runner = llm.llm_engine.engine_core.engine_core.executor.worker.runner
+    programs = runner.bucket_compiles
+    llm.generate([{"prompt_token_ids": prompt_of(5, 7)}], ps[0])
+    outs = llm.generate([{"prompt_token_ids": p} for p in prompts], ps)
+    for p, o, (rule, steps) in zip(prompts, outs, RULES):
+        check_against_generate(weights, p, o.outputs[0], rule, steps)
+    # Rule, count and threshold are data of one step program: five rules
+    # in a batch met no program that one rule alone had not.
+    assert runner.bucket_compiles - programs <= 3
+    assert runner.diffusion_passes_by_kind["store"] > 0
+    assert runner.diffusion_committed_tokens > 0 and runner.moe_experts_hit > 0
+
+
+def test_the_second_ask_of_a_prompt_hits_the_prefix_cache(llm, weights):
+    p = prompt_of(41, 3)
+    first = llm.generate([{"prompt_token_ids": p}], params_for("low_confidence_static", 2))[0]
+    again = llm.generate([{"prompt_token_ids": p}], params_for("low_confidence_static", 2))[0]
+    assert list(first.outputs[0].token_ids) == list(again.outputs[0].token_ids)
+    # Two pages of 16 are stored and clean; the third holds the open block.
+    assert again.num_cached_tokens == 32
+    check_against_generate(weights, p, again.outputs[0], "low_confidence_static", 2)
+
+
+@pytest.mark.parametrize("async_scheduling", [True, False])
+def test_preemption_in_the_middle_of_a_block_resumes_with_the_same_tokens(
+        tmp_path, weights, async_scheduling):
+    """Nine pages for four requests that each grow to three: the scheduler
+    preempts, and a resumed request prefills its tokens so far and reopens
+    the block it held."""
+    llm = make_llm(tmp_path, async_scheduling=async_scheduling, num_gpu_blocks_override=9,
+                   enable_prefix_caching=False)
+    prompts = [prompt_of(n, 5) for n in (14, 15, 17, 13)]
+    outs = llm.generate([{"prompt_token_ids": p} for p in prompts],
+                        params_for("low_confidence_static", 2, max_tokens=30))
+    sched = llm.llm_engine.engine_core.engine_core.scheduler
+    assert sched.make_stats().num_preempted_reqs > 0
+    llm.shutdown()
+    for p, o in zip(prompts, outs):
+        want = REF.generate(TINY_SDAR, weights, p, 30, "low_confidence_static", 2, 0.02)
+        assert list(o.outputs[0].token_ids) == want["tokens"]
+
+
+@pytest.mark.parametrize("rule,steps", [("low_confidence_static", 2), ("low_confidence_dynamic", 4)])
+def test_the_commit_order_the_engine_reports_replays_through_block_forward(llm, weights, rule, steps):
+    """For a confidence rule the ORDER of commits is part of the answer:
+    every pass the engine reports is rebuilt from the tokens alone (what
+    was committed before it, clean; the rest, masks) and must commit just
+    the positions the engine says, with the same tokens."""
+    p = prompt_of(11, 9)  # 11 + 13: the answer ends with its block
+    out = llm.generate([{"prompt_token_ids": p}], params_for(rule, steps, max_tokens=13))[0].outputs[0]
+    seq = p + list(out.token_ids)
+    pass_of = [-1] * len(p) + list(out.commit_passes)
+    n = B // steps
+    for k in sorted(set(out.commit_passes)):
+        at = [i for i, q in enumerate(pass_of) if q == k]
+        start = at[0] // B * B
+        end = min(start + B, len(seq))
+        known = [q < k for q in pass_of[:end]] + [False] * (start + B - end)
+        logits = np.asarray(REF.block_forward(
+            TINY_SDAR, weights, seq[:end] + [0] * (start + B - end), known))[start:]
+        prob = np.asarray(jax.nn.softmax(jnp.asarray(logits), axis=-1))
+        drawn = logits.argmax(-1)
+        pick = REF.commit(rule, n, 0.02, known[start:], prob[np.arange(B), drawn])
+        assert [start + int(j) for j in np.flatnonzero(pick)] == at
+        assert [int(drawn[i - start]) for i in at] == [seq[i] for i in at]
+
+
+def test_the_openai_server_takes_the_three_request_parameters(tmp_path, weights):
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from vllm_tpu.engine.arg_utils import AsyncEngineArgs
+    from vllm_tpu.engine.async_llm import AsyncLLM
+    from vllm_tpu.entrypoints.openai.api_server import build_app
+
+    engine = AsyncLLM.from_engine_args(AsyncEngineArgs(
+        model=model_dir(tmp_path), dtype="float32", load_format="dummy", seed=SEED,
+        max_model_len=128, block_size=16, num_gpu_blocks_override=64, max_num_seqs=8,
+        max_num_batched_tokens=64))
+    p = prompt_of(10, 1)
+
+    async def go():
+        async with TestClient(TestServer(build_app(engine, "tiny"))) as client:
+            body = {"model": "tiny", "prompt": p, "max_tokens": 9, "temperature": 0.0,
+                    "ignore_eos": True, "logprobs": 2, "remasking": "low_confidence_static",
+                    "denoising_steps": 2}
+            ok = await (await client.post("/v1/completions", json=body)).json()
+            default = await (await client.post("/v1/completions", json={
+                k: v for k, v in body.items() if k not in ("remasking", "denoising_steps")})).json()
+            bad = await client.post("/v1/completions", json={**body, "remasking": "random"})
+            return ok, default, bad.status
+
+    try:
+        ok, default, bad = asyncio.run(go())
+    finally:
+        engine.shutdown()
+    want = REF.generate(TINY_SDAR, weights, p, 9, "low_confidence_static", 2)
+    assert [int(t) for t in ok["choices"][0]["logprobs"]["tokens"]] == want["tokens"]
+    # The model's own defaults (config.json): sequential, 4 steps.
+    want = REF.generate(TINY_SDAR, weights, p, 9, "sequential", 4)
+    assert [int(t) for t in default["choices"][0]["logprobs"]["tokens"]] == want["tokens"]
+    assert bad == 400
+
+
+def test_a_pinned_ladder_leaves_one_step_program_a_page_bucket(tmp_path, weights):
+    """``token_buckets`` / ``request_buckets`` as the server's flags give
+    them (``sdar30b.chat`` pins its step to one shape): a lone request, a
+    chunked prompt and a batch of rows all run (64, 8, pages)."""
+    engine = make_llm(tmp_path, token_buckets="64", request_buckets="8")
+    try:
+        runner = engine.llm_engine.engine_core.engine_core.executor.worker.runner
+        assert runner.token_buckets == [64] and runner.request_buckets == [8]
+        prompts = [prompt_of(n, 11) for n in (3, 9, 70, 14)]
+        outs = engine.generate([{"prompt_token_ids": p} for p in prompts],
+                               params_for("low_confidence_static", 2))
+        for p, o in zip(prompts, outs):
+            check_against_generate(weights, p, o.outputs[0], "low_confidence_static", 2)
+        assert {key[:2] for key in runner._seen_buckets} == {(64, 8)}
+    finally:
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("kw", [dict(token_buckets="16,32"), dict(request_buckets="4"),
+                                dict(token_buckets="64,16")])
+def test_a_ladder_that_cannot_hold_a_full_step_is_refused(tmp_path, kw):
+    with pytest.raises(ValueError, match="must be ascending and reach"):
+        engine_config(tmp_path, **kw)
+
+
+# ---------------------------------------------------------------------------
+# (c) the ragged kernel's mask over blocks, interpret mode
+# ---------------------------------------------------------------------------
+
+
+BLOCK_CASES = [
+    ([4, 4, 4], [20, 44, 8]),  # block rows against their stored prefix
+    ([16, 24], [16, 24]),  # prefill under the mask
+    ([4, 12, 4, 8], [36, 12, 12, 32]),  # block rows beside prefill chunks
+]
+
+
+@pytest.mark.parametrize("q_lens,kv_lens", BLOCK_CASES)
+@pytest.mark.parametrize("d", [64, 128])
+def test_ragged_kernel_with_block_size_4_matches_the_xla_path(q_lens, kv_lens, d):
+    from dataclasses import replace
+
+    from tests.models.test_ragged_paged_attention import _random_case, _run_kernel
+    from vllm_tpu.ops.attention import ref_ragged_paged_attention
+
+    q, kv, md = _random_case(np.random.default_rng(5), len(q_lens), q_lens, kv_lens,
+                             2, 4, d, 8, num_blocks=64)
+    scale = d ** -0.5
+    got = _run_kernel(q, kv, 0, md, scale, block_len=B)
+    want = ref_ragged_paged_attention(q, kv, jnp.int32(0), md, scale, block_len=B)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+    # And the mask is not the causal one: the first query of a block sees
+    # the three positions to its right.
+    causal = ref_ragged_paged_attention(q, kv, jnp.int32(0), replace(md, block_len=1), scale)
+    assert float(jnp.max(jnp.abs(want[0] - causal[0]))) > 1e-3
+
+
+def test_block_size_1_leaves_the_ragged_kernel_as_it_was():
+    from tests.models.test_ragged_paged_attention import CASES, _random_case, _run_kernel
+    from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
+
+    q_lens, kv_lens = CASES[2]
+    q, kv, md = _random_case(np.random.default_rng(3), len(q_lens), q_lens, kv_lens,
+                             2, 4, 128, 8, num_blocks=64)
+    np.testing.assert_array_equal(np.asarray(_run_kernel(q, kv, 0, md, 0.1)),
+                                  np.asarray(_run_kernel(q, kv, 0, md, 0.1, block_len=1)))
+    args = (q, kv, jnp.zeros(1, jnp.int32), md.seq_lens, md.block_tables,
+            md.query_start_loc, md.num_seqs)
+    kw = dict(sm_scale=0.1, interpret=True, num_kv_pages_per_block=2, num_queries_per_block=8)
+    text = [jax.jit(lambda *a, k=k: ragged_paged_attention(*a, **kw, **k)).lower(*args).as_text()
+            for k in ({}, {"block_len": 1})]
+    assert text[0] == text[1]
+
+
+# ---------------------------------------------------------------------------
+# (d) the sampler's probability of the token it drew
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("needs_penalties", [False, True])
+@pytest.mark.parametrize("needs_top_k", [False, True])
+@pytest.mark.parametrize("needs_top_p_min_p", [False, True])
+def test_sampler_returns_the_probability_under_the_distribution_it_drew_from(
+        needs_penalties, needs_top_k, needs_top_p_min_p):
+    from tests.sample.test_sampler_kernel import V_ODD, _mixed_batch
+    from vllm_tpu.sample.sampler import (_mask_top_k, _mask_top_p_min_p, apply_penalties,
+                                         dispatch_sample, sample)
+
+    rng = np.random.default_rng(23 + needs_penalties * 4 + needs_top_k * 2 + needs_top_p_min_p)
+    logits, md = _mixed_batch(rng, V_ODD, needs_penalties)
+    flags = dict(needs_penalties=needs_penalties, needs_top_k=needs_top_k,
+                 needs_top_p_min_p=needs_top_p_min_p, needs_gumbel=True)
+    plain, _ = sample(logits, md, **flags)
+    drawn, _, prob = dispatch_sample(logits, md, **flags, return_prob=True)
+    np.testing.assert_array_equal(np.asarray(drawn), np.asarray(plain))
+    # The oracle: softmax over the sorted, truncated, scaled logits.
+    x = apply_penalties(logits, md) if needs_penalties else logits
+    t = np.asarray(md.temperature)
+    scaled = x / jnp.where(md.temperature == 0.0, 1.0, md.temperature)[:, None]
+    if needs_top_k:
+        scaled = _mask_top_k(scaled, md.top_k)
+    if needs_top_p_min_p:
+        scaled = _mask_top_p_min_p(scaled, md.top_p, md.min_p)
+    for r in range(logits.shape[0]):
+        row = np.asarray(x[r] if t[r] == 0.0 else scaled[r], np.float64)
+        order = np.sort(row)[::-1]
+        z = np.exp(order - order[0])
+        want = np.exp(row[int(drawn[r])] - order[0]) / z.sum()
+        assert abs(float(prob[r]) - want) < 1e-5 * max(want, 1e-3), (r, float(prob[r]), want)
+        assert 0.0 < float(prob[r]) <= 1.0
+
+
+def test_an_all_greedy_batch_returns_the_softmax_of_its_argmax():
+    from tests.sample.test_sampler_kernel import V_ODD, _make_md
+    from vllm_tpu.sample.sampler import dispatch_sample
+
+    logits = jnp.asarray(np.random.default_rng(2).standard_normal((4, V_ODD)) * 3, jnp.float32)
+    md = _make_md(4, V_ODD, temperature=[0.0] * 4)
+    drawn, _, prob = dispatch_sample(logits, md, needs_gumbel=False, return_prob=True)
+    want = jnp.max(jax.nn.softmax(logits, axis=-1), axis=-1)
+    np.testing.assert_allclose(np.asarray(prob), np.asarray(want), rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(drawn), np.asarray(jnp.argmax(logits, -1)))
+
+
+def test_past_the_kernels_bound_the_epilogue_pads_to_noise_tiles_and_still_cuts_the_nucleus():
+    """151936 tokens pad to 262144 lanes as a power of two, over the fused
+    kernel's bound, so no kernel ever shares the epilogue's sums there: it
+    computes at 155648 lanes with sums XLA may fuse, and the nucleus is
+    still the smallest set of largest weights that reaches top_p."""
+    from vllm_tpu.ops import sampler_kernel as sk
+    from vllm_tpu.sample.sampler import _mask_top_p_min_p, _sum_fn
+
+    assert sk.compute_width(151936) == 155648 and sk.padded_vocab(151936) == 262144
+    assert sk.compute_width(32768) == 32768 and sk.compute_width(1000) == 1024
+    assert _sum_fn(32768) is None and _sum_fn(151936) is sk.plain_sum
+    v = 140001
+    logits = jnp.asarray(np.random.default_rng(3).standard_normal((2, v)) * 2, jnp.float32)
+    got = np.asarray(_mask_top_p_min_p(logits, jnp.asarray([0.9, 0.5]), jnp.zeros(2)))
+    for r, top_p in enumerate((0.9, 0.5)):
+        x = np.asarray(logits[r], np.float64)
+        p = np.exp(x - x.max())
+        p /= p.sum()
+        order = np.argsort(-p)
+        kept = got[r] > -1e29
+        n = int(kept.sum())
+        assert kept[order[:n]].all()  # upward-closed
+        assert p[order[:n]].sum() >= top_p - 1e-4 > p[order[: n - 1]].sum() - 2e-4
+
+
+def test_the_grouped_matmul_fetches_an_experts_matrix_in_few_pieces():
+    from vllm_tpu.layers.moe import _gmm_tiling
+
+    assert _gmm_tiling(2048, 768) == (128, 2048, 768)  # gate, up: 3 MiB a visit
+    assert _gmm_tiling(768, 2048) == (128, 768, 2048)  # down
+    assert _gmm_tiling(14336, 4096) == (128, 2048, 1024)
+    assert _gmm_tiling(64, 32) == (128, 128, 128)  # the tiny presets of the tests
+
+
+# ---------------------------------------------------------------------------
+# (e) what the mechanism refuses, by configuration
+# ---------------------------------------------------------------------------
+
+
+def engine_config(tmp_path, **kw):
+    from vllm_tpu.engine.arg_utils import EngineArgs
+
+    return EngineArgs(**{**dict(model=model_dir(tmp_path), dtype="float32", load_format="dummy",
+                                max_model_len=128), **kw}).create_engine_config().finalize()
+
+
+@pytest.mark.parametrize("kw,names", [
+    (dict(speculative_method="ngram", num_speculative_tokens=2), "speculative decoding"),
+    (dict(num_decode_steps=4), "num_decode_steps"),
+    (dict(max_model_len=126), "multiple of the block length"),
+])
+def test_a_configuration_that_does_not_compose_is_refused_by_mechanism(tmp_path, kw, names):
+    with pytest.raises(ValueError, match="block_diffusion") as e:
+        engine_config(tmp_path, **kw).resolve_generation()
+    assert names in str(e.value) and "SDAR" not in str(e.value)
+
+
+def test_the_mechanism_is_read_from_the_class_and_the_config(tmp_path):
+    cfg = engine_config(tmp_path).resolve_generation()
+    assert cfg.model_config.generation == dict(
+        kind="block_diffusion", block_length=4, mask_token_id=250, remasking="sequential",
+        denoising_steps=4, confidence_threshold=0.9)
+    assert cfg.scheduler_config.block_length == 4
+    assert not cfg.scheduler_config.enable_decode_attention
+
+
+@pytest.mark.parametrize("kw,match", [
+    (dict(structured_outputs="regex"), "structured output"),
+    (dict(presence_penalty=0.5), "penalties"),
+    (dict(denoising_steps=3), "must divide"),
+])
+def test_a_request_that_does_not_compose_is_refused_by_mechanism(tmp_path, kw, match):
+    from vllm_tpu import SamplingParams
+    from vllm_tpu.engine.input_processor import InputProcessor
+    from vllm_tpu.sampling_params import StructuredOutputParams
+
+    if "structured_outputs" in kw:
+        kw = dict(structured_outputs=StructuredOutputParams(regex="a+"))
+    proc = InputProcessor(engine_config(tmp_path))
+    with pytest.raises(ValueError, match=match):
+        proc.process("r", {"prompt_token_ids": [1, 2, 3]}, SamplingParams(**kw))
+    filled = proc.process("r", {"prompt_token_ids": [1, 2, 3]}, SamplingParams()).sampling_params
+    assert (filled.remasking, filled.denoising_steps, filled.confidence_threshold) == (
+        "sequential", 4, 0.9)
+
+
+@pytest.mark.parametrize("kw", [dict(remasking="random"), dict(denoising_steps=0),
+                                dict(confidence_threshold=1.5)])
+def test_the_request_parameters_are_validated_like_the_others(kw):
+    from vllm_tpu import SamplingParams
+
+    with pytest.raises(ValueError):
+        SamplingParams(**kw)
+
+
+def test_an_autoregressive_model_refuses_the_diffusion_parameters(tmp_path):
+    from tests.models.utils import tiny_llama_dir
+    from vllm_tpu import SamplingParams
+    from vllm_tpu.engine.arg_utils import EngineArgs
+    from vllm_tpu.engine.input_processor import InputProcessor
+
+    cfg = EngineArgs(model=tiny_llama_dir(tmp_path), dtype="float32",
+                     max_model_len=128).create_engine_config().finalize().resolve_generation()
+    assert cfg.model_config.generation == {} and cfg.scheduler_config.block_length == 0
+    with pytest.raises(ValueError, match="block_diffusion"):
+        InputProcessor(cfg).process("r", {"prompt_token_ids": [1, 2]},
+                                    SamplingParams(remasking="sequential"))

@@ -229,7 +229,7 @@ _HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
               "u32": 4, "f32": 4}
 
 
-def _while_body_instructions(hlo: str):
+def _while_body_instructions(hlo: str, with_dims: bool = False):
     """(name, opcode, bytes of the result) of every instruction that sits
     directly in the body of a ``while`` of the optimised program: the layer
     scan. A fused computation's inside is another computation and is not
@@ -250,7 +250,10 @@ def _while_body_instructions(hlo: str):
         if m:
             name, dtype, dims, opcode = m.groups()
             n = math.prod(int(d) for d in dims.split(",") if d)
-            yield name, opcode, n * _HLO_BYTES.get(dtype, 4)
+            if with_dims:
+                yield name, opcode, tuple(int(d) for d in dims.split(",") if d)
+            else:
+                yield name, opcode, n * _HLO_BYTES.get(dtype, 4)
 
 
 @pytest.mark.parametrize("tree", ["bf16", "int8"])
@@ -324,3 +327,86 @@ def test_layer_scan_reads_each_weight_once_in_place(
         f"the layer scan moves weight-sized temporaries (>= {limit} bytes): "
         f"{moved}"
     )
+
+
+# SDAR-30B-A3B-Chat at the depth the benchmark's cell runs it (7 of 48
+# layers), with about the cell's KV cache.
+SDAR = dict(
+    num_hidden_layers=7, hidden_size=2048, num_attention_heads=32,
+    num_key_value_heads=4, head_dim=128, intermediate_size=6144,
+    moe_intermediate_size=768, num_experts=128, num_experts_per_tok=8,
+    norm_topk_prob=True, vocab_size=151936, rms_norm_eps=1e-6,
+    tie_word_embeddings=False, max_position_embeddings=12288, rope_theta=1e6,
+    rope_scaling=None, sliding_window=None, block_length=4,
+    mask_token_id=151669,
+)
+SDAR_KV_BLOCKS, SDAR_PAGES = 20000, 64
+
+
+@pytest.mark.parametrize("t,r", [(512, 128), (128, 32)],
+                         ids=["mixed_T512_R128", "block_rows_T128_R32"])
+def test_block_diffusion_step_compiles_and_reads_each_weight_in_place(
+    one_chip, tuned_table_sees_chip, monkeypatch, t, r
+):
+    """The model's part of the step of ``sdar30b.chat``, compiled for the
+    v5e: rows of 4 tokens under the mask over blocks (a 512-token mixed
+    step, and a step of 32 block rows) through the ragged kernel and the
+    grouped matmul, and PR 27's check on the layer scan. ``mixtral.py``
+    writes the head split that folded into the projections in ``llama.py``;
+    behind this family's per-head q/k norm XLA does not fold it (compiled
+    with and without a barrier, PR 29: the same body), so the file has
+    none. What the scan DID copy was a layer's three expert stacks, 1.2 GB,
+    into temporaries for the grouped matmul: the kernel now takes the
+    stacks whole (``layers/moe.py::fused_experts_of_layer``)."""
+    from vllm_tpu.layers import moe
+    from vllm_tpu.models.qwen3_moe import SDARMoeForCausalLM
+    from vllm_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    # The grouped matmul is the one-chip path on a TPU; here JAX is held to
+    # several CPU devices.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert moe.jax is jax
+    model = SDARMoeForCausalLM(types.SimpleNamespace(**SDAR), dtype=jnp.bfloat16)
+    assert model.generation == "block_diffusion"
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(model.init_dummy_params, jax.random.PRNGKey(0)),
+    )
+    md = attention.AttentionMetadata(
+        positions=s((t,), jnp.int32), slot_mapping=s((t,), jnp.int32),
+        block_tables=s((r, SDAR_PAGES), jnp.int32),
+        seq_lens=s((r,), jnp.int32), query_start_loc=s((r + 1,), jnp.int32),
+        token_req_idx=s((t,), jnp.int32), logits_indices=s((r,), jnp.int32),
+        num_seqs=s((1,), jnp.int32), block_len=SDAR["block_length"],
+    )
+    kv = s(attention.kv_cache_shape(model.num_layers, SDAR_KV_BLOCKS, PAGE,
+                                    model.num_kv_heads, model.head_dim),
+           jnp.bfloat16)
+
+    model.return_expert_counts = True  # as the runner sets it
+    text = jax.jit(model.apply, donate_argnums=(1,)).lower(
+        params, kv, s((t,), jnp.int32), md
+    ).compile().as_text()
+
+    assert "rpa_kernel" in text and "gmm" in text
+    # No result in the layer scan's body has the shape of a layer's weight
+    # (or of its transpose): each is read where it lies, the three expert
+    # stacks by the grouped matmul itself, whole.
+    shapes = {a.shape[1:] for a in jax.tree.leaves(params["layers"]) if a.ndim >= 3}
+    shapes |= {sh[:-2] + sh[:-3:-1] for sh in shapes}
+    # (but [T, hidden] is the step's own activations, whatever weight's
+    # transpose it looks like)
+    shapes -= {(t, model.hidden_size), (t * model.top_k, model.hidden_size)}
+    moved = [
+        (name, opcode, dims)
+        for name, opcode, dims in _while_body_instructions(text, with_dims=True)
+        if dims in shapes and (opcode in ("copy", "transpose", "dynamic-slice", "fusion")
+                               or "dynamic-slice" in name)
+    ]
+    assert not moved, f"the layer scan copies a layer's weight: {moved}"

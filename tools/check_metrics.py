@@ -87,6 +87,17 @@ REQUIRED_STEP_METRICS = {
     "vllm:step_bucket_compiles",
     "vllm:step_first_use_seconds_total",
 }
+# Documented in the README ("Generation by diffusion over blocks"); the
+# benchmark's readers diffusion_tokens_per_pass, moe_experts_hit_share,
+# moe_gmm_roofline and block_attn_roofline read these names (the fifth is
+# the kind "block" of vllm:attn_context_tokens_total).
+REQUIRED_DIFFUSION_MOE_METRICS = {
+    "vllm:diffusion_passes_total",
+    "vllm:diffusion_committed_tokens_total",
+    "vllm:moe_assignments_total",
+    "vllm:moe_experts_hit_total",
+}
+DIFFUSION_PASS_KINDS = ("denoise", "store")
 REQUIRED_STEP_PHASES = ("schedule", "dispatch", "prepare", "finalize",
                         "fetch", "update", "output")
 STEP_KINDS = ("decode", "mixed")
@@ -272,6 +283,11 @@ def check() -> list[str]:
             f"required step metric {name} is missing from "
             f"the registry (documented in README)")
     errors += check_step_exposition()
+    for name in sorted(REQUIRED_DIFFUSION_MOE_METRICS - set(seen)):
+        errors.append(
+            f"required diffusion/MoE metric {name} is missing from "
+            f"the registry (documented in README)")
+    errors += check_diffusion_exposition()
     for name in sorted(REQUIRED_SAMPLER_METRICS - set(seen)):
         errors.append(
             f"required sampler metric {name} is missing from "
@@ -344,6 +360,32 @@ def check_step_exposition() -> list[str]:
                for name in ("vllm:engine_steps_total",
                             "vllm:engine_step_tokens_total",
                             "vllm:attn_context_tokens_total")]
+    return [f"/metrics lacks the sample {w!r}" for w in wanted
+            if w not in text]
+
+
+def check_diffusion_exposition() -> list[str]:
+    """The runner's counters of generation by diffusion over blocks and of
+    the mixture of experts through a fresh registry: both kinds of pass,
+    the block rows' context and the three plain counters must come out."""
+    from vllm_tpu.core.sched_output import SchedulerStats
+    from vllm_tpu.metrics.prometheus import PrometheusRegistry
+
+    reg = PrometheusRegistry()
+    try:
+        reg.record(SchedulerStats(
+            diffusion_passes_by_kind={k: 2 for k in DIFFUSION_PASS_KINDS},
+            diffusion_committed_tokens=3, moe_assignments=5,
+            moe_experts_hit=7, attn_context_tokens_by_kind={"block": 11}))
+    except (TypeError, AttributeError) as e:
+        return [f"the diffusion stats no longer reach the registry: {e}"]
+    text = reg.render()
+    wanted = [f'vllm:diffusion_passes_total{{kind="{k}"}} 2.0'
+              for k in DIFFUSION_PASS_KINDS]
+    wanted += ["vllm:diffusion_committed_tokens_total 3.0",
+               "vllm:moe_assignments_total 5.0",
+               "vllm:moe_experts_hit_total 7.0",
+               'vllm:attn_context_tokens_total{kind="block"} 11.0']
     return [f"/metrics lacks the sample {w!r}" for w in wanted
             if w not in text]
 

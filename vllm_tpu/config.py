@@ -48,6 +48,10 @@ class ModelConfig:
     hf_config: Any = None
     # Optional override dict applied on top of the HF config (tests).
     hf_overrides: dict[str, Any] | None = None
+    # How the model generates, when not one token a sequence a step: set
+    # by EngineConfig.resolve_generation from the model class and its
+    # config.json (models/generation.py). None = autoregressive.
+    generation: dict[str, Any] | None = None
 
     def __post_init__(self) -> None:
         if self.tokenizer is None:
@@ -334,6 +338,11 @@ class SchedulerConfig:
     pressure_preemption_s: float = 0.0
     max_preemptions_per_step: int = 1
     max_preemptions_per_request: int = 4
+    # Generation by diffusion over blocks (models/generation.py): a
+    # decoding request holds a block of this many positions that is run
+    # whole several times; 0 = autoregressive. Mirrored from
+    # ModelConfig.generation by EngineConfig.resolve_generation.
+    block_length: int = 0
 
     def __post_init__(self) -> None:
         if self.max_num_batched_tokens < 1:
@@ -511,6 +520,15 @@ class CompilationConfig:
     def finalize(self, sched: SchedulerConfig) -> None:
         explicit_t = bool(self.token_buckets)
         explicit_r = bool(self.request_buckets)
+        for name, given, most in (
+            ("token_buckets", self.token_buckets, sched.max_num_batched_tokens),
+            ("request_buckets", self.request_buckets, sched.max_num_seqs),
+        ):
+            if given and (given != sorted(set(given)) or given[0] < 1
+                          or given[-1] < most):
+                raise ValueError(
+                    f"{name} {given} must be ascending and reach {most}, "
+                    "the most a step can hold")
         if not explicit_t:
             self.token_buckets = self._pow2_buckets(
                 16, max(16, sched.max_num_batched_tokens)
@@ -637,6 +655,16 @@ class EngineConfig:
                 raise ValueError(
                     "--engine-roles needs the KV fabric for the prefill->"
                     "decode handoff; set --kv-connector fabric")
+        return self
+
+    def resolve_generation(self) -> "EngineConfig":
+        """Read how the model generates from its class and ``config.json``
+        and refuse what does not compose with it, by mechanism. Called
+        where the model's config is first needed (EngineCore, the input
+        processor); idempotent."""
+        from vllm_tpu.models.generation import resolve_generation
+
+        resolve_generation(self)
         return self
 
     def compute_hash(self) -> str:

@@ -115,8 +115,15 @@ class KVCacheManager:
         new_computed_blocks: list[KVCacheBlock] | None = None,
         num_new_computed_tokens: int = 0,
         num_lookahead_tokens: int = 0,
+        num_stored_tokens: int | None = None,
     ) -> list[KVCacheBlock] | None:
         """Ensure the request has blocks covering its tokens after this step.
+
+        ``num_stored_tokens``: how many of the request's tokens hold their
+        FINAL K/V once this step has run, where that is not all it runs
+        (a denoising pass of generation by diffusion overwrites its block's
+        slots again): only pages wholly below it are registered for the
+        prefix cache.
 
         Returns the newly-allocated blocks, or None if the pool cannot
         satisfy the request (caller preempts). Reference:
@@ -171,7 +178,11 @@ class KVCacheManager:
             req_blocks.extend(new_blocks)
 
         if self.enable_caching:
-            self._cache_full_blocks(request, num_computed_tokens + num_new_tokens)
+            self._cache_full_blocks(
+                request,
+                num_computed_tokens + num_new_tokens
+                if num_stored_tokens is None else num_stored_tokens,
+            )
         return new_blocks
 
     def _free_out_of_window(

@@ -52,6 +52,22 @@ class CachedRequestData:
 
 
 @dataclass
+class BlockPass:
+    """One pass over a request's open block (generation by diffusion over
+    blocks). ``store`` = the block is clean: run it once more, keep its
+    K/V, sample nothing. Otherwise a denoising pass: every uncommitted
+    position is fed the mask token and sampled, and the row's rule commits
+    some. ``tokens`` / ``committed`` are the host's view of the block, or
+    None when a pass of this request is still in flight and the device's
+    own copy (the previous step's output) is the one to read."""
+
+    store: bool
+    seq: int  # block passes of this request before this one
+    tokens: list[int] | None = None
+    committed: list[bool] | None = None
+
+
+@dataclass
 class SchedulerOutput:
     scheduled_new_reqs: list[NewRequestData] = field(default_factory=list)
     scheduled_cached_reqs: CachedRequestData = field(default_factory=CachedRequestData)
@@ -98,6 +114,10 @@ class SchedulerOutput:
     # should drop (spans fully computed / request gone).
     scheduled_encoder_inputs: dict[str, list[int]] = field(default_factory=dict)
     free_encoder_input_ids: list[tuple[str, int]] = field(default_factory=list)
+    # Generation by diffusion over blocks: req_id -> BlockPass for every
+    # row of this step that runs an open block (rows without an entry are
+    # prefill chunks, which end on a multiple of the block length).
+    block_passes: dict[str, "BlockPass"] = field(default_factory=dict)
     # In-proc identity of each scheduled Request at schedule time. Async
     # scheduling leaves steps in flight after a request finishes; if a NEW
     # request reuses the id before the stale step drains, update_from_output
@@ -137,6 +157,10 @@ class ModelRunnerOutput:
     # scheduler reschedules them for recompute (reference: invalid-block
     # recovery, scheduler.py:2123/2226).
     invalid_req_ids: set[str] = field(default_factory=set)
+    # Generation by diffusion over blocks: req_id -> (tokens [B],
+    # committed-after-the-pass [B], per-position logprob entries or None)
+    # for every block row of the step; such rows carry no sampled_token_ids.
+    block_results: dict[str, tuple] = field(default_factory=dict)
     # Requests whose numeric-integrity guard tripped this step (NaN/Inf
     # logits or out-of-range sampled token): terminal per-request error
     # (finish_reason="error"), never an engine failure.
@@ -165,6 +189,10 @@ class EngineCoreOutput:
     # and the KV blocks currently held engine-side for this request.
     queue_time: float | None = None
     kv_blocks_held: int = 0
+    # Generation by diffusion over blocks: for each of new_token_ids the
+    # request's block pass (counted from 0, store passes included) that
+    # committed it. None for an autoregressive model.
+    commit_passes: list[int] | None = None
 
 
 # The phases of vllm:engine_step_duration_seconds that EngineCore times,
@@ -231,6 +259,15 @@ class SchedulerStats:
     step_tokens_by_kind: dict[str, int] = field(default_factory=dict)
     attn_context_tokens_by_kind: dict[str, int] = field(default_factory=dict)
     step_first_use_s: float = 0.0
+    # Generation by diffusion over blocks (cumulative, from the runner):
+    # row-passes by kind and positions committed by denoising passes.
+    # Mixture of experts (cumulative, from the runner): token-expert pairs
+    # over live tokens and experts that got a live token, both summed over
+    # layers and steps.
+    diffusion_passes_by_kind: dict[str, int] = field(default_factory=dict)
+    diffusion_committed_tokens: int = 0
+    moe_assignments: int = 0
+    moe_experts_hit: int = 0
     prep_fallback_rows: int = 0
     # Sampling-epilogue routing: in-jit sample() calls routed to the
     # fused sort-free kernel vs sampling rows that fell back to the XLA

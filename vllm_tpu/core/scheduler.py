@@ -27,6 +27,7 @@ from vllm_tpu.config import CacheConfig, SchedulerConfig
 from vllm_tpu.core.kv_cache_manager import KVCacheManager
 from vllm_tpu.core.sched_output import (
     MAX_DYNAMIC_STOP_IDS,
+    BlockPass,
     CachedRequestData,
     EngineCoreOutput,
     EngineCoreOutputs,
@@ -261,6 +262,147 @@ class Scheduler:
         # an admission allocation).
         self._pressure_preemptions_total = 0
         self._preempted_rids: list[str] = []
+        # Generation by diffusion over blocks (models/generation.py):
+        # positions a block, 0 for an autoregressive model.
+        self._block_len = scheduler_config.block_length
+
+    # ------------------------------------------------------------------
+    # Generation by diffusion over blocks
+    # ------------------------------------------------------------------
+
+    def _blk_open(self, request: Request, start: int) -> None:
+        """Open the block at ``start`` (the prefill has stored everything
+        below it): the prompt's last ``P mod B`` tokens, committed, then
+        masks. A request resumed after a preemption reopens the block it
+        held, with the tokens it had committed out of order."""
+        if request.blk_start != start or not request.blk_tokens:
+            self._blk_reset(request, start, request.all_token_ids[start:])
+        request.blk_open = True
+        request.blk_sched_committed = sum(request.blk_committed)
+
+    def _blk_reset(self, request: Request, start: int, known=()) -> None:
+        """The host's view of the block at ``start``: ``known`` tokens,
+        committed, then masks."""
+        pad = self._block_len - len(known)
+        request.blk_start = start
+        request.blk_tokens = list(known) + [0] * pad
+        request.blk_committed = [True] * len(known) + [False] * pad
+        request.blk_commit_pass = [-1] * self._block_len
+        request.blk_logprobs = [None] * self._block_len
+
+    def _blk_plan(self, request: Request, computed: int, budget: int):
+        """What a request that generates by diffusion runs next, given
+        ``computed`` stored tokens and ``budget`` tokens of the step:
+        ``(tokens, None)`` for a prefill chunk, which ends on a multiple
+        of the block length, ``(B, BlockPass)`` for a pass over its open
+        block, or None when the step has no room for either."""
+        blen = self._block_len
+        if not request.blk_open:
+            remaining = request.num_tokens // blen * blen - computed
+            if remaining > 0:
+                n = min(remaining, budget)
+                cap = self._effective_chunk_threshold()
+                if cap > 0:
+                    n = min(n, cap)
+                n = min(n, self.config.max_model_len - computed) // blen * blen
+                return (n, None) if n > 0 else None
+            self._blk_open(request, computed)
+        if budget < blen or computed + blen > self.config.max_model_len:
+            return None
+        # A pass of this request still in flight: the device's own copy of
+        # the block (the previous step's output) is ahead of the host's.
+        on_device = request.num_inflight_steps > 0
+        return blen, BlockPass(
+            store=request.blk_sched_committed >= blen,
+            seq=request.blk_passes,
+            tokens=None if on_device else list(request.blk_tokens),
+            committed=None if on_device else list(request.blk_committed),
+        )
+
+    def _blk_after_schedule(self, request: Request, n: int, bp,
+                            block_passes: dict) -> None:
+        """Schedule-time bookkeeping of a diffusion request, in the sync
+        and the async scheduler alike: what is STORED advances here, by a
+        prefill chunk or a store pass; a denoising pass stores nothing."""
+        blen = self._block_len
+        if bp is None:
+            request.num_computed_tokens += n
+            return
+        block_passes[request.request_id] = bp
+        request.blk_passes += 1
+        request.num_inflight_steps += 1
+        if bp.store:
+            request.num_computed_tokens += blen
+            request.blk_sched_committed = 0
+        else:
+            # What the sequential and the static rule commit, and the
+            # least the dynamic rule does (a dynamic row is never
+            # scheduled ahead of its result; update_from_output resets
+            # this from what came back).
+            request.blk_sched_committed = min(
+                blen,
+                request.blk_sched_committed
+                + blen // request.sampling_params.denoising_steps,
+            )
+
+    def _blk_update(self, request: Request, req_id: str, bp, result,
+                    outputs: list) -> None:
+        """Fold one block pass's result into the host's view of the
+        block, and stream every token that it and all before it are
+        committed."""
+        blen = self._block_len
+        request.num_inflight_steps = max(0, request.num_inflight_steps - 1)
+        new_token_ids: list[int] = []
+        new_logprobs: list = []
+        passes: list[int] = []
+        stopped = False
+        if bp.store:
+            # The clean block is stored; the next opens, all masks.
+            self._blk_reset(request, request.blk_start + blen)
+        elif result is not None:
+            tokens, committed, lps = result
+            for j in range(blen):
+                if committed[j] and not request.blk_committed[j]:
+                    request.blk_committed[j] = True
+                    request.blk_tokens[j] = int(tokens[j])
+                    request.blk_commit_pass[j] = bp.seq
+                    request.blk_logprobs[j] = lps[j] if lps else None
+            while not stopped:
+                j = request.num_tokens - request.blk_start
+                if not 0 <= j < blen or not request.blk_committed[j]:
+                    break
+                tok = request.blk_tokens[j]
+                request.append_output_token_ids(tok)
+                new_token_ids.append(tok)
+                passes.append(request.blk_commit_pass[j])
+                if request.blk_logprobs[j] is not None:
+                    ids, vals, lp, rank = request.blk_logprobs[j]
+                    new_logprobs.append((ids, vals, tok, lp, rank))
+                stopped = self._check_stop(request)
+        if request.blk_open and request.num_inflight_steps == 0:
+            request.blk_sched_committed = sum(request.blk_committed)
+        if stopped:
+            if request in self.running:
+                self.running.remove(request)
+            else:
+                self.waiting.remove(request)
+            self._free_request(request)
+        if new_token_ids or stopped:
+            outputs.append(
+                EngineCoreOutput(
+                    req_id=req_id,
+                    new_token_ids=new_token_ids,
+                    finish_reason=request.get_finished_reason(),
+                    stop_reason=request.stop_reason,
+                    new_logprobs=new_logprobs or None,
+                    num_cached_tokens=max(request.num_cached_tokens, 0),
+                    queue_time=request.queue_time,
+                    kv_blocks_held=len(
+                        self.kv_cache_manager.req_to_blocks.get(req_id, ())
+                    ),
+                    commit_passes=passes,
+                )
+            )
 
     # ------------------------------------------------------------------
     # Request lifecycle
@@ -406,6 +548,7 @@ class Scheduler:
         token_budget = self.config.max_num_batched_tokens
         num_scheduled_tokens: dict[str, int] = {}
         scheduled_spec_tokens: dict[str, list[int]] = {}
+        block_passes: dict[str, BlockPass] = {}
         enc_sched: dict[str, list[int]] = {}
         scheduled_new_reqs: list[NewRequestData] = []
         cached = CachedRequestData()
@@ -573,6 +716,12 @@ class Scheduler:
             elif (p.presence_penalty or p.frequency_penalty
                   or p.repetition_penalty != 1.0):
                 depth_cap = 2
+            elif (self._block_len
+                  and p.remasking == "low_confidence_dynamic"):
+                # How many positions the pass in flight commits is data:
+                # whether the next pass denoises or stores is not known
+                # until it lands.
+                depth_cap = 1
             else:
                 depth_cap = self.config.async_pipeline_depth
             if request.num_inflight_steps >= depth_cap:
@@ -582,27 +731,39 @@ class Scheduler:
             # immediately previous dispatched step; a request that skipped
             # it waits until its tokens materialize host-side.
             if (
-                request.num_output_placeholders > 0
+                (request.num_output_placeholders > 0
+                 or (self._block_len and request.num_inflight_steps > 0))
                 and request.request_id not in self._last_step_req_ids
             ):
                 req_index += 1
                 continue
-            # num_output_placeholders is 0 in sync mode; in async mode it
-            # lets a decode whose last token is still in flight be scheduled
-            # one position ahead (the runner feeds the token on device).
-            num_new_tokens = (
-                request.num_tokens_with_spec
-                + request.num_output_placeholders
-                - request.num_computed_tokens
-            )
-            chunk_cap = self._effective_chunk_threshold()
-            if chunk_cap > 0:
-                num_new_tokens = min(num_new_tokens, chunk_cap)
-            num_new_tokens = min(num_new_tokens, token_budget)
-            num_new_tokens = min(
-                num_new_tokens,
-                self.config.max_model_len - request.num_computed_tokens,
-            )
+            blk_pass = None
+            if self._block_len:
+                plan = self._blk_plan(
+                    request, request.num_computed_tokens, token_budget
+                )
+                if plan is None:
+                    req_index += 1
+                    continue
+                num_new_tokens, blk_pass = plan
+            else:
+                # num_output_placeholders is 0 in sync mode; in async mode
+                # it lets a decode whose last token is still in flight be
+                # scheduled one position ahead (the runner feeds the token
+                # on device).
+                num_new_tokens = (
+                    request.num_tokens_with_spec
+                    + request.num_output_placeholders
+                    - request.num_computed_tokens
+                )
+                chunk_cap = self._effective_chunk_threshold()
+                if chunk_cap > 0:
+                    num_new_tokens = min(num_new_tokens, chunk_cap)
+                num_new_tokens = min(num_new_tokens, token_budget)
+                num_new_tokens = min(
+                    num_new_tokens,
+                    self.config.max_model_len - request.num_computed_tokens,
+                )
             # Encoder gate: reserve encoder-cache space for any image span
             # this chunk covers; trims the chunk when the budget is full
             # (reference: _try_schedule_encoder_inputs).
@@ -637,6 +798,7 @@ class Scheduler:
                         # appends KV in-loop with no host interaction.
                         self._decode_claims.get(request.request_id, 1) - 1,
                     ),
+                    num_stored_tokens=self._blk_stored(request, blk_pass),
                 )
                 if new_blocks is not None:
                     break
@@ -673,7 +835,12 @@ class Scheduler:
             if enc_new:
                 enc_sched.setdefault(request.request_id, []).extend(enc_new)
             starts[request.request_id] = request.num_computed_tokens
-            self._after_schedule(request, num_new_tokens)
+            if self._block_len:
+                self._blk_after_schedule(
+                    request, num_new_tokens, blk_pass, block_passes
+                )
+            else:
+                self._after_schedule(request, num_new_tokens)
             req_index += 1
 
         # Phase 2: admit waiting requests.
@@ -689,7 +856,11 @@ class Scheduler:
             # and an invalid-load recompute must wait for ALL its garbage
             # in-flight outputs to drain (a resumed step's legit output
             # would otherwise be indistinguishable from them).
-            if request.num_output_placeholders > 0 or request.dropping_invalid:
+            if (
+                request.num_output_placeholders > 0
+                or request.dropping_invalid
+                or (self._block_len and request.num_inflight_steps > 0)
+            ):
                 break
 
             # Structured-output grammar still compiling -> leave in queue.
@@ -771,15 +942,27 @@ class Scheduler:
                 )
                 num_new_computed_tokens += num_external_tokens
 
-            num_new_tokens = (
-                request.num_tokens
-                - request.num_computed_tokens
-                - num_new_computed_tokens
-            )
-            chunk_cap = self._effective_chunk_threshold()
-            if chunk_cap > 0:
-                num_new_tokens = min(num_new_tokens, chunk_cap)
-            num_new_tokens = min(num_new_tokens, token_budget)
+            blk_pass = None
+            if self._block_len:
+                request.blk_open = False  # a waiting request holds no K/V
+                plan = self._blk_plan(
+                    request,
+                    request.num_computed_tokens + num_new_computed_tokens,
+                    token_budget,
+                )
+                if plan is None:
+                    break  # no room for a whole block in this step
+                num_new_tokens, blk_pass = plan
+            else:
+                num_new_tokens = (
+                    request.num_tokens
+                    - request.num_computed_tokens
+                    - num_new_computed_tokens
+                )
+                chunk_cap = self._effective_chunk_threshold()
+                if chunk_cap > 0:
+                    num_new_tokens = min(num_new_tokens, chunk_cap)
+                num_new_tokens = min(num_new_tokens, token_budget)
             assert num_new_tokens > 0
             # Encoder gate (see phase 1). The window starts after any
             # device-cache / external-tier hit.
@@ -815,6 +998,9 @@ class Scheduler:
                 new_computed_blocks=new_computed_blocks,
                 num_new_computed_tokens=num_new_computed_tokens,
                 num_lookahead_tokens=self.config.num_lookahead_tokens,
+                num_stored_tokens=self._blk_stored(
+                    request, blk_pass, num_new_computed_tokens
+                ),
             )
             if new_blocks is None:
                 if num_external_tokens:
@@ -887,7 +1073,12 @@ class Scheduler:
             if enc_new:
                 enc_sched.setdefault(request.request_id, []).extend(enc_new)
             starts[request.request_id] = request.num_computed_tokens
-            self._after_schedule(request, num_new_tokens)
+            if self._block_len:
+                self._blk_after_schedule(
+                    request, num_new_tokens, blk_pass, block_passes
+                )
+            else:
+                self._after_schedule(request, num_new_tokens)
 
         # Phase 3: cached-request records for already-running requests.
         for request in self.running:
@@ -962,6 +1153,7 @@ class Scheduler:
             num_scheduled_tokens=num_scheduled_tokens,
             total_num_scheduled_tokens=total,
             scheduled_spec_decode_tokens=scheduled_spec_tokens,
+            block_passes=block_passes,
             structured_output_request_ids=structured_rows,
             scheduled_encoder_inputs=enc_sched,
             free_encoder_input_ids=self._take_encoder_frees(),
@@ -1036,6 +1228,16 @@ class Scheduler:
         out = self._pending_encoder_frees
         self._pending_encoder_frees = []
         return out
+
+    def _blk_stored(self, request: Request, bp, hit: int = 0) -> int | None:
+        """How many of the request's tokens hold their final K/V once the
+        step has run (``KVCacheManager.allocate_slots``): a denoising pass
+        stores nothing, so the open block's page stays out of the prefix
+        cache until its last block is stored."""
+        if bp is None:
+            return None
+        stored = request.num_computed_tokens + hit
+        return stored + self._block_len if bp.store else stored
 
     def _after_schedule(self, request: Request, num_new_tokens: int) -> None:
         """Hook run right after a request is scheduled this step. The async
@@ -1165,6 +1367,11 @@ class Scheduler:
         # re-sample an already-sampled position).
         request.num_preemptions += 1
         request.spec_token_ids = []
+        # Generation by diffusion: the block closes (its K/V are gone) and
+        # is reopened after the new prefill; what the host knows of it, the
+        # tokens committed out of order included, stays.
+        request.blk_open = False
+        request.blk_sched_committed = 0
         self._num_preempted_total += 1
         self._preempted_rids.append(request.request_id)
         if to_tail:
@@ -1265,6 +1472,15 @@ class Scheduler:
                 # prefix-cache registration hold (the next allocate
                 # catches registration up).
                 self.kv_cache_manager.confirm_external_load(req_id)
+
+            if self._block_len and request.pooling_params is None:
+                bp = scheduler_output.block_passes.get(req_id)
+                if bp is not None:
+                    self._blk_update(
+                        request, req_id, bp,
+                        runner_output.block_results.get(req_id), outputs,
+                    )
+                continue
 
             generated = runner_output.sampled_token_ids[req_index]
             scheduled_spec = spec_scheduled.get(req_id, [])

@@ -28,6 +28,11 @@ from vllm_tpu.resilience.config import ResilienceConfig
 from vllm_tpu.resilience.lifecycle import LifecycleConfig
 
 
+def _int_list(text: str | None) -> list[int]:
+    """``"128,256"`` -> ``[128, 256]``; None or empty -> ``[]``."""
+    return [int(x) for x in (text or "").split(",") if x.strip()]
+
+
 @dataclass
 class EngineArgs:
     model: str = "meta-llama/Meta-Llama-3-8B"
@@ -193,6 +198,12 @@ class EngineArgs:
     # Cap on token-bucket x request-bucket step compilations (derived
     # bucket ladders are thinned to fit; see CompilationConfig).
     max_step_compilations: int = 128
+    # Explicit bucket ladders of the step program, comma-separated and
+    # ascending ("512", "128,256"); never thinned. A server kept near its
+    # capacity pins them so that it meets few step programs, each a first
+    # use that stalls every running request (CompilationConfig).
+    token_buckets: str | None = None
+    request_buckets: str | None = None
 
     # Test/bench hook: inject an HF config object directly.
     hf_config: Any = None
@@ -299,6 +310,8 @@ class EngineArgs:
             compilation_config=CompilationConfig(
                 precompile=self.precompile,
                 max_step_compilations=self.max_step_compilations,
+                token_buckets=_int_list(self.token_buckets),
+                request_buckets=_int_list(self.request_buckets),
             ),
             resilience_config=ResilienceConfig(
                 enable_recovery=self.enable_engine_recovery,

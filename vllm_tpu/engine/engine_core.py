@@ -32,7 +32,7 @@ logger = init_logger(__name__)
 
 class EngineCore:
     def __init__(self, config: EngineConfig, executor_class: type[Executor] | None = None) -> None:
-        self.config = config.finalize()
+        self.config = config.finalize().resolve_generation()
         executor_class = executor_class or Executor.get_class(config)
         self.executor = executor_class(config)
         num_blocks = self.executor.initialize()
@@ -447,6 +447,11 @@ class EngineCore:
             for name in ("steps_by_kind", "step_tokens_by_kind",
                          "attn_context_tokens_by_kind"):
                 setattr(stats, name, dict(getattr(runner, name, {})))
+            stats.diffusion_passes_by_kind = dict(
+                getattr(runner, "diffusion_passes_by_kind", {}))
+            for name in ("diffusion_committed_tokens", "moe_assignments",
+                         "moe_experts_hit"):
+                setattr(stats, name, getattr(runner, name, 0))
             stats.step_first_use_s = getattr(runner, "first_use_s", 0.0)
             stats.prep_fallback_rows = getattr(
                 runner, "prep_fallback_rows", 0

@@ -367,6 +367,11 @@ class InputProcessor:
                     if ids and ids not in seqs:
                         seqs.append(ids)
             bad_words_token_ids = seqs
+        from vllm_tpu.models.generation import fill_request_params
+
+        params = fill_request_params(
+            params, self.config.resolve_generation().model_config.generation
+        )
         return replace(
             params,
             max_tokens=min(max_tokens, cap),

@@ -57,6 +57,9 @@ class RequestState:
         self.metrics = RequestMetrics(arrival_time=arrival_time)
         self.last_token_time = arrival_time
         self.logprobs: list[dict[int, Logprob]] = []
+        # Generation by diffusion: the block pass that committed each
+        # output token (None until the engine reports one).
+        self.commit_passes: list[int] | None = None
         # Observability: trace correlation id + per-request timing fields
         # folded into a RequestTimings record at finish (/debug/requests).
         self.trace_id = trace_id
@@ -91,9 +94,12 @@ class RequestState:
         text, self.num_sent_chars = self.detokenizer.get_next_output_text(
             finished, delta, self.num_sent_chars
         )
+        passes = self.commit_passes
         if delta:
             token_ids = new_token_ids
             logprobs = self.logprobs[-len(new_token_ids) :] if self.params.logprobs else None
+            if passes is not None:
+                passes = passes[len(passes) - len(new_token_ids):]
         else:
             token_ids = self.detokenizer.output_token_ids
             logprobs = self.logprobs if self.params.logprobs else None
@@ -105,6 +111,7 @@ class RequestState:
             logprobs=logprobs,
             finish_reason=finish_reason,
             stop_reason=stop_reason,
+            commit_passes=passes,
         )
         return RequestOutput(
             request_id=self.request_id,
@@ -287,6 +294,9 @@ class OutputProcessor:
 
             if eco.new_logprobs is not None:
                 self._append_logprobs(state, eco)
+            if eco.commit_passes is not None:
+                state.commit_passes = (
+                    (state.commit_passes or []) + eco.commit_passes)
             if (
                 eco.prompt_logprobs_delta is not None
                 and state.prompt_logprobs is not None

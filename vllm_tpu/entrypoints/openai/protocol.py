@@ -143,6 +143,10 @@ class CompletionRequest:
     # QoS scheduling priority (lower = more urgent, 0 = interactive);
     # also settable via the X-Priority header (body wins).
     priority: int | None = None
+    # Generation by diffusion over blocks (refused on other models).
+    remasking: str | None = None
+    denoising_steps: int | None = None
+    confidence_threshold: float | None = None
 
     @classmethod
     def from_json(cls, d: dict) -> "CompletionRequest":
@@ -178,6 +182,9 @@ class CompletionRequest:
             slo_class=_get(d, "slo_class", str),
             tenant_id=_get(d, "tenant_id", str),
             priority=_priority(d),
+            remasking=_get(d, "remasking", str),
+            denoising_steps=_get(d, "denoising_steps", int),
+            confidence_threshold=_get(d, "confidence_threshold", (int, float)),
         )
 
     def to_sampling_params(self, stream: bool) -> SamplingParams:
@@ -206,6 +213,9 @@ class CompletionRequest:
             slo_class=self.slo_class,
             tenant_id=self.tenant_id,
             priority=self.priority,
+            remasking=self.remasking,
+            denoising_steps=self.denoising_steps,
+            confidence_threshold=self.confidence_threshold,
             output_kind=(
                 RequestOutputKind.DELTA if stream
                 else RequestOutputKind.FINAL_ONLY
@@ -245,6 +255,9 @@ class ChatCompletionRequest:
     slo_class: str | None = None
     tenant_id: str | None = None
     priority: int | None = None
+    remasking: str | None = None
+    denoising_steps: int | None = None
+    confidence_threshold: float | None = None
 
     @classmethod
     def from_json(cls, d: dict) -> "ChatCompletionRequest":
@@ -289,6 +302,9 @@ class ChatCompletionRequest:
             slo_class=_get(d, "slo_class", str),
             tenant_id=_get(d, "tenant_id", str),
             priority=_priority(d),
+            remasking=_get(d, "remasking", str),
+            denoising_steps=_get(d, "denoising_steps", int),
+            confidence_threshold=_get(d, "confidence_threshold", (int, float)),
         )
 
     def to_sampling_params(self, stream: bool) -> SamplingParams:
@@ -320,6 +336,9 @@ class ChatCompletionRequest:
             slo_class=self.slo_class,
             tenant_id=self.tenant_id,
             priority=self.priority,
+            remasking=self.remasking,
+            denoising_steps=self.denoising_steps,
+            confidence_threshold=self.confidence_threshold,
             output_kind=(
                 RequestOutputKind.DELTA if stream
                 else RequestOutputKind.FINAL_ONLY

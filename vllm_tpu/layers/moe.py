@@ -105,7 +105,12 @@ def _grouped_moe(
         x_sorted = jnp.pad(x_sorted, ((0, m_pad - m), (0, 0)))
         group_sizes = group_sizes.at[e - 1].add(m_pad - m)
 
-    mm = partial(gmm, preferred_element_type=jnp.float32, interpret=interpret)
+    def mm(lhs, rhs, sizes):
+        return gmm(
+            lhs, rhs, sizes, preferred_element_type=jnp.float32,
+            interpret=interpret, tiling=_gmm_tiling(*rhs.shape[1:]),
+        )
+
     gate = mm(x_sorted, w_gate, group_sizes)
     up = mm(x_sorted, w_up, group_sizes)
     if biases is not None:
@@ -131,6 +136,26 @@ def _grouped_moe(
     return jnp.einsum(
         "tkd,tk->td", out, weights.astype(jnp.float32)
     ).astype(hidden.dtype)
+
+
+def _gmm_tiling(k: int, n: int) -> tuple[int, int, int]:
+    """Tiles of the grouped matmul over an expert's ``[k, n]`` matrix.
+
+    megablox's default, (128, 128, 128), fetches a matrix in 32 KB pieces:
+    at a few rows an expert the kernel is then bound by its own grid (16 x
+    6 steps a visit of a 2048 x 768 expert; 9% of the byte roofline on the
+    v5e, PERF.md PR 29). Here a visit fetches the whole depth ``k`` and as
+    much width as 4 MiB of bf16 hold, in pieces that divide ``n``."""
+    def tile(dim: int, cap: int) -> int:
+        if dim <= cap:
+            return dim
+        return next((t for t in range(cap // 128 * 128, 0, -128)
+                     if dim % t == 0), 128)
+
+    if k % 128 or n % 128:
+        return (128, 128, 128)
+    tk = tile(k, 2048)
+    return (128, tk, tile(n, max(128, (4 << 20) // (2 * tk))))
 
 
 def _excl_cumsum(x: jnp.ndarray) -> jnp.ndarray:
@@ -371,11 +396,7 @@ def fused_experts(
             ),
         )
     if use_grouped is None:
-        # Grouped megablox is the single-device fast path; under a multi-
-        # device mesh the dense one-hot path is the GSPMD/EP formulation.
-        use_grouped = (
-            jax.default_backend() == "tpu" and jax.device_count() == 1
-        )
+        use_grouped = _grouped_is_the_path()
     if use_grouped:
         return _grouped_moe(
             hidden, w_gate, w_up, w_down, weights, expert_ids,
@@ -384,6 +405,51 @@ def fused_experts(
     return _dense_moe(
         hidden, w_gate, w_up, w_down, weights, expert_ids,
         act_fn=act_fn, biases=biases,
+    )
+
+
+def _grouped_is_the_path() -> bool:
+    """Grouped megablox is the single-device fast path; under a multi-
+    device mesh the dense one-hot path is the GSPMD/EP formulation."""
+    return jax.default_backend() == "tpu" and jax.device_count() == 1
+
+
+def fused_experts_of_layer(
+    hidden: jnp.ndarray,  # [T, D]
+    w_gate: jnp.ndarray,  # [L, E, D, F]: the whole stack of the layer scan
+    w_up: jnp.ndarray,  # [L, E, D, F]
+    w_down: jnp.ndarray,  # [L, E, F, D]
+    layer: jnp.ndarray,  # scalar i32
+    weights: jnp.ndarray,  # [T, k] f32 combine weights
+    expert_ids: jnp.ndarray,  # [T, k] i32, experts of this layer
+    *,
+    ep_mesh=None,
+    ep_axis: str | None = None,
+) -> jnp.ndarray:
+    """``fused_experts`` for a layer scan that holds its expert weights as
+    ``[L, E, ...]`` stacks. On the grouped (megablox) path the stacks go to
+    the kernel WHOLE, as ``L * E`` groups of which only this layer's are
+    non-empty: a Pallas call cannot read a slice of its operand in place,
+    so handing it ``stack[layer]`` makes XLA copy the layer's experts into a
+    temporary every layer of every step (1.2 GB at 128 experts of 2048 x
+    768, read and written, before the kernel reads them again). A group
+    that holds no rows is never visited, so nothing else of the stack is
+    read. The other paths index the stack and fuse the slice as before."""
+    expert_parallel = (
+        ep_mesh is not None and ep_axis and ep_mesh.shape[ep_axis] > 1
+    )
+    if expert_parallel or not _grouped_is_the_path():
+        return fused_experts(
+            hidden, w_gate[layer], w_up[layer], w_down[layer], weights,
+            expert_ids, ep_mesh=ep_mesh, ep_axis=ep_axis,
+        )
+    n_layers, e = w_gate.shape[:2]
+    return _grouped_moe(
+        hidden,
+        w_gate.reshape(n_layers * e, *w_gate.shape[2:]),
+        w_up.reshape(n_layers * e, *w_up.shape[2:]),
+        w_down.reshape(n_layers * e, *w_down.shape[2:]),
+        weights, expert_ids + layer * e,
     )
 
 

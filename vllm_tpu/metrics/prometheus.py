@@ -314,6 +314,25 @@ class PrometheusRegistry:
             "vllm:attn_context_tokens_total",
             "Context tokens attended: the sum over a step's rows of the "
             "positions each row attends to, by kind of step", "kind")
+        # Generation by diffusion over blocks and mixture of experts,
+        # counted by the runner (cumulative).
+        self.diffusion_passes = LabeledCounter(
+            "vllm:diffusion_passes_total",
+            "Passes over a request's open block under generation by "
+            "diffusion, one a row a step: denoise (samples and commits) "
+            "or store (runs the clean block once more for its K/V)", "kind")
+        self.diffusion_committed = Counter(
+            "vllm:diffusion_committed_tokens_total",
+            "Positions that denoising passes committed under generation "
+            "by diffusion over blocks")
+        self.moe_assignments = Counter(
+            "vllm:moe_assignments_total",
+            "Token-expert pairs over the live tokens of dispatched steps, "
+            "summed over layers")
+        self.moe_experts_hit = Counter(
+            "vllm:moe_experts_hit_total",
+            "Experts that got at least one live token, counted per layer "
+            "and step and summed")
         self.pipeline_stall = Counter(
             "vllm:pipeline_stall_seconds",
             "Seconds the async lag-N pipeline blocked on device results")
@@ -722,6 +741,8 @@ class PrometheusRegistry:
             self.bucket_compiles, self.bucket_hits, self.first_use_seconds,
             self.steps_by_kind, self.step_tokens_by_kind,
             self.attn_context_tokens, self.pipeline_stall,
+            self.diffusion_passes, self.diffusion_committed,
+            self.moe_assignments, self.moe_experts_hit,
             self.decode_batch_ratio, self.tokens_per_launch,
             self.prep_fallback_rows,
             self.sampler_kernel_launches, self.sampler_fallback_rows,
@@ -841,9 +862,14 @@ class PrometheusRegistry:
                 (self.steps_by_kind, s.steps_by_kind),
                 (self.step_tokens_by_kind, s.step_tokens_by_kind),
                 (self.attn_context_tokens, s.attn_context_tokens_by_kind),
+                (self.diffusion_passes, s.diffusion_passes_by_kind),
             ):
                 for kind, n in by_kind.items():
                     counter.inc_to(kind, float(n))
+            self.diffusion_committed.inc_to(
+                float(s.diffusion_committed_tokens))
+            self.moe_assignments.inc_to(float(s.moe_assignments))
+            self.moe_experts_hit.inc_to(float(s.moe_experts_hit))
             self.batch_tokens.set(s.batch_num_tokens)
             self.batch_requests.set(s.batch_num_reqs)
             self.batch_occupancy.set(s.batch_occupancy)

@@ -52,6 +52,7 @@ def _clamped_glu(gate, up):
 
 
 class GptOssForCausalLM(MixtralForCausalLM):
+    supports_eplb = False  # apply() below keeps no routing counts
     attention_bias = True
     attention_out_bias = True
 

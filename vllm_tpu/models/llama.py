@@ -96,6 +96,10 @@ class LlamaForCausalLM:
     # MLP flavor: "gated_silu" (Llama wgate/wup/wdown) or "plain"
     # (GPT-class fc1/fc2 on the wup/wdown leaves, activation mlp_act).
     mlp_type = "gated_silu"
+    # False where every layer's MLP is a mixture of experts with leaves of
+    # its own (models/mixtral.py): the dummy load then draws no dense
+    # wgate/wup/wdown stacks.
+    dense_mlp = True
     mlp_act = "silu"  # "gelu" | "gelu_new" | "relu" for plain MLPs
     mlp_bias = False  # b_up/b_down leaves (GPT-class)
     attention_out_bias = False  # bo leaf on the output projection
@@ -239,12 +243,13 @@ class LlamaForCausalLM:
             "wv": init_w(keys[2], (L, D, KH * Dh), D, "wv"),
             "wo": init_w(keys[3], (L, H * Dh, D), H * Dh, "wo"),
             "post_norm": jnp.ones((L, D), dtype),
-            "wup": init_w(keys[5], (L, D, F), D, "wup"),
-            "wdown": init_w(keys[6], (L, F, D), F, "wdown"),
         }
+        if self.dense_mlp:
+            layers["wup"] = init_w(keys[5], (L, D, F), D, "wup")
+            layers["wdown"] = init_w(keys[6], (L, F, D), F, "wdown")
         if self.norm_type == "nonparam_layer":
             del layers["input_norm"], layers["post_norm"]
-        if self.mlp_type == "gated_silu":
+        if self.dense_mlp and self.mlp_type == "gated_silu":
             layers["wgate"] = init_w(keys[4], (L, D, F), D, "wgate")
         if self.mlp_bias:
             layers["b_up"] = jnp.zeros((L, F), dtype)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from vllm_tpu.layers.layernorm import rms_norm
-from vllm_tpu.layers.moe import fused_experts, select_experts
+from vllm_tpu.layers.moe import fused_experts_of_layer, select_experts
 from vllm_tpu.layers.rotary import _apply_interleaved, _apply_rotate_half
 from vllm_tpu.models.llama import LlamaForCausalLM
 from vllm_tpu.ops.attention import (
@@ -29,11 +29,16 @@ from vllm_tpu.ops.attention import (
 
 class MixtralForCausalLM(LlamaForCausalLM):
     supports_lora = False  # MoE expert adapters are future work
+    dense_mlp = False
     supports_eplb = True
     # Set by the worker when EPLB is on: routing stays in logical expert
     # ids, a per-layer [E] map redirects to physical slots, and apply()
     # returns per-layer logical-expert token counts as a third output.
     enable_eplb = False
+    # Set by the runner: apply() returns those [L, E] counts (of LIVE
+    # tokens) whether or not EPLB is on; vllm:moe_experts_hit_total is
+    # their non-zero entries.
+    return_expert_counts = False
 
     def __init__(self, hf_config: Any, dtype=jnp.bfloat16,
                  quantization: str | None = None) -> None:
@@ -67,6 +72,7 @@ class MixtralForCausalLM(LlamaForCausalLM):
 
     def init_dummy_params(self, rng: jax.Array, dtype=None) -> dict:
         import math
+        from functools import partial
 
         dtype = dtype or self.dtype
         params = super().init_dummy_params(rng, dtype)
@@ -80,8 +86,6 @@ class MixtralForCausalLM(LlamaForCausalLM):
                 self.num_layers, self.num_experts
             )
         layers = params["layers"]
-        for name in ("wgate", "wup", "wdown"):
-            del layers[name]
         L, D, F, E = (
             self.num_layers,
             self.hidden_size,
@@ -90,15 +94,33 @@ class MixtralForCausalLM(LlamaForCausalLM):
         )
         keys = jax.random.split(jax.random.fold_in(rng, 1), 8)
 
+        @partial(jax.jit, static_argnums=(1, 2))
         def init(key, shape, fan_in):
             return (
                 jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
             ).astype(dtype)
 
+        # An expert stack is drawn a layer at a time into its place, each
+        # layer one fused program: drawn whole and eagerly, the float32
+        # draw and its scaled copy are both live (2 x 5.6 GB for 7 layers
+        # of 128 experts at 2048 x 768) beside the weights already placed.
+        @partial(jax.jit, static_argnums=(3,), donate_argnums=(0,))
+        def draw_layer(stack, key, i, fan_in):
+            w = jax.random.normal(key, stack.shape[1:], jnp.float32)
+            return jax.lax.dynamic_update_index_in_dim(
+                stack, (w / math.sqrt(fan_in)).astype(dtype), i, 0
+            )
+
+        def init_experts(key, shape, fan_in):
+            stack = jnp.zeros(shape, dtype)
+            for i, k in enumerate(jax.random.split(key, shape[0])):
+                stack = draw_layer(stack, k, i, fan_in)
+            return stack
+
         layers["router"] = init(keys[0], (L, D, E), D)
-        layers["we_gate"] = init(keys[1], (L, E, D, F), D)
-        layers["we_up"] = init(keys[2], (L, E, D, F), D)
-        layers["we_down"] = init(keys[3], (L, E, F, D), F)
+        layers["we_gate"] = init_experts(keys[1], (L, E, D, F), D)
+        layers["we_up"] = init_experts(keys[2], (L, E, D, F), D)
+        layers["we_down"] = init_experts(keys[3], (L, E, F, D), F)
         if self.shared_intermediate:
             Fs = self.shared_intermediate
             layers["ws_gate"] = init(keys[4], (L, D, Fs), D)
@@ -146,11 +168,21 @@ class MixtralForCausalLM(LlamaForCausalLM):
             else _apply_rotate_half
         )
 
+        # The expert stacks stay whole, outside what the scan slices a
+        # layer at a time (layers/moe.py::fused_experts_of_layer).
+        expert_keys = ("we_gate", "we_up", "we_down")
+        expert_stacks = [params["layers"][k] for k in expert_keys]
+        scanned = {
+            k: v for k, v in params["layers"].items() if k not in expert_keys
+        }
+        count_experts = self.enable_eplb or self.return_expert_counts
+
         def layer_fn(carry, inputs):
             x, kv = carry
             lp, li = inputs
             h = self._norm(x, lp, "input_norm")
-            q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+            with jax.named_scope("qkv_proj"):
+                q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
             if self.attention_bias:
                 q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
             if self.clip_qkv is not None:
@@ -172,27 +204,30 @@ class MixtralForCausalLM(LlamaForCausalLM):
             k = rope_apply(k, cos, sin, self.rope.rotary_dim)
             kv = write_kv(kv, li, k, v, md.slot_mapping)
             kv_scale = kv_dequant_scale(kv)
-            attn = paged_attention(
-                q, kv, li, md, self.scale, sliding_window=self.sliding_window,
-                k_scale=kv_scale, v_scale=kv_scale,
-            )
-            x = x + self.residual_multiplier * (
-                attn.reshape(t, H * Dh) @ lp["wo"]
-            )
+            with jax.named_scope("attn"):
+                attn = paged_attention(
+                    q, kv, li, md, self.scale,
+                    sliding_window=self.sliding_window,
+                    k_scale=kv_scale, v_scale=kv_scale,
+                )
+            with jax.named_scope("o_proj"):
+                x = x + self.residual_multiplier * (
+                    attn.reshape(t, H * Dh) @ lp["wo"]
+                )
 
             h2 = self._norm(x, lp, "post_norm")
-            logits = (
-                h2.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
-            )
-            weights, ids = select_experts(
-                logits, self.top_k, self.renormalize
-            )
+            with jax.named_scope("moe_router"):
+                logits = (
+                    h2.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+                )
+                weights, ids = select_experts(
+                    logits, self.top_k, self.renormalize
+                )
             counts_l = None
-            if self.enable_eplb:
+            if count_experts:
                 # Load statistics in LOGICAL expert ids over LIVE tokens
                 # only (pad slots all route identically and would drown
-                # the real signal); the l2p table redirects dispatch to
-                # the balanced physical layout.
+                # the real signal).
                 live = (
                     jnp.arange(t)
                     < md.query_start_loc[md.num_seqs[0]]
@@ -203,18 +238,16 @@ class MixtralForCausalLM(LlamaForCausalLM):
                 counts_l = jnp.zeros(
                     self.num_experts, jnp.int32
                 ).at[ids.reshape(-1)].add(contrib.reshape(-1))
+            if self.enable_eplb:
+                # The l2p table redirects dispatch to the balanced
+                # physical layout.
                 ids = lp["eplb_l2p"][ids]
-            moe_out = fused_experts(
-                h2,
-                lp["we_gate"],
-                lp["we_up"],
-                lp["we_down"],
-                weights,
-                ids,
-                use_grouped=None if not self.expert_parallel else False,
-                ep_mesh=self.ep_mesh if self.expert_parallel else None,
-                ep_axis="tp",
-            )
+            with jax.named_scope("moe_experts"):
+                moe_out = fused_experts_of_layer(
+                    h2, *expert_stacks, li, weights, ids,
+                    ep_mesh=self.ep_mesh if self.expert_parallel else None,
+                    ep_axis="tp",
+                )
             if self.shared_intermediate:
                 # Sigmoid-gated shared expert (Qwen2-MoE semantics).
                 from vllm_tpu.layers.activation import silu_and_mul
@@ -230,10 +263,10 @@ class MixtralForCausalLM(LlamaForCausalLM):
         (x, new_kv), counts = jax.lax.scan(
             layer_fn,
             (x, kv_cache),
-            (params["layers"], jnp.arange(self.num_layers, dtype=jnp.int32)),
+            (scanned, jnp.arange(self.num_layers, dtype=jnp.int32)),
         )
         x = self._norm(x, params, "final_norm")
-        if self.enable_eplb:
+        if count_experts:
             return x, new_kv, counts  # counts [L, E]
         return x, new_kv
 

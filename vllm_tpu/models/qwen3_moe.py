@@ -96,3 +96,16 @@ class Qwen3MoeForCausalLM(MixtralForCausalLM):
                 m[f"{new}.up_proj.weight"] = (f"layers.we_up.{i}.{j}", True)
                 m[f"{new}.down_proj.weight"] = (f"layers.we_down.{i}.{j}", True)
         return m
+
+
+class SDARMoeForCausalLM(Qwen3MoeForCausalLM):
+    """SDAR-MoE: the Qwen3-MoE layer, key for key, generating by diffusion
+    over blocks (``models/generation.py``, which reads the block length
+    and the mask token from ``config.json``). The model's part is the
+    attention causal over blocks, which ``apply`` runs as the step's
+    ``AttentionMetadata.block_len`` says;
+    the logits at a position are for the token AT that position (no
+    shift): an uncommitted position is fed ``mask_token_id`` and its own
+    output row predicts it."""
+
+    generation = "block_diffusion"

@@ -22,6 +22,7 @@ _REGISTRY: dict[str, tuple[str, str]] = {
     "Qwen3ForCausalLM": ("vllm_tpu.models.llama", "Qwen3ForCausalLM"),
     "Qwen3MoeForCausalLM": ("vllm_tpu.models.qwen3_moe", "Qwen3MoeForCausalLM"),
     "Qwen2MoeForCausalLM": ("vllm_tpu.models.qwen3_moe", "Qwen2MoeForCausalLM"),
+    "SDARMoeForCausalLM": ("vllm_tpu.models.qwen3_moe", "SDARMoeForCausalLM"),
     "GemmaForCausalLM": ("vllm_tpu.models.gemma", "GemmaForCausalLM"),
     "Gemma2ForCausalLM": ("vllm_tpu.models.gemma", "Gemma2ForCausalLM"),
     "Gemma3ForCausalLM": ("vllm_tpu.models.gemma", "Gemma3ForCausalLM"),
@@ -102,3 +103,33 @@ class ModelRegistry:
 
 def get_model_class(hf_config: Any) -> type:
     return ModelRegistry.resolve(hf_config)
+
+
+def load_hf_config(model_config) -> Any:
+    if model_config.hf_config is not None:
+        return model_config.hf_config
+    if model_config.model.endswith(".gguf"):
+        from vllm_tpu.models.gguf import config_from_gguf
+
+        cfg = config_from_gguf(model_config.model)
+        model_config.hf_config = cfg
+        return cfg
+    from transformers import AutoConfig, PretrainedConfig
+
+    kw = dict(revision=model_config.revision,
+              trust_remote_code=model_config.trust_remote_code)
+    try:
+        cfg = AutoConfig.from_pretrained(model_config.model, **kw)
+    except ValueError:
+        # A model_type transformers has no class for: take config.json as
+        # it is if this registry serves one of its architectures.
+        raw, _ = PretrainedConfig.get_config_dict(model_config.model, **kw)
+        known = set(ModelRegistry.get_supported_archs())
+        if not known & set(raw.get("architectures") or ()):
+            raise
+        cfg = PretrainedConfig.from_dict(raw)
+    if model_config.hf_overrides:
+        for k, v in model_config.hf_overrides.items():
+            setattr(cfg, k, v)
+    model_config.hf_config = cfg
+    return cfg

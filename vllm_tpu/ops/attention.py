@@ -77,6 +77,12 @@ class AttentionMetadata:
     # over its KV-head rows, as every model's ``kv_cache_sharding`` lays
     # them out.
     tp_mesh: Any = field(default=None, metadata=dict(static=True))
+    # Attention causal over BLOCKS of this many positions (generation by
+    # diffusion over blocks): a query sees every position j with
+    # ``j // block_len <= pos // block_len``, so whole blocks to its left
+    # and its own block in both directions. 1 = plain causal. STATIC; a
+    # row's query tokens always cover whole blocks.
+    block_len: int = field(default=1, metadata=dict(static=True))
     # Hybrid attention+SSM models (Jamba/Bamba-class): per-request state
     # slot for the constant-size Mamba caches ([R] i32; None for pure
     # attention models). Reference: HybridKVCacheCoordinator per-type
@@ -282,6 +288,9 @@ def dispatch_ragged_attention(
         sm_scale=scale, soft_cap=soft_cap, k_scale=k_scale, v_scale=v_scale,
         interpret=run_interpret,
     )
+    if md.block_len > 1:
+        assert not md.decode_only and sliding_window is None, (
+            "attention over blocks has no decode-only or windowed form")
     if use_kernel and decode_ok:
         from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
 
@@ -318,7 +327,7 @@ def dispatch_ragged_attention(
         return _call_attention_kernel(
             functools.partial(
                 ragged_paged_attention, return_lse=return_lse,
-                **static_kw, **blk_kw,
+                block_len=md.block_len, **static_kw, **blk_kw,
             ),
             md.tp_mesh,
             q,
@@ -333,6 +342,7 @@ def dispatch_ragged_attention(
         q, kv_cache, layer, md, scale, sliding_window=sliding_window,
         soft_cap=soft_cap, k_scale=k_scale, v_scale=v_scale,
         return_lse=return_lse, ctx_stride=ctx_stride, ctx_phase=ctx_phase,
+        block_len=md.block_len,
     )
 
 
@@ -351,9 +361,12 @@ def ref_ragged_paged_attention(
     ctx_stride: int = 1,
     ctx_phase: int = 0,
     ctx_min_pos=0,
+    block_len: int = 1,
 ) -> jnp.ndarray:
     """Gather-based masked attention. Each token attends to its request's
-    cached context up to and including its own position (causal).
+    cached context up to and including its own position (causal), or with
+    ``block_len`` > 1 up to the end of its own block of that many
+    positions (causal over blocks).
 
     ``ctx_stride``/``ctx_phase`` describe striped context-parallel shards:
     local page j holds global page ``j * stride + phase`` (stride 1 = the
@@ -397,7 +410,10 @@ def ref_ragged_paged_attention(
     ctx_pos = (
         ((local // bs) * ctx_stride + ctx_phase) * bs + local % bs
     )[None, :]  # [1, C] global positions of the local context slots
-    causal = ctx_pos <= md.positions[:, None]  # [T, C]
+    last_seen = md.positions
+    if block_len > 1:
+        last_seen = (md.positions // block_len + 1) * block_len - 1
+    causal = ctx_pos <= last_seen[:, None]  # [T, C]
     causal &= ctx_pos >= jnp.asarray(ctx_min_pos, jnp.int32)
     if sliding_window is not None:
         # Accepts a python int OR a traced scalar (0 = full attention),

@@ -149,6 +149,7 @@ def _rpa_kernel(
     k_scale: float | None,
     v_scale: float | None,
     return_lse: bool,
+    block_len: int = 1,
 ):
     if return_lse:
         lse_ref, kv_bufs, sems, l_ref, m_ref, acc_ref = rest
@@ -346,6 +347,11 @@ def _rpa_kernel(
             col_ids = (
                 (col_flat // page_size) * ctx_stride + ctx_phase
             ) * page_size + col_flat % page_size
+            if block_len > 1:
+                # Causal over blocks: a query sees up to the last position
+                # of its own block (a row's queries cover whole blocks, so
+                # that position is inside kv_len).
+                row_ids = (row_ids // block_len + 1) * block_len - 1
             causal_mask = row_ids < col_ids
             window = window_ref[0]
             causal_mask = jnp.logical_or(
@@ -610,6 +616,7 @@ def _fit_kv_pages_to_vmem(
         "sm_scale", "mask_value", "num_kv_pages_per_block",
         "num_queries_per_block", "vmem_limit_bytes",
         "soft_cap", "k_scale", "v_scale", "return_lse", "interpret",
+        "block_len",
     ],
 )
 def ragged_paged_attention(
@@ -634,8 +641,14 @@ def ragged_paged_attention(
     interpret: bool = False,
     ctx_stride=1,
     ctx_phase=0,
+    block_len: int = 1,
 ):
     """Mixed prefill+decode flash attention over the paged KV cache.
+
+    ``block_len`` > 1 makes the mask causal over blocks of that many
+    positions (``col // block_len <= row // block_len``); every row's
+    query tokens must then cover whole blocks, and no window applies.
+    1 leaves the program as it was.
 
     Returns ``out [T, H, D]``, or ``(out, lse [T, H] f32)`` with
     ``return_lse=True``.
@@ -762,6 +775,7 @@ def ragged_paged_attention(
             k_scale=k_scale,
             v_scale=v_scale,
             return_lse=return_lse,
+            **({"block_len": block_len} if block_len > 1 else {}),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalar_prefetches),

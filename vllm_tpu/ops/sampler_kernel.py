@@ -74,6 +74,30 @@ def padded_vocab(vocab: int) -> int:
     return max(128, next_pow2(vocab))
 
 
+# The widest padded vocabulary the kernel takes (a [row_block, V2] float32
+# working set must fit VMEM). Past it the XLA epilogue is the only path, so
+# nothing has to match the kernel bit for bit: ``compute_width`` then pads
+# to whole noise tiles only, and the sums may run in any order.
+MAX_KERNEL_VOCAB = 131072
+
+
+def compute_width(vocab: int) -> int:
+    """The width the epilogue computes at: ``padded_vocab`` where the
+    kernel may run, else the vocabulary rounded up to whole noise tiles
+    (151936 -> 153600, not 262144)."""
+    v2 = padded_vocab(vocab)
+    if v2 <= MAX_KERNEL_VOCAB:
+        return v2
+    return -(-vocab // _NOISE_TILE) * _NOISE_TILE
+
+
+def plain_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """[..., N] -> [..., 1] in whatever order XLA picks: one fused pass
+    over its operands (``row_sum``'s halving tree writes every level out),
+    for widths the kernel never sees."""
+    return jnp.sum(x, axis=-1, keepdims=True)
+
+
 def noise_tile(v2: int) -> int:
     return min(_NOISE_TILE, v2)
 
@@ -147,7 +171,7 @@ def kth_largest_ord(ordv: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
 
 
 def top_p_cut(
-    wbits: jnp.ndarray, w: jnp.ndarray, target: jnp.ndarray
+    wbits: jnp.ndarray, w: jnp.ndarray, target: jnp.ndarray, sum_fn=None
 ) -> jnp.ndarray:
     """Largest int32 q (per row) whose strictly-greater weight mass
     S_gt(q) = sum(w where bits(w) > q) still reaches ``target``. The
@@ -155,9 +179,11 @@ def top_p_cut(
     weights whose mass >= target. ``wbits`` are the raw bits of the
     non-negative ``w`` (monotonic as integers); masses go through
     ``row_sum`` so the float rounding is tiling-independent."""
+    sum_fn = sum_fn or row_sum
+
     def body(i, r):
         c = r | jnp.left_shift(jnp.int32(1), 30 - i)
-        mass = row_sum(jnp.where(wbits > c, w, 0.0))
+        mass = sum_fn(jnp.where(wbits > c, w, 0.0))
         return jnp.where(mass >= target, c, r)
 
     return lax.fori_loop(0, 31, body, jnp.zeros_like(target, jnp.int32))
@@ -207,7 +233,7 @@ def mask_top_k_block(
 
 
 def mask_top_p_min_p_block(
-    scaled: jnp.ndarray, top_p: jnp.ndarray, min_p: jnp.ndarray
+    scaled: jnp.ndarray, top_p: jnp.ndarray, min_p: jnp.ndarray, sum_fn=None
 ) -> jnp.ndarray:
     """Nucleus + min-p in weight space (top_p/min_p [rows, 1] f32).
     ``w = exp(scaled - rowmax)`` gives ``max w == 1.0`` exactly, so
@@ -215,9 +241,9 @@ def mask_top_p_min_p_block(
     (disabled) keep every token rather than shaving sub-ulp tail mass."""
     m = row_max(scaled)
     w = jnp.exp(scaled - m)
-    target = top_p * row_sum(w)
+    target = top_p * (sum_fn or row_sum)(w)
     wbits = lax.bitcast_convert_type(w, jnp.int32)  # w >= 0: bits ordered
-    q = top_p_cut(wbits, w, target)
+    q = top_p_cut(wbits, w, target, sum_fn)
     q = jnp.where(top_p >= 1.0, jnp.int32(-1), q)
     keep = (wbits > q) & (w >= min_p)
     return jnp.where(keep, scaled, jnp.float32(MASK_VALUE))
@@ -328,19 +354,31 @@ def sample_block(
     vocab: int,
     needs_top_k: bool,
     needs_top_p_min_p: bool,
-) -> jnp.ndarray:
+    return_prob: bool = False,
+    sum_fn=None,
+):
     """The whole sampling epilogue after penalties, shared verbatim by
     the reference path ([R, V2]) and the kernel ([row_block, V2]) — the
-    bit-exactness contract lives here. Returns [rows, 1] i32."""
+    bit-exactness contract lives here. Returns [rows, 1] i32; with
+    ``return_prob`` also [rows, 1] f32, the probability of the token drawn
+    under the distribution it was drawn from: the softmax of the scaled
+    and truncated logits, or for a greedy row the plain softmax of its
+    logits (generation by diffusion ranks positions by it)."""
     greedy = temperature == 0.0
     greedy_pick = row_argmax(x)
     scaled = x / jnp.where(greedy, 1.0, temperature)
     if needs_top_k:
         scaled = mask_top_k_block(scaled, top_k, vocab)
     if needs_top_p_min_p:
-        scaled = mask_top_p_min_p_block(scaled, top_p, min_p)
+        scaled = mask_top_p_min_p_block(scaled, top_p, min_p, sum_fn)
     random_pick = gumbel_argmax_block(scaled, k0, k1)
-    return jnp.where(greedy, greedy_pick, random_pick)
+    pick = jnp.where(greedy, greedy_pick, random_pick)
+    if not return_prob:
+        return pick
+    dist = jnp.where(greedy, x, scaled)
+    w = jnp.exp(dist - row_max(dist))
+    drawn = jnp.take_along_axis(w, pick, axis=-1)
+    return pick, drawn / (sum_fn or row_sum)(w)
 
 
 # ---------------------------------------------------------------------------

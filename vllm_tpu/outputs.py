@@ -28,6 +28,10 @@ class CompletionOutput:
     logprobs: LogprobsList | None = None
     finish_reason: str | None = None  # "stop" | "length" | "abort"
     stop_reason: int | str | None = None
+    # Generation by diffusion over blocks: for each of token_ids the
+    # request's block pass (from 0, store passes counted) that committed
+    # it. None for an autoregressive model.
+    commit_passes: list[int] | None = None
 
     @property
     def finished(self) -> bool:

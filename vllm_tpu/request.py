@@ -143,6 +143,26 @@ class Request:
         self.grammar_future: Any = None
         self.fsm_state = 0
 
+        # Generation by diffusion over blocks (models/generation.py; unused
+        # by an autoregressive model). The HOST's view of the open block,
+        # which lags the device by the steps in flight: its first absolute
+        # position (-1 = none yet), and per position the token, whether it
+        # is committed, the block pass that committed it (-1 = a prompt
+        # token) and that pass's logprob entry. Kept across a preemption:
+        # committed tokens never change.
+        self.blk_start = -1
+        self.blk_tokens: list[int] = []
+        self.blk_committed: list[bool] = []
+        self.blk_commit_pass: list[int] = []
+        self.blk_logprobs: list[Any] = []
+        # Schedule-time view: whether a block is open (else the request is
+        # in prefill), how many of its positions are committed once every
+        # pass in flight has landed, and the block passes scheduled so far
+        # (the PRNG counter of a pass, and the order commits are reported in).
+        self.blk_open = False
+        self.blk_sched_committed = 0
+        self.blk_passes = 0
+
         # Content-addressed block hashes for prefix caching; maintained
         # incrementally as tokens append (reference: kv_cache_utils
         # get_request_block_hasher).

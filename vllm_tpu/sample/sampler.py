@@ -67,13 +67,22 @@ def apply_penalties(logits: jnp.ndarray, md: SamplingMetadata) -> jnp.ndarray:
 
 
 def _pad_vocab(logits: jnp.ndarray) -> jnp.ndarray:
-    """Pad the vocab axis to the shared power-of-two width with -inf
-    (zero weight, never wins an argmax)."""
+    """Pad the vocab axis to the width the epilogue computes at, with -inf
+    (zero weight, never wins an argmax): the shared power of two where the
+    kernel may run, whole noise tiles past its bound."""
     v = logits.shape[-1]
-    v2 = _sk.padded_vocab(v)
+    v2 = _sk.compute_width(v)
     if v2 == v:
         return logits
     return jnp.pad(logits, ((0, 0), (0, v2 - v)), constant_values=-jnp.inf)
+
+
+def _sum_fn(vocab: int):
+    """None (the fixed-order ``row_sum`` the kernel shares) where the
+    kernel may run; past its bound one fused pass in any order."""
+    if _sk.padded_vocab(vocab) <= _sk.MAX_KERNEL_VOCAB:
+        return None
+    return _sk.plain_sum
 
 
 def _mask_top_k(logits: jnp.ndarray, top_k: jnp.ndarray) -> jnp.ndarray:
@@ -93,7 +102,9 @@ def _mask_top_p_min_p(
     weight-space cutoff (see ``ops/sampler_kernel.py``)."""
     v = logits.shape[-1]
     x = _pad_vocab(logits)
-    out = _sk.mask_top_p_min_p_block(x, top_p[:, None], min_p[:, None])
+    out = _sk.mask_top_p_min_p_block(
+        x, top_p[:, None], min_p[:, None], _sum_fn(v)
+    )
     return out[:, :v]
 
 
@@ -105,9 +116,12 @@ def sample(
     needs_top_k: bool = True,
     needs_top_p_min_p: bool = True,
     needs_gumbel: bool = True,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+    return_prob: bool = False,
+) -> tuple[jnp.ndarray, ...]:
     """Returns (sampled [R] i32, logprobs [R, V] f32 log-softmax of the
-    pre-masking distribution — what logprob reporting uses).
+    pre-masking distribution — what logprob reporting uses), and with
+    ``return_prob`` a third, [R] f32: the probability of each drawn token
+    under the distribution it was drawn from (``sample_block``).
 
     The ``needs_*`` flags are static: an all-greedy or vanilla-temperature
     batch skips the [R, V] truncation passes — and, with
@@ -123,7 +137,14 @@ def sample(
     if not needs_gumbel:
         # Statically all-greedy: temperature scaling, masking, and noise
         # cannot change an argmax; skip them (~5 [R, V] passes saved).
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), raw_logprobs
+        pick = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if not return_prob:
+            return pick, raw_logprobs
+        x = _pad_vocab(logits)
+        w = jnp.exp(x - _sk.row_max(x))
+        drawn = jnp.take_along_axis(w, pick[:, None], axis=-1)
+        total = (_sum_fn(logits.shape[-1]) or _sk.row_sum)(w)
+        return pick, raw_logprobs, (drawn / total)[:, 0]
 
     keys = md.prng_keys.astype(jnp.uint32)
     sampled = _sk.sample_block(
@@ -137,7 +158,11 @@ def sample(
         vocab=logits.shape[-1],
         needs_top_k=needs_top_k,
         needs_top_p_min_p=needs_top_p_min_p,
+        return_prob=return_prob,
+        sum_fn=_sum_fn(logits.shape[-1]),
     )
+    if return_prob:
+        return sampled[0][:, 0], raw_logprobs, sampled[1][:, 0]
     return sampled[:, 0], raw_logprobs
 
 
@@ -195,7 +220,7 @@ def sampler_kernel_eligible(
         # working set fits VMEM.
         if vocab % 128 != 0 or vocab < 2048:
             return False, False
-        if _sk.padded_vocab(vocab) > 131072:
+        if _sk.padded_vocab(vocab) > _sk.MAX_KERNEL_VOCAB:
             return False, False
     return True, interpret
 
@@ -212,18 +237,21 @@ def dispatch_sample(
     enable_kernel: bool = True,
     allow_interpret: bool = False,
     tp_size: int = 1,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+    return_prob: bool = False,
+) -> tuple[jnp.ndarray, ...]:
     """Kernel-vs-reference dispatch for the sampling epilogue (the
     ``dispatch_ragged_attention`` pattern): the fused Pallas kernel when
     eligible — one HBM logits read, no sorts — else the XLA sort-free
     reference above. Both produce bit-identical samples; A/B with
-    ``VLLM_TPU_DISABLE_SAMPLER_KERNEL=1`` before filing kernel bugs."""
+    ``VLLM_TPU_DISABLE_SAMPLER_KERNEL=1`` before filing kernel bugs.
+    ``return_prob`` (the drawn token's probability, a third output) is the
+    reference path's alone: the kernel returns token ids only."""
     import vllm_tpu.envs as envs
 
     use_kernel, interpret = sampler_kernel_eligible(
         logits.shape[-1],
         needs_gumbel=needs_gumbel,
-        enable_kernel=enable_kernel,
+        enable_kernel=enable_kernel and not return_prob,
         allow_interpret=allow_interpret,
         tp_size=tp_size,
     )
@@ -235,6 +263,7 @@ def dispatch_sample(
             needs_top_k=needs_top_k,
             needs_top_p_min_p=needs_top_p_min_p,
             needs_gumbel=needs_gumbel,
+            return_prob=return_prob,
         )
 
     # Logprob reporting reads the pre-masking distribution; computed here

@@ -104,6 +104,14 @@ class SamplingParams:
     # treats priority > 0 as batch-class for brownout shed/preemption.
     # None = unset (lets header-vs-body precedence detect a body value).
     priority: int | None = None
+    # Generation by diffusion over blocks (models/generation.py): the
+    # commit rule, the passes a block of masks takes (block_length /
+    # denoising_steps positions a pass) and the confidence above which the
+    # dynamic rule commits. None = the model's own defaults, filled in by
+    # the input processor; refused on an autoregressive model.
+    remasking: str | None = None
+    denoising_steps: int | None = None
+    confidence_threshold: float | None = None
     # Extension hook carried through untouched.
     extra_args: dict[str, Any] | None = None
 
@@ -145,6 +153,11 @@ class SamplingParams:
             raise ValueError("repetition_penalty must be > 0")
         if self.deadline_s is not None and self.deadline_s <= 0:
             raise ValueError(f"deadline_s must be > 0, got {self.deadline_s}")
+        from vllm_tpu.models.generation import validate_diffusion_params
+
+        validate_diffusion_params(
+            self.remasking, self.denoising_steps, self.confidence_threshold
+        )
         for label_name in ("slo_class", "tenant_id"):
             label = getattr(self, label_name)
             if label is None:

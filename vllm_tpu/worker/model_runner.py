@@ -79,7 +79,7 @@ class StepHandle:
         self.nan_count = None  # device scalar when VLLM_TPU_NAN_CHECK
         self.prompt_lp = None  # (vals, ids, tok_lp, rank) over [T]
         self.prompt_rows = None  # [(row_i, offset, start, n, prompt_len)]
-        self.moe_counts = None  # [L, E] expert token counts (EPLB)
+        self.moe_counts = None  # [L, E] live tokens routed to each expert
         # Numeric integrity guard (opt-in): per-row "logits not finite"
         # device bool [r_pad]; forced_nan simulates a fully poisoned
         # logits tensor (model_runner.step failpoint, action `nan`).
@@ -93,6 +93,9 @@ class StepHandle:
         # (dispatch) and the wait for its results (finalize), in seconds.
         self.prep_s: float | None = None
         self.wait_s: float | None = None
+        # The step's block passes (generation by diffusion; None for an
+        # autoregressive model).
+        self.block_passes = None
 
 
 def _bucket(value: int, buckets: list[int]) -> int:
@@ -167,7 +170,19 @@ class ModelRunner:
         self._max_r = self.request_buckets[-1]
         self._zero_sampled = jnp.zeros(self._max_r, jnp.int32)
         self._prev_rows: dict[str, int] = {}
-
+        # Generation by diffusion over blocks (models/generation.py): rows
+        # of block_len tokens with a committed flag a position. The tokens
+        # and flags one pass leaves are the next pass's input, so they ride
+        # the feedback slot the sampled tokens ride: _last_sampled is then
+        # [max_r, 2 * block_len], tokens beside flags, by the previous
+        # step's rows.
+        gen = config.model_config.generation or {}
+        self.block_len = int(gen.get("block_length", 0))
+        self.mask_token_id = int(gen.get("mask_token_id", 0))
+        if self.block_len:
+            self._zero_sampled = jnp.zeros(
+                (self._max_r, 2 * self.block_len), jnp.int32
+            )
         # Structured output: device-resident packed-bitmask table, one row
         # per (grammar, state); row 0 = all-ones (unconstrained). Synced
         # from the StructuredOutputManager when new grammars compile; a
@@ -262,6 +277,12 @@ class ModelRunner:
         self._suffix_share = None
         self._suffix_share_dead = False
 
+        # A model that can count the live tokens routed to each expert
+        # (models/mixtral.py) returns the [L, E] counts as a third output
+        # of apply(): EPLB's load statistics, vllm:moe_experts_hit_total.
+        self._moe_counts = getattr(model, "supports_eplb", False)
+        if self._moe_counts:
+            model.return_expert_counts = True
         # EPLB: logical->physical expert indirection + load accumulator.
         self._eplb = getattr(model, "enable_eplb", False)
         self.eplb_state = None
@@ -357,6 +378,7 @@ class ModelRunner:
                 "has_state_slots",
                 "decode_only",
                 "enable_sampler_kernel",
+                "block_len",
             ),
             donate_argnums=(1, 2) if self.draft_model is not None else (1,),
         )
@@ -459,6 +481,14 @@ class ModelRunner:
         self.steps_by_kind = {"decode": 0, "mixed": 0}
         self.step_tokens_by_kind = {"decode": 0, "mixed": 0}
         self.attn_context_tokens_by_kind = {"decode": 0, "mixed": 0}
+        # Generation by diffusion over blocks, cumulative: row-passes by
+        # kind, and the positions denoising passes committed. Mixture of
+        # experts, cumulative: token-expert pairs over live tokens, and
+        # experts that got a live token, both summed over layers.
+        self.diffusion_passes_by_kind = {"denoise": 0, "store": 0}
+        self.diffusion_committed_tokens = 0
+        self.moe_assignments = 0
+        self.moe_experts_hit = 0
         # Seconds spent in first uses of a step program (tracing, lowering
         # and compiling or loading it), beside bucket_compiles, their
         # count; the key of the first use now due, set by _prepare_inputs.
@@ -472,7 +502,7 @@ class ModelRunner:
     def _unpack(self, ibuf, fbuf, counts, prompt_mask, t, r, b, num_spec=0,
                 num_adj=0, num_allow=0, num_prompt_logprobs=0,
                 cascade_blocks=0, has_state_slots=0, decode_only=False,
-                dynamic_decode=False):
+                dynamic_decode=False, block_len=0):
         """Split the two packed host buffers back into metadata pytrees.
 
         One contiguous i32 upload + one f32 upload per step instead of ~12
@@ -502,6 +532,7 @@ class ModelRunner:
             num_common_prefix_blocks=cascade_blocks,
             decode_only=bool(decode_only),
             tp_mesh=self.mesh,
+            block_len=max(block_len, 1),
         )
         top_k = take(r)
         prng_keys = jax.lax.bitcast_convert_type(
@@ -552,6 +583,18 @@ class ModelRunner:
                 take(r),
                 take(r),
             )
+        blk = None
+        if block_len:
+            # Generation by diffusion over blocks, per row: the kind of
+            # pass (0 = a prefill chunk, 1 = denoise, 2 = store), the
+            # commit rule and how many positions a pass commits, the
+            # host's committed flags (read where the row has no feedback),
+            # and the dynamic rule's threshold (last in the f32 buffer).
+            blk = (
+                take(r), take(r), take(r),
+                take(r * block_len).reshape(r, block_len),
+                fbuf[fbuf.shape[0] - r :],
+            )
         adj_vals = (
             fbuf[6 * r : 6 * r + r * num_adj].reshape(r, num_adj)
             if num_adj
@@ -571,7 +614,7 @@ class ModelRunner:
         )
         logit_adjust = (adj_ids, adj_vals, allow_ids, allow_active)
         return (token_ids, md, sampling, feedback, grammar_rows, logit_adjust,
-                draft_next, token_lora, plp_next, spec, dyn)
+                draft_next, token_lora, plp_next, spec, dyn, blk)
 
     def _build_tree_metadata(self, md, spec, t_pad: int, r_pad: int):
         """In-jit tree-verify views (host prep stays the chain layout).
@@ -727,23 +770,49 @@ class ModelRunner:
         has_state_slots: int = 0,
         decode_only: bool = False,
         enable_sampler_kernel: bool = True,
+        block_len: int = 0,
     ):
         (token_ids, md, sampling, feedback, grammar_rows, logit_adjust,
-         draft_next, token_lora, plp_next, spec, dyn) = self._unpack(
+         draft_next, token_lora, plp_next, spec, dyn, blk) = self._unpack(
             ibuf, fbuf, counts, prompt_mask, t_pad, r_pad, b_pad, num_spec,
             num_adj, num_allow, num_prompt_logprobs, cascade_blocks,
-            has_state_slots, decode_only, dynamic_decode,
+            has_state_slots, decode_only, dynamic_decode, block_len,
         )
         # Device-side token feedback (async scheduling): a decode row whose
         # input token was sampled by the still-in-flight previous step reads
         # it straight from that step's device output — the host never waits.
         needs_fb = feedback >= 0
         prev_tok = prev_sampled[jnp.clip(feedback, 0, prev_sampled.shape[0] - 1)]
-        last_pos = jnp.maximum(md.query_start_loc[1:] - 1, 0)  # [r]
-        # Rows without feedback scatter out of bounds (dropped) so padded
-        # rows sharing a last_pos cannot clobber a live row's fed token.
-        idx = jnp.where(needs_fb, last_pos, t_pad)
-        token_ids = token_ids.at[idx].set(prev_tok, mode="drop")
+        if block_len:
+            # The same for a block row: the tokens and committed flags its
+            # previous pass left, still in flight, are this pass's input.
+            # A committed position feeds its token, every other the mask
+            # token's embedding.
+            is_blk = blk[0] > 0
+            blk_idx = jnp.where(
+                is_blk[:, None],
+                md.query_start_loc[:-1, None]
+                + jnp.arange(block_len, dtype=jnp.int32)[None, :],
+                t_pad,
+            )  # [r, B] into [T]; out of range where the row runs no block
+            flat_idx = jnp.clip(blk_idx, 0, t_pad - 1)
+            blk_tok = jnp.where(
+                needs_fb[:, None], prev_tok[:, :block_len], token_ids[flat_idx]
+            )
+            blk_com = jnp.where(
+                needs_fb[:, None], prev_tok[:, block_len:], blk[3]
+            ) > 0
+            token_ids = token_ids.at[blk_idx.reshape(-1)].set(
+                jnp.where(blk_com, blk_tok, self.mask_token_id).reshape(-1),
+                mode="drop",
+            )
+        else:
+            last_pos = jnp.maximum(md.query_start_loc[1:] - 1, 0)  # [r]
+            # Rows without feedback scatter out of bounds (dropped) so
+            # padded rows sharing a last_pos cannot clobber a live row's
+            # fed token.
+            idx = jnp.where(needs_fb, last_pos, t_pad)
+            token_ids = token_ids.at[idx].set(prev_tok, mode="drop")
         if needs_penalties:
             # The fed in-flight token isn't in the host-built counts yet;
             # add it here so async penalties match sync semantics.
@@ -770,7 +839,7 @@ class ModelRunner:
             **mm_kw,
         )
         aux_h = None
-        if self._eplb:
+        if self._moe_counts:
             hidden, kv_cache, moe_counts = out  # counts [L, E]
         elif getattr(self.model, "aux_hidden_layers", None) is not None:
             hidden, kv_cache, aux_h = out  # EAGLE-3 fused conditioning
@@ -855,6 +924,14 @@ class ModelRunner:
                 )
             return (kv_cache, draft_kv, (out_tokens, num_out), None, drafts,
                     None, spec_nan, None, moe_counts, spec_row_bad)
+        if block_len:
+            return self._block_epilogue(
+                params, kv_cache, draft_kv, hidden, sampling, blk,
+                flat_idx, blk_tok, blk_com, moe_counts,
+                block_len=block_len, r_pad=r_pad, needs_top_k=needs_top_k,
+                needs_top_p_min_p=needs_top_p_min_p,
+                needs_gumbel=needs_gumbel, num_logprobs=num_logprobs,
+            )
         last = hidden[md.logits_indices]  # [R, D]
         nan_count = None
         pooled = None
@@ -995,7 +1072,7 @@ class ModelRunner:
                 out_k = self.model.apply(
                     params, kv, tok, md_k, token_lora_slot=row_lora
                 )
-                if self._eplb:
+                if self._moe_counts:
                     hidden_k, kv, counts_k = out_k
                     moe = moe + counts_k
                 else:
@@ -1075,7 +1152,7 @@ class ModelRunner:
                 out_k = self.model.apply(
                     params, kv_cache, tok, md_k, token_lora_slot=row_lora
                 )
-                if self._eplb:
+                if self._moe_counts:
                     hidden_k, kv_cache, counts_k = out_k
                     moe_counts = moe_counts + counts_k
                 else:
@@ -1129,6 +1206,101 @@ class ModelRunner:
             lp = None
         return (kv_cache, draft_kv, sampled, lp, drafts, pooled, nan_count,
                 prompt_lp, moe_counts, row_bad)
+
+    def _block_epilogue(self, params, kv_cache, draft_kv, hidden, sampling,
+                        blk, flat_idx, blk_tok, blk_com, moe_counts,
+                        *, block_len, r_pad, needs_top_k, needs_top_p_min_p,
+                        needs_gumbel, num_logprobs):
+        """The end of a step under generation by diffusion over blocks:
+        every position of every block row is sampled, with the probability
+        of the token drawn under the distribution it was drawn from (its
+        confidence), and the row's rule commits some of the uncommitted
+        ones. Rule, count and threshold are data of the row, so requests
+        under different rules share one program. A store pass commits
+        nothing and leaves the next block open, all masks."""
+        from dataclasses import replace as _replace
+
+        from vllm_tpu.models.generation import REMASKING_RULES
+
+        kind, rule, n_pass, _, threshold = blk
+        b = block_len
+        logits = self.model.compute_logits(
+            params, hidden[flat_idx.reshape(-1)]
+        )  # [R * B, V] f32: the logits AT a position are for its own token
+
+        def rep(a):
+            return jnp.repeat(a, b, axis=0)
+
+        keys = rep(sampling.prng_keys)
+        keys = keys.at[:, 1].add(
+            jnp.tile(jnp.arange(b, dtype=keys.dtype), r_pad)
+        )
+        per_pos = _replace(
+            sampling, temperature=rep(sampling.temperature),
+            top_k=rep(sampling.top_k), top_p=rep(sampling.top_p),
+            min_p=rep(sampling.min_p),
+            presence_penalty=rep(sampling.presence_penalty),
+            frequency_penalty=rep(sampling.frequency_penalty),
+            repetition_penalty=rep(sampling.repetition_penalty),
+            prng_keys=keys,
+        )
+        drawn, raw_logprobs, prob = dispatch_sample(
+            logits, per_pos, needs_penalties=False, needs_top_k=needs_top_k,
+            needs_top_p_min_p=needs_top_p_min_p, needs_gumbel=needs_gumbel,
+            tp_size=self.tp_size, return_prob=True,
+        )
+        drawn = drawn.reshape(r_pad, b)
+        with jax.named_scope("commit"):
+            conf = jnp.where(blk_com, -jnp.inf, prob.reshape(r_pad, b))
+            open_ = ~blk_com
+            n = jnp.minimum(n_pass, jnp.sum(open_, axis=1))[:, None]
+            # sequential: the n leftmost uncommitted positions.
+            leftmost = open_ & (jnp.cumsum(open_, axis=1) <= n)
+            # low_confidence_static: the n most confident, the leftmost
+            # first among equals.
+            pos = jnp.arange(b)
+            ahead = (conf[:, None, :] > conf[:, :, None]) | (
+                (conf[:, None, :] == conf[:, :, None])
+                & (pos[None, None, :] < pos[None, :, None])
+            )
+            surest = open_ & (jnp.sum(ahead, axis=-1) < n)
+            # low_confidence_dynamic: every position above the threshold,
+            # but no fewer than n.
+            high = open_ & (conf > threshold[:, None])
+            dynamic = jnp.where(
+                jnp.sum(high, axis=1, keepdims=True) >= n, high, surest
+            )
+            picked = jnp.select(
+                [rule[:, None] == REMASKING_RULES.index("sequential"),
+                 rule[:, None] == REMASKING_RULES.index(
+                     "low_confidence_static")],
+                [leftmost, surest], dynamic,
+            ) & (kind == 1)[:, None]
+            tokens = jnp.where(picked, drawn, blk_tok)
+            committed = blk_com | picked
+        i32 = jnp.int32
+        # To the host: tokens, flags after the pass, how many were new.
+        out = jnp.concatenate(
+            [tokens, committed.astype(i32),
+             jnp.sum(picked, axis=1, keepdims=True).astype(i32)], axis=1)
+        # To the next pass: after a store pass the next block opens.
+        state = jnp.concatenate(
+            [tokens, (committed & (kind != 2)[:, None]).astype(i32)], axis=1)
+        lp = None
+        if num_logprobs > 0:
+            topk_vals, topk_ids = jax.lax.top_k(raw_logprobs, num_logprobs)
+            drawn_lp = jnp.take_along_axis(
+                raw_logprobs, drawn.reshape(-1, 1), axis=-1)
+            rank = jnp.sum(raw_logprobs > drawn_lp, axis=-1).astype(i32)
+            lp = tuple(x.reshape(r_pad, b, *x.shape[1:]) for x in (
+                topk_vals, topk_ids, drawn_lp[:, 0], rank))
+        nan_count = jnp.isnan(logits).sum() if self._nan_check else None
+        row_bad = (
+            ~jnp.all(jnp.isfinite(logits).reshape(r_pad, -1), axis=-1)
+            if self._guard_numerics else None
+        )
+        return (kv_cache, draft_kv, (out, state), lp, None, None, nan_count,
+                None, moe_counts, row_bad)
 
     def _eagle_drafts(self, params, draft_kv, token_ids, hidden, md,
                       anchor, emitted, draft_next, r_pad):
@@ -1637,9 +1809,12 @@ class ModelRunner:
             so.dynamic_decode and so.decode_claims and not self._is_hybrid
         )
         dyn_len = r * (MAX_DYNAMIC_STOP_IDS + 2) if dynamic else 0
+        # [+ blk_kind(r) + blk_rule(r) + blk_n(r) + blk_committed(r*B)]
+        blen = self.block_len
+        blk_len = r * (3 + blen) if blen else 0
         ibuf = np.zeros(
             4 * t + 7 * r + (r + 1) + 1 + r * b + lp_len + eagle_len
-            + lora_len + plp_len + spec_len + state_len + dyn_len,
+            + lora_len + plp_len + spec_len + state_len + dyn_len + blk_len,
             np.int32,
         )
         token_ids = ibuf[0:t]
@@ -1725,6 +1900,12 @@ class ModelRunner:
                 # floor is 0 — the lane keeps the device contract
                 # explicit for future relaxations of that gate.
                 dyn_min_out[i] = 0
+        if blen:
+            blk_kind = ibuf[o : o + r]; o += r
+            blk_rule = ibuf[o : o + r]; o += r
+            blk_n = ibuf[o : o + r]; o += r
+            blk_committed = ibuf[o : o + r * blen].reshape(r, blen)
+            o += r * blen
         token_req_idx[:] = max(r_pad - 1, 0)
         do_sample = np.zeros(r_pad, bool)
 
@@ -1793,7 +1974,7 @@ class ModelRunner:
             # feedback) — the native fill copied stale values there, which
             # the jitted step overwrites. Draft rows extend past the known
             # prefix by construction and are NOT in-flight feedback.
-            for i in np.nonzero(ends > known_live)[0]:
+            for i in () if blen else np.nonzero(ends > known_live)[0]:
                 if int(i) in draft_rows:
                     continue
                 rid = req_order[i]
@@ -1836,7 +2017,7 @@ class ModelRunner:
                 base = offset + n - 1 - nd
                 sample_pos[i, : nd + 1] = np.arange(base, base + nd + 1)
                 sample_pos[i, nd + 1 :] = base + nd
-            elif start + n > known:
+            elif start + n > known and not blen:
                 # Latest token(s) still in flight (async pipelining): the
                 # input token for this step is fed on device from the
                 # immediately previous step's sampled array. Earlier
@@ -1886,9 +2067,44 @@ class ModelRunner:
         query_start_loc[r_live + 1 :] = offset
 
         # Packed f32 sampling buffer: 6 R-vectors (+ optional adjustment
-        # values); layout must match _unpack.
+        # values) (+ under generation by diffusion the dynamic rule's
+        # threshold, last); layout must match _unpack.
         idx = np.asarray(rows, np.int64)
-        fbuf = np.zeros(6 * r + r * num_adj, np.float32)
+        fbuf = np.zeros(6 * r + r * num_adj + (r if blen else 0), np.float32)
+        if blen:
+            # Rows that run their open block: the host's tokens and flags
+            # where no pass of the request is in flight, else the previous
+            # step's own output, on the device. Prefill chunks sample
+            # nothing; a store pass neither.
+            from vllm_tpu.models.generation import REMASKING_RULES
+
+            do_sample[:] = False
+            block_ctx = 0
+            for i, rid in enumerate(req_order):
+                bp = so.block_passes.get(rid)
+                if bp is None:
+                    continue
+                p = batch.req_states[rid].sampling_params
+                off = int(query_start_loc[i])
+                blk_kind[i] = 2 if bp.store else 1
+                blk_rule[i] = REMASKING_RULES.index(p.remasking)
+                blk_n[i] = blen // p.denoising_steps
+                fbuf[fbuf.shape[0] - r + i] = p.confidence_threshold
+                if bp.tokens is None:
+                    prev_row = self._prev_rows.get(rid, -1)
+                    assert prev_row >= 0, (rid, bp)
+                    feedback[i] = prev_row
+                else:
+                    token_ids[off : off + blen] = bp.tokens
+                    blk_committed[i] = bp.committed
+                do_sample[i] = not bp.store
+                block_ctx += int(seq_lens[i])
+                self.diffusion_passes_by_kind[
+                    "store" if bp.store else "denoise"] += 1
+            # The context a block row reads, once a row.
+            self.attn_context_tokens_by_kind["block"] = (
+                self.attn_context_tokens_by_kind.get("block", 0) + block_ctx
+            )
         if num_adj:
             adj_vals = fbuf[6 * r :].reshape(r, num_adj)
             for i, lst in enumerate(adj_lists):
@@ -1942,6 +2158,12 @@ class ModelRunner:
             # The in-flight token(s) haven't been appended yet; advance the
             # PRNG counter so this step's Gumbel stream doesn't repeat.
             prng[i, 1] += lag
+        if blen:
+            # A position's Gumbel stream is keyed by the request's pass
+            # count and its place in the block, whatever is in flight.
+            for i, rid in enumerate(req_order):
+                bp = so.block_passes.get(rid)
+                prng[i, 1] = 0 if bp is None else bp.seq * blen
         if needs_penalties:
             counts_np, mask_np = self._penalty_tensors(rows, r_pad)
             counts, prompt_mask = jnp.asarray(counts_np), jnp.asarray(mask_np)
@@ -2005,7 +2227,14 @@ class ModelRunner:
             # batches on the general kernel.
             decode_only=decode_only and cascade_blocks == 0,
             enable_sampler_kernel=self.enable_sampler_kernel,
+            block_len=blen,
         )
+        num_experts = getattr(self.model, "num_experts", 0)
+        if num_experts and hasattr(self.model, "top_k"):
+            # Token-expert pairs over the step's live tokens, every layer.
+            self.moe_assignments += (
+                t_live * self.model.top_k * self.model.num_layers
+            )
         self.step_launches += 1
         if flags["decode_only"]:
             self.decode_only_launches += 1
@@ -2026,11 +2255,17 @@ class ModelRunner:
             use_kernel, _ = sampler_kernel_eligible(
                 self.model.vocab_size,
                 needs_gumbel=True,
-                enable_kernel=self.enable_sampler_kernel,
+                # A block pass needs the drawn token's probability, which
+                # the XLA epilogue alone returns: every position of every
+                # sampling row is a fallback row.
+                enable_kernel=self.enable_sampler_kernel and not blen,
                 allow_interpret=True,
                 tp_size=self.tp_size,
             )
-            if dynamic:
+            if blen:
+                self.sampler_fallback_rows += int(
+                    np.sum(nongreedy & do_sample[:r_live])) * blen
+            elif dynamic:
                 # Realized step count is unknown until finalize; stash
                 # the routing decision for deferred accounting.
                 self._dyn_sampler_acct = (use_kernel, int(np.sum(nongreedy)))
@@ -2373,7 +2608,14 @@ class ModelRunner:
                 dispatch_s, len(req_order), self.input_batch.num_reqs)
         is_spec = flags["num_spec"] > 0
         is_dynamic = bool(flags.get("dynamic_decode"))
-        if not is_spec:
+        if self.block_len:
+            # Beside what goes to the host, the next pass's tokens and
+            # flags, by this step's rows.
+            sampled, state = sampled
+            self._last_sampled = jnp.pad(
+                state, ((0, self._max_r - state.shape[0]), (0, 0)))
+            self._prev_rows = {rid: i for i, rid in enumerate(req_order)}
+        elif not is_spec:
             # Multi-step decode returns [R, K]; the feedback source for the
             # next step is the LAST sampled column. Dynamic decode returns
             # (out_tokens [R, Kmax], num_out [R]): gather each row's last
@@ -2414,6 +2656,8 @@ class ModelRunner:
                 x.copy_to_host_async()
         if row_bad is not None:
             row_bad.copy_to_host_async()
+        if moe_counts is not None:
+            moe_counts.copy_to_host_async()
         handle = StepHandle(
             req_order=req_order, do_sample=do_sample, sampled=sampled, lp=lp,
             row_states=[self.input_batch.req_states[r] for r in req_order],
@@ -2435,6 +2679,7 @@ class ModelRunner:
         handle.failed_loads = failed_loads
         handle.row_bad = row_bad
         handle.forced_nan = forced_nan
+        handle.block_passes = so.block_passes if self.block_len else None
         return handle
 
     def finalize(self, handle: "StepHandle") -> ModelRunnerOutput:
@@ -2485,16 +2730,23 @@ class ModelRunner:
             bad_rows = np.asarray(jax.device_get(handle.row_bad))
         if handle.forced_nan:
             bad_rows = np.ones(len(req_order), bool)
-        if handle.moe_counts is not None and self.eplb_state is not None:
-            self.eplb_state.update(
-                np.asarray(jax.device_get(handle.moe_counts))
-            )
-            if self.eplb_state.due:
-                self._rebalance_experts()
+        if handle.moe_counts is not None:
+            moe_counts = np.asarray(jax.device_get(handle.moe_counts))
+            # Experts with a live token, a layer (summed over the
+            # iterations of a multi-step decode, where an expert hit in
+            # several of them counts once).
+            self.moe_experts_hit += int(np.count_nonzero(moe_counts))
+            if self.eplb_state is not None:
+                self.eplb_state.update(moe_counts)
+                if self.eplb_state.due:
+                    self._rebalance_experts()
 
         out = ModelRunnerOutput(
             req_ids=req_order, invalid_req_ids=handle.failed_loads
         )
+        if handle.block_passes is not None:
+            return self._finalize_block_rows(
+                handle, out, sampled_np, lp_np, bad_rows)
         if handle.prompt_lp is not None and handle.prompt_rows:
             for (i, row, off, start, count, k) in handle.prompt_rows:
                 rid = req_order[i]
@@ -2640,6 +2892,46 @@ class ModelRunner:
                 len(out.numeric_error_req_ids),
                 sorted(out.numeric_error_req_ids),
             )
+        if self.watchdog is not None:
+            self.watchdog.disarm()
+        return out
+
+    def _finalize_block_rows(self, handle, out, sampled_np, lp_np,
+                             bad_rows) -> ModelRunnerOutput:
+        """Generation by diffusion over blocks: a block row's result is
+        its tokens and committed flags after the pass (and per position
+        the logprobs of this pass, which are a token's if this pass
+        committed it); the scheduler folds them into its view of the
+        block. Nothing is appended to the batch's token rows: a resumed
+        request brings its tokens with it."""
+        b = self.block_len
+        for i, rid in enumerate(handle.req_order):
+            out.sampled_token_ids.append([])
+            bp = handle.block_passes.get(rid)
+            if bp is None or (
+                self.input_batch.req_states.get(rid) is not handle.row_states[i]
+            ):
+                continue
+            if bad_rows is not None and bad_rows[i]:
+                out.numeric_error_req_ids.add(rid)
+                self.numeric_guard_trips["nan"] = (
+                    self.numeric_guard_trips.get("nan", 0) + 1
+                )
+                continue
+            row = sampled_np[i]
+            lps = None
+            if lp_np is not None and not bp.store:
+                k = int(handle.row_states[i].sampling_params.logprobs or 0)
+                vals, ids, drawn_lp, rank = lp_np
+                lps = [
+                    (ids[i, j, :k].tolist(), vals[i, j, :k].tolist(),
+                     float(drawn_lp[i, j]), int(rank[i, j]))
+                    for j in range(b)
+                ] if k else None
+            out.block_results[rid] = (
+                row[:b].tolist(), (row[b : 2 * b] > 0).tolist(), lps)
+            self.diffusion_committed_tokens += int(row[2 * b])
+            self.launch_sampled_tokens += int(row[2 * b])
         if self.watchdog is not None:
             self.watchdog.disarm()
         return out
@@ -2980,11 +3272,14 @@ class ModelRunner:
         """
         sched = self.config.scheduler_config
         t_max = min(sched.max_num_batched_tokens, sched.max_model_len)
-        r = min(sched.max_num_seqs, t_max)
-        first = t_max - (r - 1)
+        # A decoding row is one token, or one block under generation by
+        # diffusion.
+        row = self.block_len or 1
+        r = min(sched.max_num_seqs, t_max // row)
+        first = t_max - (r - 1) * row
         so = _dummy_scheduler_output(
             first, num_reqs=r, max_blocks=self.max_blocks_per_req,
-            worst_case_sampling=True,
+            worst_case_sampling=True, block_len=self.block_len,
         )
         try:
             self._update_states(so)
@@ -3065,15 +3360,25 @@ def _dummy_scheduler_output(
     num_reqs: int = 1,
     max_blocks: int = 1,
     worst_case_sampling: bool = False,
+    block_len: int = 0,
 ) -> SchedulerOutput:
     """Synthetic batch: request 0 carries ``num_tokens`` prompt tokens (and
     ``max_blocks`` block-table entries), requests 1..n-1 one token each —
     the mixed chunked-prefill shape that maxes every bucket dimension at
-    once for memory profiling."""
-    from vllm_tpu.core.sched_output import NewRequestData
+    once for memory profiling. With ``block_len`` (generation by diffusion)
+    requests 1..n-1 each run a denoising pass over a block of masks."""
+    from vllm_tpu.core.sched_output import BlockPass, NewRequestData
     from vllm_tpu.sampling_params import SamplingParams
 
-    if worst_case_sampling:
+    if block_len:
+        # Penalties are refused under this mechanism; the dynamic rule
+        # reads every per-row lane.
+        sp = SamplingParams(
+            max_tokens=1, temperature=1.0, top_k=8, top_p=0.9,
+            remasking="low_confidence_dynamic", denoising_steps=1,
+            confidence_threshold=0.9,
+        )
+    elif worst_case_sampling:
         sp = SamplingParams(
             max_tokens=1, temperature=1.0, top_k=8, top_p=0.9,
             repetition_penalty=1.1,
@@ -3082,8 +3387,9 @@ def _dummy_scheduler_output(
         sp = SamplingParams(max_tokens=1)
     reqs = []
     sched: dict[str, int] = {}
+    passes: dict[str, BlockPass] = {}
     for i in range(num_reqs):
-        n = num_tokens if i == 0 else 1
+        n = num_tokens if i == 0 else max(block_len, 1)
         rid = "__profile__" if num_reqs == 1 else f"__profile_{i}__"
         reqs.append(
             NewRequestData(
@@ -3095,8 +3401,13 @@ def _dummy_scheduler_output(
             )
         )
         sched[rid] = n
+        if block_len and i:
+            passes[rid] = BlockPass(
+                store=False, seq=0, tokens=[1] * n, committed=[False] * n
+            )
     return SchedulerOutput(
         scheduled_new_reqs=reqs,
         num_scheduled_tokens=sched,
-        total_num_scheduled_tokens=num_tokens + num_reqs - 1,
+        total_num_scheduled_tokens=sum(sched.values()),
+        block_passes=passes,
     )

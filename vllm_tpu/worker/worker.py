@@ -20,7 +20,8 @@ from vllm_tpu.config import EngineConfig
 from vllm_tpu.core.kv_cache_utils import get_kv_cache_config_from_specs
 from vllm_tpu.core.sched_output import ModelRunnerOutput, SchedulerOutput
 from vllm_tpu.logger import init_logger
-from vllm_tpu.models.registry import get_model_class
+# load_hf_config lives beside the registry; its importers find it here too.
+from vllm_tpu.models.registry import get_model_class, load_hf_config  # noqa: F401
 from vllm_tpu.utils.compile_cache import enable_compilation_cache
 from vllm_tpu.worker.model_runner import ModelRunner
 
@@ -42,29 +43,6 @@ def _per_device_param_bytes(params, device) -> int:
         else:
             total += leaf.nbytes
     return total
-
-
-def load_hf_config(model_config) -> Any:
-    if model_config.hf_config is not None:
-        return model_config.hf_config
-    if model_config.model.endswith(".gguf"):
-        from vllm_tpu.models.gguf import config_from_gguf
-
-        cfg = config_from_gguf(model_config.model)
-        model_config.hf_config = cfg
-        return cfg
-    from transformers import AutoConfig
-
-    cfg = AutoConfig.from_pretrained(
-        model_config.model,
-        revision=model_config.revision,
-        trust_remote_code=model_config.trust_remote_code,
-    )
-    if model_config.hf_overrides:
-        for k, v in model_config.hf_overrides.items():
-            setattr(cfg, k, v)
-    model_config.hf_config = cfg
-    return cfg
 
 
 class Worker:
