@@ -211,6 +211,38 @@ def test_requests_under_different_rules_share_a_step(llm, weights):
     assert runner.diffusion_committed_tokens > 0 and runner.moe_experts_hit > 0
 
 
+def test_sampled_block_passes_are_counted_where_the_sampler_ran(tmp_path):
+    """A sampling block pass goes to the fused kernel (here in interpret
+    mode) with the drawn token's probability and the count of usable
+    positions, and counts as a kernel launch; with the kernel turned off
+    every position of its sampling rows is a fallback row. Both draw the
+    same tokens: at this width the two paths agree bit for bit."""
+    from vllm_tpu import SamplingParams
+
+    prompts = [{"prompt_token_ids": prompt_of(n, 5)} for n in (6, 13, 9)]
+    sp = SamplingParams(temperature=0.8, top_p=0.9, seed=11, ignore_eos=True, max_tokens=10,
+                        remasking="low_confidence_static", denoising_steps=2)
+    tokens = {}
+    for enable in (True, False):
+        engine = make_llm(tmp_path / str(enable), enable_sampler_kernel=enable)
+        try:
+            runner = engine.llm_engine.engine_core.engine_core.executor.worker.runner
+            outs = engine.generate(prompts, sp)
+            tokens[enable] = [list(o.outputs[0].token_ids) for o in outs]
+            denoise = runner.diffusion_passes_by_kind["denoise"]
+            assert denoise > 0 and runner.diffusion_committed_tokens >= 30
+            if enable:
+                assert runner.sampler_kernel_launches > 0
+                assert runner.sampler_fallback_rows == 0
+            else:
+                assert runner.sampler_kernel_launches == 0
+                assert runner.sampler_fallback_rows == denoise * B
+        finally:
+            engine.shutdown()
+    assert tokens[True] == tokens[False]
+    assert len({tuple(t) for t in tokens[True]}) == 3
+
+
 def test_the_second_ask_of_a_prompt_hits_the_prefix_cache(llm, weights):
     p = prompt_of(41, 3)
     first = llm.generate([{"prompt_token_ids": p}], params_for("low_confidence_static", 2))[0]
@@ -425,11 +457,94 @@ def test_an_all_greedy_batch_returns_the_softmax_of_its_argmax():
     np.testing.assert_array_equal(np.asarray(drawn), np.asarray(jnp.argmax(logits, -1)))
 
 
+@pytest.mark.parametrize("enable_sampler_kernel", [True, False], ids=["kernel", "xla"])
+def test_the_block_pass_commits_what_sampling_every_position_commits(enable_sampler_kernel):
+    """The epilogue tells the sampler how many positions the live rows
+    hold (the kernel, here in interpret mode, returns zeros past them, and
+    with them the drawn tokens' probabilities). What it commits is what
+    the XLA epilogue over every position and the rules, worked out here
+    row by row, commit. (PR 30 also tried the usable positions FIRST, a
+    permutation: 0.3 ms a step on the chip, left out.)"""
+    import types
+
+    from tests.sample.test_sampler_kernel import _make_md
+    from vllm_tpu.models.generation import REMASKING_RULES
+    from vllm_tpu.sample.sampler import sample
+    from vllm_tpu.worker.model_runner import ModelRunner
+
+    r, t, d, v = 8, 40, 16, 256
+    rng = np.random.default_rng(11)
+    head = jnp.asarray(rng.standard_normal((d, v)), jnp.float32)
+    hidden = jnp.asarray(rng.standard_normal((t, d)) * 2, jnp.float32)
+    runner = types.SimpleNamespace(
+        model=types.SimpleNamespace(compute_logits=lambda params, h: h @ params),
+        tp_size=1, _nan_check=False, _guard_numerics=True)
+    md = _make_md(r, v, temperature=[0.7, 0.0, 1.0, 0.8, 0.9, 0.7, 1.1, 0.6],
+                  top_p=[0.9, 1.0, 0.8, 0.9, 1.0, 0.9, 0.95, 0.9])
+    # Rows: a prefill chunk, denoising rows at every stage of a block, store
+    # passes, a padding row; two commit rules.
+    kind = np.asarray([0, 1, 1, 2, 1, 1, 2, 0], np.int32)
+    rule = np.asarray([REMASKING_RULES.index(n) for n in (
+        ["low_confidence_static", "sequential"] * 4)], np.int32)
+    n_pass = np.asarray([2, 1, 2, 2, 2, 1, 2, 2], np.int32)
+    blk_com = np.asarray([[0, 0, 0, 0], [0, 0, 0, 0], [1, 0, 0, 1], [1, 1, 1, 1],
+                          [0, 1, 0, 0], [1, 1, 0, 1], [1, 1, 1, 1], [0, 0, 0, 0]], bool)
+    blk_tok = rng.integers(0, v, (r, B)).astype(np.int32)
+    flat_idx = (np.arange(r)[:, None] * B + np.arange(B)[None, :]).astype(np.int32)
+    blk = tuple(jnp.asarray(a) for a in (
+        kind, rule, n_pass, blk_com.astype(np.int32), np.full(r, 0.9, np.float32)))
+    out = ModelRunner._block_epilogue(
+        runner, head, None, None, hidden, md, blk, jnp.asarray(flat_idx),
+        jnp.asarray(blk_tok), jnp.asarray(blk_com), None, block_len=B, r_pad=r,
+        needs_top_k=False, needs_top_p_min_p=True, needs_gumbel=True,
+        num_logprobs=2, enable_sampler_kernel=enable_sampler_kernel,
+        num_live=jnp.asarray(7, jnp.int32))
+    (result, state), lp = out[2], out[3]
+    result, state = np.asarray(result), np.asarray(state)
+
+    # Every position of every row, padding included, on the XLA path.
+    keys = np.repeat(np.asarray(md.prng_keys), B, axis=0)
+    keys[:, 1] += np.tile(np.arange(B, dtype=keys.dtype), r)
+    per_pos = _make_md(r * B, v, temperature=np.repeat(md.temperature, B),
+                       top_p=np.repeat(md.top_p, B), seeds=keys)
+    logits = hidden[flat_idx.reshape(-1)] @ head
+    drawn, logprobs, prob = (np.asarray(a) for a in sample(
+        logits, per_pos, needs_top_k=False, return_prob=True))
+    drawn, prob = drawn.reshape(r, B), prob.reshape(r, B)
+    usable = 0
+    for i in range(r):
+        want_tok, want_com = blk_tok[i].copy(), blk_com[i].copy()
+        if kind[i] == 1:
+            open_ = np.flatnonzero(~blk_com[i])
+            usable += len(open_)
+            if rule[i] == REMASKING_RULES.index("sequential"):
+                picked = open_[:n_pass[i]]
+            else:  # the surest first, the leftmost among equals
+                picked = open_[np.argsort(-prob[i, open_], kind="stable")[:n_pass[i]]]
+            want_tok[picked], want_com[picked] = drawn[i, picked], True
+            assert result[i, 2 * B] == len(picked) > 0
+            # The logprobs of a position this pass committed are its own.
+            for j in picked:
+                p = i * B + j
+                assert float(lp[2][i, j]) == logprobs[p, drawn[i, j]]
+                np.testing.assert_array_equal(
+                    np.asarray(lp[1][i, j]), np.argsort(-logprobs[p], kind="stable")[:2])
+        else:
+            assert result[i, 2 * B] == 0
+        np.testing.assert_array_equal(result[i, :B], want_tok)
+        np.testing.assert_array_equal(result[i, B:2 * B] > 0, want_com)
+        np.testing.assert_array_equal(state[i, :B], want_tok)
+        np.testing.assert_array_equal(state[i, B:] > 0, want_com & (kind[i] != 2))
+    assert usable == 4 + 2 + 3 + 1  # of the 28 positions of the 7 live rows
+    assert not np.asarray(out[9]).any()  # no row flagged by the numeric guard
+
+
 def test_past_the_kernels_bound_the_epilogue_pads_to_noise_tiles_and_still_cuts_the_nucleus():
-    """151936 tokens pad to 262144 lanes as a power of two, over the fused
-    kernel's bound, so no kernel ever shares the epilogue's sums there: it
-    computes at 155648 lanes with sums XLA may fuse, and the nucleus is
-    still the smallest set of largest weights that reaches top_p."""
+    """151936 tokens would pad to 262144 lanes as a power of two, so past
+    131072 lanes the epilogue computes at whole noise tiles, 155648, the
+    fused kernel (since PR 30) and the XLA path alike; the XLA path sums in
+    one pass XLA may fuse, and the nucleus is still the smallest set of
+    largest weights that reaches top_p."""
     from vllm_tpu.ops import sampler_kernel as sk
     from vllm_tpu.sample.sampler import _mask_top_p_min_p, _sum_fn
 

@@ -35,6 +35,15 @@ from vllm_tpu.sample.sampler import (
 )
 
 V_ODD = 333  # exercises the -inf pad up to the pow2 width (512)
+# Past the widest power of two: 33 noise tiles (135168 lanes), the sums in
+# the kernel's tile order, 2763 lanes of -inf pad.
+V_WIDE = _sk.MAX_POW2_WIDTH + 333
+
+
+def _kernel_sum(vocab):
+    """The order of summation the kernel uses at this vocabulary's width,
+    for the reference to share (the halving tree where it is the default)."""
+    return _sk.kernel_sum_fn(_sk.compute_width(vocab))
 
 
 @pytest.fixture(autouse=True)
@@ -120,31 +129,38 @@ def _mixed_batch(rng, vocab, with_penalties):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("vocab", [V_ODD, V_WIDE])
+@pytest.mark.parametrize("return_prob", [False, True])
 @pytest.mark.parametrize("needs_penalties", [False, True])
 @pytest.mark.parametrize("needs_top_k", [False, True])
 @pytest.mark.parametrize("needs_top_p_min_p", [False, True])
 def test_kernel_matches_reference(
-    needs_penalties, needs_top_k, needs_top_p_min_p
+    needs_penalties, needs_top_k, needs_top_p_min_p, return_prob, vocab
 ):
     rng = np.random.default_rng(
         7 + needs_penalties * 4 + needs_top_k * 2 + needs_top_p_min_p
     )
-    logits, md = _mixed_batch(rng, V_ODD, needs_penalties)
+    logits, md = _mixed_batch(rng, vocab, needs_penalties)
     flags = dict(
         needs_penalties=needs_penalties,
         needs_top_k=needs_top_k,
         needs_top_p_min_p=needs_top_p_min_p,
         needs_gumbel=True,
+        return_prob=return_prob,
     )
-    want, want_lp = sample(logits, md, **flags)
+    want = sample(logits, md, sum_fn=_kernel_sum(vocab), **flags)
     use_kernel, interpret = sampler_kernel_eligible(
-        V_ODD, needs_gumbel=True, allow_interpret=True
+        vocab, needs_gumbel=True, allow_interpret=True
     )
     assert use_kernel and interpret, "conftest arms interpret mode"
-    got, got_lp = dispatch_sample(logits, md, allow_interpret=True, **flags)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # Raw logprobs are pre-masking in both paths.
-    np.testing.assert_array_equal(np.asarray(got_lp), np.asarray(want_lp))
+    got = dispatch_sample(logits, md, allow_interpret=True, **flags)
+    # Tokens, the raw logprobs (pre-masking in both paths) and, asked for,
+    # the drawn tokens' probabilities: bit for bit.
+    assert len(got) == len(want) == 2 + return_prob
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    if return_prob:
+        assert np.all(np.asarray(got[2]) > 0)
 
 
 def _pack_params(md: SamplingMetadata):
@@ -169,23 +185,97 @@ def _pack_params(md: SamplingMetadata):
     return params_f, params_i
 
 
-@pytest.mark.parametrize("row_block,logits_tile", [(2, 256), (8, 128),
-                                                   (3, 384)])
-def test_kernel_block_shape_invariance(row_block, logits_tile):
-    """Tiling must not change a single sampled token — the DMA tile loop
-    and row padding are pure layout."""
-    rng = np.random.default_rng(17)
-    logits, md = _mixed_batch(rng, V_ODD, True)
+def _fused(logits, md, **kw):
     params_f, params_i = _pack_params(md)
-    counts = md.output_token_counts.astype(jnp.int32)
-    pmask = md.prompt_token_mask.astype(jnp.int8)
-    want, _ = sample(logits, md, needs_penalties=True)
-    got = _sk.fused_sample(
-        logits, params_f, params_i, counts, pmask,
+    return _sk.fused_sample(
+        logits, params_f, params_i,
+        md.output_token_counts.astype(jnp.int32),
+        md.prompt_token_mask.astype(jnp.int8),
         needs_penalties=True, needs_top_k=True, needs_top_p_min_p=True,
-        row_block=row_block, logits_tile=logits_tile, interpret=True,
+        interpret=True, **kw,
     )
+
+
+@pytest.mark.parametrize("row_block,logits_tile,vocab", [
+    (2, 256, V_ODD), (8, 128, V_ODD), (3, 384, V_ODD),
+    # 17 and 5 tiles a row, the last one partial.
+    (2, 8192, V_WIDE), (8, 32768, V_WIDE),
+])
+def test_kernel_block_shape_invariance(row_block, logits_tile, vocab):
+    """Tiling must not change a single sampled token (or its probability)
+    — the DMA tile loop and row padding are pure layout."""
+    rng = np.random.default_rng(17)
+    logits, md = _mixed_batch(rng, vocab, True)
+    want, _, want_p = sample(
+        logits, md, needs_penalties=True, return_prob=True,
+        sum_fn=_kernel_sum(vocab),
+    )
+    got = _fused(logits, md, row_block=row_block, logits_tile=logits_tile)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got, got_p = _fused(logits, md, row_block=row_block,
+                        logits_tile=logits_tile, return_prob=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
+
+
+@pytest.mark.parametrize("num_active", [0, 1, 2, 3, 5, 6])
+def test_rows_past_the_active_count_are_zeros(num_active):
+    """Nobody reads the rows at or past the count: a row block wholly past
+    it is skipped, the one that straddles it is cut, and the rows before it
+    are what the full call gives."""
+    rng = np.random.default_rng(19)
+    logits, md = _mixed_batch(rng, V_ODD, True)
+    full, full_p = _fused(logits, md, row_block=2, logits_tile=256,
+                          return_prob=True)
+    assert np.all(np.asarray(full_p) > 0)
+    got, got_p = _fused(logits, md, row_block=2, logits_tile=256,
+                        return_prob=True,
+                        num_active=jnp.asarray(num_active, jnp.int32))
+    live = np.arange(6) < num_active
+    np.testing.assert_array_equal(
+        np.asarray(got), np.where(live, np.asarray(full), 0))
+    np.testing.assert_array_equal(
+        np.asarray(got_p), np.where(live, np.asarray(full_p), 0))
+    only = _fused(logits, md, row_block=2, logits_tile=256,
+                  num_active=jnp.asarray(num_active, jnp.int32))
+    np.testing.assert_array_equal(np.asarray(only), np.asarray(got))
+
+
+def test_dispatch_hands_the_active_count_to_the_kernel():
+    rng = np.random.default_rng(21)
+    logits, md = _mixed_batch(rng, V_ODD, False)
+    full = dispatch_sample(logits, md, allow_interpret=True, return_prob=True)
+    got = dispatch_sample(logits, md, allow_interpret=True, return_prob=True,
+                          num_active=jnp.asarray(3, jnp.int32))
+    np.testing.assert_array_equal(
+        np.asarray(got[0]), np.where(np.arange(6) < 3, np.asarray(full[0]), 0))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(full[1]))
+    np.testing.assert_array_equal(
+        np.asarray(got[2]), np.where(np.arange(6) < 3, np.asarray(full[2]), 0))
+    # The reference path samples every row all the same.
+    ref = dispatch_sample(logits, md, enable_kernel=False, return_prob=True,
+                          num_active=jnp.asarray(3, jnp.int32))
+    for g, w in zip(ref, full):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_wide_sums_one_fixed_order_close_to_the_fused_pass():
+    """``tiled_row_sum`` is the wide widths' fixed order: the noise tiles
+    added lane for lane, then the halving tree. The XLA epilogue's
+    ``plain_sum`` there differs from it in the last bits at most."""
+    rng = np.random.default_rng(61)
+    x = jnp.asarray(rng.random((3, 5 * 4096)), jnp.float32)
+    tiles = np.asarray(x).reshape(3, 5, 4096)
+    acc = tiles[:, 0]
+    for i in range(1, 5):
+        acc = acc + tiles[:, i]
+    np.testing.assert_array_equal(
+        np.asarray(_sk.tiled_row_sum(x)), np.asarray(_sk.row_sum(acc)))
+    np.testing.assert_allclose(
+        np.asarray(_sk.tiled_row_sum(x)), np.asarray(_sk.plain_sum(x)),
+        rtol=1e-6)
+    assert _sk.kernel_sum_fn(131072) is _sk.row_sum
+    assert _sk.kernel_sum_fn(135168) is _sk.tiled_row_sum
 
 
 def test_seeded_determinism_and_row_position_invariance():
@@ -420,7 +510,21 @@ def test_mosaic_vocab_rules(monkeypatch):
     assert ok(131072) == (True, False)
     assert ok(333) == (False, False)  # not 128-aligned
     assert ok(1024) == (False, False)  # below the floor
-    assert ok(131200) == (False, False)  # pads past the ceiling
+    # Past the widest power of two: whole noise tiles, up to the ceiling.
+    assert ok(131200) == (True, False)
+    assert ok(151936) == (True, False)  # the Qwen family's
+    assert ok(_sk.MAX_KERNEL_WIDTH) == (True, False)
+    assert ok(_sk.MAX_KERNEL_WIDTH + 128) == (False, False)  # past it
+
+
+def test_the_ceiling_is_the_vmem_estimate_on_the_least_vmem():
+    assert _sk.compute_width(151936) == 155648 == 38 * 4096
+    assert _sk.compute_width(128256) == 131072  # as before the wide widths
+    assert _sk.compute_width(V_ODD) == 512
+    widest = _sk.MAX_KERNEL_WIDTH
+    assert widest % 4096 == 0 and widest >= 155648
+    assert _sk.vmem_estimate(8, widest) <= _sk._LEAST_VMEM_BYTES
+    assert _sk.vmem_estimate(8, widest + 4096) > _sk._LEAST_VMEM_BYTES
 
 
 def test_dispatch_fallback_matches_reference():

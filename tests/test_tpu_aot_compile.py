@@ -149,26 +149,37 @@ def test_ragged_kernel_compiles_with_own_block_choice(
 
 
 @pytest.mark.parametrize(
-    "penalties,top_k,top_p",
-    [(False, False, False), (False, True, True), (True, True, True)],
-    ids=["plain", "top_k_top_p", "penalties"],
+    "r,v,penalties,top_k,top_p,return_prob,active",
+    [(128, V, False, False, False, False, False),
+     (128, V, False, True, True, False, False),
+     (128, V, True, True, True, False, False),
+     # sdar30b.chat's block pass: 128 rows x 4 positions at the Qwen
+     # family's vocabulary (38 noise tiles, no power of two), the drawn
+     # token's probability returned, the usable positions counted.
+     (512, 151936, False, False, True, True, True),
+     (512, 151936, True, True, True, True, True),
+     # mistral7b.chat's decode step with its live rows counted.
+     (128, 32768, False, False, True, False, True)],
+    ids=["plain", "top_k_top_p", "penalties", "wide_prob_active",
+         "wide_prob_active_penalties", "narrow_active"],
 )
-def test_sampler_kernel_compiles(one_chip, penalties, top_k, top_p):
+def test_sampler_kernel_compiles(
+    one_chip, r, v, penalties, top_k, top_p, return_prob, active
+):
     from vllm_tpu.ops.sampler_kernel import fused_sample
-
-    r = 128
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    state = (r, V) if penalties else (1, 128)
+    state = (r, v) if penalties else (1, 128)
     fn = functools.partial(
         fused_sample, needs_penalties=penalties, needs_top_k=top_k,
-        needs_top_p_min_p=top_p,
+        needs_top_p_min_p=top_p, return_prob=return_prob,
     )
     jax.jit(fn).lower(
-        s((r, V), jnp.float32), s((r, 128), jnp.float32),
+        s((r, v), jnp.float32), s((r, 128), jnp.float32),
         s((r, 128), jnp.int32), s(state, jnp.int32), s(state, jnp.int8),
+        *([s((), jnp.int32)] if active else []),
     ).compile()
 
 
@@ -410,3 +421,50 @@ def test_block_diffusion_step_compiles_and_reads_each_weight_in_place(
                                or "dynamic-slice" in name)
     ]
     assert not moved, f"the layer scan copies a layer's weight: {moved}"
+    if (t, r) != (512, 128):
+        return
+
+    # The end of that step, the runner's own: the head over the request
+    # bucket's 128 rows x 4 positions and the sampler. At a vocabulary of
+    # 151936 the fused kernel takes them, the drawn token's probability
+    # with them; before, the XLA epilogue did, a ``while`` of 31 bisection
+    # passes over two [512, 155648] arrays in HBM.
+    from vllm_tpu.sample.sampler import SamplingMetadata
+    from vllm_tpu.worker.model_runner import ModelRunner
+
+    b = SDAR["block_length"]
+    runner = types.SimpleNamespace(
+        model=model, tp_size=1, _nan_check=False, _guard_numerics=True)
+    per_row = (jnp.float32, jnp.int32) + (jnp.float32,) * 5
+    sampling = SamplingMetadata(
+        *(s((r,), d) for d in per_row), prng_keys=s((r, 2), jnp.uint32),
+        output_token_counts=s((1, 128), jnp.int32),
+        prompt_token_mask=s((1, 128), jnp.bool_),
+    )
+    blk = (s((r,), jnp.int32),) * 3 + (s((r, b), jnp.int32), s((r,), jnp.float32))
+
+    def epilogue(enable_sampler_kernel):
+        def fn(params, hidden, sampling, blk, flat_idx, blk_tok, blk_com, live):
+            out = ModelRunner._block_epilogue(
+                runner, params, None, None, hidden, sampling, blk, flat_idx,
+                blk_tok, blk_com, None, block_len=b, r_pad=r,
+                needs_top_k=False, needs_top_p_min_p=True, needs_gumbel=True,
+                num_logprobs=0, enable_sampler_kernel=enable_sampler_kernel,
+                num_live=live,
+            )
+            return out[2], out[9]
+
+        return jax.jit(fn).lower(
+            params, s((t, model.hidden_size), jnp.bfloat16), sampling, blk,
+            s((r, b), jnp.int32), s((r, b), jnp.int32), s((r, b), jnp.bool_),
+            s((), jnp.int32),
+        ).compile().as_text()
+
+    wide = f"[{r * b},155648]"
+    text = epilogue(True)
+    assert "fused_sampler_kernel" in text
+    assert wide not in text and "while(" not in text
+    # The check can tell: with the kernel turned off the loop is there.
+    text = epilogue(False)
+    assert "fused_sampler_kernel" not in text
+    assert wide in text and "while(" in text

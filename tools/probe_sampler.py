@@ -2,12 +2,16 @@
 (``ops/sampler_kernel.py``) vs the XLA reference epilogue
 (``sample/sampler.py:sample``).
 
-On TPU: sweeps batch x vocab at serving shapes and, per point, the
-kernel's (row_block, logits_tile) grid — wall-clock per call plus the
-``metrics/op_split.py`` device-time attribution (the "sampler" phase
-split the bench JSON reports), so a probe row is directly comparable to
-a bench run. The A/B that tunes the dispatch defaults and the README's
-"Sampling performance" numbers.
+On TPU: the benchmark's two sampling shapes, (512, 151936) and
+(128, 32768), through ``dispatch_sample`` on both paths with the same
+seeds — tokens equal, the drawn tokens' probabilities within 1e-6
+relative, both times, and the kernel's time against the count of active
+rows (one JSON line a point, and ``chiprun_out/probe_sampler.json``);
+exits non-zero on a disagreement. This is the on-chip check of the
+sampler that the benchmark's ``correct`` does not make. With ``--sweep``:
+batch x vocab at serving shapes and, per point, the kernel's (row_block,
+logits_tile) grid — wall-clock per call plus the ``metrics/op_split.py``
+device-time attribution, the A/B that tunes the dispatch defaults.
 
 On CPU (or ``--smoke``): the kernel runs in Pallas interpret mode at a
 tiny shape across the block-size sweep points and must be BIT-EXACT
@@ -83,6 +87,81 @@ def _bench(name, f, logits, md):
         best = min(best, time.monotonic() - t0)
     print(f"{name:36s} {best * 1e6:9.1f} us/call")
     return out, best
+
+
+def _per_call(f, *args, calls=20):
+    """Seconds a call, the device kept busy: ``calls`` enqueued back to
+    back, one wait at the end."""
+    jax.block_until_ready(f(*args))
+    t0 = time.monotonic()
+    for _ in range(calls):
+        out = f(*args)
+    jax.block_until_ready(out)
+    return (time.monotonic() - t0) / calls, out
+
+
+# The benchmark's two sampling shapes: sdar30b.chat's block pass (request
+# bucket 128 x 4 positions, the Qwen family's vocabulary, the drawn token's
+# probability returned) and mistral7b.chat's decode step. With each, the
+# counts of rows somebody reads that the cells' steps show.
+POINTS = ((512, 151936, (512, 256, 192, 128, 96, 64, 8)),
+          (128, 32768, (128, 64, 24, 8)))
+
+
+def tpu_points(out_path="chiprun_out/probe_sampler.json"):
+    """Kernel against the XLA epilogue on the chip, as the step programs
+    call them (top-p on, no top-k, no penalties, the probability returned):
+    tokens equal, probabilities within 1e-6 relative, and both times; then
+    the kernel's time against the count of active rows."""
+    import json
+
+    from vllm_tpu.sample.sampler import dispatch_sample
+
+    print("device:", jax.devices()[0])
+    rng = np.random.default_rng(0)
+    flags = dict(needs_top_k=False, needs_top_p_min_p=True, return_prob=True)
+    rows_out, bad = [], 0
+    for rows, vocab, actives in POINTS:
+        logits, md = _case(rng, rows, vocab)
+
+        def path(enable_kernel):
+            def f(logits, md, n):
+                tok, _, prob = dispatch_sample(
+                    logits, md, enable_kernel=enable_kernel, num_active=n,
+                    **flags)
+                return tok, prob
+            return jax.jit(f)
+
+        n_all = jnp.asarray(rows, jnp.int32)
+        t_xla, (tok_x, prob_x) = _per_call(path(False), logits, md, n_all)
+        kern = path(True)
+        t_ker, (tok_k, prob_k) = _per_call(kern, logits, md, n_all)
+        tok_x, tok_k = np.asarray(tok_x), np.asarray(tok_k)
+        prob_x, prob_k = np.asarray(prob_x), np.asarray(prob_k)
+        rel = float(np.max(np.abs(prob_k - prob_x) / prob_x))
+        same = int(np.sum(tok_x == tok_k))
+        ok = same == rows and rel <= 1e-6
+        bad += not ok
+        by_active = {}
+        for n in actives:
+            t, (tok_n, _) = _per_call(kern, logits, md, jnp.asarray(n, jnp.int32))
+            tok_n = np.asarray(tok_n)
+            cut_ok = (np.array_equal(tok_n[:n], tok_k[:n])
+                      and not tok_n[n:].any())
+            bad += not cut_ok
+            by_active[n] = round(t * 1e3, 4)
+        row = dict(rows=rows, vocab=vocab, xla_ms=round(t_xla * 1e3, 4),
+                   kernel_ms=round(t_ker * 1e3, 4), tokens_equal=same,
+                   prob_max_rel_diff=rel, kernel_ms_by_active_rows=by_active,
+                   ok=bool(ok))
+        rows_out.append(row)
+        print(json.dumps(row), flush=True)
+    os.makedirs(os.path.dirname(out_path), exist_ok=True)
+    with open(out_path, "w") as f:
+        json.dump(dict(device=str(jax.devices()[0]), points=rows_out), f,
+                  indent=1)
+    if bad:
+        raise SystemExit(f"kernel and XLA epilogue disagree at {bad} points")
 
 
 def tpu_sweep():
@@ -178,8 +257,10 @@ def main(argv=None):
     argv = sys.argv[1:] if argv is None else argv
     if "--smoke" in argv or jax.default_backend() != "tpu":
         smoke_sweep()
-    else:
+    elif "--sweep" in argv:
         tpu_sweep()
+    else:
+        tpu_points()
     return 0
 
 

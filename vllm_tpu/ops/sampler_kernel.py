@@ -31,8 +31,10 @@ The Pallas kernel (``fused_sample``) grids over row blocks, streams the
 logits (and penalty state) from HBM in double-buffered vocab tiles into a
 VMEM-resident ``[row_block, V2]`` scratch, then runs the whole epilogue —
 penalties, temperature, top-k, top-p/min-p, Gumbel argmax, greedy argmax —
-without touching HBM again: one logits read, one ``[R]`` token write, no
-sorted vocab, no probability tensor. Because every reduction above is
+without touching HBM again: one logits read, one ``[R]`` token write (and,
+asked for, the ``[R]`` probabilities of the tokens drawn), no sorted vocab,
+no probability tensor. A row block at or past the caller's count of active
+rows reads nothing and writes zeros. Because every reduction above is
 order-independent (or routed through the shared tree), the kernel is
 bit-exact against ``sample/sampler.py`` in interpret mode; tests assert
 it per sampling-parameter combination.
@@ -69,24 +71,52 @@ def next_pow2(n: int) -> int:
 
 
 def padded_vocab(vocab: int) -> int:
-    """Compute width V2 >= 128, power of two (the tree reduction and the
-    radix walks want a fixed, divisible shape)."""
+    """Compute width V2 >= 128, power of two (``row_sum``'s halving tree
+    wants one)."""
     return max(128, next_pow2(vocab))
 
 
-# The widest padded vocabulary the kernel takes (a [row_block, V2] float32
-# working set must fit VMEM). Past it the XLA epilogue is the only path, so
-# nothing has to match the kernel bit for bit: ``compute_width`` then pads
-# to whole noise tiles only, and the sums may run in any order.
-MAX_KERNEL_VOCAB = 131072
+# Up to this many padded lanes the epilogue computes at a power of two and
+# sums by ``row_sum``'s halving tree. Past it a power of two wastes up to
+# half the row (151936 would pad to 262144), so ``compute_width`` pads to
+# whole noise tiles and the kernel sums by ``tiled_row_sum``.
+MAX_POW2_WIDTH = 131072
+
+# Rows of the vocabulary a grid step of the kernel holds in VMEM.
+_ROW_BLOCK = 8
+
+
+def vmem_estimate(row_blk: int, width: int) -> int:
+    """Bytes of VMEM the kernel asks for: the epilogue keeps the
+    [row_blk, width] f32 scratch plus a handful of same-shaped temporaries
+    (weights, ordinals, noise, masks) live; 8x the scratch covers what the
+    v5e compiler asked for at R=128, V=128256 (16.06 MiB with penalties at
+    row_blk 8, past the 16 MiB default) and at R=512, V=151936 with the
+    probability returned (between 20 and 24 MiB against the estimate's 38;
+    ``tests/test_tpu_aot_compile.py`` holds both to the compiler)."""
+    return 8 * row_blk * width * 4
+
+
+# The least VMEM a TensorCore has among the chips this kernel can serve at
+# all (JAX's own table, ``jax/_src/pallas/mosaic/tpu_info.py``: v5p and
+# TPU7x 64 MiB; v5e and v6e 128 MiB; the 16 MiB of v4 and older are under
+# the estimate at the old bound of 131072 lanes, 32 MiB, and never ran it).
+_LEAST_VMEM_BYTES = 64 << 20
+
+# The widest row the kernel takes: the widest whole number of noise tiles
+# whose ``vmem_estimate`` at the default row block fits that VMEM (262144
+# lanes). Past it the XLA epilogue is the only path.
+MAX_KERNEL_WIDTH = (
+    _LEAST_VMEM_BYTES // vmem_estimate(_ROW_BLOCK, _NOISE_TILE) * _NOISE_TILE
+)
 
 
 def compute_width(vocab: int) -> int:
-    """The width the epilogue computes at: ``padded_vocab`` where the
-    kernel may run, else the vocabulary rounded up to whole noise tiles
-    (151936 -> 153600, not 262144)."""
+    """The width the epilogue computes at, kernel and XLA path alike:
+    ``padded_vocab`` up to ``MAX_POW2_WIDTH``, else the vocabulary rounded
+    up to whole noise tiles (151936 -> 155648, not 262144)."""
     v2 = padded_vocab(vocab)
-    if v2 <= MAX_KERNEL_VOCAB:
+    if v2 <= MAX_POW2_WIDTH:
         return v2
     return -(-vocab // _NOISE_TILE) * _NOISE_TILE
 
@@ -94,7 +124,7 @@ def compute_width(vocab: int) -> int:
 def plain_sum(x: jnp.ndarray) -> jnp.ndarray:
     """[..., N] -> [..., 1] in whatever order XLA picks: one fused pass
     over its operands (``row_sum``'s halving tree writes every level out),
-    for widths the kernel never sees."""
+    for the XLA epilogue at widths past ``MAX_POW2_WIDTH``."""
     return jnp.sum(x, axis=-1, keepdims=True)
 
 
@@ -122,6 +152,24 @@ def row_sum(x: jnp.ndarray) -> jnp.ndarray:
         x = x[..., :h] + x[..., h:n]
         n = h
     return jnp.sum(x, axis=-1, keepdims=True)
+
+
+def tiled_row_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """[..., N] -> [..., 1] for N any whole number of noise tiles, in one
+    FIXED order: the tiles added lane for lane, left to right, then
+    ``row_sum`` over the one tile that is left."""
+    n = x.shape[-1]
+    assert n % _NOISE_TILE == 0, n
+    acc = x[..., :_NOISE_TILE]
+    for i in range(1, n // _NOISE_TILE):
+        acc = acc + x[..., i * _NOISE_TILE : (i + 1) * _NOISE_TILE]
+    return row_sum(acc)
+
+
+def kernel_sum_fn(width: int):
+    """The fixed order of summation the kernel uses at a compute width
+    (and the reference it is compared with bit for bit)."""
+    return row_sum if width <= MAX_POW2_WIDTH else tiled_row_sum
 
 
 def row_max(x: jnp.ndarray) -> jnp.ndarray:
@@ -324,22 +372,44 @@ def gumbel_argmax_block(
     scaled: jnp.ndarray,  # [rows, V2] masked/scaled logits
     k0: jnp.ndarray,  # [rows, 1] uint32
     k1: jnp.ndarray,  # [rows, 1] uint32
+    tiles_ref=None,  # the kernel's [rows, V2] VMEM scratch, or None
 ) -> jnp.ndarray:
     """argmax(scaled + noise) streamed over noise tiles: per tile a
     first-occurrence argmax, folded with a strict ``>`` so earlier tiles
-    win ties — exactly the first-occurrence argmax of the full row."""
+    win ties — exactly the first-occurrence argmax of the full row.
+
+    The reference walks the tiles in a Python loop over slices of the
+    value. The kernel hands in its scratch: the row block is parked there
+    and the same fold runs as ONE loop body over a dynamic lane offset (a
+    ref takes one, a value does not), so a row of 38 tiles is traced and
+    lowered once, not 38 times over some 120 operations each (seconds of
+    every start and every first use of a step program, PERF.md PR 30)."""
     rows, v2 = scaled.shape
     t = noise_tile(v2)
-    best_v = jnp.full((rows, 1), -jnp.inf, jnp.float32)
-    best_i = jnp.zeros((rows, 1), jnp.int32)
-    for i in range(v2 // t):
-        seg = scaled[:, i * t : (i + 1) * t] + gumbel_tile(k0, k1, i, t)
+
+    def fold(best, i, seg):
+        best_v, best_i = best
+        seg = seg + gumbel_tile(k0, k1, i, t)
         m = row_max(seg)
-        a = row_argmax(seg) + jnp.int32(i * t)
+        a = row_argmax(seg) + i * t
         upd = m > best_v
-        best_v = jnp.where(upd, m, best_v)
-        best_i = jnp.where(upd, a, best_i)
-    return best_i
+        return jnp.where(upd, m, best_v), jnp.where(upd, a, best_i)
+
+    best = (
+        jnp.full((rows, 1), -jnp.inf, jnp.float32),
+        jnp.zeros((rows, 1), jnp.int32),
+    )
+    if tiles_ref is None:
+        for i in range(v2 // t):
+            best = fold(best, jnp.int32(i), scaled[:, i * t : (i + 1) * t])
+        return best[1]
+    tiles_ref[...] = scaled
+
+    def body(i, best):
+        lanes = pl.ds(pl.multiple_of(i * t, t), t)
+        return fold(best, i, tiles_ref[:, lanes])
+
+    return lax.fori_loop(0, v2 // t, body, best)[1]
 
 
 def sample_block(
@@ -356,6 +426,7 @@ def sample_block(
     needs_top_p_min_p: bool,
     return_prob: bool = False,
     sum_fn=None,
+    tiles_ref=None,
 ):
     """The whole sampling epilogue after penalties, shared verbatim by
     the reference path ([R, V2]) and the kernel ([row_block, V2]) — the
@@ -363,7 +434,8 @@ def sample_block(
     ``return_prob`` also [rows, 1] f32, the probability of the token drawn
     under the distribution it was drawn from: the softmax of the scaled
     and truncated logits, or for a greedy row the plain softmax of its
-    logits (generation by diffusion ranks positions by it)."""
+    logits (generation by diffusion ranks positions by it). ``tiles_ref``
+    is the kernel's scratch, for ``gumbel_argmax_block``."""
     greedy = temperature == 0.0
     greedy_pick = row_argmax(x)
     scaled = x / jnp.where(greedy, 1.0, temperature)
@@ -371,13 +443,16 @@ def sample_block(
         scaled = mask_top_k_block(scaled, top_k, vocab)
     if needs_top_p_min_p:
         scaled = mask_top_p_min_p_block(scaled, top_p, min_p, sum_fn)
-    random_pick = gumbel_argmax_block(scaled, k0, k1)
+    random_pick = gumbel_argmax_block(scaled, k0, k1, tiles_ref)
     pick = jnp.where(greedy, greedy_pick, random_pick)
     if not return_prob:
         return pick
     dist = jnp.where(greedy, x, scaled)
     w = jnp.exp(dist - row_max(dist))
-    drawn = jnp.take_along_axis(w, pick, axis=-1)
+    # The one weight at ``pick`` as a masked sum (exact: every other term
+    # is 0.0); Mosaic has no gather along lanes.
+    lane = lax.broadcasted_iota(jnp.int32, w.shape, w.ndim - 1)
+    drawn = jnp.sum(jnp.where(lane == pick, w, 0.0), axis=-1, keepdims=True)
     return pick, drawn / (sum_fn or row_sum)(w)
 
 
@@ -398,6 +473,8 @@ def _col_f(params: jnp.ndarray, c: int) -> jnp.ndarray:
 
 
 def _sampler_kernel(
+    # Scalar prefetch
+    num_active_ref,  # [1] i32 (SMEM): rows before it are read
     # Inputs
     params_f_ref,  # [row_blk, 128] f32: temp, top_p, min_p, rep, freq, pres
     params_i_ref,  # [row_blk, 128] i32: top_k, seed0, seed1
@@ -406,6 +483,7 @@ def _sampler_kernel(
     pmask_hbm_ref,  # [R, V] i8, ANY ([1, 128] dummy w/o penalties)
     # Outputs
     out_ref,  # [row_blk, 128] i32 (sampled token broadcast across lanes)
+    prob_ref,  # [row_blk, 128] f32 (its probability, likewise) or None
     # Scratch
     scaled_scratch,  # [row_blk, V2] f32
     logits_bufs,  # [2, row_blk, LT] f32
@@ -423,6 +501,7 @@ def _sampler_kernel(
     lt = logits_bufs.shape[-1]
     r0 = pl.program_id(0) * row_blk
     num_tiles = pl.cdiv(vocab, lt)
+    num_active = num_active_ref[0]
 
     def tile_copies(j, slot):
         """Async copies of vocab tile j into buffer ``slot``. Tile widths
@@ -455,63 +534,81 @@ def _sampler_kernel(
             )
         return copies
 
-    # Phase 1: stream vocab tiles HBM -> VMEM (double-buffered), apply
-    # penalties tile-wise, land the penalized logits in the row-resident
-    # scratch. Static tile loop: widths and scratch offsets are literals.
-    for c in tile_copies(0, 0):
-        c.start()
-    for j in range(num_tiles):
-        if j + 1 < num_tiles:
-            for c in tile_copies(j + 1, (j + 1) % 2):
-                c.start()
-        for c in tile_copies(j, j % 2):
-            c.wait()
-        w = min(lt, vocab - j * lt)
-        slot = j % 2
-        tile = logits_bufs[slot, :, pl.ds(0, w)]
-        if needs_penalties:
-            tile = penalize_block(
-                tile,
-                counts_bufs[slot, :, pl.ds(0, w)],
-                pmask_bufs[slot, :, pl.ds(0, w)] != 0,
-                _col_f(params_f_ref[...], 3),
-                _col_f(params_f_ref[...], 4),
-                _col_f(params_f_ref[...], 5),
-            )
-        scaled_scratch[:, j * lt : j * lt + w] = tile
-    if v2 > vocab:  # pad lanes: -inf -> zero weight, never wins argmax
-        scaled_scratch[:, vocab:v2] = jnp.full(
-            (row_blk, v2 - vocab), -jnp.inf, jnp.float32
-        )
+    @pl.when(r0 >= num_active)
+    def _nobody_reads_these_rows():
+        out_ref[...] = jnp.zeros_like(out_ref)
+        if prob_ref is not None:
+            prob_ref[...] = jnp.zeros_like(prob_ref)
 
-    # Phase 2: the shared epilogue, entirely in VMEM.
-    pf = params_f_ref[...]
-    pi = params_i_ref[...]
-    seed0 = lax.bitcast_convert_type(
-        _col_f(pi, 1).astype(jnp.int32), jnp.uint32
-    )
-    seed1 = lax.bitcast_convert_type(
-        _col_f(pi, 2).astype(jnp.int32), jnp.uint32
-    )
-    sampled = sample_block(
-        scaled_scratch[...],
-        _col_f(pf, 0),
-        _col_f(pi, 0).astype(jnp.int32),
-        _col_f(pf, 1),
-        _col_f(pf, 2),
-        seed0,
-        seed1,
-        vocab=vocab,
-        needs_top_k=needs_top_k,
-        needs_top_p_min_p=needs_top_p_min_p,
-    )
-    out_ref[...] = jnp.broadcast_to(sampled, (row_blk, 128))
+    @pl.when(r0 < num_active)
+    def _sample_the_block():
+        # Phase 1: stream vocab tiles HBM -> VMEM (double-buffered), apply
+        # penalties tile-wise, land the penalized logits in the row-resident
+        # scratch. Static tile loop: widths and scratch offsets are literals.
+        for c in tile_copies(0, 0):
+            c.start()
+        for j in range(num_tiles):
+            if j + 1 < num_tiles:
+                for c in tile_copies(j + 1, (j + 1) % 2):
+                    c.start()
+            for c in tile_copies(j, j % 2):
+                c.wait()
+            w = min(lt, vocab - j * lt)
+            slot = j % 2
+            tile = logits_bufs[slot, :, pl.ds(0, w)]
+            if needs_penalties:
+                tile = penalize_block(
+                    tile,
+                    counts_bufs[slot, :, pl.ds(0, w)],
+                    pmask_bufs[slot, :, pl.ds(0, w)] != 0,
+                    _col_f(params_f_ref[...], 3),
+                    _col_f(params_f_ref[...], 4),
+                    _col_f(params_f_ref[...], 5),
+                )
+            scaled_scratch[:, j * lt : j * lt + w] = tile
+        if v2 > vocab:  # pad lanes: -inf -> zero weight, never wins argmax
+            scaled_scratch[:, vocab:v2] = jnp.full(
+                (row_blk, v2 - vocab), -jnp.inf, jnp.float32
+            )
+
+        # Phase 2: the shared epilogue, entirely in VMEM.
+        pf = params_f_ref[...]
+        pi = params_i_ref[...]
+        seed0 = lax.bitcast_convert_type(
+            _col_f(pi, 1).astype(jnp.int32), jnp.uint32
+        )
+        seed1 = lax.bitcast_convert_type(
+            _col_f(pi, 2).astype(jnp.int32), jnp.uint32
+        )
+        sampled = sample_block(
+            scaled_scratch[...],
+            _col_f(pf, 0),
+            _col_f(pi, 0).astype(jnp.int32),
+            _col_f(pf, 1),
+            _col_f(pf, 2),
+            seed0,
+            seed1,
+            vocab=vocab,
+            needs_top_k=needs_top_k,
+            needs_top_p_min_p=needs_top_p_min_p,
+            return_prob=prob_ref is not None,
+            sum_fn=kernel_sum_fn(v2),
+            tiles_ref=scaled_scratch,
+        )
+        # The block that straddles the count: its rows past it are zeros
+        # too, as a skipped block's are.
+        row = r0 + lax.broadcasted_iota(jnp.int32, (row_blk, 128), 0)
+        live = row < num_active
+        if prob_ref is not None:
+            sampled, prob = sampled
+            prob_ref[...] = jnp.where(live, prob, 0.0)
+        out_ref[...] = jnp.where(live, sampled, 0)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=[
-        "needs_penalties", "needs_top_k", "needs_top_p_min_p",
+        "needs_penalties", "needs_top_k", "needs_top_p_min_p", "return_prob",
         "row_block", "logits_tile", "vmem_limit_bytes", "interpret",
     ],
 )
@@ -521,17 +618,22 @@ def fused_sample(
     params_i: jax.Array,  # [R, 128] i32: top_k, seed0, seed1 (bitcast)
     counts: jax.Array,  # [R, V] i32, or [1, 128] dummy
     prompt_mask: jax.Array,  # [R, V] i8, or [1, 128] dummy
+    num_active: jax.Array | None = None,  # i32 scalar; None: every row
     *,
     needs_penalties: bool = False,
     needs_top_k: bool = True,
     needs_top_p_min_p: bool = True,
-    row_block: int = 8,
+    return_prob: bool = False,
+    row_block: int = _ROW_BLOCK,
     logits_tile: int = _LOGITS_TILE,
     vmem_limit_bytes: int | None = None,
     interpret: bool = False,
-) -> jax.Array:
+):
     """Fused device sampling epilogue: [R] i32 sampled tokens from raw
-    lm_head logits in a single HBM read. See the module docstring; use
+    lm_head logits in a single HBM read; with ``return_prob`` also [R] f32,
+    each drawn token's probability (``sample_block``). Rows at or past
+    ``num_active`` are not sampled and come back as zeros; a row block
+    wholly past it moves no byte. See the module docstring; use
     ``sample/sampler.py:dispatch_sample`` rather than calling this
     directly (eligibility and the params packing live there)."""
     num_rows, vocab = logits.shape
@@ -542,18 +644,13 @@ def fused_sample(
         )
     if needs_penalties and counts.shape != (num_rows, vocab):
         raise ValueError(f"{counts.shape=} != {(num_rows, vocab)}")
-    v2 = padded_vocab(vocab)
+    v2 = compute_width(vocab)
     # row_block: a multiple of 8, or at least num_rows — Mosaic tiles the
     # [R, 128] parameter blocks (8, 128).
     row_blk = max(1, min(row_block, num_rows))
     lt = min(logits_tile, v2)
     if vmem_limit_bytes is None:
-        # The epilogue keeps the [row_blk, V2] f32 scratch plus a handful
-        # of same-shaped temporaries (weights, ordinals, noise, masks)
-        # live; 8x the scratch covers what the v5e compiler asked for at
-        # R=128, V=128256 (16.06 MiB with penalties at row_blk 8, past
-        # the 16 MiB default).
-        vmem_limit_bytes = max(16 << 20, 8 * row_blk * v2 * 4)
+        vmem_limit_bytes = max(16 << 20, vmem_estimate(row_blk, v2))
 
     # Pad rows up to the block grid; pad rows sample into the void and
     # are sliced off below.
@@ -566,8 +663,18 @@ def fused_sample(
         if needs_penalties:
             counts = jnp.pad(counts, ((0, pad), (0, 0)))
             prompt_mask = jnp.pad(prompt_mask, ((0, pad), (0, 0)))
+    if num_active is None:
+        num_active = num_rows
+    num_active = jnp.asarray(num_active, jnp.int32).reshape(1)
 
-    params_spec = pl.BlockSpec((row_blk, 128), lambda i: (i, 0))
+    def params_block(i, num_active_ref):
+        # A block nobody reads asks for the last live block's parameters
+        # again, which the pipeline then does not fetch.
+        last = jnp.maximum(pl.cdiv(num_active_ref[0], row_blk) - 1, 0)
+        return jnp.minimum(i, last), 0
+
+    params_spec = pl.BlockSpec((row_blk, 128), params_block)
+    out_spec = pl.BlockSpec((row_blk, 128), lambda i, _: (i, 0))
     scratch_shapes = [
         pltpu.VMEM((row_blk, v2), jnp.float32),
         pltpu.VMEM((2, row_blk, lt), jnp.float32),
@@ -576,26 +683,32 @@ def fused_sample(
         pltpu.SemaphoreType.DMA((3, 2)),
     ]
     scratch_shapes = [s for s in scratch_shapes if s is not None]
+    out_shape = [jax.ShapeDtypeStruct((r2, 128), jnp.int32)]
+    if return_prob:
+        out_shape.append(jax.ShapeDtypeStruct((r2, 128), jnp.float32))
 
     def kernel(*refs):
-        pf, pi, lg, ct, pm, out = refs[:6]
+        na, pf, pi, lg, ct, pm, out = refs[:7]
+        rest = list(refs[7:])
+        prob = rest.pop(0) if return_prob else None
         if needs_penalties:
-            scaled, lbufs, cbufs, pbufs, sem = refs[6:]
+            scaled, lbufs, cbufs, pbufs, sem = rest
         else:
-            scaled, lbufs, sem = refs[6:]
+            scaled, lbufs, sem = rest
             cbufs = pbufs = None
         _sampler_kernel(
-            pf, pi, lg, ct, pm, out, scaled, lbufs, cbufs, pbufs, sem,
+            na, pf, pi, lg, ct, pm, out, prob, scaled, lbufs, cbufs, pbufs,
+            sem,
             vocab=vocab,
             needs_penalties=needs_penalties,
             needs_top_k=needs_top_k,
             needs_top_p_min_p=needs_top_p_min_p,
         )
 
-    out = pl.pallas_call(
+    outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0,
+            num_scalar_prefetch=1,
             in_specs=[
                 params_spec,
                 params_spec,
@@ -603,7 +716,7 @@ def fused_sample(
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=[params_spec],
+            out_specs=[out_spec] * len(out_shape),
             grid=(r2 // row_blk,),
             scratch_shapes=scratch_shapes,
         ),
@@ -611,8 +724,10 @@ def fused_sample(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=vmem_limit_bytes,
         ),
-        out_shape=[jax.ShapeDtypeStruct((r2, 128), jnp.int32)],
+        out_shape=out_shape,
         name="fused_sampler_kernel",
         interpret=interpret,
-    )(params_f, params_i, logits, counts, prompt_mask)[0]
-    return out[:num_rows, 0]
+    )(num_active, params_f, params_i, logits, counts, prompt_mask)
+    if return_prob:
+        return outs[0][:num_rows, 0], outs[1][:num_rows, 0]
+    return outs[0][:num_rows, 0]

@@ -68,8 +68,8 @@ def apply_penalties(logits: jnp.ndarray, md: SamplingMetadata) -> jnp.ndarray:
 
 def _pad_vocab(logits: jnp.ndarray) -> jnp.ndarray:
     """Pad the vocab axis to the width the epilogue computes at, with -inf
-    (zero weight, never wins an argmax): the shared power of two where the
-    kernel may run, whole noise tiles past its bound."""
+    (zero weight, never wins an argmax): ``compute_width``, the kernel's
+    own."""
     v = logits.shape[-1]
     v2 = _sk.compute_width(v)
     if v2 == v:
@@ -78,9 +78,13 @@ def _pad_vocab(logits: jnp.ndarray) -> jnp.ndarray:
 
 
 def _sum_fn(vocab: int):
-    """None (the fixed-order ``row_sum`` the kernel shares) where the
-    kernel may run; past its bound one fused pass in any order."""
-    if _sk.padded_vocab(vocab) <= _sk.MAX_KERNEL_VOCAB:
+    """None (the halving tree the kernel shares, so the two agree bit for
+    bit) at power-of-two widths; at the wide ones one fused pass in any
+    order, since the kernel's ``tiled_row_sum`` would have XLA write every
+    partial sum of a 31-pass bisection out. There the two paths' sums
+    may differ in their last bits (1e-6 relative on the chip, tokens
+    equal: ``tools/probe_sampler.py``)."""
+    if _sk.compute_width(vocab) <= _sk.MAX_POW2_WIDTH:
         return None
     return _sk.plain_sum
 
@@ -117,11 +121,14 @@ def sample(
     needs_top_p_min_p: bool = True,
     needs_gumbel: bool = True,
     return_prob: bool = False,
+    sum_fn=None,
 ) -> tuple[jnp.ndarray, ...]:
     """Returns (sampled [R] i32, logprobs [R, V] f32 log-softmax of the
     pre-masking distribution — what logprob reporting uses), and with
     ``return_prob`` a third, [R] f32: the probability of each drawn token
     under the distribution it was drawn from (``sample_block``).
+    ``sum_fn`` overrides the order of the float sums (default: this
+    width's, ``_sum_fn``); the tests hand in the kernel's.
 
     The ``needs_*`` flags are static: an all-greedy or vanilla-temperature
     batch skips the [R, V] truncation passes — and, with
@@ -130,6 +137,7 @@ def sample(
     is a single fused argmax behind the logits matmul.
     """
     raw_logprobs = jax.nn.log_softmax(logits, axis=-1)
+    sum_fn = sum_fn or _sum_fn(logits.shape[-1])
 
     if needs_penalties:
         logits = apply_penalties(logits, md)
@@ -143,8 +151,7 @@ def sample(
         x = _pad_vocab(logits)
         w = jnp.exp(x - _sk.row_max(x))
         drawn = jnp.take_along_axis(w, pick[:, None], axis=-1)
-        total = (_sum_fn(logits.shape[-1]) or _sk.row_sum)(w)
-        return pick, raw_logprobs, (drawn / total)[:, 0]
+        return pick, raw_logprobs, (drawn / (sum_fn or _sk.row_sum)(w))[:, 0]
 
     keys = md.prng_keys.astype(jnp.uint32)
     sampled = _sk.sample_block(
@@ -159,7 +166,7 @@ def sample(
         needs_top_k=needs_top_k,
         needs_top_p_min_p=needs_top_p_min_p,
         return_prob=return_prob,
-        sum_fn=_sum_fn(logits.shape[-1]),
+        sum_fn=sum_fn,
     )
     if return_prob:
         return sampled[0][:, 0], raw_logprobs, sampled[1][:, 0]
@@ -216,11 +223,11 @@ def sampler_kernel_eligible(
         return False, False
     if not interpret:
         # Mosaic path: 128-lane-aligned vocab, big enough to beat the
-        # fused XLA epilogue, small enough that a [row_block, V2] f32
-        # working set fits VMEM.
+        # fused XLA epilogue, and a row block's working set at the width
+        # it computes at inside the least VMEM the kernel is served with.
         if vocab % 128 != 0 or vocab < 2048:
             return False, False
-        if _sk.padded_vocab(vocab) > _sk.MAX_KERNEL_VOCAB:
+        if _sk.compute_width(vocab) > _sk.MAX_KERNEL_WIDTH:
             return False, False
     return True, interpret
 
@@ -238,20 +245,24 @@ def dispatch_sample(
     allow_interpret: bool = False,
     tp_size: int = 1,
     return_prob: bool = False,
+    num_active: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, ...]:
     """Kernel-vs-reference dispatch for the sampling epilogue (the
     ``dispatch_ragged_attention`` pattern): the fused Pallas kernel when
     eligible — one HBM logits read, no sorts — else the XLA sort-free
-    reference above. Both produce bit-identical samples; A/B with
+    reference above. Both produce bit-identical samples (at a width past
+    ``MAX_POW2_WIDTH`` see ``_sum_fn``); A/B with
     ``VLLM_TPU_DISABLE_SAMPLER_KERNEL=1`` before filing kernel bugs.
-    ``return_prob`` (the drawn token's probability, a third output) is the
-    reference path's alone: the kernel returns token ids only."""
+    ``return_prob`` adds the drawn token's probability as a third output
+    on either path. ``num_active`` (an i32 scalar) promises that nobody
+    reads the rows at or past it: the kernel does no work for them and
+    returns zeros there, the reference samples them all the same."""
     import vllm_tpu.envs as envs
 
     use_kernel, interpret = sampler_kernel_eligible(
         logits.shape[-1],
         needs_gumbel=needs_gumbel,
-        enable_kernel=enable_kernel and not return_prob,
+        enable_kernel=enable_kernel,
         allow_interpret=allow_interpret,
         tp_size=tp_size,
     )
@@ -270,7 +281,6 @@ def dispatch_sample(
     # (not in-kernel) so it dead-code-eliminates when the caller drops it.
     raw_logprobs = jax.nn.log_softmax(logits, axis=-1)
 
-    num_rows = logits.shape[0]
     params_f = jnp.pad(
         jnp.stack(
             [
@@ -303,7 +313,11 @@ def dispatch_sample(
         pmask = jnp.zeros((1, 128), jnp.int8)
 
     if interpret:
-        blk_kw = dict(row_block=2, logits_tile=256)
+        # Small blocks, so that a tiny shape still takes several grid steps
+        # and tiles; at most 16 tiles a row, so that a wide one does not
+        # unroll hundreds of copies.
+        v2 = _sk.compute_width(logits.shape[-1])
+        blk_kw = dict(row_block=2, logits_tile=max(256, v2 // 16))
     else:
         blk_kw = {}
         if envs.VLLM_TPU_SAMPLER_ROW_BLOCK > 0:
@@ -317,10 +331,14 @@ def dispatch_sample(
         params_i,
         counts,
         pmask,
+        num_active,
         needs_penalties=needs_penalties,
         needs_top_k=needs_top_k,
         needs_top_p_min_p=needs_top_p_min_p,
+        return_prob=return_prob,
         interpret=interpret,
         **blk_kw,
     )
+    if return_prob:
+        return sampled[0], raw_logprobs, sampled[1]
     return sampled, raw_logprobs

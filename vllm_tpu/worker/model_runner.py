@@ -931,6 +931,8 @@ class ModelRunner:
                 block_len=block_len, r_pad=r_pad, needs_top_k=needs_top_k,
                 needs_top_p_min_p=needs_top_p_min_p,
                 needs_gumbel=needs_gumbel, num_logprobs=num_logprobs,
+                enable_sampler_kernel=enable_sampler_kernel,
+                num_live=md.num_seqs[0],
             )
         last = hidden[md.logits_indices]  # [R, D]
         nan_count = None
@@ -1020,6 +1022,7 @@ class ModelRunner:
             enable_kernel=enable_sampler_kernel,
             allow_interpret=True,
             tp_size=self.tp_size,
+            num_active=md.num_seqs[0],  # live rows come first
         )
         if dynamic_decode:
             # Device-resident dynamic multi-step decode: a lax.while_loop
@@ -1210,9 +1213,10 @@ class ModelRunner:
     def _block_epilogue(self, params, kv_cache, draft_kv, hidden, sampling,
                         blk, flat_idx, blk_tok, blk_com, moe_counts,
                         *, block_len, r_pad, needs_top_k, needs_top_p_min_p,
-                        needs_gumbel, num_logprobs):
+                        needs_gumbel, num_logprobs, enable_sampler_kernel,
+                        num_live):
         """The end of a step under generation by diffusion over blocks:
-        every position of every block row is sampled, with the probability
+        every position of every live row is sampled, with the probability
         of the token drawn under the distribution it was drawn from (its
         confidence), and the row's rule commits some of the uncommitted
         ones. Rule, count and threshold are data of the row, so requests
@@ -1247,7 +1251,9 @@ class ModelRunner:
         drawn, raw_logprobs, prob = dispatch_sample(
             logits, per_pos, needs_penalties=False, needs_top_k=needs_top_k,
             needs_top_p_min_p=needs_top_p_min_p, needs_gumbel=needs_gumbel,
+            enable_kernel=enable_sampler_kernel, allow_interpret=True,
             tp_size=self.tp_size, return_prob=True,
+            num_active=num_live * b,  # live rows come first
         )
         drawn = drawn.reshape(r_pad, b)
         with jax.named_scope("commit"):
@@ -2255,26 +2261,23 @@ class ModelRunner:
             use_kernel, _ = sampler_kernel_eligible(
                 self.model.vocab_size,
                 needs_gumbel=True,
-                # A block pass needs the drawn token's probability, which
-                # the XLA epilogue alone returns: every position of every
-                # sampling row is a fallback row.
-                enable_kernel=self.enable_sampler_kernel and not blen,
+                enable_kernel=self.enable_sampler_kernel,
                 allow_interpret=True,
                 tp_size=self.tp_size,
             )
             if blen:
-                self.sampler_fallback_rows += int(
-                    np.sum(nongreedy & do_sample[:r_live])) * blen
-            elif dynamic:
+                # Every position of a sampling row that is not a store pass.
+                rows = int(np.sum(nongreedy & do_sample[:r_live])) * blen
+            else:
+                rows = int(np.sum(nongreedy))
+            if dynamic:
                 # Realized step count is unknown until finalize; stash
                 # the routing decision for deferred accounting.
-                self._dyn_sampler_acct = (use_kernel, int(np.sum(nongreedy)))
+                self._dyn_sampler_acct = (use_kernel, rows)
             elif use_kernel:
                 self.sampler_kernel_launches += flags["num_decode_steps"]
             else:
-                self.sampler_fallback_rows += int(np.sum(nongreedy)) * flags[
-                    "num_decode_steps"
-                ]
+                self.sampler_fallback_rows += rows * flags["num_decode_steps"]
         arrays = (jnp.asarray(ibuf), jnp.asarray(fbuf), counts, prompt_mask)
         mm_arrays = None
         if self.is_mm:
