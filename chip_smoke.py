@@ -136,8 +136,8 @@ def device_facts() -> dict:
 
 
 def write_model_dir(sz: Sizes) -> str:
-    """A config-only checkpoint directory, as ``bench.py`` builds its
-    config; nothing is downloaded and there is no tokenizer."""
+    """A config-only checkpoint directory; nothing is downloaded and
+    there is no tokenizer."""
     from transformers import LlamaConfig
 
     cfg = LlamaConfig(

@@ -130,3 +130,56 @@ def test_stop_profile_roundtrip():
     assert body == {"status": "profiling stopped", "trace_dir": "/tmp/p",
                     "traced_s": 1.5, "stop_s": 0.25}
     assert engine.engine_core.calls == [("start", "/tmp/p"), ("stop",)]
+
+
+def test_the_measurement_routes_are_these_and_no_other():
+    """One way to read the program from outside: ``/metrics``, the profiler
+    control and the two ``/debug`` views. A route added beside them, or one
+    of them gone, fails here by name; anything else under those prefixes is
+    the 404 of a route that was never there."""
+    from aiohttp.test_utils import TestClient, TestServer
+
+    app = build_app(StubEngine(), "stub")
+    routes = {(r.method, r.resource.canonical) for r in app.router.routes()
+              if r.method != "HEAD"}
+    measuring = {(m, p) for m, p in routes if p.startswith(
+        ("/metrics", "/debug", "/start_profile", "/stop_profile"))}
+    assert measuring == {
+        ("GET", "/metrics"), ("GET", "/metrics/cluster"),
+        ("POST", "/start_profile"), ("POST", "/stop_profile"),
+        ("GET", "/debug/requests"), ("GET", "/debug/deadletter"),
+    }
+
+    async def run():
+        async with TestClient(TestServer(app)) as client:
+            return [(await client.request(m, p)).status for m, p in (
+                ("GET", "/debug/no-such-view"), ("POST", "/debug/requests"),
+                ("GET", "/debug/requests"))]
+
+    assert asyncio.run(run()) == [404, 405, 200]
+
+
+def test_the_observability_options_are_these_and_no_other(capsys):
+    """The options that turn measurement on are a list held by name (a
+    simplification adds none), each reaches the server's parser as a flag,
+    and a flag the parser does not have is refused by name, exit 2."""
+    import argparse
+    import dataclasses
+
+    import pytest
+
+    from vllm_tpu.config import ObservabilityConfig
+    from vllm_tpu.engine.arg_utils import AsyncEngineArgs
+
+    assert {f.name for f in dataclasses.fields(ObservabilityConfig)} == {
+        "collect_detailed_traces", "otlp_traces_endpoint", "log_stats",
+        "log_stats_interval_s", "request_trace_dir", "slo_targets"}
+    parser = AsyncEngineArgs.add_cli_args(argparse.ArgumentParser())
+    args = parser.parse_args(["--model", "m", "--request-trace-dir", "/t",
+                              "--slo-targets", "a=ttft:1s"])
+    assert (args.request_trace_dir, args.slo_targets) == ("/t", "a=ttft:1s")
+    with pytest.raises(SystemExit) as exc:
+        parser.parse_args(["--model", "m", "--no-such-interval-s", "1"])
+    assert exc.value.code == 2
+    assert ("unrecognized arguments: --no-such-interval-s"
+            in capsys.readouterr().err)

@@ -1,7 +1,7 @@
 """The one profiler control: ``/start_profile`` and ``/stop_profile`` arm a
-call that the engine-loop thread executes between two steps, the engine's
-own spans land on the profiler's clock while a profile runs, and perfwatch's
-captures go through the same start/stop pair. Tiny model, CPU."""
+call that the engine-loop thread executes between two steps, and the
+engine's own spans land on the profiler's clock while a profile runs. Tiny
+model, CPU."""
 
 from __future__ import annotations
 
@@ -141,45 +141,6 @@ def test_profile_over_http_runs_on_the_engine_thread_while_tokens_stream(
     assert any(STEP_SPANS <= evs for evs in lines.values())
     # The python tracer is off: no frame events ("$file.py:line func").
     assert not [n for n in names if n.startswith("$") or ".py:" in n]
-
-
-def test_perfwatch_capture_goes_through_the_same_pair(engine, monkeypatch):
-    """``_begin/_finish/_abort_perf_capture`` reach ``jax.profiler`` only
-    through ``tracing.start_profiler`` / ``stop_profiler``."""
-    calls = []
-    real_start, real_stop = tracing.start_profiler, tracing.stop_profiler
-
-    def start(*a, **k):
-        calls.append("start")
-        return real_start(*a, **k)
-
-    def stop():
-        calls.append("stop")
-        return real_stop()
-
-    monkeypatch.setattr(tracing, "start_profiler", start)
-    monkeypatch.setattr(tracing, "stop_profiler", stop)
-    core = engine.engine_core.engine_core
-    core._ensure_perfwatch()
-
-    def capture_then_abort():
-        core._begin_perf_capture(steps=1)
-        assert core.perfwatch.active is not None
-        assert tracing.profiler_running()["thread"] == "engine-core"
-        # While it runs, the HTTP control's start is refused too.
-        with pytest.raises(tracing.ProfilerBusyError):
-            core.start_profile()
-        core._abort_perf_capture("test")
-        return True
-
-    # Run it where the engine loop would: on the engine-loop thread.
-    monkeypatch.setattr(engine.engine_core, "_capture_then_abort",
-                        capture_then_abort, raising=False)
-    assert engine.call_on_engine_thread(
-        "_capture_then_abort").result(timeout=60) is True
-    # The capture's start, the refused second start, the abort's stop.
-    assert calls == ["start", "start", "stop"]
-    assert tracing.profiler_running() is None
 
 
 def test_trace_span_with_both_sinks_off_imports_no_jax():

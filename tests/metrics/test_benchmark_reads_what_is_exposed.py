@@ -1,0 +1,95 @@
+"""Every ``vllm:`` series a reader of the benchmark names is on ``/metrics``.
+
+The per-layer readers (``benchmark/layer_metrics/*.py``, and the kernel
+counts they cite) find their series by name in two scrapes; a series that
+was renamed or removed makes the reader return ``None`` and the ledger
+print ``null``, on the chip, after the PR. This reads the readers' sources
+(it never edits them) and holds the registry to them on the CPU: each name,
+and each ``phase`` / ``kind`` label value beside it, must come out of
+``PrometheusRegistry`` as a SAMPLE (a labelled family with no sample is a
+key the scrape does not have).
+"""
+
+from __future__ import annotations
+
+import re
+from pathlib import Path
+
+import pytest
+
+from vllm_tpu.core.sched_output import STEP_PHASES, SchedulerStats
+from vllm_tpu.metrics.prometheus import PrometheusRegistry
+from vllm_tpu.metrics.stats import IterationStats
+
+BENCHMARK = Path(__file__).resolve().parents[2] / "benchmark"
+NAME_RE = re.compile(r"vllm:[a-z0-9_]+")
+# ``name{kind="block"}`` as written, in code or in a docstring.
+LABELLED_RE = re.compile(r'(vllm:[a-z0-9_]+)\{(\w+)="(\w+)"\}')
+# ``f'...{{phase="{p}"}}'`` with ``for p in ("update", "output")`` beside it.
+FSTRING_LABEL_RE = re.compile(r'\{\{(\w+)="\{(\w+)\}"\}\}')
+
+
+def _readers() -> list[Path]:
+    files = sorted((BENCHMARK / "layer_metrics").glob("*.py"))
+    files.append(BENCHMARK / "kernels" / "block_attn.py")
+    return [f for f in files if NAME_RE.search(f.read_text())]
+
+
+def _family(name: str) -> str:
+    """``x_sum`` / ``x_count`` / ``x_bucket`` are samples of ``x``, and a
+    reader asks for a counter as ``x_total`` or ``x``: one family."""
+    name = re.sub(r"_(sum|count|bucket)$", "", name)
+    return re.sub(r"_total$", "", name)
+
+
+@pytest.fixture(scope="module")
+def exposed() -> dict[str, set[tuple[str, str]]]:
+    """family -> the (label, value) pairs its samples carry, out of a
+    registry that has seen one step of every kind."""
+    phases = {f"step_{p}_times": [0.001] for p in STEP_PHASES}
+    reg = PrometheusRegistry()
+    reg.record(
+        SchedulerStats(
+            queue_times=[0.01], bucket_compiles=1, num_preempted_reqs=1,
+            prefix_cache_queries=8, prefix_cache_hits=4,
+            step_first_use_s=7.5, sampler_fallback_rows=3,
+            steps_by_kind={"decode": 9, "mixed": 1},
+            attn_context_tokens_by_kind={"decode": 180, "mixed": 10,
+                                         "block": 11},
+            diffusion_passes_by_kind={"denoise": 2, "store": 1},
+            diffusion_committed_tokens=3, moe_assignments=5,
+            moe_experts_hit=7, **phases),
+        IterationStats(num_generation_tokens=12, num_prompt_tokens=7,
+                       ttfts=[0.05], output_s=0.0005))
+    out: dict[str, set[tuple[str, str]]] = {}
+    for line in reg.render().splitlines():
+        if not line or line.startswith("#"):
+            continue
+        key = line.rpartition(" ")[0]
+        name, _, labels = key.partition("{")
+        out.setdefault(_family(name), set()).update(
+            re.findall(r'(\w+)="([^"]*)"', labels))
+    return out
+
+
+def test_the_readers_are_found():
+    # An empty list would pass the cases below by having none.
+    assert _readers(), f"no reader under {BENCHMARK} names a vllm: series"
+
+
+@pytest.mark.parametrize("reader", _readers(), ids=lambda p: p.stem)
+def test_reader_names_only_series_that_are_exposed(reader, exposed):
+    source = reader.read_text()
+    families = {_family(n) for n in NAME_RE.findall(source)}
+    missing = sorted(f for f in families if f not in exposed)
+    assert not missing, f"{reader.name} reads {missing}: not on /metrics"
+
+    for name, label, value in LABELLED_RE.findall(source):
+        assert (label, value) in exposed[_family(name)], (
+            f'{reader.name} reads {name}{{{label}="{value}"}}: no such sample')
+    for label, var in FSTRING_LABEL_RE.findall(source):
+        values = re.search(rf"for {var} in \(([^)]*)\)", source)
+        assert values, f"{reader.name}: cannot tell what {var} runs over"
+        for value in re.findall(r'"(\w+)"', values.group(1)):
+            assert any((label, value) in exposed[f] for f in families), (
+                f'{reader.name} reads {label}="{value}": no such sample')

@@ -12,12 +12,17 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))
 
 
-def test_registry_lint_clean():
+def _lint():
     sys.path.insert(0, os.path.join(_REPO_ROOT, "tools"))
     try:
         import check_metrics
     finally:
         sys.path.pop(0)
+    return check_metrics
+
+
+def test_registry_lint_clean():
+    check_metrics = _lint()
     assert check_metrics.check() == []
 
 
@@ -34,11 +39,7 @@ def test_lint_cli_exit_code():
 def test_lint_holds_the_exposition_to_the_step_phases_and_kinds(monkeypatch):
     """A phase that stops reaching /metrics is a lint error, not a per-layer
     metric that silently reads None."""
-    sys.path.insert(0, os.path.join(_REPO_ROOT, "tools"))
-    try:
-        import check_metrics
-    finally:
-        sys.path.pop(0)
+    check_metrics = _lint()
     assert check_metrics.check_step_exposition() == []
     assert {"prepare", "fetch", "update", "output"} <= set(
         check_metrics.REQUIRED_STEP_PHASES)
@@ -46,3 +47,33 @@ def test_lint_holds_the_exposition_to_the_step_phases_and_kinds(monkeypatch):
                         check_metrics.REQUIRED_STEP_PHASES + ("sample",))
     [err] = check_metrics.check_step_exposition()
     assert "sample" in err
+
+
+def test_lint_names_a_metric_dropped_from_the_render_list(monkeypatch):
+    """What the old count of metrics stood guard over, held by name: a
+    metric that a refactor leaves on the registry but drops from the render
+    list, and a required name that is gone, are each an error that says
+    which."""
+    check_metrics = _lint()
+    from vllm_tpu.metrics import prometheus
+
+    real_init = prometheus.PrometheusRegistry.__init__
+
+    def init_without_fallback_rows(self, *a, **k):
+        real_init(self, *a, **k)
+        self._metrics = [m for m in self._metrics
+                         if m.name != "vllm:sampler_fallback_rows_total"]
+
+    monkeypatch.setattr(prometheus.PrometheusRegistry, "__init__",
+                        init_without_fallback_rows)
+    errors = check_metrics.check()
+    assert any("vllm:sampler_fallback_rows_total" in e
+               and "never render" in e for e in errors), errors
+
+    monkeypatch.setattr(prometheus.PrometheusRegistry, "__init__", real_init)
+    monkeypatch.setattr(
+        check_metrics, "REQUIRED_SAMPLER_METRICS",
+        check_metrics.REQUIRED_SAMPLER_METRICS | {"vllm:no_such_series"})
+    assert [e for e in check_metrics.check()
+            if "vllm:no_such_series" in e and "missing" in e]
+    assert not hasattr(check_metrics, "MIN_METRICS")

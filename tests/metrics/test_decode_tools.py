@@ -4,10 +4,7 @@
   sweep in Pallas interpret mode against the XLA reference;
 - ``tools/probe_sampler.py --smoke``: the fused sampling kernel's
   block-shape sweep, bit-exact against the XLA sampling epilogue;
-- ``tools/profile_decode.py``: the full engine-under-profiler path at a
-  tiny CPU shape (xplane written, graceful no-device-ops report);
-- the op classifier feeding both the profiler's phase table and
-  bench.py's ``device_ms`` JSON split.
+- ``metrics/goodput.py``'s summary schema.
 """
 
 from __future__ import annotations
@@ -15,8 +12,6 @@ from __future__ import annotations
 import os
 import subprocess
 import sys
-
-import pytest
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))
@@ -47,69 +42,6 @@ def test_probe_sampler_smoke():
     assert "smoke sweep ok" in proc.stdout
     assert proc.stdout.count("MISMATCH") == 0
     assert proc.stdout.count("kernel rb=") == 4
-
-
-@pytest.mark.slow
-def test_profile_decode_smoke():
-    """Engine + profiler end to end on CPU (tiny model; slow: spins up a
-    full LLM engine)."""
-    proc = _run_tool("profile_decode.py")
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    # Either the CPU trace carried no device-op line (expected) or a
-    # plane was found and the phase table printed.
-    assert ("no device ops in trace" in proc.stdout
-            or "plane:" in proc.stdout), proc.stdout[-2000:]
-
-
-def test_classify_op_phases():
-    from vllm_tpu.metrics.op_split import PHASES, classify_op
-
-    assert classify_op("fused_ragged_paged_attention.42") == "attention"
-    assert classify_op("decode_kernel") == "attention"
-    assert classify_op("tpu_custom_call.7") == "attention"
-    assert classify_op("dot_general.12") == "matmul"
-    assert classify_op("fusion.matmul.3") == "matmul"
-    assert classify_op("sort.1") == "sampler"
-    assert classify_op("threefry2x32") == "sampler"
-    assert classify_op("copy.5") == "other"
-    # Collectives classify as comms even through the tpu_custom_call
-    # catch-all (Pallas collectives are custom calls too: the comms
-    # marks are checked first — ordered-first-hit contract).
-    assert classify_op("all-reduce.1") == "comms"
-    assert classify_op("fusion.all_gather.3") == "comms"
-    assert classify_op("reduce-scatter.2") == "comms"
-    assert classify_op("collective-permute.1") == "comms"
-    assert classify_op("ppermute_tpu_custom_call") == "comms"
-    assert set(PHASES) == {
-        "attention", "matmul", "sampler", "comms", "other"}
-
-
-def test_perf_ab_smoke():
-    """In-proc quiet-window kernel A/B end to end on CPU: tiny engine,
-    synthetic replay batch, sampler/decode-attention variants, artifact
-    schema validated (device_ms null on CPU, wall-clock source).
-    ``--base-only`` skips the second (adaptive-spec) engine — that
-    variant is covered by the slow-tier full smoke below."""
-    proc = _run_tool("perf_ab.py", "--smoke", "--base-only")
-    assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-2000:])
-    assert "perf_ab smoke ok" in proc.stdout
-
-
-@pytest.mark.slow
-def test_perf_ab_smoke_adaptive():
-    """Full smoke including the second ngram + --spec-adaptive engine:
-    validates the ``ab.adaptive_spec`` on/off pair schema (slow: builds
-    two engines back to back)."""
-    proc = _run_tool("perf_ab.py", "--smoke")
-    assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-2000:])
-    assert "perf_ab smoke ok" in proc.stdout
-    assert '"adaptive_spec"' in proc.stdout
-
-
-def test_op_split_ms_empty_dir(tmp_path):
-    from vllm_tpu.metrics.op_split import op_split_ms
-
-    assert op_split_ms(str(tmp_path)) is None
 
 
 def test_goodput_summary_schema():

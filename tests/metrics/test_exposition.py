@@ -57,11 +57,6 @@ def _populated_registry() -> PrometheusRegistry:
         step_first_use_s=7.5,
         batch_num_tokens=96, batch_num_reqs=3, batch_occupancy=0.75,
         step_interval_s=0.006,
-        perfwatch_captures=2, perfwatch_captures_aborted=1,
-        perfwatch_device_ms={"attention": 3.2, "matmul": 1.1,
-                             "sampler": 0.4, "comms": 0.2, "other": 0.1,
-                             "total": 5.0},
-        perfwatch_mfu_est=0.16, perfwatch_hbm_bw_util_est=0.7,
     )
     it = IterationStats(
         num_generation_tokens=12, num_prompt_tokens=7,
@@ -297,26 +292,6 @@ def test_runner_counts_decode_only_against_mixed_on_a_hand_made_batch(
     assert runner.attn_context_tokens_by_kind["decode"] == 2 * decode_ctx
     assert runner.bucket_compiles == 2
     assert f'kind="decode"}} {float(2 * decode_ctx)}' in reg.render()
-
-
-def test_perfwatch_family_renders():
-    """The perfwatch capture's attribution lands as a phase-labeled
-    gauge family plus roofline gauges and ratcheting counters."""
-    text = _populated_registry().render()
-    assert 'vllm:device_time_ms_per_step{phase="attention"} 3.2' in text
-    assert 'vllm:device_time_ms_per_step{phase="comms"} 0.2' in text
-    assert 'vllm:device_time_ms_per_step{phase="total"} 5.0' in text
-    assert "vllm:mfu_est 0.16" in text
-    assert "vllm:hbm_bw_util_est 0.7" in text
-    assert "vllm:perfwatch_captures_total 2.0" in text
-    assert "vllm:perfwatch_captures_aborted_total 1.0" in text
-
-    # Counters ratchet: a stats snapshot from a respawned engine (zeros)
-    # must not decrease the rendered totals.
-    reg = _populated_registry()
-    reg.record(SchedulerStats())
-    text = reg.render()
-    assert "vllm:perfwatch_captures_total 2.0" in text
 
 
 def test_resilience_counters_never_decrease():

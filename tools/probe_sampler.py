@@ -10,8 +10,8 @@ rows (one JSON line a point, and ``chiprun_out/probe_sampler.json``);
 exits non-zero on a disagreement. This is the on-chip check of the
 sampler that the benchmark's ``correct`` does not make. With ``--sweep``:
 batch x vocab at serving shapes and, per point, the kernel's (row_block,
-logits_tile) grid — wall-clock per call plus the ``metrics/op_split.py``
-device-time attribution, the A/B that tunes the dispatch defaults.
+logits_tile) grid — wall-clock per call, the sweep that tunes the
+dispatch defaults.
 
 On CPU (or ``--smoke``): the kernel runs in Pallas interpret mode at a
 tiny shape across the block-size sweep points and must be BIT-EXACT
@@ -167,7 +167,6 @@ def tpu_points(out_path="chiprun_out/probe_sampler.json"):
 def tpu_sweep():
     import functools
 
-    from vllm_tpu.metrics.op_split import profile_op_split
     from vllm_tpu.ops.sampler_kernel import fused_sample
     from vllm_tpu.sample.sampler import sample
 
@@ -211,13 +210,6 @@ def tpu_sweep():
                     except Exception as e:  # noqa: BLE001
                         print(f"    rb={row_block} tile={tile} failed: "
                               f"{type(e).__name__}: {str(e)[:120]}")
-
-            # Device-time attribution at the default block shape — the
-            # number the bench JSON's "sampler" split reports.
-            split = profile_op_split(
-                lambda: ref_fn(logits, md).block_until_ready())
-            if split:
-                print(f"    ref op split: {split}")
 
 
 def smoke_sweep():

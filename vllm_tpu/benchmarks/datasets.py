@@ -2,7 +2,7 @@
 
 Reference analog: ``vllm/benchmarks/datasets/`` (ShareGPTDataset,
 RandomDataset, ...). The reference protocol samples 200 ShareGPT
-conversations with a fixed seed (BASELINE.md); this module provides
+conversations with a fixed seed; this module provides
 
 - :func:`load_sharegpt` — the real loader for a ShareGPT-format JSON file
   (``[{"conversations": [{"from": "human", "value": ...}, ...]}, ...]``),

@@ -255,7 +255,7 @@ class SchedulerConfig:
     # exact for greedy/seeded sampling (penalty-bearing requests are capped
     # at 2 in flight — the device-side count correction covers one token).
     # Default retuned 6 -> 3 after PR 8: at the post-PR8 step phase split
-    # (BENCH_r05: ~3ms host prep+dispatch vs ~188ms device wall) depth 2
+    # (old round: ~3ms host prep+dispatch vs ~188ms device wall) depth 2
     # already hides the host turnaround; 3 keeps one step of slack for
     # scheduler jitter while halving the stale-work window on aborts and
     # the depth-capped penalty-row exposure. See README knobs table.
@@ -455,19 +455,6 @@ class ObservabilityConfig:
     otlp_traces_endpoint: str | None = None
     log_stats: bool = True
     log_stats_interval_s: float = 10.0
-    # Perfwatch (vllm_tpu/metrics/perfwatch.py): periodic in-engine
-    # profiling windows + quiet-window kernel A/B. 0 = disabled (the
-    # engine core then carries no perfwatch state at all; on-demand
-    # captures via POST /debug/perf/capture still work and lazily
-    # create the subsystem).
-    perfwatch_interval_s: float = 0.0
-    # Decode/prefill steps per profiling window.
-    perfwatch_capture_steps: int = 8
-    # Profiled steps per kernel variant in the quiet-window A/B.
-    perfwatch_ab_steps: int = 8
-    # Continuous idle seconds before the engine counts as "quiet"
-    # (eligible for an A/B replay).
-    perfwatch_quiet_settle_s: float = 2.0
     # SLO scoreboard (vllm_tpu/metrics/reqtrace.py): directory for the
     # append-only request-trace JSONL. None = capture fully disabled
     # (no recorder object, no per-request work).

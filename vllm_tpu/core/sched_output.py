@@ -298,15 +298,6 @@ class SchedulerStats:
     batch_num_reqs: int = 0
     batch_occupancy: float = 0.0
     step_interval_s: float = 0.0
-    # Perfwatch (attached by EngineCore when armed): cumulative capture /
-    # abort counts, the last profiling window's per-step device-time
-    # split ({phase: ms} or None), and its live roofline estimates
-    # (None until a capture lands; -0 values are real zeros).
-    perfwatch_captures: int = 0
-    perfwatch_captures_aborted: int = 0
-    perfwatch_device_ms: dict | None = None
-    perfwatch_mfu_est: float | None = None
-    perfwatch_hbm_bw_util_est: float | None = None
     # Tiered KV fabric snapshot (attached by EngineCore when the fabric
     # connector is active): per-tier resident blocks, cumulative fetch
     # outcomes / demotions / transferred bytes. None = fabric off.

@@ -204,23 +204,20 @@ class Scheduler:
         # exhausting their claimed budget.
         self._decode_step_lengths: list[int] = []
         self._decode_early_exits = 0
-        # Cumulative realized-K histogram {length: launches} — never
-        # drained; bench.py reads it after scoring passes to report the
-        # realized step-length distribution next to the throughput score.
+        # Cumulative realized-K histogram {length: launches}, never
+        # drained: the realized step-length distribution of the dynamic
+        # loop, for whoever holds the scheduler (the tests do).
         self.decode_len_hist: dict[int, int] = {}
-        # No-restart disable switch for the dynamic loop: the in-engine
-        # perf A/B harness flips this directly to measure dynamic-vs-fixed
-        # on live traffic; VLLM_TPU_DISABLE_DYNAMIC_DECODE is the env
-        # spelling and --disable-dynamic-decode the config spelling of
-        # the same switch.
+        # Escape hatch for the dynamic loop, fixed at construction:
+        # --disable-dynamic-decode, or VLLM_TPU_DISABLE_DYNAMIC_DECODE
+        # in the environment.
         self.disable_dynamic_decode = scheduler_config.disable_dynamic_decode
         # Adaptive speculation: acceptance-driven draft budgets + the
         # occupancy-gated shutoff (spec_decode/adaptive.py). The
         # controller is a pure host-side state machine the scheduler
         # consults at schedule time and feeds from verification results;
-        # disable_adaptive_spec is the no-restart A/B switch (the perf
-        # harness flips it; VLLM_TPU_DISABLE_ADAPTIVE_SPEC is the env
-        # spelling).
+        # VLLM_TPU_DISABLE_ADAPTIVE_SPEC in the environment is its
+        # escape hatch.
         self.adaptive_spec = None
         if (
             scheduler_config.spec_adaptive
@@ -244,7 +241,6 @@ class Scheduler:
                 ),
                 tree=tree,
             )
-        self.disable_adaptive_spec = False
         # Realized per-request draft lengths of spec verification steps
         # (drained by make_stats — feeds vllm:spec_decode_draft_len).
         self._spec_draft_lens: list[int] = []
@@ -518,10 +514,9 @@ class Scheduler:
     # ------------------------------------------------------------------
 
     def _adaptive_spec_on(self) -> bool:
-        """Controller present and not disabled by the A/B switch or env."""
+        """Controller present and not disabled by the env."""
         return (
             self.adaptive_spec is not None
-            and not self.disable_adaptive_spec
             and not envs.VLLM_TPU_DISABLE_ADAPTIVE_SPEC
         )
 

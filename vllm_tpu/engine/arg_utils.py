@@ -183,12 +183,6 @@ class EngineArgs:
     max_preemptions_per_request: int = 4
 
     disable_log_stats: bool = False
-    # Perfwatch: periodic in-engine profiling windows (0 = off; the
-    # /debug/perf/capture endpoint still works on demand).
-    perfwatch_interval_s: float = 0.0
-    perfwatch_capture_steps: int = 8
-    perfwatch_ab_steps: int = 8
-    perfwatch_quiet_settle_s: float = 2.0
     # SLO scoreboard: request-trace capture directory (None = off) and
     # the per-class latency targets feeding the live attainment gauge
     # ("interactive=ttft:200ms,itl:50ms;batch=ttft:5s").
@@ -300,10 +294,6 @@ class EngineArgs:
             ),
             observability_config=ObservabilityConfig(
                 log_stats=not self.disable_log_stats,
-                perfwatch_interval_s=self.perfwatch_interval_s,
-                perfwatch_capture_steps=self.perfwatch_capture_steps,
-                perfwatch_ab_steps=self.perfwatch_ab_steps,
-                perfwatch_quiet_settle_s=self.perfwatch_quiet_settle_s,
                 request_trace_dir=self.request_trace_dir,
                 slo_targets=self.slo_targets,
             ),

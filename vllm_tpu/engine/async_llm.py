@@ -530,13 +530,6 @@ class AsyncLLM:
         poll_mesh = getattr(self.engine_core, "poll_mesh", None)
         if poll_mesh is not None:
             poll_mesh()
-        # Perfwatch capture/A-B scheduling rides the same tick: this IS
-        # the engine loop thread, so a due quiet-window replay can step
-        # the engine right here without racing live traffic. (In-proc
-        # client only; MP engines poll in their own busy loop.)
-        poll_perfwatch = getattr(self.engine_core, "poll_perfwatch", None)
-        if poll_perfwatch is not None:
-            poll_perfwatch()
         # Elastic-capacity tick (DP pool only): advance any in-flight
         # scale event and run the controller. Runs even when idle — a
         # drained-quiet pool is exactly when scale-down fires. May raise

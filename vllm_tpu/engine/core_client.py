@@ -177,23 +177,8 @@ class InprocClient:
     def stop_profile(self) -> dict:
         return self.engine_core.stop_profile()
 
-    def perf_status(self) -> dict:
-        return self.engine_core.perf_status()
-
-    def perf_capture(self, opts: dict | None = None) -> dict:
-        return self.engine_core.perf_capture(opts)
-
-    def perf_ab(self, opts: dict | None = None) -> dict:
-        return self.engine_core.perf_ab(opts)
-
     def kv_fabric_status(self) -> dict:
         return self.engine_core.kv_fabric_status()
-
-    def poll_perfwatch(self) -> None:
-        """Drive perfwatch capture/A-B scheduling (no-op when disabled).
-        Called from the AsyncLLM engine loop thread — the only thread
-        allowed to step the in-proc engine."""
-        self.engine_core.poll_perfwatch()
 
     @property
     def inflight(self) -> bool:
@@ -624,20 +609,6 @@ class _ZMQClientBase:
 
     def stop_profile(self) -> dict:
         return self._utility("stop_profile", timeout_ms=60_000)
-
-    def perf_status(self) -> dict:
-        return self._utility("perf_status", timeout_ms=30_000)
-
-    def perf_capture(self, opts: dict | None = None) -> dict:
-        # Arms only; the engine-core busy loop executes the window.
-        return self._utility("perf_capture", opts, timeout_ms=30_000)
-
-    def perf_ab(self, opts: dict | None = None) -> dict:
-        # Runs synchronously inside the engine-core process's utility
-        # dispatch (its busy loop is the engine loop, so stepping the
-        # synthetic replay there is safe). Warm-up compiles per variant
-        # make this slow on first use.
-        return self._utility("perf_ab", opts, timeout_ms=600_000)
 
     def kv_fabric_status(self) -> dict:
         return self._utility("kv_fabric_status", timeout_ms=60_000)

@@ -356,10 +356,6 @@ def run_engine_core(config_bytes: bytes, input_addr: str,
             # through the generic death path below: cleanly dead, never
             # half-meshed.
             poll_mesh()
-            # Perfwatch: advance capture/A-B scheduling (single None
-            # check when disabled). Runs on this thread — the engine
-            # loop — so a quiet-window replay may step the engine here.
-            core.poll_perfwatch()
             # Report BEFORE stepping: step() can block inside a cross-rank
             # collective, and idle ranks only join once the coordinator has
             # seen this rank's load (reference: DPEngineCoreProc reports at

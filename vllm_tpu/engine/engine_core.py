@@ -136,9 +136,8 @@ class EngineCore:
                 pass  # byte gauge reads 0 for the device tier
             if hasattr(self.kv_connector, "set_roofline"):
                 # Hand the fabric's cost model the worker's measured
-                # RooflineModel: the fetch-vs-recompute arbiter and the
-                # engine's perf telemetry agree on device capability by
-                # construction.
+                # RooflineModel: the fetch-vs-recompute arbiter prices
+                # recompute by what the device can do.
                 try:
                     from vllm_tpu.metrics.roofline import RooflineModel
 
@@ -170,17 +169,6 @@ class EngineCore:
             getattr(config, "resilience_config", None))
         if self.mesh_recovery is not None:
             self.mesh_recovery.start()
-        # Perfwatch (live roofline telemetry + quiet-window kernel A/B):
-        # None unless --perfwatch-interval-s > 0 — like mesh_recovery,
-        # the disabled state carries zero per-step overhead (every hook
-        # is one None check). On-demand captures (POST
-        # /debug/perf/capture) lazily create the subsystem.
-        self.perfwatch = None
-        self._perf_roofline: object = None  # RooflineModel | False cache
-        self._perf_ab_nonce = 0
-        obs = getattr(config, "observability_config", None)
-        if obs is not None and getattr(obs, "perfwatch_interval_s", 0) > 0:
-            self._ensure_perfwatch()
 
     def _make_structured_output_manager(self):
         from vllm_tpu.engine.input_processor import get_tokenizer
@@ -368,11 +356,6 @@ class EngineCore:
                 self._itl_samples.append((self._step_interval_s, burst))
         self._last_step_end = now
         self._attach_engine_stats(outputs)
-        if self.perfwatch is not None and self.perfwatch.active is not None:
-            # A profiling window is open over live traffic: count this
-            # finalized step; close the window at its target.
-            if self.perfwatch.note_step():
-                self._finish_perf_capture()
         for o in outputs.outputs:
             if self._req_trace_phase:
                 self._trace_request_progress(o)
@@ -468,9 +451,6 @@ class EngineCore:
             watchdog = getattr(runner, "watchdog", None)
             if watchdog is not None:
                 stats.step_watchdog_trips = watchdog.trips
-        if self.perfwatch is not None:
-            for key, value in self.perfwatch.stats_fields().items():
-                setattr(stats, key, value)
         if self.kv_connector is not None and hasattr(
             self.kv_connector, "fabric_stats"
         ):
@@ -923,10 +903,10 @@ class EngineCore:
                       python_tracer_level: int = 0) -> dict:
         """Start a JAX profile (and with it the engine's own spans on the
         profile's clock). With ``stop_profile`` the one way to profile:
-        HTTP ``/start_profile``, the multiprocess utility call and
-        perfwatch's captures all come through here, and only on the thread
-        that calls ``step()``, between two steps. Raises
-        ``ProfilerBusyError`` while a profile runs."""
+        HTTP ``/start_profile`` and the multiprocess utility call come
+        through here, and only on the thread that calls ``step()``,
+        between two steps. Raises ``ProfilerBusyError`` while a profile
+        runs."""
         return self.executor.collective_rpc(
             "start_profile", trace_dir, python_tracer_level)[0]
 
@@ -934,434 +914,6 @@ class EngineCore:
         """Stop the running profile: its directory, the seconds traced and
         the seconds the stop itself took."""
         return self.executor.collective_rpc("stop_profile")[0]
-
-    # ------------------------------------------------------------------
-    # Perfwatch: live roofline telemetry + quiet-window kernel A/B
-    # (vllm_tpu/metrics/perfwatch.py holds the state machines; this
-    # class owns the profiler/RPC/scheduler side effects.)
-    # ------------------------------------------------------------------
-
-    def _ensure_perfwatch(self):
-        if self.perfwatch is None:
-            from vllm_tpu.metrics.perfwatch import PerfWatch
-
-            obs = getattr(self.config, "observability_config", None)
-            self.perfwatch = PerfWatch(
-                interval_s=getattr(obs, "perfwatch_interval_s", 0.0),
-                capture_steps=getattr(obs, "perfwatch_capture_steps", 8),
-                ab_steps=getattr(obs, "perfwatch_ab_steps", 8),
-                quiet_settle_s=getattr(
-                    obs, "perfwatch_quiet_settle_s", 2.0),
-            )
-        return self.perfwatch
-
-    def _perf_runner(self):
-        return getattr(
-            getattr(self.executor, "worker", None), "runner", None
-        )
-
-    def _perf_counters(self) -> dict:
-        runner = self._perf_runner()
-        if runner is None:
-            return {}
-        return {
-            "launch_sampled_tokens": getattr(
-                runner, "launch_sampled_tokens", 0),
-            "step_launches": getattr(runner, "step_launches", 0),
-        }
-
-    def _perf_roofline_model(self):
-        """The model's RooflineModel, fetched once from the worker
-        (False caches a fetch failure so captures don't re-RPC)."""
-        if self._perf_roofline is None:
-            try:
-                from vllm_tpu.metrics.roofline import RooflineModel
-
-                info = self.executor.collective_rpc("roofline_info")[0]
-                self._perf_roofline = RooflineModel.from_dict(info)
-            except Exception as exc:
-                logger.warning("perfwatch: roofline info unavailable: %s",
-                               exc)
-                self._perf_roofline = False
-        return self._perf_roofline or None
-
-    def perf_status(self) -> dict:
-        """GET /debug/perf payload."""
-        if self.perfwatch is None:
-            return {"enabled": False, "captures_total": 0,
-                    "captures_aborted_total": 0, "last_capture": None,
-                    "last_ab": None}
-        return self.perfwatch.status()
-
-    def perf_capture(self, opts: dict | None = None) -> dict:
-        """Arm a one-shot capture ("capture"), quiet-window A/B ("ab"),
-        or whichever fits ("auto", default). Thread-safe: only ARMS —
-        the engine loop thread executes via poll_perfwatch()/step()
-        hooks, so an HTTP handler never drives the device."""
-        opts = opts or {}
-        pw = self._ensure_perfwatch()
-        return pw.arm(
-            mode=opts.get("mode", "auto"),
-            steps=opts.get("steps"),
-            force=bool(opts.get("force")),
-        )
-
-    def perf_ab(self, opts: dict | None = None) -> dict:
-        """Run the kernel A/B NOW, in the caller's thread. Safe only
-        where the caller owns the engine loop (bench.py's synchronous
-        embedding, the MP utility dispatcher, poll_perfwatch). Never
-        runs over live traffic — even forced."""
-        opts = opts or {}
-        self._ensure_perfwatch()
-        if self.has_unfinished_requests():
-            return {"error": "engine busy; the A/B replay needs a quiet "
-                             "engine (retry when idle)"}
-        return self._run_perf_ab(steps=opts.get("steps"))
-
-    def poll_perfwatch(self) -> None:
-        """Busy-loop hook (async_llm._step_once / core_proc loop):
-        advance the quiet-window machine and start anything due. A
-        single None check when perfwatch is disabled."""
-        pw = self.perfwatch
-        if pw is None:
-            return
-        busy = self.has_unfinished_requests()
-        if pw.active is not None:
-            if not busy:
-                # Traffic dried up mid-window: keep a partial window
-                # (>= 1 step is still an attribution) or abort an empty
-                # one.
-                if pw.active["done"] >= 1:
-                    self._finish_perf_capture()
-                else:
-                    self._abort_perf_capture("engine went idle before "
-                                             "any step completed")
-            return
-        action = pw.poll(busy)
-        if action == "capture":
-            self._begin_perf_capture()
-        elif action == "ab":
-            self._run_perf_ab()
-
-    def _begin_perf_capture(self, steps: int | None = None) -> None:
-        import shutil
-        import tempfile
-
-        pw = self.perfwatch
-        trace_dir = tempfile.mkdtemp(prefix="perfwatch-")
-        try:
-            self.start_profile(trace_dir)
-        except Exception as exc:
-            logger.warning("perfwatch: start_profile failed: %s", exc)
-            shutil.rmtree(trace_dir, ignore_errors=True)
-            return
-        pw.begin_capture(trace_dir, steps, self._perf_counters())
-
-    def _finish_perf_capture(self) -> dict | None:
-        import shutil
-
-        from vllm_tpu.metrics.op_split import OpSplitStream
-
-        pw = self.perfwatch
-        sess = pw.active
-        if sess is None:
-            return None
-        trace_dir = sess["trace_dir"]
-        try:
-            self.stop_profile()
-        except Exception as exc:
-            logger.warning("perfwatch: stop_profile failed: %s", exc)
-            pw.abort_capture(str(exc))
-            shutil.rmtree(trace_dir, ignore_errors=True)
-            return None
-        try:
-            stream = OpSplitStream()
-            stream.add_trace(trace_dir)
-            split = stream.split_ms(scale=1.0 / max(sess["done"], 1))
-            ctx_tokens = sum(
-                r.num_computed_tokens for r in self.scheduler.running
-            )
-            snap = pw.finish_capture(
-                split, self._perf_counters(), ctx_tokens,
-                self._perf_roofline_model(),
-            )
-            logger.info("perfwatch capture: %s", snap)
-            return snap
-        finally:
-            shutil.rmtree(trace_dir, ignore_errors=True)
-
-    def _abort_perf_capture(self, reason: str) -> None:
-        import shutil
-
-        pw = self.perfwatch
-        sess = pw.active
-        if sess is None:
-            return
-        try:
-            self.stop_profile()
-        except Exception:
-            pass
-        shutil.rmtree(sess["trace_dir"], ignore_errors=True)
-        pw.abort_capture(reason)
-        logger.warning("perfwatch capture aborted: %s", reason)
-
-    def _perf_foreign_traffic(self) -> bool:
-        """True when anything besides perfwatch's own synthetic replay
-        requests is in the scheduler — the A/B must abort."""
-        from vllm_tpu.metrics.perfwatch import AB_REQUEST_PREFIX
-
-        for r in list(self.scheduler.waiting) + self.scheduler.running:
-            if not r.request_id.startswith(AB_REQUEST_PREFIX):
-                return True
-        return False
-
-    def _perf_inject_ab_batch(self, num_reqs: int, prompt_len: int,
-                              max_tokens: int) -> list[str]:
-        """Synthesize replay requests THROUGH the normal add_request
-        path: blocks are legitimately allocated by the scheduler, so the
-        replay can never scribble over prefix-cached KV."""
-        from vllm_tpu.metrics.perfwatch import AB_REQUEST_PREFIX
-        from vllm_tpu.sampling_params import SamplingParams
-
-        ids: list[str] = []
-        for i in range(num_reqs):
-            self._perf_ab_nonce += 1
-            rid = f"{AB_REQUEST_PREFIX}{self._perf_ab_nonce}"
-            # Unique per-request prefix so the replay never rides the
-            # prefix cache (a shared prefix would shrink the KV read the
-            # A/B is trying to measure).
-            toks = [(self._perf_ab_nonce * 31 + j * 7 + i) % 251 + 1
-                    for j in range(prompt_len)]
-            self.add_request(EngineCoreRequest(
-                request_id=rid,
-                prompt_token_ids=toks,
-                sampling_params=SamplingParams(
-                    temperature=1.0, seed=1234 + i,
-                    max_tokens=max_tokens, ignore_eos=True,
-                ),
-            ))
-            ids.append(rid)
-        return ids
-
-    def _perf_drain_ab(self, ids: list[str]) -> None:
-        """Abort the synthetic requests and drain in-flight steps (their
-        outputs are identity-guarded; nothing real is in the engine)."""
-        self.abort_requests(ids)
-        guard = 0
-        while self._inflight and guard < 64:
-            self.step()
-            guard += 1
-        self._drained_outputs.clear()
-
-    def _run_perf_ab(self, steps: int | None = None) -> dict:
-        """The quiet-window A/B: per kernel-dispatch variant, inject a
-        synthetic batch mirroring the last real traffic shape, run its
-        prefill unprofiled, profile N decode steps, and diff the
-        per-variant device_ms. Aborts (counted) the moment real traffic
-        arrives."""
-        import shutil
-        import tempfile
-
-        from vllm_tpu.metrics.op_split import OpSplitStream
-        from vllm_tpu.metrics.perfwatch import ab_delta_pct
-
-        pw = self.perfwatch
-        steps = max(1, int(steps or pw.ab_steps))
-        runner = self._perf_runner()
-        shape = (getattr(runner, "last_batch_shape", None)
-                 if runner is not None else None) or {}
-        if shape:
-            pw.last_batch_shape = dict(shape)
-        sched_cfg = self.config.scheduler_config
-        num_reqs = max(1, min(int(shape.get("num_reqs", 4)),
-                              sched_cfg.max_num_seqs))
-        # Dynamic multi-step decode A/B: only meaningful when the serving
-        # config can engage the device loop at all (multi-step on, a
-        # per-launch budget > 1, and the async pipeline dynamic needs).
-        dyn_capable = (sched_cfg.num_decode_steps > 1
-                       and sched_cfg.max_decode_steps_per_launch > 1
-                       and self.async_scheduling)
-        # Prompt length approximates the retained context depth, bounded
-        # so prompt + replay decodes fit the model length. Dynamic-on
-        # variants may realize up to the per-launch budget each step, so
-        # size max_tokens for the larger of the two amortization knobs —
-        # rows finishing by length mid-window would deflate the batch.
-        per_launch = max(sched_cfg.num_decode_steps, 1)
-        if dyn_capable:
-            per_launch = max(per_launch,
-                             sched_cfg.max_decode_steps_per_launch)
-        max_tokens = max(steps * per_launch + 32, 64)
-        prompt_len = max(8, min(
-            int(shape.get("ctx_tokens_per_req", 64)),
-            sched_cfg.max_model_len - max_tokens - 1,
-            sched_cfg.max_num_batched_tokens,
-        ))
-
-        variants = {
-            "on": {"enable_sampler_kernel": True,
-                   "enable_decode_attention": True},
-            "sampler_off": {"enable_sampler_kernel": False,
-                            "enable_decode_attention": True},
-            "decode_attn_off": {"enable_sampler_kernel": True,
-                                "enable_decode_attention": False},
-        }
-        if dyn_capable:
-            # Kernel flags stay at serving defaults; the off-switch is
-            # the scheduler's A/B attribute (no worker RPC — routing
-            # back to the fixed-K chain is a schedule-time decision).
-            variants["dynamic_off"] = {"enable_sampler_kernel": True,
-                                       "enable_decode_attention": True,
-                                       "_disable_dynamic": True}
-        if getattr(self.scheduler, "adaptive_spec", None) is not None:
-            # Adaptive speculation on/off: the off side pins every
-            # request at the full static draft budget (the controller
-            # keeps learning; only its schedule-time verdicts are
-            # bypassed), so the pair isolates the drafting policy.
-            variants["adaptive_spec_off"] = {
-                "enable_sampler_kernel": True,
-                "enable_decode_attention": True,
-                "_disable_adaptive_spec": True,
-            }
-        measured: dict[str, dict] = {}
-        aborted_reason: str | None = None
-        prev_flags = None
-        prev_dyn = self.scheduler.disable_dynamic_decode
-        prev_adaptive = self.scheduler.disable_adaptive_spec
-        try:
-            for name, spec in variants.items():
-                flags = {k: v for k, v in spec.items()
-                         if not k.startswith("_")}
-                self.scheduler.disable_dynamic_decode = bool(
-                    spec.get("_disable_dynamic", prev_dyn))
-                self.scheduler.disable_adaptive_spec = bool(
-                    spec.get("_disable_adaptive_spec", prev_adaptive))
-                prev = self.executor.collective_rpc(
-                    "set_kernel_flags", flags)[0]
-                if prev_flags is None:
-                    prev_flags = prev  # the serving config, restored below
-                ids = self._perf_inject_ab_batch(
-                    num_reqs, prompt_len, max_tokens)
-                trace_dir = None
-                try:
-                    # Unprofiled warm-up: complete every prefill (and
-                    # compile this variant's decode step) before timing.
-                    guard = 0
-                    while guard < 256:
-                        if self._perf_foreign_traffic():
-                            aborted_reason = "request arrived during A/B"
-                            break
-                        running = self.scheduler.running
-                        if (running and not self.scheduler.waiting
-                                and all(r.num_computed_tokens
-                                        >= r.num_prompt_tokens
-                                        for r in running)):
-                            break
-                        self.step()
-                        guard += 1
-                    if aborted_reason:
-                        break
-                    # Flush in-flight prefill steps out of the async
-                    # pipeline so the profiled window sees pure decode.
-                    for _ in range(self._max_inflight + 1):
-                        self.step()
-                    trace_dir = tempfile.mkdtemp(prefix="perfwatch-ab-")
-                    self.start_profile(trace_dir)
-                    t0 = time.monotonic()
-                    done = 0
-                    for _ in range(steps):
-                        if self._perf_foreign_traffic():
-                            aborted_reason = ("request arrived "
-                                              "mid-quiet-window")
-                            break
-                        self.step()
-                        done += 1
-                    wall_s = time.monotonic() - t0
-                    self.stop_profile()
-                    if aborted_reason:
-                        break
-                    stream = OpSplitStream()
-                    stream.add_trace(trace_dir)
-                    split = stream.split_ms(scale=1.0 / max(done, 1))
-                    measured[name] = {
-                        "device_ms": (split["total"]
-                                      if split is not None else None),
-                        "split": split,
-                        "wall_ms": round(wall_s / max(done, 1) * 1e3, 3),
-                        "steps": done,
-                    }
-                finally:
-                    if trace_dir is not None:
-                        shutil.rmtree(trace_dir, ignore_errors=True)
-                    self._perf_drain_ab(ids)
-        except Exception as exc:
-            # A failed variant (profiler already active, compile error)
-            # must degrade to an aborted A/B, never crash the engine
-            # loop that hosts the replay.
-            logger.warning("perfwatch A/B failed: %s", exc)
-            try:
-                self.stop_profile()
-            except Exception:
-                pass
-            aborted_reason = f"error: {exc}"
-        finally:
-            self.scheduler.disable_dynamic_decode = prev_dyn
-            self.scheduler.disable_adaptive_spec = prev_adaptive
-            if prev_flags is not None:
-                self.executor.collective_rpc(
-                    "set_kernel_flags", prev_flags)
-
-        if aborted_reason is not None:
-            logger.warning("perfwatch A/B aborted: %s", aborted_reason)
-            return pw.note_ab({
-                "kind": "ab", "aborted": True, "reason": aborted_reason,
-            })
-
-        def pair(off_name: str) -> dict:
-            on, off = measured.get("on", {}), measured.get(off_name, {})
-            dev_on, dev_off = on.get("device_ms"), off.get("device_ms")
-            wall_on, wall_off = on.get("wall_ms"), off.get("wall_ms")
-            return {
-                "device_ms_on": dev_on,
-                "device_ms_off": dev_off,
-                "delta_pct": ab_delta_pct(dev_on, dev_off),
-                "wall_ms_on": wall_on,
-                "wall_ms_off": wall_off,
-                "wall_delta_pct": ab_delta_pct(wall_on, wall_off),
-                # CPU backends emit no device ops; the wall clock is
-                # then the only (and honestly-labelled) signal.
-                "source": ("device" if dev_on is not None
-                           and dev_off is not None else "wall_clock"),
-            }
-
-        result = {
-            "kind": "ab",
-            "aborted": False,
-            "steps": steps,
-            "batch": {
-                "num_reqs": num_reqs,
-                "prompt_len": prompt_len,
-                "num_decode_steps": sched_cfg.num_decode_steps,
-                "max_decode_steps_per_launch":
-                    sched_cfg.max_decode_steps_per_launch,
-            },
-            "split_on": measured.get("on", {}).get("split"),
-            "ab": {
-                "sampler_kernel": pair("sampler_off"),
-                "decode_attention": pair("decode_attn_off"),
-            },
-        }
-        if "dynamic_off" in measured:
-            # Per-step device time with the in-jit dynamic decode loop vs
-            # the fixed-K chain; note the ON side amortizes many tokens
-            # per launch, so compare per-TOKEN cost when interpreting.
-            result["ab"]["dynamic_decode"] = pair("dynamic_off")
-        if "adaptive_spec_off" in measured:
-            # Adaptive drafting vs the static budget. Device time alone
-            # undersells the ON side (shorter drafts also shift work off
-            # the wire); the goodput bench is the accepted-tokens view.
-            result["ab"]["adaptive_spec"] = pair("adaptive_spec_off")
-        logger.info("perfwatch A/B: %s", result["ab"])
-        return pw.note_ab(result)
 
     def shutdown(self) -> None:
         if self.mesh_recovery is not None:

@@ -20,7 +20,6 @@ import asyncio
 import json
 import math
 import os
-import time
 from typing import Any, AsyncIterator
 
 from aiohttp import web
@@ -632,59 +631,6 @@ async def handle_stop_profile(request: web.Request) -> web.Response:
     return await _profile_call(engine, "profiling stopped", "stop_profile")
 
 
-async def handle_debug_perf(request: web.Request) -> web.Response:
-    """Perfwatch status: quiet-window state, capture counters, the last
-    phase-attributed device-time split + live roofline estimates, and
-    the last kernel A/B result (see README "Performance observability")."""
-    engine: AsyncLLM = request.app[ENGINE_KEY]
-    core = getattr(engine, "engine_core", None)
-    if core is None or not hasattr(core, "perf_status"):
-        return web.json_response(
-            {"error": "engine does not support perfwatch"}, status=501)
-    return web.json_response(core.perf_status())
-
-
-async def handle_debug_perf_capture(request: web.Request) -> web.Response:
-    """Arm a perfwatch window: ``{"mode": "capture"|"ab"|"auto",
-    "steps": N, "force": bool, "wait_s": S}``. The engine loop executes
-    it (a capture needs live traffic; an A/B needs a quiet engine —
-    ``force`` skips the settle timer but never preempts real requests).
-    With ``wait_s`` the handler polls until the window lands (or the
-    wait expires) and returns the refreshed status."""
-    engine: AsyncLLM = request.app[ENGINE_KEY]
-    core = getattr(engine, "engine_core", None)
-    if core is None or not hasattr(core, "perf_capture"):
-        return web.json_response(
-            {"error": "engine does not support perfwatch"}, status=501)
-    body: dict = {}
-    if request.can_read_body:
-        try:
-            parsed = await request.json()
-        except Exception:
-            return web.json_response(
-                {"error": "request body must be JSON"}, status=400)
-        if isinstance(parsed, dict):
-            body = parsed
-    opts = {
-        "mode": body.get("mode", "auto"),
-        "steps": body.get("steps"),
-        "force": bool(body.get("force")),
-    }
-    ack = core.perf_capture(opts)
-    if "error" in ack:
-        return web.json_response(ack, status=400)
-    wait_s = float(body.get("wait_s", 0) or 0)
-    if wait_s > 0:
-        deadline = time.monotonic() + wait_s
-        while time.monotonic() < deadline:
-            status = core.perf_status()
-            if not status.get("armed") and not status.get("capturing"):
-                break
-            await asyncio.sleep(0.1)
-    return web.json_response(
-        {"capture": ack, "status": core.perf_status()})
-
-
 async def handle_health(request: web.Request) -> web.Response:
     """Liveness with per-engine detail: 200 while the server can serve
     anything (including degraded DP, some ranks respawning), 503 once the
@@ -1126,8 +1072,6 @@ def build_app(engine: AsyncLLM, model_name: str, metrics=None,
                         handle_admin_upgrade_abort)
     app.router.add_get("/admin/config", handle_admin_config)
     app.router.add_post("/admin/config", handle_admin_config)
-    app.router.add_get("/debug/perf", handle_debug_perf)
-    app.router.add_post("/debug/perf/capture", handle_debug_perf_capture)
     from vllm_tpu.entrypoints.openai.extra_apis import (
         handle_realtime,
         handle_responses,

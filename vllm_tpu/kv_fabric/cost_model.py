@@ -9,9 +9,8 @@ them by re-running the prefill. The decision compares
                   + tokens * flops_per_token / (peak_flops * prefill_eff)
 
 and fetches only when it wins. The compute side reuses
-``metrics/roofline.py`` — the same :class:`RooflineModel` the engine's
-perfwatch telemetry and ``bench.py`` use, so the serving engine and the
-cost model agree on what the hardware can do by construction.
+``metrics/roofline.py``: the worker's :class:`RooflineModel`, handed
+over once at engine start (``Worker.roofline_info``).
 
 ``prefill_overhead`` is the fixed per-prefill cost that is invisible to
 a pure-FLOPs model but dominates at short prefix lengths: an extra
@@ -99,7 +98,7 @@ class FetchCostModel:
 
     def set_roofline(self, roofline) -> None:
         """Adopt the worker's measured :class:`RooflineModel` (RPC'd once
-        at engine init, like perfwatch)."""
+        at engine init)."""
         self.roofline = roofline
 
     def observe_transfer(self, nbytes: int, seconds: float) -> None:

@@ -556,34 +556,6 @@ class PrometheusRegistry:
             "Wall time of a mesh recovery (loss/rejoin noticed -> "
             "re-bootstrapped, resharded, serving)",
             [0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0, 300.0])
-        # Perfwatch (vllm_tpu/metrics/perfwatch): live device-time
-        # attribution from periodic in-engine profiling windows. The
-        # per-phase gauge and roofline estimates hold the LAST completed
-        # capture's values (all zero until one lands); the counters are
-        # cumulative across the proc boundary (ratcheted via inc_to).
-        self.perf_device_ms = LabeledGauge(
-            "vllm:device_time_ms_per_step",
-            "Device time per engine step from the last perfwatch capture, "
-            "attributed by op phase (attention / matmul / sampler / comms "
-            "/ other; total = whole-step device time)", "phase")
-        self.perf_mfu = Gauge(
-            "vllm:mfu_est",
-            "Model FLOPs utilization estimated over the last perfwatch "
-            "capture window (decode roofline: sampled tok/s x 2 x active "
-            "params / peak FLOPs)")
-        self.perf_hbm_bw = Gauge(
-            "vllm:hbm_bw_util_est",
-            "HBM bandwidth utilization estimated over the last perfwatch "
-            "capture window (weights + live KV streamed per step / peak "
-            "bytes-per-second)")
-        self.perf_captures = Counter(
-            "vllm:perfwatch_captures_total",
-            "Completed perfwatch profiling windows (periodic + triggered "
-            "captures and quiet-window A/B runs)")
-        self.perf_captures_aborted = Counter(
-            "vllm:perfwatch_captures_aborted_total",
-            "Perfwatch windows aborted before completion (engine went "
-            "idle mid-capture, or live traffic arrived mid-A/B)")
         # Tiered KV fabric (vllm_tpu/kv_fabric): per-tier occupancy and
         # the fetch-vs-recompute decision counters, attached to
         # SchedulerStats by EngineCore when the fabric connector is
@@ -768,8 +740,6 @@ class PrometheusRegistry:
             self.api_server_index, self.api_server_count,
             self.mesh_rank_losses, self.mesh_recoveries,
             self.mesh_size, self.mesh_recovery_duration,
-            self.perf_device_ms, self.perf_mfu, self.perf_hbm_bw,
-            self.perf_captures, self.perf_captures_aborted,
             self.kv_fabric_tier_blocks, self.kv_fabric_fetches,
             self.kv_fabric_demotions, self.kv_fabric_fetch_bytes,
             self.kv_fabric_tier_bytes, self.kv_fabric_tier_occupancy,
@@ -882,18 +852,6 @@ class PrometheusRegistry:
             self.brownout_rung.set(float(s.brownout_rung))
             self.pressure_preemptions.inc_to(
                 float(s.pressure_preemptions))
-            # Perfwatch: counters ratchet (cumulative across the proc
-            # boundary); the attribution gauges adopt the last capture.
-            self.perf_captures.inc_to(float(s.perfwatch_captures))
-            self.perf_captures_aborted.inc_to(
-                float(s.perfwatch_captures_aborted))
-            if s.perfwatch_device_ms:
-                for phase, ms in s.perfwatch_device_ms.items():
-                    self.perf_device_ms.set(phase, float(ms))
-            if s.perfwatch_mfu_est is not None:
-                self.perf_mfu.set(s.perfwatch_mfu_est)
-            if s.perfwatch_hbm_bw_util_est is not None:
-                self.perf_hbm_bw.set(s.perfwatch_hbm_bw_util_est)
             if s.kv_fabric:
                 fab = s.kv_fabric
                 for tier, n in (fab.get("tier_blocks") or {}).items():

@@ -1,10 +1,10 @@
 """Shared roofline math for MFU / HBM-bandwidth-utilization estimates.
 
-One implementation, two consumers: ``bench.py`` (offline scored JSON)
-and the in-engine perfwatch subsystem (`vllm_tpu/metrics/perfwatch.py`,
-live ``vllm:mfu_est`` / ``vllm:hbm_bw_util_est`` gauges). Factoring the
-arithmetic here means the bench artifact and the serving engine agree on
-what "16% of the chip" means by construction.
+One consumer in the serving path: the KV fabric's fetch-or-recompute
+cost model (``kv_fabric/cost_model.py``), which prices a recompute from
+``Worker.roofline_info``. Nothing here is published on ``/metrics``: the
+device's time is read from a profile (``/start_profile``, reduced by
+``benchmark/harness/trace_reduce.py``).
 
 Model: decode is weight-read + KV-read bound. Per decode step every
 resident weight byte is read once and each running request's KV context
@@ -20,8 +20,8 @@ from typing import Any, Mapping
 
 # Per-chip published peaks by ``device_kind`` (Google Cloud TPU docs).
 # v5e: 197 TFLOP/s bf16, 819 GB/s. A kind that is not here has no peak:
-# ``mfu`` / ``hbm_bw_util`` raise a KeyError naming it, and the live
-# gauges publish nothing for it (``has_peaks``).
+# ``mfu`` / ``hbm_bw_util`` raise a KeyError naming it (``has_peaks``
+# asks first).
 PEAK_FLOPS = {"TPU v5 lite": 197e12, "TPU v5e": 197e12,
               "TPU v4": 275e12, "TPU v6 lite": 918e12}
 PEAK_HBM = {"TPU v5 lite": 819e9, "TPU v5e": 819e9,

@@ -7,11 +7,11 @@ into *intentional*, traffic-driven scaling:
 
 - :class:`AutoscaleController` — a pure state machine (injectable
   clock, no engine dependencies; same design discipline as
-  ``AdaptiveSpecController`` and ``PerfWatch``) that turns live signals
-  into scale decisions. Signals in: per-engine queue depth, sliding-
-  window SLO attainment (the PR-17 scoreboard), and kv-fabric tier
-  occupancy. Decisions out: ``"up"`` / ``"down"`` / ``None``, guarded
-  by hysteresis (separate up/down queue watermarks), a hold period (a
+  ``AdaptiveSpecController``) that turns live signals into scale
+  decisions. Signals in: per-engine queue depth, sliding-window SLO
+  attainment (the PR-17 scoreboard), and kv-fabric tier occupancy.
+  Decisions out: ``"up"`` / ``"down"`` / ``None``, guarded by
+  hysteresis (separate up/down queue watermarks), a hold period (a
   signal must *persist* before it acts — one burst never scales), a
   cooldown after every scale event (the pool must re-equilibrate before
   the next decision), and hard min/max pool bounds.

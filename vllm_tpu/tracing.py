@@ -149,10 +149,10 @@ def start_profiler(trace_dir: str | None = None,
 
     With ``stop_profiler`` the ONLY caller of ``jax.profiler`` in the
     serving path: ``Worker.start_profile`` is the RPC that reaches it, and
-    ``EngineCore.start_profile`` (HTTP, the multiprocess utility call,
-    perfwatch) is the only caller of that. It runs on the thread that
-    steps the engine, between two steps. The python tracer is off unless
-    asked for: its frames swamp the host plane and make the stop slow.
+    ``EngineCore.start_profile`` (HTTP, the multiprocess utility call)
+    is the only caller of that. It runs on the thread that steps the
+    engine, between two steps. The python tracer is off unless asked for:
+    its frames swamp the host plane and make the stop slow.
     """
     global _annotation, _profile
     if _profile is not None:

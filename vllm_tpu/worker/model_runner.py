@@ -124,18 +124,12 @@ class ModelRunner:
         sched = config.scheduler_config
         cache = config.cache_config
         self.block_size = cache.block_size
-        # Runtime kernel-dispatch toggles, seeded from the scheduler
-        # config; the perfwatch A/B flips them between variants via
-        # Worker.set_kernel_flags. enable_sampler_kernel must flow into
-        # the jitted step as a STATIC argument (a closure read would pin
-        # the value into every cached executable — flipping the config
-        # would silently keep serving the old kernel choice);
-        # enable_decode_attention only gates the already-static
+        # Kernel escape hatches, fixed for the runner's life by the
+        # scheduler config. enable_sampler_kernel is a static argument
+        # of the jitted step; enable_decode_attention gates the static
         # decode_only flag host-side.
         self.enable_decode_attention = sched.enable_decode_attention
         self.enable_sampler_kernel = sched.enable_sampler_kernel
-        # Perfwatch: most-recent live batch shape (A/B replays mirror it).
-        self.last_batch_shape: dict | None = None
 
         self.max_blocks_per_req = -(-sched.max_model_len // cache.block_size)
         # Device-resident empty placeholders (avoid a per-step 0-byte upload;
@@ -1708,17 +1702,6 @@ class ModelRunner:
             self._seen_buckets.add(bkey)
             self.bucket_compiles += 1
             self._first_use_key = bkey
-        # Perfwatch batch-shape retention: the quiet-window A/B replays
-        # a synthetic batch mirroring the last real traffic shape.
-        # ctx proxy = the widest request's block footprint (what the
-        # attention kernel actually walks).
-        if r_live:
-            self.last_batch_shape = {
-                "num_reqs": r_live,
-                "num_tokens": t_live,
-                "decode_only": bool(decode_only),
-                "ctx_tokens_per_req": max_blocks * self.block_size,
-            }
 
         # Packed i32 buffer; layout must match _unpack.
         t, r, b = t_pad, r_pad, b_pad
@@ -2863,8 +2846,8 @@ class ModelRunner:
                 out.sampled_token_ids.append([])
         # Realized emission count: exact for every path (fixed K,
         # dynamic variable-length runs, spec accepts, prefill = 0) —
-        # vllm:sampled_tokens_per_launch and the perfwatch per-launch
-        # math read this, so estimates would skew both.
+        # vllm:sampled_tokens_per_launch reads this, so an estimate
+        # would skew it.
         self.launch_sampled_tokens += sum(
             len(toks) for toks in out.sampled_token_ids
         )

@@ -945,28 +945,10 @@ class Worker:
                     info["trace_dir"])
         return info
 
-    def set_kernel_flags(self, flags: dict) -> dict:
-        """Flip the runner's runtime kernel-dispatch toggles (perfwatch
-        A/B variants). Keys: ``enable_sampler_kernel``,
-        ``enable_decode_attention``. Returns the PREVIOUS values so the
-        caller can restore them."""
-        assert self.runner is not None
-        prev = {
-            "enable_sampler_kernel": self.runner.enable_sampler_kernel,
-            "enable_decode_attention": self.runner.enable_decode_attention,
-        }
-        if "enable_sampler_kernel" in flags:
-            self.runner.enable_sampler_kernel = bool(
-                flags["enable_sampler_kernel"])
-        if "enable_decode_attention" in flags:
-            self.runner.enable_decode_attention = bool(
-                flags["enable_decode_attention"])
-        return prev
-
     def roofline_info(self) -> dict:
-        """The model's roofline parameters (msgpack-able; feeds the
-        perfwatch live MFU / HBM-bandwidth estimates — same math as
-        ``bench.py`` via ``vllm_tpu/metrics/roofline.py``)."""
+        """The model's roofline parameters (msgpack-able; the KV fabric's
+        fetch-or-recompute cost model prices a recompute with them, via
+        ``vllm_tpu/metrics/roofline.py``)."""
         from vllm_tpu.metrics import roofline as rf
 
         assert self.params is not None
