@@ -56,6 +56,7 @@ def exposed() -> dict[str, set[tuple[str, str]]]:
             steps_by_kind={"decode": 9, "mixed": 1},
             attn_context_tokens_by_kind={"decode": 180, "mixed": 10,
                                          "block": 11},
+            attn_ragged_pairs={"live": 26, "computed": 100},
             diffusion_passes_by_kind={"denoise": 2, "store": 1},
             diffusion_committed_tokens=3, moe_assignments=5,
             moe_experts_hit=7, **phases),

@@ -4,6 +4,7 @@
   sweep in Pallas interpret mode against the XLA reference;
 - ``tools/probe_sampler.py --smoke``: the fused sampling kernel's
   block-shape sweep, bit-exact against the XLA sampling epilogue;
+- ``tools/probe_ragged.py``: the ragged kernel at its tiny CPU case;
 - ``metrics/goodput.py``'s summary schema.
 """
 
@@ -42,6 +43,19 @@ def test_probe_sampler_smoke():
     assert "smoke sweep ok" in proc.stdout
     assert proc.stdout.count("MISMATCH") == 0
     assert proc.stdout.count("kernel rb=") == 4
+
+
+def test_probe_ragged_smoke():
+    """The ragged attention kernel's probe at its tiny CPU case: the
+    kernel, the parent-style explicit blocks and the host's count run, and
+    the outputs agree with the XLA reference."""
+    import json
+
+    proc = _run_tool("probe_ragged.py", "--variant", "4x8")
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    points = [json.loads(x) for x in proc.stdout.splitlines() if x.startswith("{")]
+    assert [p["kernel"] for p in points] == ["change", "4x8"]
+    assert all(p["max_abs_err"] < 3e-2 and 0 < p["live_share"] <= 100 for p in points)
 
 
 def test_goodput_summary_schema():

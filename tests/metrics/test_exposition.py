@@ -54,6 +54,7 @@ def _populated_registry() -> PrometheusRegistry:
         steps_by_kind={"decode": 9, "mixed": 1},
         step_tokens_by_kind={"decode": 18, "mixed": 10},
         attn_context_tokens_by_kind={"decode": 180, "mixed": 10},
+        attn_ragged_pairs={"live": 26, "computed": 100},
         step_first_use_s=7.5,
         batch_num_tokens=96, batch_num_reqs=3, batch_occupancy=0.75,
         step_interval_s=0.006,
@@ -224,6 +225,18 @@ def test_step_kind_counters_are_cumulative_and_never_decrease(
     text = reg.render()
     assert f'{family}{{kind="decode"}} {float(decode + 5)}' in text
     assert f'{family}{{kind="mixed"}} {float(mixed)}' in text
+
+
+def test_ragged_pairs_are_cumulative_by_kind():
+    reg = _populated_registry()
+    text = reg.render()
+    assert "# TYPE vllm:attn_ragged_pairs_total counter" in text
+    assert 'vllm:attn_ragged_pairs_total{kind="live"} 26.0' in text
+    assert 'vllm:attn_ragged_pairs_total{kind="computed"} 100.0' in text
+    reg.record(SchedulerStats(attn_ragged_pairs={"live": 30, "computed": 90}))
+    text = reg.render()  # a respawned engine's lower count lowers nothing
+    assert 'vllm:attn_ragged_pairs_total{kind="live"} 30.0' in text
+    assert 'vllm:attn_ragged_pairs_total{kind="computed"} 100.0' in text
 
 
 def test_first_use_seconds_ratchet_beside_their_count():

@@ -76,21 +76,6 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-@pytest.fixture
-def tuned_table_sees_chip(monkeypatch, topo):
-    """The tuned block-size table asks ``jax.devices()`` for the TPU
-    generation; here that is the CPU. Show its module the described
-    devices so the program picks what it would pick on the chip."""
-    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
-        tuned_block_sizes,
-    )
-
-    monkeypatch.setattr(
-        tuned_block_sizes, "jax",
-        types.SimpleNamespace(devices=lambda: topo.devices),
-    )
-
-
 def _attn_shapes(sharding, t, r, kv_dtype, kv_sharding=None, q_sharding=None):
     def s(shape, dtype, sh=sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
@@ -126,26 +111,84 @@ def test_decode_kernel_compiles(one_chip, kv_dtype):
     ).compile()
 
 
-@pytest.mark.parametrize(
-    "t,r,kv_dtype",
-    [(512, 128, jnp.bfloat16), (2048, 64, jnp.bfloat16),
-     (512, 128, jnp.float8_e4m3fn)],
-    ids=["T512_R128", "T2048_R64", "T512_R128_kv_fp8"],
-)
-def test_ragged_kernel_compiles_with_own_block_choice(
-    one_chip, tuned_table_sees_chip, t, r, kv_dtype
-):
-    from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
+def _ragged_shapes(sharding, t, r, pages, q_heads, kv_heads, kv_dtype):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    a = _attn_shapes(one_chip, t, r, kv_dtype)
+    return (
+        s((t, q_heads, D), jnp.bfloat16),
+        s((LAYERS, NUM_BLOCKS, PAGE, 2 * kv_heads, D), kv_dtype),
+        s((1,), jnp.int32), s((r,), jnp.int32), s((r, pages), jnp.int32),
+        s((r + 1,), jnp.int32), s((1,), jnp.int32),
+    )
+
+
+# (tokens, rows, pages a row, q heads, KV heads, KV dtype, block_len, LSE):
+# Llama-3-8B's and mistral7b.chat's 32 q / 8 KV heads at the cell's token
+# buckets (512, 16) and page buckets (16 ... 512), sdar30b.chat's 32 / 4
+# under the mask over blocks of 4, the tp=4 shard (8 / 2), fp8 KV, the LSE
+# output of context parallelism.
+RAGGED_SHAPES = {
+    "T512_R128": (512, 128, PAGES_PER_SEQ, H, KH, jnp.bfloat16, 1, False),
+    "T2048_R64": (2048, 64, PAGES_PER_SEQ, H, KH, jnp.bfloat16, 1, False),
+    "T512_R128_kv_fp8": (512, 128, PAGES_PER_SEQ, H, KH, jnp.float8_e4m3fn,
+                         1, False),
+    "mistral_pages16": (512, 128, 16, 32, 8, jnp.bfloat16, 1, False),
+    "mistral_pages512": (512, 128, 512, 32, 8, jnp.bfloat16, 1, False),
+    "mistral_T16": (16, 128, 64, 32, 8, jnp.bfloat16, 1, False),
+    "sdar_block_len_4": (512, 128, 128, 32, 4, jnp.bfloat16, 4, False),
+    "tp4_shard": (512, 128, PAGES_PER_SEQ, 8, 2, jnp.bfloat16, 1, False),
+    "lse": (512, 128, PAGES_PER_SEQ, H, KH, jnp.bfloat16, 1, True),
+}
+
+
+@pytest.mark.parametrize("shape", list(RAGGED_SHAPES))
+def test_ragged_kernel_compiles_with_own_block_choice(one_chip, shape):
+    """The blocks, the rungs and the scoped VMEM ``ragged_tiling`` picks
+    for the shapes (both rungs are in the one program wherever the query
+    block holds more than a narrow window)."""
+    from vllm_tpu.ops.rpa_kernel import ragged_paged_attention, ragged_tiling
+
+    t, r, pages, q_heads, kv_heads, kv_dtype, block_len, lse = (
+        RAGGED_SHAPES[shape])
+    tiling = ragged_tiling(
+        q_heads, 2 * kv_heads, D, PAGE, jnp.bfloat16, kv_dtype, t, pages,
+        block_len=block_len,
+    )
+    # A KV head's wide tile holds 512 rows: 128 tokens at 4 q heads a KV
+    # head, 64 at sdar30b.chat's 8.
+    assert tiling.q_per_blk == min(t, 512 // (q_heads // kv_heads))
+    assert tiling.kv_pages_per_blk == min(pages, 32)
+    assert tiling.narrow_q == 4 < tiling.q_per_blk
+    assert tiling.narrow_sub_pages == min(pages, 16)
+    assert (tiling.vmem_limit_bytes or 0) <= 64 << 20
     scale = _kv_scale(kv_dtype)
     fn = functools.partial(
-        ragged_paged_attention, sm_scale=D**-0.5, k_scale=scale, v_scale=scale
+        ragged_paged_attention, sm_scale=D**-0.5, k_scale=scale,
+        v_scale=scale, block_len=block_len, return_lse=lse,
     )
     jax.jit(fn).lower(
-        a["q"], a["kv"], a["layer"], a["kv_lens"], a["pages"],
-        a["cu_q_lens"], a["num_seqs"],
+        *_ragged_shapes(one_chip, t, r, pages, q_heads, kv_heads, kv_dtype)
     ).compile()
+
+
+def test_tracing_the_ragged_kernel_once_is_cheap(one_chip):
+    """The kernel is traced and lowered once a step program (30 programs
+    in mistral7b.chat's warm-up), in Python: a rung that unrolled a long
+    loop would cost a start seconds (PR 30 lost 20 s to one). Tracing and
+    lowering it takes 0.3-0.7 s here; the bound leaves room for six busy
+    workers."""
+    import time
+
+    from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
+
+    args = _ragged_shapes(one_chip, 512, 128, 512, 32, 8, jnp.bfloat16)
+    fn = jax.jit(functools.partial(ragged_paged_attention, sm_scale=D**-0.5))
+    fn.lower(*args)  # imports and caches that are not the kernel's
+    fn = jax.jit(functools.partial(ragged_paged_attention, sm_scale=D**-0.25))
+    t0 = time.process_time()
+    fn.lower(*args)
+    assert time.process_time() - t0 < 4.0
 
 
 @pytest.mark.parametrize(
@@ -186,7 +229,7 @@ def test_sampler_kernel_compiles(
 @pytest.mark.parametrize("decode_only", [True, False],
                          ids=["decode", "ragged"])
 def test_attention_dispatch_compiles_under_tp4(
-    tp4_mesh, tuned_table_sees_chip, monkeypatch, decode_only
+    tp4_mesh, monkeypatch, decode_only
 ):
     """Through ``dispatch_ragged_attention`` with q sharded over heads and
     the cache over its KV rows, as ``models/llama.py`` shards them: the
@@ -271,7 +314,7 @@ def _while_body_instructions(hlo: str, with_dims: bool = False):
 @pytest.mark.parametrize("t,r,decode_only", [(128, 128, True), (512, 128, False)],
                          ids=["decode_T128_R128", "mixed_T512_R128"])
 def test_layer_scan_reads_each_weight_once_in_place(
-    one_chip, tuned_table_sees_chip, monkeypatch, t, r, decode_only, tree
+    one_chip, monkeypatch, t, r, decode_only, tree
 ):
     """The step program of the benchmark's cell, compiled for the v5e: no
     layer may slice or relay a WEIGHT out of its ``[L, ...]`` stack into a
@@ -357,7 +400,7 @@ SDAR_KV_BLOCKS, SDAR_PAGES = 20000, 64
 @pytest.mark.parametrize("t,r", [(512, 128), (128, 32)],
                          ids=["mixed_T512_R128", "block_rows_T128_R32"])
 def test_block_diffusion_step_compiles_and_reads_each_weight_in_place(
-    one_chip, tuned_table_sees_chip, monkeypatch, t, r
+    one_chip, monkeypatch, t, r
 ):
     """The model's part of the step of ``sdar30b.chat``, compiled for the
     v5e: rows of 4 tokens under the mask over blocks (a 512-token mixed

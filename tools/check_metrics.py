@@ -84,6 +84,7 @@ REQUIRED_STEP_METRICS = {
     "vllm:engine_steps_total",
     "vllm:engine_step_tokens_total",
     "vllm:attn_context_tokens_total",
+    "vllm:attn_ragged_pairs_total",
     "vllm:step_bucket_compiles",
     "vllm:step_first_use_seconds_total",
 }
@@ -322,7 +323,8 @@ def check_step_exposition() -> list[str]:
         reg.record(
             SchedulerStats(**phases, steps_by_kind=by_kind,
                            step_tokens_by_kind=by_kind,
-                           attn_context_tokens_by_kind=by_kind),
+                           attn_context_tokens_by_kind=by_kind,
+                           attn_ragged_pairs={"live": 1, "computed": 1}),
             IterationStats(output_s=0.001))
     except (TypeError, AttributeError) as e:
         return [f"the step stats no longer reach the registry: {e}"]
@@ -333,6 +335,8 @@ def check_step_exposition() -> list[str]:
                for name in ("vllm:engine_steps_total",
                             "vllm:engine_step_tokens_total",
                             "vllm:attn_context_tokens_total")]
+    wanted += [f'vllm:attn_ragged_pairs_total{{kind="{k}"}} 1.0'
+               for k in ("live", "computed")]
     return [f"/metrics lacks the sample {w!r}" for w in wanted
             if w not in text]
 

@@ -258,6 +258,10 @@ class SchedulerStats:
     steps_by_kind: dict[str, int] = field(default_factory=dict)
     step_tokens_by_kind: dict[str, int] = field(default_factory=dict)
     attn_context_tokens_by_kind: dict[str, int] = field(default_factory=dict)
+    # (query token, key) pairs of the ragged attention kernel's calls,
+    # "live" (the mask lets them through) and "computed" (its tiles work
+    # them out), a q head a layer, cumulative, from the runner.
+    attn_ragged_pairs: dict[str, int] = field(default_factory=dict)
     step_first_use_s: float = 0.0
     # Generation by diffusion over blocks (cumulative, from the runner):
     # row-passes by kind and positions committed by denoising passes.

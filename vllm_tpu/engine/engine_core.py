@@ -428,7 +428,7 @@ class EngineCore:
                 runner, "launch_sampled_tokens", 0
             )
             for name in ("steps_by_kind", "step_tokens_by_kind",
-                         "attn_context_tokens_by_kind"):
+                         "attn_context_tokens_by_kind", "attn_ragged_pairs"):
                 setattr(stats, name, dict(getattr(runner, name, {})))
             stats.diffusion_passes_by_kind = dict(
                 getattr(runner, "diffusion_passes_by_kind", {}))

@@ -314,6 +314,11 @@ class PrometheusRegistry:
             "vllm:attn_context_tokens_total",
             "Context tokens attended: the sum over a step's rows of the "
             "positions each row attends to, by kind of step", "kind")
+        self.attn_ragged_pairs = LabeledCounter(
+            "vllm:attn_ragged_pairs_total",
+            "(query token, key) pairs of the ragged attention kernel's "
+            "calls, a q head a layer: live (the mask lets them through) "
+            "and computed (the kernel's tiles work them out)", "kind")
         # Generation by diffusion over blocks and mixture of experts,
         # counted by the runner (cumulative).
         self.diffusion_passes = LabeledCounter(
@@ -712,7 +717,8 @@ class PrometheusRegistry:
             self.spec_suspended, self.spec_suspensions,
             self.bucket_compiles, self.bucket_hits, self.first_use_seconds,
             self.steps_by_kind, self.step_tokens_by_kind,
-            self.attn_context_tokens, self.pipeline_stall,
+            self.attn_context_tokens, self.attn_ragged_pairs,
+            self.pipeline_stall,
             self.diffusion_passes, self.diffusion_committed,
             self.moe_assignments, self.moe_experts_hit,
             self.decode_batch_ratio, self.tokens_per_launch,
@@ -832,6 +838,7 @@ class PrometheusRegistry:
                 (self.steps_by_kind, s.steps_by_kind),
                 (self.step_tokens_by_kind, s.step_tokens_by_kind),
                 (self.attn_context_tokens, s.attn_context_tokens_by_kind),
+                (self.attn_ragged_pairs, s.attn_ragged_pairs),
                 (self.diffusion_passes, s.diffusion_passes_by_kind),
             ):
                 for kind, n in by_kind.items():

@@ -239,6 +239,33 @@ def _call_attention_kernel(kernel, mesh, q, kv_cache, replicated, traced_kw,
     )(q, kv_cache, replicated, traced_kw)
 
 
+def _pallas_ok(head_dim: int) -> bool:
+    """Kernels on, and a head_dim the attention kernels have a layout for."""
+    import vllm_tpu.envs as envs
+
+    return not envs.VLLM_TPU_DISABLE_PALLAS and head_dim in (64, 128, 256)
+
+
+def ragged_kernel_tiling(
+    num_q_heads: int, num_kv_heads: int, head_dim: int, page_size: int,
+    q_dtype, kv_dtype, num_q_tokens: int, pages_per_seq: int, *,
+    block_len: int = 1, tp_size: int = 1,
+):
+    """The ``RaggedTiling`` ``dispatch_ragged_attention`` runs a step of
+    these shapes with where the step is not decode-only, or None where it
+    takes the XLA reference (no TPU, kernels off, a head_dim the kernel
+    has no layout for): for the runner's count of the kernel's work."""
+    from vllm_tpu.ops.rpa_kernel import ragged_tiling
+
+    if not (_pallas_ok(head_dim) and _on_tpu()):
+        return None
+    return ragged_tiling(
+        num_q_heads // tp_size, 2 * num_kv_heads // tp_size, head_dim,
+        page_size, q_dtype, kv_dtype, num_q_tokens, pages_per_seq,
+        block_len=block_len,
+    )
+
+
 def dispatch_ragged_attention(
     q: jnp.ndarray,
     kv_cache: jnp.ndarray,
@@ -263,7 +290,6 @@ def dispatch_ragged_attention(
     import vllm_tpu.envs as envs
 
     interpret = allow_interpret and bool(envs.VLLM_TPU_PALLAS_INTERPRET)
-    kernel_ok = q.shape[-1] in (64, 128, 256)
     on_tpu = _on_tpu()
     # Decode-only fast path: every live row is a single-position decode
     # (T == R, token i == row i), so the sequence-pipelined kernel can
@@ -279,9 +305,7 @@ def dispatch_ragged_attention(
         and q.shape[0] == md.seq_lens.shape[0]
         and not envs.VLLM_TPU_DISABLE_DECODE_KERNEL
     )
-    use_kernel = (
-        not envs.VLLM_TPU_DISABLE_PALLAS and kernel_ok and (on_tpu or interpret)
-    )
+    use_kernel = _pallas_ok(q.shape[-1]) and (on_tpu or interpret)
     run_interpret = interpret and not on_tpu
     layer1 = jnp.asarray(layer, jnp.int32).reshape(1)
     static_kw = dict(
@@ -317,8 +341,8 @@ def dispatch_ragged_attention(
     if use_kernel:
         from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
 
-        # The tuned-block-size table is keyed by TPU generation; off-TPU
-        # interpret runs pick explicit small blocks instead.
+        # The kernel picks its blocks for the chip; off-TPU interpret
+        # runs name small ones instead.
         blk_kw = (
             dict(num_kv_pages_per_block=2, num_queries_per_block=8)
             if run_interpret

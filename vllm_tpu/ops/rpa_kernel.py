@@ -21,6 +21,18 @@ kernel (JAX Authors, 2025), with framework-specific extensions:
   tests); round 1 silently fell back to a quadratic gather path.
 - ``interpret=`` plumbs Pallas interpret mode for CPU-backend tests.
 - fp8 KV: ``k_scale``/``v_scale`` dequantize pages on the fly.
+- **A visit costs what is live in it.** The kernel walks (query block,
+  sequence, KV block) visits. A sequence with a few query tokens in the
+  block (a decode row of a mixed step, a row of one block under
+  generation by diffusion) runs the flash step on ONE window of query
+  tokens, every KV head's rows stacked into whole tiles, not on the whole
+  query block a head; only the pages below the sequence's end are copied;
+  and the keys are computed sub-tile by sub-tile up to the last one a
+  live query sees. The rungs are static and in one program, chosen a
+  visit from the scalars the kernel prefetches (``visit_rung``,
+  ``visit_key_extent``, ``visit_sub_tiles``); ``count_ragged_pairs``
+  counts a step's work with the same three on the host. Block sizes and
+  scoped VMEM come from ``ragged_tiling``, not from JAX's tuned table.
 
 Layout contract (``ops/attention.py``): K/V heads interleaved on axis 3
 (``0::2`` = K, ``1::2`` = V) so one page's per-head K,V pair is
@@ -30,16 +42,16 @@ contiguous for the per-page DMA.
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax._src import dtypes
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.pallas.ops.tpu.ragged_paged_attention.tuned_block_sizes import (  # noqa: E501
-    get_tuned_block_sizes,
-)
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.dtype("float32")).max)
 
@@ -47,28 +59,49 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.dtype("float32")).max)
 # not given (v5e; the chip's compiler reports it as "limit 16.00M").
 _DEFAULT_SCOPED_VMEM_BYTES = 16 << 20
 
+# What a kernel may be given at most; the v5e's VMEM is 128 MiB.
+_MAX_SCOPED_VMEM_BYTES = 64 << 20
+# Block sizes where the caller names none, and the keys of one step of the
+# narrow rung (``ragged_tiling`` says where they come from).
+_ROWS_PER_HEAD_TILE = 512
+_KEYS_PER_BLOCK = 512
+_NARROW_SUB_KEYS = 256
+# KV heads a step of the wide rung's loop over heads (of the unrolled 8,
+# 4 keep three quarters of the overlap at a program the size of 1's).
+_WIDE_HEADS_PER_STEP = 4
+
 
 def _dtype_packing(dtype) -> int:
     return 32 // dtypes.itemsize_bits(dtype)
 
 
-def strided_load_kv(ref, start, step):
+def strided_load_kv(ref, start, step, live=None):
     """Split interleaved K/V rows; handles sub-32-bit packed dtypes.
 
     ``ref`` is a flat ``[N_rows, lanes]`` VMEM view whose rows interleave
     K/V heads with period ``step``; returns the K rows starting at
-    ``start`` and the V rows starting at ``start + 1`` (lists, because a
-    packed dtype yields several heads per 32-bit load). Shared by the
+    ``start`` (a multiple of the dtype's packing; may be traced) and the V
+    rows starting at ``start + 1`` (lists, because a packed dtype yields
+    several heads per 32-bit load). ``live`` (``[N_rows // step, 1]``
+    bool) zeroes the rows it marks dead, on the 32-bit words as loaded:
+    one select for K and V of every head in the word. Shared by the
     general ragged kernel and the decode-specialized kernel
     (``rpa_decode_kernel.py``)."""
     packing = _dtype_packing(ref.dtype)
+    rows = ref.shape[0] // step
     if packing == 1:
-        return [ref[start::step, :]], [ref[start + 1 :: step, :]]
+        k = ref[pl.ds(start, rows, stride=step), :]
+        v = ref[pl.ds(start + 1, rows, stride=step), :]
+        if live is not None:
+            k, v = jnp.where(live, k, 0), jnp.where(live, v, 0)
+        return [k], [v]
     assert packing in (2, 4, 8)
     assert step % packing == 0
     k_list, v_list = [], []
     b_ref = ref.bitcast(jnp.uint32)
-    b = b_ref[start // packing :: step // packing, :]
+    b = b_ref[pl.ds(start // packing, rows, stride=step // packing), :]
+    if live is not None:
+        b = jnp.where(live, b, jnp.uint32(0))
     if ref.dtype == jnp.bfloat16:
         bk = b << 16
         bv = b & jnp.uint32(0xFFFF0000)
@@ -96,32 +129,111 @@ def fold_on_2nd_minor(vec):
     return vec.reshape(-1, vec.shape[-1])
 
 
-class _PageCopy:
-    """Async copy of one KV block's pages HBM -> VMEM, layer-indexed."""
+class RaggedTiling(NamedTuple):
+    """The kernel's tile sizes, all static: the fetched KV block and the
+    query block, and the rungs a visit is computed on. The narrow rung
+    takes a sequence whose query tokens in the block lie in ONE aligned
+    window of ``narrow_q`` tokens (a decode row, a row of one block under
+    generation by diffusion over blocks); ``narrow_q`` 0 = no such rung.
+    Keys are computed ``*_sub_pages`` pages at a time."""
 
-    def __init__(self, pages_hbm_ref, vmem_buf, sem, page_indices_ref,
-                 layer, seq_id, start_page_idx, end_page_idx):
-        self._vmem_buf = vmem_buf
-        self._copies = []
-        for i in range(vmem_buf.shape[0]):
-            page_idx = start_page_idx + i
-            page_idx = lax.select(page_idx < end_page_idx, page_idx, 0)
-            self._copies.append(
-                pltpu.make_async_copy(
-                    pages_hbm_ref.at[layer, page_indices_ref[seq_id, page_idx]],
-                    vmem_buf.at[i],
-                    sem,
-                )
-            )
+    kv_pages_per_blk: int
+    q_per_blk: int
+    narrow_q: int
+    narrow_sub_pages: int
+    wide_sub_pages: int
+    vmem_limit_bytes: int | None
 
-    def start(self):
-        for c in self._copies:
-            c.start()
 
-    def wait(self):
-        for c in self._copies:
-            c.wait()
-        return self._vmem_buf
+# The host's count of a step's work (``count_ragged_pairs``) calls the
+# three functions below with numpy arrays, the kernel with the scalars it
+# prefetched: one choice of rung and of key extent, written once.
+
+
+def visit_rung(tok_lo, tok_hi, narrow_q: int):
+    """(narrow?, window) for a sequence whose query tokens in the block
+    are ``[tok_lo, tok_hi)``."""
+    window = tok_lo // max(narrow_q, 1)
+    one_window = window == (tok_hi - 1) // max(narrow_q, 1)
+    return (narrow_q > 0) & one_window, window
+
+
+def visit_key_extent(last_pos, local_tokens, ctx_stride, block_len: int,
+                     xp=jnp):
+    """Local context tokens a visit computes up to: those the sequence
+    holds, and under the whole-context view no further than the last live
+    query of the visit sees (``last_pos``, its position). A striped
+    context keeps the whole loop, as it keeps it for the window."""
+    if block_len > 1:
+        last_pos = (last_pos // block_len + 1) * block_len - 1
+    return xp.where(
+        ctx_stride == 1, xp.minimum(local_tokens, last_pos + 1), local_tokens
+    )
+
+
+def visit_sub_tiles(key_hi, kv_blk_start, sub_keys: int, max_sub_tiles: int,
+                    xp=jnp):
+    """Sub-tiles of ``sub_keys`` keys computed in the block that starts at
+    ``kv_blk_start``; never none, so that a visit always leaves its rows
+    of l/m/acc initialised."""
+    return xp.clip(
+        (key_hi - kv_blk_start + sub_keys - 1) // sub_keys, 1, max_sub_tiles
+    )
+
+
+def count_ragged_pairs(q_lens, kv_lens, tiling: RaggedTiling, *,
+                       page_size: int, block_len: int = 1):
+    """(live, computed) (query token, key) pairs a q head of one kernel
+    call over these rows: the pairs the mask lets through, and the pairs
+    the kernel's tiles compute for them, by its own choice of rung and of
+    key extent (whole-context view, no sliding window). Host side, numpy:
+    the runner counts every step with it."""
+    q_lens = np.asarray(q_lens, np.int64)
+    kv_lens = np.asarray(kv_lens, np.int64)
+    ctx = kv_lens - q_lens
+    if block_len > 1:
+        blocks = q_lens // block_len
+        seen = block_len * block_len * blocks * (blocks + 1) // 2
+    else:
+        seen = q_lens * (q_lens + 1) // 2
+    live = int(np.sum(q_lens * ctx + seen))
+
+    q_end = np.cumsum(q_lens)
+    q_start = q_end - q_lens
+    first_blk = q_start // tiling.q_per_blk
+    visits = np.where(
+        q_lens > 0, (q_end - 1) // tiling.q_per_blk - first_blk + 1, 0
+    )
+    seq = np.repeat(np.arange(len(q_lens)), visits)
+    visits_before = np.cumsum(visits) - visits
+    q_len_start = (
+        first_blk[seq] + np.arange(len(seq)) - visits_before[seq]
+    ) * tiling.q_per_blk
+    tok_lo = np.maximum(q_start[seq] - q_len_start, 0)
+    tok_hi = np.minimum(q_end[seq] - q_len_start, tiling.q_per_blk)
+    narrow, _ = visit_rung(tok_lo, tok_hi, tiling.narrow_q)
+    blk_pos = ctx[seq] + q_len_start - q_start[seq]
+    key_hi = visit_key_extent(
+        blk_pos + tok_hi - 1, kv_lens[seq], 1, block_len, xp=np
+    )
+    toks = np.where(narrow, tiling.narrow_q, tiling.q_per_blk)
+    sub_keys = page_size * np.where(
+        narrow, tiling.narrow_sub_pages, tiling.wide_sub_pages
+    )
+    blk_keys = page_size * tiling.kv_pages_per_blk
+    num_blks = (np.maximum(kv_lens[seq], 1) + blk_keys - 1) // blk_keys
+    # Blocks wholly under the extent, the one it ends in, and the blocks
+    # past it, which still run their one sub-tile.
+    full = np.minimum(key_hi // blk_keys, num_blks)
+    rest = num_blks - full
+    last = np.where(
+        rest > 0,
+        visit_sub_tiles(key_hi, full * blk_keys, sub_keys,
+                        blk_keys // sub_keys, xp=np) * sub_keys,
+        0,
+    )
+    keys = full * blk_keys + last + np.maximum(rest - 1, 0) * sub_keys
+    return live, int(np.sum(toks * keys))
 
 
 def _rpa_kernel(
@@ -149,13 +261,21 @@ def _rpa_kernel(
     k_scale: float | None,
     v_scale: float | None,
     return_lse: bool,
-    block_len: int = 1,
+    block_len: int,
+    tiling: RaggedTiling,
 ):
+    """One (heads block, query block) program: walks the (sequence, KV
+    block) VISITS of its query block. A visit's work follows what is live
+    in it (``visit_rung``, ``visit_sub_tiles``): a sequence whose tokens
+    in this block fit one aligned window of ``tiling.narrow_q`` tokens
+    runs the flash step on that window's rows alone, and the keys of the
+    fetched block are computed sub-tile by sub-tile up to the last one a
+    live query can see."""
     if return_lse:
-        lse_ref, kv_bufs, sems, l_ref, m_ref, acc_ref = rest
+        lse_ref, kv_bufs, sems, l_ref, m_ref, acc_ref, qf_ref = rest
     else:
         lse_ref = None
-        kv_bufs, sems, l_ref, m_ref, acc_ref = rest
+        kv_bufs, sems, l_ref, m_ref, acc_ref, qf_ref = rest
 
     num_q_per_blk, num_q_heads_per_blk, head_dim = q_ref.shape
     pages_per_seq = page_indices_ref.shape[-1]
@@ -179,6 +299,7 @@ def _rpa_kernel(
     q_len_end = q_len_start + num_q_per_blk
     ctx_stride = ctx_ref[0]
     ctx_phase = ctx_ref[1]
+    window = window_ref[0]
 
     def local_ctx(seq_idx):
         """(local page count, local context token count) of this rank's
@@ -205,7 +326,6 @@ def _rpa_kernel(
         minus (window - 1). Striped context (ctx_stride > 1) skips the
         window fast-path: the floor is in global tokens and local pages
         interleave, so start at 0 (the mask stays correct)."""
-        window = window_ref[0]
         q_len = cu_q_lens_ref[seq_idx + 1] - cu_q_lens_ref[seq_idx]
         first_tok = jnp.maximum(
             kv_lens_ref[seq_idx] - q_len - (window - 1), 0
@@ -216,9 +336,15 @@ def _rpa_kernel(
             0,
         )
 
-    def make_page_copy(heads_blk_idx, seq_idx, kv_blk_idx, buf_idx):
+    def page_copies(heads_blk_idx, seq_idx, kv_blk_idx, buf_idx, *, wait):
+        """Start, or wait for, the copies HBM -> VMEM of one KV block's
+        pages, layer-indexed: the pages below the sequence's end and no
+        other, so a block's tail past the sequence costs no traffic. The
+        count is a function of (seq_idx, kv_blk_idx) alone: the start in
+        the prefetch chain and the wait in the visit agree page for page."""
         start_page = kv_blk_idx * num_kv_pages_per_blk
         end_page = jnp.minimum(pages_per_seq, local_ctx(seq_idx)[0])
+        num_pages = jnp.clip(end_page - start_page, 0, num_kv_pages_per_blk)
         if num_heads_blks == 1:
             # No heads sub-slice: a lane-dim slice on an HBM memref whose
             # head_dim is below the 128-lane tile (e.g. 64) is rejected by
@@ -229,23 +355,47 @@ def _rpa_kernel(
             src = kv_pages_hbm_ref.at[
                 :, :, :, pl.ds(heads_start, num_combined_kv_heads_per_blk), :
             ]
-        return _PageCopy(
-            src,
-            kv_bufs.at[buf_idx],
-            sems.at[buf_idx],
-            page_indices_ref,
-            layer,
-            seq_idx,
-            start_page,
-            end_page,
-        )
+
+        def one_page(i, carry):
+            copy = pltpu.make_async_copy(
+                src.at[layer, page_indices_ref[seq_idx, start_page + i]],
+                kv_bufs.at[buf_idx, i],
+                sems.at[buf_idx],
+            )
+            if wait:
+                copy.wait()
+            else:
+                copy.start()
+            return carry
+
+        lax.fori_loop(0, num_pages, one_page, 0)
 
     @pl.when(heads_blk_idx + q_blk_idx == 0)
     def prefetch_first_kv_blk():
-        make_page_copy(
+        page_copies(
             heads_blk_idx, init_seq_idx, seq_start_blk(init_seq_idx),
-            init_buf_idx,
-        ).start()
+            init_buf_idx, wait=False,
+        )
+
+    # q, l, m and acc live in VMEM as [windows, KV heads, rows of a window,
+    # lanes], a KV head's rows token-major: the narrow rung's window over
+    # all heads is one slab, a head's rows over all windows a strided one.
+    num_windows, _, win_rows, _ = qf_ref.shape
+    win_toks = win_rows // num_q_heads_per_kv_head
+
+    @pl.when(q_len_start < cu_q_lens_ref[num_seqs])
+    def fold_q_heads():
+        # Once a program and not once a visit.
+        for kv_head_idx in range(num_kv_heads_per_blk):
+            q_head_idx = kv_head_idx * num_q_heads_per_kv_head
+            qf_ref[:, kv_head_idx] = (
+                fold_on_2nd_minor(
+                    q_ref[:, q_head_idx : q_head_idx
+                          + num_q_heads_per_kv_head, :]
+                )
+                .astype(qf_ref.dtype)
+                .reshape(num_windows, win_rows, head_dim)
+            )
 
     def is_cur_q_blk_needed(q_states):
         done, cur_seq_idx, _ = q_states
@@ -265,7 +415,20 @@ def _rpa_kernel(
         # the double-buffer prefetch chain stays uniform across ranks
         # (skipping a seq would desync buffer ownership) and the masked
         # pass initializes this seq's l/m/acc scratch rows.
-        local_bound = jnp.maximum(local_ctx(cur_seq_idx)[1], 1)
+        local_tokens = local_ctx(cur_seq_idx)[1]
+        local_bound = jnp.maximum(local_tokens, 1)
+        # This sequence's query tokens inside the block, [tok_lo, tok_hi).
+        tok_lo = jnp.maximum(q_start - q_len_start, 0)
+        tok_hi = jnp.minimum(q_end - q_len_start, num_q_per_blk)
+        is_narrow, narrow_win = visit_rung(tok_lo, tok_hi, tiling.narrow_q)
+        # Position of the first token of the block, were it this seq's.
+        blk_pos = (kv_len - q_len) + q_len_start - q_start
+        key_hi = visit_key_extent(
+            blk_pos + tok_hi - 1, local_tokens, ctx_stride, block_len
+        )
+        # ``row_pos - window >= col`` masks the keys below the window; no
+        # window = a floor no column reaches.
+        window_floor = jnp.where(window > 0, window, jnp.int32(1 << 30))
 
         def get_next_prefetch_ids(heads_blk_idx, cur_seq_idx, kv_blk_idx,
                                   cur_buf_idx):
@@ -288,99 +451,58 @@ def _rpa_kernel(
             next_buf_idx = lax.select(cur_buf_idx == 0, 1, 0)
             return next_heads_blk_idx, next_seq_idx, next_kv_blk_idx, next_buf_idx
 
-        def flash_attention(q, k, v, head_l_ref, head_m_ref, head_acc_ref, *,
-                            kv_blk_idx, start_blk):
-            assert q.shape == (num_q_per_blk * num_q_heads_per_kv_head, head_dim)
-            assert k.shape == v.shape == (num_kv_per_blk, head_dim)
-            kv_len_start = kv_blk_idx * num_kv_per_blk
-
-            def masked_store(ref, val, start, end, group=1):
-                iota = lax.broadcasted_iota(jnp.int32, ref.shape, 0) // group
-                pltpu.store(
-                    ref, val, mask=jnp.logical_and(iota >= start, iota < end)
-                )
+        def flash_attention(qs, ks, vs, l_win, m_win, acc_win, *, masked,
+                            init, live):
+            """One online-softmax step over one sub-tile of keys for the
+            KV heads in ``qs``/``ks``/``vs`` (their rows stacked, so that
+            the softmax runs on whole tiles whatever a head's rows), on
+            the [.., rows, lanes] slabs of the l/m/acc scratch that hold
+            those rows; only the live rows are stored."""
+            head_rows = qs[0].shape[0]
+            rows = head_rows * len(qs)
 
             def load_with_init(ref, init_val):
-                return jnp.where(
-                    kv_blk_idx == start_blk, jnp.full_like(ref, init_val),
-                    ref[...],
+                val = ref[...].reshape(rows, ref.shape[-1])
+                return jnp.where(init, jnp.full_like(val, init_val), val)
+
+            def store_live(ref, val):
+                pltpu.store(
+                    ref, val.reshape(ref.shape),
+                    mask=jnp.broadcast_to(
+                        live, (rows, ref.shape[-1])
+                    ).reshape(ref.shape),
                 )
 
-            # KV rows beyond the (striped) context are garbage; zero them
-            # so the contraction stays NaN-free. Position arithmetic is in
-            # GLOBAL context coordinates: local flat slot c maps to page
-            # (c // ps) * stride + phase, offset c % ps.
-            kv_flat = kv_len_start + lax.broadcasted_iota(
-                jnp.int32, k.shape, 0
-            )
-            kv_gpos = (
-                (kv_flat // page_size) * ctx_stride + ctx_phase
-            ) * page_size + kv_flat % page_size
-            kv_mask = kv_gpos < kv_len
-            k = jnp.where(kv_mask, k.astype(jnp.float32), 0).astype(k.dtype)
-            v = jnp.where(kv_mask, v.astype(jnp.float32), 0).astype(v.dtype)
-
-            qk = (
+            qk = jnp.concatenate([
                 jnp.einsum("nd,md->nm", q, k,
                            preferred_element_type=jnp.float32)
-                * sm_scale
-            )
-            store_start = jnp.maximum(q_start - q_len_start, 0)
-            store_end = jnp.minimum(q_end - q_len_start, num_q_per_blk)
-
-            row_ids = (
-                (kv_len - q_len)
-                + q_len_start
-                - q_start
-                + lax.broadcasted_iota(
-                    jnp.int32,
-                    (num_q_per_blk * num_q_heads_per_kv_head, num_kv_per_blk),
-                    0,
-                )
-                // num_q_heads_per_kv_head
-            )
-            col_flat = kv_len_start + lax.broadcasted_iota(
-                jnp.int32,
-                (num_q_per_blk * num_q_heads_per_kv_head, num_kv_per_blk),
-                1,
-            )
-            col_ids = (
-                (col_flat // page_size) * ctx_stride + ctx_phase
-            ) * page_size + col_flat % page_size
-            if block_len > 1:
-                # Causal over blocks: a query sees up to the last position
-                # of its own block (a row's queries cover whole blocks, so
-                # that position is inside kv_len).
-                row_ids = (row_ids // block_len + 1) * block_len - 1
-            causal_mask = row_ids < col_ids
-            window = window_ref[0]
-            causal_mask = jnp.logical_or(
-                causal_mask,
-                (row_ids - window >= col_ids) & (window > 0),
-            )
+                for q, k in zip(qs, ks)
+            ], axis=0) * sm_scale
             if soft_cap is not None:
                 qk = soft_cap * jnp.tanh(qk / soft_cap)
-            qk += jnp.where(causal_mask, mask_value, 0.0)
+            qk = jnp.where(masked, mask_value, qk)
             m_curr = jnp.max(qk, axis=1, keepdims=True)
             s_curr = jnp.exp(qk - m_curr)
-            qkv = jnp.dot(s_curr, v, preferred_element_type=jnp.float32)
-            lm_store_shape = head_m_ref.shape
-            m_curr = jnp.broadcast_to(m_curr, lm_store_shape)
+            qkv = jnp.concatenate([
+                jnp.dot(s_curr[i * head_rows : (i + 1) * head_rows], v,
+                        preferred_element_type=jnp.float32)
+                for i, v in enumerate(vs)
+            ], axis=0)
+            lm_shape = (rows, 128)
+            m_curr = jnp.broadcast_to(m_curr, lm_shape)
             l_curr = jnp.broadcast_to(
-                s_curr.sum(axis=1, keepdims=True), lm_store_shape
+                s_curr.sum(axis=1, keepdims=True), lm_shape
             )
-            m_prev = load_with_init(head_m_ref, -jnp.inf)
-            l_prev = load_with_init(head_l_ref, 0.0)
+            m_prev = load_with_init(m_win, -jnp.inf)
+            l_prev = load_with_init(l_win, 0.0)
             m_next = jnp.maximum(m_prev, m_curr)
-            masked_store(head_m_ref, m_next, store_start, store_end,
-                         num_q_heads_per_kv_head)
+            store_live(m_win, m_next)
             alpha = jnp.exp(m_prev - m_next)
             beta = jnp.exp(m_curr - m_next)
             l_alpha = alpha * l_prev
             l_next = l_alpha + beta * l_curr
             l_next_safe = jnp.where(l_next == 0.0, 1.0, l_next)
-            masked_store(head_l_ref, l_next_safe, store_start, store_end,
-                         num_q_heads_per_kv_head)
+            store_live(l_win, l_next_safe)
 
             def broadcast_to_shape(arr, shape):
                 """Match the 128-lane l/m values to head_dim lanes. Every
@@ -396,13 +518,161 @@ def _rpa_kernel(
                     [arr for _ in range(shape[1] // arr.shape[1])], axis=1
                 )
 
-            o_curr = load_with_init(head_acc_ref, 0.0).reshape(-1, head_dim)
+            o_curr = load_with_init(acc_win, 0.0)
             l_alpha = broadcast_to_shape(l_alpha, qkv.shape)
             beta = broadcast_to_shape(beta, qkv.shape)
             l_next_safe_b = broadcast_to_shape(l_next_safe, qkv.shape)
             out = (l_alpha * o_curr + beta * qkv) / l_next_safe_b
-            masked_store(head_acc_ref, out.reshape(head_acc_ref.shape),
-                         store_start, store_end)
+            store_live(acc_win, out)
+
+        def visit(kv_buf, kv_blk_idx, *, win, sub_pages):
+            """The flash steps of one (sequence, KV block) visit over the
+            live sub-tiles of ``sub_pages`` pages of the fetched block: on
+            the rows of window ``win`` (one window of query tokens, every KV
+            head's rows in one step), or with ``win`` None on all the
+            block's query tokens, a KV head a step."""
+            narrow = win is not None
+            group = num_q_heads_per_kv_head
+            if narrow:
+                # Rows: (KV head, token of the window, q head of the group).
+                rows = num_kv_heads_per_blk * win_rows
+                tok = win * win_toks + (
+                    lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % win_rows
+                ) // group
+            else:
+                rows = num_windows * win_rows
+                tok = lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group
+            sub_keys = sub_pages * page_size
+            max_sub_tiles = num_kv_pages_per_blk // sub_pages
+            kv_len_start = kv_blk_idx * num_kv_per_blk
+            num_sub_tiles = visit_sub_tiles(
+                key_hi, kv_len_start, sub_keys, max_sub_tiles
+            )
+            live = jnp.logical_and(tok >= tok_lo, tok < tok_hi)
+            row_pos = blk_pos + tok
+            if block_len > 1:
+                # Causal over blocks: a query sees up to the last position
+                # of its own block (a row's queries cover whole blocks, so
+                # that position is inside kv_len).
+                row_pos = (row_pos // block_len + 1) * block_len - 1
+            row_floor = row_pos - window_floor
+            if not packed:
+                kv_ref = kv_buf.reshape(
+                    max_sub_tiles,
+                    sub_keys * num_combined_kv_heads_per_blk,
+                    head_dim,
+                )
+                kv_packing = _dtype_packing(kv_ref.dtype)
+                kv_load_step = max(1, kv_packing // 2)
+            else:
+                # Packed [.., KH, 2D] layout (head_dim 64): K and V are the
+                # lane halves of one 128-lane row; split with aligned lane
+                # slices (the interleaved layout's bitcast strided load
+                # requires 128-lane base memrefs, which D=64 can't give).
+                kv_ref = None
+                kv_load_step = 1
+
+            def sub_tile(sub_idx, carry):
+                init = jnp.logical_and(
+                    kv_blk_idx == cur_start_blk, sub_idx == 0
+                )
+                # Position arithmetic is in GLOBAL context coordinates:
+                # local flat slot c maps to page (c // ps) * stride +
+                # phase, offset c % ps. One row of columns and one column
+                # of rows; the comparison broadcasts them.
+                sub_start = kv_len_start + sub_idx * sub_keys
+                col_flat = sub_start + lax.broadcasted_iota(
+                    jnp.int32, (1, sub_keys), 1
+                )
+                col = (
+                    (col_flat // page_size) * ctx_stride + ctx_phase
+                ) * page_size + col_flat % page_size
+                masked = jnp.logical_or(col > row_pos, col <= row_floor)
+                # KV rows beyond the (striped) context were not copied:
+                # zero them so the contraction stays NaN-free.
+                kv_live = (
+                    sub_start
+                    + lax.broadcasted_iota(jnp.int32, (sub_keys, 1), 0)
+                ) < local_tokens
+                kw = dict(masked=masked, init=init, live=live)
+
+                def load_kv(kv_head_chunk_idx):
+                    """K and V of ``kv_load_step`` KV heads from the head
+                    ``kv_head_chunk_idx`` on, dequantized."""
+                    if kv_ref is not None:
+                        k_list, v_list = strided_load_kv(
+                            kv_ref.at[sub_idx], kv_head_chunk_idx * 2,
+                            num_combined_kv_heads_per_blk, live=kv_live,
+                        )
+                    else:
+                        kv_rows = kv_buf[
+                            pl.ds(sub_idx * sub_pages, sub_pages), :,
+                            kv_head_chunk_idx, :,
+                        ].reshape(sub_keys, kv_lanes)
+                        kv_rows = jnp.where(
+                            kv_live, kv_rows.astype(jnp.float32), 0
+                        ).astype(kv_rows.dtype)
+                        k_list = [kv_rows[:, :head_dim]]
+                        v_list = [kv_rows[:, head_dim:]]
+                    if k_scale is not None:
+                        k_list = [
+                            (k.astype(jnp.float32) * k_scale).astype(
+                                q_ref.dtype) for k in k_list
+                        ]
+                    if v_scale is not None:
+                        v_list = [
+                            (v.astype(jnp.float32) * v_scale).astype(
+                                q_ref.dtype) for v in v_list
+                        ]
+                    return k_list, v_list
+
+                def wide_heads(kv_head_chunk_idx, carry):
+                    for i, (k, v) in enumerate(zip(*load_kv(
+                            kv_head_chunk_idx))):
+                        h = kv_head_chunk_idx + i
+                        flash_attention(
+                            [qf_ref[:, h].reshape(rows, head_dim)], [k], [v],
+                            l_ref.at[:, h], m_ref.at[:, h], acc_ref.at[:, h],
+                            **kw,
+                        )
+                    return carry
+
+                head_chunks = range(0, num_kv_heads_per_blk, kv_load_step)
+                if narrow:
+                    ks, vs = [], []
+                    for kv_head_chunk_idx in head_chunks:
+                        k_list, v_list = load_kv(kv_head_chunk_idx)
+                        ks += k_list
+                        vs += v_list
+                    flash_attention(
+                        [qf_ref[win, h] for h in range(len(ks))], ks, vs,
+                        l_ref.at[win], m_ref.at[win],
+                        acc_ref.at[win], **kw,
+                    )
+                elif packed:
+                    # The packed layout's head is a sublane index.
+                    for kv_head_chunk_idx in head_chunks:
+                        wide_heads(kv_head_chunk_idx, 0)
+                else:
+                    # A loop over the KV heads, a few a step: a wide tile
+                    # is work enough for the scheduler, and the program a
+                    # start compiles is a third of the unrolled one.
+                    unroll = math.gcd(_WIDE_HEADS_PER_STEP, len(head_chunks))
+
+                    def some_heads(c, carry):
+                        for u in range(unroll):
+                            wide_heads((c * unroll + u) * kv_load_step, carry)
+                        return carry
+
+                    lax.fori_loop(
+                        0, len(head_chunks) // unroll, some_heads, 0
+                    )
+                return carry
+
+            if max_sub_tiles == 1:
+                sub_tile(0, 0)
+            else:
+                lax.fori_loop(0, num_sub_tiles, sub_tile, 0)
 
         def is_valid_kv_blk_in_cur_seq(kv_states):
             kv_blk_idx, _ = kv_states
@@ -419,68 +689,29 @@ def _rpa_kernel(
 
             @pl.when(next_heads_blk_idx < num_heads_blks)
             def prefetch_next_kv_blk():
-                make_page_copy(
+                page_copies(
                     next_heads_blk_idx, next_seq_idx, next_kv_blk_idx,
-                    next_buf_idx,
-                ).start()
-
-            kv_buf = make_page_copy(
-                heads_blk_idx, cur_seq_idx, kv_blk_idx, cur_buf_idx
-            ).wait()  # [pages, page_size, head rows, lanes]
-            if not packed:
-                kv_ref = kv_buf.reshape(
-                    num_kv_pages_per_blk * page_size
-                    * num_combined_kv_heads_per_blk,
-                    head_dim,
+                    next_buf_idx, wait=False,
                 )
-                kv_packing = _dtype_packing(kv_ref.dtype)
-                kv_load_step = max(1, kv_packing // 2)
+
+            page_copies(
+                heads_blk_idx, cur_seq_idx, kv_blk_idx, cur_buf_idx,
+                wait=True,
+            )
+            kv_buf = kv_bufs.at[cur_buf_idx]  # [pages, page, rows, lanes]
+            wide = dict(win=None, sub_pages=tiling.wide_sub_pages)
+            if tiling.narrow_q == 0:
+                visit(kv_buf, kv_blk_idx, **wide)
             else:
-                # Packed [.., KH, 2D] layout (head_dim 64): K and V are the
-                # lane halves of one 128-lane row; split with aligned lane
-                # slices (the interleaved layout's bitcast strided load
-                # requires 128-lane base memrefs, which D=64 can't give).
-                kv_ref = None
-                kv_load_step = 1
-            for kv_head_chunk_idx in range(0, num_kv_heads_per_blk,
-                                           kv_load_step):
-                if kv_ref is not None:
-                    k_list, v_list = strided_load_kv(
-                        kv_ref, kv_head_chunk_idx * 2,
-                        num_combined_kv_heads_per_blk,
-                    )
-                else:
-                    rows = kv_buf[:, :, kv_head_chunk_idx, :]
-                    k_list = [rows[..., :head_dim].reshape(-1, head_dim)]
-                    v_list = [rows[..., head_dim:].reshape(-1, head_dim)]
-                for step_idx in range(kv_load_step):
-                    k = k_list[step_idx]
-                    v = v_list[step_idx]
-                    if k_scale is not None:
-                        k = (k.astype(jnp.float32) * k_scale).astype(
-                            q_ref.dtype
-                        )
-                    if v_scale is not None:
-                        v = (v.astype(jnp.float32) * v_scale).astype(
-                            q_ref.dtype
-                        )
-                    kv_head_idx = kv_head_chunk_idx + step_idx
-                    q_head_idx = kv_head_idx * num_q_heads_per_kv_head
-                    q = fold_on_2nd_minor(
-                        q_ref[:, q_head_idx : q_head_idx
-                              + num_q_heads_per_kv_head, :]
-                    )
-                    flash_attention(
-                        q, k, v,
-                        l_ref.at[kv_head_idx],
-                        m_ref.at[kv_head_idx],
-                        acc_ref.at[
-                            :, q_head_idx : q_head_idx
-                            + num_q_heads_per_kv_head, :
-                        ],
-                        kv_blk_idx=kv_blk_idx,
-                        start_blk=cur_start_blk,
-                    )
+                @pl.when(is_narrow)
+                def narrow_visit():
+                    visit(kv_buf, kv_blk_idx, win=narrow_win,
+                          sub_pages=tiling.narrow_sub_pages)
+
+                @pl.when(jnp.logical_not(is_narrow))
+                def wide_visit():
+                    visit(kv_buf, kv_blk_idx, **wide)
+
             return kv_blk_idx + 1, next_buf_idx
 
         cur_start_blk = seq_start_blk(cur_seq_idx)
@@ -501,12 +732,21 @@ def _rpa_kernel(
     )
     seq_buf_idx_ref[0] = lax.select(seq_idx < num_seqs, seq_idx, 0)
     seq_buf_idx_ref[1] = buf_idx
-    o_ref[...] = acc_ref[...].astype(q_ref.dtype)
+    for kv_head_idx in range(num_kv_heads_per_blk):
+        q_head_idx = kv_head_idx * num_q_heads_per_kv_head
+        o_ref[:, q_head_idx : q_head_idx + num_q_heads_per_kv_head, :] = (
+            acc_ref[:, kv_head_idx]
+            .reshape(num_q_per_blk, num_q_heads_per_kv_head, head_dim)
+            .astype(o_ref.dtype)
+        )
     if return_lse:
         # lse = m + log(l): scratch blocks are [KH_blk, numq*ratio, 128]
         # (value broadcast over lanes); the host-side wrapper slices lane 0
         # and rearranges to [T, num_q_heads].
-        lse_ref[...] = m_ref[...] + jnp.log(l_ref[...])
+        for kv_head_idx in range(num_kv_heads_per_blk):
+            lse_ref[kv_head_idx] = (
+                m_ref[:, kv_head_idx] + jnp.log(l_ref[:, kv_head_idx])
+            ).reshape(lse_ref.shape[1:])
 
 
 def _validate(q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs):
@@ -562,6 +802,18 @@ def _min_heads_per_blk(num_q_heads, num_combined_kv_heads, q_dtype, kv_dtype):
     return num_q_heads, num_combined_kv_heads
 
 
+def _heads_per_blk(num_q_heads, num_combined_kv_heads, head_dim, q_dtype,
+                   kv_dtype):
+    """(q heads, combined KV heads) of one heads block."""
+    if head_dim % 128 == 0:
+        return _min_heads_per_blk(
+            num_q_heads, num_combined_kv_heads, q_dtype, kv_dtype
+        )
+    # Packed layout (head_dim 64): one heads block, no HBM heads slicing (a
+    # lane-dim or sub-tile memref slice is rejected by Mosaic).
+    return num_q_heads, num_combined_kv_heads
+
+
 def _fit_kv_pages_to_vmem(
     num_kv_pages_per_blk: int,
     vmem_budget_bytes: int,
@@ -573,26 +825,27 @@ def _fit_kv_pages_to_vmem(
     page_size: int,
     q_itemsize: int,
     kv_itemsize: int,
-) -> int:
-    """Halve the KV block until the kernel's VMEM need fits the budget.
+) -> tuple[int, int]:
+    """Halve the KV block until the kernel's VMEM need fits the budget;
+    returns (pages, the need in bytes).
 
-    The tuned table's entries (and its untuned (128, 32) default, which
-    is what Llama-3-8B's 32q/8kv heads get) assume nothing about this
-    kernel's extra scratch, and at 8B widths the default overflows the
-    chip's 16 MiB scoped limit. The need is estimated from the block
-    shapes as ``1.5 * kv double buffer + 2 * (q, out, acc, l, m blocks)
-    + the per-kv-head f32 score tiles``: the coefficients bound from
+    The need is estimated from the block shapes as ``9/8 * (1.5 * kv
+    double buffer + 2 * (q, out, acc, folded q, l, m blocks) + the
+    per-kv-head f32 score tiles) + 1 MiB``: the coefficients bound from
     above the smallest ``vmem_limit_bytes`` the v5e compiler accepted
-    (MiB, bf16 q, 32q/16 combined heads, D=128, page 16; pages x queries):
-    8x32: 5, 32x32: 11, 64x32: 17, 128x32: 28, 8x128: 14, 32x64: 18;
-    fp8 KV 32x32: 7, 64x32: 10; a tp=4 shard (8q/4) 128x32: 7.
-    ``tests/test_tpu_aot_compile.py`` holds the result to the compiler.
+    (MiB, bf16 q, D=128, page 16; pages x queries). 32q/16 combined heads:
+    32x32: 12, 32x64: 16, 32x128: 26, 64x128: 40, 128x128: 56, 8x128: 14,
+    32x256: 46, and with the LSE output 13, 18, 30, 44, 60, 18, 54; fp8 KV
+    32x32: 9, 32x128: 24, 128x128: 46; 32q/8 with a mask over blocks of 4
+    32x32: 9, 32x128: 25, 128x128: 55; a tp=4 shard (8q/4) 32x32: 3,
+    32x128: 8, 128x128: 19. ``tests/test_tpu_aot_compile.py`` holds the
+    result to the compiler.
     """
     num_kv_heads_per_blk = num_combined_kv_heads_per_blk // 2
     ratio = num_q_heads_per_blk // num_kv_heads_per_blk
     q_blk_elems = num_q_per_blk * num_q_heads_per_blk * head_dim
     lm_bytes = num_kv_heads_per_blk * num_q_per_blk * ratio * 128 * 4
-    q_side = q_blk_elems * (2 * q_itemsize + 4) + 2 * lm_bytes
+    q_side = q_blk_elems * (3 * q_itemsize + 4) + 2 * lm_bytes
 
     def need(pages: int) -> int:
         kv_tokens = pages * page_size
@@ -601,13 +854,88 @@ def _fit_kv_pages_to_vmem(
             * kv_itemsize
         )
         scores = num_kv_heads_per_blk * num_q_per_blk * ratio * kv_tokens * 4
-        return kv_double_buf * 3 // 2 + 2 * q_side + scores
+        total = kv_double_buf * 3 // 2 + 2 * q_side + scores
+        return total * 9 // 8 + (1 << 20)
 
     while num_kv_pages_per_blk > 1 and need(num_kv_pages_per_blk) > (
         vmem_budget_bytes
     ):
         num_kv_pages_per_blk //= 2
-    return num_kv_pages_per_blk
+    return num_kv_pages_per_blk, need(num_kv_pages_per_blk)
+
+
+def ragged_tiling(
+    num_q_heads: int,
+    num_combined_kv_heads: int,
+    head_dim: int,
+    page_size: int,
+    q_dtype,
+    kv_dtype,
+    num_q_tokens: int,
+    pages_per_seq: int,
+    *,
+    block_len: int = 1,
+    num_kv_pages_per_block: int | None = None,
+    num_queries_per_block: int | None = None,
+    vmem_limit_bytes: int | None = None,
+) -> RaggedTiling:
+    """The tile sizes ``ragged_paged_attention`` runs these shapes with
+    (a tp shard's head counts where the kernel runs a shard).
+
+    The blocks are the caller's, or chosen from the shapes by what a probe
+    of the kernel on the v5e and runs of both cells found (``PERF.md``, PR
+    32): as many query tokens a block as give a KV head's wide tile 512
+    rows (128 tokens at 4 q heads a KV head, 64 at 8; never under 32 nor
+    over 128) -- a prefill chunk's keys are fetched and its tiles' fixed
+    work paid once a query block, but past 512 rows the kernel gains
+    nothing and the NEXT kernel of the step loses (``gmm`` 5% at 1024
+    rows) -- KV blocks of 512 keys, and the scoped VMEM the two need asked
+    of the compiler, up to 64 MiB of the chip's 128; past that the KV
+    block halves. The rungs follow: the narrow
+    window is the fewest tokens that hold a decode row (or a row of
+    ``block_len`` tokens) and whose rows fill whole sublane tiles of q;
+    its keys go 256 at a time, the wide rung's a whole block at a time (a
+    smaller step repeats the softmax's fixed work, which costs the chunk
+    more than the dead keys of its last block)."""
+    q_dtype, kv_dtype = jnp.dtype(q_dtype), jnp.dtype(kv_dtype)
+    ratio = num_q_heads // (num_combined_kv_heads // 2)
+    num_q_per_blk = num_queries_per_block
+    num_kv_pages_per_blk = num_kv_pages_per_block
+    if num_q_per_blk is None or num_kv_pages_per_blk is None:
+        num_q_heads_per_blk, num_combined_kv_heads_per_blk = _heads_per_blk(
+            num_q_heads, num_combined_kv_heads, head_dim, q_dtype, kv_dtype
+        )
+        num_q_per_blk = min(
+            max(32, min(128, _ROWS_PER_HEAD_TILE // ratio)), num_q_tokens
+        )
+        num_kv_pages_per_blk, need = _fit_kv_pages_to_vmem(
+            min(max(1, _KEYS_PER_BLOCK // page_size), pages_per_seq),
+            vmem_limit_bytes or _MAX_SCOPED_VMEM_BYTES,
+            num_q_per_blk=num_q_per_blk,
+            num_q_heads_per_blk=num_q_heads_per_blk,
+            num_combined_kv_heads_per_blk=num_combined_kv_heads_per_blk,
+            head_dim=head_dim,
+            page_size=page_size,
+            q_itemsize=q_dtype.itemsize,
+            kv_itemsize=kv_dtype.itemsize,
+        )
+        if vmem_limit_bytes is None and need > _DEFAULT_SCOPED_VMEM_BYTES:
+            vmem_limit_bytes = need
+    tile_rows = 8 * _dtype_packing(q_dtype)
+    tile_toks = tile_rows // math.gcd(tile_rows, ratio)
+    narrow_q = -(-block_len // tile_toks) * tile_toks
+    if narrow_q >= num_q_per_blk or num_q_per_blk % narrow_q:
+        narrow_q = 0
+    return RaggedTiling(
+        kv_pages_per_blk=num_kv_pages_per_blk,
+        q_per_blk=num_q_per_blk,
+        narrow_q=narrow_q,
+        narrow_sub_pages=math.gcd(
+            num_kv_pages_per_blk, max(1, _NARROW_SUB_KEYS // page_size)
+        ),
+        wide_sub_pages=num_kv_pages_per_blk,
+        vmem_limit_bytes=vmem_limit_bytes,
+    )
 
 
 @functools.partial(
@@ -648,7 +976,6 @@ def ragged_paged_attention(
     ``block_len`` > 1 makes the mask causal over blocks of that many
     positions (``col // block_len <= row // block_len``); every row's
     query tokens must then cover whole blocks, and no window applies.
-    1 leaves the program as it was.
 
     Returns ``out [T, H, D]``, or ``(out, lse [T, H] f32)`` with
     ``return_lse=True``.
@@ -669,41 +996,18 @@ def ragged_paged_attention(
     num_combined_kv_heads = 2 * kv_rows if packed else kv_rows
     num_kv_heads = num_combined_kv_heads // 2
     _, pages_per_seq = page_indices.shape
-    if not packed:
-        num_q_heads_per_blk, num_combined_kv_heads_per_blk = (
-            _min_heads_per_blk(
-                num_q_heads, num_combined_kv_heads, q.dtype, kv_pages.dtype
-            )
-        )
-    else:
-        # Packed layout: one heads block, no HBM heads slicing (a lane-dim
-        # or sub-tile memref slice is rejected by Mosaic).
-        num_q_heads_per_blk = num_q_heads
-        num_combined_kv_heads_per_blk = num_combined_kv_heads
-    num_q_per_blk = num_queries_per_block
-    num_kv_pages_per_blk = num_kv_pages_per_block
-    if num_q_per_blk is None or num_kv_pages_per_blk is None:
-        num_kv_pages_per_blk, num_q_per_blk = get_tuned_block_sizes(
-            q.dtype,
-            kv_pages.dtype,
-            num_q_heads_per_blk,
-            num_combined_kv_heads_per_blk // 2,
-            head_dim,
-            page_size,
-            num_q_tokens,
-            pages_per_seq,
-        )
-        num_kv_pages_per_blk = _fit_kv_pages_to_vmem(
-            min(num_kv_pages_per_blk, pages_per_seq),
-            vmem_limit_bytes or _DEFAULT_SCOPED_VMEM_BYTES,
-            num_q_per_blk=num_q_per_blk,
-            num_q_heads_per_blk=num_q_heads_per_blk,
-            num_combined_kv_heads_per_blk=num_combined_kv_heads_per_blk,
-            head_dim=head_dim,
-            page_size=page_size,
-            q_itemsize=q.dtype.itemsize,
-            kv_itemsize=kv_pages.dtype.itemsize,
-        )
+    num_q_heads_per_blk, num_combined_kv_heads_per_blk = _heads_per_blk(
+        num_q_heads, num_combined_kv_heads, head_dim, q.dtype, kv_pages.dtype
+    )
+    tiling = ragged_tiling(
+        num_q_heads, num_combined_kv_heads, head_dim, page_size, q.dtype,
+        kv_pages.dtype, num_q_tokens, pages_per_seq, block_len=block_len,
+        num_kv_pages_per_block=num_kv_pages_per_block,
+        num_queries_per_block=num_queries_per_block,
+        vmem_limit_bytes=vmem_limit_bytes,
+    )
+    num_q_per_blk = tiling.q_per_blk
+    num_kv_pages_per_blk = tiling.kv_pages_per_blk
     num_q_heads_per_kv_head = num_q_heads // num_kv_heads
     num_q_blks = pl.cdiv(num_q_tokens, num_q_per_blk)
     num_kv_heads_per_blk = num_combined_kv_heads_per_blk // 2
@@ -718,8 +1022,8 @@ def ragged_paged_attention(
         (num_q_per_blk, num_q_heads_per_blk, head_dim), q_index_map
     )
     in_specs = [q_block_spec, pl.BlockSpec(memory_space=pl.ANY)]
-    lm_shape = (num_kv_heads_per_blk,
-                num_q_per_blk * num_q_heads_per_kv_head, 128)
+    rows_per_blk = num_q_per_blk * num_q_heads_per_kv_head
+    lm_shape = (num_kv_heads_per_blk, rows_per_blk, 128)
     out_specs = [q_block_spec]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     if return_lse:
@@ -729,7 +1033,7 @@ def ragged_paged_attention(
         out_shape.append(
             jax.ShapeDtypeStruct(
                 (num_heads_blks * num_kv_heads_per_blk,
-                 num_q_blks * num_q_per_blk * num_q_heads_per_kv_head, 128),
+                 num_q_blks * rows_per_blk, 128),
                 jnp.float32,
             )
         )
@@ -738,16 +1042,21 @@ def ragged_paged_attention(
         if packed
         else num_combined_kv_heads_per_blk
     )
+    # q, acc, l and m by window of the narrow rung (one window where
+    # there is none), KV head and the window's rows, token-major.
+    win_toks = tiling.narrow_q or num_q_per_blk
+    win_rows = win_toks * num_q_heads_per_kv_head
+    win_shape = (num_q_per_blk // win_toks, num_kv_heads_per_blk, win_rows)
     scratch_shapes = [
         pltpu.VMEM(
             (2, num_kv_pages_per_blk, page_size, kv_rows_per_blk, kv_lanes),
             kv_pages.dtype,
         ),
         pltpu.SemaphoreType.DMA((2,)),
-        pltpu.VMEM(lm_shape, jnp.float32),  # l
-        pltpu.VMEM(lm_shape, jnp.float32),  # m
-        pltpu.VMEM((num_q_per_blk, num_q_heads_per_blk, head_dim),
-                   jnp.float32),  # acc
+        pltpu.VMEM((*win_shape, 128), jnp.float32),  # l
+        pltpu.VMEM((*win_shape, 128), jnp.float32),  # m
+        pltpu.VMEM((*win_shape, head_dim), jnp.float32),  # acc
+        pltpu.VMEM((*win_shape, head_dim), q.dtype),  # q, folded
     ]
     window = jnp.asarray(
         0 if sliding_window is None else sliding_window, jnp.int32
@@ -775,7 +1084,8 @@ def ragged_paged_attention(
             k_scale=k_scale,
             v_scale=v_scale,
             return_lse=return_lse,
-            **({"block_len": block_len} if block_len > 1 else {}),
+            block_len=block_len,
+            tiling=tiling,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalar_prefetches),
@@ -786,7 +1096,7 @@ def ragged_paged_attention(
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=vmem_limit_bytes,
+            vmem_limit_bytes=tiling.vmem_limit_bytes,
         ),
         out_shape=out_shape,
         name="rpa_kernel",

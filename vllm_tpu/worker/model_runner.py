@@ -31,7 +31,8 @@ from vllm_tpu.core.sched_output import (
     SchedulerOutput,
 )
 from vllm_tpu.logger import init_logger
-from vllm_tpu.ops.attention import AttentionMetadata
+from vllm_tpu.ops.attention import AttentionMetadata, ragged_kernel_tiling
+from vllm_tpu.ops.rpa_kernel import count_ragged_pairs
 from vllm_tpu.resilience.failpoints import fail_point
 from vllm_tpu.tracing import trace_span
 from vllm_tpu.sample.sampler import (
@@ -475,6 +476,12 @@ class ModelRunner:
         self.steps_by_kind = {"decode": 0, "mixed": 0}
         self.step_tokens_by_kind = {"decode": 0, "mixed": 0}
         self.attn_context_tokens_by_kind = {"decode": 0, "mixed": 0}
+        # (query token, key) pairs of the ragged attention kernel's calls,
+        # cumulative, a q head a layer: those the mask lets through, and
+        # those its tiles compute for them (``count_ragged_pairs`` mirrors
+        # its choice of rung and of key extent). Steps that run it only.
+        self.attn_ragged_pairs = {"live": 0, "computed": 0}
+        self._ragged_tilings: dict[tuple[int, int], Any] = {}
         # Generation by diffusion over blocks, cumulative: row-passes by
         # kind, and the positions denoising passes committed. Mixture of
         # experts, cumulative: token-expert pairs over live tokens, and
@@ -609,6 +616,30 @@ class ModelRunner:
         logit_adjust = (adj_ids, adj_vals, allow_ids, allow_active)
         return (token_ids, md, sampling, feedback, grammar_rows, logit_adjust,
                 draft_next, token_lora, plp_next, spec, dyn, blk)
+
+    def _count_ragged_pairs(self, q_lens, kv_lens, t_pad: int, b_pad: int):
+        """Add a step's pairs to ``attn_ragged_pairs`` where its attention
+        runs the ragged kernel (a model with plain K/V heads, on the chip)."""
+        block_len = max(self.block_len, 1)
+        key = (t_pad, b_pad)
+        if key not in self._ragged_tilings:
+            m = self.model
+            plain = all(hasattr(m, a) for a in
+                        ("num_heads", "num_kv_heads", "head_dim")) and not (
+                hasattr(m, "kv_cache_shape") or hasattr(m, "alloc_kv_cache"))
+            self._ragged_tilings[key] = plain and ragged_kernel_tiling(
+                m.num_heads, m.num_kv_heads, m.head_dim, self.block_size,
+                m.dtype, self.kv_cache.dtype, t_pad, b_pad,
+                block_len=block_len, tp_size=self.tp_size,
+            )
+        tiling = self._ragged_tilings[key]
+        if tiling:
+            live, computed = count_ragged_pairs(
+                q_lens, kv_lens, tiling, page_size=self.block_size,
+                block_len=block_len,
+            )
+            self.attn_ragged_pairs["live"] += live
+            self.attn_ragged_pairs["computed"] += computed
 
     def _build_tree_metadata(self, md, spec, t_pad: int, r_pad: int):
         """In-jit tree-verify views (host prep stays the chain layout).
@@ -2232,6 +2263,11 @@ class ModelRunner:
         self.step_tokens_by_kind[kind] += t_live
         self.attn_context_tokens_by_kind[kind] += int(
             seq_lens[:r_live].sum())
+        if not flags["decode_only"]:
+            self._count_ragged_pairs(
+                np.diff(query_start_loc[: r_live + 1]), seq_lens[:r_live],
+                t_pad, b_pad,
+            )
         # launch_sampled_tokens counts REALIZED emissions — finalize
         # accumulates the per-row token runs it actually folds, which is
         # exact for every path (fixed K, dynamic, spec, prefill).
