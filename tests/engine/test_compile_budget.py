@@ -4,6 +4,8 @@ Reference analog: the CUDA-graph capture list (``cudagraph_dispatcher``)
 is the reference's compile-count control; here the knob thins the derived
 pow2 bucket ladders until token_buckets x request_buckets fits."""
 
+import pytest
+
 from vllm_tpu.config import CompilationConfig, SchedulerConfig
 
 
@@ -40,3 +42,21 @@ def test_tiny_budget_terminates():
     cc.finalize(_sched())
     # Cannot reach 1 (endpoints are kept) but must terminate at 2x2.
     assert len(cc.token_buckets) == 2 and len(cc.request_buckets) == 2
+
+
+@pytest.mark.parametrize("tokens,requests,seqs,pages", [
+    # Derived ladders, and explicit ones that leave more than one shape
+    # (the pinned diffusion cell): the page ladder as it always was.
+    ([], [], 512, [16, 32, 64, 128, 256, 512, 768]),
+    ([8192], [128, 512], 512, [16, 32, 64, 128, 256, 512, 768]),
+    ([8192], [], 512, [16, 32, 64, 128, 256, 512, 768]),
+    # A derived ladder of one entry is not a pinned one.
+    ([], [], 8, [16, 32, 64, 128, 256, 512, 768]),
+    # One explicit shape of tokens and rows: the whole table alone.
+    ([8192], [512], 512, [768]),
+], ids=["derived", "two_row_buckets", "tokens_only", "derived_single", "one_shape"])
+def test_one_pinned_step_shape_keeps_one_page_bucket(tokens, requests, seqs, pages):
+    cc = CompilationConfig(token_buckets=tokens, request_buckets=requests)
+    cc.finalize(SchedulerConfig(max_num_batched_tokens=8192, max_num_seqs=seqs))
+    assert cc.page_buckets(768) == pages
+    assert cc.page_buckets(8) == [8]

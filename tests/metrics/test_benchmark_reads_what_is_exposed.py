@@ -55,7 +55,9 @@ def exposed() -> dict[str, set[tuple[str, str]]]:
             step_first_use_s=7.5, sampler_fallback_rows=3,
             steps_by_kind={"decode": 9, "mixed": 1},
             attn_context_tokens_by_kind={"decode": 180, "mixed": 10,
-                                         "block": 11},
+                                         "block": 11, "latent": 12},
+            linear_attn_state_rows=4,
+            linear_attn_tokens_by_kind={"chunk": 3, "decode": 2},
             attn_ragged_pairs={"live": 26, "computed": 100},
             diffusion_passes_by_kind={"denoise": 2, "store": 1},
             diffusion_committed_tokens=3, moe_assignments=5,

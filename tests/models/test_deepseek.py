@@ -235,10 +235,11 @@ def test_deepseek_latent_cache_geometry(tiny_deepseek):
 
     hf_config = AutoConfig.from_pretrained(tiny_deepseek)
     model = get_model_class(hf_config)(hf_config, dtype=jnp.float32)
-    assert model.kv_cache_shape(10, 16) == (3, 10, 16, 1, 32 + 16)
+    # A row of 48 numbers lies in one 128-lane tile, tokens on the sublanes.
+    assert model.kv_cache_shape(10, 16) == (3, 10, 16, 128)
     spec = model.get_kv_cache_spec(16, 4)["layers.0"]
     assert isinstance(spec, MLAAttentionSpec)
-    assert spec.page_size_bytes == 16 * (32 + 16) * 4
+    assert spec.page_size_bytes == 16 * 128 * 4
 
 
 def test_deepseek_v3_e2e_greedy_matches_hf(tmp_path_factory):

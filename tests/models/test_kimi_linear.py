@@ -1,0 +1,292 @@
+"""Kimi-Linear on the CPU at a tiny size: the two forms of the delta rule
+with a decay a key channel against the recurrence a token, the expert
+share against the uncut layer, and the counters the runner keeps."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tests.benchmark.tiny_kimi_linear import TINY_KIMI, model_dir, share
+from vllm_tpu.ops import kda
+from vllm_tpu.ops.gdn import ragged_gated_delta_rule
+
+H, D, SLOTS, LAYERS, LAYER = 2, 16, 16, 2, 1
+
+
+def _batch(qlens, fresh, t_pad, r_pad, seed):
+    rng = np.random.default_rng(seed)
+    n, live = len(qlens), sum(qlens)
+    qsl = np.full(r_pad + 1, live, np.int32)
+    qsl[0] = 0
+    qsl[1: n + 1] = np.cumsum(qlens)
+    owner = np.zeros(t_pad, np.int32)
+    for i in range(n):
+        owner[qsl[i]: qsl[i + 1]] = i
+    f32 = lambda *shape: rng.normal(size=shape).astype(np.float32)
+    q, k, v = f32(t_pad, H, D), f32(t_pad, H, D), f32(t_pad, H, D)
+    # Log-decays from -20 (gone in one token) to -0.007 a token.
+    a = -np.exp(f32(t_pad, H, D) * 2 - 1)
+    beta = 1 / (1 + np.exp(-f32(t_pad, H)))
+    state = f32(LAYERS, SLOTS + 1, H, D, D)
+    slots = np.full(r_pad, SLOTS, np.int32)
+    slots[:n] = rng.permutation(SLOTS)[:n]
+    is_fresh = np.zeros(r_pad, bool)
+    is_fresh[:n] = fresh
+    return dict(q=q, k=k, v=v, a=a, beta=beta, state=state, slots=slots,
+                fresh=is_fresh, qsl=qsl, owner=owner, n=n, live=live)
+
+
+RAGGED = {
+    "decode rows only": ([1, 1, 1], [0, 0, 0], 5, 5, True),
+    "a chunk beside decode rows": ([1, 1, 20, 1], [0, 1, 0, 0], 32, 8, False),
+    "chunks that start and continue requests": ([3, 9, 1, 17, 2], [1, 0, 0, 1, 0], 40, 8, False),
+    "one request over four chunks": ([30], [0], 32, 2, False),
+    "more chunks than one round holds": ([2] * 14, [0, 1] * 7, 32, 16, False),
+    "a mixed step with no chunk": ([1, 1], [0, 0], 8, 4, False),
+    "a mixed step with no decode row": ([5, 11], [1, 0], 16, 4, False),
+}
+
+
+@pytest.mark.parametrize("case", list(RAGGED))
+def test_both_forms_equal_the_recurrence_a_token(case, monkeypatch):
+    """``kda_attention`` against ``ops/gdn.py``'s scan a token (given the
+    decay a channel): outputs at the live tokens, the state each row
+    leaves in its slot, and nothing else touched: other slots, the other
+    layer, the padding rows. Chunks of 8 in blocks of 4, so that the tiny
+    rows cross chunk and block boundaries."""
+    monkeypatch.setattr(kda, "CHUNK", 8)
+    monkeypatch.setattr(kda, "SUB", 4)
+    qlens, fresh, t_pad, r_pad, decode_only = RAGGED[case]
+    b = _batch(qlens, fresh, t_pad, r_pad, seed=len(case))
+    dev = {k: jnp.asarray(v) for k, v in b.items() if isinstance(v, np.ndarray)}
+    qn = kda.l2norm(dev["q"]) * D ** -0.5
+    kn = kda.l2norm(dev["k"])
+    o, new_state = kda.kda_attention(
+        qn, kn, dev["v"], dev["a"], dev["beta"], dev["state"], LAYER, dev["slots"],
+        dev["fresh"], dev["qsl"], jnp.int32(b["n"]), decode_only=decode_only)
+    h0 = np.where(b["fresh"][:, None, None, None], 0, b["state"][LAYER][b["slots"]])
+    want, want_state = ragged_gated_delta_rule(
+        dev["q"], dev["k"], dev["v"], dev["a"], dev["beta"], jnp.asarray(h0),
+        dev["owner"], dev["qsl"])
+    o, new_state = np.asarray(o), np.asarray(new_state)
+    np.testing.assert_allclose(o[: b["live"]], np.asarray(want)[: b["live"]], atol=3e-6)
+    assert not o[b["live"]:].any()
+    for i in range(b["n"]):
+        np.testing.assert_allclose(new_state[LAYER, b["slots"][i]],
+                                   np.asarray(want_state)[i], atol=3e-6)
+    idle = [s for s in range(SLOTS) if s not in b["slots"][: b["n"]]]
+    np.testing.assert_array_equal(new_state[LAYER, idle], b["state"][LAYER, idle])
+    np.testing.assert_array_equal(new_state[1 - LAYER], b["state"][1 - LAYER])
+
+
+def test_a_strong_decay_neither_overflows_nor_loses_the_weak_channels():
+    """Log-decays of -60 a token beside -1e-4: a cumulated decay over a
+    chunk of 64 is exp(-3840), and nothing divides by it."""
+    rng = np.random.default_rng(0)
+    t = 150
+    f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q, k, v = f32(t, H, D), f32(t, H, D), f32(t, H, D)
+    a = jnp.where(jnp.arange(D) % 2 == 0, -60.0, -1e-4) * jnp.ones((t, H, D))
+    beta = jax.nn.sigmoid(f32(t, H))
+    state = f32(1, 3, H, D, D)
+    qsl = jnp.asarray([0, t, t], jnp.int32)
+    o, new = kda.kda_chunks(
+        kda.l2norm(q) * D ** -0.5, kda.l2norm(k), v, a, beta, state, 0,
+        jnp.asarray([1, 2]), jnp.asarray([False, False]), qsl, jnp.int32(1))
+    want, want_state = ragged_gated_delta_rule(
+        q, k, v, a, beta, state[0, 1:2], jnp.zeros(t, jnp.int32), qsl)
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want), atol=3e-6)
+    np.testing.assert_allclose(np.asarray(new)[0, 1], np.asarray(want_state)[0], atol=3e-6)
+
+
+def test_the_short_convolution_continues_from_a_rows_tail():
+    rng = np.random.default_rng(1)
+    c, k = 6, 4
+    x = rng.normal(size=(20, c)).astype(np.float32)
+    taps = rng.normal(size=(k, c)).astype(np.float32)
+    whole = sum(np.concatenate([np.zeros((k - 1, c), np.float32), x])[j: j + 20] * taps[j]
+                for j in range(k))
+    tail = jnp.zeros((1, k - 1, c))
+    got = []
+    # 20 tokens as steps of 2, 1, 9, 1, 7: tails shorter and longer than a step.
+    for lo, hi in ((0, 2), (2, 3), (3, 12), (12, 13), (13, 20)):
+        y, tail = kda.ragged_short_conv(
+            jnp.asarray(x[lo:hi]), tail, jnp.asarray(taps),
+            jnp.zeros(hi - lo, jnp.int32), jnp.asarray([0, hi - lo], jnp.int32))
+        got.append(np.asarray(y))
+    np.testing.assert_allclose(np.concatenate(got), whole, atol=1e-5)
+
+
+def test_the_decode_only_convolution_is_the_ragged_one_a_token_a_row():
+    rng = np.random.default_rng(2)
+    r, c, k = 5, 6, 4
+    x = jnp.asarray(rng.normal(size=(r, c)), jnp.float32)
+    tail = jnp.asarray(rng.normal(size=(r, k - 1, c)), jnp.float32)
+    taps = jnp.asarray(rng.normal(size=(k, c)), jnp.float32)
+    args = (x, tail, taps, jnp.arange(r, dtype=jnp.int32), jnp.arange(r + 1, dtype=jnp.int32))
+    y, new = kda.ragged_short_conv(*args, decode_only=True)
+    want_y, want_new = kda.ragged_short_conv(*args)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(want_new))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_slot_rows_are_read_and_written_exactly_and_only_for_live_rows(dtype):
+    rng = np.random.default_rng(3)
+    stack = jnp.asarray(rng.normal(size=(2, 6, 8)), dtype)  # slot 5 is the scratch
+    slots = jnp.asarray([3, 0, 5, 5], jnp.int32)  # two live rows, two padding rows
+    np.testing.assert_array_equal(
+        np.asarray(kda.read_slot_rows(stack, jnp.int32(1), slots), np.float32),
+        np.asarray(stack[1, slots], np.float32))
+    rows = jnp.asarray(rng.normal(size=(4, 8)), dtype).at[1, 2].set(jnp.inf)
+    new = np.asarray(kda.write_slot_rows(stack, jnp.int32(1), slots, rows, jnp.int32(2)), np.float32)
+    want = np.asarray(stack, np.float32).copy()
+    want[1, 3] = np.asarray(rows[0], np.float32)
+    want[1, 0] = np.asarray(rows[1].at[2].set(0), np.float32)  # not finite: stored as 0
+    np.testing.assert_array_equal(new, want)  # the scratch slot and layer 0 untouched
+
+
+def _model(config, dtype=jnp.float32):
+    from transformers import PretrainedConfig
+
+    from vllm_tpu.models.registry import get_model_class
+
+    cfg = PretrainedConfig.from_dict(config)
+    return get_model_class(cfg)(cfg, dtype=dtype)
+
+
+def test_the_layers_run_as_scans_over_whole_periods():
+    from vllm_tpu.models.kimi_linear import layer_plan
+
+    k, m = ("kda", "moe"), ("mla", "moe")
+    first = ("kda", "dense")
+    assert layer_plan([first] + [k, k, m, k] * 3) == ([0], 4, 3, [])
+    # The published 27 layers: the last latent layer comes a layer early.
+    assert layer_plan([first] + [k, k, m, k] * 6 + [k, m]) == ([0], 4, 6, [25, 26])
+    assert layer_plan([first, k, m]) == ([0, 1, 2], 0, 0, [])
+    model = _model(TINY_KIMI)
+    assert (model.head_layers, model.period, model.reps, model.tail_layers) == ([0], 4, 2, [])
+    assert model.kinds[3] == ("mla", "moe") and model.kinds[0] == ("kda", "dense")
+
+
+def test_the_shares_routed_parts_and_the_shared_expert_once_make_the_uncut_layer():
+    """The guide's share test, on the program's own expert layer: four
+    shares of four experts each, given the slices of the uncut layer's
+    sixteen, route over all sixteen and compute their own part; the four
+    parts, with the shared expert counted once, are the uncut layer."""
+    whole = _model(TINY_KIMI)
+    rng = np.random.default_rng(2)
+    d, e, f = 64, 16, 32
+    w = lambda *shape: jnp.asarray(rng.normal(size=shape) / 8, jnp.float32)
+    lp = {"router": w(d, e), "router_bias": w(e) / 4, "ws_gate": w(d, f),
+          "ws_up": w(d, f), "ws_down": w(f, d)}
+    experts = {"we_gate": w(e, d, f), "we_up": w(e, d, f), "we_down": w(e, f, d)}
+    h = w(24, d) * 8
+    live = jnp.arange(24) < 20
+    full, counts = whole._moe(h, lp, experts, None, live)
+    assert int(counts.sum()) == 20 * 4
+    shared = whole._moe(h, lp, jax.tree.map(lambda x: x[:0], experts), None, live)
+    parts, hits = [], []
+    for rank in range(4):
+        part = _model(share(rank))
+        assert part.router_width == 16 and part.first_expert == 4 * rank
+        mine = jax.tree.map(lambda x: x[4 * rank: 4 * rank + 4], experts)
+        out, c = part._moe(h, lp, mine, None, live)
+        parts.append(out)
+        hits.append(c)
+    np.testing.assert_array_equal(np.concatenate(hits), np.asarray(counts))
+    only_shared = _model({**share(0), "num_experts": 0, "ep_size": 16})._moe(
+        h, lp, jax.tree.map(lambda x: x[:0], experts), None, live)[0]
+    del shared
+    np.testing.assert_allclose(
+        np.asarray(sum(parts) - 3 * only_shared), np.asarray(full), atol=2e-5)
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["one layer", "the scan's stack"])
+def test_the_grouped_matmul_sees_the_held_pairs_only(stacked):
+    """The megablox path under a share (interpreted here): pairs routed to
+    experts held elsewhere belong to no group; the result is the dense
+    path's, which gives them no column."""
+    from vllm_tpu.layers.moe import _dense_moe, _grouped_moe
+
+    rng = np.random.default_rng(3)
+    t, d, f, held, first, k = 40, 128, 128, 4, 8, 4
+    w = lambda *shape: jnp.asarray(rng.normal(size=shape) / 8, jnp.float32)
+    hidden, weights = w(t, d) * 8, jnp.abs(w(t, k))
+    ids = jnp.asarray(rng.integers(0, 16, (t, k)), jnp.int32)
+    wg, wu, wd = w(3, held, d, f), w(3, held, d, f), w(3, held, f, d)
+    want = _dense_moe(hidden, wg[1], wu[1], wd[1], weights, ids, first_expert=first)
+    if stacked:
+        flat = lambda x: x.reshape(3 * held, *x.shape[2:])
+        got = _grouped_moe(hidden, flat(wg), flat(wu), flat(wd), weights,
+                           ids - first + held, interpret=True, held=(held, held))
+    else:
+        got = _grouped_moe(hidden, wg[1], wu[1], wd[1], weights, ids - first,
+                           interpret=True, held=(0, held))
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
+
+
+def test_the_runner_counts_state_rows_tokens_by_form_and_latent_context(tmp_path):
+    from vllm_tpu import LLM, SamplingParams
+
+    llm = LLM(model=model_dir(tmp_path, **share(1)), load_format="dummy", seed=5,
+              dtype="float32", max_model_len=128, block_size=16,
+              num_gpu_blocks_override=64, max_num_seqs=8, max_num_batched_tokens=32)
+    prompts = [list(range(3, 3 + n)) for n in (40, 7)]
+    llm.generate([{"prompt_token_ids": p} for p in prompts],
+                 SamplingParams(temperature=0.0, max_tokens=6, ignore_eos=True))
+    runner = llm.llm_engine.engine_core.engine_core.executor.worker.runner
+    kda_layers, generated = 7, 2 * 6
+    tokens = runner.linear_attn_tokens_by_kind
+    # Every token a step held went through one of the two forms (the
+    # scheduler runs a step ahead of the answers, so a few more than the
+    # 47 prompt tokens and the 10 answers fed back).
+    fed = sum(runner.step_tokens_by_kind.values())
+    assert 47 + generated - 2 <= fed <= 47 + generated + 4
+    assert tokens["chunk"] + tokens["decode"] == fed * kda_layers
+    assert tokens["decode"] >= (generated - 2) * kda_layers
+    assert tokens["chunk"] >= 45 * kda_layers
+    steps = sum(runner.steps_by_kind.values())
+    assert steps * kda_layers <= runner.linear_attn_state_rows <= 2 * steps * kda_layers
+    assert runner.attn_context_tokens_by_kind["latent"] == sum(
+        runner.attn_context_tokens_by_kind[k] for k in ("decode", "mixed"))
+    # A share counts the pairs routed HERE: about a quarter of them all.
+    every = fed * 4 * 8
+    assert 0.1 * every < runner.moe_assignments < 0.45 * every
+    assert 0 < runner.moe_experts_hit <= steps * 8 * 4
+    # A finished request's slot comes back with the next step's notice.
+    assert set(runner._state_slot_of.values()) <= {0, 1}
+    llm.shutdown()
+
+
+def test_slots_that_leave_no_page_fail_with_the_numbers():
+    """``determine_num_kv_blocks`` with a budget the slots alone exceed."""
+    from types import SimpleNamespace
+
+    from vllm_tpu.worker.worker import Worker
+
+    model = _model(TINY_KIMI)
+    per_slot = model.state_bytes_per_slot()
+    assert per_slot == 7 * (2 * 16 * 16 * 4 + 3 * 96 * 4)
+    assert model.fixed_state_bytes(8) == 9 * per_slot
+    worker = object.__new__(Worker)
+    worker.model, worker.runner, worker.draft_model = model, None, None
+    worker.device = SimpleNamespace(
+        device_kind="fake", memory_stats=lambda: {"bytes_limit": 220_000, "bytes_in_use": 0})
+    worker.config = SimpleNamespace(
+        cache_config=SimpleNamespace(num_gpu_blocks_override=None, cache_dtype="auto",
+                                     block_size=16, gpu_memory_utilization=0.9),
+        parallel_config=SimpleNamespace(context_parallel_size=1, enable_eplb=False),
+        model_config=SimpleNamespace(jax_dtype=jnp.float32),
+        scheduler_config=SimpleNamespace(max_num_seqs=8),
+    )
+    with pytest.raises(RuntimeError, match=r"per-request state \d+ bytes for 8 slots"):
+        worker.determine_num_kv_blocks()
+    worker.device = SimpleNamespace(
+        device_kind="fake", memory_stats=lambda: {"bytes_limit": 3_000_000, "bytes_in_use": 0})
+    assert worker.determine_num_kv_blocks() > 8

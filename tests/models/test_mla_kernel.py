@@ -36,7 +36,7 @@ def _build_case(rng, seqs, h, dl, value_dim, page_size, pages_per_seq):
     t = sum(q for q, _ in seqs)
     num_pages = 1 + n * pages_per_seq
     lat_pages = rng.standard_normal(
-        (1, num_pages, page_size, 1, dl)
+        (1, num_pages, page_size, dl)
     ).astype(np.float32)
     q = rng.standard_normal((t, h, dl)).astype(np.float32) * 0.5
     kv_lens = np.zeros(n, np.int32)
@@ -49,7 +49,7 @@ def _build_case(rng, seqs, h, dl, value_dim, page_size, pages_per_seq):
         n_pages = -(-kv_len // page_size)
         pids = 1 + s * pages_per_seq + np.arange(n_pages)
         page_indices[s, :n_pages] = pids
-        rows = lat_pages[0, pids, :, 0, :].reshape(-1, dl)[:kv_len]
+        rows = lat_pages[0, pids].reshape(-1, dl)[:kv_len]
         cu[s + 1] = cu[s] + q_len
         want[cu[s]:cu[s + 1]] = _dense_reference(
             q[cu[s]:cu[s + 1]], rows, kv_len, q_len, scale, value_dim

@@ -202,9 +202,12 @@ def test_tracing_the_ragged_kernel_once_is_cheap(one_chip):
      (512, 151936, False, False, True, True, True),
      (512, 151936, True, True, True, True, True),
      # mistral7b.chat's decode step with its live rows counted.
-     (128, 32768, False, False, True, False, True)],
+     (128, 32768, False, False, True, False, True),
+     # kimilinear48b.long-answers: all 192 slots' rows at a vocabulary of
+     # 163840 (40 noise tiles), its live rows counted.
+     (192, 163840, False, False, True, False, True)],
     ids=["plain", "top_k_top_p", "penalties", "wide_prob_active",
-         "wide_prob_active_penalties", "narrow_active"],
+         "wide_prob_active_penalties", "narrow_active", "widest_active"],
 )
 def test_sampler_kernel_compiles(
     one_chip, r, v, penalties, top_k, top_p, return_prob, active
@@ -224,6 +227,106 @@ def test_sampler_kernel_compiles(
         s((r, 128), jnp.int32), s(state, jnp.int32), s(state, jnp.int8),
         *([s((), jnp.int32)] if active else []),
     ).compile()
+
+
+# kimilinear48b.long-answers: 32 heads of 128 over 193 slots of 10 layers,
+# latent rows of 512 + 64 in 640 lanes over 3 layers, 192 rows a step.
+KDA_H, KDA_D, KDA_SLOTS, KDA_LAYERS = 32, 128, 193, 10
+KDA_STATE_BYTES = KDA_LAYERS * KDA_SLOTS * KDA_H * KDA_D * KDA_D * 4
+
+
+def _kda_shapes(sharding, t, r):
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return dict(
+        qkva=[s((t, KDA_H, KDA_D))] * 4, beta=s((t, KDA_H)),
+        state=s((KDA_LAYERS, KDA_SLOTS, KDA_H, KDA_D, KDA_D)),
+        slots=s((r,), jnp.int32), fresh=s((r,), jnp.bool_), n=s((), jnp.int32),
+        qsl=s((r + 1,), jnp.int32),
+    )
+
+
+def test_kda_one_token_kernel_compiles_and_updates_the_slots_in_place(one_chip):
+    """The Pallas kernel of ``ops/kda.py`` at the cell's widths: a row's 2
+    MiB of state a grid step, double-buffered in and out, inside the
+    scoped VMEM it asks for; the 4 GB state stack aliased to the output."""
+    from vllm_tpu.ops import kda
+
+    sh = _kda_shapes(one_chip, 192, 192)
+
+    def fn(q, k, v, a, beta, state, slots, fresh, n):
+        return kda._decode_pallas(q, k, v, a, beta, state, jnp.int32(3), slots, fresh, n)
+
+    compiled = jax.jit(fn, donate_argnums=(5,)).lower(
+        *sh["qkva"], sh["beta"], sh["state"], sh["slots"], sh["fresh"], sh["n"]).compile()
+    assert "kda_decode_kernel" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= KDA_STATE_BYTES and mem.temp_size_in_bytes < 64 << 20
+
+
+def test_kda_chunked_form_compiles_with_the_state_in_place(one_chip):
+    """The chunked form at a 512-token step: XLA matmuls in a scan in a
+    ``while_loop`` of rounds, the state stack carried through both without
+    a copy (its temporaries are the chunks' operands, not the 4 GB)."""
+    from vllm_tpu.ops import kda
+
+    sh = _kda_shapes(one_chip, 512, 192)
+
+    def fn(q, k, v, a, beta, state, slots, fresh, qsl, n):
+        return kda.kda_chunks(q, k, v, a, beta, state, jnp.int32(3), slots, fresh, qsl, n)
+
+    mem = jax.jit(fn, donate_argnums=(5,)).lower(
+        *sh["qkva"], sh["beta"], sh["state"], sh["slots"], sh["fresh"], sh["qsl"],
+        sh["n"]).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= KDA_STATE_BYTES and mem.temp_size_in_bytes < 512 << 20
+
+
+def test_slot_rows_are_read_and_written_without_a_loop_a_row(one_chip):
+    """The convolution's tails, 192 rows of 3 x 12288 numbers into 193
+    slots of one of 10 layers: XLA's scatter of such rows is a loop of 192
+    iterations on the TPU (1.4 ms a layer on the v5e); the one-hot form is
+    a matmul and a select, with the stack updated in place."""
+    from vllm_tpu.ops import kda
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    width = 3 * 3 * KDA_H * KDA_D
+
+    def fn(stack, layer, slots, rows, n):
+        tails = kda.read_slot_rows(stack, layer, slots)
+        return tails, kda.write_slot_rows(stack, layer, slots, rows, n)
+
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        s((KDA_LAYERS, KDA_SLOTS, width), jnp.bfloat16), s((), jnp.int32),
+        s((192,), jnp.int32), s((192, width), jnp.bfloat16), s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert " while(" not in text and " scatter(" not in text and " gather(" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= KDA_LAYERS * KDA_SLOTS * width * 2
+
+
+@pytest.mark.parametrize("t,r,pages", [(512, 192, 768), (192, 192, 256)],
+                         ids=["mixed_T512_P768", "decode_T192_P256"])
+def test_latent_attention_kernel_compiles(one_chip, t, r, pages):
+    """``ops/mla_kernel.py`` at 32 heads over latent rows of 576 numbers in
+    640 lanes, tokens on a page's sublanes: the layout with an axis of one
+    head was refused here (a page copy cannot slice inside a tile), and a
+    row of 576 lanes likewise. 192 rows x 768 pages of page table in SMEM."""
+    from vllm_tpu.ops.mla_attention import mla_kv_cache_shape
+    from vllm_tpu.ops.mla_kernel import mla_ragged_paged_attention
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = mla_kv_cache_shape(3, 30000, PAGE, 512 + 64)
+    assert cache == (3, 30000, PAGE, 640)
+    fn = functools.partial(mla_ragged_paged_attention, sm_scale=192 ** -0.5, value_dim=512)
+    text = jax.jit(fn).lower(
+        s((t, 32, 640), jnp.bfloat16), s(cache, jnp.bfloat16), s((1,), jnp.int32),
+        s((r,), jnp.int32), s((r, pages), jnp.int32), s((r + 1,), jnp.int32),
+        s((1,), jnp.int32)).compile().as_text()
+    assert "mla_kernel" in text
 
 
 @pytest.mark.parametrize("decode_only", [True, False],

@@ -487,6 +487,9 @@ class CompilationConfig:
     # compile time/cache pressure. The default admits the full pow2
     # ladders at 8k tokens x 512 reqs.
     max_step_compilations: int = 128
+    # Set by finalize: one explicit token bucket and one explicit request
+    # bucket (see page_buckets).
+    one_step_shape: bool = False
 
     @staticmethod
     def _pow2_buckets(lo: int, hi: int) -> list[int]:
@@ -504,9 +507,21 @@ class CompilationConfig:
             return buckets
         return buckets[:-1:2] + [buckets[-1]]
 
+    def page_buckets(self, max_blocks_per_req: int) -> list[int]:
+        """Pages-per-request buckets of the block table: powers of two
+        from 16 up to the pages of max_model_len. A server whose explicit
+        ladders pin the step to ONE shape of tokens and rows gets the
+        whole table alone, so one program of each kind: the kernels read
+        a row's pages by its length, not by the table's width."""
+        if self.one_step_shape:
+            return [max_blocks_per_req]
+        return self._pow2_buckets(min(16, max_blocks_per_req), max_blocks_per_req)
+
     def finalize(self, sched: SchedulerConfig) -> None:
         explicit_t = bool(self.token_buckets)
         explicit_r = bool(self.request_buckets)
+        self.one_step_shape = (
+            len(self.token_buckets) == 1 and len(self.request_buckets) == 1)
         for name, given, most in (
             ("token_buckets", self.token_buckets, sched.max_num_batched_tokens),
             ("request_buckets", self.request_buckets, sched.max_num_seqs),

@@ -233,9 +233,11 @@ class MLAAttentionSpec(KVCacheSpec):
 
     @property
     def page_size_bytes(self) -> int:
+        # The cache pads a row to whole 128-lane tiles on every backend
+        # (``ops/mla_attention.py::mla_kv_cache_shape``).
         return (
             self.block_size * self.num_kv_heads
-            * _lane_padded(self.head_size) * self.dtype_bytes
+            * (-(-self.head_size // 128) * 128) * self.dtype_bytes
         )
 
 

@@ -272,6 +272,12 @@ class SchedulerStats:
     diffusion_committed_tokens: int = 0
     moe_assignments: int = 0
     moe_experts_hit: int = 0
+    # Linear attention over state slots (cumulative, from the runner, a
+    # layer of that kind): rows whose state a step read and wrote, tokens
+    # by the form that took them; and the slots held now.
+    linear_attn_state_rows: int = 0
+    linear_attn_tokens_by_kind: dict[str, int] = field(default_factory=dict)
+    state_slots_in_use: int = 0
     prep_fallback_rows: int = 0
     # Sampling-epilogue routing: in-jit sample() calls routed to the
     # fused sort-free kernel vs sampling rows that fell back to the XLA

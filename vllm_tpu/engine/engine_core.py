@@ -433,8 +433,12 @@ class EngineCore:
             stats.diffusion_passes_by_kind = dict(
                 getattr(runner, "diffusion_passes_by_kind", {}))
             for name in ("diffusion_committed_tokens", "moe_assignments",
-                         "moe_experts_hit"):
+                         "moe_experts_hit", "linear_attn_state_rows"):
                 setattr(stats, name, getattr(runner, name, 0))
+            stats.linear_attn_tokens_by_kind = dict(
+                getattr(runner, "linear_attn_tokens_by_kind", {}))
+            stats.state_slots_in_use = len(
+                getattr(runner, "_state_slot_of", ()))
             stats.step_first_use_s = getattr(runner, "first_use_s", 0.0)
             stats.prep_fallback_rows = getattr(
                 runner, "prep_fallback_rows", 0

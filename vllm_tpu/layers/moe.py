@@ -50,6 +50,7 @@ def _dense_moe(
     expert_ids: jnp.ndarray,  # [T, k]
     act_fn=None,
     biases=None,  # (b_gate [E,F], b_up [E,F], b_down [E,D]) or None
+    first_expert: int = 0,
 ) -> jnp.ndarray:
     """One-hot dispatch: every expert sees every token, masked combine.
     FLOP-wasteful on one chip but exactly what GSPMD wants for EP: with
@@ -58,7 +59,11 @@ def _dense_moe(
     e = w_gate.shape[0]
     x = hidden.astype(w_gate.dtype)
     # [T, E] combine weights (0 for non-selected experts).
-    onehot = jax.nn.one_hot(expert_ids, e, dtype=hidden.dtype)  # [T, k, E]
+    # An id outside the held range [first_expert, first_expert + E) has
+    # no column: its pair adds nothing.
+    onehot = jax.nn.one_hot(
+        expert_ids - first_expert, e, dtype=hidden.dtype
+    )  # [T, k, E]
     combine = jnp.einsum("tk,tke->te", weights.astype(hidden.dtype), onehot)
 
     gate = jnp.einsum("td,edf->etf", x, w_gate)
@@ -84,26 +89,41 @@ def _grouped_moe(
     interpret: bool = False,
     act_fn=None,
     biases=None,  # (b_gate [E,F], b_up [E,F], b_down [E,D]) or None
+    held: tuple[int, int] | None = None,
 ) -> jnp.ndarray:
-    """Sort-by-expert + megablox grouped matmul (single-device fast path)."""
+    """Sort-by-expert + megablox grouped matmul (single-device fast path).
+
+    ``held = (first, count)``: of the groups ``w_*`` stack, only ``count``
+    from ``first`` on can be routed to here (an expert-parallel share,
+    ``expert_ids`` already moved into the stack's numbering); a pair whose
+    id lies outside sorts behind every held one and belongs to no group,
+    so the kernel never visits its row and its part of the sum is 0."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     t, d = hidden.shape
     e = w_gate.shape[0]
     k = expert_ids.shape[1]
     flat_experts = expert_ids.reshape(-1)  # [T*k]
+    if held is not None:
+        first, count = held
+        mine = (flat_experts >= first) & (flat_experts < first + count)
+        flat_experts = jnp.where(mine, flat_experts, e)
     order = jnp.argsort(flat_experts)  # stable
     token_idx = order // k  # source token of each sorted slot
     x_sorted = hidden[token_idx]  # [T*k, D]
-    group_sizes = jnp.bincount(flat_experts, length=e).astype(jnp.int32)
+    group_sizes = jnp.bincount(flat_experts, length=e + 1)[:e].astype(
+        jnp.int32
+    )
 
     # gmm tiles rows by 128: pad the row dim, book the pad rows on the last
     # group (their garbage output is dropped by the unsort gather below).
+    # Under a share nothing is booked: rows past the groups are not run.
     m = t * k
     m_pad = -(-m // 128) * 128
     if m_pad != m:
         x_sorted = jnp.pad(x_sorted, ((0, m_pad - m), (0, 0)))
-        group_sizes = group_sizes.at[e - 1].add(m_pad - m)
+        if held is None:
+            group_sizes = group_sizes.at[e - 1].add(m_pad - m)
 
     def mm(lhs, rhs, sizes):
         return gmm(
@@ -116,7 +136,7 @@ def _grouped_moe(
     if biases is not None:
         # Per-row expert ids of the SORTED layout (pad rows were booked
         # on the last group; their biased garbage is dropped at unsort).
-        sorted_e = flat_experts[order]
+        sorted_e = jnp.minimum(flat_experts[order], e - 1)
         if m_pad != m:
             sorted_e = jnp.concatenate(
                 [sorted_e, jnp.full(m_pad - m, e - 1, sorted_e.dtype)]
@@ -129,6 +149,10 @@ def _grouped_moe(
     out_sorted = mm(act, w_down, group_sizes).astype(jnp.float32)  # [M, D]
     if biases is not None:
         out_sorted = out_sorted + biases[2][sorted_e].astype(jnp.float32)
+    if held is not None:
+        # Rows the kernel did not run hold whatever the buffer held.
+        ran = jnp.arange(m_pad) < jnp.sum(group_sizes)
+        out_sorted = jnp.where(ran[:, None], out_sorted, 0.0)
 
     # Unsort and combine with routing weights.
     inv = jnp.argsort(order)
@@ -371,6 +395,7 @@ def fused_experts(
     ep_axis: str | None = None,
     act_fn=None,
     biases=None,
+    first_expert: int | None = None,
 ) -> jnp.ndarray:
     """Experts + combine for pre-computed routing (custom gating schemes —
     DeepSeek group-limited / sigmoid-bias routing — share the expert
@@ -378,7 +403,19 @@ def fused_experts(
     single-device TPU, dense one-hot otherwise. With ``ep_mesh``/``ep_axis``
     set (and axis size > 1) the ragged all_to_all expert-parallel path is
     taken instead. ``act_fn(gate, up)`` overrides the silu GLU and
-    ``biases`` adds per-expert (gate, up, down) biases (GPT-OSS)."""
+    ``biases`` adds per-expert (gate, up, down) biases (GPT-OSS).
+
+    ``first_expert``: this layer HOLDS the experts ``[first_expert,
+    first_expert + E)`` of a router that is wider than ``E`` (one chip's
+    share of an expert-parallel deployment, without its exchange). The
+    ids stay the router's; pairs routed to experts held elsewhere are
+    dropped before the expert compute and add nothing to the result,
+    which is this share's part of the layer's sum."""
+    if first_expert is not None and ep_mesh is not None:
+        raise NotImplementedError(
+            "an expert share is one device's; a mesh shards the experts "
+            "itself (ep_moe)"
+        )
     if ep_mesh is not None and ep_axis and ep_mesh.shape[ep_axis] > 1:
         if act_fn is not None or biases is not None:
             raise NotImplementedError(
@@ -398,13 +435,16 @@ def fused_experts(
     if use_grouped is None:
         use_grouped = _grouped_is_the_path()
     if use_grouped:
+        share = first_expert is not None
         return _grouped_moe(
-            hidden, w_gate, w_up, w_down, weights, expert_ids,
+            hidden, w_gate, w_up, w_down, weights,
+            expert_ids - first_expert if share else expert_ids,
             act_fn=act_fn, biases=biases,
+            held=(0, w_gate.shape[0]) if share else None,
         )
     return _dense_moe(
         hidden, w_gate, w_up, w_down, weights, expert_ids,
-        act_fn=act_fn, biases=biases,
+        act_fn=act_fn, biases=biases, first_expert=first_expert or 0,
     )
 
 
@@ -425,6 +465,7 @@ def fused_experts_of_layer(
     *,
     ep_mesh=None,
     ep_axis: str | None = None,
+    first_expert: int | None = None,
 ) -> jnp.ndarray:
     """``fused_experts`` for a layer scan that holds its expert weights as
     ``[L, E, ...]`` stacks. On the grouped (megablox) path the stacks go to
@@ -442,14 +483,19 @@ def fused_experts_of_layer(
         return fused_experts(
             hidden, w_gate[layer], w_up[layer], w_down[layer], weights,
             expert_ids, ep_mesh=ep_mesh, ep_axis=ep_axis,
+            first_expert=first_expert,
         )
     n_layers, e = w_gate.shape[:2]
+    share = first_expert is not None
     return _grouped_moe(
         hidden,
         w_gate.reshape(n_layers * e, *w_gate.shape[2:]),
         w_up.reshape(n_layers * e, *w_up.shape[2:]),
         w_down.reshape(n_layers * e, *w_down.shape[2:]),
-        weights, expert_ids + layer * e,
+        weights, expert_ids - (first_expert or 0) + layer * e,
+        # Ids below the share's first fall into the layer before: the
+        # held range keeps them out.
+        held=(layer * e, e) if share else None,
     )
 
 

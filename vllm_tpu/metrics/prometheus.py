@@ -338,6 +338,18 @@ class PrometheusRegistry:
             "vllm:moe_experts_hit_total",
             "Experts that got at least one live token, counted per layer "
             "and step and summed")
+        self.linear_attn_state_rows = Counter(
+            "vllm:linear_attn_state_rows_total",
+            "Rows whose linear-attention state a dispatched step reads "
+            "and writes, summed over the layers of that kind")
+        self.linear_attn_tokens = LabeledCounter(
+            "vllm:linear_attn_tokens_total",
+            "Tokens through the linear-attention layers, summed over "
+            "them: decode (a row's one token) or chunk (a row of "
+            "several)", "kind")
+        self.state_slots_in_use = Gauge(
+            "vllm:state_slots_in_use",
+            "Per-request state slots held by the runner's rows")
         self.pipeline_stall = Counter(
             "vllm:pipeline_stall_seconds",
             "Seconds the async lag-N pipeline blocked on device results")
@@ -721,6 +733,8 @@ class PrometheusRegistry:
             self.pipeline_stall,
             self.diffusion_passes, self.diffusion_committed,
             self.moe_assignments, self.moe_experts_hit,
+            self.linear_attn_state_rows, self.linear_attn_tokens,
+            self.state_slots_in_use,
             self.decode_batch_ratio, self.tokens_per_launch,
             self.prep_fallback_rows,
             self.sampler_kernel_launches, self.sampler_fallback_rows,
@@ -840,6 +854,7 @@ class PrometheusRegistry:
                 (self.attn_context_tokens, s.attn_context_tokens_by_kind),
                 (self.attn_ragged_pairs, s.attn_ragged_pairs),
                 (self.diffusion_passes, s.diffusion_passes_by_kind),
+                (self.linear_attn_tokens, s.linear_attn_tokens_by_kind),
             ):
                 for kind, n in by_kind.items():
                     counter.inc_to(kind, float(n))
@@ -847,6 +862,9 @@ class PrometheusRegistry:
                 float(s.diffusion_committed_tokens))
             self.moe_assignments.inc_to(float(s.moe_assignments))
             self.moe_experts_hit.inc_to(float(s.moe_experts_hit))
+            self.linear_attn_state_rows.inc_to(
+                float(s.linear_attn_state_rows))
+            self.state_slots_in_use.set(s.state_slots_in_use)
             self.batch_tokens.set(s.batch_num_tokens)
             self.batch_requests.set(s.batch_num_reqs)
             self.batch_occupancy.set(s.batch_occupancy)

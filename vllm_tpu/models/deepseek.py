@@ -66,6 +66,102 @@ def _rope_interleaved(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp
     return jnp.stack([o1, o2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
+def select_experts_grouped(
+    logits: jnp.ndarray,  # [T, E] f32
+    bias: jnp.ndarray | None,  # [E] selection bias (sigmoid routing)
+    *,
+    top_k: int,
+    n_group: int = 1,
+    topk_group: int = 1,
+    sigmoid: bool = False,
+    method: str = "greedy",
+    renormalize: bool = False,
+    scaling: float = 1.0,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """DeepSeek routing (HF DeepseekV2MoEGate / DeepseekV3TopkRouter
+    semantics). Returns (weights [T, k] f32, ids [T, k] i32)."""
+    t, e = logits.shape
+    g, k = n_group, top_k
+    if sigmoid:
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + bias[None, :] if bias is not None else scores
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        choice = scores
+    if method in ("group_limited_greedy", "noaux_tc") and g > 1:
+        grouped = choice.reshape(t, g, e // g)
+        if method == "noaux_tc":
+            top2, _ = jax.lax.top_k(grouped, 2)
+            group_scores = top2.sum(axis=-1)  # [T, G]
+        else:
+            group_scores = grouped.max(axis=-1)
+        _, group_idx = jax.lax.top_k(group_scores, topk_group)
+        group_mask = (
+            jax.nn.one_hot(group_idx, g, dtype=jnp.float32).sum(axis=1) > 0
+        )  # [T, G]
+        mask = jnp.repeat(group_mask, e // g, axis=-1)
+        choice = jnp.where(mask, choice, 0.0)
+    _, ids = jax.lax.top_k(choice, k)
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
+    if renormalize:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return weights * scaling, ids.astype(jnp.int32)
+
+
+def latent_attention(
+    h: jnp.ndarray,  # [T, D] normed layer input
+    lp: dict,  # wq | wq_a/q_a_norm/wq_b, wkv_a, kv_a_norm, wkv_b, wo
+    kv: jnp.ndarray,  # the paged latent cache
+    li: jnp.ndarray,  # this layer's index in it
+    md: AttentionMetadata,
+    *,
+    num_heads: int,
+    nope_dim: int,
+    rope_dim: int,
+    v_dim: int,
+    latent_rank: int,
+    rms_eps: float,
+    scale: float,
+    cos_sin: tuple[jnp.ndarray, jnp.ndarray] | None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Absorbed latent attention over the paged latent cache: ``(out [T,
+    D], kv)``. ``cos_sin`` None carries the rope lanes of the query and
+    of the latent row WITHOUT rotation (``mla_use_nope``: position comes
+    from elsewhere in the model)."""
+    t = h.shape[0]
+    H, DN, DR, DV, DC = num_heads, nope_dim, rope_dim, v_dim, latent_rank
+    if "wq" in lp:
+        q = h @ lp["wq"]
+    else:
+        q = rms_norm(h @ lp["wq_a"], lp["q_a_norm"], rms_eps) @ lp["wq_b"]
+    q = q.reshape(t, H, DN + DR)
+    q_nope, q_pe = q[..., :DN], q[..., DN:]
+    kv_a = h @ lp["wkv_a"]  # [T, DC+DR]
+    c_kv = rms_norm(kv_a[:, :DC], lp["kv_a_norm"], rms_eps)
+    k_pe = kv_a[:, DC:]
+    if cos_sin is not None:
+        q_pe = _rope_interleaved(q_pe, *cos_sin)
+        k_pe = _rope_interleaved(k_pe[:, None], *cos_sin)[:, 0]
+
+    # Absorb W_uk: queries into latent space.
+    w_uk = lp["wkv_b"].reshape(DC, H, DN + DV)[..., :DN]
+    w_uv = lp["wkv_b"].reshape(DC, H, DN + DV)[..., DN:]
+    q_lat = jnp.einsum("thn,chn->thc", q_nope, w_uk)
+    q_abs = jnp.concatenate(
+        [q_lat, q_pe.astype(q_lat.dtype)], axis=-1
+    )  # [T, H, DC+DR]
+    latent = jnp.concatenate(
+        [c_kv, k_pe.astype(c_kv.dtype)], axis=-1
+    )  # [T, DC+DR]
+    kv = write_latent(kv, li, latent, md.slot_mapping)
+    with jax.named_scope("mla_attn"):
+        ctx = mla_paged_attention(
+            q_abs, kv, li, md, scale, value_dim=DC
+        )  # [T, H, DC]
+    out = jnp.einsum("thc,chv->thv", ctx, w_uv)  # absorbed W_uv
+    return out.reshape(t, H * DV) @ lp["wo"], kv
+
+
 class DeepseekV2ForCausalLM:
     """DeepSeek-V2 / V2-Lite (softmax routing); V3 subclasses the gate."""
 
@@ -294,45 +390,22 @@ class DeepseekV2ForCausalLM:
     def _select_experts(
         self, logits: jnp.ndarray, bias: jnp.ndarray | None
     ) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """DeepSeek routing (HF DeepseekV2MoEGate / DeepseekV3TopkRouter
-        semantics). Returns (weights [T, k] f32, ids [T, k] i32)."""
-        t, e = logits.shape
-        g, k = self.n_group, self.top_k
-        if self.sigmoid_routing:
-            scores = jax.nn.sigmoid(logits)
-            choice = scores + bias[None, :]
-        else:
-            scores = jax.nn.softmax(logits, axis=-1)
-            choice = scores
-        if self.topk_method in ("group_limited_greedy", "noaux_tc") and g > 1:
-            grouped = choice.reshape(t, g, e // g)
-            if self.topk_method == "noaux_tc":
-                top2, _ = jax.lax.top_k(grouped, 2)
-                group_scores = top2.sum(axis=-1)  # [T, G]
-            else:
-                group_scores = grouped.max(axis=-1)
-            _, group_idx = jax.lax.top_k(group_scores, self.topk_group)
-            group_mask = (
-                jax.nn.one_hot(group_idx, g, dtype=jnp.float32).sum(axis=1) > 0
-            )  # [T, G]
-            mask = jnp.repeat(group_mask, e // g, axis=-1)
-            choice = jnp.where(mask, choice, 0.0)
-        _, ids = jax.lax.top_k(choice, k)
-        weights = jnp.take_along_axis(scores, ids, axis=-1)
-        if self.norm_topk_prob:
-            weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
-        return weights * self.routed_scaling, ids.astype(jnp.int32)
+        return select_experts_grouped(
+            logits, bias, top_k=self.top_k, n_group=self.n_group,
+            topk_group=self.topk_group, sigmoid=self.sigmoid_routing,
+            method=self.topk_method, renormalize=self.norm_topk_prob,
+            scaling=self.routed_scaling,
+        )
 
     def apply(
         self,
         params: dict,
-        kv_cache: jnp.ndarray,  # [L, NB, BS, 1, DC+DR]
+        kv_cache: jnp.ndarray,  # [L, NB, BS, DC+DR]
         input_ids: jnp.ndarray,  # [T]
         md: AttentionMetadata,
         token_lora_slot: jnp.ndarray | None = None,  # unused
     ) -> tuple[jnp.ndarray, jnp.ndarray]:
         x = params["embed"][input_ids].astype(self.dtype)
-        t = x.shape[0]
         H = self.num_heads
         DN, DR, DV = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
         DC = self.kv_lora_rank
@@ -342,36 +415,12 @@ class DeepseekV2ForCausalLM:
 
         def attention(lp, x, kv, li):
             h = rms_norm(x, lp["input_norm"], self.rms_eps)
-            if self.q_lora_rank is None:
-                q = h @ lp["wq"]
-            else:
-                q = rms_norm(h @ lp["wq_a"], lp["q_a_norm"], self.rms_eps)
-                q = q @ lp["wq_b"]
-            q = q.reshape(t, H, self.qk_head_dim)
-            q_nope, q_pe = q[..., :DN], q[..., DN:]
-            q_pe = _rope_interleaved(q_pe, cos, sin)
-
-            kv_a = h @ lp["wkv_a"]  # [T, DC+DR]
-            c_kv = rms_norm(kv_a[:, :DC], lp["kv_a_norm"], self.rms_eps)
-            k_pe = _rope_interleaved(kv_a[:, None, DC:], cos, sin)[:, 0]
-
-            # Absorb W_uk: queries into latent space.
-            w_uk = lp["wkv_b"].reshape(DC, H, DN + DV)[..., :DN]
-            w_uv = lp["wkv_b"].reshape(DC, H, DN + DV)[..., DN:]
-            q_lat = jnp.einsum("thn,chn->thc", q_nope, w_uk)
-            q_abs = jnp.concatenate(
-                [q_lat, q_pe.astype(q_lat.dtype)], axis=-1
-            )  # [T, H, DC+DR]
-
-            latent = jnp.concatenate(
-                [c_kv, k_pe.astype(c_kv.dtype)], axis=-1
-            )  # [T, DC+DR]
-            kv = write_latent(kv, li, latent, md.slot_mapping)
-            ctx = mla_paged_attention(
-                q_abs, kv, li, md, self.scale, value_dim=DC
-            )  # [T, H, DC]
-            out = jnp.einsum("thc,chv->thv", ctx, w_uv)  # absorbed W_uv
-            return x + out.reshape(t, H * DV) @ lp["wo"], kv
+            out, kv = latent_attention(
+                h, lp, kv, li, md, num_heads=H, nope_dim=DN, rope_dim=DR,
+                v_dim=DV, latent_rank=DC, rms_eps=self.rms_eps,
+                scale=self.scale, cos_sin=(cos, sin),
+            )
+            return x + out, kv
 
         def dense_layer(carry, inputs):
             x, kv = carry
@@ -430,7 +479,7 @@ class DeepseekV2ForCausalLM:
 
     def kv_cache_shape(
         self, num_blocks: int, block_size: int
-    ) -> tuple[int, int, int, int, int]:
+    ) -> tuple[int, int, int, int]:
         return mla_kv_cache_shape(
             self.num_layers, num_blocks, block_size, self.latent_dim
         )
@@ -510,7 +559,7 @@ class DeepseekV2ForCausalLM:
 
     def kv_cache_sharding(self, model_axis: str = "tp") -> P:
         """Latent rows are shared by every head: replicate over TP."""
-        return P(None, None, None, None, None)
+        return P(None, None, None, None)
 
 
 class DeepseekV3ForCausalLM(DeepseekV2ForCausalLM):

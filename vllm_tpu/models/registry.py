@@ -35,6 +35,7 @@ _REGISTRY: dict[str, tuple[str, str]] = {
     "BambaForCausalLM": ("vllm_tpu.models.bamba", "BambaForCausalLM"),
     "JambaForCausalLM": ("vllm_tpu.models.jamba", "JambaForCausalLM"),
     "Qwen3NextForCausalLM": ("vllm_tpu.models.qwen3_next", "Qwen3NextForCausalLM"),
+    "KimiLinearForCausalLM": ("vllm_tpu.models.kimi_linear", "KimiLinearForCausalLM"),
     "Phi3ForCausalLM": ("vllm_tpu.models.phi3", "Phi3ForCausalLM"),
     "GraniteForCausalLM": ("vllm_tpu.models.granite", "GraniteForCausalLM"),
     "Olmo2ForCausalLM": ("vllm_tpu.models.olmo2", "Olmo2ForCausalLM"),

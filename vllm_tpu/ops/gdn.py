@@ -35,7 +35,7 @@ def ragged_gated_delta_rule(
     q: jnp.ndarray,  # [T, Hv, Dk] (already repeated to v-heads)
     k: jnp.ndarray,  # [T, Hv, Dk]
     v: jnp.ndarray,  # [T, Hv, Dv]
-    g: jnp.ndarray,  # [T, Hv] log-decay (<= 0)
+    g: jnp.ndarray,  # [T, Hv] log-decay (<= 0), or [T, Hv, Dk] a key channel
     beta: jnp.ndarray,  # [T, Hv] in (0, 1)
     h0: jnp.ndarray,  # [R, Hv, Dk, Dv] cached state per request
     token_req_idx: jnp.ndarray,  # [T]
@@ -66,7 +66,7 @@ def ragged_gated_delta_rule(
         s, states = carry  # s [Hv, Dk, Dv], states [R, Hv, Dk, Dv]
         q_t, k_t, v_t, g_t, b_t, first, last, rid = inp
         s = jnp.where(first, h0f[rid], s)
-        s = s * jnp.exp(g_t)[:, None, None]
+        s = s * jnp.exp(g_t).reshape(hv, -1, 1)
         mem = jnp.einsum("hk,hkv->hv", k_t, s)
         delta = (v_t - mem) * b_t[:, None]
         s = s + k_t[:, :, None] * delta[:, None, :]

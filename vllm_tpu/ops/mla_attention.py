@@ -37,30 +37,40 @@ from vllm_tpu.ops.attention import AttentionMetadata
 
 def mla_kv_cache_shape(
     num_layers: int, num_blocks: int, block_size: int, latent_dim: int
-) -> tuple[int, int, int, int, int]:
-    """[L, NB, BS, 1, latent] — one shared latent 'head', no K/V planes."""
-    return (num_layers, num_blocks, block_size, 1, latent_dim)
+) -> tuple[int, int, int, int]:
+    """[L, NB, BS, lanes]: one latent row a token, no K/V planes and no
+    head axis. A page is ``[BS, lanes]`` with the tokens on the sublanes
+    (an axis of one head there is padded to a whole sublane tile on the
+    TPU, in HBM too, and the kernel's page copy cannot slice it) and the
+    row padded with zeros to whole 128-lane tiles, which is what the
+    device would store anyway: 576 numbers lie in 640."""
+    return (num_layers, num_blocks, block_size, -(-latent_dim // 128) * 128)
+
+
+def _to_lanes(x: jnp.ndarray, lanes: int) -> jnp.ndarray:
+    pad = lanes - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
 
 def write_latent(
-    kv_cache: jnp.ndarray,  # [L, NB, BS, 1, DL]
+    kv_cache: jnp.ndarray,  # [L, NB, BS, DL]
     layer: jnp.ndarray,  # scalar i32
     latent: jnp.ndarray,  # [T, DL]  (c_kv || k_pe, rope already applied)
     slot_mapping: jnp.ndarray,  # [T]
 ) -> jnp.ndarray:
     """Scatter this step's latent rows into the paged slots (in place when
     the cache is a donated scan carry)."""
-    nl, nb, bs, one, dl = kv_cache.shape
-    flat = kv_cache.reshape(nl * nb * bs, one, dl)
+    nl, nb, bs, dl = kv_cache.shape
+    flat = kv_cache.reshape(nl * nb * bs, dl)
     flat = flat.at[layer * (nb * bs) + slot_mapping].set(
-        latent[:, None, :].astype(kv_cache.dtype)
+        _to_lanes(latent, dl).astype(kv_cache.dtype)
     )
-    return flat.reshape(nl, nb, bs, one, dl)
+    return flat.reshape(nl, nb, bs, dl)
 
 
 def mla_paged_attention(
     q_abs: jnp.ndarray,  # [T, H, DL] absorbed queries (q_lat || q_pe)
-    kv_cache: jnp.ndarray,  # [L, NB, BS, 1, DL]
+    kv_cache: jnp.ndarray,  # [L, NB, BS, DL]
     layer: jnp.ndarray,  # scalar i32
     md: AttentionMetadata,
     scale: float,
@@ -76,8 +86,9 @@ def mla_paged_attention(
     gather below is the reference path, which materializes ``[T, C, DL]``
     and only survives short contexts.
     """
-    t, h, dl = q_abs.shape
-    nl, nb, bs, _one, _dl = kv_cache.shape
+    nl, nb, bs, dl = kv_cache.shape
+    q_abs = _to_lanes(q_abs, dl)  # zeros against the rows' zero lanes
+    t, h, _ = q_abs.shape
 
     from vllm_tpu import envs
 
@@ -99,7 +110,7 @@ def mla_paged_attention(
             interpret=interpret,
         )
 
-    pages = kv_cache[layer, md.block_tables]  # [R, B, BS, 1, DL]
+    pages = kv_cache[layer, md.block_tables]  # [R, B, BS, DL]
     r, b = md.block_tables.shape
     ctx = b * bs
     lat_req = pages.reshape(r, ctx, dl)

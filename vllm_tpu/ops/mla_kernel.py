@@ -8,7 +8,8 @@ latent cache. Reference analog: ``csrc/attention/mla/`` decode kernels
 
 Differences from the general kernel, all forced by the MLA cache
 contract (``mla_attention.mla_kv_cache_shape``: one latent row
-``[c_kv (value_dim) || k_pe]`` per token — no per-head K/V planes):
+``[c_kv (value_dim) || k_pe]`` per token — no per-head K/V planes, a
+page ``[page_size, DL]``):
 
 - ONE shared "KV head" (MQA): no heads grid dim, no K/V interleave or
   packed-lane split — a page DMA delivers latent rows directly.
@@ -79,11 +80,11 @@ def _mla_kernel(
     layer_ref,  # [1]
     # Inputs
     q_ref,  # [num_q_per_blk, num_q_heads, latent_dim]
-    lat_pages_hbm_ref,  # [L, NB, page_size, 1, latent_dim]
+    lat_pages_hbm_ref,  # [L, NB, page_size, latent_dim]
     # Outputs
     o_ref,  # [num_q_per_blk, num_q_heads, value_dim]
     # Scratch
-    lat_bufs,  # [2, pages_per_blk, page_size, 1, latent_dim]
+    lat_bufs,  # [2, pages_per_blk, page_size, latent_dim]
     sems,  # [2]
     l_ref,  # [num_q_per_blk * H, 128]
     m_ref,  # [num_q_per_blk * H, 128]
@@ -97,7 +98,7 @@ def _mla_kernel(
     pages_per_seq = page_indices_ref.shape[-1]
     num_seqs = num_seqs_ref[0]
     layer = layer_ref[0]
-    _, num_pages_per_blk, page_size, _one, _dl = lat_bufs.shape
+    _, num_pages_per_blk, page_size, _dl = lat_bufs.shape
     num_kv_per_blk = num_pages_per_blk * page_size
     q_blk_idx = pl.program_id(0)
     init_seq_idx = seq_buf_idx_ref[0]
@@ -253,8 +254,8 @@ def _mla_kernel(
 
             lat_buf = make_page_copy(
                 cur_seq_idx, kv_blk_idx, cur_buf_idx
-            ).wait()  # [pages, page_size, 1, DL]
-            lat = lat_buf[:, :, 0, :].reshape(num_kv_per_blk, latent_dim)
+            ).wait()  # [pages, page_size, DL]
+            lat = lat_buf[...].reshape(num_kv_per_blk, latent_dim)
             q = q_ref[...].reshape(
                 num_q_per_blk * num_q_heads, latent_dim
             )
@@ -279,6 +280,40 @@ def _mla_kernel(
     o_ref[...] = acc_ref[...].astype(q_ref.dtype)
 
 
+def latent_blocks(num_q_heads: int, t: int, pages_per_seq: int,
+                  page_size: int) -> tuple[int, int]:
+    """The kernel's own block choice: (queries, pages) of a block. A visit
+    of a sequence runs the WHOLE block of queries against a block of its
+    keys, so the queries are few (a decode row is one of them: 128 folded
+    rows of the score matmul, 4 queries at 32 heads) and the keys many
+    (512 a block). On the v5e at 32 heads, 130 decode rows of context
+    500-4000 and 3 layers: 16 queries x 128 keys 9.95 ms, 4 x 128 5.60, 4 x
+    512 3.51, 1 x 512 2.85; beside a 382-token chunk 11.43, 8.63, 5.16,
+    7.69 (``tools/probe_mla.py``, PERF.md section 6)."""
+    return (min(max(1, 128 // max(num_q_heads, 1)), max(t, 1)),
+            max(1, min(pages_per_seq, 512 // page_size)))
+
+
+def count_latent_pairs(q_lens, kv_lens, q_per_blk: int, keys_per_blk: int):
+    """(live, computed) (query token, key) pairs a q head of one kernel
+    call over these rows: the pairs the mask lets through, and the pairs
+    the kernel's tiles compute for them: every visit of a sequence in a
+    block of queries runs the WHOLE block against every block of the
+    sequence's keys. Host side, numpy; ``rpa_kernel.count_ragged_pairs``
+    is the same count for the general kernel."""
+    import numpy as np
+
+    q_lens = np.asarray(q_lens, np.int64)
+    kv_lens = np.asarray(kv_lens, np.int64)
+    live = int(np.sum(q_lens * (kv_lens - q_lens) + q_lens * (q_lens + 1) // 2))
+    q_end = np.cumsum(q_lens)
+    q_start = q_end - q_lens
+    visits = np.where(
+        q_lens > 0, (q_end - 1) // q_per_blk - q_start // q_per_blk + 1, 0)
+    kv_blks = (np.maximum(kv_lens, 1) + keys_per_blk - 1) // keys_per_blk
+    return live, int(np.sum(visits * kv_blks) * q_per_blk * keys_per_blk)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=[
@@ -288,7 +323,7 @@ def _mla_kernel(
 )
 def mla_ragged_paged_attention(
     q_abs: jax.Array,  # [T, H, DL] absorbed queries (q_lat || q_pe)
-    lat_pages: jax.Array,  # [L, NB, page_size, 1, DL] latent cache
+    lat_pages: jax.Array,  # [L, NB, page_size, DL] latent cache
     layer: jax.Array,  # i32[1]
     kv_lens: jax.Array,  # i32[max_num_seqs]
     page_indices: jax.Array,  # i32[max_num_seqs, pages_per_seq]
@@ -309,8 +344,8 @@ def mla_ragged_paged_attention(
     sits at ``cu_q_lens[s] + i``; causal against the seq's ``kv_lens``
     context, queries occupying the final positions)."""
     t, num_q_heads, latent_dim = q_abs.shape
-    nl, nb, page_size, one, dl = lat_pages.shape
-    if one != 1 or dl != latent_dim:
+    nl, nb, page_size, dl = lat_pages.shape
+    if dl != latent_dim:
         raise ValueError(f"latent cache {lat_pages.shape} vs q {q_abs.shape}")
     if not 0 < value_dim <= latent_dim:
         raise ValueError(f"{value_dim=} out of range for {latent_dim=}")
@@ -322,15 +357,9 @@ def mla_ragged_paged_attention(
     if mask_value is None:
         mask_value = DEFAULT_MASK_VALUE
 
-    # Block sizes: the latent row is wide (DL ~ 576) so fewer pages per
-    # block than the general kernel; q blocks sized to the folded
-    # [NQ*H, DL] score matmul.
-    if num_queries_per_block is None:
-        num_queries_per_block = max(8, 512 // max(num_q_heads, 1))
-    num_q_per_blk = min(num_queries_per_block, max(t, 1))
-    if num_kv_pages_per_block is None:
-        num_kv_pages_per_block = max(1, min(pages_per_seq, 128 // page_size))
-    num_pages_per_blk = min(num_kv_pages_per_block, pages_per_seq)
+    own_q, own_pages = latent_blocks(num_q_heads, t, pages_per_seq, page_size)
+    num_q_per_blk = min(num_queries_per_block or own_q, max(t, 1))
+    num_pages_per_blk = min(num_kv_pages_per_block or own_pages, pages_per_seq)
 
     num_q_blks = pl.cdiv(t, num_q_per_blk)
     grid = (num_q_blks,)
@@ -342,7 +371,7 @@ def mla_ragged_paged_attention(
     lm_shape = (num_q_per_blk * num_q_heads, 128)
     scratch_shapes = [
         pltpu.VMEM(
-            (2, num_pages_per_blk, page_size, 1, latent_dim),
+            (2, num_pages_per_blk, page_size, latent_dim),
             lat_pages.dtype,
         ),
         pltpu.SemaphoreType.DMA((2,)),

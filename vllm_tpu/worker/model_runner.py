@@ -32,6 +32,7 @@ from vllm_tpu.core.sched_output import (
 )
 from vllm_tpu.logger import init_logger
 from vllm_tpu.ops.attention import AttentionMetadata, ragged_kernel_tiling
+from vllm_tpu.ops.mla_kernel import count_latent_pairs, latent_blocks
 from vllm_tpu.ops.rpa_kernel import count_ragged_pairs
 from vllm_tpu.resilience.failpoints import fail_point
 from vllm_tpu.tracing import trace_span
@@ -148,9 +149,7 @@ class ModelRunner:
         comp = config.compilation_config
         self.token_buckets = comp.token_buckets
         self.request_buckets = comp.request_buckets
-        self.block_buckets = comp._pow2_buckets(
-            min(16, self.max_blocks_per_req), self.max_blocks_per_req
-        )
+        self.block_buckets = comp.page_buckets(self.max_blocks_per_req)
         # Async-scheduling state: the previous dispatched step's sampled
         # device array + its request->row mapping (token feedback source).
         # _last_sampled is kept padded to the LARGEST request bucket so the
@@ -275,9 +274,16 @@ class ModelRunner:
         # A model that can count the live tokens routed to each expert
         # (models/mixtral.py) returns the [L, E] counts as a third output
         # of apply(): EPLB's load statistics, vllm:moe_experts_hit_total.
-        self._moe_counts = getattr(model, "supports_eplb", False)
+        self._moe_counts = getattr(model, "supports_eplb", False) or getattr(
+            model, "returns_expert_counts", False
+        )
         if self._moe_counts:
             model.return_expert_counts = True
+        # An expert share (the model holds ``num_experts`` of a wider
+        # router): the pairs routed HERE are data, so the device counts
+        # them; the host's product below counts every pair of a model
+        # that holds them all.
+        self._moe_share = getattr(model, "first_expert", None) is not None
         # EPLB: logical->physical expert indirection + load accumulator.
         self._eplb = getattr(model, "enable_eplb", False)
         self.eplb_state = None
@@ -490,6 +496,12 @@ class ModelRunner:
         self.diffusion_committed_tokens = 0
         self.moe_assignments = 0
         self.moe_experts_hit = 0
+        # Linear attention over per-request state slots, cumulative, a
+        # layer of that kind: rows whose state a step reads and writes,
+        # and tokens by the form that takes them ("decode" = a row's one
+        # token, "chunk" = a row of several).
+        self.linear_attn_state_rows = 0
+        self.linear_attn_tokens_by_kind = {"chunk": 0, "decode": 0}
         # Seconds spent in first uses of a step program (tracing, lowering
         # and compiling or loading it), beside bucket_compiles, their
         # count; the key of the first use now due, set by _prepare_inputs.
@@ -2250,11 +2262,36 @@ class ModelRunner:
             block_len=blen,
         )
         num_experts = getattr(self.model, "num_experts", 0)
-        if num_experts and hasattr(self.model, "top_k"):
-            # Token-expert pairs over the step's live tokens, every layer.
-            self.moe_assignments += (
-                t_live * self.model.top_k * self.model.num_layers
+        if num_experts and hasattr(self.model, "top_k") and not self._moe_share:
+            # Token-expert pairs over the step's live tokens, every
+            # layer that has experts.
+            self.moe_assignments += t_live * self.model.top_k * getattr(
+                self.model, "num_moe_layers", self.model.num_layers
             )
+        q_lens = np.diff(query_start_loc[: r_live + 1])
+        linear_layers = getattr(self.model, "num_linear_attn_layers", 0)
+        if linear_layers:
+            one = q_lens == 1
+            self.linear_attn_state_rows += r_live * linear_layers
+            self.linear_attn_tokens_by_kind["decode"] += (
+                int(one.sum()) * linear_layers)
+            self.linear_attn_tokens_by_kind["chunk"] += (
+                int(q_lens[~one].sum()) * linear_layers)
+        if getattr(self.model, "latent_dim", 0):
+            # What each row's latent attention reads, once a row a step;
+            # and its kernel's pairs, which every step of such a model
+            # runs (the fork of the ragged kernel, ops/mla_kernel.py).
+            self.attn_context_tokens_by_kind["latent"] = (
+                self.attn_context_tokens_by_kind.get("latent", 0)
+                + int(seq_lens[:r_live].sum())
+            )
+            q_blk, pages_blk = latent_blocks(
+                self.model.num_heads, t_pad, b_pad, self.block_size)
+            live, computed = count_latent_pairs(
+                q_lens, seq_lens[:r_live], q_blk, pages_blk * self.block_size,
+            )
+            self.attn_ragged_pairs["live"] += live
+            self.attn_ragged_pairs["computed"] += computed
         self.step_launches += 1
         if flags["decode_only"]:
             self.decode_only_launches += 1
@@ -2264,10 +2301,7 @@ class ModelRunner:
         self.attn_context_tokens_by_kind[kind] += int(
             seq_lens[:r_live].sum())
         if not flags["decode_only"]:
-            self._count_ragged_pairs(
-                np.diff(query_start_loc[: r_live + 1]), seq_lens[:r_live],
-                t_pad, b_pad,
-            )
+            self._count_ragged_pairs(q_lens, seq_lens[:r_live], t_pad, b_pad)
         # launch_sampled_tokens counts REALIZED emissions — finalize
         # accumulates the per-row token runs it actually folds, which is
         # exact for every path (fixed K, dynamic, spec, prefill).
@@ -2758,6 +2792,8 @@ class ModelRunner:
             # iterations of a multi-step decode, where an expert hit in
             # several of them counts once).
             self.moe_experts_hit += int(np.count_nonzero(moe_counts))
+            if self._moe_share:
+                self.moe_assignments += int(moe_counts.sum())
             if self.eplb_state is not None:
                 self.eplb_state.update(moe_counts)
                 if self.eplb_state.due:

@@ -403,11 +403,26 @@ class Worker:
         if fixed_fn is not None:
             # Hybrid models: constant-size Mamba slots come off the top of
             # the budget before paged blocks are sized.
-            state = fixed_fn(self.config.scheduler_config.max_num_seqs)
+            slots = self.config.scheduler_config.max_num_seqs
+            state = fixed_fn(slots)
+            # The provisional runner of a profiled start holds the slots
+            # already (``alloc_kv_cache`` makes them whatever the page
+            # count), and ``bytes_in_use`` has counted them once.
+            held = 0
+            if self.runner is not None and isinstance(
+                self.runner.kv_cache, dict
+            ):
+                held = sum(
+                    leaf.nbytes for name, leaf in self.runner.kv_cache.items()
+                    if name != "paged"
+                )
             logger.info(
-                "reserving %.2f GiB for per-request SSM state", state / 2**30
+                "reserving %.2f GiB for per-request state: %d slots and a "
+                "scratch one of %.1f MB each (%.2f GiB of it allocated "
+                "already)", state / 2**30, slots,
+                state / (slots + 1) / 1e6, held / 2**30,
             )
-            in_use += state
+            in_use += state - held
         if activation_bytes is not None:
             # Measured peak + 2% of the limit as safety margin (allocator
             # fragmentation, host-side staging buffers).
@@ -418,10 +433,16 @@ class Worker:
             )
         else:
             free_for_kv = (limit - in_use) * (1 - _ACTIVATION_HEADROOM)
-        if free_for_kv <= 0:
+        page_bytes = sum(spec.page_size_bytes for spec in specs.values())
+        if free_for_kv < page_bytes:
+            fixed = (
+                f", of which per-request state {state} bytes for "
+                f"{slots} slots (max_num_seqs)" if fixed_fn is not None else ""
+            )
             raise RuntimeError(
-                f"no HBM left for KV cache (limit={limit}, in_use={in_use}, "
-                f"activations={activation_bytes})"
+                f"no HBM left for KV cache (limit={limit}, in_use={in_use}"
+                f"{fixed}, activations={activation_bytes}, one page "
+                f"{page_bytes} bytes)"
             )
         kv_config = get_kv_cache_config_from_specs(specs, int(free_for_kv))
         num_blocks = kv_config.num_blocks
