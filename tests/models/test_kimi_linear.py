@@ -47,18 +47,39 @@ RAGGED = {
     "more chunks than one round holds": ([2] * 14, [0, 1] * 7, 32, 16, False),
     "a mixed step with no chunk": ([1, 1], [0, 0], 8, 4, False),
     "a mixed step with no decode row": ([5, 11], [1, 0], 16, 4, False),
+    "a row of exactly one chunk": ([8, 1, 3], [0, 0, 1], 16, 4, False),
+    "decode rows between two chunk rows": ([13, 1, 1, 18], [1, 0, 0, 0], 40, 8, False),
+    "one short chunk in nine places": ([1, 5], [0, 0], 64, 4, False),
+    "a last chunk of one token at the step's end": ([7, 9], [0, 1], 16, 4, False),
 }
 
 
+def _form(monkeypatch, form, chunk=8, sub=4):
+    """``kernel``: both forms as Pallas kernels, interpreted (what
+    ``conftest.py`` sets); ``xla``: both as what runs off the TPU. Chunks
+    of 8 in blocks of 4 unless told."""
+    from vllm_tpu import envs
+
+    assert envs.VLLM_TPU_PALLAS_INTERPRET
+    monkeypatch.setitem(envs._cache, "VLLM_TPU_DISABLE_PALLAS", form == "xla")
+    assert kda._pallas_form(jnp.zeros(1, jnp.float32)) == (form == "kernel", True)
+    monkeypatch.setattr(kda, "CHUNK", chunk)
+    monkeypatch.setattr(kda, "SUB", sub)
+
+
+FORMS = ["xla", "kernel"]
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("case", list(RAGGED))
-def test_both_forms_equal_the_recurrence_a_token(case, monkeypatch):
+def test_both_forms_equal_the_recurrence_a_token(case, form, monkeypatch):
     """``kda_attention`` against ``ops/gdn.py``'s scan a token (given the
     decay a channel): outputs at the live tokens, the state each row
     leaves in its slot, and nothing else touched: other slots, the other
-    layer, the padding rows. Chunks of 8 in blocks of 4, so that the tiny
-    rows cross chunk and block boundaries."""
-    monkeypatch.setattr(kda, "CHUNK", 8)
-    monkeypatch.setattr(kda, "SUB", 4)
+    layer, the padding rows; the kernels leave the scratch slot alone too
+    (the XLA forms park their dead places there). Chunks of 8 in blocks of
+    4, so that the tiny rows cross chunk and block boundaries."""
+    _form(monkeypatch, form)
     qlens, fresh, t_pad, r_pad, decode_only = RAGGED[case]
     b = _batch(qlens, fresh, t_pad, r_pad, seed=len(case))
     dev = {k: jnp.asarray(v) for k, v in b.items() if isinstance(v, np.ndarray)}
@@ -77,14 +98,68 @@ def test_both_forms_equal_the_recurrence_a_token(case, monkeypatch):
     for i in range(b["n"]):
         np.testing.assert_allclose(new_state[LAYER, b["slots"][i]],
                                    np.asarray(want_state)[i], atol=3e-6)
-    idle = [s for s in range(SLOTS) if s not in b["slots"][: b["n"]]]
+    idle = [s for s in range(SLOTS + (form == "kernel")) if s not in b["slots"][: b["n"]]]
     np.testing.assert_array_equal(new_state[LAYER, idle], b["state"][LAYER, idle])
     np.testing.assert_array_equal(new_state[1 - LAYER], b["state"][1 - LAYER])
 
 
-def test_a_strong_decay_neither_overflows_nor_loses_the_weak_channels():
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("places", [1, 3, 9])
+def test_a_rows_chunks_over_several_rounds_of_places(places, form, monkeypatch):
+    """Two rows of 4 and 3 chunks taken 1, 3 or 9 places a round: a row
+    that a round's end cuts goes on from what the round left, and the
+    result is the recurrence's whatever the places."""
+    _form(monkeypatch, form)
+    b = _batch([30, 1, 20], [0, 0, 1], 56, 4, seed=places)
+    dev = {k: jnp.asarray(v) for k, v in b.items() if isinstance(v, np.ndarray)}
+    o, new_state = kda.kda_chunks(
+        kda.l2norm(dev["q"]) * D ** -0.5, kda.l2norm(dev["k"]), dev["v"], dev["a"],
+        dev["beta"], dev["state"], LAYER, dev["slots"], dev["fresh"], dev["qsl"],
+        jnp.int32(b["n"]), n_chunks=places)
+    h0 = np.where(b["fresh"][:, None, None, None], 0, b["state"][LAYER][b["slots"]])
+    want, want_state = ragged_gated_delta_rule(
+        dev["q"], dev["k"], dev["v"], dev["a"], dev["beta"], jnp.asarray(h0),
+        dev["owner"], dev["qsl"])
+    o, new_state, want = np.asarray(o), np.asarray(new_state), np.asarray(want)
+    np.testing.assert_allclose(o[:30], want[:30], atol=3e-6)
+    np.testing.assert_allclose(o[31:51], want[31:51], atol=3e-6)
+    assert not o[30].any() and not o[51:].any()  # the decode row's token, the padding
+    for i in (0, 2):
+        np.testing.assert_allclose(new_state[LAYER, b["slots"][i]],
+                                   np.asarray(want_state)[i], atol=3e-6)
+    idle = [s for s in range(SLOTS + (form == "kernel")) if s not in b["slots"][[0, 2]]]
+    np.testing.assert_array_equal(new_state[LAYER, idle], b["state"][LAYER, idle])
+
+
+def test_the_kernels_matmuls_hold_what_three_bfloat16_passes_hold():
+    """Off the interpreter the kernel spells ``Precision.HIGH`` out: two
+    bfloat16 parts a side and the three products that hold a leading one
+    (2**-16 of a product), and sums of one side's own numbers from three
+    parts (float32's 2**-24)."""
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.normal(size=(64, 128)), jnp.float32)
+    y = jnp.asarray(rng.normal(size=(128, 128)), jnp.float32)
+    want = np.asarray(x, np.float64) @ np.asarray(y, np.float64)
+    high = np.abs(np.asarray(kda._dot_high(x, y, kda._NN)) - want).max()
+    one_pass = np.abs(np.asarray(jnp.dot(
+        x.astype(jnp.bfloat16), y.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32)) - want).max()
+    assert high < 5e-4 and high < one_pass / 100  # of sums near 11
+    np.testing.assert_array_equal(
+        np.asarray(kda._dot_high(x, y, kda._NN, exact=True)),
+        np.asarray(jnp.dot(x, y, precision="highest")))
+    tril = jnp.tril(jnp.ones((64, 64), jnp.float32))
+    sums = np.asarray(kda._dot_ones(tril, x, kda._NN, ones=0))
+    np.testing.assert_allclose(sums, np.cumsum(np.asarray(x, np.float64), axis=0), atol=4e-6)
+    ends = np.asarray(kda._dot_ones(x, jnp.ones((64, 8), jnp.float32), kda._TN, ones=1))
+    np.testing.assert_allclose(ends, np.tile(sums[-1][:, None], (1, 8)), atol=4e-6)
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_a_strong_decay_neither_overflows_nor_loses_the_weak_channels(form, monkeypatch):
     """Log-decays of -60 a token beside -1e-4: a cumulated decay over a
     chunk of 64 is exp(-3840), and nothing divides by it."""
+    _form(monkeypatch, form, kda.CHUNK, kda.SUB)
     rng = np.random.default_rng(0)
     t = 150
     f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)

@@ -266,20 +266,59 @@ def test_kda_one_token_kernel_compiles_and_updates_the_slots_in_place(one_chip):
 
 
 def test_kda_chunked_form_compiles_with_the_state_in_place(one_chip):
-    """The chunked form at a 512-token step: XLA matmuls in a scan in a
-    ``while_loop`` of rounds, the state stack carried through both without
-    a copy (its temporaries are the chunks' operands, not the 4 GB)."""
+    """The XLA form of the chunks (what runs off the TPU, and the tests'
+    oracle) at a 512-token step: matmuls in a scan in a ``while_loop`` of
+    rounds, the state stack carried through both without a copy (its
+    temporaries are the chunks' operands, not the 4 GB)."""
     from vllm_tpu.ops import kda
 
     sh = _kda_shapes(one_chip, 512, 192)
 
     def fn(q, k, v, a, beta, state, slots, fresh, qsl, n):
-        return kda.kda_chunks(q, k, v, a, beta, state, jnp.int32(3), slots, fresh, qsl, n)
+        return kda._chunks_xla(q, k, v, a, beta, state, jnp.int32(3), slots, fresh, qsl, n,
+                               n_chunks=9)
 
     mem = jax.jit(fn, donate_argnums=(5,)).lower(
         *sh["qkva"], sh["beta"], sh["state"], sh["slots"], sh["fresh"], sh["qsl"],
         sh["n"]).compile().memory_analysis()
     assert mem.alias_size_in_bytes >= KDA_STATE_BYTES and mem.temp_size_in_bytes < 512 << 20
+
+
+def test_kda_chunks_compile_as_one_kernel_with_fewer_loops_than_the_xla_form(
+        one_chip, monkeypatch):
+    """A mixed step's KDA state update at the cell's shapes (512 tokens,
+    192 rows, 193 slots of 10 layers, 32 heads of 128) with the chunked
+    form as ``kda_chunk_kernel``: the kernel is in the compiled program
+    beside the one-token one, the 4 GB state stack goes through both in
+    place, and what is left around them holds fewer ``while`` operations
+    than the same step over the XLA form (its rounds, its scan over the
+    places, its scatters)."""
+    from vllm_tpu.ops import kda
+
+    sh = _kda_shapes(one_chip, 512, 192)
+    # The test's process sees the CPU: name the forms a TPU takes here.
+    monkeypatch.setattr(kda, "_pallas_form", lambda state: (True, False))
+
+    def compiled():
+        def fn(q, k, v, a, beta, state, slots, fresh, qsl, n):  # traced anew each time
+            return kda.kda_attention(q, k, v, a, beta, state, jnp.int32(3), slots, fresh,
+                                     qsl, n, decode_only=False)
+
+        return jax.jit(fn, donate_argnums=(5,)).lower(
+            *sh["qkva"], sh["beta"], sh["state"], sh["slots"], sh["fresh"], sh["qsl"],
+            sh["n"]).compile()
+
+    kernel = compiled()
+    text = kernel.as_text()
+    assert "kda_chunk_kernel" in text and "kda_decode_kernel" in text
+    mem = kernel.memory_analysis()
+    assert mem.alias_size_in_bytes >= KDA_STATE_BYTES and mem.temp_size_in_bytes < 128 << 20
+    monkeypatch.setattr(
+        kda, "_chunks_pallas",
+        lambda *args, n_chunks, **kw: kda._chunks_xla(*args, n_chunks=n_chunks))
+    parent = compiled().as_text()
+    assert "kda_chunk_kernel" not in parent and "kda_decode_kernel" in parent
+    assert 0 < text.count(" while(") < parent.count(" while(")
 
 
 def test_slot_rows_are_read_and_written_without_a_loop_a_row(one_chip):

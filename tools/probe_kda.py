@@ -2,13 +2,19 @@
 (``ops/kda.py``) alone on the chip, at the shapes of
 ``kimilinear48b.long-answers``: 32 heads of 128, 193 slots of 10 layers.
 The one-token form at 130 and at 192 live rows of a 192-row step; the
-chunked form at a 382-token chunk, at a 512-token chunk, and at eight
-rows of 48 tokens; both beside each other as a mixed step runs them (130
-decode rows and a 382-token chunk). Each case: outputs and states against
-the recurrence a token (``ops/gdn.py``'s scan, given the decay a channel),
-and the least of five timings of 10 calls in one program (a step's KDA
-layers). One JSON line a point; exits non-zero on a disagreement. Without
-a TPU: one tiny case, the kernel interpreted.
+chunked form at one chunk row of 64, 128, 256, 382 and 512 tokens, at
+eight rows of 48 tokens and at twelve of 40 (two rounds of nine places);
+both beside each other as a mixed step runs them (130 decode rows and a
+382-token chunk). Each case: outputs and states
+against the recurrence a token (``ops/gdn.py``'s scan, given the decay a
+channel), and the least of five timings of 10 calls in one program (a
+step's KDA layers): ``ms_10_layers`` through ``kda_attention`` as a step
+calls it, and for the chunk cases ``chunks_ms`` / ``xla_chunks_ms``, the
+chunked form alone as the Pallas kernel and as the XLA form (each checked
+the same way). At 512 tokens the XLA form's parts are timed apart once
+(``xla_parts_ms``). One JSON line a point; exits non-zero on a
+disagreement. Without a TPU: one tiny case, the kernels interpreted.
+``python tools/probe_kda.py chunk.64,chunk.512`` runs the cases named.
 """
 
 import functools
@@ -57,13 +63,28 @@ def cases(rng, smoke):
     big = dict(heads=32, d=128, slots=192, layers=10)
     yield case(rng, "decode.130", [1] * 130, 192, 192, decode_only=True, **big)
     yield case(rng, "decode.192", [1] * 192, 192, 192, decode_only=True, **big)
+    for n in (64, 128, 256):
+        yield case(rng, f"chunk.{n}", [n], 512, 192, **big)
     yield case(rng, "chunk.382", [382], 512, 192, **big)
     yield case(rng, "chunk.512", [512], 512, 192, **big)
     yield case(rng, "chunks.8x48", [48] * 8, 512, 192, **big)
+    # Twelve chunks where a round of the kernel's grid has nine places.
+    yield case(rng, "chunks.12x40", [40] * 12, 512, 192, **big)
     yield case(rng, "mixed.130+382", [1] * 130 + [382], 512, 192, **big)
 
 
-def run(c):
+def attention(c):
+    return functools.partial(kda.kda_attention, decode_only=c["decode_only"])
+
+
+def xla_chunks(*args):
+    return kda._chunks_xla(*args, n_chunks=-(-args[0].shape[0] // kda.CHUNK) + 1)
+
+
+def run(c, form):
+    """``form(qn, kn, v, a, beta, state, layer, slots, fresh, qsl, n)``
+    over the case's layers: the first call's outputs and state, and the
+    least of five timings of the calls after it."""
     d = c["q"].shape[-1]
 
     @functools.partial(jax.jit, donate_argnums=(5,))
@@ -71,9 +92,8 @@ def run(c):
         qn, kn = kda.l2norm(q) * d ** -0.5, kda.l2norm(k)
 
         def one(state, layer):
-            o, state = kda.kda_attention(
-                qn, kn, v, a, beta, state, layer, slots, fresh, qsl, jnp.int32(c["n"]),
-                decode_only=c["decode_only"])
+            o, state = form(qn, kn, v, a, beta, state, layer, slots, fresh, qsl,
+                            jnp.int32(c["n"]))
             return state, o
 
         state, outs = jax.lax.scan(one, state, jnp.arange(c["layers"], dtype=jnp.int32))
@@ -83,16 +103,95 @@ def run(c):
     # timed calls run on the states the calls before them left.
     args = (c["q"], c["k"], c["v"], c["a"], c["beta"])
     rest = (c["slots"], c["fresh"], c["qsl"])
-    c["state_host"] = np.asarray(c["state"])
-    outs, state = jax.block_until_ready(layers(*args, c.pop("state"), *rest))
+    outs, state = jax.block_until_ready(layers(*args, jnp.asarray(c["state_host"]), *rest))
     first = np.asarray(outs), np.asarray(state)
+    return *first, least_ms(lambda st: layers(*args, st, *rest)[1], state)
+
+
+def least_ms(step, x=None, calls=4):
+    """The least of five timings of ``x = step(x)`` (``x`` may be donated:
+    each call is fed what the call before returned). A timing is ``calls``
+    calls sent one behind the other and waited for together, a call's
+    share of it: the host's millisecond to send a program and to hear of
+    its end is paid once, not beside every few ms of device time."""
     best = float("inf")
     for _ in range(5):
         t0 = time.perf_counter()
-        outs, state = jax.block_until_ready(layers(*args, state, *rest))
-        best = min(best, time.perf_counter() - t0)
-    del outs, state
-    return *first, 1e3 * best
+        for _ in range(calls):
+            x = step(x)
+        jax.block_until_ready(x)
+        best = min(best, (time.perf_counter() - t0) / calls)
+    return 1e3 * best
+
+
+def xla_parts(c):
+    """The XLA form's one round of nine places at the case's shapes, its
+    parts each alone, 10 times in one program: ms, least of five."""
+    h, d = c["q"].shape[1:]
+    t, cs = c["q"].shape[0], kda.CHUNK
+    nc = -(-t // cs) + 1
+    qlen, per_row, ends, total = kda._chunk_places(c["qsl"], jnp.int32(c["n"]), len(c["slots"]), cs)
+    g = jnp.arange(nc, dtype=jnp.int32)
+    row = jnp.clip(jnp.searchsorted(ends, g, side="right"), 0, len(c["slots"]) - 1)
+    j = g - (ends[row] - per_row[row])
+    n_valid = jnp.where(g < total, jnp.clip(qlen[row] - j * cs, 0, cs), 0)
+    ok = jnp.arange(cs)[None, :] < n_valid[:, None]
+    idx = jnp.clip(c["qsl"][row][:, None] + j[:, None] * cs + jnp.arange(cs), 0, t - 1)
+    where = c["slots"][row]
+
+    def operands(q, k, v, a, beta):
+        take = lambda x: jnp.where(ok.reshape(ok.shape + (1,) * (x.ndim - 1)), x[idx], 0.0)
+        return kda._chunk_operands(take(q), take(k), take(v), take(a), take(beta))
+
+    def scan(ops, s_in):
+        def step(s0, xs):
+            w, wk, qp, aq, kend, dend = xs
+            u = w - jnp.einsum("hik,hkv->hiv", wk, s0, precision=kda._HI)
+            o = jnp.einsum("hik,hkv->hiv", qp, s0, precision=kda._HI) + jnp.einsum(
+                "hij,hjv->hiv", aq, u, precision=kda._HI)
+            s1 = dend[..., None] * s0 + jnp.einsum("hjk,hjv->hkv", kend, u, precision=kda._HI)
+            return s1, (o, s1)
+
+        return jax.lax.scan(step, s_in, ops)[1]
+
+    def ten(fn):
+        """``fn(i, *xs)`` for the 10 layers in one program; every result
+        is folded into what is returned, so nothing is dead."""
+        def program(*xs):
+            def one(acc, i):
+                out = fn(i, *xs)
+                return acc + sum(jnp.sum(leaf[..., :1]) for leaf in jax.tree.leaves(out)), None
+            return jax.lax.scan(one, jnp.float32(0), jnp.arange(10, dtype=jnp.int32))[0]
+        return jax.jit(program)
+
+    def least(program, *xs):
+        jax.block_until_ready(program(*xs))
+        return least_ms(lambda _: program(*xs))
+
+    qkv = (c["q"], c["k"], c["v"], c["a"], c["beta"])
+    ops = jax.jit(operands)(*qkv)
+    s_in = jnp.zeros((h, d, d), jnp.float32)
+    state = jnp.asarray(c["state_host"])
+    o_all = jnp.zeros((nc * cs, h, d), jnp.float32)
+    s_all = jnp.zeros((nc, h, d, d), jnp.float32)
+    out = dict(
+        # +i so that the ten are ten (no common subexpression).
+        operands=least(ten(lambda i, *xs: operands(*(x + i for x in xs))), *qkv),
+        scan=least(ten(lambda i, ops, s: scan(ops, s + i)), ops, s_in),
+        state_gather=least(ten(lambda i, st: st[i, where]), state),
+        output_scatter=least(ten(lambda i, o: jnp.zeros((t, h, d), jnp.float32).at[
+            jnp.where(ok, idx, t).reshape(-1)].set(o + i, mode="drop")), o_all),
+    )
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def scatter(st, s_all):
+        def one(st, i):
+            return st.at[i, where].set(s_all + i), None
+        return jax.lax.scan(one, st, jnp.arange(10, dtype=jnp.int32))[0]
+
+    state = jax.block_until_ready(scatter(state, s_all))
+    out["state_scatter"] = least_ms(lambda st: scatter(st, s_all), state)
+    return out
 
 
 def reference(c, layer):
@@ -111,17 +210,34 @@ def main():
         os.environ.setdefault("VLLM_TPU_PALLAS_INTERPRET", "1")
         kda.CHUNK, kda.SUB = 8, 4
     bad = 0
+    only = set(sys.argv[1].split(",")) if len(sys.argv) > 1 else None  # case names
     for c in cases(np.random.default_rng(0), smoke):
-        outs, state, ms = run(c)
+        if only and c["name"] not in only:
+            continue
+        c["state_host"] = np.asarray(c.pop("state"))
         layer = c["layers"] - 1
         want, want_state = reference(c, layer)
         slots = np.asarray(c["slots"])[: c["n"]]
-        err = float(np.max(np.abs(outs[layer][: c["live"]] - want[: c["live"]])))
-        err_state = float(np.max(np.abs(state[layer][slots] - want_state[: c["n"]])))
-        bad += not (err < 2e-4 and err_state < 2e-4)
-        print(json.dumps(dict(
-            case=c["name"], ms_10_layers=ms, max_abs_err=err, max_abs_err_state=err_state,
-            rows=c["n"], tokens=c["live"], device=jax.devices()[0].device_kind)), flush=True)
+        point = dict(case=c["name"], rows=c["n"], tokens=c["live"],
+                     device=jax.devices()[0].device_kind)
+        forms = {"": attention(c)}
+        if c["name"].startswith(("chunk", "smoke")):
+            forms.update(chunks_=kda.kda_chunks, xla_chunks_=xla_chunks)
+        qlens = np.diff(np.asarray(c["qsl"]))[: c["n"]]
+        for name, form in forms.items():
+            outs, state, ms = run(c, form)
+            # The chunked form alone takes the rows of several tokens.
+            mine = qlens > 1 if name else qlens > 0
+            diff = np.abs(outs[layer][: c["live"]] - want[: c["live"]])[np.repeat(mine, qlens)]
+            diff_state = np.abs(state[layer][slots[mine]] - want_state[: c["n"]][mine])
+            err, err_state = float(diff.max()), float(diff_state.max())
+            bad += not (err < 2e-4 and err_state < 2e-4)
+            point.update({
+                name + ("ms" if name else "ms_10_layers"): ms,
+                name + "max_abs_err": err, name + "max_abs_err_state": err_state})
+        if c["name"] == "chunk.512":
+            point["xla_parts_ms"] = xla_parts(c)
+        print(json.dumps(point), flush=True)
         c.clear()
     return 1 if bad else 0
 

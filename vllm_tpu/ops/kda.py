@@ -20,13 +20,21 @@ token). Two forms here, both equal to that recurrence:
   they move nothing. Off the TPU (and not under the interpreter) the same
   update as einsums over every row.
 - ``kda_chunks``: rows with more than one token, in chunks of ``CHUNK``
-  tokens inside a row's segment. What does not depend on the incoming
-  state is computed for all chunks at once (the pairwise decayed
-  ``k k^T`` and ``q k^T``, the unit-triangular solve, the decayed
-  operands); a scan over the chunks then carries the state with four
-  small matmuls a chunk. A step's chunks are taken ``n_chunks`` at a
-  time, in rounds of a ``while_loop``, so that no mix of rows overflows
-  a static bound; one round is the rule.
+  tokens inside a row's segment, ``n_chunks`` places at a time in rounds
+  of a ``while_loop``, so that no mix of rows overflows a static bound;
+  one round is the rule. On the TPU one Pallas kernel a round
+  (``kda_chunk_kernel``): a step of its grid is one chunk of one row, and
+  the places past the step's last chunk do nothing and move nothing, so a
+  step pays for the chunks it carries. A chunk's tokens lie together in
+  the ragged batch and are copied in, and its outputs out, by DMAs of a
+  computed start; the row's ``[H, dk, dv]`` state is read from its slot
+  at the row's first chunk, stays in VMEM to its last and is written back
+  in place; the pairwise exponents, ``A_kk``, ``A_qk``, the solve and the
+  state matmuls of a chunk never leave VMEM. Float32 throughout, a matmul
+  as the three bfloat16 passes of ``Precision.HIGH``. Off the TPU (and as
+  the tests' oracle) the XLA form: what does not depend on the incoming
+  state for all of a round's places at once, then a scan over them with
+  four small matmuls a chunk, states and tokens gathered and scattered.
 
 Exponents. Every ``exp`` here is of a difference of cumulated decays
 ``b_i - b_j`` with ``i >= j``, or of ``b_i`` itself, so of a number
@@ -140,6 +148,21 @@ def write_slot_rows(
     return lax.dynamic_update_index_in_dim(stack, new, layer, 0)
 
 
+def _pallas_form(state: jnp.ndarray) -> tuple[bool, bool]:
+    """``(kernel, interpret)``: both forms run as Pallas kernels on a TPU
+    (interpreted where ``VLLM_TPU_PALLAS_INTERPRET`` asks off it) over a
+    float32 state, and as XLA otherwise or under
+    ``VLLM_TPU_DISABLE_PALLAS``."""
+    from vllm_tpu import envs
+
+    on_tpu = jax.default_backend() == "tpu"
+    interpret = bool(envs.VLLM_TPU_PALLAS_INTERPRET) and not on_tpu
+    kernel = state.dtype == jnp.float32 and (on_tpu or interpret) and not (
+        envs.VLLM_TPU_DISABLE_PALLAS
+    )
+    return kernel, interpret
+
+
 # ----------------------------------------------------------------------
 # One token a row
 # ----------------------------------------------------------------------
@@ -242,15 +265,10 @@ def kda_decode(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One token of every live row: ``(o [R, H, dv] f32, state)``. Rows
     past ``n_live`` are neither read nor written; their ``o`` is 0."""
-    from vllm_tpu import envs
-
-    on_tpu = jax.default_backend() == "tpu"
-    interpret = bool(envs.VLLM_TPU_PALLAS_INTERPRET) and not on_tpu
     args = (q, k, v, a, beta, state, jnp.asarray(layer, jnp.int32),
             slots, fresh, jnp.asarray(n_live, jnp.int32).reshape(()))
-    if state.dtype == jnp.float32 and (on_tpu or interpret) and not (
-        envs.VLLM_TPU_DISABLE_PALLAS
-    ):
+    pallas, interpret = _pallas_form(state)
+    if pallas:
         o, state = _decode_pallas(*args, interpret=interpret)
     else:
         o, state = _decode_xla(*args)
@@ -355,36 +373,25 @@ def _chunk_operands(q, k, v, a, beta):
             hm(k * jnp.exp(b[:, -1:] - b)), e_b[:, -1])
 
 
-def kda_chunks(
-    q: jnp.ndarray,  # [T, H, dk] f32, normalised and scaled
-    k: jnp.ndarray,  # [T, H, dk] f32, normalised
-    v: jnp.ndarray,  # [T, H, dv] f32
-    a: jnp.ndarray,  # [T, H, dk] f32 log-decay <= 0
-    beta: jnp.ndarray,  # [T, H] f32
-    state: jnp.ndarray,  # [L, S, H, dk, dv]; slot S-1 is scratch
-    layer: jnp.ndarray,  # scalar i32
-    slots: jnp.ndarray,  # [R] i32
-    fresh: jnp.ndarray,  # [R] bool: the row starts its request
-    query_start_loc: jnp.ndarray,  # [R+1]
-    num_seqs: jnp.ndarray,  # scalar i32
-    *,
-    n_chunks: int | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Every live row with MORE than one token, by chunks. Returns ``(o
-    [T, H, dv] f32, state)``; ``o`` is 0 at the tokens of other rows."""
-    t, h, dk = q.shape
-    dv = v.shape[-1]
-    r = slots.shape[0]
-    c = CHUNK
-    nc = n_chunks or (-(-t // c) + 1)
-    scratch = state.shape[1] - 1
-    layer = jnp.asarray(layer, jnp.int32)
-
+def _chunk_places(query_start_loc, num_seqs, r, c):
+    """The step's chunks in order: ``(qlen [R], per_row [R], ends [R],
+    total)``; place ``g`` is chunk ``g - (ends[row] - per_row[row])`` of
+    the row ``searchsorted(ends, g, "right")``."""
     qlen = query_start_loc[1:] - query_start_loc[:-1]
     mine = (qlen > 1) & (jnp.arange(r) < num_seqs)
     per_row = jnp.where(mine, -(-qlen // c), 0)
     ends = jnp.cumsum(per_row)
-    total = ends[-1]
+    return qlen, per_row, ends, ends[-1]
+
+
+def _chunks_xla(q, k, v, a, beta, state, layer, slots, fresh,
+                query_start_loc, num_seqs, *, n_chunks):
+    t, h, dk = q.shape
+    dv = v.shape[-1]
+    r = slots.shape[0]
+    c, nc = CHUNK, n_chunks
+    scratch = state.shape[1] - 1
+    qlen, per_row, ends, total = _chunk_places(query_start_loc, num_seqs, r, c)
 
     def one_round(carry):
         rnd, s_in, state, out = carry
@@ -439,6 +446,343 @@ def kda_chunks(
          jnp.zeros((t, h, dv), jnp.float32)),
     )
     return out, state
+
+
+# The chunked form as one kernel a layer ---------------------------------
+
+_NN = (((1,), (0,)), ((), ()))  # [m, k] [k, n]
+_NT = (((1,), (1,)), ((), ()))  # [m, k] [n, k]
+_TN = (((0,), (0,)), ((), ()))  # [k, m] [k, n]
+
+
+def _bf16_parts(x, n: int, exact: bool):
+    """Float32 ``x`` as a sum of ``n`` bfloat16 arrays, leading part
+    first; ``exact`` (the interpreter, on the CPU, where a float32 product
+    is one): ``x`` itself."""
+    if exact:
+        return [x]
+    parts = []
+    for _ in range(n):
+        parts.append(x.astype(jnp.bfloat16))
+        x = x - parts[-1].astype(jnp.float32)
+    return parts
+
+
+def _dot(x, y, dims):
+    return lax.dot_general(x, y, dims, precision=lax.Precision.HIGHEST
+                           if x.dtype == jnp.float32 else None,
+                           preferred_element_type=jnp.float32)
+
+
+def _dot_high(x, y, dims, exact: bool = False):
+    """A float32 matmul at ``Precision.HIGH`` spelt out, since Mosaic
+    takes only DEFAULT and HIGHEST: both operands cut in two bfloat16
+    parts, the three products that hold a leading part, float32 sums."""
+    xs, ys = _bf16_parts(x, 2, exact), _bf16_parts(y, 2, exact)
+    return sum(_dot(p, r, dims) for n, p in enumerate(xs)
+               for r in ys[: len(ys) - n])
+
+
+def _dot_ones(x, y, dims, exact: bool = False, ones: int = 0):
+    """A matmul whose side ``ones`` holds 0s and 1s only (exact in
+    bfloat16), the other side cut in THREE bfloat16 parts: float32 sums of
+    its own numbers."""
+    sides = [x, y]
+    whole = sides[ones]
+    total = 0.0
+    for part in _bf16_parts(sides[1 - ones], 3, exact):
+        sides[ones], sides[1 - ones] = whole.astype(part.dtype), part
+        total = total + _dot(*sides, dims)
+    return total
+
+
+def _chunk_kernel(
+    n_live_ref, layer_ref, tok0_ref, nval_ref, slot_ref, load_ref,  # prefetch
+    q_hbm, k_hbm, v_hbm, a_hbm, beta_hbm,  # [T, H, d]; beta [T, 1, lanes]
+    out_in_hbm,  # aliased to out_hbm, never read
+    s_ref,  # [H, dk, dv]: the slot of this place's row
+    out_hbm,  # [T + C, H, dv]
+    s_out_ref,  # [H, dk, dv], the same slot: held from a row's first
+    # chunk of the call to its last
+    qbuf, kbuf, vbuf, abuf, bbuf,  # [2, 2 C, H, d]: this place, the next
+    obuf,  # [2, C, H, dv]
+    b_s, k_s,  # [C, dk]: the cumulated decays and the keys of the head in hand
+    in_sem, out_sem,
+    *, sub: int, exact: bool,
+):
+    del layer_ref, slot_ref, out_in_hbm  # the index maps' and the alias's
+    c, h, dk = obuf.shape[1:]
+    t = q_hbm.shape[0]
+    i = pl.program_id(0)
+    n_live = n_live_ref[0]
+    f32 = jnp.float32
+    dot = functools.partial(_dot_high, exact=exact)
+
+    def fetch(place, buf):
+        # C whole tokens that hold the chunk's: from its first one, or the
+        # last C of the step where those would pass its end.
+        rows = pl.ds(jnp.minimum(tok0_ref[place], t - c), c)
+        return [
+            pltpu.make_async_copy(
+                src.at[rows], dst.at[buf, pl.ds(0, c)], in_sem.at[buf, n])
+            for n, (src, dst) in enumerate((
+                (q_hbm, qbuf), (k_hbm, kbuf), (v_hbm, vbuf), (a_hbm, abuf),
+                (beta_hbm, bbuf)))
+        ]
+
+    def put(place, buf):
+        return pltpu.make_async_copy(
+            obuf.at[buf], out_hbm.at[pl.ds(tok0_ref[place], c)],
+            out_sem.at[buf])
+
+    @pl.when(i < n_live)
+    def _():
+        cur = i % 2
+
+        @pl.when(i == 0)
+        def _():
+            for cp in fetch(0, 0):
+                cp.start()
+
+        @pl.when(i + 1 < n_live)
+        def _():
+            for cp in fetch(i + 1, 1 - cur):
+                cp.start()
+
+        for cp in fetch(i, cur):
+            cp.wait()
+
+        n_valid = nval_ref[i]
+        load = load_ref[i]
+        # Where the chunk's tokens lie in the 2 C rows of a buffer (the
+        # rows past the C fetched hold nothing and are masked).
+        mine = pl.ds(tok0_ref[i] - jnp.minimum(tok0_ref[i], t - c), c)
+        row_c = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+        valid = row_c < n_valid
+        ri = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+        ci = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+        tril = (ci <= ri).astype(f32)
+        lane_sc = lax.broadcasted_iota(jnp.int32, (sub, c), 1)
+        row_s = lax.broadcasted_iota(jnp.int32, (sub, 1), 0)
+        lane_h = lax.broadcasted_iota(jnp.int32, (c, bbuf.shape[-1]), 1)
+
+        def one_head(hd, carry):
+            keep = lambda x: jnp.where(valid, x, 0.0)
+            q = keep(qbuf[cur, mine, hd, :])
+            k = keep(kbuf[cur, mine, hd, :])
+            v = keep(vbuf[cur, mine, hd, :])
+            a = keep(abuf[cur, mine, hd, :])
+            beta = keep(jnp.sum(
+                jnp.where(lane_h == hd, bbuf[cur, mine, 0, :], 0.0),
+                axis=1, keepdims=True))  # [C, 1]
+            s0 = jnp.where(
+                load == 2, 0.0,
+                jnp.where(load == 1, s_ref[hd], s_out_ref[hd]))
+            # b[i] = the sum of a[j] over j <= i: cumulated, <= 0, falling.
+            b = _dot_ones(tril, a, _NN, exact)
+            kb = k * beta
+            b_s[...], k_s[...] = b, k  # read back a row (a token) at a time
+            m_rows, aqk_rows, inv_rows = [], [], []
+            for lo in range(0, c, sub):
+                bb, qb, kbb = b[lo:lo + sub], q[lo:lo + sub], kb[lo:lo + sub]
+                row = lo + row_s
+                # Inside the block: the exact pairwise exponent, a column
+                # (one earlier token against the block's rows) at a time.
+                # M = diag(beta) A_kk is strictly lower, and its column j
+                # under the diagonal, n_j, comes out of the sum over the
+                # lanes on every lane: (I + n_j e_j^T)^-1 = I - n_j e_j^T
+                # is applied at once, and after the last column ``inv``
+                # holds the block's (I + M)^-1 exactly (no matmul, so no
+                # wait for one), at the block's own lanes.
+                akk = aqk = jnp.zeros((sub, c), f32)
+                inv = (lane_sc == row).astype(f32)
+                for j in range(sub):
+                    at = pl.ds(lo + j, 1)
+                    kj = k_s[at, :] * jnp.exp(
+                        jnp.minimum(bb - b_s[at, :], 0.0))
+                    hit = lane_sc == lo + j
+                    n_j = jnp.where(
+                        row_s > j, jnp.sum(kbb * kj, axis=1, keepdims=True),
+                        0.0)
+                    akk = jnp.where(hit, n_j, akk)
+                    aqk = jnp.where(
+                        hit, jnp.sum(qb * kj, axis=1, keepdims=True), aqk)
+                    if j < sub - 1:
+                        inv = inv - n_j * inv[j:j + 1, :]
+                if lo:
+                    # Across blocks: split at the decay just before the
+                    # block (both halves <= 0); the columns from the
+                    # block on are 0 and the pairs inside it stay.
+                    ref = b[lo - 1:lo]
+                    inner = jnp.exp(bb - ref)
+                    k_out = jnp.concatenate([
+                        k[:lo] * jnp.exp(ref - b[:lo]),
+                        jnp.zeros((c - lo, dk), f32)], axis=0)
+                    cross = dot(
+                        jnp.concatenate([qb * inner, kbb * inner], axis=0),
+                        k_out, _NT)  # [2 SUB, C]
+                    aqk, akk = aqk + cross[:sub], akk + cross[sub:]
+                m_rows.append(akk)
+                aqk_rows.append(jnp.where(lane_sc <= row, aqk, 0.0))
+                inv_rows.append(inv)
+            m, a_qk, inv = (jnp.concatenate(x, axis=0)
+                            for x in (m_rows, aqk_rows, inv_rows))
+
+            # u = (I + M)^-1 (beta v - beta k e^b S0), o = q e^b S0 + A_qk u.
+            # With D the blocks on M's diagonal and E the rest, I + M =
+            # (I + D)(I + F), F = (I + D)^-1 E, and F is strictly lower BY
+            # BLOCKS: F^(C / SUB) = 0, so (I + F)^-1 = (I - F)(I + F^2)(I +
+            # F^4)... exactly. A factor is applied to u while the next
+            # power is squared: one matmul's wait a factor.
+            e_b = jnp.exp(b)
+            both = dot(jnp.concatenate([kb * e_b, q * e_b], axis=0),
+                       s0, _NN)  # [2 C, dv]
+            shift = sub.bit_length() - 1
+            power = dot(inv, jnp.where((ri >> shift) != (ci >> shift), m, 0.0),
+                        _NN)
+            u = dot(inv, v * beta - both[:c], _NN)
+            u = u - dot(power, u, _NN)
+            for _ in range(shift + 1, c.bit_length() - 1):
+                power = dot(power, power, _NN)
+                u = u + dot(power, u, _NN)
+            obuf[cur, :, hd, :] = both[c:] + dot(a_qk, u, _NN)
+            # The decay over the whole chunk with dk on the sublanes, as S
+            # is laid out.
+            d_end = jnp.exp(_dot_ones(
+                a, jnp.ones((c, v.shape[1]), f32), _TN, exact, ones=1))
+            s_out_ref[hd] = d_end * s0 + dot(
+                k * jnp.exp(b[c - 1:c, :] - b), u, _TN)
+            return carry
+
+        lax.fori_loop(0, h, one_head, 0)
+
+        @pl.when(i > 0)
+        def _():
+            put(i - 1, 1 - cur).wait()
+
+        put(i, cur).start()
+
+        @pl.when(i == n_live - 1)
+        def _():
+            put(i, cur).wait()
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_chunks", "chunk", "sub", "interpret"))
+def _chunks_pallas(q, k, v, a, beta, state, layer, slots, fresh,
+                   query_start_loc, num_seqs, *, n_chunks: int, chunk: int,
+                   sub: int, interpret: bool = False):
+    t, h, dk = q.shape
+    dv = v.shape[-1]
+    r = slots.shape[0]
+    c, nc = chunk, n_chunks
+    assert c & (c - 1) == 0 and sub & (sub - 1) == 0, (c, sub)
+    qlen, per_row, ends, total = _chunk_places(query_start_loc, num_seqs, r, c)
+    # A chunk is read as C whole tokens: a step shorter than that is padded.
+    short = max(c - t, 0)
+    pad = lambda x: jnp.pad(x, ((0, short),) + ((0, 0),) * (x.ndim - 1))
+    lanes = -(-h // 128) * 128  # a DMA moves whole lanes
+    operands = (pad(q), pad(k), pad(v), pad(a),
+                jnp.pad(beta, ((0, short), (0, lanes - h)))[:, None, :])
+    i32 = lambda x: x.astype(jnp.int32)
+
+    def slot_map(i, n, li, tok, nv, sl, ld):
+        return (li[0], sl[i], 0, 0, 0)
+
+    slot = pl.BlockSpec((None, None, h, dk, dv), slot_map)
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    call = pl.pallas_call(
+        functools.partial(_chunk_kernel, sub=sub, exact=interpret),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            in_specs=[anywhere] * 6 + [slot],
+            out_specs=[anywhere, slot],
+            grid=(nc,),
+            scratch_shapes=[
+                pltpu.VMEM((2, 2 * c, h, dk), jnp.float32),
+                pltpu.VMEM((2, 2 * c, h, dk), jnp.float32),
+                pltpu.VMEM((2, 2 * c, h, dv), jnp.float32),
+                pltpu.VMEM((2, 2 * c, h, dk), jnp.float32),
+                pltpu.VMEM((2, 2 * c, 1, lanes), jnp.float32),
+                pltpu.VMEM((2, c, h, dv), jnp.float32),
+                pltpu.VMEM((c, dk), jnp.float32),
+                pltpu.VMEM((c, dk), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 5)),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((t + c, h, dv), jnp.float32),
+            jax.ShapeDtypeStruct(state.shape, state.dtype),
+        ],
+        # After the six prefetched: operand 11 is the output's buffer and
+        # 12 the state stack.
+        input_output_aliases={11: 0, 12: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20,
+        ),
+        name="kda_chunk_kernel",
+        interpret=interpret,
+    )
+
+    def one_round(carry):
+        rnd, state, out = carry
+        # The places past the step's last chunk repeat it: same slot, so
+        # nothing moves, and the kernel does nothing there.
+        g = jnp.minimum(rnd * nc + jnp.arange(nc, dtype=jnp.int32), total - 1)
+        row = jnp.clip(jnp.searchsorted(
+            ends, g, side="right", method="compare_all"), 0, r - 1)
+        j = g - (ends[row] - per_row[row])
+        # A row's first chunk starts from its slot (from zero where the
+        # request starts); its later chunks from what the place before
+        # left in VMEM, but for a round's first place: the round before
+        # left the row's state in its slot.
+        load = jnp.where(
+            j == 0, jnp.where(fresh[row], 2, 1),
+            (jnp.arange(nc) == 0).astype(jnp.int32))
+        out, state = call(
+            i32(jnp.minimum(total - rnd * nc, nc)).reshape(1),
+            layer.reshape(1),
+            i32(query_start_loc[row] + j * c),
+            i32(jnp.clip(qlen[row] - j * c, 0, c)), i32(slots[row]),
+            i32(load), *operands, out, state)
+        return rnd + 1, state, out
+
+    _, state, out = lax.while_loop(
+        lambda carry: carry[0] * nc < total,
+        one_round,
+        (jnp.int32(0), state, jnp.zeros((t + c, h, dv), jnp.float32)),
+    )
+    return out[:t], state
+
+
+def kda_chunks(
+    q: jnp.ndarray,  # [T, H, dk] f32, normalised and scaled
+    k: jnp.ndarray,  # [T, H, dk] f32, normalised
+    v: jnp.ndarray,  # [T, H, dv] f32
+    a: jnp.ndarray,  # [T, H, dk] f32 log-decay <= 0
+    beta: jnp.ndarray,  # [T, H] f32
+    state: jnp.ndarray,  # [L, S, H, dk, dv]; slot S-1 is scratch
+    layer: jnp.ndarray,  # scalar i32
+    slots: jnp.ndarray,  # [R] i32
+    fresh: jnp.ndarray,  # [R] bool: the row starts its request
+    query_start_loc: jnp.ndarray,  # [R+1]
+    num_seqs: jnp.ndarray,  # scalar i32
+    *,
+    n_chunks: int | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Every live row with MORE than one token, by chunks. Returns ``(o
+    [T, H, dv] f32, state)``; ``o`` is 0 at the tokens of other rows."""
+    nc = n_chunks or (-(-q.shape[0] // CHUNK) + 1)
+    args = (q, k, v, a, beta, state, jnp.asarray(layer, jnp.int32), slots,
+            fresh, query_start_loc,
+            jnp.asarray(num_seqs, jnp.int32).reshape(()))
+    pallas, interpret = _pallas_form(state)
+    if pallas:
+        return _chunks_pallas(*args, n_chunks=nc, chunk=CHUNK, sub=SUB,
+                              interpret=interpret)
+    return _chunks_xla(*args, n_chunks=nc)
 
 
 def kda_attention(
