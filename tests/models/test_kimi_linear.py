@@ -225,6 +225,74 @@ def test_slot_rows_are_read_and_written_exactly_and_only_for_live_rows(dtype):
     np.testing.assert_array_equal(new, want)  # the scratch slot and layer 0 untouched
 
 
+# The convolution's kernel against the XLA form: a list of steps, each the
+# rows' (tokens, starts its request) and the token and row buckets.
+CONV_STEPS = {
+    # 20 tokens of one request: tails shorter and longer than a step.
+    "one row as steps of 2, 1, 9, 1, 7": [
+        ([(2, 1)], 4, 2), ([(1, 0)], 4, 2), ([(9, 0)], 12, 2), ([(1, 0)], 4, 2),
+        ([(7, 0)], 8, 2)],
+    # Rows of one token, a chunk that crosses a tile of 4 tokens, a row that
+    # starts its request, padding rows on the scratch slot.
+    "a mixed step": [([(1, 0), (1, 0), (11, 0), (1, 1), (1, 0)], 24, 8)],
+    "rows shorter than the tail across a tile's edge": [
+        ([(3, 0), (2, 0), (2, 1), (1, 0), (2, 0)], 12, 8)],
+    "decode only, fewer live rows than the bucket": [
+        ([(1, 0), (1, 1), (1, 0), (1, 0), (1, 0)], 8, 8)],
+    "a step with no live row": [([], 8, 4)],
+    "every token of the bucket live": [([(1, 0), (6, 0), (1, 1)], 8, 3)],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(CONV_STEPS))
+def test_the_convolutions_kernel_equals_the_xla_form_and_touches_live_slots_only(
+        case, dtype, monkeypatch):
+    """``kda_conv_kernel`` interpreted, four tokens a step of its grid,
+    against ``read_slot_rows`` -> ``ragged_short_conv`` ->
+    ``write_slot_rows``: ``y`` to a rounding of the sum, the live rows' new
+    tails and every other slot and layer bit for bit."""
+    monkeypatch.setattr(kda, "CONV_TILE", 4)
+    rng = np.random.default_rng(7)
+    g, dk, k, slots_n, layers = 6, 16, 4, 9, 2
+    widths = (2 * dk, 3 * dk, dk)  # three inputs side by side
+    taps = [jnp.asarray(rng.normal(size=(k, w)), dtype) for w in widths]
+    stack = jnp.asarray(rng.normal(size=(layers, slots_n + 1, k - 1, g, dk)), dtype)
+    decode_only = case.startswith("decode only")
+    for rows, t_pad, r_pad in CONV_STEPS[case]:
+        n = len(rows)
+        qlens = [q for q, _ in rows]
+        qsl = np.full(r_pad + 1, sum(qlens), np.int32)
+        qsl[0] = 0
+        qsl[1: n + 1] = np.cumsum(qlens)
+        owner = np.zeros(t_pad, np.int32)
+        for i in range(n):
+            owner[qsl[i]: qsl[i + 1]] = i
+        slots = np.full(r_pad, slots_n, np.int32)  # padding rows: the scratch slot
+        slots[:n] = (3 + 2 * np.arange(n)) % slots_n  # the same row, the same slot
+        fresh = np.zeros(r_pad, bool)
+        fresh[:n] = [f for _, f in rows]
+        xs = [jnp.asarray(rng.normal(size=(t_pad, w)), dtype) for w in widths]
+        if decode_only:
+            qsl = np.arange(r_pad + 1, dtype=np.int32)  # as the model gives them
+        args = (xs, stack, taps, jnp.int32(1), jnp.asarray(slots), jnp.asarray(fresh),
+                jnp.asarray(owner), jnp.asarray(qsl), jnp.int32(n))
+        got = {}
+        for form in FORMS:
+            _form(monkeypatch, form)
+            assert kda._pallas_form() == (form == "kernel", True)
+            got[form] = kda.kda_short_conv(*args, decode_only=decode_only)
+        (want_y, want_stack), (y, new) = got["xla"], got["kernel"]
+        assert y.dtype == jnp.float32 and y.shape == (t_pad, g, dk)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), rtol=1e-6, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(new, np.float32),
+                                      np.asarray(want_stack, np.float32))
+        changed = (np.asarray(new, np.float32) != np.asarray(stack, np.float32)).any(axis=(2, 3, 4))
+        assert changed[0].sum() == 0 and set(np.flatnonzero(changed[1])) == set(slots[:n])
+        assert not np.asarray(y)[sum(qlens):].any()  # nothing past the live tokens
+        stack = new
+
+
 def _model(config, dtype=jnp.float32):
     from transformers import PretrainedConfig
 

@@ -345,6 +345,72 @@ def test_slot_rows_are_read_and_written_without_a_loop_a_row(one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes >= KDA_LAYERS * KDA_SLOTS * width * 2
 
 
+def _kda_conv_shapes(sharding, t, r):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    pj = KDA_H * KDA_D
+    return ([s((t, pj), jnp.bfloat16)] * 3,
+            s((KDA_LAYERS, KDA_SLOTS, 3, 3 * KDA_H, KDA_D), jnp.bfloat16),
+            [s((4, pj), jnp.bfloat16)] * 3, s((r,), jnp.int32), s((r,), jnp.bool_),
+            s((t,), jnp.int32), s((r + 1,), jnp.int32), s((), jnp.int32))
+
+
+@pytest.mark.parametrize("t,decode_only", [(512, False), (192, True)],
+                         ids=["mixed_512_tokens", "decode_only_192_tokens"])
+def test_kda_short_convolution_compiles_as_one_kernel_over_the_slots_in_place(
+        one_chip, monkeypatch, t, decode_only):
+    """The mixer's convolution at the cell's two steps (512 or 192 tokens,
+    192 rows, q, k and v of 32 heads of 128 in bf16, 193 slots of 10 layers
+    of 3 x 96 x 128): ONE ``kda_conv_kernel``, nothing gathered, scattered or
+    looped over around it, and the 142 MB stack of tails goes through in
+    place (a slot is whole tiles: Mosaic refuses a DMA of one row of a
+    ``[slots, 36864]`` layer, "slice shape must be aligned to tiling")."""
+    from vllm_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_pallas_form", lambda state=None: (True, False))
+    xs, stack, taps, slots, fresh, tok_req, qsl, n = _kda_conv_shapes(one_chip, t, 192)
+
+    def fn(xq, xk, xv, stack, cq, ck, cv, slots, fresh, tok_req, qsl, n):
+        return kda.kda_short_conv((xq, xk, xv), stack, (cq, ck, cv), jnp.int32(3), slots,
+                                  fresh, tok_req, qsl, n, decode_only=decode_only)
+
+    compiled = jax.jit(fn, donate_argnums=(3,)).lower(
+        *xs, stack, *taps, slots, fresh, tok_req, qsl, n).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "kda_conv_kernel" in text
+    assert not any(op in text for op in (" gather(", " scatter(", " while("))
+    mem = compiled.memory_analysis()
+    stack_bytes = KDA_LAYERS * KDA_SLOTS * 3 * 3 * KDA_H * KDA_D * 2
+    assert mem.alias_size_in_bytes >= stack_bytes and mem.temp_size_in_bytes < 64 << 20
+
+
+def test_tracing_the_convolutions_kernel_once_is_cheap(one_chip):
+    """``kda_conv_kernel`` is traced and lowered ten times a step program
+    (a KDA layer each outside the scan's body, once inside): its body is
+    loops over a step's rows and tokens, not an unrolled tile. The bound is
+    the ragged kernel's (``test_tracing_the_ragged_kernel_once_is_cheap``);
+    it takes 0.3-0.6 s here."""
+    import time
+
+    from vllm_tpu.ops import kda
+
+    xs, stack, taps, slots, fresh, _, qsl, n = _kda_conv_shapes(one_chip, 512, 192)
+
+    def lower(layer):
+        def fn(xq, xk, xv, stack, cq, ck, cv, slots, fresh, qsl, n):
+            return kda._conv_pallas((xq, xk, xv), stack, (cq, ck, cv), jnp.int32(layer),
+                                    slots, fresh, qsl, n, tile=kda.CONV_TILE)
+
+        jax.jit(fn).lower(*xs, stack, *taps, slots, fresh, qsl, n)
+
+    lower(0)  # imports and caches that are not the kernel's
+    kda._conv_pallas.clear_cache()
+    t0 = time.process_time()
+    lower(1)
+    assert time.process_time() - t0 < 4.0
+
+
 @pytest.mark.parametrize("t,r,pages", [(512, 192, 768), (192, 192, 256)],
                          ids=["mixed_T512_P768", "decode_T192_P256"])
 def test_latent_attention_kernel_compiles(one_chip, t, r, pages):

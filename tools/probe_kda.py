@@ -15,6 +15,18 @@ the same way). At 512 tokens the XLA form's parts are timed apart once
 (``xla_parts_ms``). One JSON line a point; exits non-zero on a
 disagreement. Without a TPU: one tiny case, the kernels interpreted.
 ``python tools/probe_kda.py chunk.64,chunk.512`` runs the cases named.
+
+The ``conv.*`` cases are the short convolution of a layer
+(``kda_short_conv``) at the cell's shapes, 10 layers over 193 slots of 3 x
+96 x 128 bf16: 192 one-token rows of which 72 and 130 live, and the same
+rows beside a chunk of 440 and of 382 tokens. Both forms, the Pallas kernel
+(``kernel_ms``) and ``read_slot_rows`` -> ``ragged_short_conv`` ->
+``write_slot_rows`` (``xla_ms``), each also with what the mixer does next
+(SiLU, the two norms, q, k and v a head a row: ``*_scope_ms``), since the
+kernel hands its output over as ``[T, heads, 128]`` and the XLA form as
+``[T, channels]``; the least bytes (a token's inputs in and float32 out, a
+live row's tail in and out) and the kernel's share of the chip's bandwidth
+(``benchmark/peaks.json``) at them.
 """
 
 import functools
@@ -194,6 +206,102 @@ def xla_parts(c):
     return out
 
 
+def hbm_bytes_per_s():
+    """The chip's bandwidth by ``benchmark/peaks.json``; None for a device
+    the table does not hold (the CPU's smoke case)."""
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmark", "peaks.json")
+    with open(path) as f:
+        kinds = json.load(f)["device_kinds"]
+    return kinds.get(jax.devices()[0].device_kind, {}).get("hbm_bytes_per_s")
+
+
+def conv_cases(smoke):
+    if smoke:
+        yield "conv.smoke", [1, 1, 9, 1], 16, 8, False, dict(heads=2, d=16, slots=6, layers=2)
+        return
+    big = dict(heads=32, d=128, slots=192, layers=10)
+    yield "conv.decode.72", [1] * 72, 192, 192, True, big
+    yield "conv.decode.130", [1] * 130, 192, 192, True, big
+    yield "conv.mixed.72+440", [1] * 72 + [440], 512, 192, False, big
+    yield "conv.mixed.130+382", [1] * 130 + [382], 512, 192, False, big
+
+
+def conv_point(rng, name, qlens, t_pad, r_pad, decode_only, heads, d, slots, layers):
+    """Both forms of the convolution over ``layers`` layers in one program,
+    a layer's inputs its own (nothing is hoisted out of the scan): the last
+    layer's output and the stack of the one against the other, and the
+    least of five timings of each, alone and with the mixer's next steps."""
+    n, live, pj, taps_n = len(qlens), sum(qlens), heads * d, 4
+    qsl = np.full(r_pad + 1, live, np.int32)
+    qsl[0] = 0
+    qsl[1: n + 1] = np.cumsum(qlens)
+    if decode_only:
+        qsl = np.arange(r_pad + 1, dtype=np.int32)
+    owner = np.zeros(t_pad, np.int32)
+    for i in range(n):
+        owner[qsl[i]: qsl[i + 1]] = i
+    rows = np.full(r_pad, slots, np.int32)
+    rows[:n] = rng.permutation(slots)[:n]
+    bf16 = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    xs, taps = [bf16(t_pad, pj) for _ in range(3)], [bf16(taps_n, pj) for _ in range(3)]
+    stack_host = np.asarray(bf16(layers, slots + 1, taps_n - 1, 3 * heads, d))
+    rest = (jnp.asarray(rows), jnp.zeros(r_pad, bool).at[n - 1].set(len(qlens) > 1 and not decode_only),
+            jnp.asarray(owner), jnp.asarray(qsl), jnp.int32(n))
+
+    def kernel(xs, stack, layer, slots, fresh, owner, qsl, n):
+        return kda.kda_short_conv(xs, stack, taps, layer, slots, fresh, owner, qsl, n,
+                                  decode_only=decode_only)
+
+    def xla(xs, stack, layer, slots, fresh, owner, qsl, n):
+        r = slots.shape[0]
+        tail = jnp.where(fresh[:, None, None], 0, kda.read_slot_rows(
+            stack, layer, slots).reshape(r, taps_n - 1, 3 * pj))
+        y, new = kda.ragged_short_conv(
+            jnp.concatenate(xs, -1), tail, jnp.concatenate(taps, -1), owner, qsl,
+            decode_only=decode_only)
+        return y, kda.write_slot_rows(stack, layer, slots, new.reshape(r, -1), n)
+
+    def scope(y):
+        """What ``_kda_mixer`` does with the convolution's output."""
+        y = jax.nn.silu(y).reshape(t_pad, 3, heads, d)
+        return kda.l2norm(y[:, 0]) * d ** -0.5, kda.l2norm(y[:, 1]), y[:, 2]
+
+    def program(form, with_scope):
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def run(xs, stack, *rest):
+            def one(stack, layer):
+                mine = [x + layer.astype(x.dtype) for x in xs]
+                y, stack = form(mine, stack, layer, *rest)
+                return stack, scope(y) if with_scope else y
+
+            stack, outs = jax.lax.scan(one, stack, jnp.arange(layers, dtype=jnp.int32))
+            return jax.tree.map(lambda o: o[-1], outs), stack
+
+        return run
+
+    point, got = dict(case=name, rows=n, tokens=live, device=jax.devices()[0].device_kind), {}
+    for label, form, shape in (("kernel", kernel, stack_host.shape),
+                               ("xla", xla, (layers, slots + 1, -1))):
+        for with_scope in (False, True):
+            run = program(form, with_scope)
+            out, stack = jax.block_until_ready(
+                run(xs, jnp.asarray(stack_host.reshape(shape)), *rest))
+            if not with_scope:
+                got[label] = (np.asarray(out).reshape(t_pad, -1)[:live],
+                              np.asarray(stack, np.float32).reshape(layers, slots + 1, -1))
+            point[label + ("_scope_ms" if with_scope else "_ms")] = least_ms(
+                lambda st: run(xs, st, *rest)[1], stack)
+    err = float(np.abs(got["kernel"][0] - got["xla"][0]).max())
+    tails_equal = bool(np.array_equal(got["kernel"][1], got["xla"][1]))
+    least = layers * (live * 3 * pj * (2 + 4) + n * 2 * (taps_n - 1) * 3 * pj * 2)
+    point.update(max_abs_err=err, tails_equal=tails_equal, least_bytes=least)
+    if hbm_bytes_per_s():
+        point["kernel_share_of_hbm"] = least / (point["kernel_ms"] * 1e-3) / hbm_bytes_per_s()
+    print(json.dumps(point), flush=True)
+    return not (err < 1e-4 and tails_equal)
+
+
 def reference(c, layer):
     slots = np.asarray(c["slots"])
     h0 = np.where(np.asarray(c["fresh"])[:, None, None, None], 0,
@@ -211,6 +319,9 @@ def main():
         kda.CHUNK, kda.SUB = 8, 4
     bad = 0
     only = set(sys.argv[1].split(",")) if len(sys.argv) > 1 else None  # case names
+    for name, *shape, sizes in conv_cases(smoke):
+        if not only or name in only:
+            bad += conv_point(np.random.default_rng(1), name, *shape, **sizes)
     for c in cases(np.random.default_rng(0), smoke):
         if only and c["name"] not in only:
             continue

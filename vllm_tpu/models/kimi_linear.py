@@ -16,8 +16,10 @@ one shared expert.
 Cache contract: the hybrid one of ``models/qwen3_next.py``. Pages of
 latent rows for the attention layers, and per-request slots
 (``md.state_slots``) for the KDA layers' state: ``kda [Lk, S + 1, H, dk,
-dv]`` float32 and ``conv [Lk, S + 1, (K - 1) * 3 * H * dk]`` in the served
-dtype, the last slot the padding rows' scratch.
+dv]`` float32 and ``conv [Lk, S + 1, K - 1, 3 * H, dk]`` in the served
+dtype (a slot's K - 1 last inputs, a token the heads of q, k and v: whole
+tiles, which ``ops/kda.py::kda_short_conv`` reads and writes in place by
+one DMA a live row), the last slot the padding rows' scratch.
 
 Expert share. ``config.json`` may carry ``ep_size`` and ``ep_rank``:
 ``num_experts`` is then the count HELD here, the router keeps ``ep_size *
@@ -61,13 +63,7 @@ from vllm_tpu.layers.moe import fused_experts, fused_experts_of_layer
 from vllm_tpu.logger import init_logger
 from vllm_tpu.models.deepseek import latent_attention, select_experts_grouped
 from vllm_tpu.ops.attention import AttentionMetadata
-from vllm_tpu.ops.kda import (
-    kda_attention,
-    l2norm,
-    ragged_short_conv,
-    read_slot_rows,
-    write_slot_rows,
-)
+from vllm_tpu.ops.kda import kda_attention, kda_short_conv, l2norm
 from vllm_tpu.ops.mla_attention import mla_kv_cache_shape
 
 logger = init_logger(__name__)
@@ -514,30 +510,21 @@ class KimiLinearForCausalLM:
         Hk, dk, pj = self.kda_heads, self.kda_dim, self.kda_proj
         slots = md.state_slots
         with jax.named_scope("kda_proj"):
-            qkv = jnp.concatenate(
-                [h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]], axis=-1
-            )  # [T, 3 * pj]
+            qkv = (h @ lp["wq"], h @ lp["wk"], h @ lp["wv"])  # [T, pj] each
             decay_in = (h @ lp["f_a"]) @ lp["f_b"]
             gate_in = (h @ lp["g_a"]) @ lp["g_b"] + lp["g_bias"]
             beta = jax.nn.sigmoid((h @ lp["b_proj"]).astype(jnp.float32))
         with jax.named_scope("kda_conv"):
-            r = slots.shape[0]
-            tail = jnp.where(
-                fresh[:, None, None], 0,
-                read_slot_rows(cache["conv"], g_li, slots).reshape(
-                    r, -1, self.conv_dim),
-            )
-            taps = jnp.concatenate(
-                [lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=-1)
-            y, new_tail = ragged_short_conv(
-                qkv, tail, taps, tok_req, qsl, decode_only=md.decode_only)
-            y = jax.nn.silu(y)
-            cache = {**cache, "conv": write_slot_rows(
-                cache["conv"], g_li, slots, new_tail.reshape(r, -1),
-                md.num_seqs[0])}
-            q = l2norm(y[:, :pj].reshape(t, Hk, dk)) * dk ** -0.5
-            k = l2norm(y[:, pj: 2 * pj].reshape(t, Hk, dk))
-            v = y[:, 2 * pj:].reshape(t, Hk, dk)
+            y, conv = kda_short_conv(
+                qkv, cache["conv"],
+                (lp["conv_q"], lp["conv_k"], lp["conv_v"]), g_li, slots,
+                fresh, tok_req, qsl, md.num_seqs[0],
+                decode_only=md.decode_only)
+            cache = {**cache, "conv": conv}
+            y = jax.nn.silu(y)  # [T, 3 * Hk, dk]: q's heads, k's, v's
+            q = l2norm(y[:, :Hk]) * dk ** -0.5
+            k = l2norm(y[:, Hk: 2 * Hk])
+            v = y[:, 2 * Hk:]
             a = -jnp.exp(lp["a_log"].astype(jnp.float32))[:, None] * (
                 jax.nn.softplus(
                     decay_in.astype(jnp.float32)
@@ -743,10 +730,11 @@ class KimiLinearForCausalLM:
                 ),
                 dtype,
             ),
-            # A slot's K-1 rows of 3 * H * dk side by side on the lanes: an
-            # axis of 3 before the last would be padded to a sublane tile.
+            # A slot is K-1 tokens of q's, k's and v's heads, [3 * H, dk]
+            # each: whole tiles, so one DMA moves it (ops/kda.py).
             "conv": jnp.zeros(
-                (lk, s, (self.conv_kernel - 1) * self.conv_dim), self.dtype
+                (lk, s, self.conv_kernel - 1, 3 * self.kda_heads,
+                 self.kda_dim), self.dtype
             ),
             "kda": jnp.zeros(
                 (lk, s, self.kda_heads, self.kda_dim, self.kda_dim),
@@ -766,6 +754,6 @@ class KimiLinearForCausalLM:
     def kv_cache_sharding(self, model_axis: str = "tp") -> dict:
         return {
             "paged": P(None, None, None, None),
-            "conv": P(None, None, None),
+            "conv": P(None, None, None, None, None),
             "kda": P(None, None, None, None, None),
         }

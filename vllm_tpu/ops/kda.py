@@ -36,6 +36,17 @@ token). Two forms here, both equal to that recurrence:
   state for all of a round's places at once, then a scan over them with
   four small matmuls a chunk, states and tokens gathered and scattered.
 
+The short convolution. Before the rule, q, k and v each pass a depthwise
+causal convolution of K taps whose last K - 1 inputs are per-request state
+too, a slot of ``[K - 1, G, dk]`` in the served dtype (G the heads of q,
+k and v together). ``kda_short_conv``: on the TPU ONE Pallas kernel a
+layer (``kda_conv_kernel``) for both kinds of step, which reads the tail of
+a row that begins in the step from its slot, writes the tail of a row that
+ends in it back in place, and touches no other slot; off the TPU (and as
+the tests' oracle) ``read_slot_rows`` -> ``ragged_short_conv`` ->
+``write_slot_rows``, one-hot matmuls over the whole layer of slots and a
+gather a token and a tap.
+
 Exponents. Every ``exp`` here is of a difference of cumulated decays
 ``b_i - b_j`` with ``i >= j``, or of ``b_i`` itself, so of a number
 ``<= 0``: a chunk is cut in blocks of ``SUB`` tokens, a pair inside one
@@ -148,19 +159,314 @@ def write_slot_rows(
     return lax.dynamic_update_index_in_dim(stack, new, layer, 0)
 
 
-def _pallas_form(state: jnp.ndarray) -> tuple[bool, bool]:
-    """``(kernel, interpret)``: both forms run as Pallas kernels on a TPU
-    (interpreted where ``VLLM_TPU_PALLAS_INTERPRET`` asks off it) over a
-    float32 state, and as XLA otherwise or under
-    ``VLLM_TPU_DISABLE_PALLAS``."""
+def _pallas_form(state: jnp.ndarray | None = None) -> tuple[bool, bool]:
+    """``(kernel, interpret)``: the forms run as Pallas kernels on a TPU
+    (interpreted where ``VLLM_TPU_PALLAS_INTERPRET`` asks off it), and as
+    XLA otherwise or under ``VLLM_TPU_DISABLE_PALLAS``. ``state``: the
+    state stack of the two state kernels, which compute in float32 and
+    take no other; the convolution's stack is in the served dtype and
+    passes none."""
     from vllm_tpu import envs
 
     on_tpu = jax.default_backend() == "tpu"
     interpret = bool(envs.VLLM_TPU_PALLAS_INTERPRET) and not on_tpu
-    kernel = state.dtype == jnp.float32 and (on_tpu or interpret) and not (
-        envs.VLLM_TPU_DISABLE_PALLAS
-    )
+    kernel = (on_tpu or interpret) and not envs.VLLM_TPU_DISABLE_PALLAS
+    if state is not None:
+        kernel = kernel and state.dtype == jnp.float32
     return kernel, interpret
+
+
+# ----------------------------------------------------------------------
+# The short convolution over the live rows' slots
+# ----------------------------------------------------------------------
+
+CONV_TILE = 32  # tokens a step of the kernel's grid
+
+
+def _conv_kernel(
+    layer_ref, total_ref, qsl_ref, slots_ref, fresh_ref, row_ref,  # prefetch
+    *refs,
+    part: int,
+):
+    # The inputs [T, g, dk], their heads side by side making G; then:
+    (*xs_hbm,
+     w_ref,  # [K, G, dk] f32
+     stack_hbm,  # [Lk, S + 1, K - 1, G, dk]; a slot is read before it is written
+     y_ref,  # [tile, G, dk] f32: this step's tokens
+     stack_out_hbm,  # the same stack
+     win,  # [2, K - 1 + tile + (K - 1) * tile, G, dk]: the K - 1 tokens
+     # before the step's, the step's, then the tails of the rows that begin in it
+     out,  # [2, (K - 1) * tile, G, dk]: the tails of the rows that end in it
+     x_sem, in_sem, out_sem) = refs
+    tile, g = y_ref.shape[:2]
+    km1 = w_ref.shape[0] - 1
+    first_tail = km1 + tile
+    i = pl.program_id(0)
+    total = total_ref[0]
+    n_tiles = (total + tile - 1) // tile
+    layer = layer_ref[0]
+
+    def rows_of(step):
+        """The step's first token, and the rows of its first and last."""
+        t0 = step * tile
+        return t0, row_ref[t0], row_ref[jnp.minimum(t0 + tile, total) - 1]
+
+    def for_rows(step, act, wants):
+        """``act(row, its place among the step's rows)`` for the step's
+        rows that ``wants(row, t0)``."""
+        t0, lo, hi = rows_of(step)
+
+        def one(k, carry):
+            r = jnp.minimum(lo + k, hi)
+
+            @pl.when((lo + k <= hi) & wants(r, t0))
+            def _():
+                act(r, k)
+
+            return carry
+
+        lax.fori_loop(0, tile, one, 0)
+
+    # A row's tail is wanted where a token of its first K - 1 lies in the
+    # step, and written where its last token does (a live row holds a
+    # token: one without would keep its tail).
+    begins = lambda r, t0: qsl_ref[r] + km1 > t0
+    ends = lambda r, t0: (qsl_ref[r + 1] <= t0 + tile) & (qsl_ref[r + 1] > qsl_ref[r])
+
+    def tail_in(r, k, buf):
+        return pltpu.make_async_copy(
+            stack_hbm.at[layer, slots_ref[r]],
+            win.at[buf, pl.ds(first_tail + km1 * k, km1)], in_sem.at[buf])
+
+    def tail_out(r, k, buf):
+        return pltpu.make_async_copy(
+            out.at[buf, pl.ds(km1 * k, km1)],
+            stack_out_hbm.at[layer, slots_ref[r]], out_sem.at[buf])
+
+    def tokens_in(step, buf):
+        copies, g0 = [], 0
+        for n, x_hbm in enumerate(xs_hbm):
+            copies.append(pltpu.make_async_copy(
+                x_hbm.at[pl.ds(step * tile, tile)],
+                win.at[buf, pl.ds(km1, tile), pl.ds(g0, x_hbm.shape[1])],
+                x_sem.at[buf, n]))
+            g0 += x_hbm.shape[1]
+        return copies
+
+    def fetch(step, buf):
+        for cp in tokens_in(step, buf):
+            cp.start()
+
+        def tail(r, k):
+            @pl.when(fresh_ref[r] != 0)
+            def _():  # the request starts: zero, without a read
+                win[buf, pl.ds(first_tail + km1 * k, km1)] = jnp.zeros(
+                    (km1,) + win.shape[2:], win.dtype)
+
+            @pl.when(fresh_ref[r] == 0)
+            def _():
+                tail_in(r, k, buf).start()
+
+        for_rows(step, tail, begins)
+
+    @pl.when(i < n_tiles)
+    def _():
+        cur = i % 2
+
+        @pl.when(i == 0)
+        def _():
+            fetch(0, 0)
+
+        @pl.when(i + 1 < n_tiles)
+        def _():
+            fetch(i + 1, 1 - cur)
+
+        for cp in tokens_in(i, cur):
+            cp.wait()
+        for_rows(i, lambda r, k: tail_in(r, k, cur).wait(),
+                 lambda r, t0: begins(r, t0) & (fresh_ref[r] == 0))
+
+        t0, lo, _ = rows_of(i)
+        for g0 in range(0, g, part):
+            gs = slice(g0, g0 + part)
+            w = [w_ref[j, gs, :] for j in range(km1 + 1)]
+
+            def token(ii, carry, gs=gs, w=w):
+                t = t0 + ii
+
+                @pl.when(t < total)
+                def _():
+                    r = row_ref[t]
+                    pos = t - qsl_ref[r]
+                    tail = first_tail + km1 * (r - lo)
+                    here = win[cur, km1 + ii, gs, :]
+                    y = here.astype(jnp.float32) * w[km1]
+                    back = []  # the input s tokens back, s = 1 ... K - 1
+                    for s in range(1, km1 + 1):
+                        back.append(win[
+                            cur,
+                            jnp.where(pos >= s, km1 + ii - s, tail + km1 - s + pos),
+                            gs, :])
+                        y = y + back[-1].astype(jnp.float32) * w[km1 - s]
+                    y_ref[ii, gs, :] = y
+
+                    @pl.when(t + 1 == qsl_ref[r + 1])
+                    def _():  # the row's last token: its K - 1 last inputs
+                        place = km1 * (r - lo)
+                        for j, v in enumerate(back[-2::-1] + [here]):
+                            out[cur, place + j, gs, :] = v
+
+                return carry
+
+            lax.fori_loop(0, tile, token, 0)
+
+        # The next step's tokens follow these.
+        win[1 - cur, pl.ds(0, km1)] = win[cur, pl.ds(tile, km1)]
+
+        @pl.when(i > 0)
+        def _():
+            for_rows(i - 1, lambda r, k: tail_out(r, k, 1 - cur).wait(), ends)
+
+        for_rows(i, lambda r, k: tail_out(r, k, cur).start(), ends)
+
+        @pl.when(i == n_tiles - 1)
+        def _():
+            for_rows(i, lambda r, k: tail_out(r, k, cur).wait(), ends)
+
+
+def _live_tokens(query_start_loc, n_live):
+    """``query_start_loc[n_live]``, the step's live tokens, without a gather."""
+    at = jnp.arange(query_start_loc.shape[0]) == n_live
+    return jnp.sum(jnp.where(at, query_start_loc, 0))
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _conv_pallas(xs, stack, weights, layer, slots, fresh, query_start_loc,
+                 n_live, *, tile: int, interpret: bool = False):
+    t = xs[0].shape[0]
+    km1, g, dk = stack.shape[2:]
+    r = slots.shape[0]
+    i32 = lambda v: v.astype(jnp.int32)
+    # A step of the grid is ``tile`` whole tokens, a token [heads, dk].
+    tp = -(-t // tile) * tile
+    xs = [jnp.pad(x, ((0, tp - t), (0, 0))).reshape(tp, -1, dk) for x in xs]
+    w = jnp.concatenate(weights, axis=-1).astype(jnp.float32)
+    qsl = i32(query_start_loc)
+    total = _live_tokens(qsl, n_live)
+    ts = jnp.arange(tp, dtype=jnp.int32)
+    # The row of each token: the live rows that end at or before it.
+    row = jnp.sum(
+        (qsl[None, 1:] <= ts[:, None]) & (jnp.arange(r) < n_live)[None, :],
+        axis=1, dtype=jnp.int32)
+    # The heads in hand at once: [32, dk] f32 a tap.
+    part = 32 if all(x.shape[1] % 32 == 0 for x in xs) else g
+
+    def last_tile(i, layer, total, *_):
+        return (jnp.clip((total[0] + tile - 1) // tile - 1, 0, i), 0, 0)
+
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    y, stack = pl.pallas_call(
+        functools.partial(_conv_kernel, part=part),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            in_specs=[anywhere] * len(xs) + [
+                pl.BlockSpec((km1 + 1, g, dk), lambda i, *_: (0, 0, 0)),
+                anywhere,
+            ],
+            out_specs=[pl.BlockSpec((tile, g, dk), last_tile), anywhere],
+            grid=(tp // tile,),
+            scratch_shapes=[
+                pltpu.VMEM((2, km1 + tile + km1 * tile, g, dk), stack.dtype),
+                pltpu.VMEM((2, km1 * tile, g, dk), stack.dtype),
+                pltpu.SemaphoreType.DMA((2, len(xs))),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((tp, g, dk), jnp.float32),
+            jax.ShapeDtypeStruct(stack.shape, stack.dtype),
+        ],
+        # After the six prefetched and the inputs and taps: the stack.
+        input_output_aliases={6 + len(xs) + 1: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=48 << 20,
+        ),
+        name="kda_conv_kernel",
+        interpret=interpret,
+    )(
+        i32(layer).reshape(1), i32(total).reshape(1), qsl, i32(slots),
+        i32(fresh), row, *xs, w.reshape(km1 + 1, g, dk), stack,
+    )
+    # The tokens past the live ones were never written.
+    return jnp.where((ts < total)[:t, None, None], y[:t], 0.0), stack
+
+
+def kda_short_conv(
+    xs: tuple[jnp.ndarray, ...],  # each [T, C_i]: this step's inputs, side
+    # by side the C = G * dk channels
+    stack: jnp.ndarray,  # [Lk, S + 1, K - 1, G, dk] in their dtype; slot S is scratch
+    weights: tuple[jnp.ndarray, ...],  # each [K, C_i]; tap K-1 is the current token
+    layer: jnp.ndarray,  # scalar i32
+    slots: jnp.ndarray,  # [R] i32, distinct over the live rows
+    fresh: jnp.ndarray,  # [R] bool: the row starts its request
+    token_req_idx: jnp.ndarray,  # [T]
+    query_start_loc: jnp.ndarray,  # [R+1]
+    num_seqs: jnp.ndarray,  # scalar i32: rows [0, num_seqs) are live
+    *,
+    decode_only: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The depthwise causal convolution of a layer over each live row's
+    segment, continued from the row's slot and stored back to it: ``(y [T,
+    G, dk] f32, stack)``; ``y`` is 0 past the live tokens.
+
+    On the TPU one Pallas kernel (``kda_conv_kernel``) over the live
+    tokens, ``CONV_TILE`` a step of its grid. A token is ``[G, dk]``, whole
+    tiles, so the K - 1 tokens before it are a step of the leading axis and
+    nothing is shifted or gathered: the tokens come in by one DMA an input
+    a step, the tail of a row that begins in the step by one DMA from its
+    slot (zeroed without a read where the request starts), and the K - 1
+    last inputs of a row that ends in it (tail ++ its tokens, so a row
+    shorter than K - 1 keeps part of its old tail) go back by one DMA to
+    the same slot, the stack aliased. Rows past ``num_seqs``, the scratch
+    slot and the steps past the last live token move nothing. A
+    decode-only step is the case of one token a row: the same kernel.
+    Mosaic takes no single row of a tiled array, which is why a slot is
+    ``[K - 1, G, dk]`` and not ``(K - 1) * C`` lanes of a row, and the
+    inputs are taken apart because joining them is a pass over them.
+    ``write_slot_rows`` stores a number that is not finite as 0 because
+    its one-hot product would carry it to other rows; a row written by its
+    own DMA has no such product, so here it is stored as it is: the same
+    stored values for finite inputs.
+
+    Off the TPU (and as the tests' oracle) ``read_slot_rows`` ->
+    ``ragged_short_conv`` -> ``write_slot_rows`` over the stack seen as
+    ``[Lk, S + 1, (K - 1) * C]`` (``decode_only``, static, picks the form of
+    ``ragged_short_conv`` that gathers nothing; on a TPU, under
+    ``VLLM_TPU_DISABLE_PALLAS``, that view is a copy of the stack each way:
+    a form for finding faults, not for serving)."""
+    t = xs[0].shape[0]
+    lk, s1, km1, g, dk = stack.shape
+    n_live = jnp.asarray(num_seqs, jnp.int32).reshape(())
+    layer = jnp.asarray(layer, jnp.int32)
+    kernel, interpret = _pallas_form()
+    if kernel and all(x.dtype == stack.dtype for x in xs):
+        return _conv_pallas(
+            tuple(xs), stack, tuple(weights), layer, slots, fresh,
+            query_start_loc, n_live, tile=CONV_TILE, interpret=interpret)
+    r = slots.shape[0]
+    flat = stack.reshape(lk, s1, km1 * g * dk)
+    tail = jnp.where(
+        fresh[:, None, None], 0,
+        read_slot_rows(flat, layer, slots).reshape(r, km1, g * dk))
+    y, new_tail = ragged_short_conv(
+        jnp.concatenate(xs, axis=-1), tail, jnp.concatenate(weights, axis=-1),
+        token_req_idx, query_start_loc, decode_only=decode_only)
+    flat = write_slot_rows(flat, layer, slots, new_tail.reshape(r, -1), n_live)
+    live = jnp.arange(t) < _live_tokens(query_start_loc, n_live)
+    return (jnp.where(live[:, None, None], y.reshape(t, g, dk), 0.0),
+            flat.reshape(stack.shape))
 
 
 # ----------------------------------------------------------------------
