@@ -44,6 +44,9 @@ def grpc_addr(tmp_path_factory):
             ))
             ready.set()
             await server.wait_for_termination()
+            # Or its loop thread lives on in this worker, and writes its
+            # spans into the trace of whichever later test turns tracing on.
+            engine.shutdown()
 
         asyncio.run(run())
 
@@ -53,6 +56,7 @@ def grpc_addr(tmp_path_factory):
     yield addr
     if stop:
         stop[0]().result(timeout=10)
+    t.join(timeout=30)
 
 
 def _ident(b: bytes) -> bytes:

@@ -120,7 +120,18 @@ def test_trace_id_across_two_processes_and_merge(ckpt, tmp_path,
     process with VLLM_TPU_TRACE_DIR set, then merge the two per-process
     trace files — one request's trace id must link spans from BOTH pids,
     and the merged object must be valid chrome-trace JSON with a flow."""
+    import gc
+
     import vllm_tpu.tracing as tracing
+    from vllm_tpu.engine.async_llm import AsyncLLM
+
+    # An in-process engine loop that an earlier test of this worker left
+    # running would write its ``wait_input`` spans (category ``engine``)
+    # into this process's file, and the merge would name the frontend an
+    # engine core.
+    for obj in gc.get_objects():
+        if type(obj) is AsyncLLM and getattr(obj, "_thread", None) is not None:
+            obj.shutdown()
 
     monkeypatch.setenv("VLLM_TPU_TRACE_DIR", str(tmp_path))
     # The module caches the enabled decision; reset for this test.

@@ -61,7 +61,16 @@ def exposed() -> dict[str, set[tuple[str, str]]]:
             attn_ragged_pairs={"live": 26, "computed": 100},
             diffusion_passes_by_kind={"denoise": 2, "store": 1},
             diffusion_committed_tokens=3, moe_assignments=5,
-            moe_experts_hit=7, **phases),
+            moe_experts_hit=7,
+            # The start and compiling (PR 39): once, and when they moved.
+            step_first_use_stage_s={"trace": 3.0, "lower": 1.5,
+                                    "backend": 2.5, "run": 0.5},
+            compile_seconds={"trace": 4.0, "lower": 2.0, "backend": 9.0},
+            compile_cache={"hit": 5, "miss": 0},
+            startup={"pid": 1, "created": 0.0, "phases": dict.fromkeys(
+                ("import", "init_device", "load_model", "profile",
+                 "kv_cache", "precompile", "engine", "frontend"), 1.0)},
+            **phases),
         IterationStats(num_generation_tokens=12, num_prompt_tokens=7,
                        ttfts=[0.05], output_s=0.0005))
     out: dict[str, set[tuple[str, str]]] = {}

@@ -87,6 +87,12 @@ REQUIRED_STEP_METRICS = {
     "vllm:attn_ragged_pairs_total",
     "vllm:step_bucket_compiles",
     "vllm:step_first_use_seconds_total",
+    # The start and compiling (README "The start and compiling"): the
+    # benchmark's five setup_* readers read these.
+    "vllm:step_first_use_stage_seconds_total",
+    "vllm:compile_seconds_total",
+    "vllm:compile_cache_total",
+    "vllm:startup_seconds",
 }
 # Documented in the README ("Generation by diffusion over blocks"); the
 # benchmark's readers diffusion_tokens_per_pass, moe_experts_hit_share,

@@ -263,6 +263,15 @@ class SchedulerStats:
     # them out), a q head a layer, cumulative, from the runner.
     attn_ragged_pairs: dict[str, int] = field(default_factory=dict)
     step_first_use_s: float = 0.0
+    # The same seconds by stage ("trace", "lower", "backend", "run"), and
+    # the process's compile counters from JAX's own events (seconds by
+    # stage, persistent-cache results): cumulative, and attached only to
+    # the snapshot after they moved (None otherwise). The start's phases
+    # (``tracing.StartupTimeline.snapshot``) ride the first snapshot alone.
+    step_first_use_stage_s: dict[str, float] | None = None
+    compile_seconds: dict[str, float] | None = None
+    compile_cache: dict[str, int] | None = None
+    startup: dict | None = None
     # Generation by diffusion over blocks (cumulative, from the runner):
     # row-passes by kind and positions committed by denoising passes.
     # Mixture of experts (cumulative, from the runner): token-expert pairs

@@ -645,6 +645,11 @@ class MPClient(_ZMQClientBase):
         self._output.bind(output_addr)
         self._input = self._ctx.socket(zmq.PUSH)
         self._input.bind(input_addr)
+        from vllm_tpu.tracing import startup
+
+        # From the spawn to READY the engine's process keeps the start's
+        # timeline, from its own creation on: not this process's time.
+        startup.begin(None)
         self._proc = self._spawn_proc(input_addr)
         self._procs = [self._proc]
         self._inputs = [self._input]
@@ -659,6 +664,7 @@ class MPClient(_ZMQClientBase):
         # Block until the engine proc finishes init (model load + KV
         # sizing + warm-up can take minutes on first compile).
         frames = self._recv(timeout_ms=int(ready_timeout_s * 1000))
+        startup.end()
         if frames is None or frames[0] != core_proc.MSG_READY:
             raise EngineDeadError(
                 "engine core process failed to initialize"

@@ -216,7 +216,15 @@ def run_engine_core(config_bytes: bytes, input_addr: str,
     core = None
     try:
         config = pickle.loads(config_bytes)
+        # The start's timeline of THIS process: `import` is its creation
+        # to here (the interpreter, the modules above, the sockets), then
+        # the engine's phases; READY ends it. The phases cross to the
+        # front end with the first stats snapshot.
+        from vllm_tpu.tracing import startup
+
+        startup.imported()
         core = EngineCore(config)
+        startup.ready()
         install_watchdog_escalation(core)
         from vllm_tpu.versioning import SCHEMA_VERSION
         for sock in outs:

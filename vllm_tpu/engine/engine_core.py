@@ -18,6 +18,8 @@ from vllm_tpu.core.scheduler import Scheduler
 from vllm_tpu.engine.executor import Executor
 from vllm_tpu.logger import init_logger
 from vllm_tpu.tracing import (
+    compiles,
+    startup,
     trace_async_begin,
     trace_async_end,
     trace_enabled,
@@ -32,6 +34,13 @@ logger = init_logger(__name__)
 
 class EngineCore:
     def __init__(self, config: EngineConfig, executor_class: type[Executor] | None = None) -> None:
+        # `engine` on the start's timeline: all of this constructor less
+        # the worker's phases inside `executor.initialize()`.
+        with startup.phase("engine") as note:
+            self._init(config, executor_class)
+            note.append(f"{config.cache_config.num_gpu_blocks} KV blocks")
+
+    def _init(self, config: EngineConfig, executor_class: type[Executor] | None) -> None:
         self.config = config.finalize().resolve_generation()
         executor_class = executor_class or Executor.get_class(config)
         self.executor = executor_class(config)
@@ -71,11 +80,10 @@ class EngineCore:
         self._last_batch: tuple[int, int] = (0, 0)
         self._last_step_end: float | None = None
         self._step_interval_s = 0.0
-        # Per-finalized-step (interval_s, max tokens emitted by any one
-        # request) samples for the bench's goodput/ITL scoring: a step
-        # that emits k tokens for a request spreads its interval over k
-        # inter-token gaps. Bounded; drained by drain_itl_samples().
-        self._itl_samples: deque[tuple[float, int]] = deque(maxlen=4096)
+        # What of the start and of compiling has reached a stats snapshot:
+        # the start's phases go once, the compile counters when they moved.
+        self._startup_sent = False
+        self._compile_mark: tuple[int, int] | None = None
         # Request lifecycle phase per in-flight request, keyed by req id:
         # (trace_id, "queue" | "prefill" | "decode"). Only populated while
         # tracing is enabled — the async b/e span bookkeeping is pure
@@ -227,13 +235,6 @@ class EngineCore:
             len(self.scheduler.running) + len(self._inflight),
         )
 
-    def drain_itl_samples(self) -> list[tuple[float, int]]:
-        """Drain the (step_interval_s, max tokens emitted per request)
-        samples collected since the last call (bench goodput scoring)."""
-        out = list(self._itl_samples)
-        self._itl_samples.clear()
-        return out
-
     def execute_dummy_batch(self) -> None:
         """One no-request device step, so idle DP ranks keep participating
         in cross-rank collectives during a wave (reference: ``core.py:731``
@@ -349,11 +350,6 @@ class EngineCore:
         now = time.monotonic()
         if self._last_step_end is not None:
             self._step_interval_s = now - self._last_step_end
-            burst = max(
-                (len(o.new_token_ids) for o in outputs.outputs), default=0
-            )
-            if burst > 0:
-                self._itl_samples.append((self._step_interval_s, burst))
         self._last_step_end = now
         self._attach_engine_stats(outputs)
         for o in outputs.outputs:
@@ -403,6 +399,9 @@ class EngineCore:
         if stats is None:
             return
         stats.pipeline_stall_s = self._stall_s
+        if not self._startup_sent:
+            self._startup_sent = True
+            stats.startup = startup.snapshot()
         # Drain the per-phase step durations accumulated since the last
         # snapshot into this one (exactly-once export).
         for phase in STEP_PHASES:
@@ -440,6 +439,15 @@ class EngineCore:
             stats.state_slots_in_use = len(
                 getattr(runner, "_state_slot_of", ()))
             stats.step_first_use_s = getattr(runner, "first_use_s", 0.0)
+            # Compiling moves these and nothing else does: one compare a
+            # step, the copies only after a first use or a compile.
+            mark = (compiles.heard, stats.bucket_compiles)
+            if mark != self._compile_mark:
+                self._compile_mark = mark
+                stats.compile_seconds = dict(compiles.seconds)
+                stats.compile_cache = dict(compiles.cache)
+                stats.step_first_use_stage_s = dict(
+                    getattr(runner, "first_use_stage_s", {}))
             stats.prep_fallback_rows = getattr(
                 runner, "prep_fallback_rows", 0
             )

@@ -285,6 +285,13 @@ def _run_upgrade(args: argparse.Namespace) -> None:
 
 
 def main(argv: list[str] | None = None) -> None:
+    # The start's timeline (tracing.py): `import` ends here, and all the
+    # front end does from here to the bound socket is `frontend`, less
+    # the engine's phases inside it. `run_server` closes it.
+    from vllm_tpu.tracing import startup
+
+    startup.imported()
+    startup.begin("frontend")
     parser = argparse.ArgumentParser(prog="vllm-tpu")
     sub = parser.add_subparsers(required=True)
     _add_serve(sub)

@@ -1134,6 +1134,10 @@ def run_server(engine_args, host: str = "0.0.0.0", port: int = 8000,
         await runner.setup()
         site = web.TCPSite(runner, host, port)
         await site.start()
+        # The socket is bound and /health answers: the start is over.
+        from vllm_tpu.tracing import startup
+
+        metrics.record_startup(startup.ready())
         loop = asyncio.get_running_loop()
         stop = asyncio.Event()
         for sig in (signal.SIGTERM, signal.SIGINT):

@@ -301,6 +301,32 @@ class PrometheusRegistry:
             "Seconds spent in first uses of a step program (traced, "
             "lowered, compiled or loaded inside the step's dispatch); "
             "vllm:step_bucket_compiles counts them")
+        self.first_use_stage_seconds = LabeledCounter(
+            "vllm:step_first_use_stage_seconds_total",
+            "The seconds of vllm:step_first_use_seconds_total by stage: "
+            "trace and lower (Python), backend (XLA's compile or the load "
+            "from the persistent cache), run (the rest of the call)",
+            "stage")
+        # What JAX itself reports of compiling, for the whole engine
+        # process (tracing.CompileEvents): the memory profile's compile
+        # and every small jit beside the step programs.
+        self.compile_seconds = LabeledCounter(
+            "vllm:compile_seconds_total",
+            "Seconds JAX spent tracing (trace), lowering (lower) and in the "
+            "backend (XLA's compile or the load from the persistent "
+            "cache), by stage, from jax.monitoring", "stage")
+        self.compile_cache = LabeledCounter(
+            "vllm:compile_cache_total",
+            "Programs the persistent compilation cache had (hit) or got "
+            "(miss), from jax.monitoring", "result")
+        # The start, by phase (tracing.StartupTimeline): set once, when
+        # the server is ready and when an engine process's phases arrive
+        # with its first stats snapshot.
+        self.startup_seconds = LabeledGauge(
+            "vllm:startup_seconds",
+            "Seconds of the start by phase, process creation to a server "
+            "that answers; a phase's seconds are its own, less the phases "
+            "inside it, and the phases add up", "phase")
         # By kind of step: "decode" = the decode-only step that runs the
         # decode attention kernel, "mixed" = every other. Counted where
         # the runner builds the step.
@@ -728,6 +754,8 @@ class PrometheusRegistry:
             self.spec_acceptance_rate, self.spec_draft_len,
             self.spec_suspended, self.spec_suspensions,
             self.bucket_compiles, self.bucket_hits, self.first_use_seconds,
+            self.first_use_stage_seconds, self.compile_seconds,
+            self.compile_cache, self.startup_seconds,
             self.steps_by_kind, self.step_tokens_by_kind,
             self.attn_context_tokens, self.attn_ragged_pairs,
             self.pipeline_stall,
@@ -784,6 +812,32 @@ class PrometheusRegistry:
         self._last_sampler_kernel = 0
         self._last_sampler_fallback = 0
         self._last_decode_early_exits = 0
+        # pid -> the phases of that process's start; and when this
+        # process's own start was over (perf_counter's clock).
+        self._startup_by_pid: dict[int, dict[str, float]] = {}
+        self._startup_ready_at: float | None = None
+
+    def record_startup(self, snapshot: dict) -> None:
+        """This process's own start, when it is ready
+        (``StartupTimeline.ready()``)."""
+        self._startup_ready_at = time.perf_counter()
+        self._take_startup(snapshot)
+
+    def _take_startup(self, snapshot: dict) -> None:
+        """One process's start: this one's, or an engine process's with its
+        first stats snapshot. A phase reads the sum over the processes that
+        took part (the front end's ``import`` and the engine's follow each
+        other). A process created after this one was ready is a respawn,
+        not the start: left out."""
+        if (self._startup_ready_at is not None
+                and snapshot["created"] > self._startup_ready_at):
+            return
+        self._startup_by_pid[snapshot["pid"]] = snapshot["phases"]
+        for phases in self._startup_by_pid.values():
+            for phase in phases:
+                self.startup_seconds.set(phase, sum(
+                    p.get(phase, 0.0)
+                    for p in self._startup_by_pid.values()))
 
     # StatLoggerBase interface -----------------------------------------
 
@@ -848,6 +902,18 @@ class PrometheusRegistry:
                     self.step_duration.observe(phase, t)
             # Runner-side cumulative counters: ratchet, never assign.
             self.first_use_seconds.inc_to(s.step_first_use_s)
+            if s.compile_seconds is not None:
+                # The three ride together, and only after they moved.
+                for counter, moved in (
+                    (self.first_use_stage_seconds, s.step_first_use_stage_s),
+                    (self.compile_seconds, s.compile_seconds),
+                    (self.compile_cache, s.compile_cache),
+                ):
+                    for key, v in (moved or {}).items():
+                        counter.inc(key, 0.0)  # a sample also where it is 0
+                        counter.inc_to(key, float(v))
+            if s.startup is not None:
+                self._take_startup(s.startup)
             for counter, by_kind in (
                 (self.steps_by_kind, s.steps_by_kind),
                 (self.step_tokens_by_kind, s.step_tokens_by_kind),

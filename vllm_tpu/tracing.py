@@ -29,6 +29,16 @@ also opens a ``jax.profiler.TraceAnnotation`` named
 profiler's clock beside the device's operations. JAX is imported by
 ``start_profiler`` only: a process that never profiles (the frontend of
 the multiprocess client) never imports it here.
+
+Two more things sit on the same sinks (PR 39). ``startup`` is the start of
+this process as a timeline of phases (``StartupTimeline``): each phase a
+span of category ``startup`` and a number of seconds, the numbers exposed
+once as ``vllm:startup_seconds{phase}``. ``compiles`` listens to what JAX
+itself reports of tracing, lowering and compiling (``CompileEvents``;
+``jax.monitoring``, imported by ``listen`` only): cumulative seconds by
+stage, persistent-cache hits and misses, and a ``compile.<stage>`` span an
+event. JAX calls it only where something is traced, lowered or compiled,
+so a steady step never enters it.
 """
 
 from __future__ import annotations
@@ -40,6 +50,10 @@ import threading
 import time
 import uuid
 from contextlib import contextmanager
+
+from vllm_tpu.logger import init_logger
+
+logger = init_logger(__name__)
 
 _lock = threading.Lock()
 _file = None
@@ -134,6 +148,22 @@ def _base(name: str, category: str, ph: str, **attrs) -> dict:
     }
 
 
+def _emit_complete(name: str, category: str, t0_us: int, t1_us: int,
+                   attrs: dict) -> None:
+    """A complete (``ph: X``) event from ``t0_us`` to ``t1_us``,
+    microseconds on ``perf_counter``'s clock."""
+    _emit({
+        "name": name,
+        "cat": category,
+        "ph": "X",
+        "ts": t0_us,
+        "dur": t1_us - t0_us,
+        "pid": os.getpid(),
+        "tid": threading.get_ident() % 2**31,
+        "args": attrs,
+    })
+
+
 class ProfilerBusyError(RuntimeError):
     """A profile is already running (or none is, on a stop)."""
 
@@ -195,11 +225,14 @@ def trace_span(name: str, category: str = "engine", **attrs):
     """Complete-event span; no-op (near-zero cost) when both sinks are
     off. Chrome sink: ``name`` as given. Profiler sink (while a profile
     runs): ``vllm_tpu.<category>.<name>``; per-request spans (category
-    ``request``) stay out of it, one a step is what a gap is read by."""
+    ``request``) stay out of it, one a step is what a gap is read by.
+    Yields the chrome event's ``args`` (None when both sinks are off):
+    what the body adds there is written when the span closes. The
+    profiler's annotation is fixed when it opens."""
     annotate = _annotation if category != "request" else None
     chrome = trace_enabled()
     if annotate is None and not chrome:
-        yield
+        yield None
         return
     attrs = {k: v for k, v in attrs.items() if v is not None}
     span = None
@@ -208,22 +241,13 @@ def trace_span(name: str, category: str = "engine", **attrs):
         span.__enter__()
     t0 = time.perf_counter_ns() // 1000  # chrome trace wants microseconds
     try:
-        yield
+        yield attrs
     finally:
         if span is not None:
             span.__exit__(None, None, None)
         if chrome:
-            t1 = time.perf_counter_ns() // 1000
-            _emit({
-                "name": name,
-                "cat": category,
-                "ph": "X",
-                "ts": t0,
-                "dur": t1 - t0,
-                "pid": os.getpid(),
-                "tid": threading.get_ident() % 2**31,
-                "args": attrs,
-            })
+            _emit_complete(name, category, t0,
+                           time.perf_counter_ns() // 1000, attrs)
 
 
 def trace_instant(name: str, category: str = "request", **attrs) -> None:
@@ -260,3 +284,210 @@ def trace_async_end(name: str, trace_id: str | None,
     ev = _base(name, category, "e", trace_id=trace_id, **attrs)
     ev["id"] = trace_id
     _emit(ev)
+
+
+# ----------------------------------------------------------------------
+# The start's timeline
+# ----------------------------------------------------------------------
+
+def process_created() -> float:
+    """``perf_counter``'s reading at the moment this process was created:
+    its start time from ``/proc/self/stat`` (field 22, clock ticks since
+    boot) moved onto ``perf_counter``'s clock by way of the boot-time clock
+    read now. To a clock tick (10 ms); now, where ``/proc`` has none."""
+    now = time.perf_counter()
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # The command (field 2) may hold spaces: count from its ")".
+            after_comm = f.read().rpartition(b")")[2].split()
+        ticks = int(after_comm[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return now
+    return now - max(age, 0.0)
+
+
+class StartupTimeline:
+    """One process's start, from its creation to ``ready()``, by phase.
+
+    A phase is opened and closed where its work happens (``phase``, or
+    ``begin`` and ``end`` where the two lie in different functions). Phases
+    nest, and a phase's seconds are its SELF time: its span less the
+    phases inside it. So ``engine`` (all of ``EngineCore.__init__``) reads
+    what is left beside the worker's phases, ``frontend`` (``main`` to the
+    bound socket) what is left beside the engine, and the phases of a
+    process add up to ``created`` .. ``ready()`` with nothing in between.
+    ``phase(None)`` is time another process accounts for (the wait for a
+    spawned engine). A name counts once, the first time: a second engine
+    built in this process, and anything after ``ready()``, changes nothing.
+    Each phase is also a span of category ``startup`` on the chrome sink,
+    under one ``startup`` span that ``ready()`` writes.
+    """
+
+    def __init__(self, created: float | None = None) -> None:
+        self.created = process_created() if created is None else created
+        self.phases: dict[str, float] = {}  # in the order they ended
+        self.ready_at: float | None = None
+        # The open phases, outermost first: [name, began, seconds of the
+        # phases that ended inside it].
+        self._open: list[list] = []
+        # Where the last outermost phase ended, once ``imported()`` has
+        # run: the next outermost one begins there, so that the phases of
+        # a process with a main of its own leave nothing between them.
+        self._mark: float | None = None
+
+    def _record(self, name: str | None, began: float, ended: float,
+                inside: float, detail: str) -> None:
+        if self.ready_at is not None or name is None or name in self.phases:
+            return
+        seconds = self.phases[name] = ended - began - inside
+        if trace_enabled():
+            _emit_complete(name, "startup", int(began * 1e6),
+                           int(ended * 1e6), {"self_s": seconds})
+        logger.info("startup: %s %.1f s%s", name, seconds,
+                    f" ({detail})" if detail else "")
+
+    def imported(self) -> None:
+        """The entry of the process's own main: ``import`` ends here. It
+        began when the process was created, so it holds the interpreter's
+        start and every module imported on the way."""
+        if self._mark is None:
+            self._mark = time.perf_counter()
+            self._record("import", self.created, self._mark, 0.0, "")
+
+    def begin(self, name: str | None) -> None:
+        began = time.perf_counter()
+        if not self._open and self._mark is not None:
+            began = self._mark
+        self._open.append([name, began, 0.0])
+
+    def end(self, detail: str = "") -> None:
+        name, began, inside = self._open.pop()
+        ended = time.perf_counter()
+        if self._open:
+            self._open[-1][2] += ended - began
+        elif self._mark is not None:
+            self._mark = ended
+        self._record(name, began, ended, inside, detail)
+
+    @contextmanager
+    def phase(self, name: str | None):
+        """``with startup.phase("load_model") as note:``; what the body
+        appends to ``note`` ends the phase's log line (``7.5 GB``)."""
+        note: list[str] = []
+        self.begin(name)
+        try:
+            yield note
+        finally:
+            self.end(", ".join(note))
+
+    def ready(self) -> dict:
+        """This process's part of the start is over: close what is open
+        (the front end's own phase), write the ``startup`` span over all of
+        it and the one log line, and return ``snapshot()``."""
+        while self._open:
+            self.end()
+        if self.ready_at is None:
+            self.ready_at = self._mark or time.perf_counter()
+            if trace_enabled():
+                _emit_complete("startup", "startup", int(self.created * 1e6),
+                               int(self.ready_at * 1e6), dict(self.phases))
+            logger.info("startup: %s, total %.1f s", ", ".join(
+                f"{name} {s:.1f} s" for name, s in self.phases.items()),
+                self.ready_at - self.created)
+        return self.snapshot()
+
+    def snapshot(self) -> dict:
+        """What crosses to the metrics registry, once: whose phases these
+        are, when that process was created (``perf_counter``'s clock, one
+        for every process of a host) and the seconds of each phase."""
+        return {"pid": os.getpid(), "created": self.created,
+                "phases": dict(self.phases)}
+
+
+# This process's start. Module state because the thing itself is one a
+# process: its creation time and its one start.
+startup = StartupTimeline()
+
+
+# ----------------------------------------------------------------------
+# What compiling costs, from JAX's own events
+# ----------------------------------------------------------------------
+
+class CompileEvents:
+    """Listener to ``jax.monitoring``: seconds of tracing, lowering and
+    backend compilation (XLA's compile or the load from the persistent
+    cache, whichever happened), and persistent-cache hits and misses, for
+    the whole process since ``listen()``.
+
+    JAX reports a trace when it ends, with its duration, and reports the
+    traces of the jitted functions called inside it first, each in full:
+    summed as they come, a nested function would count twice. ``_traces``
+    holds (start, seconds) of the traces counted so far that no later one
+    has covered; a trace that ends takes back those that began inside it.
+    Lowering and backend compilation happen once a program and do not nest.
+    """
+
+    STAGE_OF = {
+        "/jax/core/compile/jaxpr_trace_duration": "trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+        "/jax/core/compile/backend_compile_duration": "backend",
+    }
+    RESULT_OF = {
+        "/jax/compilation_cache/cache_hits": "hit",
+        "/jax/compilation_cache/cache_misses": "miss",
+    }
+    MAX_OPEN_TRACES = 1024
+
+    def __init__(self) -> None:
+        self.seconds = {"trace": 0.0, "lower": 0.0, "backend": 0.0}
+        self.cache = {"hit": 0, "miss": 0}
+        self.heard = 0  # events counted: a reader's cheap "anything new?"
+        self._traces: list[tuple[float, float]] = []
+        self._listening = False
+
+    def listen(self) -> None:
+        """Register with JAX, once a process and for its life."""
+        if self._listening:
+            return
+        self._listening = True
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(self.on_duration)
+        monitoring.register_event_listener(self.on_event)
+
+    def on_duration(self, event: str, duration: float, **kwargs) -> None:
+        stage = self.STAGE_OF.get(event)
+        if stage is None:
+            return
+        now = time.perf_counter()
+        began = now - duration
+        with _lock:
+            if stage == "trace":
+                traces = self._traces
+                while traces and traces[-1][0] >= began:
+                    self.seconds["trace"] -= traces.pop()[1]
+                traces.append((began, duration))
+                del traces[:-self.MAX_OPEN_TRACES]
+            self.seconds[stage] += duration
+            self.heard += 1
+        if trace_enabled():
+            _emit_complete(f"compile.{stage}", "compile", int(began * 1e6),
+                           int(now * 1e6),
+                           {"fun_name": str(kwargs.get("fun_name", ""))})
+
+    def on_event(self, event: str, **kwargs) -> None:
+        result = self.RESULT_OF.get(event)
+        if result is not None:
+            with _lock:
+                self.cache[result] += 1
+                self.heard += 1
+
+    def reading(self) -> tuple[float, float, float, int, int]:
+        """(trace, lower, backend seconds, cache hits, misses) so far."""
+        s, c = self.seconds, self.cache
+        return s["trace"], s["lower"], s["backend"], c["hit"], c["miss"]
+
+
+compiles = CompileEvents()

@@ -35,7 +35,7 @@ from vllm_tpu.ops.attention import AttentionMetadata, ragged_kernel_tiling
 from vllm_tpu.ops.mla_kernel import count_latent_pairs, latent_blocks
 from vllm_tpu.ops.rpa_kernel import count_ragged_pairs
 from vllm_tpu.resilience.failpoints import fail_point
-from vllm_tpu.tracing import trace_span
+from vllm_tpu.tracing import compiles, trace_span
 from vllm_tpu.sample.sampler import (
     SamplingMetadata,
     dispatch_sample,
@@ -507,6 +507,12 @@ class ModelRunner:
         # count; the key of the first use now due, set by _prepare_inputs.
         self.first_use_s = 0.0
         self._first_use_key: tuple | None = None
+        # The same seconds by stage: Python tracing, lowering, the backend
+        # (XLA's compile or the load from the persistent cache), from
+        # JAX's own events read before and after the call, and "run", the
+        # rest of the call. They sum to first_use_s.
+        self.first_use_stage_s = {
+            "trace": 0.0, "lower": 0.0, "backend": 0.0, "run": 0.0}
 
     # ------------------------------------------------------------------
     # Jitted step
@@ -2592,6 +2598,22 @@ class ModelRunner:
 
     # ------------------------------------------------------------------
 
+    def _note_first_use(self, seconds: float, before: tuple) -> dict:
+        """Book a first use of ``seconds``: what JAX reported of tracing,
+        lowering and the backend since ``before`` (``compiles.reading()``
+        taken just before the call), "run" the rest, and whether the
+        persistent cache had the program."""
+        after = compiles.reading()
+        split = dict(zip(("trace", "lower", "backend"),
+                         (a - b for a, b in zip(after, before))))
+        split["run"] = seconds - sum(split.values())
+        self.first_use_s += seconds
+        for stage, s in split.items():
+            self.first_use_stage_s[stage] += s
+        hits, misses = after[3] - before[3], after[4] - before[4]
+        split["cache"] = "miss" if misses else "hit" if hits else "none"
+        return split
+
     def dispatch(self, so: SchedulerOutput) -> "StepHandle":
         """Upload inputs and enqueue the jitted step; returns immediately
         with device-array handles (no host sync). The async engine pipeline
@@ -2643,25 +2665,34 @@ class ModelRunner:
         first_use, self._first_use_key = self._first_use_key, None
         # A first use of a step program is traced, lowered and compiled
         # (or loaded from the persistent cache) inside this call, with
-        # every running request waiting.
+        # every running request waiting. JAX's own events say which of
+        # the three took the seconds (tracing.compiles, read before and
+        # after); the span's attributes are filled in before it closes.
+        before = compiles.reading() if first_use is not None else None
         with (trace_span("first_use", "runner", key=str(first_use), **step)
-              if first_use is not None else nullcontext()), \
+              if first_use is not None else nullcontext()) as span_args, \
                 trace_span("dispatch", "runner", **step):
             (self.kv_cache, self.draft_kv, sampled, lp, drafts, pooled,
              nan_count, prompt_lp, moe_counts, row_bad) = self._step_fn(
                 self.params, self.kv_cache, self.draft_kv, *arrays, prev,
                 mask_table, **mm_kwargs, **flags,
             )
-        dispatch_s = time.perf_counter() - t1
+            dispatch_s = time.perf_counter() - t1
+            if before is not None:
+                split = self._note_first_use(dispatch_s, before)
+                if span_args is not None:
+                    span_args.update(split)
         self.timing["dispatch_s"] += dispatch_s
         self.timing["steps"] += 1
         if first_use is not None:
-            self.first_use_s += dispatch_s
             logger.info(
                 "first use of step program (t_pad, r_pad, b_pad)=%s (%s "
-                "step): %.2f s; %d request(s) in the step, %d held by the "
-                "runner (running or in prefill)", first_use, step["kind"],
-                dispatch_s, len(req_order), self.input_batch.num_reqs)
+                "step): %.2f s (trace %.2f, lower %.2f, backend %.2f, run "
+                "%.2f; cache %s); %d request(s) in the step, %d held by "
+                "the runner (running or in prefill)", first_use,
+                step["kind"], dispatch_s, split["trace"], split["lower"],
+                split["backend"], split["run"], split["cache"],
+                len(req_order), self.input_batch.num_reqs)
         is_spec = flags["num_spec"] > 0
         is_dynamic = bool(flags.get("dynamic_decode"))
         if self.block_len:

@@ -22,6 +22,7 @@ from vllm_tpu.core.sched_output import ModelRunnerOutput, SchedulerOutput
 from vllm_tpu.logger import init_logger
 # load_hf_config lives beside the registry; its importers find it here too.
 from vllm_tpu.models.registry import get_model_class, load_hf_config  # noqa: F401
+from vllm_tpu.tracing import compiles, startup
 from vllm_tpu.utils.compile_cache import enable_compilation_cache
 from vllm_tpu.worker.model_runner import ModelRunner
 
@@ -59,6 +60,7 @@ class Worker:
     # ------------------------------------------------------------------
 
     def init_device(self) -> None:
+        compiles.listen()
         enable_compilation_cache()
         dev_cfg = self.config.device_config.device
         if dev_cfg != "auto":
@@ -459,9 +461,16 @@ class Worker:
         return num_blocks
 
     def initialize(self) -> int:
-        """Full startup; returns the KV block count for the scheduler."""
-        self.init_device()
-        self.load_model()
+        """Full startup; returns the KV block count for the scheduler.
+        Each part is a phase of the start's timeline (``tracing.startup``)
+        and logs its seconds as it ends, so a start that hangs says where."""
+        with startup.phase("init_device") as note:
+            self.init_device()
+            note.append(f"{self.device}")
+        with startup.phase("load_model") as note:
+            self.load_model()
+            note.append("%.1f GB of weights on the device" % (
+                _per_device_param_bytes(self.params, self.device) / 1e9))
         if getattr(self.model, "is_stateful_ssm", False):
             # Pure-SSM models: constant-size per-request state, so one
             # "block" = the whole sequence (reference MambaSpec block_size
@@ -499,38 +508,44 @@ class Worker:
                 self.config.scheduler_config.max_num_seqs
             )
         cache = self.config.cache_config
-        if cache.num_gpu_blocks_override is not None:
-            # Explicit budget: no profiling, single allocation.
-            num_blocks = self.determine_num_kv_blocks()
-            cache.num_gpu_blocks = num_blocks
-            self.runner = ModelRunner(
+        from vllm_tpu import envs
+
+        def make_runner(num_blocks: int) -> ModelRunner:
+            return ModelRunner(
                 self.config, self.model, self.params, num_blocks, self.mesh,
                 draft_model=self.draft_model, draft_params=self.draft_params,
             )
-            return num_blocks
+
         # Profile-based sizing: build the runner with a provisional pool,
         # measure the compiled max-bucket step's peak memory, then size and
         # re-allocate the real KV cache (reference: gpu_worker.py:352).
-        from vllm_tpu import envs
-
-        self.runner = ModelRunner(
-            self.config, self.model, self.params, 64, self.mesh,
-            draft_model=self.draft_model, draft_params=self.draft_params,
-        )
-        act = (
-            self.runner.profile_step_memory()
-            if envs.VLLM_TPU_PROFILE_KV_SIZING and self._memory_limit_known()
-            else None
-        )
-        num_blocks = self.determine_num_kv_blocks(act)
-        cache.num_gpu_blocks = num_blocks
-        self.runner.resize_kv_cache(num_blocks)
+        # Under an explicit budget: no profiling (the phase reads 0), a
+        # single allocation.
+        act = None
+        with startup.phase("profile") as note:
+            if cache.num_gpu_blocks_override is None:
+                self.runner = make_runner(64)
+                if (envs.VLLM_TPU_PROFILE_KV_SIZING
+                        and self._memory_limit_known()):
+                    act = self.runner.profile_step_memory()
+                    if act is not None:
+                        note.append(
+                            "%.0f MiB of activations" % (act / 2**20))
+        with startup.phase("kv_cache") as note:
+            num_blocks = self.determine_num_kv_blocks(act)
+            cache.num_gpu_blocks = num_blocks
+            if self.runner is None:
+                self.runner = make_runner(num_blocks)
+            else:
+                self.runner.resize_kv_cache(num_blocks)
+            note.append(f"{num_blocks} blocks of {cache.block_size} tokens")
         return num_blocks
 
     def compile_or_warm_up_model(self) -> None:
-        if self.config.compilation_config.precompile:
-            assert self.runner is not None
-            self.runner.profile_run()
+        with startup.phase("precompile"):
+            if self.config.compilation_config.precompile:
+                assert self.runner is not None
+                self.runner.profile_run()
 
     # ------------------------------------------------------------------
 
