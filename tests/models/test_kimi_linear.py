@@ -316,6 +316,51 @@ def test_the_layers_run_as_scans_over_whole_periods():
     assert model.kinds[3] == ("mla", "moe") and model.kinds[0] == ("kda", "dense")
 
 
+def test_the_period_scan_carries_the_period_index_and_no_weight():
+    """``apply`` holds ONE scan over the periods, and what that scan steps
+    over (its ``xs``) is the period's index alone: the ``[P, n, ...]``
+    stacks enter it whole, as constants, and each layer slices its own
+    leaf ``[period, place]`` out of them. Handed over as ``xs`` they reach
+    an iteration as ``[n, ...]`` slabs that n layers index, which the
+    TPU's compiler copies out of the stack twice an iteration (PR 40;
+    ``tests/test_tpu_aot_compile.py`` reads the compiled body where that
+    compiler is installed)."""
+    from vllm_tpu.ops.attention import AttentionMetadata
+
+    model = _model(TINY_KIMI)
+    model.max_state_slots = 4
+    t, r = 8, 4
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    md = AttentionMetadata(
+        positions=i32(t), slot_mapping=i32(t), block_tables=i32(r, 2),
+        seq_lens=i32(r), query_start_loc=i32(r + 1), token_req_idx=i32(t),
+        logits_indices=i32(r), num_seqs=i32(1), state_slots=i32(r),
+    )
+    args = (
+        jax.eval_shape(model.init_dummy_params, jax.random.PRNGKey(0)),
+        jax.eval_shape(lambda: model.alloc_kv_cache(8, 16, jnp.float32)),
+        i32(t), md,
+    )
+    jaxpr = jax.make_jaxpr(model.apply)(*args).jaxpr
+    paths = [path for path, _ in jax.tree_util.tree_flatten_with_path(args)[0]]
+    assert len(paths) == len(jaxpr.invars)
+    in_periods = lambda path: (
+        path[0].idx == 0 and getattr(path[1], "key", None) == "periods")
+    stacks = {v for v, path in zip(jaxpr.invars, paths) if in_periods(path)}
+    assert len(stacks) == len(jax.tree.leaves(args[0]["periods"]))
+
+    scans = [e for e in jaxpr.eqns
+             if e.primitive.name == "scan" and stacks & set(e.invars)]
+    assert len(scans) == 1
+    (scan,) = scans
+    assert scan.params["length"] == model.reps == 2
+    first_x = scan.params["num_consts"] + scan.params["num_carry"]
+    assert stacks <= set(scan.invars[: scan.params["num_consts"]])
+    assert [(x.aval.shape, x.aval.dtype) for x in scan.invars[first_x:]] == [
+        ((model.reps,), jnp.int32)
+    ]
+
+
 def test_the_shares_routed_parts_and_the_shared_expert_once_make_the_uncut_layer():
     """The guide's share test, on the program's own expert layer: four
     shares of four experts each, given the slices of the uncut layer's

@@ -491,11 +491,11 @@ _HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
               "u32": 4, "f32": 4}
 
 
-def _while_body_instructions(hlo: str, with_dims: bool = False):
-    """(name, opcode, bytes of the result) of every instruction that sits
-    directly in the body of a ``while`` of the optimised program: the layer
-    scan. A fused computation's inside is another computation and is not
-    listed: what it holds never reaches HBM."""
+def _while_body_instructions(hlo: str):
+    """(name, opcode, bytes of the result, its dims) of every instruction
+    that sits directly in the body of a ``while`` of the optimised program:
+    the layer scan. A fused computation's inside is another computation and
+    is not listed: what it holds never reaches HBM."""
     bodies = set(re.findall(r"\bbody=%([\w.\-]+)", hlo))
     assert bodies, "the step program holds no while loop: is the scan gone?"
     current = None
@@ -511,11 +511,8 @@ def _while_body_instructions(hlo: str, with_dims: bool = False):
         )
         if m:
             name, dtype, dims, opcode = m.groups()
-            n = math.prod(int(d) for d in dims.split(",") if d)
-            if with_dims:
-                yield name, opcode, tuple(int(d) for d in dims.split(",") if d)
-            else:
-                yield name, opcode, n * _HLO_BYTES.get(dtype, 4)
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            yield name, opcode, math.prod(dims) * _HLO_BYTES.get(dtype, 4), dims
 
 
 @pytest.mark.parametrize("tree", ["bf16", "int8"])
@@ -580,7 +577,7 @@ def test_layer_scan_reads_each_weight_once_in_place(
     limit = 2 * model.hidden_size * model.num_kv_heads * model.head_dim
     moved = [
         (name, opcode, nbytes)
-        for name, opcode, nbytes in _while_body_instructions(text)
+        for name, opcode, nbytes, _ in _while_body_instructions(text)
         if nbytes >= limit
         and (opcode in ("copy", "transpose", "dynamic-slice")
              or "dynamic-slice" in name)
@@ -667,7 +664,7 @@ def test_block_diffusion_step_compiles_and_reads_each_weight_in_place(
     shapes -= {(t, model.hidden_size), (t * model.top_k, model.hidden_size)}
     moved = [
         (name, opcode, dims)
-        for name, opcode, dims in _while_body_instructions(text, with_dims=True)
+        for name, opcode, _, dims in _while_body_instructions(text)
         if dims in shapes and (opcode in ("copy", "transpose", "dynamic-slice", "fusion")
                                or "dynamic-slice" in name)
     ]
@@ -719,3 +716,103 @@ def test_block_diffusion_step_compiles_and_reads_each_weight_in_place(
     text = epilogue(False)
     assert "fused_sampler_kernel" not in text
     assert wide in text and "while(" in text
+
+
+# Kimi-Linear-48B-A3B-Instruct as ``kimilinear48b.long-answers`` runs it:
+# the cell's two step programs, 192 rows of 768 pages, 193 state slots,
+# about the cell's latent pages.
+KIMI_CONFIG = "benchmark/configs/kimi-linear-48b-a3b-instruct.json"
+KIMI_ROWS, KIMI_PAGES, KIMI_BLOCKS = 192, 768, 32264
+# One layer of ``periods/moe/ws_gate`` [2304, 1024] in bf16: the smallest
+# of the dense weights that the scan copied a slab of (PR 40).
+KIMI_WEIGHT_BYTES = 4_718_592
+# What the compiler still takes out of its stack a latent layer: ``wkv_b``
+# [512, 8192] bf16, which two einsums absorb through a reshape and a
+# split (a slice, and a copy that relays it): 0.1 ms a step.
+KIMI_WKV_B = ((512, 8192), 8_388_608)
+
+
+@pytest.mark.parametrize("t,decode_only", [(192, True), (512, False)],
+                         ids=["decode_T192_R192", "mixed_T512_R192"])
+def test_kimi_linear_scan_reads_each_weight_once_in_place(
+    one_chip, monkeypatch, t, decode_only
+):
+    """Both step programs of ``kimilinear48b.long-answers``, compiled for
+    the v5e with the Pallas forms on: no iteration of the period scan may
+    slice a WEIGHT out of its ``[P, n, ...]`` stack into a temporary, nor
+    copy or transpose one. PR 40 found 21 such slices an iteration, 617 MB
+    (``dynamic-slice_bitcast_fusion`` and its ``.remat3`` twins, 3.7 ms of
+    the chip's 23 ms step): the scan handed an iteration the period's
+    leaves as ``[n, ...]`` slabs that n layers indexed, and a slice with n
+    consumers folds into none of them. Each layer now takes ``leaf[period, place]``
+    from the stacks themselves, and the latent query projection ends at a
+    barrier as ``models/llama.py``'s do.
+
+    The limit is the smallest of those weights and not lower: at 512
+    tokens the activations' relayouts are copies of 4-8 MB, which is why
+    a ``copy`` counts only with a weight's dims."""
+    import json
+    import pathlib
+
+    from transformers import PretrainedConfig
+
+    from vllm_tpu.models.kimi_linear import KimiLinearForCausalLM
+    from vllm_tpu.ops import attention
+    from vllm_tpu.ops.mla_attention import mla_kv_cache_shape
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    # ops/kda.py, ops/mla_attention.py and layers/moe.py ask the backend;
+    # here JAX is held to several CPU devices.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    config = json.loads((root / KIMI_CONFIG).read_text())
+    config.pop("bench")
+    model = KimiLinearForCausalLM(PretrainedConfig.from_dict(config), dtype=jnp.bfloat16)
+    model.max_state_slots = KIMI_ROWS
+    assert (model.period, model.reps) == (4, 3)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    on_chip = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(model.init_dummy_params, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.alloc_kv_cache(KIMI_BLOCKS, PAGE, jnp.bfloat16)))
+    assert cache["paged"].shape == mla_kv_cache_shape(3, KIMI_BLOCKS, PAGE, 576)
+    assert cache["kda"].shape[:2] == (10, KIMI_ROWS + 1)
+    r = KIMI_ROWS
+    md = attention.AttentionMetadata(
+        positions=s((t,), jnp.int32), slot_mapping=s((t,), jnp.int32),
+        block_tables=s((r, KIMI_PAGES), jnp.int32),
+        seq_lens=s((r,), jnp.int32), query_start_loc=s((r + 1,), jnp.int32),
+        token_req_idx=s((t,), jnp.int32), logits_indices=s((r,), jnp.int32),
+        num_seqs=s((1,), jnp.int32), decode_only=decode_only,
+        state_slots=s((r,), jnp.int32),
+    )
+    text = jax.jit(model.apply, donate_argnums=(1,)).lower(
+        params, cache, s((t,), jnp.int32), md
+    ).compile().as_text()
+
+    for kernel in ("kda_decode_kernel", "kda_conv_kernel", "mla_kernel", "gmm"):
+        assert kernel in text
+    assert ("kda_chunk_kernel" in text) == (not decode_only)
+    # A weight's dims: one layer of a leaf, the slab of a period's n
+    # layers of it, and either transposed (axes of 1 apart: XLA keeps the
+    # sliced ones).
+    squeezed = lambda dims: tuple(d for d in dims if d != 1)
+    shapes = {squeezed(a.shape[k:]) for a in jax.tree.leaves(params["periods"])
+              for k in (1, 2) if a.ndim >= 4}
+    shapes |= {sh[:-2] + sh[:-3:-1] for sh in shapes}
+    moved = [
+        (name, opcode, dims, nbytes)
+        for name, opcode, nbytes, dims in _while_body_instructions(text)
+        if nbytes >= KIMI_WEIGHT_BYTES
+        and ("dynamic-slice" in name or opcode == "dynamic-slice"
+             or (opcode in ("copy", "transpose") and squeezed(dims) in shapes))
+        and (squeezed(dims), nbytes) != KIMI_WKV_B
+    ]
+    assert not moved, (
+        f"the period scan moves weight-sized temporaries "
+        f"(>= {KIMI_WEIGHT_BYTES} bytes): {moved}"
+    )

@@ -134,6 +134,14 @@ def latent_attention(
         q = h @ lp["wq"]
     else:
         q = rms_norm(h @ lp["wq_a"], lp["q_a_norm"], rms_eps) @ lp["wq_b"]
+    # The projection ends here, as [T, heads * head_dim] rows. Left free,
+    # XLA folds the head split below into the dot, lays its output out
+    # token-minor, and then slices AND transposes the weight out of its
+    # stack into temporaries a layer (models/llama.py has the same before
+    # its head split, PERF.md PR 27; here 28 MB twice a latent layer of
+    # Kimi-Linear, PR 40); behind the barrier the dot reads the stack in
+    # place.
+    q = jax.lax.optimization_barrier(q)
     q = q.reshape(t, H, DN + DR)
     q_nope, q_pe = q[..., :DN], q[..., DN:]
     kv_a = h @ lp["wkv_a"]  # [T, DC+DR]

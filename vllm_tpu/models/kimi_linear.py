@@ -28,7 +28,14 @@ result for the pairs routed to them (``layers/moe.py``, ``first_expert``).
 
 Layers run as scans wherever the kinds repeat: the leading layers one by
 one, then ``lax.scan`` over whole periods of the pattern (3 KDA : 1
-latent), then what is left. Param tree::
+latent), then what is left. The scan steps over the period's index alone
+and each layer slices its own leaves ``leaf[period, place]`` out of the
+whole ``[P, n, ...]`` stacks, so that every slice has one consumer and the
+matmul that uses a weight reads it where it lies. Handed to the scan as
+``xs``, the stacks reached an iteration as ``[n, ...]`` slabs that n layers
+indexed, and XLA wrote each slab out of its stack twice an iteration: 4.2
+ms of a 23.1 ms step on the v5e, which then took 16.3 (PERF.md, PR 40).
+Param tree::
 
     embed [V, D], final_norm [D], lm_head [D, V]
     single/<layer>/   the layers outside the periods, leaves unstacked
@@ -655,15 +662,17 @@ class KimiLinearForCausalLM:
             n_of = lambda kind, col: sum(1 for k in pattern if k[col] == kind)
             g0, a0 = kda_before(s), mla_before(s)
             experts = params.get("experts")
+            stacks = params["periods"]
 
-            def period_fn(carry, inputs):
+            def period_fn(carry, rep):
                 x, cache = carry
-                lp, rep = inputs
+                # One layer's leaves out of the whole stacks (the module
+                # docstring says why not a slab of the period's).
+                pick = lambda kind, j: jax.tree.map(
+                    lambda leaf: leaf[rep, j], stacks[kind])
                 seen = {"kda": 0, "mla": 0, "dense": 0, "moe": 0}
                 counts = []
                 for off, (mixer, ffn) in enumerate(pattern):
-                    pick = lambda kind, j: jax.tree.map(
-                        lambda leaf: leaf[j], lp[kind])
                     x, cache, c = layer(
                         x, cache, mixer, ffn, pick("norms", off),
                         pick(mixer, seen[mixer]), pick(ffn, seen[ffn]),
@@ -679,8 +688,7 @@ class KimiLinearForCausalLM:
                 return (x, cache), (jnp.stack(counts) if counts else None)
 
             (x, cache), per_period = jax.lax.scan(
-                period_fn, (x, cache),
-                (params["periods"], jnp.arange(self.reps, dtype=jnp.int32)),
+                period_fn, (x, cache), jnp.arange(self.reps, dtype=jnp.int32),
             )
             if per_period is not None:
                 moe_offs = [o for o in range(p) if pattern[o][1] == "moe"]
