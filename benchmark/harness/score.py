@@ -105,6 +105,11 @@ def score(records: list, seconds: float, chips: int) -> dict:
         "prompt_tokens_due": sum(r.prompt_tokens for r in scored),
         "output_tokens_due": sum(r.max_tokens for r in scored),
         "window_output_tokens": window_tokens,
+        # Of those, the tokens of requests due BEFORE the window (the ramp's):
+        # what set-up still owed when the window opened. A stall in the ramp
+        # moves tokens from before the window into it (PERF.md section 2).
+        "window_output_tokens_owed_by_the_ramp": sum(
+            tokens_in(r, 0.0, seconds) for r in records if not r.scored),
         "multi_token_chunks": sum(1 for r in good if len(r.chunks) < r.max_tokens),
     }
     return {"metrics": metrics, "attempted": len(scored), "failed": len(bad), "facts": facts}

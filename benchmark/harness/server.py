@@ -8,11 +8,56 @@ import signal
 import socket
 import subprocess
 import sys
+import time
 from pathlib import Path
 
 from .manifest import ROOT, Cell
 
 NO_CHIP = 3
+
+
+def session_members(sessions: list[int]) -> list[tuple[int, str]]:
+    """(pid, command line) of every process alive in one of ``sessions``,
+    from ``/proc``. Every child of a run is started with
+    ``start_new_session=True``, so its session id is its pid and whatever
+    it starts stays in that session unless it leaves on purpose."""
+    out = []
+    for d in Path("/proc").iterdir():
+        if not d.name.isdigit():
+            continue
+        try:
+            stat = (d / "stat").read_text()
+            # "pid (comm) state ppid pgrp session ...": comm may hold anything.
+            state, _ppid, _pgrp, session = stat[stat.rindex(")") + 2:].split()[:4]
+            if state == "Z" or int(session) not in sessions:
+                continue
+            cmd = (d / "cmdline").read_bytes().replace(b"\0", b" ").decode(errors="replace")
+        except OSError:
+            continue  # gone between the listing and the read
+        out.append((int(d.name), cmd.strip()))
+    return out
+
+
+def end_sessions(sessions: list[int], wait_s: float = 5.0) -> list[tuple[int, str]]:
+    """SIGKILL to each session's process group and to whatever else is
+    still in the session, then wait until none is left. Returns who is
+    there all the same after ``wait_s`` (a process stuck in the kernel)."""
+    deadline = time.monotonic() + wait_s
+    while True:
+        for sid in sessions:
+            try:
+                os.killpg(sid, signal.SIGKILL)
+            except (ProcessLookupError, PermissionError):
+                pass
+        left = session_members(sessions)
+        for pid, _ in left:
+            try:
+                os.kill(pid, signal.SIGKILL)
+            except ProcessLookupError:
+                pass
+        if not left or time.monotonic() > deadline:
+            return left
+        time.sleep(0.05)
 
 
 def free_port() -> int:
@@ -104,10 +149,7 @@ class Server:
                 self.proc.wait(timeout)
             except subprocess.TimeoutExpired:
                 pass
-        try:
-            os.killpg(self.proc.pid, signal.SIGKILL)
-        except ProcessLookupError:
-            pass
+        end_sessions([self.proc.pid])
         self.proc.wait()
         self.log.close()
         if self.facts_path.is_file():

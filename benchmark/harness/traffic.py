@@ -1,13 +1,20 @@
 """Traffic generators. One general generator per arrival pattern, each
 driven by a data file under ``benchmark/traffic/``.
 
-Every seed gets the SAME work in another order. The lengths of a phase are
+Every seed gets the SAME work in the SAME order. The lengths of a phase are
 the mid-quantiles of the file's distributions (a stratified sample, so the
 sum of prompt and output tokens is the same in every run), paired once by
 the file's ``pool_seed``; the gaps between arrivals are the mid-quantiles of
-the exponential distribution, scaled to fill the phase exactly. ``--seed``
-only permutes the pairs and the gaps and draws the token ids. So two seeds
-differ as two orders of one load do, not as two loads.
+the exponential distribution, scaled to fill the phase exactly; and the
+order of the pairs and of the gaps is the file's too (``_draws``: from
+``pool_seed`` and the phase's number), whatever the mix. ``--seed`` draws the
+token ids (and, in the server, the weights and the sampler's draws) and
+nothing else. So two seeds send request for request the same lengths at the
+same times: which long prompts and which long answers fall inside the
+window is a property of the cell, not of the run. A second stretch of one
+run (the traced segment after the window) is a ``segment`` of its own, with
+its own phase numbers: another order from the same file, not the window's
+replayed.
 
 Times are seconds relative to the opening of the measured window: the ramp
 is negative, the window is ``[0, seconds)``.
@@ -69,15 +76,23 @@ def quantile_sample(dist: dict, n: int) -> np.ndarray:
     return np.clip(np.rint(x), dist.get("min", 1), dist.get("max", 2**31)).astype(int)
 
 
-def arrival_times(n: int, start_s: float, duration_s: float, rng) -> np.ndarray:
+def _draws(traffic: dict, seed: int, phase: int, generator: int):
+    """The two sources of one phase of one generator: ``order``, which
+    permutes the pairs of lengths and the gaps and comes from the traffic
+    file alone, and ``ids``, which draws the token ids and comes from
+    ``--seed``."""
+    return _rng(traffic["pool_seed"], phase, generator, 11), _rng(seed, phase, generator)
+
+
+def arrival_times(n: int, start_s: float, duration_s: float, order) -> np.ndarray:
     """``n`` arrivals in ``[start, start + duration)``: exponential gaps at
-    their mid-quantiles, scaled to fill the phase, in the seed's order."""
+    their mid-quantiles, scaled to fill the phase, in the file's order."""
     if n <= 0:
         return np.zeros(0)
     u = (np.arange(n) + 0.5) / n
     gaps = -np.log1p(-u)
     gaps *= duration_s / gaps.sum()
-    gaps = gaps[rng.permutation(n)]
+    gaps = gaps[order.permutation(n)]
     return start_s + np.cumsum(gaps) - gaps / 2
 
 
@@ -100,63 +115,64 @@ def _tokens(rng, n: int, vocab: int) -> list[int]:
     return rng.integers(0, vocab, size=int(n)).tolist()
 
 
-def _phases(ramp_s: float, seconds: float) -> list[tuple[float, float, int]]:
-    """(start, duration, phase number): the ramp, then the window."""
+def _phases(ramp_s: float, seconds: float, segment: int) -> list[tuple[float, float, int, bool]]:
+    """(start, duration, phase number, whether it is the window): the ramp,
+    then the window; each segment of a run has its own two numbers."""
     out = []
     if ramp_s > 0:
-        out.append((-ramp_s, ramp_s, 0))
+        out.append((-ramp_s, ramp_s, 2 * segment, False))
     if seconds > 0:
-        out.append((0.0, seconds, 1))
+        out.append((0.0, seconds, 2 * segment + 1, True))
     return out
 
 
-def open_poisson(traffic, rate, ramp_s, seconds, seed, vocab, positions) -> Plan:
+def open_poisson(traffic, rate, ramp_s, seconds, seed, vocab, positions, segment=0) -> Plan:
     reqs: list[Request] = []
-    for start, dur, phase in _phases(ramp_s, seconds):
+    for start, dur, phase, window in _phases(ramp_s, seconds, segment):
         n = int(round(rate * dur))
-        rng = _rng(seed, phase, 1)
+        order, ids = _draws(traffic, seed, phase, 1)
         lens = _pairs(traffic, n, positions, ("prompt_len", "output_len"))
-        lens = lens[rng.permutation(n)]
-        due = arrival_times(n, start, dur, rng)
+        lens = lens[order.permutation(n)]
+        due = arrival_times(n, start, dur, order)
         for (p, o), t in zip(lens, due):
             reqs.append(Request(
-                idx=len(reqs), due_s=float(t), prompt=_tokens(rng, p, vocab),
+                idx=len(reqs), due_s=float(t), prompt=_tokens(ids, p, vocab),
                 max_tokens=int(o), sampling=dict(traffic["sampling"]),
-                scored=phase == 1,
+                scored=window,
             ))
     reqs.sort(key=lambda r: r.due_s)
     return Plan("open", reqs, start_s=-ramp_s, end_s=seconds)
 
 
-def open_poisson_shared_docs(traffic, rate, ramp_s, seconds, seed, vocab, positions) -> Plan:
+def open_poisson_shared_docs(traffic, rate, ramp_s, seconds, seed, vocab, positions,
+                             segment=0) -> Plan:
     """``rate`` counts requests; documents arrive at ``rate / asks_per_doc``."""
     asks = int(traffic["asks_per_doc"])
     spread = float(traffic["ask_spread_s"])
     reqs: list[Request] = []
     docs = 0
-    for start, dur, phase in _phases(ramp_s, seconds):
+    for start, dur, phase, _ in _phases(ramp_s, seconds, segment):
         n = int(round(rate / asks * dur))
-        rng = _rng(seed, phase, 2)
+        order, ids = _draws(traffic, seed, phase, 2)
         pool = _rng(traffic["pool_seed"], n, 7)
-        doc_len = quantile_sample(traffic["doc_len"], n)[rng.permutation(n)]
+        doc_len = quantile_sample(traffic["doc_len"], n)[order.permutation(n)]
         qo = _pairs(
             {**traffic, "pool_seed": traffic["pool_seed"] + 1}, n * asks,
             2**31, ("question_len", "output_len"),
-        )[rng.permutation(n * asks)].reshape(n, asks, 2)
+        )[order.permutation(n * asks)].reshape(n, asks, 2)
         # Ask k of a document falls in the k-th part of the spread, at a
         # place fixed by the file's seed.
         offs = (np.arange(asks)[None, :] + pool.random((n, asks))) / asks * spread
-        offs = offs[rng.permutation(n)]
-        arrive = arrival_times(n, start, dur, rng)
+        arrive = arrival_times(n, start, dur, order)
         for d in range(n):
-            doc = _tokens(rng, doc_len[d], vocab)
+            doc = _tokens(ids, doc_len[d], vocab)
             for k in range(asks):
                 due = float(arrive[d] + offs[d, k])
                 q, o = (int(x) for x in qo[d, k])
                 if due >= seconds or len(doc) + q + o > positions:
                     continue
                 reqs.append(Request(
-                    idx=0, due_s=due, prompt=doc + _tokens(rng, q, vocab),
+                    idx=0, due_s=due, prompt=doc + _tokens(ids, q, vocab),
                     max_tokens=o, sampling=dict(traffic["sampling"]),
                     scored=0.0 <= due < seconds, group=docs,
                 ))
@@ -167,16 +183,16 @@ def open_poisson_shared_docs(traffic, rate, ramp_s, seconds, seed, vocab, positi
     return Plan("open", reqs, start_s=-ramp_s, end_s=seconds)
 
 
-def closed_clients(traffic, rate, ramp_s, seconds, seed, vocab, positions) -> Plan:
+def closed_clients(traffic, rate, ramp_s, seconds, seed, vocab, positions, segment=0) -> Plan:
     """``rate`` is not used: the clients set the pace. The pool is long
     enough that it does not run out; a request is scored when it is sent
     inside the window."""
     n = int(traffic["pool_requests"])
-    rng = _rng(seed, 3)
+    order, ids = _draws(traffic, seed, 2 * segment, 3)
     lens = _pairs(traffic, n, positions, ("prompt_len", "output_len"))
-    lens = lens[rng.permutation(n)]
+    lens = lens[order.permutation(n)]
     reqs = [
-        Request(idx=i, due_s=None, prompt=_tokens(rng, p, vocab),
+        Request(idx=i, due_s=None, prompt=_tokens(ids, p, vocab),
                 max_tokens=int(o), sampling=dict(traffic["sampling"]))
         for i, (p, o) in enumerate(lens)
     ]
@@ -192,7 +208,11 @@ GENERATORS = {
 
 
 def build_plan(traffic: dict, rate: float, ramp_s: float, seconds: float,
-               seed: int, vocab: int, positions: int) -> Plan:
+               seed: int, vocab: int, positions: int, segment: int = 0) -> Plan:
+    """The plan of one stretch of a run: ``segment`` 0 is the window with
+    its ramp, 1 the traced segment that follows it. The lengths, the gaps
+    and their order are the traffic file's, one order a segment; ``seed``
+    draws the token ids."""
     try:
         gen = GENERATORS[traffic["generator"]]
     except KeyError:
@@ -200,14 +220,14 @@ def build_plan(traffic: dict, rate: float, ramp_s: float, seconds: float,
             f"traffic names the generator {traffic['generator']!r}; "
             f"the harness has {sorted(GENERATORS)}"
         ) from None
-    plan = gen(traffic, rate, ramp_s, seconds, seed, vocab, positions)
+    plan = gen(traffic, rate, ramp_s, seconds, seed, vocab, positions, segment)
     plan.score_until = plan.end_s
     return plan
 
 
 def extend(plan: Plan, more: Plan, by_s: float) -> Plan:
     """``plan`` followed by the window phase of ``more`` (the same mix at
-    the same rate, from another seed), shifted to begin where ``plan``
+    the same rate, another segment), shifted to begin where ``plan``
     ends; none of the added requests is scored."""
     if plan.kind == "open":
         for r in more.requests:

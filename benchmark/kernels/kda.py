@@ -15,10 +15,12 @@ token) and pays operations instead: a token's part of the pairwise
 right-hand sides (2 x 64 x 2d), and of the four state matmuls (4 x 2 x d
 x d), a head. ``least_seconds`` takes the larger of all bytes over the
 bandwidth and the chunked operations over the bf16 peak (the program
-computes them in float32 passes, which cost more: a lower bound). It runs
-as XLA fusions that the trace does not name apart from others, so the
-roofline share reads the one-token kernel alone and is given the decode
-rows alone; ``tools/probe_kda.py`` times the chunked form by itself.
+computes them in float32 passes, which cost more: a lower bound). Since
+PR 35 it is a Pallas kernel of its own, which the trace prints as
+``kda_chunk_kernel``: ``kda_roofline`` reads the one-token kernel and is
+given the decode rows alone (``TRACE_KERNELS`` below), ``kda_chunk_roofline``
+reads the chunk kernel's time against the chunk tokens through the same
+``least_seconds``; ``tools/probe_kda.py`` times either form by itself.
 """
 
 # The names the trace prints for the kernel's calls (instance numbers

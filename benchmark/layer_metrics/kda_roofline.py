@@ -6,8 +6,8 @@ decode-only steps and the decode rows of the mixed steps). Least time: the
 rows it takes, ``vllm:linear_attn_tokens_total{kind="decode"}`` (rows of
 one token, summed over the KDA layers), each reading and writing its state
 once with its token's operands (``benchmark/kernels/kda.py``), over the
-chip's HBM bandwidth. The chunked form is XLA fusions the trace does not
-name apart, so neither its time nor its tokens are in here. The counter is
+chip's HBM bandwidth. The chunked form is a kernel of its own since PR 35
+(``kda_chunk_roofline``): neither its time nor its tokens are in here. The counter is
 scraped inside each slice: under-read, never over-read. None where the
 program has no such counter or the trace no such kernel."""
 

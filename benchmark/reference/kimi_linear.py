@@ -76,6 +76,19 @@ one, the periods stacked), so that the CPU test compares leaf by leaf;
 
 On a TPU a float32 matmul runs in lower precision unless told otherwise,
 so everything here runs under ``jax.default_matmul_precision("highest")``.
+
+``compute``. ``forward`` and ``forward_many`` take the type the activations
+are kept in, float32 unless said: the reference. With ``compute`` the served
+type (bfloat16) the SAME formulae run with weights and activations in that
+type, every product of matrices rounded to it as it is stored, the logits
+too, and in float32 only what the configuration states in float32: the
+recurrent state and its step, the convolution's sum, the norms' and the
+softmax's arithmetic, the router's scores, the decay. That pass is not the
+reference and is compared with nothing served: its distance from the
+float32 pass, at the served tokens, is what rounding to the served type
+costs THIS seed's weights on THESE tokens, and the checker reads the
+served error as a factor over it (``harness/checker.py``). In float32 every
+cast below is the identity, so the reference is what it was to the bit.
 """
 
 from __future__ import annotations
@@ -264,17 +277,29 @@ def seeded_weights(config: dict, seed: int, dtype=jnp.bfloat16) -> dict:
     return w
 
 
-def layer_weights(config: dict, weights: dict, layer: int) -> dict:
+# Float32 whatever the activations are kept in: the family draws and
+# keeps them so.
+FLOAT32_LEAVES = ("a_log", "dt_bias", "router_bias")
+
+
+def layer_weights(config: dict, weights: dict, layer: int, compute=jnp.float32) -> dict:
     """Layer ``layer``'s leaves as ``{"norms", "mixer", "ffn", "experts"}``,
-    float32."""
+    in ``compute`` (``FLOAT32_LEAVES`` in float32)."""
     s = sizes(config)
     kinds = s["kinds"]
     mixer, ffn = kinds[layer]
     _, start, period, reps, _ = plan(kinds)
-    f32 = partial(jax.tree.map, lambda x: x.astype(jnp.float32))
+
+    def cast(tree):
+        if not isinstance(tree, dict):
+            return tree  # None: a layer without experts
+        return {k: cast(v) if isinstance(v, dict) or v is None else
+                v.astype(jnp.float32 if k in FLOAT32_LEAVES else compute)
+                for k, v in tree.items()}
+
     if not reps or not start <= layer < start + period * reps:
         one = weights["single"][str(layer)]
-        return f32({"norms": one["norms"], "mixer": one[mixer], "ffn": one[ffn],
+        return cast({"norms": one["norms"], "mixer": one[mixer], "ffn": one[ffn],
                     "experts": one.get("experts")})
     rep, off = divmod(layer - start, period)
     pattern = kinds[start: start + period]
@@ -286,11 +311,17 @@ def layer_weights(config: dict, weights: dict, layer: int) -> dict:
         per = sum(1 for k in pattern if k[1] == "moe")
         row = rep * per + nth("moe", 1)
         out["experts"] = jax.tree.map(lambda x: x[row], weights["experts"])
-    return f32(out)
+    return cast(out)
+
+
+def to_f32(x):
+    return x.astype(jnp.float32)
 
 
 def rms_norm(x, weight, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * weight
+    y = to_f32(x)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps) * to_f32(weight)
+    return y.astype(x.dtype)
 
 
 def l2norm(x):
@@ -301,6 +332,7 @@ def short_conv(x, taps):
     """Depthwise causal convolution: ``taps [K, C]``, tap K-1 on the
     current token, zeros before the first."""
     k = taps.shape[0]
+    x, taps = to_f32(x), to_f32(taps)
     padded = jnp.concatenate([jnp.zeros((k - 1, x.shape[1]), x.dtype), x])
     return sum(padded[j: j + x.shape[0]] * taps[j] for j in range(k))
 
@@ -314,8 +346,8 @@ def kda_mixer(h, lw, s):
     )
     q, k = l2norm(q) / math.sqrt(d), l2norm(k)
     a = -jnp.exp(lw["a_log"])[:, None] * heads(
-        jax.nn.softplus((h @ lw["f_a"]) @ lw["f_b"] + lw["dt_bias"]))
-    beta = jax.nn.sigmoid(h @ lw["b_proj"])  # [T, H]
+        jax.nn.softplus(to_f32((h @ lw["f_a"]) @ lw["f_b"]) + lw["dt_bias"]))
+    beta = jax.nn.sigmoid(to_f32(h @ lw["b_proj"]))  # [T, H]
 
     def token(S, x):
         q_t, k_t, v_t, a_t, b_t = x
@@ -325,9 +357,9 @@ def kda_mixer(h, lw, s):
         return S, jnp.einsum("hk,hkv->hv", q_t, S)
 
     _, o = jax.lax.scan(token, jnp.zeros((H, d, d), jnp.float32), (q, k, v, a, beta))
-    gate = jax.nn.sigmoid(heads((h @ lw["g_a"]) @ lw["g_b"] + lw["g_bias"]))
+    gate = jax.nn.sigmoid(heads(to_f32((h @ lw["g_a"]) @ lw["g_b"]) + to_f32(lw["g_bias"])))
     o = rms_norm(o, lw["o_norm"], s["eps"]) * gate
-    return o.reshape(t, H * d) @ lw["wo"]
+    return o.reshape(t, H * d).astype(h.dtype) @ lw["wo"]
 
 
 def latent_mixer(h, lw, s):
@@ -338,9 +370,11 @@ def latent_mixer(h, lw, s):
     r = kv_a[:, DC:]  # not rotated
     kv = (c @ lw["wkv_b"]).reshape(t, A, DN + DV)
     k = jnp.concatenate([kv[..., :DN], jnp.broadcast_to(r[:, None], (t, A, DR))], -1)
-    scores = jnp.einsum("iad,jad->aij", q, k) / math.sqrt(DN + DR)
+    scores = jnp.einsum("iad,jad->aij", q, k,
+                        preferred_element_type=jnp.float32) / math.sqrt(DN + DR)
     scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores, -jnp.inf)
-    out = jnp.einsum("aij,jav->iav", jax.nn.softmax(scores, axis=-1), kv[..., DN:])
+    out = jnp.einsum("aij,jav->iav", jax.nn.softmax(scores, axis=-1).astype(h.dtype),
+                     kv[..., DN:])
     return out.reshape(t, A * DV) @ lw["wo"]
 
 
@@ -351,18 +385,19 @@ def swiglu(h, gate, up, down):
 def routed(h, lw, experts, s):
     """``(the held experts' part of the routed sum + the shared expert,
     the routing weights [T, E] over the whole router)``."""
-    scores = jax.nn.sigmoid(h @ lw["router"])  # [T, E]
+    scores = jax.nn.sigmoid(to_f32(h) @ to_f32(lw["router"]))  # [T, E]
     _, ids = jax.lax.top_k(scores + lw["router_bias"], s["top_k"])
     picked = jnp.take_along_axis(scores, ids, axis=-1)
     picked = picked / (picked.sum(-1, keepdims=True) + 1e-20) * s["scaling"]
     weight = jnp.zeros_like(scores).at[jnp.arange(h.shape[0])[:, None], ids].set(picked)
     def one(y, x):  # one expert at a time over every row
-        w_e, gate, up, down = x
-        return y + w_e[:, None] * swiglu(h, gate, up, down), None
+        w_e, gate, up_, down = x
+        return y + w_e[:, None] * to_f32(swiglu(h, gate, up_, down)), None
 
     held = weight[:, s["first"]: s["first"] + s["held"]].T  # [held, T]
-    y, _ = jax.lax.scan(one, jnp.zeros_like(h), (
+    y, _ = jax.lax.scan(one, jnp.zeros(h.shape, jnp.float32), (
         held, experts["we_gate"], experts["we_up"], experts["we_down"]))
+    y = y.astype(h.dtype)
     if s["Fs"]:
         y = y + swiglu(h, lw["ws_gate"], lw["ws_up"], lw["ws_down"])
     return y, weight
@@ -379,18 +414,18 @@ def layer(x, lw, kinds, s):
     return x + routed(h, lw["ffn"], lw["experts"], s)[0]
 
 
-def forward(config: dict, weights: dict, tokens) -> jnp.ndarray:
-    """Logits ``[T, V]`` in float32 for one sequence."""
+def forward(config: dict, weights: dict, tokens, compute=jnp.float32) -> jnp.ndarray:
+    """Logits ``[T, V]`` in float32 for one sequence, computed in ``compute``."""
     s = sizes(config)
     with jax.default_matmul_precision("highest"):
-        x = weights["embed"][jnp.asarray(tokens, jnp.int32)].astype(jnp.float32)
+        x = weights["embed"][jnp.asarray(tokens, jnp.int32)].astype(compute)
         for i, kinds in enumerate(s["kinds"]):
-            x = _layer_jit(x, layer_weights(config, weights, i), kinds, _frozen(s))
-        x = rms_norm(x, weights["final_norm"].astype(jnp.float32), s["eps"])
-        return x @ weights["lm_head"].astype(jnp.float32)
+            x = _layer_jit(x, layer_weights(config, weights, i, compute), kinds, _frozen(s))
+        x = rms_norm(x, weights["final_norm"], s["eps"])
+        return to_f32(x @ weights["lm_head"].astype(compute))
 
 
-def forward_many(config: dict, weights: dict, sequences: list) -> list:
+def forward_many(config: dict, weights: dict, sequences: list, compute=jnp.float32) -> list:
     """``forward`` of each sequence, one at a time. Every sequence is run
     at the length of the longest, filled up with token 0 behind its end
     and cut back: nothing here looks ahead, so the rows are those of the
@@ -401,7 +436,8 @@ def forward_many(config: dict, weights: dict, sequences: list) -> list:
     import numpy as np
 
     longest = max(len(seq) for seq in sequences)
-    return [np.asarray(forward(config, weights, list(seq) + [0] * (longest - len(seq)))[: len(seq)])
+    return [np.asarray(forward(config, weights, list(seq) + [0] * (longest - len(seq)),
+                               compute)[: len(seq)])
             for seq in sequences]
 
 

@@ -14,7 +14,8 @@ worth keeping goes on earlier lines and into ``bench_out/<workload>/``.
 ``--trace 0`` run up to the moment the window's plan has returned and the
 two scrapes are in, and every end-to-end number comes from that clean
 window. Only then does it touch the profiler: a second plan of the same
-mix and rate, and slices through ``/start_profile`` once its ramp is over
+mix and rate (its own order of work from the traffic file, not the window's
+replayed), and slices through ``/start_profile`` once its ramp is over
 (``traced_segment``). ``--trace 1`` (slices INSIDE the window, nothing of
 the run a measurement) stays for a tree without ``trace_in_run``.
 
@@ -34,6 +35,7 @@ T_PROCESS_START = time.perf_counter()
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
 import json  # noqa: E402
+import os  # noqa: E402
 import shutil  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
@@ -47,7 +49,9 @@ from benchmark.harness.client import Client  # noqa: E402
 from benchmark.harness.manifest import (  # noqa: E402
     BENCH_DIR, ROOT, Cell, kernel_counts, load_manifest, peaks_for,
 )
-from benchmark.harness.server import NO_CHIP, Server, child_env  # noqa: E402
+from benchmark.harness.server import (  # noqa: E402
+    NO_CHIP, Server, child_env, end_sessions, session_members,
+)
 
 WARM_SEED = 7_000_003  # the warm-up's traffic never depends on --seed
 COMPILES = ("vllm:step_bucket_compiles_total", "vllm:step_bucket_compiles")
@@ -186,7 +190,7 @@ async def profiled_slice(client: Client, d: Path, for_s: float) -> dict:
 async def traced_segment(client: Client, plan, for_s: float, most: int,
                          trace_root: Path) -> dict:
     """``--trace 2``, after the window has closed: drive ``plan`` (the same
-    mix and rate from the next seed, its own ramp, then ``SEGMENT_S``) and
+    mix and rate as segment 1, its own ramp, then ``SEGMENT_S``) and
     take profiler slices once the ramp is over. The first start and stop of
     the profiler in a process cost more than the others, so one throw-away
     pair goes first, inside the ramp. Up to ``most`` slices that hold the
@@ -259,7 +263,7 @@ async def drive(cell: Cell, server: Server, args, out_dir: Path) -> dict:
             # The same mix goes on after the window, unscored, for as long
             # as the profiler needs to give a device trace.
             more = traffic_mod.build_plan(cell.traffic, rate, 0.0, args.trace_extension,
-                                          args.seed + 1, vocab, positions)
+                                          args.seed, vocab, positions, segment=1)
             plan = traffic_mod.extend(plan, more, args.trace_extension)
             shutil.rmtree(out_dir / "trace", ignore_errors=True)
             side.append(asyncio.create_task(traced(
@@ -281,34 +285,43 @@ async def drive(cell: Cell, server: Server, args, out_dir: Path) -> dict:
             # too, and a drain under continued arrivals reads differently.
             more = traffic_mod.build_plan(cell.traffic, rate, ramp_s,
                                           SEGMENT_S + SEGMENT_MORE_S,
-                                          args.seed + 1, vocab, positions)
+                                          args.seed, vocab, positions, segment=1)
             res["trace_info"] = await traced_segment(
                 client, more, args.trace_seconds, args.trace_slices,
                 out_dir / "trace")
     return res
 
 
-def run_child(cmd: list[str], env: dict, log_path: Path, limit_s: float) -> int:
+def run_child(cmd: list[str], env: dict, log_path: Path, limit_s: float,
+              sessions: list[int]) -> int:
+    """Run the checker or the reducer in a session of its own, noted in
+    ``sessions``. Whether it returns or is cut at ``limit_s``, its whole
+    group is ended with it: a child that is killed alone leaves whatever
+    it started behind, and the driver refuses a run that does. The child is
+    reaped only AFTER its session is ended, so the pid the group is killed
+    by is still its own."""
     with open(log_path, "w") as f:
         proc = subprocess.Popen(cmd, cwd=str(ROOT), env=env, stdout=f,
                                 stderr=subprocess.STDOUT, start_new_session=True)
-        try:
-            return proc.wait(limit_s)
-        except subprocess.TimeoutExpired:
-            proc.kill()
-            proc.wait()
-            return -9
+        sessions.append(proc.pid)
+        deadline = time.monotonic() + limit_s
+        seen = os.WEXITED | os.WNOHANG | os.WNOWAIT  # look, do not reap
+        while os.waitid(os.P_PID, proc.pid, seen) is None and time.monotonic() < deadline:
+            time.sleep(0.05)
+        end_sessions([proc.pid])
+        return proc.wait()  # its own exit code, or -9 where it was cut
 
 
-def decide_correct(cell: Cell, check: dict) -> tuple[bool, list[str]]:
-    """Each number compared, beside its limit (``benchmark/cells/``)."""
-    lines, ok = [], True
-    for key, limit in cell.numbers["correct_limits"].items():
-        got = check[key]
-        good = got <= limit
-        ok &= good
-        lines.append(f"correct: {key} = {got:.6g} (limit {limit:g}) {'ok' if good else 'OVER'}")
-    return ok, lines
+def compared(key: str, value: float, limit: float) -> str:
+    return f"correct: {key} = {value:.6g} (limit {limit:g}) {'ok' if value <= limit else 'OVER'}"
+
+
+def decide_correct(cell: Cell, check: dict) -> tuple[bool, dict]:
+    """(``correct``, each number compared beside its limit): the limits are
+    the cell's (``benchmark/cells/``), the numbers the checker's."""
+    checked = {key: {"value": check[key], "limit": limit}
+               for key, limit in cell.numbers["correct_limits"].items()}
+    return all(c["value"] <= c["limit"] for c in checked.values()), checked
 
 
 def layer_context(cell: Cell, res: dict, scored: dict, trace: dict | None,
@@ -363,6 +376,36 @@ def layer_context(cell: Cell, res: dict, scored: dict, trace: dict | None,
 
 
 def main(argv: list[str]) -> int:
+    """One run. The result line is printed only once no process of the
+    sessions the run started (server, checker, reducer) is left: whatever is
+    found there at the end is killed and waited for, and only a process that
+    outlives that too (30 s under SIGKILL: stuck in the kernel) fails the run,
+    without a line."""
+    sessions: list[int] = []
+    left: list = []
+    try:
+        code, result = run(argv, sessions)
+    finally:
+        found = session_members(sessions)
+        if found:
+            log(f"{len(found)} process(es) of this run were still there at its end and "
+                f"are killed: {found}")
+            left = end_sessions(sessions, 30.0)
+    if left:
+        log(f"FAILED: {len(left)} process(es) of this run outlived it: {left}")
+        return 1
+    if result is not None:
+        # Each number compared beside its limit, printed ONCE: the last lines
+        # on standard error; the line on standard output has them as its
+        # last key.
+        for key, c in result["checked"].items():
+            print(compared(key, **c), file=sys.stderr)
+        print(json.dumps(result))
+    return code
+
+
+def run(argv: list[str], sessions: list[int]) -> tuple[int, dict | None]:
+    """The run itself: (exit code, the object of the last line or None)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -397,12 +440,13 @@ def main(argv: list[str]) -> int:
     log(f"cell {cell.name}: config {cell.config_name}, traffic {cell.traffic_name}, "
         f"seed {args.seed}, {args.seconds} s, trace {args.trace}")
     server.start()
+    sessions.append(server.proc.pid)
     facts = None
     try:
         res = asyncio.run(drive(cell, server, args, out_dir))
     except RunFailed as e:
         log(f"FAILED: {e}\n--- server log ---\n{server.log_tail()}")
-        return 1
+        return 1, None
     except Exception:
         log(f"--- server log ---\n{server.log_tail()}")
         raise
@@ -410,32 +454,34 @@ def main(argv: list[str]) -> int:
         facts = server.stop()
     if not facts:
         log(f"FAILED: the server left no device facts\n{server.log_tail()}")
-        return 1
+        return 1, None
     if facts["platform"] != "tpu":
         log(f"FAILED: the server ran on {facts}")
-        return 1
+        return 1, None
     log(f"server stopped; device {facts}")
 
     # The reference, on the chip the server has just freed.
     env = child_env(cache_dir)
     job = {"config": cell.hf_config, "family": cell.family, "seed": args.seed,
            "chips": cell.chips, "samples": res["samples"],
-           "dtype": {**cell.engine_args, **extra}.get("dtype", "bfloat16")}
+           "dtype": {**cell.engine_args, **extra}.get("dtype", "bfloat16"),
+           # A limit on a `_factor` asks for the reference's second pass.
+           "factors": any(k.endswith("_factor") for k in cell.numbers["correct_limits"])}
     (out_dir / "check_job.json").write_text(json.dumps(job))
     rc = run_child([sys.executable, str(BENCH_DIR / "harness" / "checker.py"),
                     str(out_dir / "check_job.json"), str(out_dir / "check.json")],
-                   env, out_dir / "checker.log", 600.0)
+                   env, out_dir / "checker.log", 600.0, sessions)
     if rc != 0:
         log(f"FAILED: the checker exited with {rc}\n{(out_dir / 'checker.log').read_text()[-3000:]}")
-        return 1
+        return 1, None
     check = json.loads((out_dir / "check.json").read_text())
-    correct, lines = decide_correct(cell, check)
-    for line in lines:
-        log(line)
+    correct, checked = decide_correct(cell, check)
     log(f"check: {json.dumps({k: v for k, v in check.items() if k != 'per_sample'})}")
+    # A sequence apart: worst_sequence_mean_abs is the largest of these means.
+    log("check, by sequence (prompt tokens, mean_abs, max_abs): "
+        + json.dumps([[p["prompt"], p["mean_abs"], p["max_abs"]] for p in check["per_sample"]]))
     if args.check_only:
-        print(json.dumps({"correct": correct, "check": check, "device": facts}))
-        return 0
+        return 0, {"correct": correct, "check": check, "device": facts, "checked": checked}
 
     scored = scoring.score(res["records"], args.seconds, cell.chips)
     (out_dir / "requests.jsonl").write_text("".join(json.dumps({
@@ -476,15 +522,15 @@ def main(argv: list[str]) -> int:
                f"first {res['trace_info'].get('first_stop_s')} s" if args.trace == 2 else ""))
         if not slices:
             log("FAILED: no trace with a device plane")
-            return 1
+            return 1, None
         env_cpu = dict(env, JAX_PLATFORMS="cpu")
         rc = run_child([sys.executable, str(BENCH_DIR / "harness" / "trace_reduce.py"),
                         str(out_dir / "trace.json"), *(sl["dir"] for sl in slices)],
-                       env_cpu, out_dir / "trace_reduce.log", 300.0)
+                       env_cpu, out_dir / "trace_reduce.log", 300.0, sessions)
         if rc != 0:
             log(f"FAILED: trace reduction exited with {rc}\n"
                 f"{(out_dir / 'trace_reduce.log').read_text()[-3000:]}")
-            return 1
+            return 1, None
         trace = json.loads((out_dir / "trace.json").read_text())
         if args.trace == 2:
             shutil.rmtree(out_dir / "trace", ignore_errors=True)  # reduced: not kept
@@ -511,9 +557,9 @@ def main(argv: list[str]) -> int:
     else:
         result["metrics"] = measured
     result["device"] = device
+    result["checked"] = checked
     (out_dir / "result.json").write_text(json.dumps(result))
-    print(json.dumps(result))
-    return 0
+    return 0, result
 
 
 if __name__ == "__main__":

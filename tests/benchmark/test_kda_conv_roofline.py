@@ -5,10 +5,8 @@ convolution for the tokens and live rows that
 device seconds of ``kda_conv_kernel``, in per cent; None from a trace
 without the kernel (the parent of PR 38) or a program without the counters.
 
-The metric has NO entry in ``BENCHMARK.json`` yet: an entry goes at the end
-of ``per_layer``, and ``test_kda_chunk_roofline.py`` holds
-``kda_chunk_roofline`` to that place (PERF.md section 7, From PR 38). The
-entry's form is held here for the day it is added."""
+The entry in ``BENCHMARK.json`` came with PR 42 (appended after
+``kda_chunk_roofline``); its form is held here."""
 
 from __future__ import annotations
 
@@ -37,9 +35,10 @@ def ctx_of(slice_deltas, trace):
 
 def test_an_entry_is_the_one_cells_under_the_kernels():
     entries = [m for m in MANIFEST["per_layer"] if m["name"] == NAME]
-    assert entries in ([], [{
+    assert entries == [{
         "name": NAME, "unit": "%", "better": "higher", "source": "device_trace",
-        "layer": "kernels", "moves": "itl_p95_ms", "workloads": [CELL_NAME]}])
+        "layer": "kernels", "moves": "itl_p95_ms", "workloads": [CELL_NAME]}]
+    assert NAME in {m["name"] for m in CELL.per_layer}
     for other in ("mistral7b.chat", "sdar30b.chat"):
         assert NAME not in {m["name"] for m in M.Cell(MANIFEST, other).per_layer}
 

@@ -86,7 +86,7 @@ def test_the_shares_of_the_reference_sum_to_its_uncut_layer():
 
 
 def served_samples(tmp_path, seed, dtype="float32", lengths=(6, 9, 23, 70), config=None,
-                   **engine):
+                   max_tokens=12, **engine):
     """Greedy answers with logprobs in the shape the checker reads; the
     70-token prompt is prefilled over three steps beside the others'
     decode rows, so the carried state and the latent pages are both read
@@ -99,7 +99,8 @@ def served_samples(tmp_path, seed, dtype="float32", lengths=(6, 9, 23, 70), conf
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(0, 240, n).tolist() for n in lengths]
     outs = llm.generate([{"prompt_token_ids": p} for p in prompts],
-                        SamplingParams(temperature=0.0, max_tokens=12, logprobs=5, ignore_eos=True))
+                        SamplingParams(temperature=0.0, max_tokens=max_tokens, logprobs=5,
+                                       ignore_eos=True))
     llm.shutdown()
     return [{"prompt": p, "tokens": list(o.outputs[0].token_ids),
              "top_logprobs": [{str(t): lp.logprob for t, lp in top.items()}

@@ -4,13 +4,18 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import json
+import os
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import pytest
 
 from benchmark import run_cell as rc
 from benchmark.harness.manifest import Cell, load_manifest
-from benchmark.harness.server import engine_cli_args
+from benchmark.harness.server import end_sessions, engine_cli_args, session_members
 
 from .xplane_writer import xspace
 
@@ -34,13 +39,15 @@ def test_each_number_compared_is_printed_beside_its_limit(key):
     cell = Cell(load_manifest(), "mistral7b.chat")
     limits = cell.numbers["correct_limits"]
     sound = {k: v / 2 for k, v in limits.items()}
-    ok, lines = rc.decide_correct(cell, sound)
-    assert ok and len(lines) == len(limits) == 3
-    line = next(l for l in lines if f"{key} = " in l)
-    assert f"limit {limits[key]:g}" in line and "ok" in line
+    ok, checked = rc.decide_correct(cell, sound)
+    assert ok and list(checked) == list(limits) and len(limits) == 3
+    assert checked[key] == {"value": sound[key], "limit": limits[key]}
+    line = rc.compared(key, **checked[key])
+    assert f"{key} = " in line and f"limit {limits[key]:g}" in line and "ok" in line
     # One number over its limit is enough, whatever the others read.
-    bad, lines = rc.decide_correct(cell, {**sound, key: limits[key] * 2})
-    assert not bad and sum("OVER" in l for l in lines) == 1
+    bad, checked = rc.decide_correct(cell, {**sound, key: limits[key] * 2})
+    assert not bad
+    assert sum("OVER" in rc.compared(k, **c) for k, c in checked.items()) == 1
 
 
 @pytest.mark.parametrize("args,want", [
@@ -134,7 +141,9 @@ def _drive_trace2(tmp_path, monkeypatch, no_device=()):
     cell.numbers = {**cell.numbers, "warmup_waves": []}
     StubClient.log, StubClient.no_device = [], no_device
     monkeypatch.setattr(rc, "Client", StubClient)
-    monkeypatch.setattr(rc, "SEGMENT_S", 0.45)
+    # Room for every attempt on a loaded machine: the segment ends as soon as
+    # its slices are taken, so a run that is on time takes no longer for it.
+    monkeypatch.setattr(rc, "SEGMENT_S", 3.0)
     monkeypatch.setattr(rc, "SEGMENT_MORE_S", 0.6)
     args = argparse.Namespace(check_only=False, rate=None, seconds=0.3, seed=5, trace=2,
                               trace_seconds=0.1, trace_slices=2, trace_extension=60.0)
@@ -215,3 +224,84 @@ def test_the_three_kinds_of_run_are_taken(trace, capsys):
     ap_ok = ["--workload", "no.such-cell", "--seed", "1", "--seconds", "1", "--trace", trace]
     with pytest.raises(KeyError, match="no workload"):
         rc.main(ap_ok)
+
+
+# ---------------------------------------------------------------------------
+# No process of a run outlives it
+# ---------------------------------------------------------------------------
+
+# A child that starts a grandchild (which stays in the child's session),
+# writes the grandchild's pid down and then either hangs or returns.
+CHILD = ("import subprocess, sys, time\n"
+         "p = subprocess.Popen([sys.executable, '-c', 'import time; time.sleep(120)'])\n"
+         "open(sys.argv[1], 'w').write(str(p.pid))\n"
+         "time.sleep(float(sys.argv[2]))\n")
+
+
+def alive(pid: int) -> bool:
+    try:
+        stat = Path(f"/proc/{pid}/stat").read_text()
+    except OSError:
+        return False
+    return stat[stat.rindex(")") + 2] != "Z"
+
+
+@pytest.mark.parametrize("hangs,limit_s,want", [(120.0, 5.0, -9), (0.0, 60.0, 0)],
+                         ids=["cut_at_its_limit", "returns_and_leaves_it"])
+def test_a_child_takes_its_grandchild_with_it(tmp_path, hangs, limit_s, want):
+    """PR 28 was refused as ``process_left_running``: the checker and the
+    reducer run in sessions of their own, and a child killed alone at its
+    time limit leaves whatever it started. The whole group goes, whether
+    the child was cut or came back."""
+    sessions: list[int] = []
+    began = time.perf_counter()
+    code = rc.run_child([sys.executable, "-c", CHILD, str(tmp_path / "pid"), str(hangs)],
+                        dict(os.environ), tmp_path / "child.log", limit_s, sessions)
+    assert code == want and time.perf_counter() - began < 30.0
+    grandchild = int((tmp_path / "pid").read_text())
+    assert len(sessions) == 1 and session_members(sessions) == []
+    assert not alive(grandchild)
+
+
+@pytest.mark.parametrize("dies", [True, False], ids=["it_dies", "it_outlives_the_kill"])
+def test_what_a_run_left_behind_is_ended_before_its_line(monkeypatch, capsys, dies):
+    """``main`` looks, at its end, through every session the run started and
+    ends what it finds there. The line is printed once nothing is left; a
+    process that outlives the kill as well (here: the kill reports so) fails
+    the run, which then prints none."""
+    started = []
+    result = {"correct": True, "checked": {}}
+
+    def run(argv, sessions):
+        p = subprocess.Popen([sys.executable, "-c", "import time; time.sleep(120)"],
+                             start_new_session=True)
+        started.append(p)
+        sessions.append(p.pid)
+        return 0, result
+
+    monkeypatch.setattr(rc, "run", run)
+    if not dies:
+        monkeypatch.setattr(rc, "end_sessions",
+                            lambda sessions, wait_s: end_sessions(sessions) or [(1, "stuck")])
+    try:
+        assert rc.main([]) == (0 if dies else 1)
+        out = capsys.readouterr().out.splitlines()
+        assert any("still there at its end" in line for line in out)
+        assert any("outlived it" in line for line in out) != dies
+        assert [json.loads(line) for line in out if line.startswith("{")] == ([result] if dies else [])
+        assert started[0].wait(10) == -9 and session_members([started[0].pid]) == []
+    finally:
+        end_sessions([p.pid for p in started])
+
+
+def test_a_clean_run_prints_each_number_beside_its_limit_last(monkeypatch, capsys):
+    result = {"correct": True, "attempted": 3, "failed": 0, "metrics": {}, "device": {},
+              "checked": {"p50_abs": {"value": 0.0101, "limit": 0.022}}}
+    monkeypatch.setattr(rc, "run", lambda argv, sessions: (0, result))
+    assert rc.main([]) == 0
+    out = capsys.readouterr()
+    assert json.loads(out.out.splitlines()[-1]) == result
+    assert list(json.loads(out.out.splitlines()[-1]))[-1] == "checked"
+    # Once: the lines on standard error, and no second copy in the log.
+    assert out.err.splitlines()[-1] == "correct: p50_abs = 0.0101 (limit 0.022) ok"
+    assert (out.out + out.err).count("p50_abs = ") == 1

@@ -67,6 +67,9 @@ def test_only_requests_due_in_the_window_are_scored_but_every_token_in_it_counts
     out = S.score([ramp, inside], seconds=10.0, chips=1)
     assert out["attempted"] == 1
     assert out["metrics"]["output_tokens_per_s"] == pytest.approx(5 / 10.0)
+    # Of the window's 5 tokens the ramp's request still owed 2 when it opened.
+    assert out["facts"]["window_output_tokens"] == pytest.approx(5.0)
+    assert out["facts"]["window_output_tokens_owed_by_the_ramp"] == pytest.approx(2.0)
     assert S.score([ramp, inside], seconds=10.0, chips=4)["metrics"][
         "output_tokens_per_s"] == pytest.approx(5 / 40.0)
 
