@@ -17,7 +17,7 @@ def test_schedule_new_requests_full_prefill():
     assert len(sched.running) == 3
     # Block allocation covers the prompt.
     for r in reqs:
-        assert len(out.scheduled_new_reqs[0].block_ids) >= 50 // 16
+        assert len(out.scheduled_new_reqs[0].block_ids[0]) >= 50 // 16
 
 
 def test_chunked_prefill_respects_token_budget():

@@ -27,8 +27,8 @@ def test_out_of_window_blocks_freed():
         num_blocks=64, block_size=4, enable_caching=False, sliding_window=16
     )
     req = make_request("a", 64)
-    blocks = m.allocate_slots(req, 64)
-    assert blocks is not None and len(blocks) == 16
+    (blocks,) = m.allocate_slots(req, 64)  # one list a KV group
+    assert len(blocks) == 16
     free_before = m.get_num_free_blocks()
 
     # Advance to computed=64, schedule one more token: queries at pos >= 64
@@ -99,7 +99,7 @@ def test_window_aware_prefix_hit():
     m.free(r1)
 
     r2 = make_hashed_request("b", prompt, bs)
-    hit_blocks, hit_tokens = m.get_computed_blocks(r2)
+    (hit_blocks,), hit_tokens = m.get_computed_blocks(r2)
     # Hit capped at num_tokens-1 -> 16 blocks / 64 tokens; only the last
     # `required` blocks are materialized, the prefix is null stand-ins.
     assert hit_tokens == 64
@@ -108,7 +108,7 @@ def test_window_aware_prefix_hit():
     assert not any(b.is_null for b in hit_blocks[12:])
     # The hit is usable: allocation on top of it succeeds.
     assert m.allocate_slots(
-        r2, 1, new_computed_blocks=hit_blocks, num_new_computed_tokens=64
+        r2, 1, new_computed_blocks=(hit_blocks,), num_new_computed_tokens=64
     ) is not None
 
 
@@ -130,7 +130,7 @@ def test_window_hit_survives_broken_prefix():
     m.free(r1)
 
     r2 = make_hashed_request("b", prompt, bs)
-    hit_blocks, hit_tokens = m.get_computed_blocks(r2)
+    (hit_blocks,), hit_tokens = m.get_computed_blocks(r2)
     # Runs: [0..13) cached, block 13 missing, [14..16) cached. The tail
     # run (2 blocks) is too short; the next run ends at block 13 ->
     # hit = 13 blocks = 52 tokens, last 4 real, 9 nulls.
@@ -157,7 +157,7 @@ def test_window_hit_plain_prefix_fallback():
     m.free(r1)
 
     r2 = make_hashed_request("b", prompt, bs)
-    hit_blocks, hit_tokens = m.get_computed_blocks(r2)
+    (hit_blocks,), hit_tokens = m.get_computed_blocks(r2)
     assert hit_tokens == 8
     assert len(hit_blocks) == 2
     assert not any(b.is_null for b in hit_blocks)

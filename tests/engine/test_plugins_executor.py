@@ -107,7 +107,7 @@ def test_executor_seam(tmp_path):
             scheduled_new_reqs=[NewRequestData(
                 req_id="r0", prompt_token_ids=[5, 9, 11],
                 sampling_params=SamplingParams(max_tokens=4, temperature=0.0),
-                block_ids=[1], num_computed_tokens=0,
+                block_ids=([1],), num_computed_tokens=0,
             )],
             num_scheduled_tokens={"r0": 3},
             total_num_scheduled_tokens=3,

@@ -54,8 +54,14 @@ def exposed() -> dict[str, set[tuple[str, str]]]:
             prefix_cache_queries=8, prefix_cache_hits=4,
             step_first_use_s=7.5, sampler_fallback_rows=3,
             steps_by_kind={"decode": 9, "mixed": 1},
+            step_tokens_by_kind={"decode": 9, "mixed": 40},
             attn_context_tokens_by_kind={"decode": 180, "mixed": 10,
-                                         "block": 11, "latent": 12},
+                                         "block": 11, "latent": 12,
+                                         "window": 13},
+            # KV groups over one pool, window layers beside full ones.
+            kv_group_pages_in_use=[3, 1], kv_pages_allocated=[5, 5],
+            kv_window_pages_freed=4, kv_page_steps={"held": 4, "pool": 10},
+            window_attn_rows=6, window_attn_pairs=9,
             linear_attn_state_rows=4,
             linear_attn_tokens_by_kind={"chunk": 3, "decode": 2},
             attn_ragged_pairs={"live": 26, "computed": 100},

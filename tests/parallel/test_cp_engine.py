@@ -68,7 +68,7 @@ def test_striped_manager_positions():
     first = mgr.allocate_slots(req, 10)  # blocks 0..2 (ceil(10/4))
     req.num_computed_tokens = 10
     second = mgr.allocate_slots(req, 13)  # blocks 3..5 (ceil(23/4)=6)
-    ids = [b.block_id for b in first + second]
+    ids = [b.block_id for b in first[0] + second[0]]
     for k, bid in enumerate(ids):
         assert mgr.block_pool.color_of(bid) == k % 2, (k, bid)
 

@@ -816,3 +816,138 @@ def test_kimi_linear_scan_reads_each_weight_once_in_place(
         f"the period scan moves weight-sized temporaries "
         f"(>= {KIMI_WEIGHT_BYTES} bytes): {moved}"
     )
+
+
+# Laguna-XS.2 (``lagunaxs2.code-mixed``): window layers of 64 query heads
+# and full layers of 48 over 8 KV heads of 128, window 512, the cell's two
+# step shapes (512 tokens x 64 rows mixed, 64 rows decode-only) at the whole
+# page table of 12,288 tokens. A group of 6 query heads a KV head is new to
+# the tilings.
+LAGUNA_CONFIG = "benchmark/configs/laguna-xs.2.json"
+LAGUNA_ROWS, LAGUNA_PAGES, LAGUNA_BLOCKS = 64, 768, 4096
+LAGUNA_GROUP_LAYERS = 10
+
+
+def _laguna_attn_args(sharding, t, r, q_heads, ragged):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    args = [
+        s((t, q_heads, D), jnp.bfloat16),
+        s((LAGUNA_GROUP_LAYERS, LAGUNA_BLOCKS, PAGE, 2 * 8, D), jnp.bfloat16),
+        s((1,), jnp.int32), s((r,), jnp.int32), s((r, LAGUNA_PAGES), jnp.int32),
+    ]
+    if ragged:
+        args.append(s((r + 1,), jnp.int32))
+    return args + [s((1,), jnp.int32)]
+
+
+@pytest.mark.parametrize("window", [512, 0], ids=["window512", "full"])
+@pytest.mark.parametrize("q_heads", [48, 64])
+def test_laguna_ragged_kernel_compiles(one_chip, q_heads, window):
+    """The mixed step's kernel at both kinds' head counts, with the window
+    as the traced scalar the layers hand it and with none, under its own
+    name in the trace. 6 query heads a KV head: 80 query tokens a block
+    (480 rows of the 512 a KV head's wide tile holds, the most that are
+    whole sublane tiles), and the narrow rung of 8 tokens is kept for the
+    decode rows of a mixed step."""
+    from vllm_tpu.ops.rpa_kernel import ragged_paged_attention, ragged_tiling
+
+    tiling = ragged_tiling(q_heads, 16, D, PAGE, jnp.bfloat16, jnp.bfloat16,
+                           512, LAGUNA_PAGES)
+    assert tiling.q_per_blk == {48: 80, 64: 64}[q_heads]
+    assert tiling.narrow_q == {48: 8, 64: 2}[q_heads]
+    assert tiling.kv_pages_per_blk == 32
+    assert (tiling.vmem_limit_bytes or 0) <= 64 << 20
+    name = "rpa_kernel_window" if window else "rpa_kernel"
+    fn = functools.partial(ragged_paged_attention, sm_scale=D**-0.5, name=name)
+    text = jax.jit(lambda *a: fn(*a, sliding_window=jnp.int32(window))).lower(
+        *_laguna_attn_args(one_chip, 512, LAGUNA_ROWS, q_heads, True)
+    ).compile().as_text()
+    assert f'"{name}"' in text or name in text
+
+
+@pytest.mark.parametrize("window", [512, 0], ids=["window512", "full"])
+@pytest.mark.parametrize("q_heads", [48, 64])
+def test_laguna_decode_kernel_compiles(one_chip, q_heads, window):
+    from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
+
+    name = "rpa_decode_kernel_window" if window else "rpa_decode_kernel"
+    fn = functools.partial(decode_paged_attention, sm_scale=D**-0.5, name=name)
+    text = jax.jit(lambda *a: fn(*a, sliding_window=jnp.int32(window))).lower(
+        *_laguna_attn_args(one_chip, LAGUNA_ROWS, LAGUNA_ROWS, q_heads, False)
+    ).compile().as_text()
+    assert name in text
+
+
+@pytest.mark.parametrize("t,decode_only", [(64, True), (512, False)],
+                         ids=["decode_T64_R64", "mixed_T512_R64"])
+def test_laguna_step_compiles_and_reads_each_weight_in_place(
+    one_chip, monkeypatch, t, decode_only
+):
+    """Both step programs of ``lagunaxs2.code-mixed`` over ONE period less
+    than the whole (layer 0, one scanned period... the file's 40 layers cut
+    to 12: layer 0, two periods, the tail) at the published widths, with a
+    block table and a slot mapping a KV group: the two kinds' kernels under
+    their two names, the grouped matmul, and no iteration of the period
+    scan slicing a weight out of its stack (PR 40's finding)."""
+    import json
+    import pathlib
+
+    from transformers import PretrainedConfig
+
+    from vllm_tpu.models.laguna import LagunaForCausalLM
+    from vllm_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    config = json.loads((root / LAGUNA_CONFIG).read_text())
+    config.pop("bench")
+    for key in ("layer_types", "mlp_layer_types", "num_attention_heads_per_layer"):
+        config[key] = config[key][:12]
+    config["num_hidden_layers"] = 12
+    from vllm_tpu.layers.rotary import serving_positions
+
+    with serving_positions(LAGUNA_PAGES * PAGE):  # as the worker does
+        model = LagunaForCausalLM(PretrainedConfig.from_dict(config), dtype=jnp.bfloat16)
+    assert (model.periods, model.tail, model.num_kv_groups) == (2, 3, 4)
+    assert model.heads_of == {"full_attention": 48, "sliding_attention": 64}
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(model.init_dummy_params, jax.random.PRNGKey(0)))
+    cache = s((model.kv_group_layers, LAGUNA_BLOCKS, PAGE, 16, D), jnp.bfloat16)
+    r, g = LAGUNA_ROWS, model.num_kv_groups
+    md = attention.AttentionMetadata(
+        positions=s((t,), jnp.int32), slot_mapping=s((g, t), jnp.int32),
+        block_tables=s((g, r, LAGUNA_PAGES), jnp.int32),
+        seq_lens=s((r,), jnp.int32), query_start_loc=s((r + 1,), jnp.int32),
+        token_req_idx=s((t,), jnp.int32), logits_indices=s((r,), jnp.int32),
+        num_seqs=s((1,), jnp.int32), decode_only=decode_only,
+    )
+    text = jax.jit(model.apply, donate_argnums=(1,)).lower(
+        params, cache, s((t,), jnp.int32), md
+    ).compile().as_text()
+
+    base = "rpa_decode_kernel" if decode_only else "rpa_kernel"
+    assert base + "_window" in text and re.search(base + r'(?!_window)\b', text)
+    assert "gmm" in text
+    squeezed = lambda dims: tuple(d for d in dims if d != 1)
+    shapes = {squeezed(a.shape[k:]) for a in jax.tree.leaves(params["periods"])
+              for k in (1, 2) if a.ndim >= 3}
+    shapes |= {sh[:-2] + sh[:-3:-1] for sh in shapes if len(sh) >= 2}
+    smallest = 2048 * 512 * 2  # a shared expert's matrix
+    moved = [
+        (name, opcode, dims, nbytes)
+        for name, opcode, nbytes, dims in _while_body_instructions(text)
+        if nbytes >= smallest
+        and ("dynamic-slice" in name or opcode == "dynamic-slice"
+             or (opcode in ("copy", "transpose") and squeezed(dims) in shapes))
+    ]
+    assert not moved, (
+        f"the period scan moves weight-sized temporaries (>= {smallest} bytes): {moved}")

@@ -112,8 +112,14 @@ class CacheConfig:
     # (= cp mesh ranks); a request's k-th block comes from color k % cp.
     # Set from ParallelConfig.context_parallel_size at engine-config build.
     num_kv_stripes: int = 1
-    # Populated at model load from the model's attention window (None =
-    # full attention); drives out-of-window block freeing.
+    # Populated at model load from the layers' KV specs
+    # (``core/kv_cache_utils.group_kv_cache_specs``): the groups the cache
+    # manager keeps a block table and a freeing policy for. A uniform
+    # model has one; ``sliding_window`` is then its window (None = full
+    # attention) and what drives out-of-window block freeing. With
+    # several groups each group's spec says whether it has a window, and
+    # ``sliding_window`` is None.
+    kv_cache_groups: list | None = None
     sliding_window: int | None = None
     # External KV store ("host_offload" = content-addressed host-RAM tier
     # reloading evicted prefixes; "fabric" = the full tiered KV fabric:

@@ -1,8 +1,14 @@
 """Request-level KV block allocation with prefix-cache reuse.
 
-Reference analog: ``vllm/v1/core/kv_cache_manager.py:106``. Round-1 scope is
-a single full-attention KV group (the reference's UnitaryKVCacheCoordinator
-path); the interface leaves room for hybrid groups (sliding window, mamba).
+Reference analog: ``vllm/v1/core/kv_cache_manager.py:106`` with the
+coordinators of ``kv_cache_coordinator.py`` folded in: the manager keeps a
+list of single-type managers, ONE A KV GROUP (``kv_cache_utils.
+group_kv_cache_specs``), over one ``BlockPool``. A uniform model is a list
+of one, and every count below is that manager's; a model that mixes window
+and full layers has a block table a group and request, needs the SUM of the
+groups' new blocks from the pool, and its window groups hand the pages
+their window has passed back to it as the request advances. Recurrent-state
+slots are not groups: they stay with the runner (ROADMAP D3).
 """
 
 from __future__ import annotations
@@ -11,9 +17,14 @@ from dataclasses import dataclass
 from math import ceil
 
 from vllm_tpu.core.block_pool import BlockPool
-from vllm_tpu.core.kv_cache_utils import KVCacheBlock
+from vllm_tpu.core.kv_cache_utils import KVCacheBlock, KVCacheGroupSpec
+from vllm_tpu.core.single_type_managers import (
+    FullAttentionManager,
+    SlidingWindowManager,
+)
 from vllm_tpu.logger import init_logger
 from vllm_tpu.request import Request
+from vllm_tpu.tracing import trace_span
 
 logger = init_logger(__name__)
 
@@ -43,66 +54,92 @@ class KVCacheManager:
         sliding_window: int | None = None,
         event_sink=None,
         num_stripes: int = 1,
+        kv_cache_groups: list[KVCacheGroupSpec] | None = None,
     ) -> None:
         self.block_size = block_size
         # Context parallelism: a request's k-th context block comes from
         # pool color k % num_stripes (= the cp rank holding that page).
         self.num_stripes = num_stripes
-        self.sliding_window = sliding_window
         self.enable_caching = enable_caching
         self.block_pool = BlockPool(
             num_blocks, enable_caching,
             event_sink=event_sink, block_size=block_size,
             num_colors=num_stripes,
         )
-        # Per-attention-type policy (reference:
-        # single_type_kv_cache_manager.py family under the unitary
-        # coordinator): full attention vs sliding window (window-aware
-        # prefix hits + out-of-window freeing). Hybrid per-group
-        # coordination plugs in here.
-        from vllm_tpu.core.single_type_managers import (
-            FullAttentionManager,
-            SlidingWindowManager,
+        # One policy a group (full attention; sliding window: window-aware
+        # prefix hits + out-of-window freeing). Without the worker's
+        # groups: one group, a window group where ``sliding_window`` says
+        # so.
+        windows = (
+            [sliding_window] if kv_cache_groups is None
+            else [g.sliding_window for g in kv_cache_groups]
         )
-
-        self.type_manager = (
-            SlidingWindowManager(self.block_pool, block_size, sliding_window)
-            if sliding_window is not None
-            else FullAttentionManager(self.block_pool, block_size)
-        )
-
-        self.req_to_blocks: dict[str, list[KVCacheBlock]] = {}
-        # Sliding window: first not-yet-freed block index per request, so
-        # each block is nulled exactly once (no O(seq_len) rescans).
-        self._first_live_blk: dict[str, int] = {}
-        # How many leading blocks of each request are already registered in
-        # the prefix cache (avoids re-hashing on every allocate).
-        self.num_cached_blocks: dict[str, int] = {}
+        if len(windows) > 1 and num_stripes > 1:
+            raise NotImplementedError(
+                "context parallelism with several KV groups: the pool's "
+                "stripes follow ONE block table's order"
+            )
+        self.managers = [
+            FullAttentionManager(self.block_pool, block_size, g)
+            if window is None
+            else SlidingWindowManager(self.block_pool, block_size, window, g)
+            for g, window in enumerate(windows)
+        ]
+        self.num_groups = len(self.managers)
+        # What a lookup that hit nothing and an allocation that took
+        # nothing return: a list a group, as every block table here is.
+        self._no_blocks: tuple[list[KVCacheBlock], ...] = tuple(
+            [] for _ in self.managers)
+        self._window_managers = [
+            m for m in self.managers if m.sliding_window is not None
+        ]
+        # Group 0's tables and registered counts under the names they had
+        # when there was one group (the scheduler's external-load path and
+        # the tests read them).
+        self.req_to_blocks = self.managers[0].req_to_blocks
+        self.num_cached_blocks = self.managers[0].num_cached_blocks
         # req_id -> token floor above which prefix-cache registration is
         # held back while an external KV load is unconfirmed.
         self.cache_reg_cap: dict[str, int] = {}
         self.prefix_cache_stats = PrefixCacheStats()
+        # Cumulative: pages the window groups gave back before their
+        # request ended (the managers count what each group took).
+        self.window_pages_freed = 0
 
     # ------------------------------------------------------------------
     # Prefix cache lookup (waiting -> running transition)
     # ------------------------------------------------------------------
 
-    def get_computed_blocks(self, request: Request) -> tuple[list[KVCacheBlock], int]:
-        """Longest cached prefix for a new request.
+    def get_computed_blocks(
+        self, request: Request
+    ) -> tuple[tuple[list[KVCacheBlock], ...], int]:
+        """Longest cached prefix for a new request: the hit blocks, a list
+        a group, and the hit's tokens.
 
         Caps the hit at ``num_tokens - 1`` so at least one token is actually
         scheduled (the model must produce logits for sampling) — reference:
-        ``find_longest_cache_hit`` semantics in ``kv_cache_utils.py``.
+        ``find_longest_cache_hit`` semantics in ``kv_cache_utils.py``. With
+        several groups the hit is the longest length at which EVERY group
+        holds what its layers will read there (a full group the contiguous
+        prefix, a window group the last run that covers the window): each
+        group is asked under the others' answer until all give the same
+        (reference: ``HybridKVCacheCoordinator.find_longest_cache_hit``).
         """
         if not self.enable_caching or not request.block_hashes:
-            return [], 0
+            return self._no_blocks, 0
         max_hit_blocks = (request.num_tokens - 1) // self.block_size
-        hit_blocks = self.type_manager.find_longest_cache_hit(
-            request, max_hit_blocks
-        )
-        num_hit_tokens = len(hit_blocks) * self.block_size
+        while True:
+            hits = [
+                m.find_longest_cache_hit(request, max_hit_blocks)
+                for m in self.managers
+            ]
+            shortest = min(len(h) for h in hits)
+            if all(len(h) == shortest for h in hits):
+                break
+            max_hit_blocks = shortest
+        num_hit_tokens = shortest * self.block_size
         self.prefix_cache_stats.observe(request.num_tokens, num_hit_tokens)
-        return hit_blocks, num_hit_tokens
+        return (tuple(hits) if shortest else self._no_blocks), num_hit_tokens
 
     # ------------------------------------------------------------------
     # Slot allocation (every scheduling of a request)
@@ -112,90 +149,90 @@ class KVCacheManager:
         self,
         request: Request,
         num_new_tokens: int,
-        new_computed_blocks: list[KVCacheBlock] | None = None,
+        new_computed_blocks: tuple[list[KVCacheBlock], ...] | None = None,
         num_new_computed_tokens: int = 0,
         num_lookahead_tokens: int = 0,
         num_stored_tokens: int | None = None,
-    ) -> list[KVCacheBlock] | None:
-        """Ensure the request has blocks covering its tokens after this step.
+    ) -> tuple[list[KVCacheBlock], ...] | None:
+        """Ensure the request has blocks covering its tokens after this step
+        in every group.
 
+        ``new_computed_blocks``: what ``get_computed_blocks`` returned.
         ``num_stored_tokens``: how many of the request's tokens hold their
-        FINAL K/V once this step has run, where that is not all it runs
-        (a denoising pass of generation by diffusion overwrites its block's
+        FINAL K/V once this step has run, where that is not all it runs (a
+        denoising pass of generation by diffusion overwrites its block's
         slots again): only pages wholly below it are registered for the
         prefix cache.
 
-        Returns the newly-allocated blocks, or None if the pool cannot
-        satisfy the request (caller preempts). Reference:
+        Returns the newly-allocated blocks, a list a group, or None if the
+        pool cannot satisfy the request (caller preempts). Reference:
         ``kv_cache_manager.py allocate_slots``.
         """
         assert num_new_tokens > 0
-        new_computed_blocks = new_computed_blocks or []
-
-        req_blocks = self.req_to_blocks.setdefault(request.request_id, [])
+        rid = request.request_id
+        tables = [m.req_to_blocks.setdefault(rid, []) for m in self.managers]
         # Reclaim this request's own out-of-window blocks BEFORE the
         # availability check, so a full pool with reclaimable blocks does
         # not spuriously preempt (entries become null stand-ins; list
         # length, and thus the required-block math, is unchanged).
-        if self.sliding_window is not None:
-            self._free_out_of_window(request, req_blocks)
+        if self._window_managers:
+            with trace_span("free_window_pages", "scheduler"):
+                for m in self._window_managers:
+                    self.window_pages_freed += m.remove_skipped_blocks(request)
         num_computed_tokens = request.num_computed_tokens + num_new_computed_tokens
         # Lookahead covers speculative positions whose KV lands this step.
         num_required_blocks = ceil(
             (num_computed_tokens + num_new_tokens + num_lookahead_tokens)
             / self.block_size
         )
-        num_new_blocks = (
-            num_required_blocks - len(req_blocks) - len(new_computed_blocks)
+        stored = (
+            num_computed_tokens + num_new_tokens
+            if num_stored_tokens is None else num_stored_tokens
         )
+        # Every group's table has one entry a block of the sequence (a
+        # null stand-in where the page went back), so every group needs
+        # the same count.
+        hits = new_computed_blocks or self._no_blocks
+        if not hits[0] and num_required_blocks <= len(tables[0]):
+            # Most calls: a decode row whose last block has room.
+            if self.enable_caching:
+                self._cache_full_blocks(request, stored)
+            return self._no_blocks
+        num_held = len(tables[0]) + len(hits[0])
+        num_new_blocks = num_required_blocks - num_held
 
         # Cache-hit blocks with ref 0 sit in the free queue; touching them
         # consumes free capacity, so subtract them from the availability
         # check (per color: a hit block occupies its own stripe's queue).
-        first_color = (
-            (len(req_blocks) + len(new_computed_blocks)) % self.num_stripes
-        )
+        first_color = num_held % self.num_stripes
         evictable = [0] * self.num_stripes
-        for b in new_computed_blocks:
-            if b.ref_cnt == 0 and not b.is_null:
-                evictable[self.block_pool.color_of(b.block_id)] += 1
+        for hit in hits:
+            for b in hit:
+                if b.ref_cnt == 0 and not b.is_null:
+                    evictable[self.block_pool.color_of(b.block_id)] += 1
         if num_new_blocks > 0 and not self.block_pool.can_allocate(
-            num_new_blocks, first_color, evictable
+            num_new_blocks * self.num_groups, first_color, evictable
         ):
             return None
 
-        # Commit the cache hits.
-        if new_computed_blocks:
-            self.block_pool.touch(new_computed_blocks)
-            req_blocks.extend(new_computed_blocks)
-            self.num_cached_blocks[request.request_id] = len(req_blocks)
-
-        new_blocks: list[KVCacheBlock] = []
-        if num_new_blocks > 0:
-            new_blocks = self.block_pool.get_new_blocks(
-                num_new_blocks, first_color=len(req_blocks) % self.num_stripes
-            )
-            req_blocks.extend(new_blocks)
+        new_blocks: list[list[KVCacheBlock]] = []
+        for m, table, hit in zip(self.managers, tables, hits):
+            # Commit the cache hits.
+            if hit:
+                self.block_pool.touch(hit)
+                m.extend(rid, hit)
+                m.num_cached_blocks[rid] = len(table)
+            new = []
+            if num_new_blocks > 0:
+                new = self.block_pool.get_new_blocks(
+                    num_new_blocks, first_color=len(table) % self.num_stripes
+                )
+                m.extend(rid, new)
+            new_blocks.append(new)
 
         if self.enable_caching:
-            self._cache_full_blocks(
-                request,
-                num_computed_tokens + num_new_tokens
-                if num_stored_tokens is None else num_stored_tokens,
-            )
-        return new_blocks
-
-    def _free_out_of_window(
-        self, request: Request, req_blocks: list[KVCacheBlock]
-    ) -> None:
-        """Per-type freeing policy (SlidingWindowManager nulls blocks
-        wholly below the window; full attention frees nothing)."""
-        start = self._first_live_blk.get(request.request_id, 0)
-        self._first_live_blk[request.request_id] = (
-            self.type_manager.remove_skipped_blocks(
-                request, req_blocks, start
-            )
-        )
+            self._cache_full_blocks(request, stored)
+        return tuple(new_blocks)
 
     def defer_caching_from(self, request_id: str, token_floor: int) -> None:
         """Block prefix-cache registration at/after ``token_floor`` until
@@ -227,16 +264,8 @@ class KVCacheManager:
         num_full = min(
             num_tokens_after_step // self.block_size, len(request.block_hashes)
         )
-        num_cached = self.num_cached_blocks.get(request.request_id, 0)
-        if num_full <= num_cached:
-            return
-        self.block_pool.cache_full_blocks(
-            self.req_to_blocks[request.request_id],
-            request.block_hashes,
-            num_cached_blocks=num_cached,
-            num_full_blocks=num_full,
-        )
-        self.num_cached_blocks[request.request_id] = num_full
+        for m in self.managers:
+            m.cache_full_blocks(request, num_full)
 
     # ------------------------------------------------------------------
     # Free
@@ -246,26 +275,42 @@ class KVCacheManager:
         """Drop the request's blocks from the prefix cache (their content
         is garbage after a failed external KV load — a later request, or
         this one's recompute, must not hit them)."""
-        for b in self.req_to_blocks.get(request.request_id, []):
-            self.block_pool._maybe_evict_cached_block(b)
-        self.num_cached_blocks.pop(request.request_id, None)
+        for m in self.managers:
+            for b in m.req_to_blocks.get(request.request_id, []):
+                self.block_pool._maybe_evict_cached_block(b)
+            m.num_cached_blocks.pop(request.request_id, None)
         self.cache_reg_cap.pop(request.request_id, None)
 
     def free(self, request: Request) -> None:
-        """Release all blocks. Freed tail-first so eviction consumes the end
-        of the sequence before its (more reusable) prefix."""
-        blocks = self.req_to_blocks.pop(request.request_id, [])
-        self.num_cached_blocks.pop(request.request_id, None)
+        """Release the request's blocks of every group."""
         self.cache_reg_cap.pop(request.request_id, None)
-        self._first_live_blk.pop(request.request_id, None)
-        self.block_pool.free_blocks(list(reversed(blocks)))
+        for m in self.managers:
+            m.free(request.request_id)
 
     # ------------------------------------------------------------------
     # Introspection
     # ------------------------------------------------------------------
 
-    def get_block_ids(self, request_id: str) -> list[int]:
-        return [b.block_id for b in self.req_to_blocks.get(request_id, [])]
+    def get_block_ids(self, request_id: str) -> tuple[list[int], ...]:
+        """The request's block table, a list a group."""
+        return tuple(
+            [b.block_id for b in m.req_to_blocks.get(request_id, [])]
+            for m in self.managers
+        )
+
+    def num_pages_held(self, request_id: str) -> int:
+        """Pages of the pool the request holds over all groups (a null
+        stand-in where a window has passed is not a page)."""
+        held = 0
+        for m in self.managers:  # every request's every step: no generator
+            held += m.pages_held.get(request_id, 0)
+        return held
+
+    def pages_in_use_by_group(self) -> list[int]:
+        return [m.pages_in_use for m in self.managers]
+
+    def pages_allocated_by_group(self) -> list[int]:
+        return [m.pages_allocated for m in self.managers]
 
     def get_num_free_blocks(self) -> int:
         return self.block_pool.get_num_free_blocks()

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import hashlib
 import struct
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import TYPE_CHECKING, Callable, NamedTuple, Optional
 
 if TYPE_CHECKING:
@@ -270,43 +270,72 @@ class MambaSpec(KVCacheSpec):
 
 @dataclass
 class KVCacheGroupSpec:
-    """Layers sharing one block-table/allocation group."""
+    """Layers sharing one block-table/allocation group: a page id of the
+    group holds ``block_size`` tokens of EVERY layer named here."""
 
     layer_names: list[str]
     kv_cache_spec: KVCacheSpec
 
+    @property
+    def page_size_bytes(self) -> int:
+        return len(self.layer_names) * self.kv_cache_spec.page_size_bytes
 
-@dataclass
-class KVCacheConfig:
-    """Engine-wide cache plan (reference: ``kv_cache_interface.py:735``)."""
+    @property
+    def sliding_window(self) -> int | None:
+        """The window the group's pages may be freed under (None: every
+        page is kept while the request lives)."""
+        if isinstance(self.kv_cache_spec, SlidingWindowSpec):
+            return self.kv_cache_spec.sliding_window
+        return None
 
-    num_blocks: int
-    kv_cache_groups: list[KVCacheGroupSpec] = field(default_factory=list)
 
-
-def get_kv_cache_config_from_specs(
+def group_kv_cache_specs(
     specs: dict[str, KVCacheSpec],
-    available_memory_bytes: int,
-    num_blocks_override: int | None = None,
-) -> KVCacheConfig:
-    """Size the cache: group layers by identical spec, divide free memory by
-    the per-token footprint. Round-1 scope: uniform specs → one group.
+) -> list[KVCacheGroupSpec]:
+    """Group a model's paged layers by spec into groups of EQUAL layer
+    count over one page pool (reference: ``kv_cache_utils.py
+    _get_kv_cache_groups_uniform_page_size``).
 
-    Reference: ``get_kv_cache_config`` (``kv_cache_utils.py``).
+    One spec gives one group of every layer: the page, the block count and
+    the step programs a uniform model always had. Several specs (window
+    layers beside full ones) must share one page shape a layer; the group
+    size is the smallest kind's layer count and a kind with ``k`` times as
+    many layers gives ``k`` groups, its ``i``-th layer going to the kind's
+    group ``i % k`` (layers 1, 2, 3, 5, 6, 7, ... of a 1:3 pattern are
+    groups 1, 2 and 3 with one layer of each a period). A page id then
+    means ``block_size`` tokens of one group's layers, whichever group it
+    was handed to, and the cache is one array ``[group size, pages, ...]``
+    indexed by a layer's place in its group.
     """
     assert specs, "model exposed no KV cache specs"
-    groups: dict[tuple, KVCacheGroupSpec] = {}
-    for name, spec in specs.items():
-        key = (type(spec).__name__, spec.block_size, spec.num_kv_heads, spec.head_size, spec.dtype_bytes)
-        if key not in groups:
-            groups[key] = KVCacheGroupSpec([], spec)
-        groups[key].layer_names.append(name)
+    import dataclasses
 
-    page_bytes_all_layers = sum(
-        g.kv_cache_spec.page_size_bytes * len(g.layer_names) for g in groups.values()
-    )
-    if num_blocks_override is not None:
-        num_blocks = num_blocks_override
-    else:
-        num_blocks = max(1, available_memory_bytes // page_bytes_all_layers)
-    return KVCacheConfig(num_blocks=num_blocks, kv_cache_groups=list(groups.values()))
+    kinds: dict[tuple, KVCacheGroupSpec] = {}
+    for name, spec in specs.items():
+        key = (type(spec).__name__,) + dataclasses.astuple(spec)
+        if key not in kinds:
+            kinds[key] = KVCacheGroupSpec([], spec)
+        kinds[key].layer_names.append(name)
+    if len(kinds) == 1:
+        return list(kinds.values())
+    pages = {k.kv_cache_spec.page_size_bytes for k in kinds.values()}
+    size = min(len(k.layer_names) for k in kinds.values())
+    uneven = [len(k.layer_names) for k in kinds.values()
+              if len(k.layer_names) % size]
+    if len(pages) > 1 or uneven:
+        raise ValueError(
+            "KV groups over one page pool need one page shape a layer and "
+            "layer counts that are multiples of the smallest kind's: got "
+            + ", ".join(
+                f"{len(k.layer_names)} x {type(k.kv_cache_spec).__name__} "
+                f"of {k.kv_cache_spec.page_size_bytes} bytes"
+                for k in kinds.values())
+        )
+    groups = []
+    for kind in kinds.values():
+        k = len(kind.layer_names) // size
+        groups += [
+            KVCacheGroupSpec(kind.layer_names[j::k], kind.kv_cache_spec)
+            for j in range(k)
+        ]
+    return groups

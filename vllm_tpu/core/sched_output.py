@@ -25,7 +25,9 @@ class NewRequestData:
     req_id: str
     prompt_token_ids: list[int]
     sampling_params: SamplingParams
-    block_ids: list[int]
+    # The request's block table: one list a KV group (a uniform model
+    # has one group).
+    block_ids: tuple[list[int], ...]
     num_computed_tokens: int
     lora_name: str | None = None
     mm_inputs: list[Any] | None = None
@@ -43,7 +45,9 @@ class CachedRequestData:
     # All token ids, only populated for resumed requests (the runner's copy
     # went stale across preemption); None otherwise.
     resumed_req_token_ids: list[list[int] | None] = field(default_factory=list)
-    new_block_ids: list[list[int]] = field(default_factory=list)
+    # A request's new blocks (all of them when resumed), in the form of
+    # ``NewRequestData.block_ids``; ``()`` where it got none.
+    new_block_ids: list[tuple[list[int], ...]] = field(default_factory=list)
     num_computed_tokens: list[int] = field(default_factory=list)
 
     @property
@@ -208,6 +212,17 @@ class SchedulerStats:
     num_running_reqs: int = 0
     num_waiting_reqs: int = 0
     kv_cache_usage: float = 0.0
+    # KV groups over the one pool (``core/kv_cache_manager.py``): pages
+    # each group's requests hold now, and cumulative since engine start
+    # the pages each group took from the pool and the pages the window
+    # groups gave back before their request ended.
+    kv_group_pages_in_use: list[int] = field(default_factory=list)
+    kv_pages_allocated: list[int] = field(default_factory=list)
+    kv_window_pages_freed: int = 0
+    # Pages held ("held") and pages of the pool ("pool"), each summed over
+    # the scheduler's steps so far: held over pool between two scrapes is
+    # the mean share of the pool in use.
+    kv_page_steps: dict[str, int] = field(default_factory=dict)
     prefix_cache_queries: int = 0
     prefix_cache_hits: int = 0
     num_preempted_reqs: int = 0  # cumulative since engine start
@@ -287,6 +302,11 @@ class SchedulerStats:
     linear_attn_state_rows: int = 0
     linear_attn_tokens_by_kind: dict[str, int] = field(default_factory=dict)
     state_slots_in_use: int = 0
+    # Window attention layers beside full ones (cumulative, from the
+    # runner, a layer of that kind): rows a step, and the (query token,
+    # key) pairs the window's mask lets through, a q head.
+    window_attn_rows: int = 0
+    window_attn_pairs: int = 0
     prep_fallback_rows: int = 0
     # Sampling-epilogue routing: in-jit sample() calls routed to the
     # fused sort-free kernel vs sampling rows that fell back to the XLA

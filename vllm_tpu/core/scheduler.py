@@ -96,6 +96,15 @@ class RequestQueue:
         return iter(self._q)
 
 
+def _block_ids(blocks: tuple[list, ...]) -> tuple[list[int], ...]:
+    """The ids of what ``KVCacheManager.allocate_slots`` returned, a list a
+    KV group; ``()`` where no group grew (every group grows by the same
+    count), so that the runner can skip the row."""
+    if not blocks[0]:
+        return ()
+    return tuple([b.block_id for b in group] for group in blocks)
+
+
 class Scheduler:
     # Async subclass advances computed counts at schedule time instead of
     # update time (lag-1 pipelining); several accounting paths branch on it.
@@ -128,6 +137,7 @@ class Scheduler:
             block_size=cache_config.block_size,
             enable_caching=cache_config.enable_prefix_caching,
             sliding_window=cache_config.sliding_window,
+            kv_cache_groups=cache_config.kv_cache_groups,
             num_stripes=cache_config.num_kv_stripes,
             event_sink=(
                 self.kv_event_publisher.record
@@ -137,6 +147,11 @@ class Scheduler:
         )
         self.block_size = cache_config.block_size
         self.structured_output_manager = structured_output_manager
+        if kv_connector is not None and self.kv_cache_manager.num_groups > 1:
+            raise NotImplementedError(
+                "an external KV tier with several KV groups: its keys name "
+                "ONE page a block of tokens"
+            )
         self.kv_connector = kv_connector
         # (block_ids, keys) save records awaiting shipment to the runner.
         self._pending_kv_saves: list[tuple] = []
@@ -166,6 +181,7 @@ class Scheduler:
         # counter would lose events when async lag-1 runs two schedule()
         # calls between logger updates).
         self._num_preempted_total = 0
+        self._kv_page_steps = {"held": 0, "pool": 0}
         # Preempted ids from a schedule() whose output was never
         # dispatched (zero scheduled tokens): re-delivered on the next
         # dispatched step so the runner still releases per-request state.
@@ -393,9 +409,8 @@ class Scheduler:
                     new_logprobs=new_logprobs or None,
                     num_cached_tokens=max(request.num_cached_tokens, 0),
                     queue_time=request.queue_time,
-                    kv_blocks_held=len(
-                        self.kv_cache_manager.req_to_blocks.get(req_id, ())
-                    ),
+                    kv_blocks_held=self.kv_cache_manager.num_pages_held(
+                        req_id),
                     commit_passes=passes,
                 )
             )
@@ -454,7 +469,8 @@ class Scheduler:
             and request.pooling_params is None
             and not request.mm_inputs  # hashes don't cover image content
         ):
-            block_ids = self.kv_cache_manager.get_block_ids(
+            # One group: ``__init__`` refuses an external tier with more.
+            (block_ids,) = self.kv_cache_manager.get_block_ids(
                 request.request_id
             )
             # Only blocks whose KV was actually computed (an abort can
@@ -824,9 +840,7 @@ class Scheduler:
 
             num_scheduled_tokens[request.request_id] = num_new_tokens
             token_budget -= num_new_tokens
-            new_blocks_per_req[request.request_id] = [
-                b.block_id for b in new_blocks
-            ]
+            new_blocks_per_req[request.request_id] = _block_ids(new_blocks)
             if enc_new:
                 enc_sched.setdefault(request.request_id, []).extend(enc_new)
             starts[request.request_id] = request.num_computed_tokens
@@ -907,7 +921,7 @@ class Scheduler:
                 and not is_mean_pooling
                 and not wants_prompt_lp
                 and not request.mm_inputs
-                else ([], 0)
+                else (None, 0)
             )
             # External KV tier: whole blocks beyond the device hit.
             num_external_tokens = 0
@@ -1087,7 +1101,7 @@ class Scheduler:
             cached.req_ids.append(req_id)
             cached.resumed_from_preemption.append(False)
             cached.resumed_req_token_ids.append(None)
-            cached.new_block_ids.append(new_blocks_per_req.get(req_id, []))
+            cached.new_block_ids.append(new_blocks_per_req.get(req_id, ()))
             cached.num_computed_tokens.append(
                 starts.get(req_id, request.num_computed_tokens)
             )
@@ -1634,10 +1648,8 @@ class Scheduler:
                         prompt_logprobs_delta=prompt_lp_delta,
                         num_cached_tokens=max(request.num_cached_tokens, 0),
                         queue_time=request.queue_time,
-                        kv_blocks_held=len(
-                            self.kv_cache_manager.req_to_blocks.get(
-                                req_id, ()
-                            )
+                        kv_blocks_held=(
+                            self.kv_cache_manager.num_pages_held(req_id)
                         ),
                     )
                 )
@@ -1730,10 +1742,22 @@ class Scheduler:
         draft_lens, self._spec_draft_lens = self._spec_draft_lens, []
         preempted_rids, self._preempted_rids = self._preempted_rids, []
         ctl = self.adaptive_spec
+        # Pages held and pages there are, summed over these snapshots (one
+        # a step): their ratio over a stretch of time is the mean share of
+        # the pool in use there.
+        in_use = self.kv_cache_manager.pages_in_use_by_group()
+        pool = self.kv_cache_manager.block_pool
+        self._kv_page_steps["held"] += sum(in_use)
+        self._kv_page_steps["pool"] += pool.num_blocks - pool.num_colors
         return SchedulerStats(
             num_running_reqs=len(self.running),
             num_waiting_reqs=len(self.waiting),
             kv_cache_usage=self.kv_cache_manager.usage,
+            kv_group_pages_in_use=in_use,
+            kv_pages_allocated=(
+                self.kv_cache_manager.pages_allocated_by_group()),
+            kv_window_pages_freed=self.kv_cache_manager.window_pages_freed,
+            kv_page_steps=dict(self._kv_page_steps),
             prefix_cache_queries=stats.queries,
             prefix_cache_hits=stats.hits,
             num_preempted_reqs=self._num_preempted_total,

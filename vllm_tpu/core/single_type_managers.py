@@ -3,11 +3,13 @@
 Reference analog: ``vllm/v1/core/single_type_kv_cache_manager.py``
 (FullAttentionManager :xx, SlidingWindowManager :507). The policies —
 how a cache-type finds prefix hits and which blocks it may free — are
-factored out of :class:`~vllm_tpu.core.kv_cache_manager.KVCacheManager`
-so hybrid per-group coordination (different policies for different
-layer groups, ``kv_cache_coordinator.py:392``) has its seam; today the
-engine runs ONE group (unitary coordinator semantics) and the facade
-delegates to exactly one of these.
+factored out of :class:`~vllm_tpu.core.kv_cache_manager.KVCacheManager`,
+which keeps ONE of these a KV group (``kv_cache_utils.
+group_kv_cache_specs``) over the one block pool. Each owns its group's
+block table a request: a uniform model has one group and one manager, a
+model that mixes window and full layers one full manager and a window
+manager for every group of window layers, each freeing what its window
+has passed.
 """
 
 from __future__ import annotations
@@ -25,40 +27,89 @@ class FullAttentionManager:
     """Plain causal attention: hits are the longest CONTIGUOUS cached
     prefix; nothing is ever freed early."""
 
-    def __init__(self, block_pool: "BlockPool", block_size: int) -> None:
+    sliding_window: int | None = None
+
+    def __init__(
+        self, block_pool: "BlockPool", block_size: int, group_id: int = 0
+    ) -> None:
         self.block_pool = block_pool
         self.block_size = block_size
+        self.group_id = group_id
+        # This group's block table a request (null stand-ins where a
+        # window manager has given the page back), and how many leading
+        # blocks of each are registered in the prefix cache already
+        # (avoids re-hashing on every allocate).
+        self.req_to_blocks: dict[str, list[KVCacheBlock]] = {}
+        self.num_cached_blocks: dict[str, int] = {}
+        # Pages of the pool this group's requests hold (null stand-ins are
+        # not pages; a page shared through the prefix cache counts once a
+        # request): a request, now over all of them, and taken since the
+        # start.
+        self.pages_held: dict[str, int] = {}
+        self.pages_in_use = 0
+        self.pages_allocated = 0
+
+    def extend(self, request_id: str, blocks: list[KVCacheBlock]) -> None:
+        """Append blocks taken from the pool (new, or cache hits touched)
+        to the request's table."""
+        self.req_to_blocks[request_id].extend(blocks)
+        n = sum(not b.is_null for b in blocks)
+        self.pages_held[request_id] = self.pages_held.get(request_id, 0) + n
+        self.pages_in_use += n
+        self.pages_allocated += n
 
     def find_longest_cache_hit(
         self, request: "Request", max_hit_blocks: int
     ) -> list[KVCacheBlock]:
         hit: list[KVCacheBlock] = []
         for block_hash in request.block_hashes[:max_hit_blocks]:
-            block = self.block_pool.get_cached_block(block_hash)
+            block = self.block_pool.get_cached_block(
+                block_hash, self.group_id)
             if block is None:
                 break
             hit.append(block)
         return hit
 
-    def remove_skipped_blocks(
-        self, request: "Request", req_blocks: list[KVCacheBlock],
-        first_live: int,
-    ) -> int:
-        return first_live  # nothing falls out of a full-attention window
+    def cache_full_blocks(self, request: "Request", num_full: int) -> None:
+        """Register the request's blocks below ``num_full`` that are not
+        registered yet under their content hashes and this group."""
+        num_cached = self.num_cached_blocks.get(request.request_id, 0)
+        if num_full <= num_cached:
+            return
+        self.block_pool.cache_full_blocks(
+            self.req_to_blocks[request.request_id],
+            request.block_hashes,
+            num_cached_blocks=num_cached,
+            num_full_blocks=num_full,
+            group_id=self.group_id,
+        )
+        self.num_cached_blocks[request.request_id] = num_full
+
+    def free(self, request_id: str) -> None:
+        """Release the request's blocks of this group, tail first so that
+        eviction consumes the end of the sequence before its (more
+        reusable) prefix."""
+        blocks = self.req_to_blocks.pop(request_id, [])
+        self.num_cached_blocks.pop(request_id, None)
+        self.pages_in_use -= self.pages_held.pop(request_id, 0)
+        self.block_pool.free_blocks(list(reversed(blocks)))
 
 
-class SlidingWindowManager:
+class SlidingWindowManager(FullAttentionManager):
     """Sliding-window attention: hits are the LAST cached run covering
     the window (out-of-window prefix served as null stand-ins), and
     blocks wholly below the window are freed as the sequence advances.
     Reference: ``single_type_kv_cache_manager.py:507``."""
 
     def __init__(
-        self, block_pool: "BlockPool", block_size: int, sliding_window: int
+        self, block_pool: "BlockPool", block_size: int, sliding_window: int,
+        group_id: int = 0,
     ) -> None:
-        self.block_pool = block_pool
-        self.block_size = block_size
+        super().__init__(block_pool, block_size, group_id)
         self.sliding_window = sliding_window
+        # First not-yet-freed block index a request, so each block is
+        # nulled exactly once (no O(seq_len) rescans).
+        self._first_live_blk: dict[str, int] = {}
 
     def find_longest_cache_hit(
         self, request: "Request", max_hit_blocks: int
@@ -74,7 +125,8 @@ class SlidingWindowManager:
         blocks: list[KVCacheBlock] = [null] * len(hashes)
         run = 0
         for i in range(len(hashes) - 1, -1, -1):
-            block = self.block_pool.get_cached_block(hashes[i])
+            block = self.block_pool.get_cached_block(
+                hashes[i], self.group_id)
             if block is None:
                 run = 0
                 continue
@@ -86,15 +138,15 @@ class SlidingWindowManager:
         # block 0 (plain prefix semantics).
         return blocks[:run]
 
-    def remove_skipped_blocks(
-        self, request: "Request", req_blocks: list[KVCacheBlock],
-        first_live: int,
-    ) -> int:
+    def remove_skipped_blocks(self, request: "Request") -> int:
         """Replace blocks wholly below the window with the null block and
-        free them; returns the new first-live index. Entries stay in the
-        table (reads are window-masked, slots never rewritten). The floor
-        uses only ROLLBACK-PROOF tokens (async scheduling advances counts
-        optimistically; spec verification can roll back)."""
+        free them; returns how many pages went back to the pool. Entries
+        stay in the table (reads are window-masked, slots never
+        rewritten). The floor uses only ROLLBACK-PROOF tokens (async
+        scheduling advances counts optimistically; spec verification can
+        roll back)."""
+        req_blocks = self.req_to_blocks[request.request_id]
+        first_live = self._first_live_blk.get(request.request_id, 0)
         confirmed = (
             request.num_computed_tokens
             - request.num_output_placeholders
@@ -105,10 +157,21 @@ class SlidingWindowManager:
             first_needed_tok // self.block_size, len(req_blocks)
         )
         null = self.block_pool.null_block
+        freed = 0
         for i in range(first_live, first_needed_blk):
             b = req_blocks[i]
             if b.is_null:
                 continue
             req_blocks[i] = null
             self.block_pool.free_blocks([b])
-        return max(first_live, first_needed_blk)
+            freed += 1
+        self._first_live_blk[request.request_id] = max(
+            first_live, first_needed_blk)
+        if freed:
+            self.pages_held[request.request_id] -= freed
+            self.pages_in_use -= freed
+        return freed
+
+    def free(self, request_id: str) -> None:
+        self._first_live_blk.pop(request_id, None)
+        super().free(request_id)

@@ -432,7 +432,8 @@ class EngineCore:
             stats.diffusion_passes_by_kind = dict(
                 getattr(runner, "diffusion_passes_by_kind", {}))
             for name in ("diffusion_committed_tokens", "moe_assignments",
-                         "moe_experts_hit", "linear_attn_state_rows"):
+                         "moe_experts_hit", "linear_attn_state_rows",
+                         "window_attn_rows", "window_attn_pairs"):
                 setattr(stats, name, getattr(runner, name, 0))
             stats.linear_attn_tokens_by_kind = dict(
                 getattr(runner, "linear_attn_tokens_by_kind", {}))

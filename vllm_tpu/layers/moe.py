@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from vllm_tpu.layers.activation import silu_and_mul
+
 
 def select_experts(
     router_logits: jnp.ndarray,  # [T, E] (pre-softmax)
@@ -39,6 +41,48 @@ def select_experts(
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights, ids.astype(jnp.int32)
+
+
+def select_experts_grouped(
+    logits: jnp.ndarray,  # [T, E] f32
+    bias: jnp.ndarray | None,  # [E] selection bias (sigmoid routing)
+    *,
+    top_k: int,
+    n_group: int = 1,
+    topk_group: int = 1,
+    sigmoid: bool = False,
+    method: str = "greedy",
+    renormalize: bool = False,
+    scaling: float = 1.0,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """DeepSeek routing (HF DeepseekV2MoEGate / DeepseekV3TopkRouter
+    semantics). Returns (weights [T, k] f32, ids [T, k] i32)."""
+    t, e = logits.shape
+    g, k = n_group, top_k
+    if sigmoid:
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + bias[None, :] if bias is not None else scores
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        choice = scores
+    if method in ("group_limited_greedy", "noaux_tc") and g > 1:
+        grouped = choice.reshape(t, g, e // g)
+        if method == "noaux_tc":
+            top2, _ = jax.lax.top_k(grouped, 2)
+            group_scores = top2.sum(axis=-1)  # [T, G]
+        else:
+            group_scores = grouped.max(axis=-1)
+        _, group_idx = jax.lax.top_k(group_scores, topk_group)
+        group_mask = (
+            jax.nn.one_hot(group_idx, g, dtype=jnp.float32).sum(axis=1) > 0
+        )  # [T, G]
+        mask = jnp.repeat(group_mask, e // g, axis=-1)
+        choice = jnp.where(mask, choice, 0.0)
+    _, ids = jax.lax.top_k(choice, k)
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
+    if renormalize:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return weights * scaling, ids.astype(jnp.int32)
 
 
 def _dense_moe(
@@ -515,3 +559,54 @@ def fused_moe(
     return fused_experts(
         hidden, w_gate, w_up, w_down, weights, expert_ids, use_grouped
     )
+
+
+def share_of_sparse_layer(
+    h2: jnp.ndarray,  # [T, D] normed layer input
+    lp: dict,  # router [D, E_all], router_bias [E_all] | absent, ws_* shared
+    experts: dict,  # we_gate, we_up, we_down: [E, ...] or a [L, E, ...] stack
+    row,  # the layer's row in a stack, None where ``experts`` is one layer's
+    live: jnp.ndarray,  # [T] bool, tokens that are not padding
+    *,
+    select: dict,  # select_experts_grouped's keywords
+    num_experts: int,  # experts HELD here
+    first_expert: int | None,  # the first of them in the router, None = all
+    shared: bool,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """A sparse layer as the models that serve one chip's share of an
+    expert-parallel deployment run it (``ep_size`` / ``ep_rank`` in
+    ``config.json``): the router over ALL its outputs, the held experts'
+    part of the routed sum, the shared expert where there is one, and the
+    live tokens routed to each held expert ``[E]``."""
+    with jax.named_scope("moe_router"):
+        logits = h2.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+        weights, ids = select_experts_grouped(
+            logits, lp.get("router_bias"), **select)
+    local = ids - (first_expert or 0)
+    counts = jnp.zeros(num_experts, jnp.int32).at[
+        local.reshape(-1)
+    ].add(
+        jnp.broadcast_to(live[:, None], ids.shape).reshape(-1)
+        .astype(jnp.int32) * (local.reshape(-1) >= 0),
+        mode="drop",
+    )
+    with jax.named_scope("moe_experts"):
+        if row is None:
+            out = fused_experts(
+                h2, experts["we_gate"], experts["we_up"],
+                experts["we_down"], weights, ids,
+                first_expert=first_expert,
+            )
+        else:
+            out = fused_experts_of_layer(
+                h2, experts["we_gate"], experts["we_up"],
+                experts["we_down"], row, weights, ids,
+                first_expert=first_expert,
+            )
+    if shared:
+        with jax.named_scope("moe_shared"):
+            gate_up = jnp.concatenate(
+                [h2 @ lp["ws_gate"], h2 @ lp["ws_up"]], -1
+            )
+            out = out + silu_and_mul(gate_up) @ lp["ws_down"]
+    return out, counts

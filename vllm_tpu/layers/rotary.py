@@ -8,11 +8,38 @@ variants the round-1 model zoo needs: none, linear, llama3, yarn.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+# Tables up to this size inline as trace literals (no device fetch at
+# lowering); a larger one would bloat every bucket executable with a
+# duplicated constant.
+_INLINE_LIMIT_BYTES = 8 << 20
+
+# How many positions the process that is building a model will serve, where
+# it has said (``serving_positions``).
+_served_positions: int | None = None
+
+
+@contextlib.contextmanager
+def serving_positions(max_model_len: int):
+    """While a worker builds its model: a rope table that the published
+    ``max_position_embeddings`` would take past the inline limit holds
+    ``max_model_len`` rows and no more (positions beyond are never served).
+    A long-context model's table is then mostly small enough to inline
+    again; one under the limit keeps its published length, so the step
+    programs of a model that never had the problem are what they were."""
+    global _served_positions
+    previous, _served_positions = _served_positions, max_model_len
+    try:
+        yield
+    finally:
+        _served_positions = previous
 
 
 def _base_inv_freq(head_dim: int, theta: float, rotary_dim: int | None = None) -> np.ndarray:
@@ -150,9 +177,13 @@ class RotaryEmbedding:
             else:
                 raise NotImplementedError(f"rope_type {rope_type}")
 
-        t = np.arange(max_position, dtype=np.float64)
+        rows = max_position
+        row_bytes = len(inv_freq) * np.dtype(dtype).itemsize
+        if _served_positions and rows * row_bytes > _INLINE_LIMIT_BYTES:
+            rows = min(rows, _served_positions)
+        t = np.arange(rows, dtype=np.float64)
         freqs = np.outer(t, inv_freq)  # [P, rd/2]
-        if inv_freq_long is not None and max_position > original_max:
+        if inv_freq_long is not None and rows > original_max:
             freqs[original_max:] = np.outer(
                 t[original_max:], inv_freq_long
             )
@@ -165,26 +196,27 @@ class RotaryEmbedding:
             (np.sin(freqs) * mscale).astype(dtype)
         )
 
-    # Small tables inline as trace literals (no device fetch at lowering);
-    # large long-context tables would bloat every bucket executable with a
-    # duplicated constant, so they stay a single shared device array.
-    _INLINE_LIMIT_BYTES = 8 << 20
+    def _table(self, name: str) -> jnp.ndarray:
+        """Small tables inline as trace literals; a table still over the
+        limit at the served length is ONE device array, made outside
+        whatever trace first asks for it (made inside, it is that trace's
+        tracer, and the next step program to be traced meets it:
+        ``UnexpectedTracerError``)."""
+        host = getattr(self, f"_{name}_np")
+        if host.nbytes <= _INLINE_LIMIT_BYTES:
+            return jnp.asarray(host)
+        if not hasattr(self, f"_{name}_dev"):
+            with jax.ensure_compile_time_eval():
+                setattr(self, f"_{name}_dev", jnp.asarray(host))
+        return getattr(self, f"_{name}_dev")
 
     @property
     def cos(self) -> jnp.ndarray:
-        if self._cos_np.nbytes > self._INLINE_LIMIT_BYTES:
-            if not hasattr(self, "_cos_dev"):
-                self._cos_dev = jnp.asarray(self._cos_np)
-            return self._cos_dev
-        return jnp.asarray(self._cos_np)
+        return self._table("cos")
 
     @property
     def sin(self) -> jnp.ndarray:
-        if self._sin_np.nbytes > self._INLINE_LIMIT_BYTES:
-            if not hasattr(self, "_sin_dev"):
-                self._sin_dev = jnp.asarray(self._sin_np)
-            return self._sin_dev
-        return jnp.asarray(self._sin_np)
+        return self._table("sin")
 
     def __call__(
         self, positions: jnp.ndarray, q: jnp.ndarray, k: jnp.ndarray

@@ -373,6 +373,32 @@ class PrometheusRegistry:
             "Tokens through the linear-attention layers, summed over "
             "them: decode (a row's one token) or chunk (a row of "
             "several)", "kind")
+        self.window_attn_rows = Counter(
+            "vllm:window_attn_rows_total",
+            "Rows a dispatched step runs through window attention, summed "
+            "over the layers of that kind (a model that mixes window and "
+            "full layers)")
+        self.window_attn_pairs = Counter(
+            "vllm:window_attn_pairs_total",
+            "(query token, key) pairs the window's mask lets through over "
+            "the rows of dispatched steps, a q head a window layer")
+        # KV groups over the one page pool (core/kv_cache_manager.py).
+        self.kv_group_pages_in_use = LabeledGauge(
+            "vllm:kv_group_pages_in_use",
+            "Pages of the pool the requests of a KV group hold", "group")
+        self.kv_pages_allocated = LabeledCounter(
+            "vllm:kv_pages_allocated_total",
+            "Pages a KV group took from the pool (prefix-cache hits "
+            "touched count too)", "group")
+        self.kv_window_pages_freed = Counter(
+            "vllm:kv_window_pages_freed_total",
+            "Pages the window groups gave back to the pool before their "
+            "request ended: wholly under the window's floor")
+        self.kv_page_steps = LabeledCounter(
+            "vllm:kv_page_steps_total",
+            "Pages summed over the scheduler's steps: held (by requests, "
+            "all groups) and pool (all there are); held over pool between "
+            "two scrapes is the mean share of the pool in use", "kind")
         self.state_slots_in_use = Gauge(
             "vllm:state_slots_in_use",
             "Per-request state slots held by the runner's rows")
@@ -762,6 +788,9 @@ class PrometheusRegistry:
             self.diffusion_passes, self.diffusion_committed,
             self.moe_assignments, self.moe_experts_hit,
             self.linear_attn_state_rows, self.linear_attn_tokens,
+            self.window_attn_rows, self.window_attn_pairs,
+            self.kv_group_pages_in_use, self.kv_pages_allocated,
+            self.kv_window_pages_freed, self.kv_page_steps,
             self.state_slots_in_use,
             self.decode_batch_ratio, self.tokens_per_launch,
             self.prep_fallback_rows,
@@ -930,6 +959,15 @@ class PrometheusRegistry:
             self.moe_experts_hit.inc_to(float(s.moe_experts_hit))
             self.linear_attn_state_rows.inc_to(
                 float(s.linear_attn_state_rows))
+            self.window_attn_rows.inc_to(float(s.window_attn_rows))
+            self.window_attn_pairs.inc_to(float(s.window_attn_pairs))
+            for g, n in enumerate(s.kv_group_pages_in_use):
+                self.kv_group_pages_in_use.set(str(g), n)
+            for g, n in enumerate(s.kv_pages_allocated):
+                self.kv_pages_allocated.inc_to(str(g), float(n))
+            self.kv_window_pages_freed.inc_to(float(s.kv_window_pages_freed))
+            for kind, n in s.kv_page_steps.items():
+                self.kv_page_steps.inc_to(kind, float(n))
             self.state_slots_in_use.set(s.state_slots_in_use)
             self.batch_tokens.set(s.batch_num_tokens)
             self.batch_requests.set(s.batch_num_reqs)

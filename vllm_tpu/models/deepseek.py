@@ -43,7 +43,10 @@ from jax.sharding import PartitionSpec as P
 from vllm_tpu.core.kv_cache_utils import KVCacheSpec, MLAAttentionSpec
 from vllm_tpu.layers.activation import silu_and_mul
 from vllm_tpu.layers.layernorm import rms_norm
-from vllm_tpu.layers.moe import fused_experts
+from vllm_tpu.layers.moe import (  # noqa: F401 (the routing's old home)
+    fused_experts,
+    select_experts_grouped,
+)
 from vllm_tpu.layers.rotary import RotaryEmbedding
 from vllm_tpu.logger import init_logger
 from vllm_tpu.ops.attention import AttentionMetadata
@@ -64,48 +67,6 @@ def _rope_interleaved(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp
     o1 = x1 * cos - x2 * sin
     o2 = x2 * cos + x1 * sin
     return jnp.stack([o1, o2], axis=-1).reshape(x.shape).astype(x.dtype)
-
-
-def select_experts_grouped(
-    logits: jnp.ndarray,  # [T, E] f32
-    bias: jnp.ndarray | None,  # [E] selection bias (sigmoid routing)
-    *,
-    top_k: int,
-    n_group: int = 1,
-    topk_group: int = 1,
-    sigmoid: bool = False,
-    method: str = "greedy",
-    renormalize: bool = False,
-    scaling: float = 1.0,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """DeepSeek routing (HF DeepseekV2MoEGate / DeepseekV3TopkRouter
-    semantics). Returns (weights [T, k] f32, ids [T, k] i32)."""
-    t, e = logits.shape
-    g, k = n_group, top_k
-    if sigmoid:
-        scores = jax.nn.sigmoid(logits)
-        choice = scores + bias[None, :] if bias is not None else scores
-    else:
-        scores = jax.nn.softmax(logits, axis=-1)
-        choice = scores
-    if method in ("group_limited_greedy", "noaux_tc") and g > 1:
-        grouped = choice.reshape(t, g, e // g)
-        if method == "noaux_tc":
-            top2, _ = jax.lax.top_k(grouped, 2)
-            group_scores = top2.sum(axis=-1)  # [T, G]
-        else:
-            group_scores = grouped.max(axis=-1)
-        _, group_idx = jax.lax.top_k(group_scores, topk_group)
-        group_mask = (
-            jax.nn.one_hot(group_idx, g, dtype=jnp.float32).sum(axis=1) > 0
-        )  # [T, G]
-        mask = jnp.repeat(group_mask, e // g, axis=-1)
-        choice = jnp.where(mask, choice, 0.0)
-    _, ids = jax.lax.top_k(choice, k)
-    weights = jnp.take_along_axis(scores, ids, axis=-1)
-    if renormalize:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
-    return weights * scaling, ids.astype(jnp.int32)
 
 
 def latent_attention(

@@ -66,9 +66,9 @@ from jax.sharding import PartitionSpec as P
 from vllm_tpu.core.kv_cache_utils import KVCacheSpec, MLAAttentionSpec
 from vllm_tpu.layers.activation import silu_and_mul
 from vllm_tpu.layers.layernorm import rms_norm
-from vllm_tpu.layers.moe import fused_experts, fused_experts_of_layer
+from vllm_tpu.layers.moe import share_of_sparse_layer
 from vllm_tpu.logger import init_logger
-from vllm_tpu.models.deepseek import latent_attention, select_experts_grouped
+from vllm_tpu.models.deepseek import latent_attention
 from vllm_tpu.ops.attention import AttentionMetadata
 from vllm_tpu.ops.kda import kda_attention, kda_short_conv, l2norm
 from vllm_tpu.ops.mla_attention import mla_kv_cache_shape
@@ -554,42 +554,17 @@ class KimiLinearForCausalLM:
     def _moe(self, h2, lp, experts, row, live):
         """Routed experts held here plus the shared one, and the live
         tokens routed to each held expert ``[E]``."""
-        with jax.named_scope("moe_router"):
-            logits = h2.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
-            weights, ids = select_experts_grouped(
-                logits, lp["router_bias"], top_k=self.top_k,
-                n_group=self.n_group, topk_group=self.topk_group,
-                sigmoid=self.sigmoid_routing, method="noaux_tc",
-                renormalize=self.renormalize, scaling=self.routed_scaling,
-            )
-        local = ids - (self.first_expert or 0)
-        counts = jnp.zeros(self.num_experts, jnp.int32).at[
-            local.reshape(-1)
-        ].add(
-            jnp.broadcast_to(live[:, None], ids.shape).reshape(-1)
-            .astype(jnp.int32) * (local.reshape(-1) >= 0),
-            mode="drop",
+        return share_of_sparse_layer(
+            h2, lp, experts, row, live,
+            select=dict(
+                top_k=self.top_k, n_group=self.n_group,
+                topk_group=self.topk_group, sigmoid=self.sigmoid_routing,
+                method="noaux_tc", renormalize=self.renormalize,
+                scaling=self.routed_scaling,
+            ),
+            num_experts=self.num_experts, first_expert=self.first_expert,
+            shared=bool(self.n_shared),
         )
-        with jax.named_scope("moe_experts"):
-            if row is None:
-                out = fused_experts(
-                    h2, experts["we_gate"], experts["we_up"],
-                    experts["we_down"], weights, ids,
-                    first_expert=self.first_expert,
-                )
-            else:
-                out = fused_experts_of_layer(
-                    h2, experts["we_gate"], experts["we_up"],
-                    experts["we_down"], row, weights, ids,
-                    first_expert=self.first_expert,
-                )
-        if self.n_shared:
-            with jax.named_scope("moe_shared"):
-                gate_up = jnp.concatenate(
-                    [h2 @ lp["ws_gate"], h2 @ lp["ws_up"]], -1
-                )
-                out = out + silu_and_mul(gate_up) @ lp["ws_down"]
-        return out, counts
 
     def apply(
         self,

@@ -36,6 +36,7 @@ _REGISTRY: dict[str, tuple[str, str]] = {
     "JambaForCausalLM": ("vllm_tpu.models.jamba", "JambaForCausalLM"),
     "Qwen3NextForCausalLM": ("vllm_tpu.models.qwen3_next", "Qwen3NextForCausalLM"),
     "KimiLinearForCausalLM": ("vllm_tpu.models.kimi_linear", "KimiLinearForCausalLM"),
+    "LagunaForCausalLM": ("vllm_tpu.models.laguna", "LagunaForCausalLM"),
     "Phi3ForCausalLM": ("vllm_tpu.models.phi3", "Phi3ForCausalLM"),
     "GraniteForCausalLM": ("vllm_tpu.models.granite", "GraniteForCausalLM"),
     "Olmo2ForCausalLM": ("vllm_tpu.models.olmo2", "Olmo2ForCausalLM"),

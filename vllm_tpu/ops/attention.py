@@ -162,9 +162,12 @@ def paged_attention(
     soft_cap: float | None = None,
     k_scale: float | None = None,
     v_scale: float | None = None,
+    kernel_suffix: str = "",
 ) -> jnp.ndarray:
     """Backend dispatcher: in-repo Pallas flash kernel on TPU, XLA reference
-    elsewhere (and under VLLM_TPU_DISABLE_PALLAS)."""
+    elsewhere (and under VLLM_TPU_DISABLE_PALLAS). ``kernel_suffix`` is
+    appended to the kernel's name in a trace (``rpa_kernel_window``): a
+    model whose layers are of two kinds tells their calls apart by it."""
     import vllm_tpu.envs as envs
 
     if md.tree_mask is not None:
@@ -191,6 +194,7 @@ def paged_attention(
     return dispatch_ragged_attention(
         q, kv_cache, layer, md, scale, sliding_window=sliding_window,
         soft_cap=soft_cap, k_scale=k_scale, v_scale=v_scale,
+        kernel_suffix=kernel_suffix,
     )
 
 
@@ -281,6 +285,7 @@ def dispatch_ragged_attention(
     ctx_stride=1,
     ctx_phase=0,
     allow_interpret: bool = False,
+    kernel_suffix: str = "",
 ):
     """THE kernel-vs-reference dispatch point (plain and striped-context
     callers alike — eligibility rules live only here): the Pallas flash
@@ -331,7 +336,9 @@ def dispatch_ragged_attention(
                     envs.VLLM_TPU_DECODE_KV_PAGES_PER_BLOCK
                 )
         return _call_attention_kernel(
-            functools.partial(decode_paged_attention, **static_kw, **blk_kw),
+            functools.partial(
+                decode_paged_attention, **static_kw, **blk_kw,
+                name="rpa_decode_kernel" + kernel_suffix),
             md.tp_mesh,
             q,
             kv_cache,
@@ -352,6 +359,7 @@ def dispatch_ragged_attention(
             functools.partial(
                 ragged_paged_attention, return_lse=return_lse,
                 block_len=md.block_len, **static_kw, **blk_kw,
+                name="rpa_kernel" + kernel_suffix,
             ),
             md.tp_mesh,
             q,

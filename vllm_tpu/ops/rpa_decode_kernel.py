@@ -433,7 +433,7 @@ def _validate(q, kv_pages, kv_lens, page_indices, num_seqs):
     static_argnames=[
         "sm_scale", "mask_value", "soft_cap", "k_scale", "v_scale",
         "num_seqs_per_block", "num_kv_pages_per_block",
-        "vmem_limit_bytes", "interpret",
+        "vmem_limit_bytes", "interpret", "name",
     ],
 )
 def decode_paged_attention(
@@ -454,8 +454,13 @@ def decode_paged_attention(
     num_kv_pages_per_block: int | None = None,
     vmem_limit_bytes: int | None = None,
     interpret: bool = False,
+    name: str = "rpa_decode_kernel",
 ):
     """Decode-only flash attention over the paged KV cache.
+
+    ``name`` is the ``pallas_call``'s, which a profiler trace prints: a
+    model that runs this kernel for two kinds of layer gives one of them
+    another (``dispatch_ragged_attention``, ``kernel_suffix``).
 
     Semantically identical to ``ragged_paged_attention`` restricted to
     ``q_len == 1`` for every row (``cu_q_lens == arange``); returns
@@ -543,7 +548,7 @@ def decode_paged_attention(
             vmem_limit_bytes=vmem_limit_bytes,
         ),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
-        name="rpa_decode_kernel",
+        name=name,
         interpret=interpret,
     )
     return kernel(*scalar_prefetches, q, kv_pages)[0]

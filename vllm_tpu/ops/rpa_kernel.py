@@ -864,6 +864,13 @@ def _fit_kv_pages_to_vmem(
     return num_kv_pages_per_blk, need(num_kv_pages_per_blk)
 
 
+def _narrow_tile_tokens(q_dtype, ratio: int) -> int:
+    """The fewest query tokens whose ``ratio`` rows each fill whole sublane
+    tiles of q."""
+    tile_rows = 8 * _dtype_packing(q_dtype)
+    return tile_rows // math.gcd(tile_rows, ratio)
+
+
 def ragged_tiling(
     num_q_heads: int,
     num_combined_kv_heads: int,
@@ -908,6 +915,10 @@ def ragged_tiling(
         num_q_per_blk = min(
             max(32, min(128, _ROWS_PER_HEAD_TILE // ratio)), num_q_tokens
         )
+        # Whole sublane tiles of q rows (a group of 6 q heads a KV head
+        # gives 85 tokens, taken as 80), so that the narrow rung below
+        # divides the block; the groups that divide 512 are as they were.
+        num_q_per_blk -= num_q_per_blk % _narrow_tile_tokens(q_dtype, ratio)
         num_kv_pages_per_blk, need = _fit_kv_pages_to_vmem(
             min(max(1, _KEYS_PER_BLOCK // page_size), pages_per_seq),
             vmem_limit_bytes or _MAX_SCOPED_VMEM_BYTES,
@@ -921,8 +932,7 @@ def ragged_tiling(
         )
         if vmem_limit_bytes is None and need > _DEFAULT_SCOPED_VMEM_BYTES:
             vmem_limit_bytes = need
-    tile_rows = 8 * _dtype_packing(q_dtype)
-    tile_toks = tile_rows // math.gcd(tile_rows, ratio)
+    tile_toks = _narrow_tile_tokens(q_dtype, ratio)
     narrow_q = -(-block_len // tile_toks) * tile_toks
     if narrow_q >= num_q_per_blk or num_q_per_blk % narrow_q:
         narrow_q = 0
@@ -944,7 +954,7 @@ def ragged_tiling(
         "sm_scale", "mask_value", "num_kv_pages_per_block",
         "num_queries_per_block", "vmem_limit_bytes",
         "soft_cap", "k_scale", "v_scale", "return_lse", "interpret",
-        "block_len",
+        "block_len", "name",
     ],
 )
 def ragged_paged_attention(
@@ -970,8 +980,12 @@ def ragged_paged_attention(
     ctx_stride=1,
     ctx_phase=0,
     block_len: int = 1,
+    name: str = "rpa_kernel",
 ):
     """Mixed prefill+decode flash attention over the paged KV cache.
+
+    ``name`` is the ``pallas_call``'s, which a profiler trace prints
+    (``decode_paged_attention`` says when it is not the default).
 
     ``block_len`` > 1 makes the mask causal over blocks of that many
     positions (``col // block_len <= row // block_len``); every row's
@@ -1099,7 +1113,7 @@ def ragged_paged_attention(
             vmem_limit_bytes=tiling.vmem_limit_bytes,
         ),
         out_shape=out_shape,
-        name="rpa_kernel",
+        name=name,
         interpret=interpret,
     )
 

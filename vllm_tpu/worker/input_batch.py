@@ -67,6 +67,7 @@ class InputBatch:
         max_num_reqs: int,
         max_model_len: int,
         max_blocks_per_req: int,
+        num_kv_groups: int = 1,
     ) -> None:
         self.max_num_reqs = max_num_reqs
         self.max_model_len = max_model_len
@@ -80,7 +81,14 @@ class InputBatch:
         self.token_ids = np.zeros((n, m), dtype=np.int32)
         self.num_tokens = np.zeros(n, dtype=np.int32)
         self.num_computed_tokens = np.zeros(n, dtype=np.int32)
-        self.block_table = np.zeros((n, max_blocks_per_req), dtype=np.int32)
+        # A block table a KV group; every group's row holds one entry a
+        # block of the sequence, so ``num_blocks`` is every group's.
+        # ``block_table`` is group 0's (the only one of a uniform model).
+        self.block_tables = [
+            np.zeros((n, max_blocks_per_req), dtype=np.int32)
+            for _ in range(num_kv_groups)
+        ]
+        self.block_table = self.block_tables[0]
         self.num_blocks = np.zeros(n, dtype=np.int32)
 
         # Sampling columns.
@@ -121,9 +129,7 @@ class InputBatch:
         self.token_ids[row, :n_tok] = data.prompt_token_ids
         self.num_tokens[row] = n_tok
         self.num_computed_tokens[row] = data.num_computed_tokens
-        nb = len(data.block_ids)
-        self.block_table[row, :nb] = data.block_ids
-        self.num_blocks[row] = nb
+        self._set_blocks(row, 0, data.block_ids)
 
         p = data.sampling_params
         self.temperature[row] = p.temperature
@@ -149,10 +155,7 @@ class InputBatch:
             # Swap-condense: move the tail row into the vacated slot.
             moved_id = self.req_ids[last]
             assert moved_id is not None
-            for col in (
-                self.token_ids,
-                self.block_table,
-            ):
+            for col in (self.token_ids, *self.block_tables):
                 col[row] = col[last]
             for vec in (
                 self.num_tokens,
@@ -180,14 +183,24 @@ class InputBatch:
     # Per-step updates (CachedRequestData application)
     # ------------------------------------------------------------------
 
-    def append_block_ids(self, req_id: str, new_block_ids: list[int]) -> None:
+    def _set_blocks(
+        self, row: int, start: int, block_ids: tuple[list[int], ...]
+    ) -> None:
+        """Write ``block_ids`` (one list a KV group, all of one length)
+        into the row's tables from entry ``start`` on."""
+        for table, ids in zip(self.block_tables, block_ids, strict=True):
+            table[row, start : start + len(ids)] = ids
+        self.num_blocks[row] = start + len(ids)
+
+    def append_block_ids(
+        self, req_id: str, new_block_ids: tuple[list[int], ...]
+    ) -> None:
         row = self.req_states[req_id].in_batch_row
-        nb = self.num_blocks[row]
-        self.block_table[row, nb : nb + len(new_block_ids)] = new_block_ids
-        self.num_blocks[row] = nb + len(new_block_ids)
+        self._set_blocks(row, int(self.num_blocks[row]), new_block_ids)
 
     def reset_for_resume(
-        self, req_id: str, token_ids: list[int], block_ids: list[int], num_computed: int
+        self, req_id: str, token_ids: list[int],
+        block_ids: tuple[list[int], ...], num_computed: int
     ) -> None:
         """Preemption-resume: block table and computed count restart."""
         state = self.req_states[req_id]
@@ -195,8 +208,7 @@ class InputBatch:
         self.token_ids[row, : len(token_ids)] = token_ids
         self.num_tokens[row] = len(token_ids)
         state.num_tokens = len(token_ids)
-        self.block_table[row, : len(block_ids)] = block_ids
-        self.num_blocks[row] = len(block_ids)
+        self._set_blocks(row, 0, block_ids)
         self.num_computed_tokens[row] = num_computed
         state.num_computed_tokens = num_computed
 

@@ -100,6 +100,19 @@ class StepHandle:
         self.block_passes = None
 
 
+def window_live_pairs(q_lens, kv_lens, window: int) -> int:
+    """(query token, key) pairs a causal mask with ``i - j < window`` lets
+    through over these rows: a query at position ``p`` sees ``min(p + 1,
+    window)`` keys, and a row's queries are its last ``q_len`` positions."""
+    q_lens = np.asarray(q_lens, np.int64)
+    kv_lens = np.asarray(kv_lens, np.int64)
+    first = kv_lens - q_lens  # position of a row's first query
+    # Queries under the window see p + 1 keys, the others ``window``.
+    ramp = np.clip(window - 1 - first, 0, q_lens)  # queries with p + 1 < window
+    ramp_sum = ramp * first + ramp * (ramp + 1) // 2
+    return int(np.sum(ramp_sum + (q_lens - ramp) * window))
+
+
 def _bucket(value: int, buckets: list[int]) -> int:
     i = bisect.bisect_left(buckets, value)
     if i == len(buckets):
@@ -140,10 +153,25 @@ class ModelRunner:
             jnp.zeros((0, 0), jnp.int32),
             jnp.zeros((0, 0), bool),
         )
+        # KV groups over the one page pool (the worker grouped the layers'
+        # specs at load): a block table and a slot mapping a group into the
+        # step program, stacked on a leading axis where there are several.
+        self.kv_groups = cache.kv_cache_groups or [None]
+        self.num_kv_groups = len(self.kv_groups)
+        if self.num_kv_groups > 1 and (
+            config.speculative_config.enabled or sched.num_decode_steps > 1
+            or sched.enable_cascade_attention
+        ):
+            raise NotImplementedError(
+                "several KV groups with speculative decoding, multi-step "
+                "decode or cascade attention: those paths derive slots "
+                "from ONE block table inside the step program"
+            )
         self.input_batch = InputBatch(
             max_num_reqs=sched.max_num_seqs,
             max_model_len=sched.max_model_len,
             max_blocks_per_req=self.max_blocks_per_req,
+            num_kv_groups=self.num_kv_groups,
         )
 
         comp = config.compilation_config
@@ -501,6 +529,11 @@ class ModelRunner:
         # and tokens by the form that takes them ("decode" = a row's one
         # token, "chunk" = a row of several).
         self.linear_attn_state_rows = 0
+        # Window attention layers beside full ones, cumulative: rows a
+        # step times the layers of that kind, and the (query token, key)
+        # pairs their mask lets through, a q head a layer.
+        self.window_attn_rows = 0
+        self.window_attn_pairs = 0
         self.linear_attn_tokens_by_kind = {"chunk": 0, "decode": 0}
         # Seconds spent in first uses of a step program (tracing, lowering
         # and compiling or loading it), beside bucket_compiles, their
@@ -539,15 +572,27 @@ class ModelRunner:
 
         token_ids = take(t)
         s = num_spec
+        # Further KV groups' slot mappings and block tables ride the END
+        # of the buffer, so that a uniform model's layout is what it was;
+        # the model's layers pick their group's row of both.
+        more = self.num_kv_groups - 1
+        if more:
+            tail = ibuf[ibuf.shape[0] - more * (t + r * b):]
+            group_slots = tail[: more * t].reshape(more, t)
+            group_tables = tail[more * t:].reshape(more, r, b)
+            stacked = lambda first, rest: jnp.concatenate([first[None], rest])
+        else:
+            group_slots = group_tables = None
+            stacked = lambda first, rest: first
         md = AttentionMetadata(
             positions=take(t),
-            slot_mapping=take(t),
+            slot_mapping=stacked(take(t), group_slots),
             token_req_idx=take(t),
             seq_lens=take(r),
             query_start_loc=take(r + 1),
             logits_indices=take(r),
             num_seqs=take(1),
-            block_tables=take(r * b).reshape(r, b),
+            block_tables=stacked(take(r * b).reshape(r, b), group_tables),
             num_common_prefix_blocks=cascade_blocks,
             decode_only=bool(decode_only),
             tp_mesh=self.mesh,
@@ -1850,9 +1895,12 @@ class ModelRunner:
         # [+ blk_kind(r) + blk_rule(r) + blk_n(r) + blk_committed(r*B)]
         blen = self.block_len
         blk_len = r * (3 + blen) if blen else 0
+        # [+ group_slots((G-1)*t) + group_tables((G-1)*r*b)], last
+        more_groups = self.num_kv_groups - 1
         ibuf = np.zeros(
             4 * t + 7 * r + (r + 1) + 1 + r * b + lp_len + eagle_len
-            + lora_len + plp_len + spec_len + state_len + dyn_len + blk_len,
+            + lora_len + plp_len + spec_len + state_len + dyn_len + blk_len
+            + more_groups * (t + r * b),
             np.int32,
         )
         token_ids = ibuf[0:t]
@@ -2103,6 +2151,23 @@ class ModelRunner:
             block_tables[i, :nb] = bt_row[:nb]
             offset += n
         query_start_loc[r_live + 1 :] = offset
+        if more_groups:
+            # The further groups' tables and slots, as group 0's above:
+            # live rows' entries under their block count, live tokens'
+            # slots; padding stays 0, the null page.
+            tail = ibuf[ibuf.shape[0] - more_groups * (t + r * b):]
+            rows_idx = np.asarray(rows, np.int64)
+            held = (np.arange(b)[None, :]
+                    < batch.num_blocks[rows_idx][:, None])
+            tok_row = token_req_idx[:offset]
+            tok_pos = positions[:offset]
+            for g in range(more_groups):
+                table = np.where(
+                    held, batch.block_tables[g + 1][rows_idx, :b], 0)
+                tail[more_groups * t + g * r * b:][: r * b].reshape(
+                    r, b)[:r_live] = table
+                tail[g * t:][:offset] = (
+                    table[tok_row, tok_pos // bs] * bs + tok_pos % bs)
 
         # Packed f32 sampling buffer: 6 R-vectors (+ optional adjustment
         # values) (+ under generation by diffusion the dynamic rule's
@@ -2283,6 +2348,20 @@ class ModelRunner:
                 int(one.sum()) * linear_layers)
             self.linear_attn_tokens_by_kind["chunk"] += (
                 int(q_lens[~one].sum()) * linear_layers)
+        window = getattr(self.model, "attn_window", 0)
+        if window:
+            # Window layers beside full ones: the rows they run, the keys
+            # a row's window layer must read (its queries' windows, once a
+            # row a step) and the pairs the window's mask lets through, a
+            # q head a window layer.
+            kv_lens = seq_lens[:r_live].astype(np.int64)
+            self.window_attn_rows += (
+                r_live * self.model.num_window_attn_layers)
+            self.attn_context_tokens_by_kind["window"] = (
+                self.attn_context_tokens_by_kind.get("window", 0)
+                + int(np.minimum(kv_lens, q_lens + window - 1).sum()))
+            self.window_attn_pairs += window_live_pairs(
+                q_lens, kv_lens, window)
         if getattr(self.model, "latent_dim", 0):
             # What each row's latent attention reads, once a row a step;
             # and its kernel's pairs, which every step of such a model
@@ -3087,8 +3166,12 @@ class ModelRunner:
                 # per token instead of K/V planes).
                 kv_shape = custom_shape(self.num_kv_blocks, cache.block_size)
             else:
+                # A page id holds one GROUP's layers (every layer of a
+                # uniform model): the cache's first axis is a layer's
+                # place in its group.
+                group = self.kv_groups[0]
                 kv_shape = kv_cache_shape(
-                    self.model.num_layers,
+                    len(group.layer_names) if group else self.model.num_layers,
                     self.num_kv_blocks,
                     cache.block_size,
                     self.model.num_kv_heads,
@@ -3369,6 +3452,7 @@ class ModelRunner:
         so = _dummy_scheduler_output(
             first, num_reqs=r, max_blocks=self.max_blocks_per_req,
             worst_case_sampling=True, block_len=self.block_len,
+            num_kv_groups=self.num_kv_groups,
         )
         try:
             self._update_states(so)
@@ -3427,7 +3511,8 @@ class ModelRunner:
             min(
                 self.config.scheduler_config.max_num_batched_tokens,
                 self.config.scheduler_config.max_model_len,
-            )
+            ),
+            num_kv_groups=self.num_kv_groups,
         )
         self.execute_model(so)
         self.input_batch.remove_request("__profile__")
@@ -3438,7 +3523,8 @@ class ModelRunner:
         """Smallest-bucket step with a throwaway request: keeps an idle DP
         rank stepping in lockstep with busy ranks (cross-rank collectives
         need all participants). Reference: ``core.py:731``."""
-        self.execute_model(_dummy_scheduler_output(1))
+        self.execute_model(_dummy_scheduler_output(
+            1, num_kv_groups=self.num_kv_groups))
         self.input_batch.remove_request("__profile__")
         if self._is_hybrid:
             self._release_state_slot("__profile__")
@@ -3450,9 +3536,11 @@ def _dummy_scheduler_output(
     max_blocks: int = 1,
     worst_case_sampling: bool = False,
     block_len: int = 0,
+    num_kv_groups: int = 1,
 ) -> SchedulerOutput:
     """Synthetic batch: request 0 carries ``num_tokens`` prompt tokens (and
-    ``max_blocks`` block-table entries), requests 1..n-1 one token each —
+    ``max_blocks`` block-table entries a KV group), requests 1..n-1 one
+    token each —
     the mixed chunked-prefill shape that maxes every bucket dimension at
     once for memory profiling. With ``block_len`` (generation by diffusion)
     requests 1..n-1 each run a denoising pass over a block of masks."""
@@ -3485,7 +3573,10 @@ def _dummy_scheduler_output(
                 req_id=rid,
                 prompt_token_ids=[1] * n,
                 sampling_params=sp,
-                block_ids=[0] * (max_blocks if i == 0 else 1),
+                block_ids=tuple(
+                    [0] * (max_blocks if i == 0 else 1)
+                    for _ in range(num_kv_groups)
+                ),
                 num_computed_tokens=0,
             )
         )

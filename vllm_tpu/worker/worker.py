@@ -17,8 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from vllm_tpu.config import EngineConfig
-from vllm_tpu.core.kv_cache_utils import get_kv_cache_config_from_specs
+from vllm_tpu.core.kv_cache_utils import group_kv_cache_specs
 from vllm_tpu.core.sched_output import ModelRunnerOutput, SchedulerOutput
+from vllm_tpu.layers.rotary import serving_positions
 from vllm_tpu.logger import init_logger
 # load_hf_config lives beside the registry; its importers find it here too.
 from vllm_tpu.models.registry import get_model_class, load_hf_config  # noqa: F401
@@ -117,9 +118,12 @@ class Worker:
                 )
             mc.quantization = method
         model_cls = get_model_class(hf_config)
-        self.model = model_cls(
-            hf_config, dtype=mc.jax_dtype, quantization=mc.quantization
-        )
+        # Rope tables that would pass the inline limit are built for the
+        # positions this server serves (``layers/rotary.py``).
+        with serving_positions(mc.max_model_len):
+            self.model = model_cls(
+                hf_config, dtype=mc.jax_dtype, quantization=mc.quantization
+            )
         if getattr(self.model, "is_encoder_decoder", False):
             cap = getattr(self.model, "max_position", None)
             if cap and mc.max_model_len > cap:
@@ -229,9 +233,24 @@ class Worker:
             self.model.pp_microbatches = pc.pipeline_microbatches
             self.model.pp_mesh = self.mesh
         # The model decides whether it really uses a window (some HF
-        # configs carry sliding_window for archs that ignore it).
-        window = getattr(self.model, "sliding_window", None)
-        self.config.cache_config.sliding_window = window
+        # configs carry sliding_window for archs that ignore it), and
+        # which of its layers do: its layers' KV specs, grouped, are what
+        # the cache manager keeps block tables and frees pages by.
+        cache = self.config.cache_config
+        spec_fn = getattr(self.model, "get_kv_cache_spec", None)
+        groups = spec_fn and group_kv_cache_specs(spec_fn(
+            cache.block_size, 1 if cache.cache_dtype != "auto"
+            else jnp.dtype(mc.jax_dtype).itemsize))
+        if groups and len(groups) > 1:
+            cache.kv_cache_groups = groups
+            cache.sliding_window = None
+            if pc.context_parallel_size > 1:
+                raise NotImplementedError(
+                    "context parallelism with several KV groups")
+        else:
+            cache.sliding_window = (
+                groups[0].sliding_window if groups
+                else getattr(self.model, "sliding_window", None))
 
         shardings = None
         if self.mesh is not None:
@@ -357,18 +376,21 @@ class Worker:
         specs = self.model.get_kv_cache_spec(
             cache.block_size, jnp.dtype(kv_dtype).itemsize
         )
+        draft_page_bytes = 0
         if self.draft_model is not None:
             # The draft KV (1 layer for EAGLE, the full stack for a
-            # draft model) comes out of the same budget.
+            # draft model) comes out of the same budget: its layers ride
+            # the target's page ids.
             from vllm_tpu.core.kv_cache_utils import FullAttentionSpec
 
-            for i in range(getattr(self.draft_model, "num_layers", 1)):
-                specs[f"draft_{i}"] = FullAttentionSpec(
-                    block_size=cache.block_size,
-                    num_kv_heads=self.draft_model.num_kv_heads,
-                    head_size=self.draft_model.head_dim,
-                    dtype_bytes=jnp.dtype(kv_dtype).itemsize,
-                )
+            draft_page_bytes = getattr(
+                self.draft_model, "num_layers", 1
+            ) * FullAttentionSpec(
+                block_size=cache.block_size,
+                num_kv_heads=self.draft_model.num_kv_heads,
+                head_size=self.draft_model.head_dim,
+                dtype_bytes=jnp.dtype(kv_dtype).itemsize,
+            ).page_size_bytes
         if not self._memory_limit_known():
             raise ValueError(
                 "cannot size the KV cache: device "
@@ -435,7 +457,10 @@ class Worker:
             )
         else:
             free_for_kv = (limit - in_use) * (1 - _ACTIVATION_HEADROOM)
-        page_bytes = sum(spec.page_size_bytes for spec in specs.values())
+        # One page id costs one GROUP's layers (every layer, where the
+        # model has one spec), whichever group it is handed to.
+        groups = group_kv_cache_specs(specs)
+        page_bytes = groups[0].page_size_bytes + draft_page_bytes
         if free_for_kv < page_bytes:
             fixed = (
                 f", of which per-request state {state} bytes for "
@@ -446,8 +471,7 @@ class Worker:
                 f"{fixed}, activations={activation_bytes}, one page "
                 f"{page_bytes} bytes)"
             )
-        kv_config = get_kv_cache_config_from_specs(specs, int(free_for_kv))
-        num_blocks = kv_config.num_blocks
+        num_blocks = int(free_for_kv) // page_bytes
         if cp > 1:
             # The budget above is PER DEVICE and the cache's block dim is
             # cp-sharded: the global pool holds cp x the per-device count.
@@ -458,6 +482,18 @@ class Worker:
             num_blocks,
             cache.block_size,
         )
+        if len(groups) > 1:
+            logger.info(
+                "KV groups: %d over the one pool, a page %d tokens of one "
+                "group's layers, %d bytes: %s",
+                len(groups), cache.block_size, page_bytes,
+                "; ".join(
+                    "%d %s%s" % (
+                        len(g.layer_names), type(g.kv_cache_spec).__name__,
+                        "" if g.sliding_window is None
+                        else " (window %d)" % g.sliding_window,
+                    ) for g in groups),
+            )
         return num_blocks
 
     def initialize(self) -> int:
