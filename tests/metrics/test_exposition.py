@@ -356,17 +356,78 @@ def test_diffusion_and_moe_counters_are_cumulative_and_never_decrease():
         assert f"# TYPE {family} counter" in text
 
 
+def test_a_store_that_rode_with_a_denoising_pass_is_counted_apart():
+    """``vllm:diffusion_stores_fused_total``: a pass that stores a clean
+    block AND denoises the next is one pass of the model, counted once
+    under ``kind="denoise"``; ``kind="store"`` is the lone stores that are
+    left, so fused / (fused + store) is how often the two rode together,
+    and committed / (denoise + store) is tokens a pass of the model."""
+    reg = PrometheusRegistry()
+    reg.record(SchedulerStats(
+        diffusion_passes_by_kind={"denoise": 40, "store": 1},
+        diffusion_stores_fused=19, diffusion_committed_tokens=80))
+    reg.record(SchedulerStats())  # a respawned engine lowers nothing
+    text = reg.render()
+    for sample in ("vllm:diffusion_stores_fused_total 19.0",
+                   'vllm:diffusion_passes_total{kind="denoise"} 40.0',
+                   'vllm:diffusion_passes_total{kind="store"} 1.0',
+                   "# TYPE vllm:diffusion_stores_fused_total counter"):
+        assert sample in text, sample
+    help_of = {line.split()[2]: line for line in text.splitlines()
+               if line.startswith("# HELP vllm:diffusion_")}
+    assert "counts here, once" in help_of["vllm:diffusion_passes_total"]
+    assert "rode with" in help_of["vllm:diffusion_stores_fused_total"]
+
+
+@pytest.mark.parametrize("lone_stores", [0, 3])
+def test_the_accepted_reader_of_tokens_a_pass_reads_a_run_whose_stores_all_rode(lone_stores):
+    """``benchmark/layer_metrics/diffusion_tokens_per_pass.py`` (accepted,
+    not this repository's to edit here) divides by denoise + store and
+    returns None where either sample is missing: ``kind="store"`` has a
+    sample at 0 once any pass is counted, and none before (a model that
+    does not generate by diffusion exposes the family without samples)."""
+    from benchmark.harness.manifest import BENCH_DIR, load_module
+
+    def scrape(reg):
+        out = {}
+        for line in reg.render().splitlines():
+            if line and not line.startswith("#"):
+                key, _, value = line.rpartition(" ")
+                out[key] = float(value)
+        return out
+
+    reg = PrometheusRegistry()
+    reg.record(SchedulerStats(diffusion_passes_by_kind={"denoise": 0, "store": 0}))
+    assert not [k for k in scrape(reg) if k.startswith("vllm:diffusion_passes_total")]
+    reg.record(SchedulerStats(diffusion_passes_by_kind={"denoise": 10, "store": 0},
+                              diffusion_committed_tokens=20))
+    m0 = scrape(reg)
+    assert m0['vllm:diffusion_passes_total{kind="store"}'] == 0.0
+    reg.record(SchedulerStats(
+        diffusion_passes_by_kind={"denoise": 50, "store": lone_stores},
+        diffusion_stores_fused=20 - lone_stores, diffusion_committed_tokens=100))
+    m1 = scrape(reg)
+    reader = load_module(BENCH_DIR / "layer_metrics" / "diffusion_tokens_per_pass.py")
+    got = reader.read({"delta": lambda n: m1[n] - m0[n] if n in m0 and n in m1 else None})
+    assert got == 80 / (40 + lone_stores)
+
+
+@pytest.mark.parametrize("step_tokens", [64, 7], ids=["room_for_two_blocks", "room_for_one"])
 def test_runner_counts_passes_commits_and_experts_on_a_hand_made_batch(
-        tmp_path):
+        tmp_path, step_tokens):
     """One prompt of 9 tokens, 7 more under the static rule at two steps,
     one step at a time: the prefill of 8, then the first block (1 prompt
     token, 3 masks: passes of 2 and 1, then its store), then a block of
-    four masks (2 + 2), after whose last token the request ends."""
+    four masks (2 + 2), after whose last token the request ends. Where a
+    step holds two blocks the store rides with the second block's first
+    pass, one row of 8 that counts once, as ``denoise``; where it holds 7
+    tokens the prefill is two chunks of 4 and the store runs alone."""
     from tests.benchmark.tiny_sdar_moe import TINY_SDAR, model_dir
     from vllm_tpu import LLM, SamplingParams
 
     llm = LLM(model=model_dir(tmp_path), dtype="float32", load_format="dummy",
-              max_model_len=128, block_size=16, max_num_batched_tokens=64,
+              max_model_len=128, block_size=16,
+              max_num_batched_tokens=step_tokens,
               max_num_seqs=4, num_gpu_blocks_override=64,
               async_scheduling=False)
     out = llm.generate(
@@ -376,14 +437,20 @@ def test_runner_counts_passes_commits_and_experts_on_a_hand_made_batch(
                        denoising_steps=2))[0].outputs[0]
     assert out.commit_passes[-1] == 4 and len(out.token_ids) == 7
     runner = llm.llm_engine.engine_core.engine_core.executor.worker.runner
-    assert runner.diffusion_passes_by_kind == {"denoise": 4, "store": 1}
+    room = step_tokens >= 8
+    assert runner.diffusion_passes_by_kind == {
+        "denoise": 4, "store": 0 if room else 1}
+    assert runner.diffusion_stores_fused == (1 if room else 0)
     assert runner.diffusion_committed_tokens == 7
-    # The context a block row reads, once a row: 12 for the three passes of
-    # the first block, 16 for the two of the second.
-    assert runner.attn_context_tokens_by_kind["block"] == 3 * 12 + 2 * 16
+    # The context a block row reads, once a row: 12 for each pass of the
+    # first block, 16 for each of the second, and 16 for the row of both.
+    assert runner.attn_context_tokens_by_kind["block"] == (
+        2 * 12 + 16 + 16 if room else 3 * 12 + 2 * 16)
     layers, top = TINY_SDAR["num_hidden_layers"], TINY_SDAR["num_experts_per_tok"]
     tokens = 8 + 5 * 4
     assert runner.moe_assignments == tokens * top * layers
     # Each step hits at least its 2 experts a layer and at most all 8.
-    assert 6 * 2 * layers <= runner.moe_experts_hit <= 6 * 8 * layers
+    steps = 5 if room else 7
+    assert runner.step_launches == steps
+    assert steps * 2 * layers <= runner.moe_experts_hit <= steps * 8 * layers
     llm.shutdown()

@@ -272,6 +272,142 @@ def test_preemption_in_the_middle_of_a_block_resumes_with_the_same_tokens(
         assert list(o.outputs[0].token_ids) == want["tokens"]
 
 
+def runner_of(engine):
+    return engine.llm_engine.engine_core.engine_core.executor.worker.runner
+
+
+def block_counts(runner):
+    return (runner.diffusion_passes_by_kind["denoise"], runner.diffusion_passes_by_kind["store"],
+            runner.diffusion_stores_fused, runner.diffusion_committed_tokens)
+
+
+@pytest.fixture(scope="module")
+def roomy(tmp_path_factory):
+    """One step at a time and 64 tokens a step: every clean block has room
+    for the next behind it, and the counters are exact."""
+    engine = make_llm(tmp_path_factory.mktemp("roomy"), async_scheduling=False)
+    yield engine
+    engine.shutdown()
+
+
+@pytest.mark.parametrize("rule,steps", [("sequential", 4), ("sequential", 2), ("sequential", 1),
+                                        ("low_confidence_static", 2), ("low_confidence_static", 4)])
+def test_a_block_after_the_first_costs_its_denoising_passes_and_no_store(roomy, weights, rule, steps):
+    """Five whole blocks: each store but the last block's (the request ends
+    with its last token) rides with the next block's first pass, so the
+    model runs ``denoising_steps`` passes a block and the counters say so;
+    tokens and pass numbers are the reference's all the same."""
+    before = block_counts(runner_of(roomy))
+    p = prompt_of(8, 21)
+    out = roomy.generate([{"prompt_token_ids": p}],
+                         params_for(rule, steps, max_tokens=20, logprobs=2))[0].outputs[0]
+    check_against_generate(weights, p, out, rule, steps, 20)
+    denoise, store, fused, committed = np.subtract(block_counts(runner_of(roomy)), before)
+    assert (denoise, store, fused, committed) == (5 * steps, 0, 4, 20)
+    # What benchmark/layer_metrics/diffusion_tokens_per_pass.py reads.
+    assert committed / (denoise + store) == 4 / steps
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("step", dict(max_num_batched_tokens=6, async_scheduling=False)),
+    ("max_model_len", dict(max_model_len=24, async_scheduling=True))])
+def test_the_lone_store_pass_runs_where_two_blocks_do_not_fit(tmp_path, weights, why, kw):
+    """Six tokens a step hold a block and not two: every store runs alone,
+    as it did. At the model's length the last block has none behind it:
+    the lag pipeline schedules its store before the last token lands, and
+    that store runs alone while the others ride."""
+    engine = make_llm(tmp_path, **kw)
+    try:
+        p = prompt_of(9, 4)
+        n = 15 if why == "max_model_len" else 11  # 9 + 15: the model's length
+        rule, steps = "low_confidence_static", 2
+        out = engine.generate([{"prompt_token_ids": p}], params_for(rule, steps, max_tokens=n))[0]
+        check_against_generate(weights, p, out.outputs[0], rule, steps, n)
+        assert out.outputs[0].finish_reason == "length"
+        denoise, store, fused, committed = block_counts(runner_of(engine))
+        assert committed == n
+        if why == "step":
+            assert (denoise, store, fused) == (6, 2, 0)
+        else:
+            assert (denoise, store, fused) == (8, 1, 3)
+    finally:
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("async_scheduling", [True, False])
+def test_preemptions_among_rows_of_two_blocks_resume_with_the_same_tokens(
+        tmp_path, weights, async_scheduling):
+    """Ten pages for five requests under three rules that each grow to
+    three: requests are preempted with rows of two blocks scheduled and in
+    flight, and every answer is the reference's."""
+    engine = make_llm(tmp_path, async_scheduling=async_scheduling, num_gpu_blocks_override=10,
+                      enable_prefix_caching=False)
+    try:
+        prompts = [prompt_of(n, 6) for n in (13, 16, 14, 15, 12)]
+        rules = [("low_confidence_static", 2), ("sequential", 2), ("low_confidence_dynamic", 4),
+                 ("low_confidence_static", 1), ("low_confidence_static", 2)]
+        outs = engine.generate([{"prompt_token_ids": p} for p in prompts],
+                               [params_for(r, s, max_tokens=30) for r, s in rules])
+        sched = engine.llm_engine.engine_core.engine_core.scheduler
+        assert sched.make_stats().num_preempted_reqs > 0
+        denoise, store, fused, committed = block_counts(runner_of(engine))
+        assert fused > 20 and committed >= 150
+    finally:
+        engine.shutdown()
+    for p, o, (rule, steps) in zip(prompts, outs, rules):
+        want = REF.generate(TINY_SDAR, weights, p, 30, rule, steps, 0.02)
+        assert list(o.outputs[0].token_ids) == want["tokens"]
+
+
+@pytest.mark.parametrize("answer,cached", [(14, 16), (16, 16), (17, 32)])
+def test_a_second_ask_hits_the_prefix_up_to_the_last_stored_block(roomy, weights, answer, cached):
+    """A prompt of one page and an answer that ends in the second page's
+    last block (14 tokens: the block is open; 16: it is clean, and the
+    request ended before its store) or one token into the third page (17:
+    the row that opened that block stored the one before). A row of two
+    blocks puts only its first block in the cache, so the second page is
+    hit only once its last block was stored."""
+    p = prompt_of(16, 40 + answer)
+    rule, steps = "low_confidence_static", 2
+    first = roomy.generate([{"prompt_token_ids": p}],
+                           params_for(rule, steps, max_tokens=answer))[0].outputs[0]
+    check_against_generate(weights, p, first, rule, steps, answer)
+    longer = p + list(first.token_ids) + prompt_of(9, 2)
+    again = roomy.generate([{"prompt_token_ids": longer}], params_for(rule, steps))[0]
+    assert again.num_cached_tokens == cached
+    check_against_generate(weights, longer, again.outputs[0], rule, steps)
+
+
+def test_a_dynamic_row_beside_static_ones_shares_steps_of_both_row_widths(roomy, weights):
+    """Five requests under the three rules in one batch, 64 tokens a step:
+    steps hold rows of one block and of two together (the scheduler's
+    outputs say so), every store rides, and each answer, tokens and pass
+    numbers, is the reference's."""
+    sched = roomy.llm_engine.engine_core.engine_core.scheduler
+    widths, schedule = [], sched.schedule
+
+    def watched():
+        so = schedule()
+        if so.block_passes:
+            widths.append({so.num_scheduled_tokens[rid] for rid in so.block_passes})
+        return so
+
+    sched.schedule = watched
+    before = block_counts(runner_of(roomy))
+    try:
+        prompts = [prompt_of(n, 13) for n in (7, 12, 5, 10, 9)]
+        outs = roomy.generate([{"prompt_token_ids": p} for p in prompts],
+                              [params_for(r, s, max_tokens=17) for r, s in RULES])
+    finally:
+        del sched.schedule
+    for p, o, (rule, steps) in zip(prompts, outs, RULES):
+        check_against_generate(weights, p, o.outputs[0], rule, steps, 17)
+    assert {B, 2 * B} in widths and {2 * B} in widths
+    denoise, store, fused, committed = np.subtract(block_counts(runner_of(roomy)), before)
+    # A pass may commit positions past an answer's last token.
+    assert store == 0 and fused >= 5 * 4 and committed >= 5 * 17
+
+
 @pytest.mark.parametrize("rule,steps", [("low_confidence_static", 2), ("low_confidence_dynamic", 4)])
 def test_the_commit_order_the_engine_reports_replays_through_block_forward(llm, weights, rule, steps):
     """For a confidence rule the ORDER of commits is part of the answer:

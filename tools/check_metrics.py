@@ -101,6 +101,7 @@ REQUIRED_STEP_METRICS = {
 REQUIRED_DIFFUSION_MOE_METRICS = {
     "vllm:diffusion_passes_total",
     "vllm:diffusion_committed_tokens_total",
+    "vllm:diffusion_stores_fused_total",
     "vllm:moe_assignments_total",
     "vllm:moe_experts_hit_total",
 }
@@ -358,14 +359,16 @@ def check_diffusion_exposition() -> list[str]:
     try:
         reg.record(SchedulerStats(
             diffusion_passes_by_kind={k: 2 for k in DIFFUSION_PASS_KINDS},
-            diffusion_committed_tokens=3, moe_assignments=5,
-            moe_experts_hit=7, attn_context_tokens_by_kind={"block": 11}))
+            diffusion_committed_tokens=3, diffusion_stores_fused=4,
+            moe_assignments=5, moe_experts_hit=7,
+            attn_context_tokens_by_kind={"block": 11}))
     except (TypeError, AttributeError) as e:
         return [f"the diffusion stats no longer reach the registry: {e}"]
     text = reg.render()
     wanted = [f'vllm:diffusion_passes_total{{kind="{k}"}} 2.0'
               for k in DIFFUSION_PASS_KINDS]
     wanted += ["vllm:diffusion_committed_tokens_total 3.0",
+               "vllm:diffusion_stores_fused_total 4.0",
                "vllm:moe_assignments_total 5.0",
                "vllm:moe_experts_hit_total 7.0",
                'vllm:attn_context_tokens_total{kind="block"} 11.0']

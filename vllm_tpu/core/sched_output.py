@@ -58,17 +58,32 @@ class CachedRequestData:
 @dataclass
 class BlockPass:
     """One pass over a request's open block (generation by diffusion over
-    blocks). ``store`` = the block is clean: run it once more, keep its
-    K/V, sample nothing. Otherwise a denoising pass: every uncommitted
-    position is fed the mask token and sampled, and the row's rule commits
-    some. ``tokens`` / ``committed`` are the host's view of the block, or
-    None when a pass of this request is still in flight and the device's
-    own copy (the previous step's output) is the one to read."""
+    blocks). ``store`` = the block is clean: run it once more and keep its
+    K/V. Alone that is a row of ``B`` positions that samples nothing; with
+    ``denoise_next`` the row is ``2B`` positions, the clean block and then
+    the next block, all masks, whose first denoising pass this is too: one
+    pass of the model that takes TWO numbers, ``seq`` for the store and
+    ``seq + 1`` for what it commits. Without ``store`` a denoising pass:
+    every uncommitted position is fed the mask token and sampled, and the
+    row's rule commits some. ``tokens`` / ``committed`` are the host's view
+    of the block the row STARTS with, or None when a pass of this request
+    is still in flight and the device's own copy (the previous step's
+    output) is the one to read."""
 
     store: bool
     seq: int  # block passes of this request before this one
     tokens: list[int] | None = None
     committed: list[bool] | None = None
+    denoise_next: bool = False
+
+    @property
+    def denoises(self) -> bool:
+        return self.denoise_next or not self.store
+
+    @property
+    def commit_seq(self) -> int:
+        """The number its commits are reported under."""
+        return self.seq + 1 if self.denoise_next else self.seq
 
 
 @dataclass
@@ -194,7 +209,8 @@ class EngineCoreOutput:
     queue_time: float | None = None
     kv_blocks_held: int = 0
     # Generation by diffusion over blocks: for each of new_token_ids the
-    # request's block pass (counted from 0, store passes included) that
+    # request's block pass (counted from 0; a block's store included,
+    # whether it ran alone or rode with the next block's first pass) that
     # committed it. None for an autoregressive model.
     commit_passes: list[int] | None = None
 
@@ -288,12 +304,15 @@ class SchedulerStats:
     compile_cache: dict[str, int] | None = None
     startup: dict | None = None
     # Generation by diffusion over blocks (cumulative, from the runner):
-    # row-passes by kind and positions committed by denoising passes.
+    # row-passes by kind (a pass that stores a clean block and denoises
+    # the next counts once, as "denoise"), positions committed by
+    # denoising passes, and the stores that rode with a denoising pass.
     # Mixture of experts (cumulative, from the runner): token-expert pairs
     # over live tokens and experts that got a live token, both summed over
     # layers and steps.
     diffusion_passes_by_kind: dict[str, int] = field(default_factory=dict)
     diffusion_committed_tokens: int = 0
+    diffusion_stores_fused: int = 0
     moe_assignments: int = 0
     moe_experts_hit: int = 0
     # Linear attention over state slots (cumulative, from the runner, a

@@ -307,7 +307,10 @@ class Scheduler:
         ``computed`` stored tokens and ``budget`` tokens of the step:
         ``(tokens, None)`` for a prefill chunk, which ends on a multiple
         of the block length, ``(B, BlockPass)`` for a pass over its open
-        block, or None when the step has no room for either."""
+        block, ``(2B, BlockPass)`` where the block is clean and the step
+        and the model's length have room for the next block behind it
+        (its store rides with the next block's first denoising pass), or
+        None when the step has no room for a block."""
         blen = self._block_len
         if not request.blk_open:
             remaining = request.num_tokens // blen * blen - computed
@@ -321,32 +324,40 @@ class Scheduler:
             self._blk_open(request, computed)
         if budget < blen or computed + blen > self.config.max_model_len:
             return None
+        store = request.blk_sched_committed >= blen
+        fused = (store and budget >= 2 * blen
+                 and computed + 2 * blen <= self.config.max_model_len)
         # A pass of this request still in flight: the device's own copy of
         # the block (the previous step's output) is ahead of the host's.
         on_device = request.num_inflight_steps > 0
-        return blen, BlockPass(
-            store=request.blk_sched_committed >= blen,
+        return (2 * blen if fused else blen), BlockPass(
+            store=store,
             seq=request.blk_passes,
             tokens=None if on_device else list(request.blk_tokens),
             committed=None if on_device else list(request.blk_committed),
+            denoise_next=fused,
         )
 
     def _blk_after_schedule(self, request: Request, n: int, bp,
                             block_passes: dict) -> None:
         """Schedule-time bookkeeping of a diffusion request, in the sync
         and the async scheduler alike: what is STORED advances here, by a
-        prefill chunk or a store pass; a denoising pass stores nothing."""
+        prefill chunk or by a pass that stores its clean block, alone or
+        with the next block's first denoising pass behind it; a denoising
+        pass stores nothing."""
         blen = self._block_len
         if bp is None:
             request.num_computed_tokens += n
             return
         block_passes[request.request_id] = bp
-        request.blk_passes += 1
+        # A pass that stores AND denoises takes two numbers, the store's
+        # and its commits': pass numbers stay those of one pass a step.
+        request.blk_passes += 2 if bp.denoise_next else 1
         request.num_inflight_steps += 1
         if bp.store:
             request.num_computed_tokens += blen
             request.blk_sched_committed = 0
-        else:
+        if bp.denoises:
             # What the sequential and the static rule commit, and the
             # least the dynamic rule does (a dynamic row is never
             # scheduled ahead of its result; update_from_output resets
@@ -369,15 +380,17 @@ class Scheduler:
         passes: list[int] = []
         stopped = False
         if bp.store:
-            # The clean block is stored; the next opens, all masks.
+            # The clean block is stored; the next opens, all masks. Here
+            # and not at schedule time: the passes that completed the
+            # clean block may have been in flight then, and land first.
             self._blk_reset(request, request.blk_start + blen)
-        elif result is not None:
+        if bp.denoises and result is not None:
             tokens, committed, lps = result
             for j in range(blen):
                 if committed[j] and not request.blk_committed[j]:
                     request.blk_committed[j] = True
                     request.blk_tokens[j] = int(tokens[j])
-                    request.blk_commit_pass[j] = bp.seq
+                    request.blk_commit_pass[j] = bp.commit_seq
                     request.blk_logprobs[j] = lps[j] if lps else None
             while not stopped:
                 j = request.num_tokens - request.blk_start
@@ -1241,8 +1254,10 @@ class Scheduler:
     def _blk_stored(self, request: Request, bp, hit: int = 0) -> int | None:
         """How many of the request's tokens hold their final K/V once the
         step has run (``KVCacheManager.allocate_slots``): a denoising pass
-        stores nothing, so the open block's page stays out of the prefix
-        cache until its last block is stored."""
+        stores nothing, and of the ``2B`` positions of a pass that stores
+        a clean block and denoises the next only the first ``B`` are
+        final, so the open block's page stays out of the prefix cache
+        until its last block is stored."""
         if bp is None:
             return None
         stored = request.num_computed_tokens + hit

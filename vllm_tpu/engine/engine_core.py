@@ -431,7 +431,8 @@ class EngineCore:
                 setattr(stats, name, dict(getattr(runner, name, {})))
             stats.diffusion_passes_by_kind = dict(
                 getattr(runner, "diffusion_passes_by_kind", {}))
-            for name in ("diffusion_committed_tokens", "moe_assignments",
+            for name in ("diffusion_committed_tokens",
+                         "diffusion_stores_fused", "moe_assignments",
                          "moe_experts_hit", "linear_attn_state_rows",
                          "window_attn_rows", "window_attn_pairs"):
                 setattr(stats, name, getattr(runner, name, 0))

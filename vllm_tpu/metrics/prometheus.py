@@ -350,8 +350,15 @@ class PrometheusRegistry:
         self.diffusion_passes = LabeledCounter(
             "vllm:diffusion_passes_total",
             "Passes over a request's open block under generation by "
-            "diffusion, one a row a step: denoise (samples and commits) "
-            "or store (runs the clean block once more for its K/V)", "kind")
+            "diffusion, one a row a step: denoise (samples and commits; "
+            "one that also stores the clean block before its own counts "
+            "here, once) or store (runs the clean block once more for its "
+            "K/V and nothing else)", "kind")
+        self.diffusion_stores_fused = Counter(
+            "vllm:diffusion_stores_fused_total",
+            "Clean blocks whose store rode with the next block's first "
+            "denoising pass, in one row of two blocks, under generation "
+            "by diffusion over blocks")
         self.diffusion_committed = Counter(
             "vllm:diffusion_committed_tokens_total",
             "Positions that denoising passes committed under generation "
@@ -786,6 +793,7 @@ class PrometheusRegistry:
             self.attn_context_tokens, self.attn_ragged_pairs,
             self.pipeline_stall,
             self.diffusion_passes, self.diffusion_committed,
+            self.diffusion_stores_fused,
             self.moe_assignments, self.moe_experts_hit,
             self.linear_attn_state_rows, self.linear_attn_tokens,
             self.window_attn_rows, self.window_attn_pairs,
@@ -948,13 +956,21 @@ class PrometheusRegistry:
                 (self.step_tokens_by_kind, s.step_tokens_by_kind),
                 (self.attn_context_tokens, s.attn_context_tokens_by_kind),
                 (self.attn_ragged_pairs, s.attn_ragged_pairs),
-                (self.diffusion_passes, s.diffusion_passes_by_kind),
                 (self.linear_attn_tokens, s.linear_attn_tokens_by_kind),
             ):
                 for kind, n in by_kind.items():
                     counter.inc_to(kind, float(n))
+            if any(s.diffusion_passes_by_kind.values()):
+                # Once a pass is counted, a sample of each kind, also
+                # where it is 0: with every store riding along "store"
+                # stays there for whoever divides by denoise + store.
+                for kind, n in s.diffusion_passes_by_kind.items():
+                    self.diffusion_passes.inc(kind, 0.0)
+                    self.diffusion_passes.inc_to(kind, float(n))
             self.diffusion_committed.inc_to(
                 float(s.diffusion_committed_tokens))
+            self.diffusion_stores_fused.inc_to(
+                float(s.diffusion_stores_fused))
             self.moe_assignments.inc_to(float(s.moe_assignments))
             self.moe_experts_hit.inc_to(float(s.moe_experts_hit))
             self.linear_attn_state_rows.inc_to(

@@ -5,8 +5,13 @@ holds a block of ``block_length`` positions that is run whole several
 times. A pass feeds every uncommitted position the mask token's embedding,
 samples all of them, and a commit rule turns some into tokens; committed
 tokens never change. When none is left uncommitted the clean block is run
-once more and its K/V stored. Attention is causal over blocks: a position
-sees whole blocks to its left and its own block in both directions.
+once more and its K/V stored: in one row with the next block, all masks,
+behind it, which is that block's first denoising pass too, wherever the
+step's token budget and the model's length have room for both; else in a
+store pass of its own. Attention is causal over blocks: a position sees
+whole blocks to its left and its own block in both directions, so in a row
+of two blocks the second sees the first's fresh K/V as a prefill chunk's
+later blocks see its earlier ones.
 
 The mechanism is a class attribute of the model (``generation``), its
 numbers come from the model's ``config.json``, and everything downstream

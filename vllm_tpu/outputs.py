@@ -29,8 +29,9 @@ class CompletionOutput:
     finish_reason: str | None = None  # "stop" | "length" | "abort"
     stop_reason: int | str | None = None
     # Generation by diffusion over blocks: for each of token_ids the
-    # request's block pass (from 0, store passes counted) that committed
-    # it. None for an autoregressive model.
+    # request's block pass (from 0; a block's store counted, whether it ran
+    # alone or rode with the next block's first pass) that committed it.
+    # None for an autoregressive model.
     commit_passes: list[int] | None = None
 
     @property

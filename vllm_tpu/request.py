@@ -158,7 +158,8 @@ class Request:
         # Schedule-time view: whether a block is open (else the request is
         # in prefill), how many of its positions are committed once every
         # pass in flight has landed, and the block passes scheduled so far
-        # (the PRNG counter of a pass, and the order commits are reported in).
+        # (the PRNG counter of a pass, and the order commits are reported
+        # in; a pass that stores a block and denoises the next takes two).
         self.blk_open = False
         self.blk_sched_committed = 0
         self.blk_passes = 0

@@ -193,11 +193,14 @@ class ModelRunner:
         self._zero_sampled = jnp.zeros(self._max_r, jnp.int32)
         self._prev_rows: dict[str, int] = {}
         # Generation by diffusion over blocks (models/generation.py): rows
-        # of block_len tokens with a committed flag a position. The tokens
-        # and flags one pass leaves are the next pass's input, so they ride
-        # the feedback slot the sampled tokens ride: _last_sampled is then
+        # of block_len tokens with a committed flag a position (of twice
+        # block_len where a clean block's store rides with the next
+        # block's first denoising pass). The tokens and flags one pass
+        # leaves are the next pass's input, so they ride the feedback slot
+        # the sampled tokens ride: _last_sampled is then
         # [max_r, 2 * block_len], tokens beside flags, by the previous
-        # step's rows.
+        # step's rows; after a pass that stored a block they are the NEXT
+        # block's.
         gen = config.model_config.generation or {}
         self.block_len = int(gen.get("block_length", 0))
         self.mask_token_id = int(gen.get("mask_token_id", 0))
@@ -520,7 +523,12 @@ class ModelRunner:
         # kind, and the positions denoising passes committed. Mixture of
         # experts, cumulative: token-expert pairs over live tokens, and
         # experts that got a live token, both summed over layers.
+        # A pass that stores a clean block AND denoises the next is one
+        # pass of the model and counts once, as "denoise"; "store" counts
+        # the lone store passes, diffusion_stores_fused the stores that
+        # rode along.
         self.diffusion_passes_by_kind = {"denoise": 0, "store": 0}
+        self.diffusion_stores_fused = 0
         self.diffusion_committed_tokens = 0
         self.moe_assignments = 0
         self.moe_experts_hit = 0
@@ -650,10 +658,12 @@ class ModelRunner:
         blk = None
         if block_len:
             # Generation by diffusion over blocks, per row: the kind of
-            # pass (0 = a prefill chunk, 1 = denoise, 2 = store), the
-            # commit rule and how many positions a pass commits, the
-            # host's committed flags (read where the row has no feedback),
-            # and the dynamic rule's threshold (last in the f32 buffer).
+            # pass (0 = a prefill chunk, 1 = denoise, 2 = store, 3 = a
+            # row of two blocks, the first stored and the second
+            # denoised), the commit rule and how many positions a pass
+            # commits, the host's committed flags of the block the row
+            # starts with (read where the row has no feedback), and the
+            # dynamic rule's threshold (last in the f32 buffer).
             blk = (
                 take(r), take(r), take(r),
                 take(r * block_len).reshape(r, block_len),
@@ -875,17 +885,21 @@ class ModelRunner:
             # The same for a block row: the tokens and committed flags its
             # previous pass left, still in flight, are this pass's input.
             # A committed position feeds its token, every other the mask
-            # token's embedding.
-            is_blk = blk[0] > 0
+            # token's embedding. They are the block the row STARTS with;
+            # the head, the sampler and the rule run over the row's LAST
+            # block, which is the same one but in a row of two blocks
+            # (kind 3): there the first is clean and stored, and the
+            # second, which the host filled with mask tokens, has no
+            # position committed yet.
+            kind = blk[0]
+            lanes = jnp.arange(block_len, dtype=jnp.int32)[None, :]
             blk_idx = jnp.where(
-                is_blk[:, None],
-                md.query_start_loc[:-1, None]
-                + jnp.arange(block_len, dtype=jnp.int32)[None, :],
+                (kind > 0)[:, None], md.query_start_loc[:-1, None] + lanes,
                 t_pad,
             )  # [r, B] into [T]; out of range where the row runs no block
-            flat_idx = jnp.clip(blk_idx, 0, t_pad - 1)
             blk_tok = jnp.where(
-                needs_fb[:, None], prev_tok[:, :block_len], token_ids[flat_idx]
+                needs_fb[:, None], prev_tok[:, :block_len],
+                token_ids[jnp.clip(blk_idx, 0, t_pad - 1)],
             )
             blk_com = jnp.where(
                 needs_fb[:, None], prev_tok[:, block_len:], blk[3]
@@ -893,6 +907,10 @@ class ModelRunner:
             token_ids = token_ids.at[blk_idx.reshape(-1)].set(
                 jnp.where(blk_com, blk_tok, self.mask_token_id).reshape(-1),
                 mode="drop",
+            )
+            blk_com = blk_com & (kind != 3)[:, None]
+            flat_idx = jnp.clip(
+                md.query_start_loc[1:, None] - block_len + lanes, 0, t_pad - 1
             )
         else:
             last_pos = jnp.maximum(md.query_start_loc[1:] - 1, 0)  # [r]
@@ -1308,8 +1326,13 @@ class ModelRunner:
         of the token drawn under the distribution it was drawn from (its
         confidence), and the row's rule commits some of the uncommitted
         ones. Rule, count and threshold are data of the row, so requests
-        under different rules share one program. A store pass commits
-        nothing and leaves the next block open, all masks."""
+        under different rules share one program. ``flat_idx`` holds a
+        row's LAST ``block_len`` positions: the open block, which in a row
+        of two blocks (kind 3: the clean block, stored, then the next) is
+        the second, so such a row pays the head for one block. A lone
+        store pass (kind 2) commits nothing; after either the state handed
+        to the next pass is the next block's: all masks after a lone
+        store, what this pass committed after a row of two."""
         from dataclasses import replace as _replace
 
         from vllm_tpu.models.generation import REMASKING_RULES
@@ -1369,7 +1392,7 @@ class ModelRunner:
                  rule[:, None] == REMASKING_RULES.index(
                      "low_confidence_static")],
                 [leftmost, surest], dynamic,
-            ) & (kind == 1)[:, None]
+            ) & ((kind == 1) | (kind == 3))[:, None]
             tokens = jnp.where(picked, drawn, blk_tok)
             committed = blk_com | picked
         i32 = jnp.int32
@@ -1377,7 +1400,8 @@ class ModelRunner:
         out = jnp.concatenate(
             [tokens, committed.astype(i32),
              jnp.sum(picked, axis=1, keepdims=True).astype(i32)], axis=1)
-        # To the next pass: after a store pass the next block opens.
+        # To the next pass: after a lone store pass the next block opens
+        # (a row of two blocks opened it itself: blk_com came in empty).
         state = jnp.concatenate(
             [tokens, (committed & (kind != 2)[:, None]).astype(i32)], axis=1)
         lp = None
@@ -2178,7 +2202,9 @@ class ModelRunner:
             # Rows that run their open block: the host's tokens and flags
             # where no pass of the request is in flight, else the previous
             # step's own output, on the device. Prefill chunks sample
-            # nothing; a store pass neither.
+            # nothing; a lone store pass neither. A row of two blocks (the
+            # clean one, stored, then the next, all masks) is a sampling
+            # row like a denoising pass, and counts as one.
             from vllm_tpu.models.generation import REMASKING_RULES
 
             do_sample[:] = False
@@ -2189,7 +2215,7 @@ class ModelRunner:
                     continue
                 p = batch.req_states[rid].sampling_params
                 off = int(query_start_loc[i])
-                blk_kind[i] = 2 if bp.store else 1
+                blk_kind[i] = (3 if bp.denoise_next else 2) if bp.store else 1
                 blk_rule[i] = REMASKING_RULES.index(p.remasking)
                 blk_n[i] = blen // p.denoising_steps
                 fbuf[fbuf.shape[0] - r + i] = p.confidence_threshold
@@ -2200,10 +2226,13 @@ class ModelRunner:
                 else:
                     token_ids[off : off + blen] = bp.tokens
                     blk_committed[i] = bp.committed
-                do_sample[i] = not bp.store
+                if bp.denoise_next:
+                    token_ids[off + blen : off + 2 * blen] = self.mask_token_id
+                    self.diffusion_stores_fused += 1
+                do_sample[i] = bp.denoises
                 block_ctx += int(seq_lens[i])
                 self.diffusion_passes_by_kind[
-                    "store" if bp.store else "denoise"] += 1
+                    "denoise" if bp.denoises else "store"] += 1
             # The context a block row reads, once a row.
             self.attn_context_tokens_by_kind["block"] = (
                 self.attn_context_tokens_by_kind.get("block", 0) + block_ctx
@@ -2266,7 +2295,7 @@ class ModelRunner:
             # count and its place in the block, whatever is in flight.
             for i, rid in enumerate(req_order):
                 bp = so.block_passes.get(rid)
-                prng[i, 1] = 0 if bp is None else bp.seq * blen
+                prng[i, 1] = 0 if bp is None else bp.commit_seq * blen
         if needs_penalties:
             counts_np, mask_np = self._penalty_tensors(rows, r_pad)
             counts, prompt_mask = jnp.asarray(counts_np), jnp.asarray(mask_np)
@@ -2404,7 +2433,8 @@ class ModelRunner:
                 tp_size=self.tp_size,
             )
             if blen:
-                # Every position of a sampling row that is not a store pass.
+                # Every position of the open block of a sampling row (a
+                # lone store pass is none).
                 rows = int(np.sum(nongreedy & do_sample[:r_live])) * blen
             else:
                 rows = int(np.sum(nongreedy))
@@ -3088,7 +3118,7 @@ class ModelRunner:
                 continue
             row = sampled_np[i]
             lps = None
-            if lp_np is not None and not bp.store:
+            if lp_np is not None and bp.denoises:
                 k = int(handle.row_states[i].sampling_params.logprobs or 0)
                 vals, ids, drawn_lp, rank = lp_np
                 lps = [
