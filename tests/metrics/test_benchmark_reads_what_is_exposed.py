@@ -62,6 +62,11 @@ def exposed() -> dict[str, set[tuple[str, str]]]:
             kv_group_pages_in_use=[3, 1], kv_pages_allocated=[5, 5],
             kv_window_pages_freed=4, kv_page_steps={"held": 4, "pool": 10},
             window_attn_rows=6, window_attn_pairs=9,
+            # Groups of unlike page shape, a sink inside the kernels (PR 45).
+            kv_group_page_tokens=[32, 16],
+            kv_token_bytes=[{"published": 7680.0, "held": 9216.0},
+                            {"published": 15360.0, "held": 18432.0}],
+            attn_sink_rows=2,
             linear_attn_state_rows=4,
             linear_attn_tokens_by_kind={"chunk": 3, "decode": 2},
             attn_ragged_pairs={"live": 26, "computed": 100},

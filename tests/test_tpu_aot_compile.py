@@ -951,3 +951,133 @@ def test_laguna_step_compiles_and_reads_each_weight_in_place(
     ]
     assert not moved, (
         f"the period scan moves weight-sized temporaries (>= {smallest} bytes): {moved}")
+
+
+# MiMo-V2.5 (``mimov25.long-answers``): keys of 192 lanes (stored as 256)
+# beside values of 128 in one head-major page row, 64 query heads over 8 KV
+# heads of 16 tokens a page in a window layer (window 128, a sink logit a
+# head) and over 4 KV heads of 32 tokens a page in a full layer, ONE cache
+# array for both; the cell's two step shapes at the whole page table of
+# 12,288 tokens.
+MIMO_CONFIG = "benchmark/configs/mimo-v2.5.json"
+MIMO_ROWS, MIMO_PAGES, MIMO_BLOCKS = 128, 768, 8192
+MIMO_KINDS = {"window": (8, 16, 128, True), "full": (4, 32, 0, False)}
+
+
+def _mimo_attn_args(sharding, t, r, kind, ragged):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    kh, bs, _, sink = MIMO_KINDS[kind]
+    args = [
+        s((t, 64, 256), jnp.bfloat16),
+        s((2, MIMO_BLOCKS, kh, bs, 384), jnp.bfloat16),
+        s((1,), jnp.int32), s((r,), jnp.int32), s((r, MIMO_PAGES), jnp.int32),
+    ]
+    if ragged:
+        args.append(s((r + 1,), jnp.int32))
+    args.append(s((1,), jnp.int32))
+    return args, (s((64,), jnp.float32) if sink else None)
+
+
+@pytest.mark.parametrize("kind", list(MIMO_KINDS))
+def test_mimo_ragged_kernel_compiles_on_the_split_page(one_chip, kind):
+    """The mixed step's kernel over ``[L, NB, KH, BS, 256 + 128]`` pages
+    with a query padded to 256 lanes and an output of 128, at 8 and at 16
+    query heads a KV head; the window kind with its sinks as an operand."""
+    from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
+
+    kh, bs, window, _ = MIMO_KINDS[kind]
+    name = "rpa_kernel_window" if window else "rpa_kernel"
+    args, sinks = _mimo_attn_args(one_chip, 512, MIMO_ROWS, kind, True)
+    fn = functools.partial(ragged_paged_attention, sm_scale=192**-0.5, name=name,
+                           head_major=True)
+    lowered = jax.jit(lambda sinks, *a: fn(
+        *a, sliding_window=jnp.int32(window), sinks=sinks)).lower(sinks, *args)
+    assert lowered.out_info.shape == (512, 64, 128)
+    assert name in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("kind", list(MIMO_KINDS))
+def test_mimo_decode_kernel_compiles_on_the_split_page(one_chip, kind):
+    from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
+
+    kh, bs, window, _ = MIMO_KINDS[kind]
+    name = "rpa_decode_kernel_window" if window else "rpa_decode_kernel"
+    args, sinks = _mimo_attn_args(one_chip, MIMO_ROWS, MIMO_ROWS, kind, False)
+    fn = functools.partial(decode_paged_attention, sm_scale=192**-0.5, name=name,
+                           head_major=True)
+    lowered = jax.jit(lambda sinks, *a: fn(
+        *a, sliding_window=jnp.int32(window), sinks=sinks)).lower(sinks, *args)
+    assert lowered.out_info.shape == (MIMO_ROWS, 64, 128)
+    assert name in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("t,decode_only", [(MIMO_ROWS, True), (512, False)],
+                         ids=["decode_T128_R128", "mixed_T512_R128"])
+def test_mimo_step_compiles_and_reads_each_weight_in_place(
+    one_chip, monkeypatch, t, decode_only
+):
+    """Both step programs of ``mimov25.long-answers`` at the file's own
+    widths and depth (F W W W W F W W W W: two layers in line and two
+    scans over runs of four window layers), with a block table and a slot
+    mapping a KV group over ONE cache array ``[2, pages, 128, 384]``: the
+    two kinds' kernels under their two names, the grouped matmul, and no
+    iteration of a run's scan slicing a weight out of its stack."""
+    import json
+    import pathlib
+
+    from transformers import PretrainedConfig
+
+    from vllm_tpu.layers.rotary import serving_positions
+    from vllm_tpu.models.mimo_v2 import MiMoV2ForCausalLM
+    from vllm_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    config = json.loads((root / MIMO_CONFIG).read_text())
+    config.pop("bench")
+    with serving_positions(MIMO_PAGES * 16):  # as the worker does
+        model = MiMoV2ForCausalLM(PretrainedConfig.from_dict(config), dtype=jnp.bfloat16)
+    assert model.runs == [(0, 1), (1, 4), (5, 1), (6, 4)]
+    assert (model.num_kv_groups, model.kv_group_places) == (5, 2)
+    assert model.kv_cache_shape(MIMO_BLOCKS, 16) == (2, MIMO_BLOCKS, 128, 384)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(model.init_dummy_params, jax.random.PRNGKey(0)))
+    cache = s(model.kv_cache_shape(MIMO_BLOCKS, 16), jnp.bfloat16)
+    r, g = MIMO_ROWS, model.num_kv_groups
+    md = attention.AttentionMetadata(
+        positions=s((t,), jnp.int32), slot_mapping=s((g, t), jnp.int32),
+        block_tables=s((g, r, MIMO_PAGES), jnp.int32),
+        seq_lens=s((r,), jnp.int32), query_start_loc=s((r + 1,), jnp.int32),
+        token_req_idx=s((t,), jnp.int32), logits_indices=s((r,), jnp.int32),
+        num_seqs=s((1,), jnp.int32), decode_only=decode_only,
+    )
+    text = jax.jit(model.apply, donate_argnums=(1,)).lower(
+        params, cache, s((t,), jnp.int32), md
+    ).compile().as_text()
+
+    base = "rpa_decode_kernel" if decode_only else "rpa_kernel"
+    assert base + "_window" in text and re.search(base + r'(?!_window)\b', text)
+    assert "gmm" in text
+    squeezed = lambda dims: tuple(d for d in dims if d != 1)
+    shapes = {squeezed(a.shape[1:]) for name in ("window", "moe", "experts")
+              for a in jax.tree.leaves(params[name]) if a.ndim >= 3}
+    shapes |= {sh[:-2] + sh[:-3:-1] for sh in shapes if len(sh) >= 2}
+    smallest = 4096 * 256 * 2  # a router's matrix
+    moved = [
+        (name, opcode, dims, nbytes)
+        for name, opcode, nbytes, dims in _while_body_instructions(text)
+        if nbytes >= smallest
+        and ("dynamic-slice" in name or opcode == "dynamic-slice"
+             or (opcode in ("copy", "transpose") and squeezed(dims) in shapes))
+    ]
+    assert not moved, (
+        f"a run's scan moves weight-sized temporaries (>= {smallest} bytes): {moved}")

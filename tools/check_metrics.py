@@ -197,6 +197,7 @@ def check() -> list[str]:
     """Return a list of lint errors (empty = clean)."""
     from vllm_tpu.metrics.prometheus import (
         BiLabeledCounter,
+        BiLabeledGauge,
         Counter,
         Gauge,
         Histogram,
@@ -207,7 +208,7 @@ def check() -> list[str]:
         PrometheusRegistry,
     )
 
-    metric_types = (BiLabeledCounter, Counter, Gauge, Histogram,
+    metric_types = (BiLabeledCounter, BiLabeledGauge, Counter, Gauge, Histogram,
                     InfoGauge, LabeledCounter, LabeledGauge,
                     LabeledHistogram)
     reg = PrometheusRegistry()

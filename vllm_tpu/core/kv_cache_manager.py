@@ -7,8 +7,13 @@ group_kv_cache_specs``), over one ``BlockPool``. A uniform model is a list
 of one, and every count below is that manager's; a model that mixes window
 and full layers has a block table a group and request, needs the SUM of the
 groups' new blocks from the pool, and its window groups hand the pages
-their window has passed back to it as the request advances. Recurrent-state
-slots are not groups: they stay with the runner (ROADMAP D3).
+their window has passed back to it as the request advances. A page holds
+``block_size`` tokens of ITS group: groups whose layers cost unlike bytes a
+token hold unlike tokens a page (``KVCacheGroupSpec.block_size``), so every
+count of pages below is taken a group, by that group's manager, and the
+manager's own ``block_size`` is only the smallest of them, the one the
+request's hashes are made at. Recurrent-state slots are not groups: they
+stay with the runner (ROADMAP D3).
 """
 
 from __future__ import annotations
@@ -56,6 +61,8 @@ class KVCacheManager:
         num_stripes: int = 1,
         kv_cache_groups: list[KVCacheGroupSpec] | None = None,
     ) -> None:
+        # The engine's: the tokens a content hash of a request covers, and
+        # the smallest of the groups' (a group's is a multiple of it).
         self.block_size = block_size
         # Context parallelism: a request's k-th context block comes from
         # pool color k % num_stripes (= the cp rank holding that page).
@@ -74,18 +81,31 @@ class KVCacheManager:
             [sliding_window] if kv_cache_groups is None
             else [g.sliding_window for g in kv_cache_groups]
         )
+        sizes = (
+            [block_size] if kv_cache_groups is None
+            else [g.block_size for g in kv_cache_groups]
+        )
         if len(windows) > 1 and num_stripes > 1:
             raise NotImplementedError(
                 "context parallelism with several KV groups: the pool's "
                 "stripes follow ONE block table's order"
             )
+        if any(size % block_size for size in sizes):
+            raise ValueError(
+                f"a KV group's page must hold a multiple of the {block_size} "
+                f"tokens a content hash covers; got {sizes}")
         self.managers = [
-            FullAttentionManager(self.block_pool, block_size, g)
+            FullAttentionManager(
+                self.block_pool, size, g, hash_block_size=block_size)
             if window is None
-            else SlidingWindowManager(self.block_pool, block_size, window, g)
-            for g, window in enumerate(windows)
+            else SlidingWindowManager(
+                self.block_pool, size, window, g, hash_block_size=block_size)
+            for g, (window, size) in enumerate(zip(windows, sizes))
         ]
         self.num_groups = len(self.managers)
+        # A prefix hit ends where every group's pages end: a multiple of
+        # the largest page's tokens (each a multiple of the hashes').
+        self._hit_unit = max(sizes)
         # What a lookup that hit nothing and an allocation that took
         # nothing return: a list a group, as every block table here is.
         self._no_blocks: tuple[list[KVCacheBlock], ...] = tuple(
@@ -127,19 +147,22 @@ class KVCacheManager:
         """
         if not self.enable_caching or not request.block_hashes:
             return self._no_blocks, 0
-        max_hit_blocks = (request.num_tokens - 1) // self.block_size
+        unit = self._hit_unit
+        max_hit_tokens = (request.num_tokens - 1) // unit * unit
         while True:
             hits = [
-                m.find_longest_cache_hit(request, max_hit_blocks)
+                m.find_longest_cache_hit(
+                    request, max_hit_tokens // m.block_size)
                 for m in self.managers
             ]
-            shortest = min(len(h) for h in hits)
-            if all(len(h) == shortest for h in hits):
+            lengths = [len(h) * m.block_size
+                       for h, m in zip(hits, self.managers)]
+            shortest = min(lengths) // unit * unit
+            if all(n == shortest for n in lengths):
                 break
-            max_hit_blocks = shortest
-        num_hit_tokens = shortest * self.block_size
-        self.prefix_cache_stats.observe(request.num_tokens, num_hit_tokens)
-        return (tuple(hits) if shortest else self._no_blocks), num_hit_tokens
+            max_hit_tokens = shortest
+        self.prefix_cache_stats.observe(request.num_tokens, shortest)
+        return (tuple(hits) if shortest else self._no_blocks), shortest
 
     # ------------------------------------------------------------------
     # Slot allocation (every scheduling of a request)
@@ -181,51 +204,54 @@ class KVCacheManager:
                     self.window_pages_freed += m.remove_skipped_blocks(request)
         num_computed_tokens = request.num_computed_tokens + num_new_computed_tokens
         # Lookahead covers speculative positions whose KV lands this step.
-        num_required_blocks = ceil(
-            (num_computed_tokens + num_new_tokens + num_lookahead_tokens)
-            / self.block_size
-        )
+        num_tokens_after = (
+            num_computed_tokens + num_new_tokens + num_lookahead_tokens)
         stored = (
             num_computed_tokens + num_new_tokens
             if num_stored_tokens is None else num_stored_tokens
         )
-        # Every group's table has one entry a block of the sequence (a
-        # null stand-in where the page went back), so every group needs
-        # the same count.
+        # A group's table has one entry a page of ITS tokens over the
+        # sequence (a null stand-in where the page went back): groups of
+        # one page size need the same count, a group of twice the tokens
+        # a page half of it.
         hits = new_computed_blocks or self._no_blocks
-        if not hits[0] and num_required_blocks <= len(tables[0]):
+        num_new_blocks = [
+            ceil(num_tokens_after / m.block_size) - len(table) - len(hit)
+            for m, table, hit in zip(self.managers, tables, hits)
+        ]
+        if not hits[0] and max(num_new_blocks) <= 0:
             # Most calls: a decode row whose last block has room.
             if self.enable_caching:
                 self._cache_full_blocks(request, stored)
             return self._no_blocks
-        num_held = len(tables[0]) + len(hits[0])
-        num_new_blocks = num_required_blocks - num_held
 
         # Cache-hit blocks with ref 0 sit in the free queue; touching them
         # consumes free capacity, so subtract them from the availability
         # check (per color: a hit block occupies its own stripe's queue).
-        first_color = num_held % self.num_stripes
+        first_color = (len(tables[0]) + len(hits[0])) % self.num_stripes
         evictable = [0] * self.num_stripes
         for hit in hits:
             for b in hit:
                 if b.ref_cnt == 0 and not b.is_null:
                     evictable[self.block_pool.color_of(b.block_id)] += 1
-        if num_new_blocks > 0 and not self.block_pool.can_allocate(
-            num_new_blocks * self.num_groups, first_color, evictable
+        needed = sum(n for n in num_new_blocks if n > 0)
+        if needed and not self.block_pool.can_allocate(
+            needed, first_color, evictable
         ):
             return None
 
         new_blocks: list[list[KVCacheBlock]] = []
-        for m, table, hit in zip(self.managers, tables, hits):
+        for m, table, hit, n in zip(
+                self.managers, tables, hits, num_new_blocks):
             # Commit the cache hits.
             if hit:
                 self.block_pool.touch(hit)
                 m.extend(rid, hit)
                 m.num_cached_blocks[rid] = len(table)
             new = []
-            if num_new_blocks > 0:
+            if n > 0:
                 new = self.block_pool.get_new_blocks(
-                    num_new_blocks, first_color=len(table) % self.num_stripes
+                    n, first_color=len(table) % self.num_stripes
                 )
                 m.extend(rid, new)
             new_blocks.append(new)
@@ -261,11 +287,11 @@ class KVCacheManager:
         cap = self.cache_reg_cap.get(request.request_id)
         if cap is not None:
             num_tokens_after_step = min(num_tokens_after_step, cap)
-        num_full = min(
+        num_hashed = min(
             num_tokens_after_step // self.block_size, len(request.block_hashes)
         )
         for m in self.managers:
-            m.cache_full_blocks(request, num_full)
+            m.cache_full_blocks(request, num_hashed)
 
     # ------------------------------------------------------------------
     # Free

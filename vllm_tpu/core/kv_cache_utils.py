@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import hashlib
 import struct
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Callable, NamedTuple, Optional
 
 if TYPE_CHECKING:
@@ -188,31 +188,51 @@ class KVCacheSpec:
     """Per-layer cache requirement (reference: ``vllm/v1/kv_cache_interface.py``).
 
     ``page_size_bytes`` drives KV sizing; the worker allocates
-    ``num_blocks`` pages per layer.
+    ``num_blocks`` pages per layer. ``v_head_size`` is the value head's
+    width where it is not the key's (None: ``head_size`` for both, the
+    layouts every model had).
     """
 
     block_size: int
     num_kv_heads: int
     head_size: int
     dtype_bytes: int
+    v_head_size: int | None = field(default=None, kw_only=True)
 
     @property
     def page_size_bytes(self) -> int:
         # Mirrors ops/attention.py kv_cache_shape: head_dim below the
         # 128-lane tile pair-packs K||V on the lane axis ([.., KH, 2*D]);
-        # otherwise K/V interleave on the sublane axis ([.., 2*KH, D]).
-        # Budget the lane-padded physical bytes of the actual minor dim
-        # (second-minor sublane padding is not modeled; the sizing safety
-        # margin absorbs it).
-        from vllm_tpu.ops.attention import packed_kv_layout
+        # otherwise K/V interleave on the sublane axis ([.., 2*KH, D]);
+        # unlike key and value widths give head-major rows of a key padded
+        # to whole lane tiles and its value ([.., KH, BS, Dk_pad + Dv]).
+        # Budget the lane-padded physical bytes of the actual minor dim.
+        # The second-minor dim is not modeled: it is whole sublane tiles
+        # in the first two layouts at every KV-head count served so far
+        # (16 rows of bf16), and ``block_size`` tokens in the third, which
+        # ``ops/attention.py::split_kv_cache_shape`` refuses where they
+        # would pad; the runner compares the allocation with what the
+        # device reports (``ModelRunner._alloc_kv_cache`` logs "KV cache
+        # on the device", a warning past 2%).
+        from vllm_tpu.ops.attention import packed_kv_layout, split_kv_lanes
 
-        if packed_kv_layout(self.head_size):
+        if self.v_head_size not in (None, self.head_size):
+            rows = self.num_kv_heads
+            lanes = split_kv_lanes(self.head_size, self.v_head_size)
+        elif packed_kv_layout(self.head_size):
             rows, lanes = self.num_kv_heads, 2 * self.head_size
         else:
             rows, lanes = 2 * self.num_kv_heads, self.head_size
         return (
             self.block_size * rows * _lane_padded(lanes) * self.dtype_bytes
         )
+
+    @property
+    def published_token_bytes(self) -> int:
+        """Bytes a token a layer as the model's config states them: a key
+        and a value a KV head, no padding."""
+        v = self.head_size if self.v_head_size is None else self.v_head_size
+        return self.num_kv_heads * (self.head_size + v) * self.dtype_bytes
 
     def max_memory_usage_bytes(self, max_model_len: int) -> int:
         import math
@@ -271,14 +291,33 @@ class MambaSpec(KVCacheSpec):
 @dataclass
 class KVCacheGroupSpec:
     """Layers sharing one block-table/allocation group: a page id of the
-    group holds ``block_size`` tokens of EVERY layer named here."""
+    group holds ``block_size`` tokens of EVERY layer named here.
+    ``num_places`` is the layers a page id of the pool has room for (the
+    cache array's first axis): more than the group's layers only in a short
+    last group, whose places past its layers are never written."""
 
     layer_names: list[str]
     kv_cache_spec: KVCacheSpec
+    num_places: int = 0
+
+    def __post_init__(self) -> None:
+        self.num_places = self.num_places or len(self.layer_names)
+
+    @property
+    def block_size(self) -> int:
+        """Tokens a page of THIS group holds; groups of one pool may
+        differ in it (never in a page's bytes)."""
+        return self.kv_cache_spec.block_size
 
     @property
     def page_size_bytes(self) -> int:
-        return len(self.layer_names) * self.kv_cache_spec.page_size_bytes
+        return self.num_places * self.kv_cache_spec.page_size_bytes
+
+    @property
+    def lost_bytes_per_page(self) -> int:
+        """Bytes of a page no layer of the group writes (a short group)."""
+        return (self.num_places - len(self.layer_names)) * (
+            self.kv_cache_spec.page_size_bytes)
 
     @property
     def sliding_window(self) -> int | None:
@@ -292,20 +331,28 @@ class KVCacheGroupSpec:
 def group_kv_cache_specs(
     specs: dict[str, KVCacheSpec],
 ) -> list[KVCacheGroupSpec]:
-    """Group a model's paged layers by spec into groups of EQUAL layer
-    count over one page pool (reference: ``kv_cache_utils.py
+    """Group a model's paged layers by spec into groups of EQUAL page bytes
+    over one page pool (reference: ``kv_cache_utils.py
     _get_kv_cache_groups_uniform_page_size``).
 
     One spec gives one group of every layer: the page, the block count and
     the step programs a uniform model always had. Several specs (window
-    layers beside full ones) must share one page shape a layer; the group
-    size is the smallest kind's layer count and a kind with ``k`` times as
-    many layers gives ``k`` groups, its ``i``-th layer going to the kind's
-    group ``i % k`` (layers 1, 2, 3, 5, 6, 7, ... of a 1:3 pattern are
-    groups 1, 2 and 3 with one layer of each a period). A page id then
-    means ``block_size`` tokens of one group's layers, whichever group it
-    was handed to, and the cache is one array ``[group size, pages, ...]``
-    indexed by a layer's place in its group.
+    layers beside full ones) give groups of the smallest kind's layer
+    count: a kind with ``k`` times as many layers gives ``k`` groups, its
+    ``i``-th layer going to the kind's group ``i % k`` (layers 1, 2, 3, 5,
+    6, 7, ... of a 1:3 pattern are groups 1, 2 and 3 with one layer of each
+    a period). Where a kind's count is no multiple, its groups are filled
+    in layer order and the LAST one is short: its page ids still pay for
+    the whole group size (``KVCacheGroupSpec.lost_bytes_per_page``).
+
+    Kinds whose layers cost unlike bytes a token (a kind with half the KV
+    heads) keep one page SIZE by unlike tokens a page: the kind with the
+    most bytes a token keeps the specs' ``block_size``, a kind with ``1/m``
+    of them holds ``m`` times the tokens, and a ratio that is no whole
+    number is refused. A page id then means ``group.block_size`` tokens of
+    one group's layers, whichever group it was handed to, and the cache is
+    one array ``[group size, pages, ...]`` indexed by a layer's place in
+    its group.
     """
     assert specs, "model exposed no KV cache specs"
     import dataclasses
@@ -317,25 +364,34 @@ def group_kv_cache_specs(
             kinds[key] = KVCacheGroupSpec([], spec)
         kinds[key].layer_names.append(name)
     if len(kinds) == 1:
-        return list(kinds.values())
-    pages = {k.kv_cache_spec.page_size_bytes for k in kinds.values()}
-    size = min(len(k.layer_names) for k in kinds.values())
-    uneven = [len(k.layer_names) for k in kinds.values()
-              if len(k.layer_names) % size]
-    if len(pages) > 1 or uneven:
+        return [KVCacheGroupSpec(k.layer_names, k.kv_cache_spec)
+                for k in kinds.values()]
+    page = max(k.kv_cache_spec.page_size_bytes for k in kinds.values())
+    uneven = [k for k in kinds.values()
+              if page % k.kv_cache_spec.page_size_bytes]
+    if uneven:
         raise ValueError(
-            "KV groups over one page pool need one page shape a layer and "
-            "layer counts that are multiples of the smallest kind's: got "
+            "KV groups over one page pool need pages of equal bytes: a "
+            "kind's bytes a token must divide the largest kind's; got "
             + ", ".join(
                 f"{len(k.layer_names)} x {type(k.kv_cache_spec).__name__} "
-                f"of {k.kv_cache_spec.page_size_bytes} bytes"
+                f"of {k.kv_cache_spec.page_size_bytes} bytes a "
+                f"{k.kv_cache_spec.block_size} tokens"
                 for k in kinds.values())
         )
+    size = min(len(k.layer_names) for k in kinds.values())
     groups = []
     for kind in kinds.values():
-        k = len(kind.layer_names) // size
+        more = page // kind.kv_cache_spec.page_size_bytes
+        spec = kind.kv_cache_spec if more == 1 else dataclasses.replace(
+            kind.kv_cache_spec, block_size=more * kind.kv_cache_spec.block_size)
+        names = kind.layer_names
+        k = -(-len(names) // size)
         groups += [
-            KVCacheGroupSpec(kind.layer_names[j::k], kind.kv_cache_spec)
+            KVCacheGroupSpec(
+                names[j * size:(j + 1) * size] if len(names) % size
+                else names[j::k],
+                spec, num_places=size)
             for j in range(k)
         ]
     return groups

@@ -235,6 +235,13 @@ class SchedulerStats:
     kv_group_pages_in_use: list[int] = field(default_factory=list)
     kv_pages_allocated: list[int] = field(default_factory=list)
     kv_window_pages_freed: int = 0
+    # Fixed at start, a KV group (empty for a uniform model): the tokens a
+    # page of the group holds, and the bytes one token costs the group's
+    # layers as the model's config states them ("published") and as the
+    # cache holds them ("held": lane padding, and a short group's places
+    # no layer writes).
+    kv_group_page_tokens: list[int] = field(default_factory=list)
+    kv_token_bytes: list[dict[str, float]] = field(default_factory=list)
     # Pages held ("held") and pages of the pool ("pool"), each summed over
     # the scheduler's steps so far: held over pool between two scrapes is
     # the mean share of the pool in use.
@@ -326,6 +333,9 @@ class SchedulerStats:
     # key) pairs the window's mask lets through, a q head.
     window_attn_rows: int = 0
     window_attn_pairs: int = 0
+    # Rows x layers a step ran with a sink logit inside the softmax
+    # (cumulative, from the runner).
+    attn_sink_rows: int = 0
     prep_fallback_rows: int = 0
     # Sampling-epilogue routing: in-jit sample() calls routed to the
     # fused sort-free kernel vs sampling rows that fell back to the XLA

@@ -98,9 +98,10 @@ class RequestQueue:
 
 def _block_ids(blocks: tuple[list, ...]) -> tuple[list[int], ...]:
     """The ids of what ``KVCacheManager.allocate_slots`` returned, a list a
-    KV group; ``()`` where no group grew (every group grows by the same
-    count), so that the runner can skip the row."""
-    if not blocks[0]:
+    KV group; ``()`` where no group grew, so that the runner can skip the
+    row (groups of one page size grow together; a group of more tokens a
+    page grows on fewer steps, and its list is then empty)."""
+    if not any(blocks):
         return ()
     return tuple([b.block_id for b in group] for group in blocks)
 
@@ -182,6 +183,14 @@ class Scheduler:
         # calls between logger updates).
         self._num_preempted_total = 0
         self._kv_page_steps = {"held": 0, "pool": 0}
+        groups = cache_config.kv_cache_groups or []
+        self._kv_group_page_tokens = [g.block_size for g in groups]
+        self._kv_token_bytes = [
+            {"published": float(len(g.layer_names)
+                                * g.kv_cache_spec.published_token_bytes),
+             "held": g.page_size_bytes / g.block_size}
+            for g in groups
+        ]
         # Preempted ids from a schedule() whose output was never
         # dispatched (zero scheduled tokens): re-delivered on the next
         # dispatched step so the runner still releases per-request state.
@@ -1772,6 +1781,8 @@ class Scheduler:
             kv_pages_allocated=(
                 self.kv_cache_manager.pages_allocated_by_group()),
             kv_window_pages_freed=self.kv_cache_manager.window_pages_freed,
+            kv_group_page_tokens=self._kv_group_page_tokens,
+            kv_token_bytes=self._kv_token_bytes,
             kv_page_steps=dict(self._kv_page_steps),
             prefix_cache_queries=stats.queries,
             prefix_cache_hits=stats.hits,

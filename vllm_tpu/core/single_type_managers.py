@@ -30,10 +30,16 @@ class FullAttentionManager:
     sliding_window: int | None = None
 
     def __init__(
-        self, block_pool: "BlockPool", block_size: int, group_id: int = 0
+        self, block_pool: "BlockPool", block_size: int, group_id: int = 0,
+        hash_block_size: int | None = None,
     ) -> None:
         self.block_pool = block_pool
+        # Tokens a page of THIS group holds, and how many of the request's
+        # content hashes (made a ``hash_block_size`` tokens) one page
+        # spans: the page's key is the LAST of them, whose chain covers
+        # the page's every token.
         self.block_size = block_size
+        self.hashes_per_block = block_size // (hash_block_size or block_size)
         self.group_id = group_id
         # This group's block table a request (null stand-ins where a
         # window manager has given the page back), and how many leading
@@ -58,11 +64,18 @@ class FullAttentionManager:
         self.pages_in_use += n
         self.pages_allocated += n
 
+    def _page_hashes(self, request: "Request") -> list:
+        """The request's content hashes, one a page of this group."""
+        k = self.hashes_per_block
+        if k == 1:
+            return request.block_hashes
+        return request.block_hashes[k - 1::k]
+
     def find_longest_cache_hit(
         self, request: "Request", max_hit_blocks: int
     ) -> list[KVCacheBlock]:
         hit: list[KVCacheBlock] = []
-        for block_hash in request.block_hashes[:max_hit_blocks]:
+        for block_hash in self._page_hashes(request)[:max_hit_blocks]:
             block = self.block_pool.get_cached_block(
                 block_hash, self.group_id)
             if block is None:
@@ -70,15 +83,17 @@ class FullAttentionManager:
             hit.append(block)
         return hit
 
-    def cache_full_blocks(self, request: "Request", num_full: int) -> None:
-        """Register the request's blocks below ``num_full`` that are not
-        registered yet under their content hashes and this group."""
+    def cache_full_blocks(self, request: "Request", num_hashed: int) -> None:
+        """Register the request's pages that the first ``num_hashed``
+        content hashes fill and that are not registered yet, under their
+        hashes and this group."""
+        num_full = num_hashed // self.hashes_per_block
         num_cached = self.num_cached_blocks.get(request.request_id, 0)
         if num_full <= num_cached:
             return
         self.block_pool.cache_full_blocks(
             self.req_to_blocks[request.request_id],
-            request.block_hashes,
+            self._page_hashes(request),
             num_cached_blocks=num_cached,
             num_full_blocks=num_full,
             group_id=self.group_id,
@@ -103,9 +118,9 @@ class SlidingWindowManager(FullAttentionManager):
 
     def __init__(
         self, block_pool: "BlockPool", block_size: int, sliding_window: int,
-        group_id: int = 0,
+        group_id: int = 0, hash_block_size: int | None = None,
     ) -> None:
-        super().__init__(block_pool, block_size, group_id)
+        super().__init__(block_pool, block_size, group_id, hash_block_size)
         self.sliding_window = sliding_window
         # First not-yet-freed block index a request, so each block is
         # nulled exactly once (no O(seq_len) rescans).
@@ -120,7 +135,7 @@ class SlidingWindowManager(FullAttentionManager):
         backward for the LAST such run; a run anchored at block 0 is a
         plain prefix hit at any length."""
         required = -(-(self.sliding_window - 1) // self.block_size)
-        hashes = request.block_hashes[:max_hit_blocks]
+        hashes = self._page_hashes(request)[:max_hit_blocks]
         null = self.block_pool.null_block
         blocks: list[KVCacheBlock] = [null] * len(hashes)
         run = 0

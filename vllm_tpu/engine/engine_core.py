@@ -434,7 +434,8 @@ class EngineCore:
             for name in ("diffusion_committed_tokens",
                          "diffusion_stores_fused", "moe_assignments",
                          "moe_experts_hit", "linear_attn_state_rows",
-                         "window_attn_rows", "window_attn_pairs"):
+                         "window_attn_rows", "window_attn_pairs",
+                         "attn_sink_rows"):
                 setattr(stats, name, getattr(runner, name, 0))
             stats.linear_attn_tokens_by_kind = dict(
                 getattr(runner, "linear_attn_tokens_by_kind", {}))

@@ -148,6 +148,8 @@ class BiLabeledCounter:
     """One counter family with two label dimensions (e.g. scale
     direction x outcome)."""
 
+    kind = "counter"  # the exposition's TYPE
+
     def __init__(self, name: str, doc: str, label1: str,
                  label2: str) -> None:
         self.name, self.doc = name, doc
@@ -166,7 +168,7 @@ class BiLabeledCounter:
     def render(self) -> str:
         out = [
             f"# HELP {self.name} {self.doc}",
-            f"# TYPE {self.name} counter",
+            f"# TYPE {self.name} {self.kind}",
         ]
         for (k1, k2) in sorted(self.values):
             out.append(
@@ -174,6 +176,15 @@ class BiLabeledCounter:
                 f" {self.values[(k1, k2)]}"
             )
         return "\n".join(out) + "\n"
+
+
+class BiLabeledGauge(BiLabeledCounter):
+    """One gauge family with two label dimensions: set, not counted up."""
+
+    kind = "gauge"
+
+    def set(self, key1: str, key2: str, v: float) -> None:
+        self.values[(key1, key2)] = v
 
 
 class InfoGauge:
@@ -401,6 +412,23 @@ class PrometheusRegistry:
             "vllm:kv_window_pages_freed_total",
             "Pages the window groups gave back to the pool before their "
             "request ended: wholly under the window's floor")
+        self.kv_group_page_tokens = LabeledGauge(
+            "vllm:kv_group_page_tokens",
+            "Tokens one page of the pool holds of a KV group's layers "
+            "(groups whose layers cost unlike bytes a token differ in it, "
+            "never in a page's bytes); fixed at start", "group")
+        self.kv_token_bytes = BiLabeledGauge(
+            "vllm:kv_token_bytes",
+            "Bytes one token costs a KV group's layers: published (a key "
+            "and a value a KV head as the model's config states them) and "
+            "held (as the cache lays them out: lane padding, and a short "
+            "group's places no layer writes); fixed at start",
+            "group", "what")
+        self.attn_sink_rows = Counter(
+            "vllm:attn_sink_rows_total",
+            "Rows a dispatched step ran through attention with a sink "
+            "logit a head inside the softmax, summed over the layers that "
+            "have one")
         self.kv_page_steps = LabeledCounter(
             "vllm:kv_page_steps_total",
             "Pages summed over the scheduler's steps: held (by requests, "
@@ -799,6 +827,8 @@ class PrometheusRegistry:
             self.window_attn_rows, self.window_attn_pairs,
             self.kv_group_pages_in_use, self.kv_pages_allocated,
             self.kv_window_pages_freed, self.kv_page_steps,
+            self.kv_group_page_tokens, self.kv_token_bytes,
+            self.attn_sink_rows,
             self.state_slots_in_use,
             self.decode_batch_ratio, self.tokens_per_launch,
             self.prep_fallback_rows,
@@ -982,6 +1012,12 @@ class PrometheusRegistry:
             for g, n in enumerate(s.kv_pages_allocated):
                 self.kv_pages_allocated.inc_to(str(g), float(n))
             self.kv_window_pages_freed.inc_to(float(s.kv_window_pages_freed))
+            for g, n in enumerate(s.kv_group_page_tokens):
+                self.kv_group_page_tokens.set(str(g), n)
+            for g, costs in enumerate(s.kv_token_bytes):
+                for what, n in costs.items():
+                    self.kv_token_bytes.set(str(g), what, n)
+            self.attn_sink_rows.inc_to(float(s.attn_sink_rows))
             for kind, n in s.kv_page_steps.items():
                 self.kv_page_steps.inc_to(kind, float(n))
             self.state_slots_in_use.set(s.state_slots_in_use)

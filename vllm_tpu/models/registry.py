@@ -37,6 +37,7 @@ _REGISTRY: dict[str, tuple[str, str]] = {
     "Qwen3NextForCausalLM": ("vllm_tpu.models.qwen3_next", "Qwen3NextForCausalLM"),
     "KimiLinearForCausalLM": ("vllm_tpu.models.kimi_linear", "KimiLinearForCausalLM"),
     "LagunaForCausalLM": ("vllm_tpu.models.laguna", "LagunaForCausalLM"),
+    "MiMoV2ForCausalLM": ("vllm_tpu.models.mimo_v2", "MiMoV2ForCausalLM"),
     "Phi3ForCausalLM": ("vllm_tpu.models.phi3", "Phi3ForCausalLM"),
     "GraniteForCausalLM": ("vllm_tpu.models.granite", "GraniteForCausalLM"),
     "Olmo2ForCausalLM": ("vllm_tpu.models.olmo2", "Olmo2ForCausalLM"),

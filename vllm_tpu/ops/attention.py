@@ -119,6 +119,44 @@ def kv_cache_shape(
     return (num_layers, num_blocks, block_size, 2 * num_kv_heads, head_dim)
 
 
+def split_kv_lanes(head_dim: int, v_head_dim: int) -> int:
+    """Lanes of one row of the SPLIT layout (a key and a value of unlike
+    widths): the key padded with zeros to whole 128-lane tiles, then the
+    value. A key of 192 and a value of 128 give 256 + 128."""
+    return -(-head_dim // 128) * 128 + v_head_dim
+
+
+def split_kv_cache_shape(
+    num_layers: int, num_blocks: int, block_size: int, num_kv_heads: int,
+    head_dim: int, v_head_dim: int, dtype=jnp.bfloat16,
+) -> tuple[int, int, int, int]:
+    """Geometry of a cache whose key and value heads differ in width: one
+    donated 4-D buffer ``[L, NB, KH * BS, Dk_pad + Dv]``, a page's rows
+    HEAD-MAJOR (row ``h * BS + offset``), so that a KV head's tokens are
+    whole sublane tiles, a tp shard's heads are contiguous rows, and kinds
+    of layer with ``KH * BS`` in common (8 heads of 16 tokens, 4 of 32)
+    share ONE array: ``write_kv`` and the attention ops view it as
+    ``[L, NB, KH, BS, lanes]`` by the K/V head count of the layer they
+    serve. ``block_size`` must fill whole sublane tiles of ``dtype`` on
+    the TPU (16 tokens of bf16, 32 of fp8), or a page would pad there."""
+    tile = 8 * 4 // jnp.dtype(dtype).itemsize
+    if jax.default_backend() == "tpu" and block_size % tile:
+        raise ValueError(
+            f"a page of {block_size} tokens pads to {tile}-row sublane "
+            f"tiles of {jnp.dtype(dtype).name} in the split KV layout: "
+            f"use a block_size that is a multiple of {tile}")
+    return (num_layers, num_blocks, num_kv_heads * block_size,
+            split_kv_lanes(head_dim, v_head_dim))
+
+
+def _split_view(kv_cache: jnp.ndarray, num_kv_heads: int) -> jnp.ndarray:
+    """``[L, NB, KH * BS, lanes]`` seen as ``[L, NB, KH, BS, lanes]``."""
+    nl, nb, rows, lanes = kv_cache.shape
+    assert rows % num_kv_heads == 0, (kv_cache.shape, num_kv_heads)
+    return kv_cache.reshape(
+        nl, nb, num_kv_heads, rows // num_kv_heads, lanes)
+
+
 def kv_dequant_scale(kv_cache) -> float | None:
     """Dequant scale for quantized (fp8) KV pages: values are cast, not
     scaled, on insert, so the scale is 1.0; None = no dequant needed."""
@@ -135,7 +173,23 @@ def write_kv(
     slot_mapping: jnp.ndarray,  # [T]
 ) -> jnp.ndarray:
     """Scatter this step's K/V into layer `layer`'s paged slots (in place
-    when the cache is a donated scan carry)."""
+    when the cache is a donated scan carry). A 4-D cache is the split
+    layout (``split_kv_cache_shape``): ``k [T, KH, Dk]`` and ``v [T, KH,
+    Dv]`` go to row ``h * BS + offset`` of their page, the key's padding
+    lanes written as zeros, ``BS`` read off the cache and ``KH``."""
+    if kv_cache.ndim == 4:
+        nl, nb, rows, lanes = kv_cache.shape
+        t, kh, d = k.shape
+        bs = rows // kh
+        pad = lanes - d - v.shape[-1]
+        kv_new = jnp.concatenate(
+            [k, jnp.zeros((t, kh, pad), k.dtype), v], axis=-1)
+        row = ((layer * nb + slot_mapping // bs)[:, None] * kh
+               + jnp.arange(kh, dtype=jnp.int32)[None, :]) * bs + (
+            slot_mapping % bs)[:, None]  # [T, KH]
+        flat = kv_cache.reshape(nl * nb * rows, lanes)
+        flat = flat.at[row].set(kv_new.astype(kv_cache.dtype))
+        return flat.reshape(kv_cache.shape)
     nl, nb, bs, rows, lanes = kv_cache.shape
     t, kh, d = k.shape
     if packed_kv_layout(d):
@@ -163,13 +217,31 @@ def paged_attention(
     k_scale: float | None = None,
     v_scale: float | None = None,
     kernel_suffix: str = "",
+    sinks: jnp.ndarray | None = None,
+    num_kv_heads: int | None = None,
 ) -> jnp.ndarray:
     """Backend dispatcher: in-repo Pallas flash kernel on TPU, XLA reference
     elsewhere (and under VLLM_TPU_DISABLE_PALLAS). ``kernel_suffix`` is
     appended to the kernel's name in a trace (``rpa_kernel_window``): a
-    model whose layers are of two kinds tells their calls apart by it."""
+    model whose layers are of two kinds tells their calls apart by it.
+
+    ``sinks [H]`` float32: a learned logit a q head that joins every row's
+    softmax denominator and has no value (``p_ij = exp(s_ij) / (exp(sink)
+    + sum_k exp(s_ik))``), inside both kernels as their starting state. A
+    4-D ``kv_cache`` is the split layout (``split_kv_cache_shape``) and
+    needs ``num_kv_heads``; the output then has the value's width."""
     import vllm_tpu.envs as envs
 
+    if sinks is not None or kv_cache.ndim == 4:
+        assert md.tree_mask is None and md.num_common_prefix_blocks == 0, (
+            "tree verification and cascade attention have no form with a "
+            "sink or with unlike key and value widths")
+        return dispatch_ragged_attention(
+            q, kv_cache, layer, md, scale, sliding_window=sliding_window,
+            soft_cap=soft_cap, k_scale=k_scale, v_scale=v_scale,
+            kernel_suffix=kernel_suffix, sinks=sinks,
+            num_kv_heads=num_kv_heads,
+        )
     if md.tree_mask is not None:
         # Tree-verification step: ancestor-masked window + paged context.
         # Single choke point for the no-sliding-window contract — the
@@ -203,21 +275,34 @@ def _on_tpu() -> bool:
 
 
 def _call_attention_kernel(kernel, mesh, q, kv_cache, replicated, traced_kw,
-                           return_lse: bool = False):
+                           return_lse: bool = False, sinks=None,
+                           head_major: bool = False):
     """Run a Pallas attention kernel; per tp shard when ``mesh`` has
     tp > 1. Each shard sees its own q heads ``[T, H/tp, D]`` and its own
     rows of the cache ``[L, NB, BS, rows/tp, lanes]`` (K/V of one head are
-    adjacent rows, or one packed row, so a head never straddles shards);
-    the row metadata and the traced scalars are replicated."""
+    adjacent rows, or one packed row, so a head never straddles shards),
+    or its own heads of the split layout's view ``[L, NB, KH/tp, BS,
+    lanes]`` (``head_major``), and its own heads' ``sinks``; the row
+    metadata and the traced scalars are replicated."""
     from jax.sharding import PartitionSpec as P
 
     from vllm_tpu.parallel.mesh import AXIS_TP, shard_map
 
     tp = 1 if mesh is None else mesh.shape[AXIS_TP]
+    if sinks is not None:
+        plain = kernel
+        kernel = lambda q, kv, sinks, *rep, **kw: plain(
+            q, kv, *rep, sinks=sinks, **kw)
+        replicated = (sinks, *replicated)
     if tp == 1:
         return kernel(q, kv_cache, *replicated, **traced_kw)
-    num_kv_heads = kv_cache.shape[3] // (1 if packed_kv_layout(q.shape[-1])
-                                         else 2)
+    if head_major:
+        num_kv_heads, cache_spec = kv_cache.shape[2], P(
+            None, None, AXIS_TP, None, None)
+    else:
+        num_kv_heads = kv_cache.shape[3] // (
+            1 if packed_kv_layout(q.shape[-1]) else 2)
+        cache_spec = P(None, None, None, AXIS_TP, None)
     if q.shape[1] % tp or num_kv_heads % tp:
         raise ValueError(
             f"tensor parallel size {tp} must divide the q heads "
@@ -231,10 +316,13 @@ def _call_attention_kernel(kernel, mesh, q, kv_cache, replicated, traced_kw,
         for k, v in traced_kw.items()
     }
     heads = P(None, AXIS_TP, None)
+    rep_specs = P()
+    if sinks is not None:
+        rep_specs = (P(AXIS_TP), *[P()] * (len(replicated) - 1))
     return shard_map(
         lambda q, kv, rep, kw: kernel(q, kv, *rep, **kw),
         mesh=mesh,
-        in_specs=(heads, P(None, None, None, AXIS_TP, None), P(), P()),
+        in_specs=(heads, cache_spec, rep_specs, P()),
         out_specs=(heads, P(None, AXIS_TP)) if return_lse else heads,
         # Mosaic wants every mesh axis manual, not only tp: take all that
         # an enclosing shard_map (context parallelism) has not taken.
@@ -243,17 +331,21 @@ def _call_attention_kernel(kernel, mesh, q, kv_cache, replicated, traced_kw,
     )(q, kv_cache, replicated, traced_kw)
 
 
-def _pallas_ok(head_dim: int) -> bool:
-    """Kernels on, and a head_dim the attention kernels have a layout for."""
+def _pallas_ok(head_dim: int, split: bool = False) -> bool:
+    """Kernels on, and a layout the attention kernels have: K and V of one
+    ``head_dim`` of 64 (packed on the lane axis), 128 or 256 (interleaved
+    rows); or the split layout, whose key the dispatch has padded to whole
+    lane tiles already, whatever the value's width."""
     import vllm_tpu.envs as envs
 
-    return not envs.VLLM_TPU_DISABLE_PALLAS and head_dim in (64, 128, 256)
+    return not envs.VLLM_TPU_DISABLE_PALLAS and (
+        head_dim % 128 == 0 if split else head_dim in (64, 128, 256))
 
 
 def ragged_kernel_tiling(
     num_q_heads: int, num_kv_heads: int, head_dim: int, page_size: int,
     q_dtype, kv_dtype, num_q_tokens: int, pages_per_seq: int, *,
-    block_len: int = 1, tp_size: int = 1,
+    block_len: int = 1, tp_size: int = 1, split: bool = False,
 ):
     """The ``RaggedTiling`` ``dispatch_ragged_attention`` runs a step of
     these shapes with where the step is not decode-only, or None where it
@@ -261,7 +353,7 @@ def ragged_kernel_tiling(
     has no layout for): for the runner's count of the kernel's work."""
     from vllm_tpu.ops.rpa_kernel import ragged_tiling
 
-    if not (_pallas_ok(head_dim) and _on_tpu()):
+    if not (_pallas_ok(head_dim, split) and _on_tpu()):
         return None
     return ragged_tiling(
         num_q_heads // tp_size, 2 * num_kv_heads // tp_size, head_dim,
@@ -286,14 +378,30 @@ def dispatch_ragged_attention(
     ctx_phase=0,
     allow_interpret: bool = False,
     kernel_suffix: str = "",
+    sinks: jnp.ndarray | None = None,
+    num_kv_heads: int | None = None,
 ):
     """THE kernel-vs-reference dispatch point (plain and striped-context
     callers alike — eligibility rules live only here): the Pallas flash
     kernel when it can run (TPU; or interpret mode on other backends when
     ``allow_interpret`` and VLLM_TPU_PALLAS_INTERPRET — the CP shard_map
-    tests), else the XLA gather reference."""
+    tests), else the XLA gather reference. ``sinks`` and the split layout
+    (a 4-D cache and its ``num_kv_heads``) as ``paged_attention`` says."""
     import vllm_tpu.envs as envs
 
+    split = kv_cache.ndim == 4
+    if split:
+        # The kernels and the reference see the page head-major in five
+        # dims and the query padded with zeros to the key's stored lanes.
+        kv_cache = _split_view(kv_cache, num_kv_heads)
+        pad = -q.shape[-1] % 128
+        if pad:
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, pad)))
+        assert not return_lse, "no LSE output from the split layout"
+    if sinks is not None:
+        assert not return_lse and md.block_len == 1, (
+            "a sink joins neither an LSE merge nor attention over blocks")
+        sinks = sinks.astype(jnp.float32)
     interpret = allow_interpret and bool(envs.VLLM_TPU_PALLAS_INTERPRET)
     on_tpu = _on_tpu()
     # Decode-only fast path: every live row is a single-position decode
@@ -310,13 +418,16 @@ def dispatch_ragged_attention(
         and q.shape[0] == md.seq_lens.shape[0]
         and not envs.VLLM_TPU_DISABLE_DECODE_KERNEL
     )
-    use_kernel = _pallas_ok(q.shape[-1]) and (on_tpu or interpret)
+    use_kernel = _pallas_ok(q.shape[-1], split) and (on_tpu or interpret)
     run_interpret = interpret and not on_tpu
     layer1 = jnp.asarray(layer, jnp.int32).reshape(1)
     static_kw = dict(
         sm_scale=scale, soft_cap=soft_cap, k_scale=k_scale, v_scale=v_scale,
         interpret=run_interpret,
     )
+    extra = dict(sinks=sinks, head_major=split)
+    if split:
+        static_kw["head_major"] = True
     if md.block_len > 1:
         assert not md.decode_only and sliding_window is None, (
             "attention over blocks has no decode-only or windowed form")
@@ -344,6 +455,7 @@ def dispatch_ragged_attention(
             kv_cache,
             (layer1, md.seq_lens, md.block_tables, md.num_seqs),
             dict(sliding_window=sliding_window),
+            **extra,
         )
     if use_kernel:
         from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
@@ -369,12 +481,13 @@ def dispatch_ragged_attention(
             dict(sliding_window=sliding_window, ctx_stride=ctx_stride,
                  ctx_phase=ctx_phase),
             return_lse=return_lse,
+            **extra,
         )
     return ref_ragged_paged_attention(
         q, kv_cache, layer, md, scale, sliding_window=sliding_window,
         soft_cap=soft_cap, k_scale=k_scale, v_scale=v_scale,
         return_lse=return_lse, ctx_stride=ctx_stride, ctx_phase=ctx_phase,
-        block_len=md.block_len,
+        block_len=md.block_len, sinks=sinks, head_major=split,
     )
 
 
@@ -394,11 +507,16 @@ def ref_ragged_paged_attention(
     ctx_phase: int = 0,
     ctx_min_pos=0,
     block_len: int = 1,
+    sinks: jnp.ndarray | None = None,
+    head_major: bool = False,
 ) -> jnp.ndarray:
     """Gather-based masked attention. Each token attends to its request's
     cached context up to and including its own position (causal), or with
     ``block_len`` > 1 up to the end of its own block of that many
-    positions (causal over blocks).
+    positions (causal over blocks). ``sinks [H]`` joins every row's
+    softmax denominator; ``head_major`` says the cache is the split
+    layout's view ``[L, NB, KH, BS, Dk_pad + Dv]`` and ``q`` padded to
+    ``Dk_pad``: the output is ``[T, H, Dv]``.
 
     ``ctx_stride``/``ctx_phase`` describe striped context-parallel shards:
     local page j holds global page ``j * stride + phase`` (stride 1 = the
@@ -408,13 +526,16 @@ def ref_ragged_paged_attention(
     additionally returns the per-(token, head) logsumexp — the
     ``merge_attn_states`` contract."""
     t, h, d = q.shape
-    nl, nb, bs, rows, lanes = kv_cache.shape
-    packed = packed_kv_layout(d)
+    # Gather only the referenced pages of this layer: [R, B, BS, rows, lanes].
+    pages = kv_cache[layer, md.block_tables]
+    if head_major:
+        pages = jnp.swapaxes(pages, 2, 3)  # [R, B, KH, BS, .] -> [R, B, BS, KH, .]
+    nl, nb = kv_cache.shape[:2]
+    bs, rows, lanes = pages.shape[2:]
+    packed = head_major or packed_kv_layout(d)
     kh = rows if packed else rows // 2
     groups = h // kh
 
-    # Gather only the referenced pages of this layer: [R, B, BS, rows, lanes].
-    pages = kv_cache[layer, md.block_tables]
     r, b = md.block_tables.shape
     ctx = b * bs
     kv_req = pages.reshape(r, ctx, rows, lanes)
@@ -454,11 +575,18 @@ def ref_ragged_paged_attention(
         causal &= (ctx_pos > (md.positions[:, None] - win)) | (win <= 0)
     scores = jnp.where(causal[:, None, None, :], scores, -jnp.inf)
 
-    probs = jax.nn.softmax(scores, axis=-1)
+    if sinks is not None:
+        # One more column a row, with no value: it only takes its share.
+        sink = jnp.broadcast_to(
+            sinks.reshape(1, kh, groups, 1), (t, kh, groups, 1))
+        probs = jax.nn.softmax(
+            jnp.concatenate([scores, sink], axis=-1), axis=-1)[..., :-1]
+    else:
+        probs = jax.nn.softmax(scores, axis=-1)
     # Fully-masked rows (padding tokens) produce NaN-free zeros:
     probs = jnp.where(jnp.isnan(probs), 0.0, probs)
     out = jnp.einsum("tkgc,tckd->tkgd", probs, v_t)
-    out = out.reshape(t, h, d).astype(q.dtype)
+    out = out.reshape(t, h, v_t.shape[-1]).astype(q.dtype)
     if not return_lse:
         return out
     lse = jax.scipy.special.logsumexp(scores, axis=-1)  # [T, KH, G]

@@ -117,28 +117,37 @@ def _decode_kernel(
     # Inputs
     q_ref,  # [S_BLK, num_q_heads_per_blk, head_dim]
     kv_pages_hbm_ref,  # [L, NB, page_size, kv_rows, kv_lanes]
-    # Outputs
+    # [with sinks: sink_ref [KH_blk, S_BLK * G, 128] f32, then] Outputs
     o_ref,  # [S_BLK, num_q_heads_per_blk, head_dim]
     # Scratch
     kv_bufs,  # [2, S_BLK * KP, page_size, kv_rows_per_blk, kv_lanes]
     sems,  # DMA semaphores (2,)
-    *,
+    *rest,
     sm_scale: float,
     soft_cap: float | None,
     mask_value: float,
     k_scale: float | None,
     v_scale: float | None,
+    head_major: bool = False,
 ):
+    sink_ref = None
+    if rest:  # the logit of each row's q head, an input after the cache
+        sink_ref, o_ref, kv_bufs, sems = o_ref, kv_bufs, sems, *rest
     s_blk, num_q_heads_per_blk, head_dim = q_ref.shape
+    v_dim = o_ref.shape[-1]  # head_dim but in the split layout
     r_max = kv_lens_ref.shape[0]
     pages_per_seq = page_indices_ref.shape[1]
     num_seqs = num_seqs_ref[0]
     layer = layer_ref[0]
     window = window_ref[0]
-    _, skp, page_size, kv_rows_per_blk, kv_lanes = kv_bufs.shape
+    if head_major:  # the split layout's page, [KH, page_size, Dk_pad + Dv]
+        _, skp, kv_rows_per_blk, page_size, kv_lanes = kv_bufs.shape
+    else:
+        _, skp, page_size, kv_rows_per_blk, kv_lanes = kv_bufs.shape
     kp = skp // s_blk
     kv_tile = kp * page_size  # context tokens per sequence per tile
-    packed = kv_lanes == 2 * head_dim  # [.., KH, 2D] layout (head_dim 64)
+    # [.., KH, 2D] layout (head_dim 64), or the split one: K || V a row.
+    packed = head_major or kv_lanes == 2 * head_dim
     num_combined_kv_heads_per_blk = (
         2 * kv_rows_per_blk if packed else kv_rows_per_blk
     )
@@ -331,9 +340,10 @@ def _decode_kernel(
             else:
                 # Packed [.., KH, 2D]: K/V are the lane halves of one
                 # 128-lane row.
-                rows = kv_buf[:, :, chunk_idx, :]
+                rows = (kv_buf[:, chunk_idx, :, :] if head_major
+                        else kv_buf[:, :, chunk_idx, :])
                 k_list = [rows[..., :head_dim].reshape(-1, head_dim)]
-                v_list = [rows[..., head_dim:].reshape(-1, head_dim)]
+                v_list = [rows[..., head_dim:].reshape(-1, v_dim)]
             for step_idx in range(kv_load_step):
                 k = k_list[step_idx]
                 v = v_list[step_idx]
@@ -384,13 +394,17 @@ def _decode_kernel(
                 new_states[kv_head_idx] = (m_next, l_next, acc_next)
         return 1 - buf_idx, tuple(new_states)
 
+    # A sink is a row's state before its first key: a maximum of its
+    # logit and a denominator of exp(0).
     init_states = tuple(
         (
-            jnp.full((sg, 1), mask_value, jnp.float32),  # m
-            jnp.zeros((sg, 1), jnp.float32),  # l
-            jnp.zeros((sg, head_dim), jnp.float32),  # acc
+            jnp.full((sg, 1), mask_value, jnp.float32) if sink_ref is None
+            else sink_ref[i][:, :1],  # m
+            jnp.full((sg, 1), 0.0 if sink_ref is None else 1.0,
+                     jnp.float32),  # l
+            jnp.zeros((sg, v_dim), jnp.float32),  # acc
         )
-        for _ in range(num_kv_heads_per_blk)
+        for i in range(num_kv_heads_per_blk)
     )
     _, final_states = lax.fori_loop(
         t_start, t_end, tile_body, (start_parity(), init_states)
@@ -400,14 +414,20 @@ def _decode_kernel(
     for kv_head_idx in range(num_kv_heads_per_blk):
         _, l, acc = final_states[kv_head_idx]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        outs.append((acc / l_safe).reshape(s_blk, g, head_dim))
+        outs.append((acc / l_safe).reshape(s_blk, g, v_dim))
     o_ref[...] = jnp.concatenate(outs, axis=1).astype(q_ref.dtype)
 
 
-def _validate(q, kv_pages, kv_lens, page_indices, num_seqs):
+def _validate(q, kv_pages, kv_lens, page_indices, num_seqs, head_major):
     num_rows, num_q_heads, head_dim = q.shape
     _, _, _, kv_rows, kv_lanes = kv_pages.shape
-    if kv_lanes == 2 * head_dim:  # packed [.., KH, 2D]
+    if head_major:  # split [.., KH, BS, Dk_pad + Dv], q padded to Dk_pad
+        num_kv_heads = kv_pages.shape[2]
+        if kv_lanes <= head_dim or head_dim % 128:
+            raise ValueError(
+                f"split layout: {kv_lanes=} must hold a key of {head_dim=} "
+                "whole lane tiles and a value")
+    elif kv_lanes == 2 * head_dim:  # packed [.., KH, 2D]
         num_kv_heads = kv_rows
     else:
         assert kv_rows % 2 == 0
@@ -433,7 +453,7 @@ def _validate(q, kv_pages, kv_lens, page_indices, num_seqs):
     static_argnames=[
         "sm_scale", "mask_value", "soft_cap", "k_scale", "v_scale",
         "num_seqs_per_block", "num_kv_pages_per_block",
-        "vmem_limit_bytes", "interpret", "name",
+        "vmem_limit_bytes", "interpret", "name", "head_major",
     ],
 )
 def decode_paged_attention(
@@ -455,8 +475,15 @@ def decode_paged_attention(
     vmem_limit_bytes: int | None = None,
     interpret: bool = False,
     name: str = "rpa_decode_kernel",
+    sinks: jax.Array | None = None,  # f32[num_q_heads]
+    head_major: bool = False,
 ):
     """Decode-only flash attention over the paged KV cache.
+
+    ``sinks`` and ``head_major`` as ``ragged_paged_attention`` has them: a
+    logit a q head that a row's softmax state starts at, and the split
+    layout's view ``[L, NB, KH, page_size, Dk_pad + Dv]`` under a query
+    padded to ``Dk_pad``, which returns ``[R, H, Dv]``.
 
     ``name`` is the ``pallas_call``'s, which a profiler trace prints: a
     model that runs this kernel for two kinds of layer gives one of them
@@ -467,15 +494,19 @@ def decode_paged_attention(
     ``out [R, H, D]``. See the module docstring for the pipelining
     design. Rows at or beyond ``num_seqs[0]`` produce finite garbage.
     """
-    _validate(q, kv_pages, kv_lens, page_indices, num_seqs)
+    _validate(q, kv_pages, kv_lens, page_indices, num_seqs, head_major)
     if mask_value is None:
         mask_value = DEFAULT_MASK_VALUE
     num_rows, num_q_heads, head_dim = q.shape
     _, _, page_size, kv_rows, kv_lanes = kv_pages.shape
-    packed = kv_lanes == 2 * head_dim
+    v_dim = head_dim
+    if head_major:
+        kv_rows, page_size = kv_pages.shape[2:4]
+        v_dim = kv_lanes - head_dim
+    packed = head_major or kv_lanes == 2 * head_dim
     num_combined_kv_heads = 2 * kv_rows if packed else kv_rows
     _, pages_per_seq = page_indices.shape
-    if not packed:
+    if not packed:  # (the split layout, as the packed one: one heads block)
         num_q_heads_per_blk, num_combined_kv_heads_per_blk = (
             _min_heads_per_blk(
                 num_q_heads, num_combined_kv_heads, q.dtype, kv_pages.dtype
@@ -510,13 +541,29 @@ def decode_paged_attention(
         if packed
         else num_combined_kv_heads_per_blk
     )
+    page_shape = (
+        (kv_rows_per_blk, page_size) if head_major
+        else (page_size, kv_rows_per_blk)
+    )
     scratch_shapes = [
-        pltpu.VMEM(
-            (2, s_blk * kp, page_size, kv_rows_per_blk, kv_lanes),
-            kv_pages.dtype,
-        ),
+        pltpu.VMEM((2, s_blk * kp, *page_shape, kv_lanes), kv_pages.dtype),
         pltpu.SemaphoreType.DMA((2,)),
     ]
+    operands = [q, kv_pages]
+    in_specs = [q_block_spec, pl.BlockSpec(memory_space=pl.ANY)]
+    if sinks is not None:
+        # Row r of a KV head is (sequence r // g, q head r % g of its group).
+        num_kv_heads = num_combined_kv_heads // 2
+        g = num_q_heads // num_kv_heads
+        operands.append(jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(num_kv_heads, 1, g, 1),
+            (num_kv_heads, s_blk, g, 128),
+        ).reshape(num_kv_heads, s_blk * g, 128))
+        in_specs.append(pl.BlockSpec(
+            (num_combined_kv_heads_per_blk // 2, s_blk * g, 128),
+            lambda h, sb, *_: (h, 0, 0)))
+    out_block_spec = q_block_spec if v_dim == head_dim else pl.BlockSpec(
+        (s_blk, num_q_heads_per_blk, v_dim), q_index_map)
     window = jnp.asarray(
         0 if sliding_window is None else sliding_window, jnp.int32
     ).reshape(1)
@@ -535,11 +582,12 @@ def decode_paged_attention(
             mask_value=mask_value,
             k_scale=k_scale,
             v_scale=v_scale,
+            head_major=head_major,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalar_prefetches),
-            in_specs=[q_block_spec, pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=[q_block_spec],
+            in_specs=in_specs,
+            out_specs=[out_block_spec],
             grid=grid,
             scratch_shapes=scratch_shapes,
         ),
@@ -547,8 +595,9 @@ def decode_paged_attention(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem_limit_bytes,
         ),
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(
+            (num_rows, num_q_heads, v_dim), q.dtype)],
         name=name,
         interpret=interpret,
     )
-    return kernel(*scalar_prefetches, q, kv_pages)[0]
+    return kernel(*scalar_prefetches, *operands)[0]

@@ -263,6 +263,8 @@ def _rpa_kernel(
     return_lse: bool,
     block_len: int,
     tiling: RaggedTiling,
+    head_major: bool = False,
+    has_sinks: bool = False,
 ):
     """One (heads block, query block) program: walks the (sequence, KV
     block) VISITS of its query block. A visit's work follows what is live
@@ -271,6 +273,11 @@ def _rpa_kernel(
     runs the flash step on that window's rows alone, and the keys of the
     fetched block are computed sub-tile by sub-tile up to the last one a
     live query can see."""
+    sink_ref = None
+    if has_sinks:
+        # [KH, rows of a window, 128] f32, an input after the cache: the
+        # logit of each row's q head, the same in every window.
+        sink_ref, o_ref, *rest = (o_ref, *rest)
     if return_lse:
         lse_ref, kv_bufs, sems, l_ref, m_ref, acc_ref, qf_ref = rest
     else:
@@ -278,13 +285,22 @@ def _rpa_kernel(
         kv_bufs, sems, l_ref, m_ref, acc_ref, qf_ref = rest
 
     num_q_per_blk, num_q_heads_per_blk, head_dim = q_ref.shape
+    v_dim = o_ref.shape[-1]  # head_dim but in the split layout
     pages_per_seq = page_indices_ref.shape[-1]
     num_seqs = num_seqs_ref[0]
     layer = layer_ref[0]
-    _, num_kv_pages_per_blk, page_size, kv_head_rows_per_blk, kv_lanes = (
-        kv_bufs.shape
-    )
-    packed = kv_lanes == 2 * head_dim  # [.., KH, 2D] layout (head_dim 64)
+    if head_major:
+        # The split layout's page, [KH, page_size, Dk_pad + Dv]: a row is
+        # a key and its value as a packed row is, the head a major index.
+        _, num_kv_pages_per_blk, kv_head_rows_per_blk, page_size, kv_lanes = (
+            kv_bufs.shape
+        )
+    else:
+        _, num_kv_pages_per_blk, page_size, kv_head_rows_per_blk, kv_lanes = (
+            kv_bufs.shape
+        )
+    # [.., KH, 2D] layout (head_dim 64), or the split one: K || V a row.
+    packed = head_major or kv_lanes == 2 * head_dim
     num_combined_kv_heads_per_blk = (
         kv_head_rows_per_blk if not packed else 2 * kv_head_rows_per_blk
     )
@@ -452,7 +468,7 @@ def _rpa_kernel(
             return next_heads_blk_idx, next_seq_idx, next_kv_blk_idx, next_buf_idx
 
         def flash_attention(qs, ks, vs, l_win, m_win, acc_win, *, masked,
-                            init, live):
+                            init, live, sink=None):
             """One online-softmax step over one sub-tile of keys for the
             KV heads in ``qs``/``ks``/``vs`` (their rows stacked, so that
             the softmax runs on whole tiles whatever a head's rows), on
@@ -493,8 +509,15 @@ def _rpa_kernel(
             l_curr = jnp.broadcast_to(
                 s_curr.sum(axis=1, keepdims=True), lm_shape
             )
-            m_prev = load_with_init(m_win, -jnp.inf)
-            l_prev = load_with_init(l_win, 0.0)
+            if sink is None:
+                m_prev = load_with_init(m_win, -jnp.inf)
+                l_prev = load_with_init(l_win, 0.0)
+            else:
+                # The sink is the row's state before its first key: a
+                # maximum of its logit and a denominator of exp(0).
+                m_prev = jnp.where(
+                    init, sink, m_win[...].reshape(rows, m_win.shape[-1]))
+                l_prev = load_with_init(l_win, 1.0)
             m_next = jnp.maximum(m_prev, m_curr)
             store_live(m_win, m_next)
             alpha = jnp.exp(m_prev - m_next)
@@ -605,10 +628,11 @@ def _rpa_kernel(
                             num_combined_kv_heads_per_blk, live=kv_live,
                         )
                     else:
-                        kv_rows = kv_buf[
-                            pl.ds(sub_idx * sub_pages, sub_pages), :,
-                            kv_head_chunk_idx, :,
-                        ].reshape(sub_keys, kv_lanes)
+                        sub = pl.ds(sub_idx * sub_pages, sub_pages)
+                        kv_rows = (
+                            kv_buf[sub, kv_head_chunk_idx, :, :] if head_major
+                            else kv_buf[sub, :, kv_head_chunk_idx, :]
+                        ).reshape(sub_keys, kv_lanes)
                         kv_rows = jnp.where(
                             kv_live, kv_rows.astype(jnp.float32), 0
                         ).astype(kv_rows.dtype)
@@ -630,10 +654,13 @@ def _rpa_kernel(
                     for i, (k, v) in enumerate(zip(*load_kv(
                             kv_head_chunk_idx))):
                         h = kv_head_chunk_idx + i
+                        sink = None if sink_ref is None else jnp.broadcast_to(
+                            sink_ref[h][None], (num_windows, win_rows, 128)
+                        ).reshape(rows, 128)
                         flash_attention(
                             [qf_ref[:, h].reshape(rows, head_dim)], [k], [v],
                             l_ref.at[:, h], m_ref.at[:, h], acc_ref.at[:, h],
-                            **kw,
+                            sink=sink, **kw,
                         )
                     return carry
 
@@ -647,9 +674,11 @@ def _rpa_kernel(
                     flash_attention(
                         [qf_ref[win, h] for h in range(len(ks))], ks, vs,
                         l_ref.at[win], m_ref.at[win],
-                        acc_ref.at[win], **kw,
+                        acc_ref.at[win],
+                        sink=None if sink_ref is None
+                        else sink_ref[...].reshape(rows, 128), **kw,
                     )
-                elif packed:
+                elif packed and not head_major:
                     # The packed layout's head is a sublane index.
                     for kv_head_chunk_idx in head_chunks:
                         wide_heads(kv_head_chunk_idx, 0)
@@ -736,7 +765,7 @@ def _rpa_kernel(
         q_head_idx = kv_head_idx * num_q_heads_per_kv_head
         o_ref[:, q_head_idx : q_head_idx + num_q_heads_per_kv_head, :] = (
             acc_ref[:, kv_head_idx]
-            .reshape(num_q_per_blk, num_q_heads_per_kv_head, head_dim)
+            .reshape(num_q_per_blk, num_q_heads_per_kv_head, v_dim)
             .astype(o_ref.dtype)
         )
     if return_lse:
@@ -749,10 +778,17 @@ def _rpa_kernel(
             ).reshape(lse_ref.shape[1:])
 
 
-def _validate(q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs):
+def _validate(q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs,
+              head_major=False):
     _, num_q_heads, head_dim = q.shape
     _, _, _, kv_rows, kv_lanes = kv_pages.shape
-    if kv_lanes == 2 * head_dim:  # packed [.., KH, 2D]
+    if head_major:  # split [.., KH, BS, Dk_pad + Dv], q padded to Dk_pad
+        num_kv_heads, head_dim_k = kv_pages.shape[2], head_dim
+        if kv_lanes <= head_dim or head_dim % 128:
+            raise ValueError(
+                f"split layout: {kv_lanes=} must hold a key of {head_dim=} "
+                "whole lane tiles and a value")
+    elif kv_lanes == 2 * head_dim:  # packed [.., KH, 2D]
         num_kv_heads, head_dim_k = kv_rows, kv_lanes // 2
     else:
         assert kv_rows % 2 == 0
@@ -954,7 +990,7 @@ def ragged_tiling(
         "sm_scale", "mask_value", "num_kv_pages_per_block",
         "num_queries_per_block", "vmem_limit_bytes",
         "soft_cap", "k_scale", "v_scale", "return_lse", "interpret",
-        "block_len", "name",
+        "block_len", "name", "head_major",
     ],
 )
 def ragged_paged_attention(
@@ -981,8 +1017,17 @@ def ragged_paged_attention(
     ctx_phase=0,
     block_len: int = 1,
     name: str = "rpa_kernel",
+    sinks: jax.Array | None = None,  # f32[num_q_heads]
+    head_major: bool = False,
 ):
     """Mixed prefill+decode flash attention over the paged KV cache.
+
+    ``sinks``: a logit a q head that every row's running maximum starts at
+    (its denominator at 1, its accumulator at 0) where they start at minus
+    infinity and 0 without. ``head_major``: ``kv_pages`` is the split
+    layout's view ``[L, NB, KH, page_size, Dk_pad + Dv]`` and ``q`` padded
+    to ``Dk_pad`` lanes (``ops/attention.py::split_kv_cache_shape``); the
+    output is ``[T, H, Dv]``.
 
     ``name`` is the ``pallas_call``'s, which a profiler trace prints
     (``decode_paged_attention`` says when it is not the default).
@@ -1001,18 +1046,32 @@ def ragged_paged_attention(
     positions derive from it). The contract matches
     ``ref_ragged_paged_attention`` and ``cp_attention.cp_write_and_attend``.
     """
-    _validate(q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs)
+    _validate(q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs,
+              head_major)
+    assert sinks is None or not return_lse
     if mask_value is None:
         mask_value = DEFAULT_MASK_VALUE
     num_q_tokens, num_q_heads, head_dim = q.shape
     _, _, page_size, kv_rows, kv_lanes = kv_pages.shape
-    packed = kv_lanes == 2 * head_dim  # [.., KH, 2D] layout (head_dim 64)
+    v_dim = head_dim
+    if head_major:
+        kv_rows, page_size = kv_pages.shape[2:4]
+        v_dim = kv_lanes - head_dim
+    # [.., KH, 2D] layout (head_dim 64), or the split one: K || V a row.
+    packed = head_major or kv_lanes == 2 * head_dim
     num_combined_kv_heads = 2 * kv_rows if packed else kv_rows
     num_kv_heads = num_combined_kv_heads // 2
     _, pages_per_seq = page_indices.shape
-    num_q_heads_per_blk, num_combined_kv_heads_per_blk = _heads_per_blk(
-        num_q_heads, num_combined_kv_heads, head_dim, q.dtype, kv_pages.dtype
-    )
+    if head_major:
+        # One heads block: the estimate of VMEM below then counts a key
+        # and a value of head_dim lanes each, a third over the rows held.
+        num_q_heads_per_blk = num_q_heads
+        num_combined_kv_heads_per_blk = num_combined_kv_heads
+    else:
+        num_q_heads_per_blk, num_combined_kv_heads_per_blk = _heads_per_blk(
+            num_q_heads, num_combined_kv_heads, head_dim, q.dtype,
+            kv_pages.dtype
+        )
     tiling = ragged_tiling(
         num_q_heads, num_combined_kv_heads, head_dim, page_size, q.dtype,
         kv_pages.dtype, num_q_tokens, pages_per_seq, block_len=block_len,
@@ -1040,6 +1099,11 @@ def ragged_paged_attention(
     lm_shape = (num_kv_heads_per_blk, rows_per_blk, 128)
     out_specs = [q_block_spec]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+    if v_dim != head_dim:
+        out_specs = [pl.BlockSpec(
+            (num_q_per_blk, num_q_heads_per_blk, v_dim), q_index_map)]
+        out_shape = [jax.ShapeDtypeStruct(
+            (num_q_tokens, num_q_heads, v_dim), q.dtype)]
     if return_lse:
         out_specs.append(
             pl.BlockSpec(lm_shape, lambda h, qb, *_: (h, qb, 0))
@@ -1061,17 +1125,31 @@ def ragged_paged_attention(
     win_toks = tiling.narrow_q or num_q_per_blk
     win_rows = win_toks * num_q_heads_per_kv_head
     win_shape = (num_q_per_blk // win_toks, num_kv_heads_per_blk, win_rows)
+    page_shape = (
+        (kv_rows_per_blk, page_size) if head_major
+        else (page_size, kv_rows_per_blk)
+    )
     scratch_shapes = [
         pltpu.VMEM(
-            (2, num_kv_pages_per_blk, page_size, kv_rows_per_blk, kv_lanes),
-            kv_pages.dtype,
+            (2, num_kv_pages_per_blk, *page_shape, kv_lanes), kv_pages.dtype
         ),
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.VMEM((*win_shape, 128), jnp.float32),  # l
         pltpu.VMEM((*win_shape, 128), jnp.float32),  # m
-        pltpu.VMEM((*win_shape, head_dim), jnp.float32),  # acc
+        pltpu.VMEM((*win_shape, v_dim), jnp.float32),  # acc
         pltpu.VMEM((*win_shape, head_dim), q.dtype),  # q, folded
     ]
+    operands = [q, kv_pages]
+    if sinks is not None:
+        # A row of a KV head's window is (token, q head of its group).
+        operands.append(jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(
+                num_kv_heads, 1, num_q_heads_per_kv_head, 1),
+            (num_kv_heads, win_toks, num_q_heads_per_kv_head, 128),
+        ).reshape(num_kv_heads, win_rows, 128))
+        in_specs.append(pl.BlockSpec(
+            (num_kv_heads_per_blk, win_rows, 128),
+            lambda h, qb, *_: (h, 0, 0)))
     window = jnp.asarray(
         0 if sliding_window is None else sliding_window, jnp.int32
     ).reshape(1)
@@ -1100,6 +1178,8 @@ def ragged_paged_attention(
             return_lse=return_lse,
             block_len=block_len,
             tiling=tiling,
+            head_major=head_major,
+            has_sinks=sinks is not None,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalar_prefetches),
@@ -1117,7 +1197,7 @@ def ragged_paged_attention(
         interpret=interpret,
     )
 
-    outs = kernel(*scalar_prefetches, q, kv_pages)
+    outs = kernel(*scalar_prefetches, *operands)
     if not return_lse:
         return outs[0]
     out, lse_raw = outs

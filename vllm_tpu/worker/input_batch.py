@@ -81,15 +81,18 @@ class InputBatch:
         self.token_ids = np.zeros((n, m), dtype=np.int32)
         self.num_tokens = np.zeros(n, dtype=np.int32)
         self.num_computed_tokens = np.zeros(n, dtype=np.int32)
-        # A block table a KV group; every group's row holds one entry a
-        # block of the sequence, so ``num_blocks`` is every group's.
-        # ``block_table`` is group 0's (the only one of a uniform model).
+        # A block table a KV group; a group's row holds one entry a page
+        # of the sequence, a page the tokens of ITS group (groups of one
+        # page size hold the same count, a group of twice the tokens a
+        # page half of it). ``block_table`` and ``num_blocks`` are group
+        # 0's (the only one of a uniform model).
         self.block_tables = [
             np.zeros((n, max_blocks_per_req), dtype=np.int32)
             for _ in range(num_kv_groups)
         ]
         self.block_table = self.block_tables[0]
-        self.num_blocks = np.zeros(n, dtype=np.int32)
+        self.num_blocks_by_group = np.zeros((num_kv_groups, n), dtype=np.int32)
+        self.num_blocks = self.num_blocks_by_group[0]
 
         # Sampling columns.
         self.temperature = np.zeros(n, dtype=np.float32)
@@ -160,7 +163,7 @@ class InputBatch:
             for vec in (
                 self.num_tokens,
                 self.num_computed_tokens,
-                self.num_blocks,
+                *self.num_blocks_by_group,
                 self.temperature,
                 self.top_k,
                 self.top_p,
@@ -184,19 +187,23 @@ class InputBatch:
     # ------------------------------------------------------------------
 
     def _set_blocks(
-        self, row: int, start: int, block_ids: tuple[list[int], ...]
+        self, row: int, start: int | None, block_ids: tuple[list[int], ...]
     ) -> None:
-        """Write ``block_ids`` (one list a KV group, all of one length)
-        into the row's tables from entry ``start`` on."""
-        for table, ids in zip(self.block_tables, block_ids, strict=True):
-            table[row, start : start + len(ids)] = ids
-        self.num_blocks[row] = start + len(ids)
+        """Write ``block_ids`` (one list a KV group) into the row's tables
+        from entry ``start`` on; None = after what each group holds (the
+        groups' counts differ where their pages hold unlike tokens)."""
+        for table, held, ids in zip(
+                self.block_tables, self.num_blocks_by_group, block_ids,
+                strict=True):
+            at = int(held[row]) if start is None else start
+            table[row, at : at + len(ids)] = ids
+            held[row] = at + len(ids)
 
     def append_block_ids(
         self, req_id: str, new_block_ids: tuple[list[int], ...]
     ) -> None:
         row = self.req_states[req_id].in_batch_row
-        self._set_blocks(row, int(self.num_blocks[row]), new_block_ids)
+        self._set_blocks(row, None, new_block_ids)
 
     def reset_for_resume(
         self, req_id: str, token_ids: list[int],

@@ -158,6 +158,13 @@ class ModelRunner:
         # step program, stacked on a leading axis where there are several.
         self.kv_groups = cache.kv_cache_groups or [None]
         self.num_kv_groups = len(self.kv_groups)
+        # Tokens a page of each group holds (the engine's, for a uniform
+        # model), and the group whose tables are longest: the step
+        # program's padded blocks a request follow its counts.
+        self.group_block_sizes = [
+            g.block_size if g else cache.block_size for g in self.kv_groups]
+        self._longest_tables = self.group_block_sizes.index(
+            min(self.group_block_sizes))
         if self.num_kv_groups > 1 and (
             config.speculative_config.enabled or sched.num_decode_steps > 1
             or sched.enable_cascade_attention
@@ -542,6 +549,9 @@ class ModelRunner:
         # pairs their mask lets through, a q head a layer.
         self.window_attn_rows = 0
         self.window_attn_pairs = 0
+        # Rows a step ran through attention with a sink logit a head,
+        # summed over the layers that have one.
+        self.attn_sink_rows = 0
         self.linear_attn_tokens_by_kind = {"chunk": 0, "decode": 0}
         # Seconds spent in first uses of a step program (tracing, lowering
         # and compiling or loading it), beside bucket_compiles, their
@@ -700,15 +710,20 @@ class ModelRunner:
             plain = all(hasattr(m, a) for a in
                         ("num_heads", "num_kv_heads", "head_dim")) and not (
                 hasattr(m, "kv_cache_shape") or hasattr(m, "alloc_kv_cache"))
-            self._ragged_tilings[key] = plain and ragged_kernel_tiling(
-                m.num_heads, m.num_kv_heads, m.head_dim, self.block_size,
-                m.dtype, self.kv_cache.dtype, t_pad, b_pad,
-                block_len=block_len, tp_size=self.tp_size,
-            )
-        tiling = self._ragged_tilings[key]
+            # A model whose pages are the split layout names the call the
+            # count follows (its full layers'): (q heads, KV heads, the
+            # key's stored lanes, tokens a page of that group).
+            split = getattr(m, "ragged_kernel_geometry", None)
+            geometry = split(self.block_size) if split else plain and (
+                m.num_heads, m.num_kv_heads, m.head_dim, self.block_size)
+            self._ragged_tilings[key] = geometry and (ragged_kernel_tiling(
+                *geometry, m.dtype, self.kv_cache.dtype, t_pad, b_pad,
+                block_len=block_len, tp_size=self.tp_size, split=bool(split),
+            ), geometry[3])
+        tiling, page_size = self._ragged_tilings[key] or (None, 0)
         if tiling:
             live, computed = count_ragged_pairs(
-                q_lens, kv_lens, tiling, page_size=self.block_size,
+                q_lens, kv_lens, tiling, page_size=page_size,
                 block_len=block_len,
             )
             self.attn_ragged_pairs["live"] += live
@@ -1806,9 +1821,8 @@ class ModelRunner:
         r_pad = _bucket(max(r_live, 1), self.request_buckets)
         if decode_only:
             t_pad = r_pad
-        max_blocks = max(
-            (int(batch.num_blocks[row]) for row in rows), default=1
-        )
+        held = batch.num_blocks_by_group[self._longest_tables]
+        max_blocks = max((int(held[row]) for row in rows), default=1)
         b_pad = _bucket(max(max_blocks, 1), self.block_buckets)
         # Bucket-cache observability: first sight of a (tokens, reqs,
         # blocks) triple compiles a new jitted-step variant (possibly
@@ -2019,7 +2033,7 @@ class ModelRunner:
         token_req_idx[:] = max(r_pad - 1, 0)
         do_sample = np.zeros(r_pad, bool)
 
-        bs = self.block_size
+        bs = self.group_block_sizes[0]
         offset = 0
         pending_rows: list[int] = []
         # The native fill runs on EVERY batch shape (the old `and not s`
@@ -2181,17 +2195,18 @@ class ModelRunner:
             # slots; padding stays 0, the null page.
             tail = ibuf[ibuf.shape[0] - more_groups * (t + r * b):]
             rows_idx = np.asarray(rows, np.int64)
-            held = (np.arange(b)[None, :]
-                    < batch.num_blocks[rows_idx][:, None])
             tok_row = token_req_idx[:offset]
             tok_pos = positions[:offset]
             for g in range(more_groups):
+                held = (np.arange(b)[None, :]
+                        < batch.num_blocks_by_group[g + 1][rows_idx][:, None])
                 table = np.where(
                     held, batch.block_tables[g + 1][rows_idx, :b], 0)
                 tail[more_groups * t + g * r * b:][: r * b].reshape(
                     r, b)[:r_live] = table
+                gbs = self.group_block_sizes[g + 1]
                 tail[g * t:][:offset] = (
-                    table[tok_row, tok_pos // bs] * bs + tok_pos % bs)
+                    table[tok_row, tok_pos // gbs] * gbs + tok_pos % gbs)
 
         # Packed f32 sampling buffer: 6 R-vectors (+ optional adjustment
         # values) (+ under generation by diffusion the dynamic rule's
@@ -2391,6 +2406,8 @@ class ModelRunner:
                 + int(np.minimum(kv_lens, q_lens + window - 1).sum()))
             self.window_attn_pairs += window_live_pairs(
                 q_lens, kv_lens, window)
+            self.attn_sink_rows += r_live * getattr(
+                self.model, "num_sink_attn_layers", 0)
         if getattr(self.model, "latent_dim", 0):
             # What each row's latent attention reads, once a row a step;
             # and its kernel's pairs, which every step of such a model
@@ -3187,6 +3204,8 @@ class ModelRunner:
         kv_dtype = self._kv_dtype()
         custom_alloc = getattr(self.model, "alloc_kv_cache", None)
         custom_shape = getattr(self.model, "kv_cache_shape", None)
+        device = jax.local_devices()[0]
+        in_use_before = (device.memory_stats() or {}).get("bytes_in_use")
         if custom_alloc is not None:
             # Model-defined state pytree (SSM conv+state buffers).
             kv = custom_alloc(self.num_kv_blocks, cache.block_size, kv_dtype)
@@ -3219,14 +3238,25 @@ class ModelRunner:
                 self.model.kv_cache_sharding(),
                 is_leaf=lambda n: isinstance(n, jnp.ndarray),
             )
+        reckoned = sum(
+            leaf.size * leaf.dtype.itemsize
+            for leaf in jax.tree_util.tree_leaves(kv)
+        )
         logger.info(
             "KV cache allocated: %s (%.2f GiB)",
             jax.tree.map(lambda a: (a.shape, str(a.dtype)), kv),
-            sum(
-                leaf.size * leaf.dtype.itemsize
-                for leaf in jax.tree_util.tree_leaves(kv)
-            ) / 2**30,
+            reckoned / 2**30,
         )
+        if in_use_before is not None and self.mesh is None:
+            # What the device holds for it against what the shapes say: a
+            # page whose second-minor dim pads on the device shows here
+            # (``KVCacheSpec.page_size_bytes`` models the lanes only).
+            jax.block_until_ready(kv)
+            took = device.memory_stats()["bytes_in_use"] - in_use_before
+            log = logger.warning if abs(took - reckoned) > 0.02 * reckoned \
+                else logger.info
+            log("KV cache on the device: %d bytes in use for %d reckoned "
+                "(%+.2f%%)", took, reckoned, 100.0 * (took - reckoned) / reckoned)
         return kv
 
     def _alloc_draft_kv(self):

@@ -483,16 +483,29 @@ class Worker:
             cache.block_size,
         )
         if len(groups) > 1:
+            # A page is one size in bytes and, where the kinds' layers
+            # cost unlike bytes a token, unlike tokens a group; a short
+            # group's page pays for places no layer writes.
+            lost = sum(g.lost_bytes_per_page for g in groups)
             logger.info(
-                "KV groups: %d over the one pool, a page %d tokens of one "
-                "group's layers, %d bytes: %s",
-                len(groups), cache.block_size, page_bytes,
+                "KV groups: %d over the one pool, a page %s tokens of one "
+                "group's layers, %d bytes: %s%s",
+                len(groups),
+                "/".join(str(n) for n in sorted(
+                    {g.block_size for g in groups}, reverse=True)),
+                page_bytes,
                 "; ".join(
-                    "%d %s%s" % (
+                    "%d %s%s of %d tokens a page" % (
                         len(g.layer_names), type(g.kv_cache_spec).__name__,
                         "" if g.sliding_window is None
                         else " (window %d)" % g.sliding_window,
+                        g.block_size,
                     ) for g in groups),
+                "" if not lost else
+                "; %d of %d places hold no layer: %d bytes of a page of "
+                "the short last group are lost" % (
+                    sum(g.num_places - len(g.layer_names) for g in groups),
+                    sum(g.num_places for g in groups), lost),
             )
         return num_blocks
 
