@@ -135,4 +135,11 @@ def test_the_entries_are_appended_with_the_one_cell():
     assert by_name["attn_kv_full_roofline"]["source"] == "device_trace"
     assert by_name["kv_held_bytes_share"]["layer"] == "KV cache manager"
     assert by_name["kv_held_bytes_share"]["moves"] == "output_tokens_per_s"
-    assert [m["name"] for m in MANIFEST["per_layer"]][-3:] == NEW
+    # Both shares are the decode kernel's (0.446 of 0.461 s of the window kind
+    # in the ledger's PR 45 line), which runs in no mixed step: since PR 46 they
+    # bound a claim on the MEDIAN gap, which is a decode-only step.
+    assert {by_name[n]["moves"] for n in NEW[:2]} == {"itl_p50_ms"}
+    # Appended together and in this order; what follows them is a later PR's.
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + 3] == NEW

@@ -39,9 +39,11 @@ def phases(**seconds) -> dict:
 
 
 M0 = {COUNT: 1000.0, **phases(schedule=1.0, prepare=2.0, fetch=30.0, update=0.5, output=0.25),
-      "vllm:step_first_use_seconds_total": 111.5}
+      "vllm:step_first_use_seconds_total": 111.5,
+      'vllm:engine_steps_total{kind="mixed"}': 300.0, 'vllm:engine_steps_total{kind="decode"}': 700.0}
 M1 = {COUNT: 3500.0, **phases(schedule=1.5, prepare=4.5, fetch=70.0, update=1.0, output=1.0),
-      "vllm:step_first_use_seconds_total": 111.5}
+      "vllm:step_first_use_seconds_total": 111.5,
+      'vllm:engine_steps_total{kind="mixed"}': 800.0, 'vllm:engine_steps_total{kind="decode"}': 2700.0}
 # 4 s of the decode kernel for 3e7 context tokens: 2.4 s at 819 GB/s.
 TRACE = {"kernel_seconds": {"rpa_decode_kernel": 4.0, "fusion": 9.0},
          "kernel_calls": {"rpa_decode_kernel": 1600, "rpa_kernel": 400},
@@ -61,6 +63,9 @@ LEAST = 3e7 * BYTES_A_TOKEN / peaks_for("TPU v5 lite")["hbm_bytes_per_s"]
     # 40 s of the 50 s between the scrapes spent waiting for the device.
     ("device_wait_share", 80.0, 'phase="fetch"'),
     ("setup_first_use_s", 111.5, "first_use"),
+    # 500 of the window's 2500 steps carried a prompt's chunk.
+    ("mixed_step_share", 20.0, 'kind="mixed"'),
+    ("mixed_step_share", 20.0, 'kind="decode"'),
     ("decode_attn_roofline_counted", 100.0 * LEAST / 4.0, "attn_context"),
     # 3 ms of 8 ms carry no span of the program's.
     ("idle_unnamed_share", 37.5, "idle_gaps"),
@@ -97,3 +102,25 @@ def test_no_steps_in_the_window_reads_nothing():
     for name in ("schedule_ms_per_step", "prepare_ms_per_step", "output_ms_per_step"):
         assert CELL.layer_metric(name).read(make_ctx(M0, still, TRACE, {})) is None
     assert CELL.layer_metric("device_wait_share").read(make_ctx(M0, still)) == 0.0
+    # Counters that stand still: nothing to read.
+    assert CELL.layer_metric("mixed_step_share").read(make_ctx(M0, still)) is None
+
+
+@pytest.mark.parametrize("cell", ["mistral7b.chat", "kimilinear48b.long-answers",
+                                  "lagunaxs2.code-mixed", "mimov25.long-answers"])
+def test_a_cell_of_single_token_rows_says_which_step_each_percentile_is(cell):
+    """``mixed_step_share`` lists the four cells in which it read a number on
+    the chip (PR 46). Not ``sdar30b.chat``: its reader found nothing to read
+    in both of that PR's runs of the cell (rows of several tokens; which of
+    the two samples is missing there was not looked up), and a metric without
+    a list has to print in every cell."""
+    manifest = load_manifest()
+    c = Cell(manifest, cell)
+    entry = next(m for m in c.per_layer if m["name"] == "mixed_step_share")
+    assert cell in entry["workloads"] and "sdar30b.chat" not in entry["workloads"]
+    assert all(m["name"] != "mixed_step_share" for m in Cell(manifest, "sdar30b.chat").per_layer)
+    assert entry["moves"] == "itl_p95_ms"
+    assert entry["layer"] == "scheduler" and entry["source"] == "program_counter"
+    only_mixed = {'vllm:engine_steps_total{kind="mixed"}': 900.0,
+                  'vllm:engine_steps_total{kind="decode"}': 700.0}
+    assert c.layer_metric("mixed_step_share").read(make_ctx(M0, {**M1, **only_mixed})) == 100.0

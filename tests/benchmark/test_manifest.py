@@ -28,7 +28,7 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 ACCEPTED = {
     "configs": ["mistral-7b-v0.3", "sdar-30b-a3b-chat", "kimi-linear-48b-a3b-instruct"],
     "workloads": ["mistral7b.chat", "sdar30b.chat", "kimilinear48b.long-answers"],
-    "end_to_end": ["itl_p95_ms", "output_tokens_per_s", "setup_s"],
+    "end_to_end": ["itl_p95_ms", "output_tokens_per_s", "setup_s", "itl_p50_ms"],
     "per_layer": [
         "gen_late_p95_ms", "frontend_ttft_gap_ms", "client_ttft_p50_ms", "queue_wait_mean_ms",
         "gen_tokens_per_step", "prefix_hit_share", "preemptions", "step_interval_ms_mean",
@@ -174,18 +174,73 @@ def test_what_a_chat_user_feels_is_end_to_end_and_bounded():
     ``ttft_p50_ms`` spreading by more than half of the widest bound there is
     (PERF.md section 2): the first is in every run's log, the second is read
     per layer as ``client_ttft_p50_ms``. A time to first token is so many
-    512-token steps, and ``itl_p95_ms`` is what one of them takes."""
-    assert list(E2E) == ["itl_p95_ms", "output_tokens_per_s", "setup_s"]
+    512-token steps, and ``itl_p95_ms`` is what one of them takes. Since
+    PR 46 the median of the same gaps stands beside it: the decode-only step,
+    four steps in five, which the percentile never falls on."""
+    assert list(E2E) == ["itl_p95_ms", "output_tokens_per_s", "setup_s", "itl_p50_ms"]
     assert E2E["output_tokens_per_s"]["better"] == "higher"
     assert E2E["itl_p95_ms"]["bound"] == 0.05
     moved = {m["name"]: m["moves"] for m in MANIFEST["per_layer"]}
     assert moved["client_ttft_p50_ms"] == moved["queue_wait_mean_ms"] == "itl_p95_ms"
     assert moved["gen_tokens_per_step"] == moved["device_idle_share"] == "output_tokens_per_s"
-    assert moved["step_interval_ms_mean"] == moved["decode_attn_roofline"] == "itl_p95_ms"
+    assert moved["step_interval_ms_mean"] == moved["mixed_step_share"] == "itl_p95_ms"
+
+
+# The cells whose median gap says something and repeats. ``sdar30b.chat`` commits
+# two tokens in a pass, so half of its gaps are 0 by ``score.token_gaps``' rule and
+# its median gap read 0.0 in every run there is. ``lagunaxs2.code-mixed`` has TWO
+# states: 22.0-22.5 ms in 13 of its 18 logged runs and 24.7-24.9 in 5, one seed
+# reading both, 12% apart, which no bound the contract allows can hold (PERF.md
+# section 2 has the runs).
+MEDIAN_GAP_CELLS = ["mistral7b.chat", "kimilinear48b.long-answers", "mimov25.long-answers"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_median_gap_is_reported_where_it_says_something_and_repeats(cell):
+    assert E2E["itl_p50_ms"]["workloads"] == MEDIAN_GAP_CELLS
+    assert E2E["itl_p50_ms"]["unit"] == "ms" and E2E["itl_p50_ms"]["better"] == "lower"
+    assert E2E["itl_p50_ms"]["source"] == "host_clock"
+    reported = [m["name"] for m in M.Cell(MANIFEST, cell).end_to_end]
+    assert ("itl_p50_ms" in reported) == (cell in MEDIAN_GAP_CELLS)
+    assert reported[:3] == ["itl_p95_ms", "output_tokens_per_s", "setup_s"]
+
+
+def test_the_median_gaps_bound_lies_between_what_the_runs_spread_and_the_most_allowed():
+    """Over twice the widest spread a set of this PR's runs read (2.93%: a
+    bound whose half the runs out-spread is refused as too tight), at most
+    8 times the widest spread of all the runs of a cell (2.59%), and never
+    over the 0.1 the contract allows."""
+    assert 2 * 0.0293 < E2E["itl_p50_ms"]["bound"] <= min(8 * 0.0259, 0.1)
+
+
+@pytest.mark.parametrize("name,cell", [
+    ("decode_attn_roofline", "mistral7b.chat"),
+    ("decode_attn_roofline_counted", "mistral7b.chat"),
+    ("attn_kv_window_roofline", "mimov25.long-answers"),
+    ("attn_kv_full_roofline", "mimov25.long-answers")])
+def test_a_share_of_the_decode_kernel_bounds_a_claim_on_the_median_gap(name, cell):
+    """``moves`` says which step a share bounds. The decode kernel runs only
+    in a decode-only step (``ops/attention.py::dispatch_ragged_attention``), and
+    the median gap is that step: these four read nothing else. Each lists the
+    one cell it is read in, which reports the metric (the form check asks
+    that). Whatever a mixed step dominates, and every entry without a list
+    (``sdar30b.chat`` prints those too), stays on ``itl_p95_ms``."""
+    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
+    assert entry["moves"] == "itl_p50_ms" and entry["workloads"] == [cell]
+    assert cell in MEDIAN_GAP_CELLS
+
+
+def test_nothing_else_moves_the_median_gap_and_no_entry_without_a_list_does():
+    onto_the_median = [m for m in MANIFEST["per_layer"] if m["moves"] == "itl_p50_ms"]
+    assert {m["name"] for m in onto_the_median} == {
+        "decode_attn_roofline", "decode_attn_roofline_counted",
+        "attn_kv_window_roofline", "attn_kv_full_roofline"}
+    assert all("workloads" in m for m in onto_the_median)
 
 
 @pytest.mark.parametrize("name,bound", [
-    ("itl_p95_ms", 0.05), ("output_tokens_per_s", 0.015), ("setup_s", 0.1)])
+    ("itl_p95_ms", 0.05), ("output_tokens_per_s", 0.015), ("setup_s", 0.1),
+    ("itl_p50_ms", 0.08)])
 def test_the_bounds_are_what_the_spreads_set(name, bound):
     """``output_tokens_per_s`` stood on 10% until PR 42. Once every seed
     sends one order of work an open loop under its knee completes what was
@@ -194,7 +249,13 @@ def test_the_bounds_are_what_the_spreads_set(name, bound):
     1%: in ``kimilinear48b.long-answers`` one set of three runs spread by
     0.56% with no stalled run and one run of twelve read 1.3% over the
     others, which a bound of 1% would refuse (PERF.md section 2 has the
-    runs). The other two are as they were."""
+    runs). The other two are as they were. ``itl_p50_ms`` (PR 46) is 8% and
+    not the 5% its issue capped it at: two sets of three runs a cell of one
+    program spread by 2.2-2.9% of the median in ``mimov25.long-answers``
+    (two runs of ONE seed differ by up to 2.6% there) and in
+    ``kimilinear48b.long-answers`` (the seed's router), and the driver
+    refuses a bound whose half its own runs out-spread (PERF.md section 2
+    has the runs and the reckoning)."""
     assert E2E[name]["bound"] == bound
 
 

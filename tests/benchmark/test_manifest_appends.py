@@ -1,6 +1,8 @@
 """Adding to the benchmark is adding files and appending entries: a copy of
-the tree with a SYNTHETIC fourth configuration, a fourth cell and a 36th
-per-layer metric, each made of copies of accepted files and appended at the
+the tree with a SYNTHETIC configuration, cell and per-layer metric more
+than it has (the counts are read off the manifest, never written down: as
+literals they failed from PR 43 to PR 46, whenever a real entry was
+appended), each made of copies of accepted files and appended at the
 end of its list, passes the form checks of ``test_manifest.py`` and the two
 accepted tests that once held an entry to a place, with none of them touched.
 
@@ -21,6 +23,12 @@ from benchmark.harness import manifest as M
 from . import test_manifest as forms
 
 CONFIG, CELL, METRIC = "synthetic-7b", "synthetic7b.chat", "synthetic_steps_per_s"
+LISTS = ("configs", "workloads", "per_layer")
+BEFORE = M.load_manifest()  # the manifest as the tree has it, before the append
+
+
+def counts_of(manifest: dict) -> list[int]:
+    return [len(manifest[k]) for k in LISTS]
 READER = '''"""Steps a second of the window (a synthetic reader for the tests)."""
 
 
@@ -65,7 +73,9 @@ def test_the_form_checks_pass_over_appended_entries(tree, monkeypatch):
     monkeypatch.setattr(M, "ROOT", tree)
     monkeypatch.setattr(M, "BENCH_DIR", tree / "benchmark")
     manifest = M.load_manifest()
-    assert [len(manifest[k]) for k in ("configs", "workloads", "per_layer")] == [4, 4, 36]
+    assert counts_of(manifest) == [n + 1 for n in counts_of(BEFORE)]
+    for k in LISTS:  # appended: what was there is there, in its order
+        assert manifest[k][:-1] == BEFORE[k]
     forms.check_accepted_entries_are_a_prefix(manifest)
     forms.check_top_level_keys_and_limits(manifest)
     forms.check_names_and_units_use_only_the_allowed_characters(manifest)
@@ -108,7 +118,8 @@ def test_the_two_tests_that_once_held_a_place_pass_over_the_appended_tree(tree, 
     monkeypatch.setattr(M, "ROOT", tree)
     monkeypatch.setattr(M, "BENCH_DIR", tree / "benchmark")
     manifest = M.load_manifest()
-    assert len(manifest["per_layer"]) == 36 and METRIC in {m["name"] for m in manifest["per_layer"]}
+    assert len(manifest["per_layer"]) == len(BEFORE["per_layer"]) + 1
+    assert manifest["per_layer"][-1]["name"] == METRIC
     monkeypatch.setattr(chunk, "MANIFEST", manifest)
     monkeypatch.setattr(timeline, "MANIFEST", manifest)
     monkeypatch.setattr(timeline, "CELLS", [w["name"] for w in manifest["workloads"]])

@@ -229,7 +229,9 @@ def test_a_reader_finds_nothing_on_a_program_without_its_counters(name):
     assert read(ctx_of({}, {}, trace)) is None
     assert read(ctx_of({}, None, None)) is None
     entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == [NAME] and entry["moves"] == "itl_p95_ms"
+    # The cell that brought the entry leads its list (PR 46 put two more cells on
+    # ``moe_share_gmm_roofline``); each of these is read in a mixed step too.
+    assert entry["workloads"][0] == NAME and entry["moves"] == "itl_p95_ms"
 
 
 def test_what_the_accepted_listless_metrics_find_here():

@@ -165,4 +165,5 @@ def test_a_reader_finds_nothing_on_a_program_without_its_counters(name):
     assert read(ctx_of({}, {}, trace)) is None
     assert read(ctx_of({}, None, None)) is None
     entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == ["sdar30b.chat"]
+    # PR 46 put ``lagunaxs2.code-mixed`` on ``moe_experts_hit_share`` too.
+    assert entry["workloads"][0] == "sdar30b.chat"

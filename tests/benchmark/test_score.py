@@ -46,12 +46,49 @@ def test_a_failed_request_is_slower_than_any_percentile():
     bad = [rec(18.0, [], 2, done=False, error="boom"), rec(19.0, [19.5], 2, done=False)]
     out = S.score(good + bad, seconds=30.0, chips=1)
     assert out["attempted"] == 20 and out["failed"] == 2
-    assert out["facts"]["tail_is_a_failure"] == {"ttft_p50": False, "ttft_p95": True, "itl_p95": True}
+    assert out["facts"]["tail_is_a_failure"] == {"ttft_p50": False, "ttft_p95": True,
+                                                 "itl_p95": True, "itl_p50": False}
     # The number printed is the largest that finished, never a small one.
     assert out["metrics"]["ttft_p95_ms"] == pytest.approx(100.0)
     assert out["metrics"]["ttft_p50_ms"] == pytest.approx(100.0)
     all_bad = S.score(bad, seconds=30.0, chips=1)
     assert all_bad["failed"] == 2 and all_bad["facts"]["tail_is_a_failure"]["ttft_p50"]
+
+
+def stream(due, first, gap, chunks, max_tokens, **kw):
+    """A request whose chunks arrive ``gap`` seconds apart."""
+    return rec(due, [first + gap * i for i in range(chunks)], max_tokens, **kw)
+
+
+@pytest.mark.parametrize("records,p50_ms,p95_ms", [
+    # One token a chunk: 18 decode-only steps of 20 ms and two mixed steps of
+    # 60 ms in each of 5 requests. The median is the step that runs most
+    # often, the percentile the slow one.
+    ([rec(0.0, [0.1 + 0.02 * i + 0.04 * ((i > 5) + (i > 15)) for i in range(21)], 21)
+      for _ in range(5)], 20.0, 60.0),
+    # Two tokens a chunk (a diffusion pass commits two): of a request's 19
+    # gaps 9 are a pass and 10 are 0, so the median is 0 and says nothing.
+    ([stream(0.0, 0.1, 0.06, 10, 20) for _ in range(5)], 0.0, 60.0),
+    # A failed request's missing tokens rank last: 6 gaps of 20 ms, then 7
+    # tokens that never came. The median falls among them and prints the
+    # largest gap that finished, never a smaller one.
+    ([stream(0.0, 0.1, 0.02, 4, 4), stream(0.0, 0.1, 0.02, 4, 4),
+      rec(1.0, [1.1], 8, done=False, error="cut")], 20.0, 20.0),
+], ids=["one_token_a_chunk", "two_tokens_a_chunk", "a_failed_request_ranks_last"])
+def test_the_median_gap_over_records_made_by_hand(records, p50_ms, p95_ms):
+    out = S.score(records, seconds=30.0, chips=1)
+    assert out["metrics"]["itl_p50_ms"] == pytest.approx(p50_ms, abs=1e-6)
+    assert out["metrics"]["itl_p95_ms"] == pytest.approx(p95_ms, abs=1e-6)
+    # An end-to-end metric now, no longer a fact for the log alone.
+    assert "itl_p50_ms" not in out["facts"]
+
+
+def test_a_median_that_falls_on_a_failure_says_so():
+    bad = [rec(float(i), [i + 0.1], 8, done=False, error="cut") for i in range(3)]
+    out = S.score([stream(0.0, 0.1, 0.02, 4, 4)] + bad, seconds=30.0, chips=1)
+    assert out["facts"]["tail_is_a_failure"]["itl_p50"] is True
+    assert out["metrics"]["itl_p50_ms"] == pytest.approx(20.0)
+    assert S.score([], seconds=30.0, chips=1)["metrics"] == {}
 
 
 @pytest.mark.parametrize("kw", [dict(finish="stop"), dict(status=503), dict(error="shed"),
