@@ -2,7 +2,8 @@
 exact-equivalence tests against the XLA reference, in Pallas interpret mode
 on CPU, plus the dispatcher eligibility contract: decode-only batches take
 the sequence-pipelined kernel, everything else (mixed prefill+decode, LSE,
-striped context, the env escape hatch) stays on the general ragged kernel.
+striped context, the env escape hatch, and every call that states a sliding
+window) stays on the general ragged kernel.
 """
 
 from __future__ import annotations
@@ -159,14 +160,38 @@ def test_decode_kernel_fp8_kv_scale(fp8):
     )
 
 
-def test_decode_kernel_sliding_window():
+def dispatched(q, kv_cache, md, scale, layer=0, **kw):
+    """``dispatch_ragged_attention``'s answer in interpret mode, and the
+    names of the ``pallas_call``s it lowered the call to."""
+    import vllm_tpu.ops.rpa_decode_kernel as dk
+    import vllm_tpu.ops.rpa_kernel as rk
+
+    names = []
+    with pytest.MonkeyPatch.context() as mp:
+        for module, fn in ((rk, "ragged_paged_attention"),
+                           (dk, "decode_paged_attention")):
+            mp.setattr(
+                module, fn,
+                lambda *a, _real=getattr(module, fn), **k: (
+                    names.append(k["name"]), _real(*a, **k))[1])
+        out = dispatch_ragged_attention(
+            q, kv_cache, jnp.int32(layer), md, scale, allow_interpret=True,
+            **kw)
+    return out, names
+
+
+def test_decode_only_rows_under_a_sliding_window():
+    """What ``decode_paged_attention(sliding_window=16)`` held until the
+    decode kernel lost its window: the same rows, through the dispatcher,
+    which sends a call that states a window to the ragged kernel."""
     rng = np.random.default_rng(3)
     kh, h, d, bs = 2, 4, 128, 8
     q, kv_cache, md = _decode_case(
         rng, [60, 9, 41], kh, h, d, bs, num_blocks=64
     )
     scale = d ** -0.5
-    got = _run_decode_kernel(q, kv_cache, 0, md, scale, sliding_window=16)
+    got, names = dispatched(q, kv_cache, md, scale, sliding_window=16)
+    assert names == ["rpa_kernel"]
     want = ref_ragged_paged_attention(
         q, kv_cache, jnp.int32(0), md, scale, sliding_window=16
     )
@@ -294,6 +319,73 @@ def test_dispatch_decode_only_takes_decode_kernel(
     )
 
 
+# Contexts shorter than the window of 16, equal to it and past it, a floor
+# inside a page (60 - 16 = 44) and on a page's edge (40 - 16 = 24: page 3 of
+# 8 tokens), dead rows behind the live ones, and fewer live rows than the
+# decode kernel's block of 4 on the chip.
+ROWS = {
+    "five_rows_of_sixteen": ([60, 9, 16, 40, 33], 16),
+    "three_rows_of_four": ([41, 5, 24], 4),
+}
+
+
+@pytest.mark.parametrize("rows", ROWS)
+@pytest.mark.parametrize(
+    "window", [None, 16, 4096],
+    ids=["no_window", "window_16", "window_past_every_context"])
+@pytest.mark.parametrize("layout", ["plain", "packed_64", "split_sinks"])
+def test_dispatch_decode_only_lowers_by_the_window(layout, window, rows):
+    """A decode-only batch lowers to ``rpa_kernel<suffix>`` when the call
+    states a window, whatever its length, and to ``rpa_decode_kernel
+    <suffix>`` when it states none; either way the live rows are the XLA
+    reference's. The decode kernel writes its dead rows (finite); the
+    ragged kernel writes no row past the live ones, in a decode-only step
+    as in a mixed step's padding, and what the interpreter leaves there is
+    not a number: the runner reads neither. Under a window of 16 the
+    pages wholly under a row's floor are the null page, as a window
+    group's manager leaves them once it gave them back."""
+    import dataclasses
+
+    from tests.models.test_laguna import null_under_the_floor
+
+    kv_lens, r_pad = ROWS[rows]
+    n, bs = len(kv_lens), 8
+    rng = np.random.default_rng(12)
+    kw = dict(sliding_window=window, kernel_suffix="_kind")
+    if layout == "split_sinks":
+        from tests.models.test_split_kv_attention import _view, split_case
+
+        kh, h, dk, dv = 2, 8, 96, 64
+        q, kv_cache, md, _, _ = split_case(
+            rng, [1] * n, kv_lens, kh, h, dk, dv, bs=bs, r_pad=r_pad)
+        md = dataclasses.replace(md, decode_only=True)
+        sinks = jnp.asarray(rng.standard_normal(h) + 1.0, jnp.float32)
+        kw.update(sinks=sinks, num_kv_heads=kh)
+        layer, scale = 1, dk ** -0.5
+        qp, view = _view(q, kv_cache, kh)
+        want = ref_ragged_paged_attention(  # (its md holds live tokens only)
+            qp[:n], view, jnp.int32(layer), md, scale, sliding_window=window,
+            sinks=sinks, head_major=True)
+        out_shape = (r_pad, h, dv)
+    else:
+        kh, h, d = 2, 4, 128 if layout == "plain" else 64
+        q, kv_cache, md = _decode_case(
+            rng, kv_lens, kh, h, d, bs, num_blocks=64, r_pad=r_pad)
+        layer, scale = 0, d ** -0.5
+        want = ref_ragged_paged_attention(
+            q, kv_cache, jnp.int32(layer), md, scale, sliding_window=window)
+        out_shape = q.shape
+    if window == 16:
+        md = null_under_the_floor(md, window, bs)
+    got, names = dispatched(q, kv_cache, md, scale, layer=layer, **kw)
+    assert names == [
+        ("rpa_decode_kernel" if window is None else "rpa_kernel") + "_kind"]
+    assert got.shape == out_shape
+    np.testing.assert_allclose(
+        np.asarray(got)[:n], np.asarray(want)[:n], rtol=2e-4, atol=2e-4)
+    assert np.isfinite(np.asarray(got)[:n if window else r_pad]).all()
+
+
 def test_dispatch_mixed_batch_takes_general_kernel(
     monkeypatch, pallas_interpret_env
 ):
@@ -389,7 +481,8 @@ def test_dispatch_token_row_mismatch_takes_general_kernel(
     assert general_calls and not decode_calls
 
 
-@pytest.mark.parametrize("path", ["decode", "ragged", "ragged_lse"])
+@pytest.mark.parametrize(
+    "path", ["decode", "decode_window", "ragged", "ragged_lse"])
 @pytest.mark.parametrize("d", [64, 128])
 def test_dispatch_under_tp_mesh_runs_kernels_per_shard(
     pallas_interpret_env, path, d
@@ -398,7 +491,9 @@ def test_dispatch_under_tp_mesh_runs_kernels_per_shard(
     Mosaic call cannot be partitioned by GSPMD; ``tests/
     test_tpu_aot_compile.py`` asks the chip's compiler): each shard gets
     its own q heads and its own cache rows, and the result — and the LSE —
-    equals the unsharded reference."""
+    equals the unsharded reference. ``decode`` states no window and runs
+    the decode kernel a shard; ``decode_window`` is a decode-only batch
+    under a window, the ragged kernel a shard, its window an array."""
     import dataclasses
 
     from jax.sharding import Mesh, NamedSharding
@@ -416,10 +511,11 @@ def test_dispatch_under_tp_mesh_runs_kernels_per_shard(
     )
     scale = d ** -0.5
     lse = path == "ragged_lse"
-    md = dataclasses.replace(md, decode_only=path == "decode")
+    window = None if path == "decode" else 16
+    md = dataclasses.replace(md, decode_only=path.startswith("decode"))
     want = ref_ragged_paged_attention(
         q, kv_cache, jnp.int32(0), md, scale, return_lse=lse,
-        sliding_window=16,
+        sliding_window=window,
     )
     md_tp = dataclasses.replace(md, tp_mesh=mesh)
     q_sh = jax.device_put(q, NamedSharding(mesh, P(None, "tp", None)))
@@ -428,7 +524,7 @@ def test_dispatch_under_tp_mesh_runs_kernels_per_shard(
     )
     got = jax.jit(
         lambda q, kv, md: _dispatch(
-            q, kv, md, scale, return_lse=lse, sliding_window=16
+            q, kv, md, scale, return_lse=lse, sliding_window=window
         )
     )(q_sh, kv_sh, md_tp)
     n = 3

@@ -16,7 +16,7 @@ import pytest
 from transformers import PretrainedConfig
 
 from tests.benchmark.tiny_laguna import TINY_LAGUNA, model_dir
-from tests.models.test_decode_attention import _decode_case, _run_decode_kernel
+from tests.models.test_decode_attention import _decode_case, dispatched
 from tests.models.test_ragged_paged_attention import (
     _bundled_ref,
     _random_case,
@@ -64,15 +64,19 @@ def test_ragged_kernel_with_a_window_over_null_pages(kh, h):
 
 
 @pytest.mark.parametrize("kh,h", [(2, 12), (2, 16)], ids=["6_a_kv_head", "8_a_kv_head"])
-def test_decode_kernel_with_a_window_over_null_pages(kh, h):
+def test_a_decode_only_step_with_a_window_over_null_pages(kh, h):
+    """The decode-only step of a window layer, as the dispatcher lowers it:
+    the ragged kernel under the window layers' name (the decode kernel has
+    no window), every row one visit that starts at its floor."""
     from vllm_tpu.ops.attention import ref_ragged_paged_attention
 
     rng = np.random.default_rng(6)
     q, kv_cache, md = _decode_case(rng, [70, 45, 12, 33], kh, h, 128, BS, num_blocks=64)
     scale = 128 ** -0.5
     want = ref_ragged_paged_attention(q, kv_cache, jnp.int32(0), md, scale, sliding_window=WINDOW)
-    got = _run_decode_kernel(q, kv_cache, 0, null_under_the_floor(md, WINDOW, BS), scale,
-                             sliding_window=jnp.int32(WINDOW), name="rpa_decode_kernel_window")
+    got, names = dispatched(q, kv_cache, null_under_the_floor(md, WINDOW, BS), scale,
+                            sliding_window=jnp.int32(WINDOW), kernel_suffix="_window")
+    assert names == ["rpa_kernel_window"]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
 
 
@@ -87,8 +91,9 @@ def test_a_group_of_six_query_heads_keeps_whole_tiles_and_the_narrow_rung():
 
 
 def test_the_dispatch_names_a_window_layers_kernel(monkeypatch):
-    """``paged_attention(kernel_suffix=...)`` reaches both kernels as their
-    ``pallas_call``'s name; without it the names are what they were."""
+    """``paged_attention(kernel_suffix=...)`` reaches the kernel as its
+    ``pallas_call``'s name; without it the names are what they were. A
+    window layer's call is the ragged kernel's in a decode-only step too."""
     from vllm_tpu.ops import attention, rpa_decode_kernel, rpa_kernel
 
     seen = []
@@ -104,7 +109,7 @@ def test_the_dispatch_names_a_window_layers_kernel(monkeypatch):
     dq, dkv, dmd = _decode_case(rng, [9, 12], 2, 4, 128, BS, num_blocks=16)
     attention.paged_attention(dq, dkv, 0, dmd, 1.0, sliding_window=4, kernel_suffix="_window")
     attention.paged_attention(dq, dkv, 0, dmd, 1.0)
-    assert seen == ["rpa_kernel_window", "rpa_kernel", "rpa_decode_kernel_window",
+    assert seen == ["rpa_kernel_window", "rpa_kernel", "rpa_kernel_window",
                     "rpa_decode_kernel"]
 
 

@@ -23,7 +23,6 @@ from vllm_tpu.ops.attention import (
     split_kv_lanes,
     write_kv,
 )
-from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
 from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
 
 BS = 8
@@ -196,26 +195,30 @@ def test_the_ragged_kernel_on_the_split_layout(dk, dv, kh, h, window, sink):
 @pytest.mark.parametrize("window,sink", MODES, ids=MODE_IDS)
 @pytest.mark.parametrize("kh,h", [(2, 8), (4, 8)], ids=["4_a_kv_head", "2_a_kv_head"])
 @pytest.mark.parametrize("dk,dv", WIDTHS, ids=["96_64", "192_128"])
-def test_the_decode_kernel_on_the_split_layout(dk, dv, kh, h, window, sink):
+def test_a_decode_only_step_on_the_split_layout(dk, dv, kh, h, window, sink):
+    """As the dispatcher lowers it: the decode kernel for a full layer, the
+    ragged kernel for a window layer (the decode kernel has no window)."""
+    from tests.models.test_decode_attention import dispatched
+
     rng = np.random.default_rng(4)
     kv_lens = [33, 1, 17, 2, 9]
     q, cache, md, k_all, v_all = split_case(
         rng, [1] * 5, kv_lens, kh, h, dk, dv, r_pad=8)
     sinks = jnp.asarray(rng.standard_normal(h) + 1.0, jnp.float32) if sink else None
     scale = dk ** -0.5
-    qp, view = _view(q, cache, kh)
+    md = dataclasses.replace(md, decode_only=True)
     if window:
         md = null_under_the_floor(md, window, BS)
-    got = decode_paged_attention(
-        qp, view, jnp.asarray([1], jnp.int32), md.seq_lens, md.block_tables, md.num_seqs,
-        sm_scale=scale, interpret=True, num_seqs_per_block=2, num_kv_pages_per_block=2,
-        head_major=True, sinks=sinks,
+    got, names = dispatched(
+        q, cache, md, scale, layer=1, sinks=sinks, num_kv_heads=kh,
         sliding_window=None if window is None else jnp.int32(window))
+    assert names == ["rpa_kernel" if window else "rpa_decode_kernel"]
     assert got.shape == (8, h, dv)
     np.testing.assert_allclose(
         np.asarray(got)[:5], dense(q[:5], k_all, v_all, md, scale, window, sinks),
         rtol=2e-4, atol=2e-4)
-    assert np.isfinite(np.asarray(got)).all()  # dead rows: finite, never read
+    if not window:  # the decode kernel's dead rows: finite, never read
+        assert np.isfinite(np.asarray(got)).all()
 
 
 @pytest.mark.parametrize("sink", [False, True], ids=["plain", "sink"])
@@ -239,10 +242,13 @@ def test_a_row_whose_first_tile_is_wholly_masked(sink):
         num_kv_pages_per_block=2, num_queries_per_block=8, head_major=True,
         sinks=sinks, sliding_window=jnp.int32(5))
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-4)
-    dec = decode_paged_attention(
-        qp, view, jnp.asarray([1], jnp.int32), md.seq_lens, md.block_tables, md.num_seqs,
-        sm_scale=scale, interpret=True, num_seqs_per_block=2, num_kv_pages_per_block=1,
-        head_major=True, sinks=sinks, sliding_window=jnp.int32(5))
+    # And as the decode-only step of that layer, through the dispatcher.
+    from tests.models.test_decode_attention import dispatched
+
+    dec, names = dispatched(
+        q, cache, dataclasses.replace(md, decode_only=True), scale, layer=1,
+        sinks=sinks, num_kv_heads=2, sliding_window=jnp.int32(5))
+    assert names == ["rpa_kernel"]
     np.testing.assert_allclose(np.asarray(dec), want, rtol=2e-4, atol=2e-4)
 
 
@@ -272,8 +278,9 @@ def test_a_sink_inside_the_kernels_of_the_layouts_there_were(sink):
 
 
 def test_the_dispatch_keeps_the_decode_kernel_for_a_sink_and_the_split_layout(monkeypatch):
-    """``decode_ok`` stays true with ``sinks`` (they ask for no LSE), and
-    both kernels get the head-major view, the padded query and the names."""
+    """``decode_ok`` stays true with ``sinks`` (they ask for no LSE) where
+    the call states no window, and both kernels get the head-major view,
+    the padded query and the names."""
     from vllm_tpu.ops import attention, rpa_decode_kernel, rpa_kernel
 
     seen = []
@@ -292,10 +299,12 @@ def test_the_dispatch_keeps_the_decode_kernel_for_a_sink_and_the_split_layout(mo
     md = dataclasses.replace(md, decode_only=True)
     paged_attention(q, cache, 1, md, 1.0, sliding_window=4, kernel_suffix="_window",
                     sinks=sinks, num_kv_heads=2)
+    paged_attention(q, cache, 1, md, 1.0, sinks=sinks, num_kv_heads=2)
     paged_attention(q, cache, 1, md, 1.0, num_kv_heads=2)
     assert seen == [
         ("rpa_kernel_window", 256, (2, BS, 384), True, True),
-        ("rpa_decode_kernel_window", 256, (2, BS, 384), True, True),
+        ("rpa_kernel_window", 256, (2, BS, 384), True, True),
+        ("rpa_decode_kernel", 256, (2, BS, 384), True, True),
         ("rpa_decode_kernel", 256, (2, BS, 384), False, True),
     ]
 
@@ -313,11 +322,15 @@ def test_under_a_tensor_parallel_mesh_the_sinks_are_split_with_the_heads(monkeyp
     rng = np.random.default_rng(8)
     sinks = jnp.asarray(rng.standard_normal(8) + 1.0, jnp.float32)
     scale = 96 ** -0.5
-    for q_lens, kv_lens, decode in (([1, 6, 1], [30, 19, 5], False), ([1, 1, 1, 1], [30, 19, 5, 2], True)):
+    # A mixed step and a decode-only one under a window (the ragged kernel a
+    # shard), and a decode-only one without (the decode kernel a shard).
+    for q_lens, kv_lens, decode, window in (
+            ([1, 6, 1], [30, 19, 5], False, 12), ([1, 1, 1, 1], [30, 19, 5, 2], True, 12),
+            ([1, 1, 1, 1], [30, 19, 5, 2], True, None)):
         q, cache, md, k_all, v_all = split_case(rng, q_lens, kv_lens, 2, 8, 96, 64)
         md = dataclasses.replace(md, tp_mesh=mesh, decode_only=decode)
         got = jax.jit(lambda q, cache, md, sinks: dispatch_ragged_attention(
-            q, cache, jnp.int32(1), md, scale, sliding_window=12, sinks=sinks,
+            q, cache, jnp.int32(1), md, scale, sliding_window=window, sinks=sinks,
             num_kv_heads=2, allow_interpret=True))(q, cache, md, sinks)
         np.testing.assert_allclose(
-            np.asarray(got), dense(q, k_all, v_all, md, scale, 12, sinks), rtol=2e-4, atol=2e-4)
+            np.asarray(got), dense(q, k_all, v_all, md, scale, window, sinks), rtol=2e-4, atol=2e-4)

@@ -867,17 +867,45 @@ def test_laguna_ragged_kernel_compiles(one_chip, q_heads, window):
     assert f'"{name}"' in text or name in text
 
 
+def _holds_both_kinds_kernels(text, decode_only):
+    """A window layer's call is the ragged kernel's in both step programs;
+    a full layer's is the decode kernel's in the decode-only one."""
+    base = "rpa_decode_kernel" if decode_only else "rpa_kernel"
+    assert "rpa_kernel_window" in text and re.search(base + r'(?!_window)\b', text)
+    assert "rpa_decode_kernel_window" not in text
+
+
+def _decode_only_call(args, window, sm_scale, sinks=None, **kw):
+    """(jitted call, its arguments, name) of one layer's attention in the
+    decode-only step as the dispatcher lowers it: the decode kernel where
+    the layer states no window, the ragged kernel with the window as a
+    scalar where it states one (the decode kernel has none). ``args`` are
+    the kernel's shapes, with the row offsets where there is a window."""
+    from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
+    from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
+
+    if window:
+        name = "rpa_kernel_window"
+        fn = functools.partial(ragged_paged_attention, sm_scale=sm_scale, name=name, **kw)
+        call = lambda sinks, *a: fn(*a, sliding_window=jnp.int32(window), sinks=sinks)
+    else:
+        name = "rpa_decode_kernel"
+        fn = functools.partial(decode_paged_attention, sm_scale=sm_scale, name=name, **kw)
+        call = lambda sinks, *a: fn(*a, sinks=sinks)
+    return jax.jit(call), (sinks, *args), name
+
+
+def _laguna_decode_only_call(sharding, q_heads, window, sm_scale=D**-0.5):
+    return _decode_only_call(
+        _laguna_attn_args(sharding, LAGUNA_ROWS, LAGUNA_ROWS, q_heads, bool(window)),
+        window, sm_scale)
+
+
 @pytest.mark.parametrize("window", [512, 0], ids=["window512", "full"])
 @pytest.mark.parametrize("q_heads", [48, 64])
-def test_laguna_decode_kernel_compiles(one_chip, q_heads, window):
-    from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
-
-    name = "rpa_decode_kernel_window" if window else "rpa_decode_kernel"
-    fn = functools.partial(decode_paged_attention, sm_scale=D**-0.5, name=name)
-    text = jax.jit(lambda *a: fn(*a, sliding_window=jnp.int32(window))).lower(
-        *_laguna_attn_args(one_chip, LAGUNA_ROWS, LAGUNA_ROWS, q_heads, False)
-    ).compile().as_text()
-    assert name in text
+def test_laguna_decode_only_attention_compiles(one_chip, q_heads, window):
+    fn, args, name = _laguna_decode_only_call(one_chip, q_heads, window)
+    assert name in fn.lower(*args).compile().as_text()
 
 
 @pytest.mark.parametrize("t,decode_only", [(64, True), (512, False)],
@@ -934,8 +962,7 @@ def test_laguna_step_compiles_and_reads_each_weight_in_place(
         params, cache, s((t,), jnp.int32), md
     ).compile().as_text()
 
-    base = "rpa_decode_kernel" if decode_only else "rpa_kernel"
-    assert base + "_window" in text and re.search(base + r'(?!_window)\b', text)
+    _holds_both_kinds_kernels(text, decode_only)
     assert "gmm" in text
     squeezed = lambda dims: tuple(d for d in dims if d != 1)
     shapes = {squeezed(a.shape[k:]) for a in jax.tree.leaves(params["periods"])
@@ -998,19 +1025,41 @@ def test_mimo_ragged_kernel_compiles_on_the_split_page(one_chip, kind):
     assert name in lowered.compile().as_text()
 
 
-@pytest.mark.parametrize("kind", list(MIMO_KINDS))
-def test_mimo_decode_kernel_compiles_on_the_split_page(one_chip, kind):
-    from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
+def _mimo_decode_only_call(sharding, kind, sm_scale=192**-0.5):
+    window = MIMO_KINDS[kind][2]
+    args, sinks = _mimo_attn_args(sharding, MIMO_ROWS, MIMO_ROWS, kind, bool(window))
+    return _decode_only_call(args, window, sm_scale, sinks, head_major=True)
 
-    kh, bs, window, _ = MIMO_KINDS[kind]
-    name = "rpa_decode_kernel_window" if window else "rpa_decode_kernel"
-    args, sinks = _mimo_attn_args(one_chip, MIMO_ROWS, MIMO_ROWS, kind, False)
-    fn = functools.partial(decode_paged_attention, sm_scale=192**-0.5, name=name,
-                           head_major=True)
-    lowered = jax.jit(lambda sinks, *a: fn(
-        *a, sliding_window=jnp.int32(window), sinks=sinks)).lower(sinks, *args)
+
+@pytest.mark.parametrize("kind", list(MIMO_KINDS))
+def test_mimo_decode_only_attention_compiles_on_the_split_page(one_chip, kind):
+    fn, args, name = _mimo_decode_only_call(one_chip, kind)
+    lowered = fn.lower(*args)
     assert lowered.out_info.shape == (MIMO_ROWS, 64, 128)
     assert name in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("model", ["laguna", "mimo"])
+def test_tracing_a_window_layers_decode_only_call_once_is_cheap(one_chip, model):
+    """``test_tracing_the_ragged_kernel_once_is_cheap``'s bound at the two
+    decode-only step programs that hold ``rpa_kernel_window`` since PR 47
+    (``lagunaxs2.code-mixed`` T = R = 64, ``mimov25.long-answers`` T = R =
+    128 on the split page with sinks): a window layer's call is traced and
+    lowered once a run of layers a program, in Python, and that is
+    ``setup_first_use_s``."""
+    import time
+
+    def call(sm_scale):
+        if model == "laguna":
+            return _laguna_decode_only_call(one_chip, 64, 512, sm_scale)[:2]
+        return _mimo_decode_only_call(one_chip, "window", sm_scale)[:2]
+
+    fn, args = call(0.5)
+    fn.lower(*args)  # imports and caches that are not the kernel's
+    fn, args = call(0.25)
+    t0 = time.process_time()
+    fn.lower(*args)
+    assert time.process_time() - t0 < 4.0
 
 
 @pytest.mark.parametrize("t,decode_only", [(MIMO_ROWS, True), (512, False)],
@@ -1064,8 +1113,7 @@ def test_mimo_step_compiles_and_reads_each_weight_in_place(
         params, cache, s((t,), jnp.int32), md
     ).compile().as_text()
 
-    base = "rpa_decode_kernel" if decode_only else "rpa_kernel"
-    assert base + "_window" in text and re.search(base + r'(?!_window)\b', text)
+    _holds_both_kinds_kernels(text, decode_only)
     assert "gmm" in text
     squeezed = lambda dims: tuple(d for d in dims if d != 1)
     shapes = {squeezed(a.shape[1:]) for name in ("window", "moe", "experts")

@@ -407,9 +407,13 @@ def dispatch_ragged_attention(
     # Decode-only fast path: every live row is a single-position decode
     # (T == R, token i == row i), so the sequence-pipelined kernel can
     # batch KV DMAs across sequences instead of walking them serially.
-    # Striped-context (CP) and LSE callers stay on the general kernel.
+    # Striped-context (CP) and LSE callers stay on the general kernel, and
+    # so does a call that states a window (an int or a traced scalar): a
+    # row then reads a block of keys, which the general kernel's narrow
+    # rung fetches from the window's floor on, a live row a visit.
     decode_ok = (
         md.decode_only
+        and sliding_window is None
         and not return_lse
         and isinstance(ctx_stride, int)
         and ctx_stride == 1
@@ -454,7 +458,7 @@ def dispatch_ragged_attention(
             q,
             kv_cache,
             (layer1, md.seq_lens, md.block_tables, md.num_seqs),
-            dict(sliding_window=sliding_window),
+            {},
             **extra,
         )
     if use_kernel:

@@ -40,12 +40,12 @@ Contract (the decode-only fast path of ``ops/attention.py``):
 - rows at or beyond ``num_seqs`` are dead: they read the null page and
   produce finite garbage, exactly like the general kernel's padding.
 
-Sliding window (dynamic scalar, 0 = full) starts the tile loop at the
-window floor; fp8 KV dequant (``k_scale``/``v_scale``) and the packed
-``[.., KH, 2D]`` head_dim-64 layout are handled identically to the
-general kernel (shared ``strided_load_kv``). No LSE output — callers
-needing LSE (context parallelism, tree verification) stay on the
-general kernel.
+fp8 KV dequant (``k_scale``/``v_scale``) and the packed ``[.., KH, 2D]``
+head_dim-64 layout are handled identically to the general kernel (shared
+``strided_load_kv``). No LSE output and no sliding mask — callers needing
+either (context parallelism, tree verification, a layer that keeps the
+last N keys) stay on the general kernel, which starts a row at its
+floor.
 """
 
 from __future__ import annotations
@@ -65,9 +65,6 @@ from vllm_tpu.ops.rpa_kernel import (
     fold_on_2nd_minor,
     strided_load_kv,
 )
-
-# Tile loop sentinel for "no live sequence in this block".
-_I32_MAX = 0x7FFFFFFF
 
 
 class _TileCopy:
@@ -113,7 +110,6 @@ def _decode_kernel(
     page_indices_ref,  # [R, pages_per_seq]
     num_seqs_ref,  # [1]
     layer_ref,  # [1]
-    window_ref,  # [1] i32 sliding window; 0 = full attention
     # Inputs
     q_ref,  # [S_BLK, num_q_heads_per_blk, head_dim]
     kv_pages_hbm_ref,  # [L, NB, page_size, kv_rows, kv_lanes]
@@ -139,7 +135,6 @@ def _decode_kernel(
     pages_per_seq = page_indices_ref.shape[1]
     num_seqs = num_seqs_ref[0]
     layer = layer_ref[0]
-    window = window_ref[0]
     if head_major:  # the split layout's page, [KH, page_size, Dk_pad + Dv]
         _, skp, kv_rows_per_blk, page_size, kv_lanes = kv_bufs.shape
     else:
@@ -170,31 +165,17 @@ def _decode_kernel(
         kv_len = seq_kv_len(seq_idx)
         return jnp.minimum(pl.cdiv(kv_len, page_size), pages_per_seq)
 
-    def block_bounds(blk_idx):
-        """(first tile, one-past-last tile) for a sequence block. A pure
-        function of the scalar prefetches and blk_idx ONLY, so the DMA
-        prefetch chain and the compute loop always agree. The end floor
-        is 1: a block of dead/empty rows still runs one fully-masked
-        tile, keeping buffer ownership uniform. With a sliding window
-        the start is the MINIMUM window floor over the block's live
-        sequences (per-sequence floors differ; masking absorbs the
-        rest)."""
+    def block_tiles(blk_idx):
+        """Tiles of a sequence block, walked from tile 0. A pure function
+        of the scalar prefetches and blk_idx ONLY, so the DMA prefetch
+        chain and the compute loop always agree. The floor is 1: a block
+        of dead/empty rows still runs one fully-masked tile, keeping
+        buffer ownership uniform."""
         t_end = jnp.int32(1)
-        t_start = jnp.int32(_I32_MAX)
         for s in range(s_blk):
-            kv_len = seq_kv_len(blk_idx * s_blk + s)
-            pn = jnp.minimum(pl.cdiv(kv_len, page_size), pages_per_seq)
-            t_end = jnp.maximum(t_end, pl.cdiv(pn, kp))
-            first = jnp.where(
-                window > 0,
-                jnp.maximum(kv_len - window, 0) // kv_tile,
-                0,
-            )
-            t_start = jnp.minimum(
-                t_start, jnp.where(kv_len > 0, first, _I32_MAX)
-            )
-        t_start = jnp.where(t_start == jnp.int32(_I32_MAX), 0, t_start)
-        return jnp.minimum(t_start, t_end - 1), t_end
+            t_end = jnp.maximum(
+                t_end, pl.cdiv(seq_end_page(blk_idx * s_blk + s), kp))
+        return t_end
 
     def make_tile_copy(h_blk, b_blk, tile_idx, slot):
         if num_heads_blks == 1:
@@ -218,7 +199,7 @@ def _decode_kernel(
             layer, tile_idx, kp, seq_cols,
         )
 
-    t_start, t_end = block_bounds(seq_blk_idx)
+    t_end = block_tiles(seq_blk_idx)
 
     def start_parity():
         """Double-buffer parity at this program's first tile: the total
@@ -232,8 +213,7 @@ def _decode_kernel(
         persist."""
 
         def add_iters(blk_idx, acc):
-            ts, te = block_bounds(blk_idx)
-            return acc + (te - ts)
+            return acc + block_tiles(blk_idx)
 
         before = lax.fori_loop(0, seq_blk_idx, add_iters, jnp.int32(0))
         if num_heads_blks > 1:
@@ -245,7 +225,7 @@ def _decode_kernel(
 
     @pl.when(heads_blk_idx + seq_blk_idx == 0)
     def prefetch_first_tile():
-        make_tile_copy(0, 0, block_bounds(0)[0], 0).start()
+        make_tile_copy(0, 0, jnp.int32(0), 0).start()
 
     def next_prefetch_ids(tile_idx):
         """Grid-order successor of (heads_blk, seq_blk, tile): next tile
@@ -264,7 +244,7 @@ def _decode_kernel(
             heads_blk_idx + 1,
             heads_blk_idx,
         )
-        nt = lax.select(last_tile, block_bounds(nb)[0], nt)
+        nt = lax.select(last_tile, 0, nt)
         return nh, nb, nt
 
     # Tile-invariant geometry: the block-diagonal sequence mask and the
@@ -311,11 +291,10 @@ def _decode_kernel(
         ).wait()  # [S*KP, page_size, rows, lanes]
 
         # Context positions of this tile's columns and the combined mask:
-        # block-diagonal x causal (pos < kv_len, q sits at kv_len - 1)
-        # x sliding window. Dead rows have kv_len 0 => fully masked.
+        # block-diagonal x causal (pos < kv_len, q sits at kv_len - 1).
+        # Dead rows have kv_len 0 => fully masked.
         pos = tile_idx * kv_tile + col_off  # [SG, SKV]
         keep = same_seq & (pos < kv_len_cols)
-        keep &= (window <= 0) | (pos >= kv_len_cols - window)
         # K/V rows past the context are DMA'd garbage; zero them so the
         # contraction stays NaN-free.
         kv_valid = (
@@ -407,7 +386,7 @@ def _decode_kernel(
         for i in range(num_kv_heads_per_blk)
     )
     _, final_states = lax.fori_loop(
-        t_start, t_end, tile_body, (start_parity(), init_states)
+        0, t_end, tile_body, (start_parity(), init_states)
     )
 
     outs = []
@@ -465,7 +444,6 @@ def decode_paged_attention(
     num_seqs: jax.Array,  # i32[1]
     *,
     sm_scale: float = 1.0,
-    sliding_window=None,  # int | traced i32 scalar | None; 0/None = full
     soft_cap: float | None = None,
     mask_value: float | None = None,
     k_scale: float | None = None,
@@ -564,15 +542,11 @@ def decode_paged_attention(
             lambda h, sb, *_: (h, 0, 0)))
     out_block_spec = q_block_spec if v_dim == head_dim else pl.BlockSpec(
         (s_blk, num_q_heads_per_blk, v_dim), q_index_map)
-    window = jnp.asarray(
-        0 if sliding_window is None else sliding_window, jnp.int32
-    ).reshape(1)
     scalar_prefetches = (
         kv_lens,
         page_indices,
         num_seqs,
         layer.astype(jnp.int32).reshape(1),
-        window,
     )
     kernel = pl.pallas_call(
         functools.partial(
