@@ -55,6 +55,7 @@ def _populated_registry() -> PrometheusRegistry:
         step_tokens_by_kind={"decode": 18, "mixed": 10},
         attn_context_tokens_by_kind={"decode": 180, "mixed": 10},
         attn_ragged_pairs={"live": 26, "computed": 100},
+        attn_decode_tiles={"run": 40, "least": 29.25},
         step_first_use_s=7.5,
         batch_num_tokens=96, batch_num_reqs=3, batch_occupancy=0.75,
         step_interval_s=0.006,
@@ -237,6 +238,18 @@ def test_ragged_pairs_are_cumulative_by_kind():
     text = reg.render()  # a respawned engine's lower count lowers nothing
     assert 'vllm:attn_ragged_pairs_total{kind="live"} 30.0' in text
     assert 'vllm:attn_ragged_pairs_total{kind="computed"} 100.0' in text
+
+
+def test_decode_tiles_are_cumulative_by_kind():
+    reg = _populated_registry()
+    text = reg.render()
+    assert "# TYPE vllm:attn_decode_tiles_total counter" in text
+    assert 'vllm:attn_decode_tiles_total{kind="run"} 40.0' in text
+    assert 'vllm:attn_decode_tiles_total{kind="least"} 29.25' in text
+    reg.record(SchedulerStats(attn_decode_tiles={"run": 52, "least": 20.0}))
+    text = reg.render()  # a respawned engine's lower count lowers nothing
+    assert 'vllm:attn_decode_tiles_total{kind="run"} 52.0' in text
+    assert 'vllm:attn_decode_tiles_total{kind="least"} 29.25' in text
 
 
 def test_first_use_seconds_ratchet_beside_their_count():

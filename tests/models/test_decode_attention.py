@@ -21,7 +21,11 @@ from vllm_tpu.ops.attention import (
     ref_ragged_paged_attention,
     write_kv,
 )
-from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
+from vllm_tpu.ops.rpa_decode_kernel import (
+    decode_paged_attention,
+    decode_row_order,
+    decode_tile_counts,
+)
 
 # Small explicit blocks so interpret runs exercise multi-tile loops, the
 # cross-program DMA chain, AND partial sequence blocks.
@@ -100,7 +104,9 @@ def _run_decode_kernel(q, kv_cache, layer, md, scale, **kw):
 
 
 @pytest.mark.parametrize(
-    "kh,h", [(1, 1), (2, 4), (2, 8), (4, 4)]  # GQA ratios 1, 2, 4
+    # GQA ratios 1, 2, 4; and 8 KV heads, which are two heads blocks of 4 in
+    # float32 (a row's output leaves a heads block at a time)
+    "kh,h", [(1, 1), (2, 4), (2, 8), (4, 4), (8, 8), (8, 16)]
 )
 @pytest.mark.parametrize("d", [64, 128])
 def test_decode_kernel_matches_reference(kh, h, d):
@@ -113,12 +119,15 @@ def test_decode_kernel_matches_reference(kh, h, d):
         rng, kv_lens, kh, h, d, bs, num_blocks=64, r_pad=8
     )
     scale = d ** -0.5
-    got = _run_decode_kernel(q, kv_cache, 0, md, scale)
+    got = _run_decode_kernel(
+        q, kv_cache, 0, md, scale,
+        order=decode_row_order(md.seq_lens, md.num_seqs))
     want = ref_ragged_paged_attention(q, kv_cache, jnp.int32(0), md, scale)
     n = len(kv_lens)
     np.testing.assert_allclose(
         np.asarray(got)[:n], np.asarray(want)[:n], rtol=2e-4, atol=2e-4
     )
+    assert not np.asarray(got)[n:].any()
 
 
 def test_decode_kernel_bf16_odd_gqa():
@@ -258,6 +267,174 @@ def test_decode_kernel_multi_step_chain():
         )
 
 
+def _with_live(md, n):
+    """The step with its first ``n`` rows live."""
+    import dataclasses
+
+    return dataclasses.replace(md, num_seqs=jnp.asarray([n], jnp.int32))
+
+
+def test_decode_kernel_chain_with_a_changing_block_count():
+    """Successive calls over one cache whose live rows, and so whose live
+    blocks (2 rows each: 3, 1, 4, 1 of the 4 a call's grid has), change
+    from call to call, as a bucket's steps do: each call's copy chain and
+    buffer parity are its own, and every call matches the reference."""
+    rng = np.random.default_rng(13)
+    kh, h, d, bs = 2, 4, 128, 8
+    kv_lens = [17, 5, 40, 33, 9, 64, 2]
+    q, kv_cache, md = _decode_case(
+        rng, kv_lens, kh, h, d, bs, num_blocks=64, r_pad=8)
+    scale = d ** -0.5
+    for n in (5, 1, 7, 0):
+        md_n = _with_live(md, n)
+        got = _run_decode_kernel(
+            q, kv_cache, 0, md_n, scale,
+            order=decode_row_order(md_n.seq_lens, md_n.num_seqs))
+        want = ref_ragged_paged_attention(
+            q, kv_cache, jnp.int32(0), md_n, scale)
+        np.testing.assert_allclose(
+            np.asarray(got)[:n], np.asarray(want)[:n], rtol=2e-4, atol=2e-4,
+            err_msg=f"{n} live rows")
+        assert not np.asarray(got)[n:].any(), f"{n} live rows: dead rows"
+
+
+# (live rows, rows of the bucket) of the live-tile walk's cases: none, fewer
+# than a block, a block, a block and a row, and the cell's: 17 of 128, and
+# a full bucket.
+LIVE_ROWS = [(0, 8), (1, 8), (3, 8), (4, 8), (5, 8), (17, 128), (128, 128)]
+
+
+def _contexts(rng, n):
+    """One long row among short ones, a row of exactly a tile (2 pages of 8
+    through the dispatcher off the chip), the rest a few pages or less."""
+    kv_lens = rng.integers(1, 30, size=n)
+    kv_lens[rng.integers(n)] = 16
+    kv_lens[n // 2] = 90
+    return [int(kv) for kv in kv_lens]
+
+
+def _with_order(md):
+    import dataclasses
+
+    return dataclasses.replace(
+        md, decode_order=decode_row_order(md.seq_lens, md.num_seqs))
+
+
+@pytest.mark.parametrize("live,r_pad", LIVE_ROWS)
+@pytest.mark.parametrize("layout", ["plain", "packed_64", "split_sinks"])
+def test_the_walk_over_live_tiles(layout, live, r_pad):
+    """The decode kernel through the dispatcher, under the step's
+    ``decode_row_order``: every live row is the XLA reference's, every dead
+    row finite, and a row's output does not depend on the rows it shares a
+    block with: BIT-EQUAL whether the rows come as given and unordered, as
+    given, or shuffled (which is what lets the order change nothing a
+    client can read)."""
+    import dataclasses
+
+    rng = np.random.default_rng(14)
+    kv_lens = _contexts(rng, max(live, 1))
+    q, kv_cache, md, scale, kw, want = _layout_case(
+        rng, layout, kv_lens, r_pad)
+    if not live:  # (a case needs a row to size its tables by)
+        md = _with_live(md, 0)
+    got, names = dispatched(q, kv_cache, _with_order(md), scale, **kw)
+    assert names == ["rpa_decode_kernel"]
+    got = np.asarray(got)
+    assert got.shape[0] == r_pad and np.isfinite(got).all()
+    np.testing.assert_allclose(got[:live], want[:live], rtol=2e-4, atol=2e-4)
+    unordered, _ = dispatched(q, kv_cache, md, scale, **kw)
+    np.testing.assert_array_equal(np.asarray(unordered)[:live], got[:live])
+    # The same rows in another arrangement: row i is now row perm[i].
+    perm = np.arange(r_pad)
+    perm[:live] = rng.permutation(live)
+    shuffled = dataclasses.replace(
+        md, seq_lens=md.seq_lens[perm], block_tables=md.block_tables[perm])
+    moved, _ = dispatched(
+        q[perm], kv_cache, _with_order(shuffled), scale, **kw)
+    np.testing.assert_array_equal(np.asarray(moved)[:live], got[perm][:live])
+
+
+def _tiles_the_kernel_runs(monkeypatch, kv_lens, r_pad, order=True):
+    """Tiles the kernel's own loop waits for in one call (on its one heads
+    block), counted by the interpreter: a callback a ``_TileCopy.wait``."""
+    import vllm_tpu.ops.rpa_decode_kernel as dk
+
+    waits = []
+    real = dk._TileCopy.wait
+
+    def counted(self):
+        jax.debug.callback(lambda: waits.append(1))
+        return real(self)
+
+    monkeypatch.setattr(dk._TileCopy, "wait", counted)
+    dk.decode_paged_attention.clear_cache()
+    rng = np.random.default_rng(15)
+    q, kv_cache, md = _decode_case(
+        rng, kv_lens or [1], 2, 4, 128, 8, num_blocks=2 + sum(
+            -(-kv // 8) for kv in kv_lens or [1]), r_pad=r_pad)
+    md = _with_live(md, len(kv_lens))
+    jax.block_until_ready(_run_decode_kernel(
+        q, kv_cache, 0, md, 128 ** -0.5,
+        order=decode_row_order(md.seq_lens, md.num_seqs) if order else None))
+    jax.effects_barrier()
+    monkeypatch.undo()
+    dk.decode_paged_attention.clear_cache()
+    return len(waits)
+
+
+@pytest.mark.parametrize("kv_lens,r_pad", [
+    ([], 8), ([40], 8), ([90, 3, 16, 7, 33], 8), ([16] * 8, 8),
+    ([5, 70, 9, 2, 31, 64, 17, 1, 48], 32)],
+    ids=["no_row", "one_row", "five_rows", "equal_rows", "nine_of_32"])
+def test_decode_tile_counts_are_the_kernels_own(monkeypatch, kv_lens, r_pad):
+    """``decode_tile_counts``'s ``run`` is what the kernel's loop runs under
+    the step's order (2 rows a block, tiles of 2 pages of 8), dead blocks
+    nothing; rows of equal context run the least their keys need; and
+    without the order the same rows run more or as many."""
+    blocks = dict(page_size=8, **BLK)
+    run, least = decode_tile_counts(
+        np.asarray(kv_lens, np.int32), len(kv_lens), **blocks)
+    assert run == _tiles_the_kernel_runs(monkeypatch, kv_lens, r_pad)
+    assert least == sum(-(-kv // 16) for kv in kv_lens) / 2
+    assert run >= least
+    if len(set(kv_lens)) == 1 and len(kv_lens) % 2 == 0:
+        assert run == least
+    assert run <= _tiles_the_kernel_runs(
+        monkeypatch, kv_lens, r_pad, order=False)
+
+
+@pytest.mark.parametrize("on_chip,window,want", [
+    (True, None, {"run": 9 + 8, "least": 4.0 + 5.25}),
+    (False, None, {"run": 0, "least": 0.0}),
+    (True, 4096, {"run": 0, "least": 0.0}),
+], ids=["decode_kernel", "no_kernel_here", "every_call_states_a_window"])
+def test_the_runner_counts_a_decode_only_steps_tiles(
+        monkeypatch, on_chip, window, want):
+    """``ModelRunner._count_decode_tiles``, what a decode-only step adds to
+    ``vllm:attn_decode_tiles_total``: one call's tiles by the blocks the
+    dispatcher would run it with (4 rows, 128 keys of 16-token pages), and
+    nothing where the step's plain call is not the decode kernel's."""
+    import types
+
+    import vllm_tpu.ops.attention as attention
+    from vllm_tpu.worker.model_runner import ModelRunner
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: on_chip)
+    runner = types.SimpleNamespace(
+        _attn_call_geometry=((32, 8, 128, 16), False),
+        model=types.SimpleNamespace(sliding_window=window),
+        attn_decode_tiles={"run": 0, "least": 0.0}, _decode_tilings={})
+    # 5 rows: [900, 300, 200, 130] and [16] run 8 + 1 tiles of 128 keys;
+    # their own 8 + 3 + 2 + 2 + 1, four rows a tile, are 4.
+    ModelRunner._count_decode_tiles(
+        runner, np.asarray([200, 900, 16, 130, 300], np.int32), 128, 64)
+    # 6 rows, a step later: [700, 520, 400, 260] and [129, 1] run 6 + 2;
+    # their own 6 + 5 + 4 + 3 + 2 + 1 are 5.25.
+    ModelRunner._count_decode_tiles(
+        runner, np.asarray([1, 520, 700, 129, 400, 260], np.int32), 128, 64)
+    assert runner.attn_decode_tiles == want
+
+
 # ----------------------------------------------------------------------
 # Dispatcher eligibility (ops/attention.py)
 # ----------------------------------------------------------------------
@@ -319,6 +496,38 @@ def test_dispatch_decode_only_takes_decode_kernel(
     )
 
 
+def _layout_case(rng, layout, kv_lens, r_pad, window=None):
+    """A decode-only step of the layout: the dispatcher's arguments, and
+    the XLA reference's answer over the live rows (under ``window``)."""
+    import dataclasses
+
+    n, bs = len(kv_lens), 8
+    kw = {}
+    if layout == "split_sinks":
+        from tests.models.test_split_kv_attention import _view, split_case
+
+        kh, h, dk, dv = 2, 8, 96, 64
+        q, kv_cache, md, _, _ = split_case(
+            rng, [1] * n, kv_lens, kh, h, dk, dv, bs=bs, r_pad=r_pad)
+        md = dataclasses.replace(md, decode_only=True)
+        sinks = jnp.asarray(rng.standard_normal(h) + 1.0, jnp.float32)
+        kw.update(sinks=sinks, num_kv_heads=kh, layer=1)
+        scale = dk ** -0.5
+        qp, view = _view(q, kv_cache, kh)
+        want = ref_ragged_paged_attention(  # (its md holds live tokens only)
+            qp[:n], view, jnp.int32(1), md, scale, sliding_window=window,
+            sinks=sinks, head_major=True)
+    else:
+        kh, h, d = 2, 4, 128 if layout == "plain" else 64
+        q, kv_cache, md = _decode_case(
+            rng, kv_lens, kh, h, d, bs, num_blocks=2 + sum(
+                -(-kv // bs) for kv in kv_lens), r_pad=r_pad)
+        scale = d ** -0.5
+        want = ref_ragged_paged_attention(
+            q, kv_cache, jnp.int32(0), md, scale, sliding_window=window)
+    return q, kv_cache, md, scale, kw, np.asarray(want)[:n]
+
+
 # Contexts shorter than the window of 16, equal to it and past it, a floor
 # inside a page (60 - 16 = 44) and on a page's edge (40 - 16 = 24: page 3 of
 # 8 tokens), dead rows behind the live ones, and fewer live rows than the
@@ -344,45 +553,21 @@ def test_dispatch_decode_only_lowers_by_the_window(layout, window, rows):
     not a number: the runner reads neither. Under a window of 16 the
     pages wholly under a row's floor are the null page, as a window
     group's manager leaves them once it gave them back."""
-    import dataclasses
-
     from tests.models.test_laguna import null_under_the_floor
 
     kv_lens, r_pad = ROWS[rows]
     n, bs = len(kv_lens), 8
-    rng = np.random.default_rng(12)
-    kw = dict(sliding_window=window, kernel_suffix="_kind")
-    if layout == "split_sinks":
-        from tests.models.test_split_kv_attention import _view, split_case
-
-        kh, h, dk, dv = 2, 8, 96, 64
-        q, kv_cache, md, _, _ = split_case(
-            rng, [1] * n, kv_lens, kh, h, dk, dv, bs=bs, r_pad=r_pad)
-        md = dataclasses.replace(md, decode_only=True)
-        sinks = jnp.asarray(rng.standard_normal(h) + 1.0, jnp.float32)
-        kw.update(sinks=sinks, num_kv_heads=kh)
-        layer, scale = 1, dk ** -0.5
-        qp, view = _view(q, kv_cache, kh)
-        want = ref_ragged_paged_attention(  # (its md holds live tokens only)
-            qp[:n], view, jnp.int32(layer), md, scale, sliding_window=window,
-            sinks=sinks, head_major=True)
-        out_shape = (r_pad, h, dv)
-    else:
-        kh, h, d = 2, 4, 128 if layout == "plain" else 64
-        q, kv_cache, md = _decode_case(
-            rng, kv_lens, kh, h, d, bs, num_blocks=64, r_pad=r_pad)
-        layer, scale = 0, d ** -0.5
-        want = ref_ragged_paged_attention(
-            q, kv_cache, jnp.int32(layer), md, scale, sliding_window=window)
-        out_shape = q.shape
+    q, kv_cache, md, scale, kw, want = _layout_case(
+        np.random.default_rng(12), layout, kv_lens, r_pad, window=window)
+    kw.update(sliding_window=window, kernel_suffix="_kind")
     if window == 16:
         md = null_under_the_floor(md, window, bs)
-    got, names = dispatched(q, kv_cache, md, scale, layer=layer, **kw)
+    got, names = dispatched(q, kv_cache, md, scale, **kw)
     assert names == [
         ("rpa_decode_kernel" if window is None else "rpa_kernel") + "_kind"]
-    assert got.shape == out_shape
+    assert got.shape == (r_pad, *want.shape[1:])
     np.testing.assert_allclose(
-        np.asarray(got)[:n], np.asarray(want)[:n], rtol=2e-4, atol=2e-4)
+        np.asarray(got)[:n], want, rtol=2e-4, atol=2e-4)
     assert np.isfinite(np.asarray(got)[:n if window else r_pad]).all()
 
 
@@ -492,7 +677,7 @@ def test_dispatch_under_tp_mesh_runs_kernels_per_shard(
     test_tpu_aot_compile.py`` asks the chip's compiler): each shard gets
     its own q heads and its own cache rows, and the result — and the LSE —
     equals the unsharded reference. ``decode`` states no window and runs
-    the decode kernel a shard; ``decode_window`` is a decode-only batch
+    the decode kernel a shard, under the step's order of the rows; ``decode_window`` is a decode-only batch
     under a window, the ragged kernel a shard, its window an array."""
     import dataclasses
 
@@ -517,6 +702,8 @@ def test_dispatch_under_tp_mesh_runs_kernels_per_shard(
         q, kv_cache, jnp.int32(0), md, scale, return_lse=lse,
         sliding_window=window,
     )
+    if path == "decode":  # (the step's order of the rows, replicated)
+        md = _with_order(md)
     md_tp = dataclasses.replace(md, tp_mesh=mesh)
     q_sh = jax.device_put(q, NamedSharding(mesh, P(None, "tp", None)))
     kv_sh = jax.device_put(

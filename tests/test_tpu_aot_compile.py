@@ -98,7 +98,12 @@ def _kv_scale(kv_dtype):
 
 @pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.float8_e4m3fn],
                          ids=["kv_bf16", "kv_fp8"])
-def test_decode_kernel_compiles(one_chip, kv_dtype):
+@pytest.mark.parametrize("ordered", [True, False],
+                         ids=["rows_in_order", "rows_as_given"])
+def test_decode_kernel_compiles(one_chip, kv_dtype, ordered):
+    """The cell's bucket of 128 rows, with the step's order of the rows as
+    the fifth scalar prefetch (q rows fetched and output rows written each
+    at its own place) and without one."""
     from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
 
     a = _attn_shapes(one_chip, 128, 128, kv_dtype)
@@ -106,8 +111,24 @@ def test_decode_kernel_compiles(one_chip, kv_dtype):
     fn = functools.partial(
         decode_paged_attention, sm_scale=D**-0.5, k_scale=scale, v_scale=scale
     )
+    order = a["kv_lens"] if ordered else None  # (i32[128], as the order is)
     jax.jit(fn).lower(
-        a["q"], a["kv"], a["layer"], a["kv_lens"], a["pages"], a["num_seqs"]
+        a["q"], a["kv"], a["layer"], a["kv_lens"], a["pages"], a["num_seqs"],
+        order,
+    ).compile()
+
+
+def test_decode_kernel_compiles_on_several_heads_blocks(one_chip):
+    """32 KV heads (Llama-2-7B's) are four heads blocks of 8: a row's
+    output leaves by the kernel's own copy into a slice of its heads."""
+    from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
+
+    a = _attn_shapes(one_chip, 128, 128, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct(
+        (4, NUM_BLOCKS, PAGE, 2 * H, D), jnp.bfloat16, sharding=one_chip)
+    jax.jit(functools.partial(decode_paged_attention, sm_scale=D**-0.5)).lower(
+        a["q"], kv, a["layer"], a["kv_lens"], a["pages"], a["num_seqs"],
+        a["kv_lens"],
     ).compile()
 
 

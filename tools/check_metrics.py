@@ -85,6 +85,7 @@ REQUIRED_STEP_METRICS = {
     "vllm:engine_step_tokens_total",
     "vllm:attn_context_tokens_total",
     "vllm:attn_ragged_pairs_total",
+    "vllm:attn_decode_tiles_total",
     "vllm:step_bucket_compiles",
     "vllm:step_first_use_seconds_total",
     # The start and compiling (README "The start and compiling"): the
@@ -332,7 +333,8 @@ def check_step_exposition() -> list[str]:
             SchedulerStats(**phases, steps_by_kind=by_kind,
                            step_tokens_by_kind=by_kind,
                            attn_context_tokens_by_kind=by_kind,
-                           attn_ragged_pairs={"live": 1, "computed": 1}),
+                           attn_ragged_pairs={"live": 1, "computed": 1},
+                           attn_decode_tiles={"run": 1, "least": 1.0}),
             IterationStats(output_s=0.001))
     except (TypeError, AttributeError) as e:
         return [f"the step stats no longer reach the registry: {e}"]
@@ -345,6 +347,8 @@ def check_step_exposition() -> list[str]:
                             "vllm:attn_context_tokens_total")]
     wanted += [f'vllm:attn_ragged_pairs_total{{kind="{k}"}} 1.0'
                for k in ("live", "computed")]
+    wanted += [f'vllm:attn_decode_tiles_total{{kind="{k}"}} 1.0'
+               for k in ("run", "least")]
     return [f"/metrics lacks the sample {w!r}" for w in wanted
             if w not in text]
 

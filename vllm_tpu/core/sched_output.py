@@ -300,6 +300,10 @@ class SchedulerStats:
     # "live" (the mask lets them through) and "computed" (its tiles work
     # them out), a q head a layer, cumulative, from the runner.
     attn_ragged_pairs: dict[str, int] = field(default_factory=dict)
+    # Tiles of the decode attention kernel, "run" (its walk) and "least"
+    # (what the rows' keys need), one call a decode-only step that takes
+    # it, cumulative, from the runner.
+    attn_decode_tiles: dict[str, float] = field(default_factory=dict)
     step_first_use_s: float = 0.0
     # The same seconds by stage ("trace", "lower", "backend", "run"), and
     # the process's compile counters from JAX's own events (seconds by

@@ -427,7 +427,8 @@ class EngineCore:
                 runner, "launch_sampled_tokens", 0
             )
             for name in ("steps_by_kind", "step_tokens_by_kind",
-                         "attn_context_tokens_by_kind", "attn_ragged_pairs"):
+                         "attn_context_tokens_by_kind", "attn_ragged_pairs",
+                         "attn_decode_tiles"):
                 setattr(stats, name, dict(getattr(runner, name, {})))
             stats.diffusion_passes_by_kind = dict(
                 getattr(runner, "diffusion_passes_by_kind", {}))

@@ -356,6 +356,12 @@ class PrometheusRegistry:
             "(query token, key) pairs of the ragged attention kernel's "
             "calls, a q head a layer: live (the mask lets them through) "
             "and computed (the kernel's tiles work them out)", "kind")
+        self.attn_decode_tiles = LabeledCounter(
+            "vllm:attn_decode_tiles_total",
+            "Tiles (a block of rows x the pages of one tile) of the decode "
+            "attention kernel, one call a decode-only step that takes it: "
+            "run (its walk over the live blocks) and least (what the "
+            "rows' own keys need)", "kind")
         # Generation by diffusion over blocks and mixture of experts,
         # counted by the runner (cumulative).
         self.diffusion_passes = LabeledCounter(
@@ -819,7 +825,7 @@ class PrometheusRegistry:
             self.compile_cache, self.startup_seconds,
             self.steps_by_kind, self.step_tokens_by_kind,
             self.attn_context_tokens, self.attn_ragged_pairs,
-            self.pipeline_stall,
+            self.attn_decode_tiles, self.pipeline_stall,
             self.diffusion_passes, self.diffusion_committed,
             self.diffusion_stores_fused,
             self.moe_assignments, self.moe_experts_hit,
@@ -986,6 +992,7 @@ class PrometheusRegistry:
                 (self.step_tokens_by_kind, s.step_tokens_by_kind),
                 (self.attn_context_tokens, s.attn_context_tokens_by_kind),
                 (self.attn_ragged_pairs, s.attn_ragged_pairs),
+                (self.attn_decode_tiles, s.attn_decode_tiles),
                 (self.linear_attn_tokens, s.linear_attn_tokens_by_kind),
             ):
                 for kind, n in by_kind.items():

@@ -70,6 +70,12 @@ class AttentionMetadata:
     # jit, and the runner forces ``t_pad == r_pad`` when setting it, so
     # the extra trace count is bounded by the request buckets.
     decode_only: bool = field(default=False, metadata=dict(static=True))
+    # [R] i32, for a decode-only step: the order the decode kernel serves
+    # the rows in (``ops/rpa_decode_kernel.py::decode_row_order``: live
+    # rows longest first, so that a block's rows end together), computed
+    # once a step where the metadata is built, not in the layers. None =
+    # the rows as given.
+    decode_order: jnp.ndarray | None = None
     # The runner's device mesh when it has one (else None). STATIC. A
     # Mosaic kernel cannot be partitioned by GSPMD, so under tp > 1 the
     # dispatch runs the Pallas kernels per tp shard inside a shard_map
@@ -362,6 +368,36 @@ def ragged_kernel_tiling(
     )
 
 
+def _decode_block_overrides() -> dict:
+    """The decode kernel's block sizes where the environment names them."""
+    import vllm_tpu.envs as envs
+
+    kw = {}
+    if envs.VLLM_TPU_DECODE_SEQS_PER_BLOCK > 0:
+        kw["num_seqs_per_block"] = envs.VLLM_TPU_DECODE_SEQS_PER_BLOCK
+    if envs.VLLM_TPU_DECODE_KV_PAGES_PER_BLOCK > 0:
+        kw["num_kv_pages_per_block"] = envs.VLLM_TPU_DECODE_KV_PAGES_PER_BLOCK
+    return kw
+
+
+def decode_kernel_tiling(
+    head_dim: int, page_size: int, num_rows: int, pages_per_seq: int, *,
+    split: bool = False,
+):
+    """(rows a block, pages a tile) ``dispatch_ragged_attention`` runs a
+    decode-only step's plain call (no window, no LSE) of these shapes with,
+    or None where that call is not the decode kernel's (no TPU, kernels
+    off, the escape hatch): for the runner's count of the kernel's work."""
+    import vllm_tpu.envs as envs
+    from vllm_tpu.ops.rpa_decode_kernel import decode_tiling
+
+    if envs.VLLM_TPU_DISABLE_DECODE_KERNEL or not (
+            _pallas_ok(head_dim, split) and _on_tpu()):
+        return None
+    return decode_tiling(
+        num_rows, page_size, pages_per_seq, **_decode_block_overrides())
+
+
 def dispatch_ragged_attention(
     q: jnp.ndarray,
     kv_cache: jnp.ndarray,
@@ -438,18 +474,11 @@ def dispatch_ragged_attention(
     if use_kernel and decode_ok:
         from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
 
-        if run_interpret:
-            blk_kw = dict(num_seqs_per_block=2, num_kv_pages_per_block=2)
-        else:
-            blk_kw = {}
-            if envs.VLLM_TPU_DECODE_SEQS_PER_BLOCK > 0:
-                blk_kw["num_seqs_per_block"] = (
-                    envs.VLLM_TPU_DECODE_SEQS_PER_BLOCK
-                )
-            if envs.VLLM_TPU_DECODE_KV_PAGES_PER_BLOCK > 0:
-                blk_kw["num_kv_pages_per_block"] = (
-                    envs.VLLM_TPU_DECODE_KV_PAGES_PER_BLOCK
-                )
+        blk_kw = (
+            dict(num_seqs_per_block=2, num_kv_pages_per_block=2)
+            if run_interpret
+            else _decode_block_overrides()
+        )
         return _call_attention_kernel(
             functools.partial(
                 decode_paged_attention, **static_kw, **blk_kw,
@@ -457,7 +486,8 @@ def dispatch_ragged_attention(
             md.tp_mesh,
             q,
             kv_cache,
-            (layer1, md.seq_lens, md.block_tables, md.num_seqs),
+            (layer1, md.seq_lens, md.block_tables, md.num_seqs,
+             md.decode_order),
             {},
             **extra,
         )

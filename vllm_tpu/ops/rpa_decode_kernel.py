@@ -1,25 +1,34 @@
 """Decode-specialized Pallas TPU kernel: sequence-pipelined paged attention.
 
-The general ragged kernel (``rpa_kernel.py``) walks sequences with a
-per-sequence ``while_loop``: one double-buffered DMA chain *within* a
-sequence, but every sequence boundary serializes a DMA wait plus a tiny
-``[G, D] x [D, ctx]`` contraction. At decode shapes (q_len == 1 for
-every row, short-to-medium contexts) that is ~2k serial iterations per
-layer per step and measures ~40x off the KV-read roofline — the analog
-of the reference's dedicated ``paged_attention_v1/v2.cu`` decode path
-next to its unified varlen flash kernel.
-
-This kernel flips the loop structure for the decode-only case:
+The general ragged kernel (``rpa_kernel.py``) visits a sequence at a time:
+a decode row is one visit of its narrow rung, its keys fetched and
+contracted for that row alone. This kernel is the decode-only step's plain
+call (one token a row, no window, no LSE), and takes FOUR rows a visit — the
+analog of the reference's dedicated ``paged_attention_v1/v2.cu`` decode
+path next to its unified varlen flash kernel:
 
 - **Grid** ``(kv_head_blocks, sequence_blocks)``: each program owns a
   block of ``num_seqs_per_block`` sequences, not one ragged q span.
+- **The walk visits live tiles only.** Live rows are the first
+  ``num_seqs``, so the live blocks are the first
+  ``max(1, cdiv(num_seqs, num_seqs_per_block))``. A program past them
+  starts no copy of keys, awaits none and runs no tile: it sends zeros to
+  its rows of the output and is done, and the copy chain and the buffer
+  parity pass over it. Within the live blocks, position ``p`` of the walk
+  serves row ``order[p]`` (``decode_row_order``: the live rows by context,
+  longest first, computed once a step), so that the rows of a block end
+  together: a block runs the tiles of its LONGEST row. q rows are fetched
+  and output rows written each at its own place in the step (one-row
+  views of q under the order; the kernel's own copies out), so a caller
+  sees its own row order whatever the walk's. ``decode_tile_counts`` is the
+  host's count of the tiles a call runs beside the least its rows need.
 - **DMAs pipelined ACROSS sequences**: one KV *tile* =
   ``num_kv_pages_per_block`` pages of *every* sequence in the block,
   issued as one batch of parallel page copies into a single
   double-buffered VMEM scratch. While tile *t* is being contracted,
-  tile *t+1* — or the first tile of the *next* sequence block, chained
-  across grid programs like the general kernel's ``seq_buf_idx`` — is
-  already in flight. Per-sequence DMA latency no longer serializes.
+  tile *t+1* — or the first tile of the *next* live sequence block,
+  chained across grid programs like the general kernel's ``seq_buf_idx``
+  — is already in flight. Per-sequence DMA latency no longer serializes.
 - **One MXU contraction per tile**: the per-sequence ``q_i @ K_i^T``
   matvecs are concatenated into a single 2D
   ``[S*G, D] x [D, S*KV_TILE]`` cross-product dot with a block-diagonal
@@ -29,7 +38,17 @@ This kernel flips the loop structure for the decode-only case:
 - **Online softmax carried as loop values** (per kv-head ``m``/``l``/
   ``acc`` tuples in the ``fori_loop`` carry) instead of masked scratch
   stores; the accumulator is rescaled once per tile and normalized once
-  at the end.
+  at the end. A tile that holds none of a row's keys leaves the row's
+  state as it was, so a row's output is the same whatever rows share its
+  block.
+
+What a tile costs: 4 rows x 8 pages are copied whatever they hold (a column
+past a row's end is its column 0 again, so that every tile is the same
+count of copies), at Llama-8B's 8 KV heads 2 MB, 2.6 us of the v5e's HBM;
+every KV head's ``[512, 128]`` keys and values go through float32 to zero
+what lies past a row's context (only a row's last tile holds any), and the
+cross product computes 16 rows where 4 are wanted. Both are per-tile costs
+this file still pays (PERF.md section 7).
 
 Contract (the decode-only fast path of ``ops/attention.py``):
 
@@ -37,8 +56,8 @@ Contract (the decode-only fast path of ``ops/attention.py``):
   (the runner forces ``t_pad == r_pad`` for decode-only batches);
 - ``kv_lens [R]`` — context length *including* the current token, so
   causality degenerates to ``pos < kv_len`` (no q-position arithmetic);
-- rows at or beyond ``num_seqs`` are dead: they read the null page and
-  produce finite garbage, exactly like the general kernel's padding.
+- rows at or beyond ``num_seqs`` are dead: no key of theirs is read and
+  their output is zeros.
 
 fp8 KV dequant (``k_scale``/``v_scale``) and the packed ``[.., KH, 2D]``
 head_dim-64 layout are handled identically to the general kernel (shared
@@ -54,6 +73,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -79,7 +99,7 @@ class _TileCopy:
     def __init__(self, src_hbm_ref, vmem_buf, sem, page_indices_ref,
                  layer, tile_idx, kp, seq_cols):
         # vmem_buf: [S_BLK * KP, PS, rows, lanes]; seq_cols: per local
-        # sequence (clamped row index into page_indices, end page).
+        # sequence (its row of page_indices, end page).
         self._vmem_buf = vmem_buf
         self._copies = []
         for s, (row, end) in enumerate(seq_cols):
@@ -104,21 +124,35 @@ class _TileCopy:
         return self._vmem_buf
 
 
+def _live_blocks(num_seqs, s_blk):
+    """Sequence blocks that hold a live row; block 0 always counts (with no
+    live row at all it runs one masked tile and writes its rows' zeros)."""
+    return jnp.maximum(pl.cdiv(num_seqs, s_blk), 1)
+
+
+def _row_at(pos, order_ref, num_seqs):
+    """The step's row served at position ``pos`` of the walk: ``order``
+    places the live rows, a dead position is its own row."""
+    return jnp.where(pos < num_seqs, order_ref[pos], pos)
+
+
 def _decode_kernel(
     # Scalar prefetch
     kv_lens_ref,  # [R] context length incl. the current token
     page_indices_ref,  # [R, pages_per_seq]
     num_seqs_ref,  # [1]
     layer_ref,  # [1]
-    # Inputs
-    q_ref,  # [S_BLK, num_q_heads_per_blk, head_dim]
-    kv_pages_hbm_ref,  # [L, NB, page_size, kv_rows, kv_lanes]
-    # [with sinks: sink_ref [KH_blk, S_BLK * G, 128] f32, then] Outputs
-    o_ref,  # [S_BLK, num_q_heads_per_blk, head_dim]
-    # Scratch
-    kv_bufs,  # [2, S_BLK * KP, page_size, kv_rows_per_blk, kv_lanes]
-    sems,  # DMA semaphores (2,)
-    *rest,
+    order_ref,  # [R] the row each position of the walk serves
+    # Inputs: S_BLK views of q, one row each
+    # [num_q_heads_per_blk, head_dim], then
+    # kv_pages_hbm_ref [L, NB, page_size, kv_rows, kv_lanes]
+    # [with sinks: sink_ref [KH_blk, S_BLK * G, 128] f32]; the output
+    # o_hbm_ref [R, num_q_heads, v_dim] in HBM; the scratch
+    # kv_bufs [2, S_BLK * KP, page_size, kv_rows_per_blk, kv_lanes],
+    # sems DMA (2,), q_buf [S_BLK, num_q_heads_per_blk, head_dim],
+    # o_buf and zero_buf [S_BLK, num_q_heads_per_blk, v_dim], o_sems DMA (2,)
+    *refs,
+    s_blk: int,
     sm_scale: float,
     soft_cap: float | None,
     mask_value: float,
@@ -126,12 +160,12 @@ def _decode_kernel(
     v_scale: float | None,
     head_major: bool = False,
 ):
-    sink_ref = None
-    if rest:  # the logit of each row's q head, an input after the cache
-        sink_ref, o_ref, kv_bufs, sems = o_ref, kv_bufs, sems, *rest
-    s_blk, num_q_heads_per_blk, head_dim = q_ref.shape
-    v_dim = o_ref.shape[-1]  # head_dim but in the split layout
-    r_max = kv_lens_ref.shape[0]
+    q_refs, refs = refs[:s_blk], refs[s_blk:]
+    kv_pages_hbm_ref, *sink, o_hbm_ref = refs[:-6]
+    sink_ref = sink[0] if sink else None
+    kv_bufs, sems, q_buf, o_buf, zero_buf, o_sems = refs[-6:]
+    _, num_q_heads_per_blk, head_dim = q_buf.shape
+    v_dim = o_buf.shape[-1]  # head_dim but in the split layout
     pages_per_seq = page_indices_ref.shape[1]
     num_seqs = num_seqs_ref[0]
     layer = layer_ref[0]
@@ -154,23 +188,29 @@ def _decode_kernel(
     seq_blk_idx = pl.program_id(1)
     num_heads_blks = pl.num_programs(0)
     num_seq_blks = pl.num_programs(1)
+    # The walk visits the live blocks only: a program past them runs no
+    # tile, and the DMA chain and the buffer parity skip it.
+    num_live_blks = _live_blocks(num_seqs, s_blk)
 
-    def seq_kv_len(seq_idx):
-        """Context length of a global sequence row; 0 beyond the live
-        count (dead rows attend nothing and their K/V is zeroed)."""
-        idx = jnp.minimum(seq_idx, r_max - 1)
-        return jnp.where(seq_idx < num_seqs, kv_lens_ref[idx], 0)
+    def seq_row(pos):
+        return _row_at(pos, order_ref, num_seqs)
 
-    def seq_end_page(seq_idx):
-        kv_len = seq_kv_len(seq_idx)
+    def seq_kv_len(pos):
+        """Context length of the row at a position of the walk; 0 beyond
+        the live count (dead rows attend nothing and their K/V is
+        zeroed)."""
+        return jnp.where(pos < num_seqs, kv_lens_ref[seq_row(pos)], 0)
+
+    def seq_end_page(pos):
+        kv_len = seq_kv_len(pos)
         return jnp.minimum(pl.cdiv(kv_len, page_size), pages_per_seq)
 
     def block_tiles(blk_idx):
-        """Tiles of a sequence block, walked from tile 0. A pure function
-        of the scalar prefetches and blk_idx ONLY, so the DMA prefetch
-        chain and the compute loop always agree. The floor is 1: a block
-        of dead/empty rows still runs one fully-masked tile, keeping
-        buffer ownership uniform."""
+        """Tiles of a LIVE sequence block, walked from tile 0: those of
+        its longest row. A pure function of the scalar prefetches and
+        blk_idx ONLY, so the DMA prefetch chain and the compute loop
+        always agree. The floor of 1 is block 0's when no row is live: it
+        runs one fully-masked tile, keeping buffer ownership uniform."""
         t_end = jnp.int32(1)
         for s in range(s_blk):
             t_end = jnp.maximum(
@@ -189,212 +229,282 @@ def _decode_kernel(
             ]
         seq_cols = []
         for s in range(s_blk):
-            seq_idx = b_blk * s_blk + s
-            seq_cols.append((
-                jnp.minimum(seq_idx, r_max - 1),
-                seq_end_page(seq_idx),
-            ))
+            pos = b_blk * s_blk + s
+            seq_cols.append((seq_row(pos), seq_end_page(pos)))
         return _TileCopy(
             src, kv_bufs.at[slot], sems.at[slot], page_indices_ref,
             layer, tile_idx, kp, seq_cols,
         )
 
-    t_end = block_tiles(seq_blk_idx)
+    def heads_of(dst):
+        """This heads block's heads of rows of the output."""
+        if num_heads_blks == 1:
+            return dst
+        return dst.at[:, pl.ds(heads_blk_idx * num_q_heads_per_blk,
+                               num_q_heads_per_blk)]
 
-    def start_parity():
-        """Double-buffer parity at this program's first tile: the total
-        tile-loop trip count of every EARLIER grid program, mod 2.
+    def out_copies(b_blk):
+        """A live block's rows of ``o_buf``, each to its own row of the
+        output."""
+        return [
+            pltpu.make_async_copy(
+                o_buf.at[pl.ds(s, 1)],
+                heads_of(o_hbm_ref.at[pl.ds(seq_row(b_blk * s_blk + s), 1)]),
+                o_sems.at[0])
+            for s in range(s_blk)
+        ]
 
-        Derived arithmetically instead of carrying a mutable scalar-
-        prefetch ref across programs (the general kernel's
-        ``seq_buf_idx`` trick): parity is a pure function of the grid
-        position and the scalar prefetches, which also holds in
-        interpret mode, where cross-program scalar mutations do not
-        persist."""
-
-        def add_iters(blk_idx, acc):
-            return acc + block_tiles(blk_idx)
-
-        before = lax.fori_loop(0, seq_blk_idx, add_iters, jnp.int32(0))
-        if num_heads_blks > 1:
-            per_heads_blk = lax.fori_loop(
-                0, num_seq_blks, add_iters, jnp.int32(0)
-            )
-            before = before + heads_blk_idx * per_heads_blk
-        return lax.rem(before, 2)
+    def zeros_copy(b_blk):
+        """Zeros to a dead block's rows of the output, which are the
+        block's own places (``_row_at``)."""
+        return pltpu.make_async_copy(
+            zero_buf, heads_of(o_hbm_ref.at[pl.ds(b_blk * s_blk, s_blk)]),
+            o_sems.at[1])
 
     @pl.when(heads_blk_idx + seq_blk_idx == 0)
-    def prefetch_first_tile():
+    def first_program():
         make_tile_copy(0, 0, jnp.int32(0), 0).start()
+        zero_buf[...] = jnp.zeros(zero_buf.shape, zero_buf.dtype)
 
-    def next_prefetch_ids(tile_idx):
-        """Grid-order successor of (heads_blk, seq_blk, tile): next tile
-        in this block, else the next block's first tile, else the next
-        heads block's first block (mirrors the general kernel's
-        cross-program chain)."""
-        nt = tile_idx + 1
-        last_tile = nt >= t_end
-        nb0 = seq_blk_idx + 1
-        wrap = nb0 >= num_seq_blks
-        nb = lax.select(
-            last_tile, lax.select(wrap, 0, nb0), seq_blk_idx
-        )
-        nh = lax.select(
-            jnp.logical_and(last_tile, wrap),
-            heads_blk_idx + 1,
-            heads_blk_idx,
-        )
-        nt = lax.select(last_tile, 0, nt)
-        return nh, nb, nt
+    @pl.when(seq_blk_idx >= num_live_blks)
+    def dead_block():
+        # Every row of the block is dead: no copy of keys is started or
+        # awaited and no tile runs. Its rows of the output are written
+        # zeros (finite, as the rows of a live block past the live count
+        # come out), and the last program awaits those writes.
+        zeros_copy(seq_blk_idx).start()
 
-    # Tile-invariant geometry: the block-diagonal sequence mask and the
-    # per-column/-row context lengths of this block's sequences.
-    kv_len_blk = [
-        seq_kv_len(seq_blk_idx * s_blk + s) for s in range(s_blk)
-    ]
-    rows_iota = lax.broadcasted_iota(jnp.int32, (sg, skv), 0)
-    cols_iota = lax.broadcasted_iota(jnp.int32, (sg, skv), 1)
-    same_seq = (rows_iota // g) == (cols_iota // kv_tile)
-    col_off = cols_iota % kv_tile  # position offset within the seq tile
-    kv_len_cols = jnp.concatenate(
-        [
-            kv_len_blk[s] * jnp.ones((1, kv_tile), jnp.int32)
-            for s in range(s_blk)
-        ],
-        axis=1,
-    )  # [1, SKV]
-    kv_len_rows = jnp.concatenate(
-        [
-            kv_len_blk[s] * jnp.ones((kv_tile, 1), jnp.int32)
-            for s in range(s_blk)
-        ],
-        axis=0,
-    )  # [SKV, 1]
-    kv_row_off = lax.broadcasted_iota(jnp.int32, (skv, 1), 0) % kv_tile
+    @pl.when(seq_blk_idx < num_live_blks)
+    def live_block():
+        """One live sequence block: its tiles from 0 to its longest row's
+        last, then its rows' outputs sent to their own rows."""
+        t_end = block_tiles(seq_blk_idx)
 
-    # Per-kv-head query rows [S*G, D]; row r belongs to sequence r // g.
-    q_heads = [
-        fold_on_2nd_minor(q_ref[:, i * g : (i + 1) * g, :])
-        for i in range(num_kv_heads_per_blk)
-    ]
+        def start_parity():
+            """Double-buffer parity at this program's first tile: the total
+            tile-loop trip count of every EARLIER live program, mod 2.
 
-    def tile_body(tile_idx, carry):
-        buf_idx, head_states = carry
-        nh, nb, nt = next_prefetch_ids(tile_idx)
+            Derived arithmetically instead of carrying a mutable scalar-
+            prefetch ref across programs (the general kernel's
+            ``seq_buf_idx`` trick): parity is a pure function of the grid
+            position and the scalar prefetches, which also holds in
+            interpret mode, where cross-program scalar mutations do not
+            persist."""
 
-        @pl.when(nh < num_heads_blks)
-        def prefetch_next_tile():
-            make_tile_copy(nh, nb, nt, 1 - buf_idx).start()
+            def add_iters(blk_idx, acc):
+                return acc + block_tiles(blk_idx)
 
-        kv_buf = make_tile_copy(
-            heads_blk_idx, seq_blk_idx, tile_idx, buf_idx
-        ).wait()  # [S*KP, page_size, rows, lanes]
+            before = lax.fori_loop(0, seq_blk_idx, add_iters, jnp.int32(0))
+            if num_heads_blks > 1:
+                per_heads_blk = lax.fori_loop(
+                    0, num_live_blks, add_iters, jnp.int32(0)
+                )
+                before = before + heads_blk_idx * per_heads_blk
+            return lax.rem(before, 2)
 
-        # Context positions of this tile's columns and the combined mask:
-        # block-diagonal x causal (pos < kv_len, q sits at kv_len - 1).
-        # Dead rows have kv_len 0 => fully masked.
-        pos = tile_idx * kv_tile + col_off  # [SG, SKV]
-        keep = same_seq & (pos < kv_len_cols)
-        # K/V rows past the context are DMA'd garbage; zero them so the
-        # contraction stays NaN-free.
-        kv_valid = (
-            tile_idx * kv_tile + kv_row_off
-        ) < kv_len_rows  # [SKV, 1]
-
-        if not packed:
-            kv_ref = kv_buf.reshape(
-                skp * page_size * num_combined_kv_heads_per_blk, head_dim
+        def next_prefetch_ids(tile_idx):
+            """Successor of (heads_blk, seq_blk, tile) in the walk: next tile
+            in this block, else the next LIVE block's first tile, else the
+            next heads block's first block (mirrors the general kernel's
+            cross-program chain)."""
+            nt = tile_idx + 1
+            last_tile = nt >= t_end
+            nb0 = seq_blk_idx + 1
+            wrap = nb0 >= num_live_blks
+            nb = lax.select(
+                last_tile, lax.select(wrap, 0, nb0), seq_blk_idx
             )
-            kv_packing = _dtype_packing(kv_ref.dtype)
-            kv_load_step = max(1, kv_packing // 2)
-        else:
-            kv_ref = None
-            kv_load_step = 1
-        new_states = list(head_states)
-        for chunk_idx in range(0, num_kv_heads_per_blk, kv_load_step):
-            if kv_ref is not None:
-                k_list, v_list = strided_load_kv(
-                    kv_ref, chunk_idx * 2, num_combined_kv_heads_per_blk
+            nh = lax.select(
+                jnp.logical_and(last_tile, wrap),
+                heads_blk_idx + 1,
+                heads_blk_idx,
+            )
+            nt = lax.select(last_tile, 0, nt)
+            return nh, nb, nt
+
+        # Tile-invariant geometry: the block-diagonal sequence mask and the
+        # per-column/-row context lengths of this block's sequences.
+        kv_len_blk = [
+            seq_kv_len(seq_blk_idx * s_blk + s) for s in range(s_blk)
+        ]
+        rows_iota = lax.broadcasted_iota(jnp.int32, (sg, skv), 0)
+        cols_iota = lax.broadcasted_iota(jnp.int32, (sg, skv), 1)
+        same_seq = (rows_iota // g) == (cols_iota // kv_tile)
+        col_off = cols_iota % kv_tile  # position offset within the seq tile
+        kv_len_cols = jnp.concatenate(
+            [
+                kv_len_blk[s] * jnp.ones((1, kv_tile), jnp.int32)
+                for s in range(s_blk)
+            ],
+            axis=1,
+        )  # [1, SKV]
+        kv_len_rows = jnp.concatenate(
+            [
+                kv_len_blk[s] * jnp.ones((kv_tile, 1), jnp.int32)
+                for s in range(s_blk)
+            ],
+            axis=0,
+        )  # [SKV, 1]
+        kv_row_off = lax.broadcasted_iota(jnp.int32, (skv, 1), 0) % kv_tile
+
+        # The block's rows of q, each fetched from its own row of the
+        # step, and per kv head the query rows [S*G, D]; row r belongs to
+        # sequence r // g.
+        for s in range(s_blk):
+            q_buf[s] = q_refs[s][...]
+        q_heads = [
+            fold_on_2nd_minor(q_buf[:, i * g : (i + 1) * g, :])
+            for i in range(num_kv_heads_per_blk)
+        ]
+
+        def tile_body(tile_idx, carry):
+            buf_idx, head_states = carry
+            nh, nb, nt = next_prefetch_ids(tile_idx)
+
+            @pl.when(nh < num_heads_blks)
+            def prefetch_next_tile():
+                make_tile_copy(nh, nb, nt, 1 - buf_idx).start()
+
+            kv_buf = make_tile_copy(
+                heads_blk_idx, seq_blk_idx, tile_idx, buf_idx
+            ).wait()  # [S*KP, page_size, rows, lanes]
+
+            # Context positions of this tile's columns and the combined mask:
+            # block-diagonal x causal (pos < kv_len, q sits at kv_len - 1).
+            # Dead rows have kv_len 0 => fully masked.
+            pos = tile_idx * kv_tile + col_off  # [SG, SKV]
+            keep = same_seq & (pos < kv_len_cols)
+            # K/V rows past the context are DMA'd garbage; zero them so the
+            # contraction stays NaN-free.
+            kv_valid = (
+                tile_idx * kv_tile + kv_row_off
+            ) < kv_len_rows  # [SKV, 1]
+
+            if not packed:
+                kv_ref = kv_buf.reshape(
+                    skp * page_size * num_combined_kv_heads_per_blk, head_dim
                 )
+                kv_packing = _dtype_packing(kv_ref.dtype)
+                kv_load_step = max(1, kv_packing // 2)
             else:
-                # Packed [.., KH, 2D]: K/V are the lane halves of one
-                # 128-lane row.
-                rows = (kv_buf[:, chunk_idx, :, :] if head_major
-                        else kv_buf[:, :, chunk_idx, :])
-                k_list = [rows[..., :head_dim].reshape(-1, head_dim)]
-                v_list = [rows[..., head_dim:].reshape(-1, v_dim)]
-            for step_idx in range(kv_load_step):
-                k = k_list[step_idx]
-                v = v_list[step_idx]
-                if k_scale is not None:
-                    k = (k.astype(jnp.float32) * k_scale).astype(
-                        q_ref.dtype
+                kv_ref = None
+                kv_load_step = 1
+            new_states = list(head_states)
+            for chunk_idx in range(0, num_kv_heads_per_blk, kv_load_step):
+                if kv_ref is not None:
+                    k_list, v_list = strided_load_kv(
+                        kv_ref, chunk_idx * 2, num_combined_kv_heads_per_blk
                     )
-                if v_scale is not None:
-                    v = (v.astype(jnp.float32) * v_scale).astype(
-                        q_ref.dtype
+                else:
+                    # Packed [.., KH, 2D]: K/V are the lane halves of one
+                    # 128-lane row.
+                    rows = (kv_buf[:, chunk_idx, :, :] if head_major
+                            else kv_buf[:, :, chunk_idx, :])
+                    k_list = [rows[..., :head_dim].reshape(-1, head_dim)]
+                    v_list = [rows[..., head_dim:].reshape(-1, v_dim)]
+                for step_idx in range(kv_load_step):
+                    k = k_list[step_idx]
+                    v = v_list[step_idx]
+                    if k_scale is not None:
+                        k = (k.astype(jnp.float32) * k_scale).astype(
+                            q_buf.dtype
+                        )
+                    if v_scale is not None:
+                        v = (v.astype(jnp.float32) * v_scale).astype(
+                            q_buf.dtype
+                        )
+                    k = jnp.where(kv_valid, k.astype(jnp.float32), 0.0).astype(
+                        k.dtype
                     )
-                k = jnp.where(kv_valid, k.astype(jnp.float32), 0.0).astype(
-                    k.dtype
-                )
-                v = jnp.where(kv_valid, v.astype(jnp.float32), 0.0).astype(
-                    v.dtype
-                )
-                kv_head_idx = chunk_idx + step_idx
-                # ONE 2D cross-product contraction for the whole block;
-                # the block-diagonal mask kills cross-sequence terms.
-                s_qk = (
-                    jnp.einsum(
-                        "nd,md->nm", q_heads[kv_head_idx], k,
-                        preferred_element_type=jnp.float32,
+                    v = jnp.where(kv_valid, v.astype(jnp.float32), 0.0).astype(
+                        v.dtype
                     )
-                    * sm_scale
-                )
-                if soft_cap is not None:
-                    s_qk = soft_cap * jnp.tanh(s_qk / soft_cap)
-                # Masked entries become a CONSTANT floor and their
-                # probabilities are zeroed explicitly. Unlike the general
-                # kernel (whose per-seq loop never visits a tile fully
-                # past a sequence's context), a sequence here runs every
-                # tile of its BLOCK — additive masking would let the raw
-                # score spread of a fully-masked tile leak into m/l.
-                s_qk = jnp.where(keep, s_qk, mask_value)
-                m_prev, l_prev, acc_prev = new_states[kv_head_idx]
-                m_curr = jnp.max(s_qk, axis=1, keepdims=True)
-                m_next = jnp.maximum(m_prev, m_curr)
-                alpha = jnp.exp(m_prev - m_next)
-                p = jnp.where(keep, jnp.exp(s_qk - m_next), 0.0)
-                l_next = alpha * l_prev + jnp.sum(
-                    p, axis=1, keepdims=True
-                )
-                acc_next = alpha * acc_prev + jnp.dot(
-                    p, v, preferred_element_type=jnp.float32
-                )
-                new_states[kv_head_idx] = (m_next, l_next, acc_next)
-        return 1 - buf_idx, tuple(new_states)
+                    kv_head_idx = chunk_idx + step_idx
+                    # ONE 2D cross-product contraction for the whole block;
+                    # the block-diagonal mask kills cross-sequence terms.
+                    s_qk = (
+                        jnp.einsum(
+                            "nd,md->nm", q_heads[kv_head_idx], k,
+                            preferred_element_type=jnp.float32,
+                        )
+                        * sm_scale
+                    )
+                    if soft_cap is not None:
+                        s_qk = soft_cap * jnp.tanh(s_qk / soft_cap)
+                    # Masked entries become a CONSTANT floor and their
+                    # probabilities are zeroed explicitly. Unlike the general
+                    # kernel (whose per-seq loop never visits a tile fully
+                    # past a sequence's context), a sequence here runs every
+                    # tile of its BLOCK — additive masking would let the raw
+                    # score spread of a fully-masked tile leak into m/l. A
+                    # tile that holds none of a row's keys leaves its state
+                    # as it was (alpha 1, p 0), so a row's output does not
+                    # depend on the rows it shares a block with.
+                    s_qk = jnp.where(keep, s_qk, mask_value)
+                    m_prev, l_prev, acc_prev = new_states[kv_head_idx]
+                    m_curr = jnp.max(s_qk, axis=1, keepdims=True)
+                    m_next = jnp.maximum(m_prev, m_curr)
+                    alpha = jnp.exp(m_prev - m_next)
+                    p = jnp.where(keep, jnp.exp(s_qk - m_next), 0.0)
+                    l_next = alpha * l_prev + jnp.sum(
+                        p, axis=1, keepdims=True
+                    )
+                    acc_next = alpha * acc_prev + jnp.dot(
+                        p, v, preferred_element_type=jnp.float32
+                    )
+                    new_states[kv_head_idx] = (m_next, l_next, acc_next)
+            return 1 - buf_idx, tuple(new_states)
 
-    # A sink is a row's state before its first key: a maximum of its
-    # logit and a denominator of exp(0).
-    init_states = tuple(
-        (
-            jnp.full((sg, 1), mask_value, jnp.float32) if sink_ref is None
-            else sink_ref[i][:, :1],  # m
-            jnp.full((sg, 1), 0.0 if sink_ref is None else 1.0,
-                     jnp.float32),  # l
-            jnp.zeros((sg, v_dim), jnp.float32),  # acc
+        # A sink is a row's state before its first key: a maximum of its
+        # logit and a denominator of exp(0).
+        init_states = tuple(
+            (
+                jnp.full((sg, 1), mask_value, jnp.float32) if sink_ref is None
+                else sink_ref[i][:, :1],  # m
+                jnp.full((sg, 1), 0.0 if sink_ref is None else 1.0,
+                         jnp.float32),  # l
+                jnp.zeros((sg, v_dim), jnp.float32),  # acc
+            )
+            for i in range(num_kv_heads_per_blk)
         )
-        for i in range(num_kv_heads_per_blk)
-    )
-    _, final_states = lax.fori_loop(
-        0, t_end, tile_body, (start_parity(), init_states)
-    )
+        _, final_states = lax.fori_loop(
+            0, t_end, tile_body, (start_parity(), init_states)
+        )
 
-    outs = []
-    for kv_head_idx in range(num_kv_heads_per_blk):
-        _, l, acc = final_states[kv_head_idx]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        outs.append((acc / l_safe).reshape(s_blk, g, v_dim))
-    o_ref[...] = jnp.concatenate(outs, axis=1).astype(q_ref.dtype)
+        outs = []
+        for kv_head_idx in range(num_kv_heads_per_blk):
+            _, l, acc = final_states[kv_head_idx]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            outs.append((acc / l_safe).reshape(s_blk, g, v_dim))
+        # The rows leave through ONE buffer: the live program before this one
+        # has had this program's tiles to send its own.
+        sent = out_copies(seq_blk_idx)
+
+        @pl.when(heads_blk_idx + seq_blk_idx > 0)
+        def await_previous_rows():
+            for c in sent:
+                c.wait()
+
+        o_buf[...] = jnp.concatenate(outs, axis=1).astype(o_buf.dtype)
+        for c in sent:
+            c.start()
+
+        @pl.when(jnp.logical_and(heads_blk_idx == num_heads_blks - 1,
+                                 seq_blk_idx == num_live_blks - 1))
+        def await_last_rows():
+            for c in sent:
+                c.wait()
+
+    @pl.when(jnp.logical_and(heads_blk_idx == num_heads_blks - 1,
+                             seq_blk_idx == num_seq_blks - 1))
+    def await_dead_rows():
+        def await_block(_, carry):
+            zeros_copy(seq_blk_idx).wait()  # (equal sizes: one for any)
+            return carry
+
+        lax.fori_loop(
+            0, num_heads_blks * (num_seq_blks - num_live_blks), await_block,
+            0)
 
 
 def _validate(q, kv_pages, kv_lens, page_indices, num_seqs, head_major):
@@ -427,6 +537,48 @@ def _validate(q, kv_pages, kv_lens, page_indices, num_seqs, head_major):
         raise ValueError(f"{num_q_heads=} % {num_kv_heads=} != 0")
 
 
+def decode_tiling(num_rows, page_size, pages_per_seq,
+                  num_seqs_per_block=None, num_kv_pages_per_block=None):
+    """(sequences a block, pages a tile of one sequence) the kernel runs a
+    call of these shapes with: 4 rows, and a tile of some 128 tokens a row
+    (big enough to shape the contraction, small enough that short decode
+    contexts don't over-fetch), unless the caller names others."""
+    if num_seqs_per_block is None:
+        num_seqs_per_block = 4
+    if num_kv_pages_per_block is None:
+        num_kv_pages_per_block = max(1, 128 // page_size)
+    return (max(1, min(num_seqs_per_block, num_rows)),
+            max(1, min(num_kv_pages_per_block, pages_per_seq)))
+
+
+def decode_row_order(kv_lens: jax.Array, num_seqs: jax.Array) -> jax.Array:
+    """The order the kernel's walk serves a step's rows in, i32[R]: the
+    live rows by context, longest first, so that the four rows of a block
+    end together; then the dead rows, each in its own place. Computed once
+    a step, beside ``kv_lens``. Any permutation of the live rows is a
+    valid order (each row's output is the same under all of them); this
+    one runs the fewest tiles."""
+    rows = jnp.arange(kv_lens.shape[0], dtype=jnp.int32)
+    key = jnp.where(rows < num_seqs[0], -kv_lens, 1)
+    return jnp.argsort(key, stable=True).astype(jnp.int32)
+
+
+def decode_tile_counts(kv_lens, num_seqs: int, *, page_size: int,
+                       num_seqs_per_block: int,
+                       num_kv_pages_per_block: int) -> tuple[int, float]:
+    """Tiles (a block of rows x the pages of one tile) a call RUNS on a
+    heads block under ``decode_row_order``, and the LEAST its bytes need:
+    every live row's own ``ceil(context / tile)``, summed, over the rows a
+    block. NumPy, on the host: the runner's count of the kernel's work a
+    decode-only step (``vllm:attn_decode_tiles_total``). ``run`` mirrors
+    the kernel's ``block_tiles`` over its live blocks."""
+    tile = page_size * num_kv_pages_per_block
+    own = -(-np.sort(np.asarray(kv_lens[:num_seqs], np.int64))[::-1] // tile)
+    longest = own[::num_seqs_per_block]  # of each live block
+    run = int(np.maximum(longest, 1).sum()) if num_seqs else 1
+    return run, float(own.sum()) / num_seqs_per_block
+
+
 @functools.partial(
     jax.jit,
     static_argnames=[
@@ -442,6 +594,7 @@ def decode_paged_attention(
     kv_lens: jax.Array,  # i32[R], context incl. the current token
     page_indices: jax.Array,  # i32[R, pages_per_seq]
     num_seqs: jax.Array,  # i32[1]
+    order: jax.Array | None = None,  # i32[R], ``decode_row_order``
     *,
     sm_scale: float = 1.0,
     soft_cap: float | None = None,
@@ -470,7 +623,13 @@ def decode_paged_attention(
     Semantically identical to ``ragged_paged_attention`` restricted to
     ``q_len == 1`` for every row (``cu_q_lens == arange``); returns
     ``out [R, H, D]``. See the module docstring for the pipelining
-    design. Rows at or beyond ``num_seqs[0]`` produce finite garbage.
+    design. Rows at or beyond ``num_seqs[0]`` come back as zeros.
+
+    ``order`` is the step's ``decode_row_order``: position ``p`` of the
+    kernel's walk serves row ``order[p]`` (only its first ``num_seqs[0]``
+    entries, a permutation of the live rows, are read). q goes in and the
+    output comes back in the step's own row order whatever it says, and a
+    row's output does not depend on it; ``None`` walks the rows as given.
     """
     _validate(q, kv_pages, kv_lens, page_indices, num_seqs, head_major)
     if mask_value is None:
@@ -494,26 +653,36 @@ def decode_paged_attention(
         num_q_heads_per_blk = num_q_heads
         num_combined_kv_heads_per_blk = num_combined_kv_heads
 
-    if num_seqs_per_block is None:
-        num_seqs_per_block = 4
-    s_blk = max(1, min(num_seqs_per_block, num_rows))
-    if num_kv_pages_per_block is None:
-        # Target a ~128-token KV tile per sequence: big enough to shape
-        # the contraction, small enough that short decode contexts don't
-        # over-fetch.
-        num_kv_pages_per_block = max(1, 128 // page_size)
-    kp = max(1, min(num_kv_pages_per_block, pages_per_seq))
+    s_blk, kp = decode_tiling(
+        num_rows, page_size, pages_per_seq, num_seqs_per_block,
+        num_kv_pages_per_block)
+    if order is None:
+        order = jnp.arange(num_rows, dtype=jnp.int32)
+    pad = -num_rows % s_blk
+    if pad:  # whole blocks of rows: the rows added are dead
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+        kv_lens = jnp.pad(kv_lens, (0, pad))
+        page_indices = jnp.pad(page_indices, ((0, pad), (0, 0)))
+        order = jnp.concatenate([
+            order, jnp.arange(num_rows, num_rows + pad, dtype=jnp.int32)])
 
     num_heads_blks = num_q_heads // num_q_heads_per_blk
-    num_seq_blks = pl.cdiv(num_rows, s_blk)
+    num_seq_blks = (num_rows + pad) // s_blk
     grid = (num_heads_blks, num_seq_blks)
 
-    def q_index_map(heads_blk_idx, seq_blk_idx, *_):
-        return (seq_blk_idx, heads_blk_idx, 0)
+    def q_row_spec(s):
+        """Row ``s`` of a block: the row of q the walk serves there. A
+        program past the live blocks names the last live block's rows
+        again, so that nothing is fetched for it."""
 
-    q_block_spec = pl.BlockSpec(
-        (s_blk, num_q_heads_per_blk, head_dim), q_index_map
-    )
+        def index_map(heads_blk_idx, seq_blk_idx, kv_lens_ref,
+                      page_indices_ref, num_seqs_ref, layer_ref, order_ref):
+            n = num_seqs_ref[0]
+            blk = jnp.minimum(seq_blk_idx, _live_blocks(n, s_blk) - 1)
+            return _row_at(blk * s_blk + s, order_ref, n), heads_blk_idx, 0
+
+        return pl.BlockSpec((None, num_q_heads_per_blk, head_dim), index_map)
+
     kv_rows_per_blk = (
         num_combined_kv_heads_per_blk // 2
         if packed
@@ -523,12 +692,18 @@ def decode_paged_attention(
         (kv_rows_per_blk, page_size) if head_major
         else (page_size, kv_rows_per_blk)
     )
+    out_rows = (s_blk, num_q_heads_per_blk, v_dim)
     scratch_shapes = [
         pltpu.VMEM((2, s_blk * kp, *page_shape, kv_lanes), kv_pages.dtype),
         pltpu.SemaphoreType.DMA((2,)),
+        pltpu.VMEM((s_blk, num_q_heads_per_blk, head_dim), q.dtype),
+        pltpu.VMEM(out_rows, q.dtype),  # a live block's rows on their way
+        pltpu.VMEM(out_rows, q.dtype),  # zeros, the dead blocks' rows
+        pltpu.SemaphoreType.DMA((2,)),  # live rows', dead rows' writes
     ]
-    operands = [q, kv_pages]
-    in_specs = [q_block_spec, pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [q] * s_blk + [kv_pages]
+    in_specs = [q_row_spec(s) for s in range(s_blk)] + [
+        pl.BlockSpec(memory_space=pl.ANY)]
     if sinks is not None:
         # Row r of a KV head is (sequence r // g, q head r % g of its group).
         num_kv_heads = num_combined_kv_heads // 2
@@ -540,17 +715,17 @@ def decode_paged_attention(
         in_specs.append(pl.BlockSpec(
             (num_combined_kv_heads_per_blk // 2, s_blk * g, 128),
             lambda h, sb, *_: (h, 0, 0)))
-    out_block_spec = q_block_spec if v_dim == head_dim else pl.BlockSpec(
-        (s_blk, num_q_heads_per_blk, v_dim), q_index_map)
     scalar_prefetches = (
         kv_lens,
         page_indices,
         num_seqs,
         layer.astype(jnp.int32).reshape(1),
+        order,
     )
     kernel = pl.pallas_call(
         functools.partial(
             _decode_kernel,
+            s_blk=s_blk,
             sm_scale=sm_scale,
             soft_cap=soft_cap,
             mask_value=mask_value,
@@ -561,7 +736,8 @@ def decode_paged_attention(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalar_prefetches),
             in_specs=in_specs,
-            out_specs=[out_block_spec],
+            # Written by the kernel's own copies, a row to its own place.
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             grid=grid,
             scratch_shapes=scratch_shapes,
         ),
@@ -570,8 +746,8 @@ def decode_paged_attention(
             vmem_limit_bytes=vmem_limit_bytes,
         ),
         out_shape=[jax.ShapeDtypeStruct(
-            (num_rows, num_q_heads, v_dim), q.dtype)],
+            (num_rows + pad, num_q_heads, v_dim), q.dtype)],
         name=name,
         interpret=interpret,
     )
-    return kernel(*scalar_prefetches, *operands)[0]
+    return kernel(*scalar_prefetches, *operands)[0][:num_rows]

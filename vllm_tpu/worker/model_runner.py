@@ -16,6 +16,7 @@ Step dataflow:
 from __future__ import annotations
 
 import bisect
+import functools
 import time
 from contextlib import nullcontext
 from typing import Any
@@ -31,8 +32,13 @@ from vllm_tpu.core.sched_output import (
     SchedulerOutput,
 )
 from vllm_tpu.logger import init_logger
-from vllm_tpu.ops.attention import AttentionMetadata, ragged_kernel_tiling
+from vllm_tpu.ops.attention import (
+    AttentionMetadata,
+    decode_kernel_tiling,
+    ragged_kernel_tiling,
+)
 from vllm_tpu.ops.mla_kernel import count_latent_pairs, latent_blocks
+from vllm_tpu.ops.rpa_decode_kernel import decode_row_order, decode_tile_counts
 from vllm_tpu.ops.rpa_kernel import count_ragged_pairs
 from vllm_tpu.resilience.failpoints import fail_point
 from vllm_tpu.tracing import compiles, trace_span
@@ -526,6 +532,13 @@ class ModelRunner:
         # its choice of rung and of key extent). Steps that run it only.
         self.attn_ragged_pairs = {"live": 0, "computed": 0}
         self._ragged_tilings: dict[tuple[int, int], Any] = {}
+        # Tiles of the decode attention kernel, cumulative, one call (a
+        # layer's, on one heads block) a decode-only step that takes it:
+        # those its walk runs, and the least the rows' keys need
+        # (``decode_tile_counts``). run / least is what the rows of a
+        # block that end apart cost.
+        self.attn_decode_tiles = {"run": 0, "least": 0.0}
+        self._decode_tilings: dict[tuple[int, int], Any] = {}
         # Generation by diffusion over blocks, cumulative: row-passes by
         # kind, and the positions denoising passes committed. Mixture of
         # experts, cumulative: token-expert pairs over live tokens, and
@@ -616,6 +629,9 @@ class ModelRunner:
             tp_mesh=self.mesh,
             block_len=max(block_len, 1),
         )
+        if decode_only:
+            # Once a step, for every layer's call of the decode kernel.
+            md.decode_order = decode_row_order(md.seq_lens, md.num_seqs)
         top_k = take(r)
         prng_keys = jax.lax.bitcast_convert_type(
             take(2 * r).reshape(r, 2), jnp.uint32
@@ -700,25 +716,36 @@ class ModelRunner:
         return (token_ids, md, sampling, feedback, grammar_rows, logit_adjust,
                 draft_next, token_lora, plp_next, spec, dyn, blk)
 
+    @functools.cached_property
+    def _attn_call_geometry(self):
+        """(q heads, KV heads, head_dim, tokens a page) of the model's plain
+        attention call and whether its pages are the split layout, for the
+        counts of the attention kernels' work; None where the model has no
+        such call (a cache of its own making)."""
+        m = self.model
+        # A model whose pages are the split layout names the call the
+        # counts follow (its full layers'): (q heads, KV heads, the key's
+        # stored lanes, tokens a page of that group).
+        split = getattr(m, "ragged_kernel_geometry", None)
+        if split:
+            return split(self.block_size), True
+        plain = all(hasattr(m, a) for a in
+                    ("num_heads", "num_kv_heads", "head_dim")) and not (
+            hasattr(m, "kv_cache_shape") or hasattr(m, "alloc_kv_cache"))
+        if not plain:
+            return None
+        return (m.num_heads, m.num_kv_heads, m.head_dim, self.block_size), False
+
     def _count_ragged_pairs(self, q_lens, kv_lens, t_pad: int, b_pad: int):
         """Add a step's pairs to ``attn_ragged_pairs`` where its attention
         runs the ragged kernel (a model with plain K/V heads, on the chip)."""
         block_len = max(self.block_len, 1)
         key = (t_pad, b_pad)
         if key not in self._ragged_tilings:
-            m = self.model
-            plain = all(hasattr(m, a) for a in
-                        ("num_heads", "num_kv_heads", "head_dim")) and not (
-                hasattr(m, "kv_cache_shape") or hasattr(m, "alloc_kv_cache"))
-            # A model whose pages are the split layout names the call the
-            # count follows (its full layers'): (q heads, KV heads, the
-            # key's stored lanes, tokens a page of that group).
-            split = getattr(m, "ragged_kernel_geometry", None)
-            geometry = split(self.block_size) if split else plain and (
-                m.num_heads, m.num_kv_heads, m.head_dim, self.block_size)
+            geometry, split = self._attn_call_geometry or (None, False)
             self._ragged_tilings[key] = geometry and (ragged_kernel_tiling(
-                *geometry, m.dtype, self.kv_cache.dtype, t_pad, b_pad,
-                block_len=block_len, tp_size=self.tp_size, split=bool(split),
+                *geometry, self.model.dtype, self.kv_cache.dtype, t_pad,
+                b_pad, block_len=block_len, tp_size=self.tp_size, split=split,
             ), geometry[3])
         tiling, page_size = self._ragged_tilings[key] or (None, 0)
         if tiling:
@@ -728,6 +755,28 @@ class ModelRunner:
             )
             self.attn_ragged_pairs["live"] += live
             self.attn_ragged_pairs["computed"] += computed
+
+    def _count_decode_tiles(self, kv_lens, r_pad: int, b_pad: int):
+        """Add a decode-only step's tiles to ``attn_decode_tiles`` where its
+        plain attention call runs the decode kernel: those one call runs
+        on a heads block, and the least its rows' keys need."""
+        key = (r_pad, b_pad)
+        if key not in self._decode_tilings:
+            blocks = None
+            # (a call that states a window is the ragged kernel's)
+            if self._attn_call_geometry and getattr(
+                    self.model, "sliding_window", None) is None:
+                (_, _, head_dim, page_size), split = self._attn_call_geometry
+                blocks = decode_kernel_tiling(
+                    head_dim, page_size, r_pad, b_pad, split=split)
+            self._decode_tilings[key] = blocks and dict(
+                page_size=page_size, num_seqs_per_block=blocks[0],
+                num_kv_pages_per_block=blocks[1])
+        blocks = self._decode_tilings[key]
+        if blocks:
+            run, least = decode_tile_counts(kv_lens, len(kv_lens), **blocks)
+            self.attn_decode_tiles["run"] += run
+            self.attn_decode_tiles["least"] += least
 
     def _build_tree_metadata(self, md, spec, t_pad: int, r_pad: int):
         """In-jit tree-verify views (host prep stays the chain layout).
@@ -2431,7 +2480,9 @@ class ModelRunner:
         self.step_tokens_by_kind[kind] += t_live
         self.attn_context_tokens_by_kind[kind] += int(
             seq_lens[:r_live].sum())
-        if not flags["decode_only"]:
+        if flags["decode_only"]:
+            self._count_decode_tiles(seq_lens[:r_live], r_pad, b_pad)
+        else:
             self._count_ragged_pairs(q_lens, seq_lens[:r_live], t_pad, b_pad)
         # launch_sampled_tokens counts REALIZED emissions — finalize
         # accumulates the per-row token runs it actually folds, which is
@@ -2617,6 +2668,8 @@ class ModelRunner:
             num_seqs=md.num_seqs,
             state_slots=md.state_slots,
             decode_only=decode_ok,
+            # (the rows' contexts grow by a token each: the order holds)
+            decode_order=md.decode_order,
             tp_mesh=md.tp_mesh,
         )
 
