@@ -1,11 +1,10 @@
 """The kernel escape hatches pick their path when the engine is built.
 
-``enable_sampler_kernel``, ``enable_decode_attention`` and
-``disable_dynamic_decode`` are read once, at construction; nothing flips
-them afterwards. Each hatch must route every step to the other path (the
-runner's and the scheduler's own counters say which one ran) and serve the
-tokens the default engine serves, greedy and seeded alike. Tiny model, CPU,
-Pallas kernels in interpret mode.
+``enable_sampler_kernel`` and ``enable_decode_attention`` are read once,
+at construction; nothing flips them afterwards. Each hatch must route
+every step to the other path (the runner's own counters say which one ran)
+and serve the tokens the default engine serves, greedy and seeded alike.
+Tiny model, CPU, Pallas kernels in interpret mode.
 """
 
 from __future__ import annotations
@@ -31,20 +30,18 @@ def _serve(ckpt, **hatch):
     llm = LLM(
         model=ckpt, dtype="float32", max_model_len=128, block_size=16,
         num_gpu_blocks_override=64, max_num_seqs=4,
-        max_num_batched_tokens=128, num_decode_steps=8, **hatch,
+        max_num_batched_tokens=128, **hatch,
     )
     rng = np.random.default_rng(7)
     prompts = [{"prompt_token_ids": rng.integers(5, 120, size=n).tolist()}
                for n in (7, 4)]
     tokens = [[o.outputs[0].token_ids for o in llm.generate(prompts, sp)]
               for sp in (GREEDY, SEEDED)]
-    core = llm.llm_engine.engine_core.engine_core
-    runner = core.executor.worker.runner
+    runner = llm.llm_engine.engine_core.engine_core.executor.worker.runner
     ran = {
         "sampler_kernel": runner.sampler_kernel_launches > 0,
         "sampler_fallback": runner.sampler_fallback_rows > 0,
         "decode_attention": runner.decode_only_launches > 0,
-        "dynamic_decode": bool(core.scheduler.decode_len_hist),
     }
     return tokens, ran
 
@@ -54,15 +51,14 @@ def default(ckpt):
     tokens, ran = _serve(ckpt)
     # The default engine takes every fast path this file switches off.
     assert ran == {"sampler_kernel": True, "sampler_fallback": False,
-                   "decode_attention": True, "dynamic_decode": True}
+                   "decode_attention": True}
     return tokens
 
 
 @pytest.mark.parametrize("hatch, off, on", [
     ({"enable_sampler_kernel": False}, "sampler_kernel", "sampler_fallback"),
     ({"enable_decode_attention": False}, "decode_attention", None),
-    ({"disable_dynamic_decode": True}, "dynamic_decode", None),
-], ids=["sampler_kernel", "decode_attention", "dynamic_decode"])
+], ids=["sampler_kernel", "decode_attention"])
 def test_hatch_selects_the_other_path_and_serves_the_same_tokens(
         ckpt, default, hatch, off, on):
     tokens, ran = _serve(ckpt, **hatch)
@@ -70,3 +66,19 @@ def test_hatch_selects_the_other_path_and_serves_the_same_tokens(
     if on is not None:
         assert ran[on]
     assert tokens == default
+
+
+@pytest.mark.parametrize("argv", [
+    ["--num-decode-steps", "4"],
+    ["--max-decode-steps-per-launch", "16"],
+    ["--disable-dynamic-decode"],
+], ids=lambda argv: argv[0])
+def test_a_removed_flag_is_refused_by_name(argv, capsys):
+    """The flags of the decode drivers that left in PR 49 are errors of the
+    `serve` command's parser, not options it accepts and ignores."""
+    from vllm_tpu.entrypoints.cli.main import main
+
+    with pytest.raises(SystemExit) as exc:
+        main(["serve", "some-model", *argv])
+    assert exc.value.code == 2
+    assert f"unrecognized arguments: {argv[0]}" in capsys.readouterr().err

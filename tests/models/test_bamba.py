@@ -129,18 +129,20 @@ def test_bamba_multi_request_slots(tiny_bamba):
     assert len(runner._state_slot_of) <= 1
 
 
-def test_bamba_multi_step_decode_parity(tiny_bamba):
-    """K-step in-jit decode threads SSM state between chained positions
-    (state_slots ride _single_pos_metadata)."""
+def test_bamba_async_pipeline_parity(tiny_bamba):
+    """A row decoding steps ahead of its tokens reads and writes its SSM
+    state slot in step order: the async engine serves what sync serves."""
     from vllm_tpu import SamplingParams
 
     rng = np.random.default_rng(4)
-    prompt = {"prompt_token_ids": rng.integers(5, 120, size=13).tolist()}
+    prompts = [{"prompt_token_ids": rng.integers(5, 120, size=n).tolist()}
+               for n in (13, 6)]
     sp = SamplingParams(temperature=0.0, max_tokens=8, ignore_eos=True)
-    ref = _mk(tiny_bamba).generate([prompt], sp)[0].outputs[0].token_ids
-    got = _mk(tiny_bamba, num_decode_steps=2).generate(
-        [prompt], sp
-    )[0].outputs[0].token_ids
+    ref, got = (
+        [o.outputs[0].token_ids
+         for o in _mk(tiny_bamba, async_scheduling=mode).generate(prompts, sp)]
+        for mode in (False, True)
+    )
     assert got == ref
 
 

@@ -225,10 +225,9 @@ def test_decode_kernel_soft_cap_and_layer_indexing():
     )
 
 
-def test_decode_kernel_multi_step_chain():
-    """num_decode_steps > 1 shape: K successive single-position calls
-    with K/V appended between steps (what ``_single_pos_metadata``
-    produces inside the multi-step decode loop) each match the
+def test_decode_kernel_successive_positions():
+    """Successive single-position calls with K/V appended between them
+    (what every decode-only step after another is) each match the
     reference."""
     import dataclasses
 

@@ -724,7 +724,6 @@ def engine_config(tmp_path, **kw):
 
 @pytest.mark.parametrize("kw,names", [
     (dict(speculative_method="ngram", num_speculative_tokens=2), "speculative decoding"),
-    (dict(num_decode_steps=4), "num_decode_steps"),
     (dict(max_model_len=126), "multiple of the block length"),
 ])
 def test_a_configuration_that_does_not_compose_is_refused_by_mechanism(tmp_path, kw, names):

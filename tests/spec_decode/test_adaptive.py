@@ -390,25 +390,6 @@ def test_spec_adaptive_requires_spec():
         ).create_engine_config()
 
 
-def test_spec_rejects_multi_step_and_names_dynamic_flag():
-    """Satellite: the config-time spec x multi-step error tells the
-    operator about --disable-dynamic-decode, and that flag exists."""
-    from vllm_tpu.engine.arg_utils import EngineArgs
-
-    with pytest.raises(ValueError, match="--disable-dynamic-decode"):
-        EngineArgs(
-            model="dummy-llama", speculative_method="ngram",
-            num_speculative_tokens=3, num_decode_steps=4,
-        ).create_engine_config()
-    cfg = EngineArgs(
-        model="dummy-llama", disable_dynamic_decode=True
-    ).create_engine_config()
-    assert cfg.scheduler_config.disable_dynamic_decode is True
-    parser = EngineArgs.add_cli_args(__import__("argparse").ArgumentParser())
-    args = parser.parse_args(["--disable-dynamic-decode"])
-    assert args.disable_dynamic_decode is True
-
-
 def test_adaptive_watermarks_validated_at_config_time():
     from vllm_tpu.engine.arg_utils import EngineArgs
 

@@ -72,8 +72,6 @@ REQUIRED_DECODE_METRICS = {
     "vllm:decode_batch_ratio",
     "vllm:sampled_tokens_per_launch",
     "vllm:prep_fallback_rows_total",
-    "vllm:decode_steps_per_launch",
-    "vllm:decode_early_exits_total",
 }
 
 # Documented in the README ("Metrics"); the benchmark's per-layer readers

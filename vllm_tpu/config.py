@@ -267,26 +267,6 @@ class SchedulerConfig:
     # the depth-capped penalty-row exposure. See README knobs table.
     async_pipeline_depth: int = 3
     enable_chunked_prefill: bool = True
-    # In-jit multi-step decode (reference analog: vLLM v0
-    # --num-scheduler-steps): when every scheduled request is a pure
-    # decode, run up to N sequential decode iterations inside ONE jitted
-    # launch, emitting N tokens per request per host round trip. Exact for
-    # greedy and seeded sampling; steps carrying prefill, spec, pooling,
-    # grammar, logprobs, or logits processors fall back to 1.
-    num_decode_steps: int = 1
-    # Device-resident dynamic multi-step decode: when a multi-step launch
-    # is eligible (num_decode_steps > 1 and every row passes the same
-    # plain-decode gate as fixed K), the jitted step runs a lax.while_loop
-    # with ON-DEVICE stop detection — per-row eos/stop-token-id match
-    # (gated on min_tokens) and per-row max_tokens / max_model_len bounds
-    # — exiting early once every row has finished. One launch then emits
-    # up to this many tokens per row instead of exactly num_decode_steps.
-    # This is the host-interaction budget: larger values amortize more
-    # per-launch overhead but lengthen the worst-case latency to the next
-    # host touch (streaming chunks, aborts). 0 disables the dynamic loop
-    # (fixed-K unrolled chain only); the VLLM_TPU_DISABLE_DYNAMIC_DECODE
-    # env is the no-restart escape hatch for the same switch.
-    max_decode_steps_per_launch: int = 128
     # Decode-specialized attention: batches where every row is a pure
     # decode (one query token) dispatch to the sequence-pipelined kernel
     # (ops/rpa_decode_kernel.py) instead of the general ragged kernel.
@@ -317,11 +297,6 @@ class SchedulerConfig:
     # flash kernel unless the shared prefix dominates the context.
     enable_cascade_attention: bool = False
     policy: Literal["fcfs", "priority"] = "fcfs"
-    # Hard off-switch for the dynamic lax.while_loop decode path (the
-    # fixed-K unrolled chain still runs when num_decode_steps > 1). CLI
-    # spelling --disable-dynamic-decode; the
-    # VLLM_TPU_DISABLE_DYNAMIC_DECODE env is the no-restart equivalent.
-    disable_dynamic_decode: bool = False
     # Adaptive speculation (copied from SpeculativeConfig at
     # EngineConfig.finalize — the controller lives scheduler-side and the
     # scheduler only sees this config).
@@ -353,44 +328,10 @@ class SchedulerConfig:
     def __post_init__(self) -> None:
         if self.max_num_batched_tokens < 1:
             raise ValueError("max_num_batched_tokens must be >= 1")
-        if self.max_decode_steps_per_launch < 0:
-            raise ValueError("max_decode_steps_per_launch must be >= 0")
         if self.max_preemptions_per_step < 0:
             raise ValueError("max_preemptions_per_step must be >= 0")
         if self.max_preemptions_per_request < 0:
             raise ValueError("max_preemptions_per_request must be >= 0")
-
-    def validate_decode_steps(
-        self, *, spec_enabled: bool, needs_mrope: bool = False
-    ) -> None:
-        """Single source of truth for multi-step-decode compatibility.
-
-        Called once at ``EngineConfig.finalize`` (config-time facts) and
-        again by the worker after model load (m-rope is a trait of the
-        resolved model class, unknowable at config time). Both call sites
-        share this one implementation so the checks and messages cannot
-        drift apart.
-        """
-        if self.num_decode_steps <= 1:
-            return
-        if spec_enabled:
-            raise ValueError(
-                "num_decode_steps > 1 is incompatible with speculative "
-                "decoding: spec already emits multiple tokens per launch, "
-                "and its in-jit draft/verify chain owns the device loop "
-                "that both fixed-K and dynamic multi-step decode would "
-                "occupy. Pass --num-decode-steps 1 (and "
-                "--disable-dynamic-decode to also pin the dynamic "
-                "while-loop path off) when enabling "
-                "--num-speculative-tokens"
-            )
-        if needs_mrope:
-            raise ValueError(
-                "m-rope models (Qwen2-VL) do not support "
-                "num_decode_steps > 1 yet (neither the unrolled decode "
-                "chain nor the dynamic lax.while_loop threads the mrope "
-                "delta across in-loop positions)"
-            )
 
 
 @dataclass
@@ -651,7 +592,6 @@ class EngineConfig:
                 "enabled (set --speculative-method and "
                 "--num-speculative-tokens)"
             )
-        sc.validate_decode_steps(spec_enabled=spec.enabled)
         pc = self.parallel_config
         if pc.engine_roles:
             from vllm_tpu.disagg.roles import parse_engine_roles

@@ -28,16 +28,6 @@ class AsyncScheduler(Scheduler):
             request.num_computed_tokens >= request.num_tokens
             and request.pooling_params is None  # pooling never samples
         ):
-            # This step samples output token(s) not yet known host-side.
-            # In-jit multi-step decode samples K per launch; the chained
-            # tokens' KV is written in-jit, so computed advances with them.
-            # Dynamic multi-step claims a per-request budget instead of a
-            # global K; update_from_output rolls back whatever the device
-            # loop did not realize.
-            k = (
-                getattr(self, "_decode_claims", {}).get(request.request_id)
-                or getattr(self, "_decode_k", 1)
-            )
-            request.num_output_placeholders += k
-            request.num_computed_tokens += k - 1
+            # This step samples an output token not yet known host-side.
+            request.num_output_placeholders += 1
             request.num_inflight_steps += 1

@@ -11,12 +11,6 @@ from typing import Any
 
 from vllm_tpu.sampling_params import SamplingParams
 
-# Dynamic multi-step decode ships each row's stop set (eos + stop token
-# ids) to the device as a fixed-width [rows, MAX_DYNAMIC_STOP_IDS] i32
-# lane, padded with -1. The scheduler routes requests with wider stop
-# sets to the fixed-K unrolled chain instead.
-MAX_DYNAMIC_STOP_IDS = 8
-
 
 @dataclass
 class NewRequestData:
@@ -103,16 +97,6 @@ class SchedulerOutput:
     # resume, so holding the slot while it waits both leaks capacity and
     # can exhaust the slot pool (running + preempted > max_num_seqs).
     preempted_req_ids: set[str] = field(default_factory=set)
-    # In-jit multi-step decode: tokens sampled per request this step.
-    num_decode_steps: int = 1
-    # Device-resident dynamic multi-step decode: when True the runner runs
-    # the lax.while_loop body with on-device stop detection instead of the
-    # fixed-K unrolled chain; decode_claims carries the per-request step
-    # budget (<= max_decode_steps_per_launch, bounded per row by
-    # max_tokens / max_model_len headroom). The realized per-row length
-    # comes back through ModelRunnerOutput.sampled_token_ids.
-    dynamic_decode: bool = False
-    decode_claims: dict[str, int] = field(default_factory=dict)
     # Adaptive speculation: when True the occupancy gate has suspended
     # drafting batch-wide — the runner skips proposer work entirely this
     # step; spec_draft_budgets carries each scheduled request's current
@@ -281,8 +265,8 @@ class SchedulerStats:
     # Decode-path observability (cumulative, attached by EngineCore from
     # the runner): jitted-step launches, launches whose batch was
     # decode-only (one token per row — sequence-pipelined kernel shape),
-    # tokens sampled across launches (tokens/launch = multi-step
-    # amortization), and step-input rows assembled by the Python loop
+    # tokens sampled across launches (several a row under speculation
+    # and diffusion), and step-input rows assembled by the Python loop
     # instead of the native fill.
     step_launches: int = 0
     decode_only_launches: int = 0
@@ -346,13 +330,6 @@ class SchedulerStats:
     # reference path (all-greedy launches count as neither).
     sampler_kernel_launches: int = 0
     sampler_fallback_rows: int = 0
-    # Dynamic multi-step decode: per-request realized step counts of
-    # dynamic launches that completed this snapshot (drained each
-    # snapshot — feeds the vllm:decode_steps_per_launch histogram), and
-    # the cumulative count of dynamic launches that exited the device
-    # loop before exhausting their claimed budget (a row stopped early).
-    decode_step_lengths: list[int] = field(default_factory=list)
-    decode_early_exits: int = 0
     # Engine-step phase durations (drained each snapshot, seconds), one
     # list a phase of STEP_PHASES — attached by EngineCore from its
     # schedule/dispatch/finalize/update sites and from the runner's

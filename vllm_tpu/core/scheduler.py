@@ -26,7 +26,6 @@ import vllm_tpu.envs as envs
 from vllm_tpu.config import CacheConfig, SchedulerConfig
 from vllm_tpu.core.kv_cache_manager import KVCacheManager
 from vllm_tpu.core.sched_output import (
-    MAX_DYNAMIC_STOP_IDS,
     BlockPass,
     CachedRequestData,
     EngineCoreOutput,
@@ -212,31 +211,6 @@ class Scheduler:
         # sampled array, so a request with in-flight tokens that MISSED that
         # step (depth cap, budget) must wait for host materialization.
         self._last_step_req_ids: set[str] = set()
-        # Device-resident dynamic multi-step decode state: whether the
-        # last schedule() chose the dynamic path, the per-request claimed
-        # step budgets of that schedule, and the hard in-flight gate — a
-        # request with a dynamic launch in flight must NOT be rescheduled
-        # until update_from_output reconciles its realized length (its
-        # true position is unknown while the device loop runs).
-        self._decode_k = 1
-        self._dynamic_decode = False
-        self._decode_claims: dict[str, int] = {}
-        self._dynamic_inflight: set[str] = set()
-        # Observability: realized per-request step counts of dynamic
-        # launches reconciled since the last stats snapshot (drained by
-        # make_stats — feeds vllm:decode_steps_per_launch), and the
-        # cumulative count of launches that exited the device loop before
-        # exhausting their claimed budget.
-        self._decode_step_lengths: list[int] = []
-        self._decode_early_exits = 0
-        # Cumulative realized-K histogram {length: launches}, never
-        # drained: the realized step-length distribution of the dynamic
-        # loop, for whoever holds the scheduler (the tests do).
-        self.decode_len_hist: dict[int, int] = {}
-        # Escape hatch for the dynamic loop, fixed at construction:
-        # --disable-dynamic-decode, or VLLM_TPU_DISABLE_DYNAMIC_DECODE
-        # in the environment.
-        self.disable_dynamic_decode = scheduler_config.disable_dynamic_decode
         # Adaptive speculation: acceptance-driven draft budgets + the
         # occupancy-gated shutoff (spec_decode/adaptive.py). The
         # controller is a pure host-side state machine the scheduler
@@ -481,7 +455,6 @@ class Scheduler:
         return out
 
     def _free_request(self, request: Request) -> None:
-        self._dynamic_inflight.discard(request.request_id)
         if self.adaptive_spec is not None:
             self.adaptive_spec.forget(request.request_id)
         self._free_encoder_for_request(request)
@@ -537,16 +510,6 @@ class Scheduler:
     def get_num_unfinished_requests(self) -> int:
         return len(self.running) + len(self.waiting)
 
-    def _dynamic_decode_ok(self, request: Request) -> bool:
-        """Per-request eligibility for the dynamic decode loop beyond the
-        plain-decode gate: the row's stop set (eos unless ignored, plus
-        stop token ids) must fit the fixed device lane width."""
-        p = request.sampling_params
-        n_stop = len(p.all_stop_token_ids)
-        if not p.ignore_eos and request.eos_token_id is not None:
-            n_stop += 1
-        return n_stop <= MAX_DYNAMIC_STOP_IDS
-
     # ------------------------------------------------------------------
     # schedule()
     # ------------------------------------------------------------------
@@ -599,74 +562,6 @@ class Scheduler:
         # freed request slots are admittable in this same step's phase 2.
         preempted_in_step |= self._pressure_preempt()
 
-        # In-jit multi-step decode: eligible only when EVERY live request
-        # is a pure single-token decode with no feature that needs host
-        # work between tokens (async only — the sync path advances counts
-        # at update time).
-        decode_k = 1
-        dynamic = False
-        decode_claims: dict[str, int] = {}
-        cfg_k = self.config.num_decode_steps
-        if cfg_k > 1 and self.async_scheduling and not self.waiting:
-            def _plain_decode(r):
-                p = r.sampling_params
-                return (
-                    r.pooling_params is None
-                    and not r.spec_token_ids
-                    and p.logprobs is None
-                    and not r.use_structured_output
-                    and not _needs_logits_processors(p)
-                    and not (p.presence_penalty or p.frequency_penalty
-                             or p.repetition_penalty != 1.0)
-                    and (r.num_tokens_with_spec + r.num_output_placeholders
-                         - r.num_computed_tokens) == 1
-                )
-
-            if self.running and all(map(_plain_decode, self.running)):
-                kmax = self.config.max_decode_steps_per_launch
-                # Dynamic path (the default for multi-step): the jitted
-                # step runs a lax.while_loop with on-device stop
-                # detection, so instead of a fixed K the scheduler CLAIMS
-                # up to kmax positions per row — each bounded by the row's
-                # max_model_len / max_tokens headroom — and the device
-                # reports the realized length back. Falls back to the
-                # statically unrolled fixed-K chain when disabled or when
-                # any row's stop set exceeds the device lane width.
-                dynamic = (
-                    kmax > 1
-                    and not self.disable_dynamic_decode
-                    and not envs.VLLM_TPU_DISABLE_DYNAMIC_DECODE
-                    and all(map(self._dynamic_decode_ok, self.running))
-                )
-                if dynamic:
-                    for r in self.running:
-                        if r.num_output_placeholders > 0:
-                            # Fixed-path tokens still in flight: the row
-                            # sits out this step (drain barrier below) so
-                            # its position settles before a claim.
-                            continue
-                        decode_claims[r.request_id] = min(
-                            kmax,
-                            self.config.max_model_len
-                            - r.num_computed_tokens - 1,
-                            r.max_tokens - r.num_output_tokens,
-                        )
-                else:
-                    # The k-th sampled token of a row lands at position
-                    # computed + k; near max_model_len fall back to single
-                    # steps rather than compiling intermediate chain
-                    # lengths (num_decode_steps is a static jit arg — only
-                    # two traces ever exist: 1 and cfg_k).
-                    room = min(
-                        self.config.max_model_len - r.num_computed_tokens - 1
-                        for r in self.running
-                    )
-                    if room >= cfg_k:
-                        decode_k = cfg_k
-        self._decode_k = decode_k
-        self._dynamic_decode = dynamic
-        self._decode_claims = decode_claims
-
         # Spec-decode steps disable logprobs for the whole batch (the
         # runner's per-token logprob contract is single-token), so while ANY
         # request wants logprobs, drop pending drafts at the authoritative
@@ -716,23 +611,6 @@ class Scheduler:
         req_index = 0
         while req_index < len(self.running) and token_budget > 0:
             request = self.running[req_index]
-            # Dynamic multi-step: a request whose dynamic launch is still
-            # in flight cannot be rescheduled — its realized length (and
-            # therefore its true position) is unknown until
-            # update_from_output reconciles the claim.
-            if request.request_id in self._dynamic_inflight:
-                req_index += 1
-                continue
-            # Dynamic engages only from a settled position: rows with
-            # fixed-path tokens still in flight sit out this step so their
-            # placeholders drain (the runner's device-side token feedback
-            # is never used across a dynamic launch).
-            if (
-                self._dynamic_decode
-                and request.num_output_placeholders > 0
-            ):
-                req_index += 1
-                continue
             # Pipeline bound: each in-flight step feeds its input token
             # device-side from the immediately previous step's sampled
             # array, so chaining is exact at any depth. Penalty-bearing
@@ -823,14 +701,7 @@ class Scheduler:
             while True:
                 new_blocks = self.kv_cache_manager.allocate_slots(
                     request, num_new_tokens,
-                    num_lookahead_tokens=max(
-                        self.config.num_lookahead_tokens,
-                        self._decode_k - 1,
-                        # Dynamic claim: blocks must cover the whole
-                        # claimed window up front — the device loop
-                        # appends KV in-loop with no host interaction.
-                        self._decode_claims.get(request.request_id, 1) - 1,
-                    ),
+                    num_lookahead_tokens=self.config.num_lookahead_tokens,
                     num_stored_tokens=self._blk_stored(request, blk_pass),
                 )
                 if new_blocks is not None:
@@ -1140,20 +1011,6 @@ class Scheduler:
                     )
 
         total = sum(num_scheduled_tokens.values())
-        # Dynamic claims, narrowed to rows actually scheduled (a claimed
-        # row can drop out on budget/preemption). The flag ships only when
-        # every scheduled row holds a claim — the jitted loop has no mixed
-        # fixed/dynamic mode within one launch.
-        claims_out = {
-            rid: self._decode_claims[rid]
-            for rid in num_scheduled_tokens
-            if rid in self._decode_claims
-        }
-        dynamic_out = (
-            self._dynamic_decode
-            and len(claims_out) == len(num_scheduled_tokens)
-            and bool(claims_out)
-        )
         # Adaptive speculation: feed the occupancy gate from this step's
         # realized token-budget fill (same definition as the
         # vllm:engine_batch_occupancy gauge) and ship the verdicts — the
@@ -1173,9 +1030,6 @@ class Scheduler:
                     for rid in num_scheduled_tokens
                 }
         output = SchedulerOutput(
-            num_decode_steps=self._decode_k,
-            dynamic_decode=dynamic_out,
-            decode_claims=claims_out if dynamic_out else {},
             spec_suspended=spec_suspended,
             spec_draft_budgets=spec_budgets,
             kv_connector_load=kv_connector_load,
@@ -1201,8 +1055,6 @@ class Scheduler:
         self._pending_preempted = set()
         if total > 0:
             self._last_step_req_ids = set(num_scheduled_tokens)
-            if dynamic_out:
-                self._dynamic_inflight |= set(num_scheduled_tokens)
         if self.kv_event_publisher is not None:
             self.kv_event_publisher.flush()
         return output
@@ -1279,24 +1131,11 @@ class Scheduler:
         update_from_output."""
 
     def _drain_invalid(
-        self,
-        request: Request,
-        req_id: str,
-        runner_output,
-        req_index: int,
-        scheduler_output: SchedulerOutput | None = None,
+        self, request: Request, runner_output, req_index: int
     ) -> None:
         """Consume an invalid-epoch step's placeholders without appending
         its garbage tokens; resume waits until the count drains to 0."""
-        generated = runner_output.sampled_token_ids[req_index]
-        drained = max(len(generated), 0)
-        if scheduler_output is not None:
-            # A dynamic launch claimed (and placeholdered) its full
-            # budget regardless of how many tokens it realized.
-            drained = max(
-                drained, scheduler_output.decode_claims.get(req_id, 0)
-            )
-            self._dynamic_inflight.discard(req_id)
+        drained = len(runner_output.sampled_token_ids[req_index])
         request.num_output_placeholders = max(
             0, request.num_output_placeholders - drained
         )
@@ -1339,9 +1178,6 @@ class Scheduler:
                 # the same prefill, freeing nothing durable.
                 and (r.num_output_tokens > 0
                      or r.num_output_placeholders > 0)
-                # A dynamic launch in flight holds an unreconciled
-                # claim; let it settle rather than discard the window.
-                and r.request_id not in self._dynamic_inflight
                 and r.num_preemptions
                 < self.config.max_preemptions_per_request
             )
@@ -1467,18 +1303,12 @@ class Scheduler:
                 # else: already preempted (block-pressure victim between
                 # dispatch and update) — it sits in waiting once; a second
                 # _preempt would double-insert it.
-                self._drain_invalid(
-                    request, req_id, runner_output, req_index,
-                    scheduler_output,
-                )
+                self._drain_invalid(request, runner_output, req_index)
                 continue
             if request.dropping_invalid:
                 # In-flight output from before an invalid-load preemption:
                 # drain its placeholders without materializing tokens.
-                self._drain_invalid(
-                    request, req_id, runner_output, req_index,
-                    scheduler_output,
-                )
+                self._drain_invalid(request, runner_output, req_index)
                 continue
             if req_id in runner_output.numeric_error_req_ids:
                 # Numeric guard tripped on this request's row (NaN/Inf
@@ -1544,32 +1374,6 @@ class Scheduler:
 
             if not self.async_scheduling:
                 request.num_computed_tokens += num_tokens_scheduled
-            elif req_id in scheduler_output.decode_claims:
-                # Dynamic multi-step reconciliation: schedule() claimed
-                # `claimed` positions (placeholders and computed count
-                # advanced by the full claim); the device loop realized
-                # len(generated) of them. Drain the FULL claim of
-                # placeholders and roll the unrealized tail of computed
-                # positions back — their KV was never written (done rows
-                # park writes in the null block), and block_hashes only
-                # grow as tokens append, so nothing unrealized was ever
-                # prefix-cache-registered. A request preempted between
-                # dispatch and now already had computed reset to 0.
-                claimed = scheduler_output.decode_claims[req_id]
-                self._dynamic_inflight.discard(req_id)
-                request.num_output_placeholders = max(
-                    0, request.num_output_placeholders - claimed
-                )
-                request.num_inflight_steps = max(
-                    0, request.num_inflight_steps - 1
-                )
-                if request.status == RequestStatus.RUNNING:
-                    request.num_computed_tokens -= claimed - len(generated)
-                self._decode_step_lengths.append(len(generated))
-                g = len(generated)
-                self.decode_len_hist[g] = self.decode_len_hist.get(g, 0) + 1
-                if g < claimed:
-                    self._decode_early_exits += 1
             elif generated:
                 request.num_output_placeholders = max(
                     0, request.num_output_placeholders - len(generated)
@@ -1760,9 +1564,6 @@ class Scheduler:
         accept_lengths, self._spec_accept_lengths = (
             self._spec_accept_lengths, []
         )
-        decode_lengths, self._decode_step_lengths = (
-            self._decode_step_lengths, []
-        )
         draft_lens, self._spec_draft_lens = self._spec_draft_lens, []
         preempted_rids, self._preempted_rids = self._preempted_rids, []
         ctl = self.adaptive_spec
@@ -1801,8 +1602,6 @@ class Scheduler:
             spec_suspensions=(
                 ctl.suspensions_total if ctl is not None else 0
             ),
-            decode_step_lengths=decode_lengths,
-            decode_early_exits=self._decode_early_exits,
             preempted_req_ids=preempted_rids,
             pressure_preemptions=self._pressure_preemptions_total,
             brownout_rung=self.brownout_rung if self._qos_on() else 0,

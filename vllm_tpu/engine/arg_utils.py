@@ -65,8 +65,6 @@ class EngineArgs:
     enable_chunked_prefill: bool = True
     scheduling_policy: str = "fcfs"
     async_scheduling: bool = True
-    num_decode_steps: int = 1
-    max_decode_steps_per_launch: int = 128
     encoder_cache_budget: int = 4096
     enable_cascade_attention: bool = False
     enable_decode_attention: bool = True
@@ -105,7 +103,6 @@ class EngineArgs:
     spec_adaptive_high_watermark: float = 0.85
     spec_adaptive_low_watermark: float = 0.60
     spec_adaptive_ema_half_life_s: float = 10.0
-    disable_dynamic_decode: bool = False
 
     enable_lora: bool = False
     max_lora_rank: int = 16
@@ -258,13 +255,10 @@ class EngineArgs:
                 enable_chunked_prefill=self.enable_chunked_prefill,
                 policy=self.scheduling_policy,  # type: ignore[arg-type]
                 async_scheduling=self.async_scheduling,
-                num_decode_steps=self.num_decode_steps,
-                max_decode_steps_per_launch=self.max_decode_steps_per_launch,
                 encoder_cache_budget=self.encoder_cache_budget,
                 enable_cascade_attention=self.enable_cascade_attention,
                 enable_decode_attention=self.enable_decode_attention,
                 enable_sampler_kernel=self.enable_sampler_kernel,
-                disable_dynamic_decode=self.disable_dynamic_decode,
                 pressure_preemption_s=self.pressure_preemption_s,
                 max_preemptions_per_step=self.max_preemptions_per_step,
                 max_preemptions_per_request=(

@@ -1665,8 +1665,8 @@ class DPLBClient(_ZMQClientBase):
                 continue
             if not ph.resumed:
                 if o.finish_reason is None:
-                    # Multi-step engines can stream the token before the
-                    # finish frame; bank it for the resume prompt.
+                    # A token can stream before the finish frame; bank
+                    # it for the resume prompt.
                     ph.record.emitted_token_ids.extend(o.new_token_ids)
                     continue
                 resume = disagg.note_prefill_finished(

@@ -75,14 +75,6 @@ _readers: dict[str, Callable[[], Any]] = {
     "VLLM_TPU_DECODE_KV_PAGES_PER_BLOCK": _int(
         "VLLM_TPU_DECODE_KV_PAGES_PER_BLOCK", 0
     ),
-    # Escape hatch for device-resident dynamic multi-step decode (the
-    # in-jit lax.while_loop with on-device stop detection): multi-step
-    # launches fall back to the statically unrolled fixed-K chain when
-    # set. Outputs are bit-identical either way; A/B this before filing
-    # dynamic-decode bugs.
-    "VLLM_TPU_DISABLE_DYNAMIC_DECODE": _bool(
-        "VLLM_TPU_DISABLE_DYNAMIC_DECODE", False
-    ),
     # Escape hatch for the adaptive speculation controller
     # (spec_decode/adaptive.py): draft budgets revert to the static
     # num_speculative_tokens and the occupancy gate never suspends.

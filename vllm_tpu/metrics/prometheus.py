@@ -449,16 +449,15 @@ class PrometheusRegistry:
         # Decode-path efficiency (runner cumulative counters -> derived
         # gauges): what fraction of jitted-step launches took the
         # decode-only shape (sequence-pipelined attention kernel), and
-        # how many sampled tokens each launch amortizes (multi-step
-        # decode: K tokens per launch; 1.0 = no amortization).
+        # how many sampled tokens each launch emits (more than one a
+        # row under speculation and generation by diffusion).
         self.decode_batch_ratio = Gauge(
             "vllm:decode_batch_ratio",
             "Fraction of jitted-step launches that were decode-only "
             "(cumulative since engine start)")
         self.tokens_per_launch = Gauge(
             "vllm:sampled_tokens_per_launch",
-            "Sampled tokens per jitted-step launch (cumulative average; "
-            "in-jit multi-step decode amortization)")
+            "Sampled tokens per jitted-step launch (cumulative average)")
         self.prep_fallback_rows = Counter(
             "vllm:prep_fallback_rows_total",
             "Step-input rows assembled by the Python fallback instead of "
@@ -471,21 +470,6 @@ class PrometheusRegistry:
             "vllm:sampler_fallback_rows_total",
             "Sampling (non-greedy) rows sampled by the XLA reference path "
             "because the fused sampling kernel was ineligible or disabled")
-        # Dynamic multi-step decode: realized per-request step counts of
-        # device-resident lax.while_loop launches (how far each row ran
-        # before an on-device stop / budget exit), and launches that
-        # exited before exhausting their claimed step budget.
-        self.decode_steps_per_launch = Histogram(
-            "vllm:decode_steps_per_launch",
-            "Realized per-request decode steps of a dynamic multi-step "
-            "launch (device loop iterations a row consumed before stop "
-            "detection or the per-launch budget ended it)",
-            [1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 96.0, 128.0, 256.0])
-        self.decode_early_exits = Counter(
-            "vllm:decode_early_exits_total",
-            "Dynamic decode launches whose device loop exited before the "
-            "claimed per-request step budget (a row hit a stop token or "
-            "all rows finished)")
         self.request_success = LabeledCounter(
             "vllm:request_success_total",
             "Finished requests by reason", "finished_reason")
@@ -839,7 +823,6 @@ class PrometheusRegistry:
             self.decode_batch_ratio, self.tokens_per_launch,
             self.prep_fallback_rows,
             self.sampler_kernel_launches, self.sampler_fallback_rows,
-            self.decode_steps_per_launch, self.decode_early_exits,
             self.request_success,
             self.step_duration, self.batch_tokens, self.batch_requests,
             self.batch_occupancy, self.step_interval,
@@ -884,7 +867,6 @@ class PrometheusRegistry:
         self._last_prep_fallback = 0
         self._last_sampler_kernel = 0
         self._last_sampler_fallback = 0
-        self._last_decode_early_exits = 0
         # pid -> the phases of that process's start; and when this
         # process's own start was over (perf_counter's clock).
         self._startup_by_pid: dict[int, dict[str, float]] = {}
@@ -965,11 +947,6 @@ class PrometheusRegistry:
             self.sampler_fallback_rows.inc(
                 max(0, s.sampler_fallback_rows - self._last_sampler_fallback))
             self._last_sampler_fallback = s.sampler_fallback_rows
-            for n in s.decode_step_lengths:
-                self.decode_steps_per_launch.observe(n)
-            self.decode_early_exits.inc(
-                max(0, s.decode_early_exits - self._last_decode_early_exits))
-            self._last_decode_early_exits = s.decode_early_exits
             for phase in STEP_PHASES:
                 for t in getattr(s, f"step_{phase}_times"):
                     self.step_duration.observe(phase, t)

@@ -97,11 +97,6 @@ def resolve_generation(config) -> None:
         raise ValueError(
             f"speculative decoding is incompatible with {why}: a draft row "
             "of 1 + k tokens and a block row cannot share a position")
-    if sc.num_decode_steps > 1:
-        raise ValueError(
-            f"num_decode_steps > 1 (the multi-step and dynamic decode "
-            f"loops) is incompatible with {why}: both loops advance one "
-            "position an iteration")
     if config.parallel_config.pipeline_parallel_size > 1:
         raise ValueError(f"pipeline parallelism is incompatible with {why}")
     if config.parallel_config.context_parallel_size > 1:

@@ -12,9 +12,7 @@ in :func:`mrope_positions`).
 
 v1 scope: fixed image geometry (every image resized to one static
 ``image_size`` — dynamic-resolution grids are a bucket-explosion
-tradeoff deferred like Llava's), single images (no video), and
-``num_decode_steps == 1`` (the in-jit decode chain does not thread the
-mrope delta yet; the worker enforces this).
+tradeoff deferred like Llava's) and single images (no video).
 """
 
 from __future__ import annotations

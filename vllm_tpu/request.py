@@ -124,8 +124,8 @@ class Request:
         # has not yet been materialized host-side (reference:
         # v1/core/sched/async_scheduler.py num_output_placeholders).
         self.num_output_placeholders = 0
-        # Sampling STEPS in flight (placeholders counts TOKENS; multi-step
-        # decode makes them differ).
+        # Sampling STEPS in flight (placeholders counts TOKENS; a block
+        # pass under generation by diffusion is a step with none).
         self.num_inflight_steps = 0
         # Number of scheduler preemptions (stats).
         self.num_preemptions = 0

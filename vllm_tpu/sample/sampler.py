@@ -173,24 +173,6 @@ def sample(
     return sampled[:, 0], raw_logprobs
 
 
-def stop_token_hit(
-    tokens: jnp.ndarray,  # [R] i32 tokens just sampled
-    stop_ids: jnp.ndarray,  # [R, S] i32 per-row stop set, -1 pads
-    out_count: jnp.ndarray,  # [R] i32 output tokens emitted INCLUDING these
-    min_out: jnp.ndarray,  # [R] i32 per-row min_tokens floor
-) -> jnp.ndarray:
-    """Per-row on-device stop detection for the dynamic decode loop (and
-    any consumer of fused-sampler output that wants in-jit stop checks):
-    True where the row's freshly sampled token is in its stop set —
-    eos-unless-ignored and ``stop_token_ids`` both arrive via ``stop_ids``
-    — gated on the ``min_tokens`` floor, mirroring the host-side
-    ``Scheduler._check_stop`` ordering (length caps are enforced
-    separately via the per-row step budget). -1 pad lanes never match:
-    sampled token ids are non-negative."""
-    hit = jnp.any(tokens[:, None] == stop_ids, axis=-1)
-    return hit & (out_count >= min_out)
-
-
 def sampler_kernel_eligible(
     vocab: int,
     *,

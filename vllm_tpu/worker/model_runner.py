@@ -27,7 +27,6 @@ import numpy as np
 
 from vllm_tpu.config import EngineConfig
 from vllm_tpu.core.sched_output import (
-    MAX_DYNAMIC_STOP_IDS,
     ModelRunnerOutput,
     SchedulerOutput,
 )
@@ -57,21 +56,13 @@ class StepHandle:
     """A dispatched-but-not-fetched step (device arrays + row bookkeeping)."""
 
     def __init__(self, req_order=None, do_sample=None, sampled=None, lp=None,
-                 row_states=None, empty: bool = False, spec: bool = False,
-                 dynamic: bool = False) -> None:
+                 row_states=None, empty: bool = False,
+                 spec: bool = False) -> None:
         self.req_order = req_order or []
         self.do_sample = do_sample
         self.sampled = sampled  # [R] ids, or (out_tokens [R,S+1], num_out [R])
         self.lp = lp
         self.spec = spec
-        # Dynamic multi-step decode: sampled is (out_tokens [R, Kmax],
-        # num_out [R]) with per-row REALIZED lengths (the device loop
-        # stopped each row at its stop token or claimed budget).
-        self.dynamic = dynamic
-        # Deferred sampler-routing accounting for dynamic launches:
-        # (use_kernel, nongreedy_rows) — the realized step count is only
-        # known at finalize.
-        self.dyn_sampler_acct = None
         # CachedRequestState identities at dispatch time: finalize only folds
         # a token into a row still owned by the same request instance (the
         # id may have been reused while this step was in flight).
@@ -172,13 +163,13 @@ class ModelRunner:
         self._longest_tables = self.group_block_sizes.index(
             min(self.group_block_sizes))
         if self.num_kv_groups > 1 and (
-            config.speculative_config.enabled or sched.num_decode_steps > 1
+            config.speculative_config.enabled
             or sched.enable_cascade_attention
         ):
             raise NotImplementedError(
-                "several KV groups with speculative decoding, multi-step "
-                "decode or cascade attention: those paths derive slots "
-                "from ONE block table inside the step program"
+                "several KV groups with speculative decoding or cascade "
+                "attention: those paths derive slots from ONE block table "
+                "inside the step program"
             )
         self.input_batch = InputBatch(
             max_num_reqs=sched.max_num_seqs,
@@ -417,8 +408,6 @@ class ModelRunner:
                 "num_spec",
                 "num_adj",
                 "num_allow",
-                "num_decode_steps",
-                "dynamic_decode",
                 "cascade_blocks",
                 "has_state_slots",
                 "decode_only",
@@ -499,8 +488,8 @@ class ModelRunner:
         self.prep_fallback_rows = 0
         # Decode-path observability: jitted-step launches, launches whose
         # batch was decode-only (eligible for the sequence-pipelined
-        # kernel), and rows*steps sampled — tokens/launch measures the
-        # multi-step amortization. Exported via SchedulerStats.
+        # kernel), and tokens sampled (several a row a launch under
+        # speculation and diffusion). Exported via SchedulerStats.
         self.step_launches = 0
         self.decode_only_launches = 0
         self.launch_sampled_tokens = 0
@@ -509,9 +498,6 @@ class ModelRunner:
         # XLA reference (greedy-only launches count as neither).
         self.sampler_kernel_launches = 0
         self.sampler_fallback_rows = 0
-        # Deferred sampler accounting for the in-flight dynamic launch
-        # (set by _prepare_inputs, moved onto the StepHandle by dispatch).
-        self._dyn_sampler_acct = None
         # Step-time breakdown, always on and cumulative: host preparation
         # of the step's inputs, the call that enqueues the jitted step, and
         # the wait for its results in finalize (the host waiting for the
@@ -585,7 +571,7 @@ class ModelRunner:
     def _unpack(self, ibuf, fbuf, counts, prompt_mask, t, r, b, num_spec=0,
                 num_adj=0, num_allow=0, num_prompt_logprobs=0,
                 cascade_blocks=0, has_state_slots=0, decode_only=False,
-                dynamic_decode=False, block_len=0):
+                block_len=0):
         """Split the two packed host buffers back into metadata pytrees.
 
         One contiguous i32 upload + one f32 upload per step instead of ~12
@@ -669,18 +655,6 @@ class ModelRunner:
         if has_state_slots:
             # Hybrid attention+SSM: per-request Mamba state slot.
             md.state_slots = take(r)
-        dyn = None
-        if dynamic_decode:
-            # Dynamic multi-step decode: per-row stop set (-1 pads), step
-            # budget (0 on padding rows -> done before the loop body ever
-            # runs), and min_tokens floor for the in-loop stop check.
-            dyn = (
-                take(r * MAX_DYNAMIC_STOP_IDS).reshape(
-                    r, MAX_DYNAMIC_STOP_IDS
-                ),
-                take(r),
-                take(r),
-            )
         blk = None
         if block_len:
             # Generation by diffusion over blocks, per row: the kind of
@@ -714,7 +688,7 @@ class ModelRunner:
         )
         logit_adjust = (adj_ids, adj_vals, allow_ids, allow_active)
         return (token_ids, md, sampling, feedback, grammar_rows, logit_adjust,
-                draft_next, token_lora, plp_next, spec, dyn, blk)
+                draft_next, token_lora, plp_next, spec, blk)
 
     @functools.cached_property
     def _attn_call_geometry(self):
@@ -926,8 +900,6 @@ class ModelRunner:
         num_spec: int = 0,
         num_adj: int = 0,
         num_allow: int = 0,
-        num_decode_steps: int = 1,
-        dynamic_decode: bool = False,
         cascade_blocks: int = 0,
         has_state_slots: int = 0,
         decode_only: bool = False,
@@ -935,10 +907,10 @@ class ModelRunner:
         block_len: int = 0,
     ):
         (token_ids, md, sampling, feedback, grammar_rows, logit_adjust,
-         draft_next, token_lora, plp_next, spec, dyn, blk) = self._unpack(
+         draft_next, token_lora, plp_next, spec, blk) = self._unpack(
             ibuf, fbuf, counts, prompt_mask, t_pad, r_pad, b_pad, num_spec,
             num_adj, num_allow, num_prompt_logprobs, cascade_blocks,
-            has_state_slots, decode_only, dynamic_decode, block_len,
+            has_state_slots, decode_only, block_len,
         )
         # Device-side token feedback (async scheduling): a decode row whose
         # input token was sampled by the still-in-flight previous step reads
@@ -1194,160 +1166,6 @@ class ModelRunner:
             tp_size=self.tp_size,
             num_active=md.num_seqs[0],  # live rows come first
         )
-        if dynamic_decode:
-            # Device-resident dynamic multi-step decode: a lax.while_loop
-            # whose condition does ON-DEVICE stop detection. Each
-            # iteration runs the single-position body over all rows; a
-            # row finishes when its fresh token hits the row's stop set
-            # (eos + stop_token_ids, min_tokens-gated) or its claimed
-            # step budget (max_tokens / max_model_len headroom, bounded
-            # host-side). The loop exits once every row is done — one
-            # launch emits up to num_decode_steps (= the host-interaction
-            # budget) tokens per row with zero host roundtrips between
-            # them. Scheduler guarantees every row is a plain decode.
-            from dataclasses import replace as _dreplace
-
-            from vllm_tpu.sample.sampler import stop_token_hit
-
-            stop_ids, max_steps, min_out = dyn
-            kmax = num_decode_steps
-            rows_r = jnp.arange(r_pad, dtype=jnp.int32)
-            pos0 = md.positions[md.logits_indices]
-            row_lora = (
-                token_lora[md.logits_indices]
-                if token_lora is not None
-                else None
-            )
-            out0 = jnp.zeros((r_pad, kmax), jnp.int32).at[:, 0].set(sampled)
-            n_out0 = jnp.ones(r_pad, jnp.int32)
-            # Padding rows ship max_steps 0 -> done before the body runs.
-            done0 = stop_token_hit(sampled, stop_ids, n_out0, min_out) | (
-                n_out0 >= max_steps
-            )
-
-            def _cond(carry):
-                _, k, _, _, _, done, _ = carry
-                return (k < kmax) & ~jnp.all(done)
-
-            def _body(carry):
-                kv, k, tok, out, n_out, done, moe = carry
-                # Each row's query sits at its own realized position; done
-                # rows stop advancing (their n_out is frozen).
-                md_k = self._single_pos_metadata(md, pos0 + n_out, r_pad)
-                # Done rows park their KV write in the null block (slot 0
-                # — write-only garbage, the padding convention): their
-                # frozen position's slot already holds trusted KV that a
-                # re-write with a stale token would poison.
-                md_k = _dreplace(
-                    md_k,
-                    slot_mapping=jnp.where(done, 0, md_k.slot_mapping),
-                )
-                out_k = self.model.apply(
-                    params, kv, tok, md_k, token_lora_slot=row_lora
-                )
-                if self._moe_counts:
-                    hidden_k, kv, counts_k = out_k
-                    moe = moe + counts_k
-                else:
-                    hidden_k, kv = out_k
-                logits_k = self.model.compute_logits(params, hidden_k)
-                # Global k == the row's output index for every live row (a
-                # row emits on every iteration until done), so seeded
-                # streams match the fixed-K chain bit-for-bit.
-                sampling_k = _dreplace(
-                    sampling,
-                    prng_keys=sampling.prng_keys.at[:, 1].add(
-                        k.astype(sampling.prng_keys.dtype)
-                    ),
-                )
-                tok_new, _ = dispatch_sample(
-                    logits_k,
-                    sampling_k,
-                    needs_penalties=False,
-                    needs_top_k=needs_top_k,
-                    needs_top_p_min_p=needs_top_p_min_p,
-                    needs_gumbel=needs_gumbel,
-                    enable_kernel=enable_sampler_kernel,
-                    allow_interpret=True,
-                    tp_size=self.tp_size,
-                )
-                run = ~done
-                # Done rows scatter to column kmax (dropped).
-                col = jnp.where(run, n_out, kmax)
-                out = out.at[rows_r, col].set(tok_new, mode="drop")
-                n_out = n_out + run.astype(jnp.int32)
-                tok = jnp.where(run, tok_new, tok)
-                done = done | (
-                    run
-                    & (
-                        stop_token_hit(tok_new, stop_ids, n_out, min_out)
-                        | (n_out >= max_steps)
-                    )
-                )
-                return (kv, k + 1, tok, out, n_out, done, moe)
-
-            (kv_cache, _, _, out_tokens, num_out, _, moe_counts) = (
-                jax.lax.while_loop(
-                    _cond,
-                    _body,
-                    (
-                        kv_cache,
-                        jnp.int32(1),
-                        sampled,
-                        out0,
-                        n_out0,
-                        done0,
-                        moe_counts,
-                    ),
-                )
-            )
-            return (kv_cache, draft_kv, (out_tokens, num_out), None, None,
-                    None, nan_count, None, moe_counts, row_bad)
-        if num_decode_steps > 1:
-            # In-jit multi-step decode: chain K-1 more single-position
-            # iterations, feeding each sampled token back device-side.
-            # Scheduler guarantees every row is a plain decode (no spec /
-            # grammar / processors / penalties / logprobs / pooling).
-            from dataclasses import replace as _dreplace
-
-            outs = [sampled]
-            tok = sampled
-            pos0 = md.positions[md.logits_indices]  # current input position
-            # Per-row adapter slot = the slot of the row's last token.
-            row_lora = (
-                token_lora[md.logits_indices]
-                if token_lora is not None
-                else None
-            )
-            for k in range(1, num_decode_steps):
-                # Position of the token sampled last iteration.
-                md_k = self._single_pos_metadata(md, pos0 + k, r_pad)
-                out_k = self.model.apply(
-                    params, kv_cache, tok, md_k, token_lora_slot=row_lora
-                )
-                if self._moe_counts:
-                    hidden_k, kv_cache, counts_k = out_k
-                    moe_counts = moe_counts + counts_k
-                else:
-                    hidden_k, kv_cache = out_k
-                logits_k = self.model.compute_logits(params, hidden_k)
-                sampling_k = _dreplace(
-                    sampling,
-                    prng_keys=sampling.prng_keys.at[:, 1].add(k),
-                )
-                tok, _ = dispatch_sample(
-                    logits_k,
-                    sampling_k,
-                    needs_penalties=False,
-                    needs_top_k=needs_top_k,
-                    needs_top_p_min_p=needs_top_p_min_p,
-                    needs_gumbel=needs_gumbel,
-                    enable_kernel=enable_sampler_kernel,
-                    allow_interpret=True,
-                    tp_size=self.tp_size,
-                )
-                outs.append(tok)
-            sampled = jnp.stack(outs, axis=1)  # [R, K]
 
         drafts = None
         if self.draft_model is not None:
@@ -1966,19 +1784,8 @@ class ModelRunner:
         # + top_k(r) + prng(2r) + feedback(r) + grammar_rows(r)
         # [+ adj_ids(r*num_adj)] [+ allow_ids(r*num_allow) + allow_flag(r)]
         # [+ num_draft(r) + draft(r*s) + sample_pos(r*(s+1))]
-        # [+ state_slots(r)] [+ stop_ids(r*8) + max_steps(r) + min_out(r)]
+        # [+ state_slots(r)]
         state_len = r if self._is_hybrid else 0
-        # Device-resident dynamic multi-step decode: active when the
-        # scheduler claimed per-row step budgets. Runner-side fallback for
-        # hybrid-state models (SSM / cross-attention slots): a done row
-        # cannot park its per-request STATE write the way KV parks in the
-        # null block, so those models stay on the fixed chain — the
-        # scheduler's full-claim reconciliation is realized-length based
-        # and stays correct when fewer tokens come back.
-        dynamic = bool(
-            so.dynamic_decode and so.decode_claims and not self._is_hybrid
-        )
-        dyn_len = r * (MAX_DYNAMIC_STOP_IDS + 2) if dynamic else 0
         # [+ blk_kind(r) + blk_rule(r) + blk_n(r) + blk_committed(r*B)]
         blen = self.block_len
         blk_len = r * (3 + blen) if blen else 0
@@ -1986,7 +1793,7 @@ class ModelRunner:
         more_groups = self.num_kv_groups - 1
         ibuf = np.zeros(
             4 * t + 7 * r + (r + 1) + 1 + r * b + lp_len + eagle_len
-            + lora_len + plp_len + spec_len + state_len + dyn_len + blk_len
+            + lora_len + plp_len + spec_len + state_len + blk_len
             + more_groups * (t + r * b),
             np.int32,
         )
@@ -2047,32 +1854,6 @@ class ModelRunner:
             state_slots[:] = self.config.scheduler_config.max_num_seqs
             for i, rid in enumerate(req_order):
                 state_slots[i] = self._state_slot_of[rid]
-        if dynamic:
-            w = MAX_DYNAMIC_STOP_IDS
-            dyn_stop_ids = ibuf[o : o + r * w].reshape(r, w); o += r * w
-            dyn_stop_ids[:] = -1  # sampled ids are >= 0: pads never match
-            dyn_max_steps = ibuf[o : o + r]; o += r  # 0 pads -> done row
-            dyn_min_out = ibuf[o : o + r]; o += r
-            for i, rid in enumerate(req_order):
-                state = batch.req_states[rid]
-                p = state.sampling_params
-                stops: list[int] = []
-                if not p.ignore_eos and state.eos_token_id is not None:
-                    stops.append(int(state.eos_token_id))
-                for tok_id in p.all_stop_token_ids:
-                    if tok_id not in stops:
-                        stops.append(int(tok_id))
-                # The scheduler routes wider stop sets to the fixed
-                # chain; a truncated set only over-generates — the host
-                # fold trims past the first stop either way.
-                stops = stops[:w]
-                dyn_stop_ids[i, : len(stops)] = stops
-                dyn_max_steps[i] = so.decode_claims[rid]
-                # min_tokens rows never reach the dynamic path (the
-                # plain-decode gate excludes logits processors), so the
-                # floor is 0 — the lane keeps the device contract
-                # explicit for future relaxations of that gate.
-                dyn_min_out[i] = 0
         if blen:
             blk_kind = ibuf[o : o + r]; o += r
             blk_rule = ibuf[o : o + r]; o += r
@@ -2153,10 +1934,7 @@ class ModelRunner:
                 rid = req_order[i]
                 lag = int(ends[i] - known_live[i])
                 prev_row = self._prev_rows.get(rid, -1)
-                max_lag = self._max_pipeline_depth * max(
-                    1, self.config.scheduler_config.num_decode_steps
-                )
-                assert lag <= max_lag and prev_row >= 0, (
+                assert lag <= self._max_pipeline_depth and prev_row >= 0, (
                     rid, lag, prev_row)
                 feedback[i] = prev_row
                 pending_rows.append((int(i), lag))
@@ -2200,10 +1978,7 @@ class ModelRunner:
                 # counter so seeded streams don't repeat).
                 lag = start + n - known
                 prev_row = self._prev_rows.get(rid, -1)
-                max_lag = self._max_pipeline_depth * max(
-                    1, self.config.scheduler_config.num_decode_steps
-                )
-                assert lag <= max_lag and prev_row >= 0, (
+                assert lag <= self._max_pipeline_depth and prev_row >= 0, (
                     rid, start, n, known, prev_row)
                 feedback[i] = prev_row
                 pending_rows.append((i, lag))
@@ -2410,15 +2185,6 @@ class ModelRunner:
             has_state_slots=int(self._is_hybrid),
             num_adj=num_adj,
             num_allow=num_allow,
-            # Dynamic decode reuses num_decode_steps as the LOOP BOUND
-            # (the host-interaction budget) — a config constant, so the
-            # dynamic trace compiles exactly once per batch shape.
-            num_decode_steps=(
-                self.config.scheduler_config.max_decode_steps_per_launch
-                if dynamic
-                else so.num_decode_steps
-            ),
-            dynamic_decode=dynamic,
             # Cascade rewrites the attention call shape; keep such
             # batches on the general kernel.
             decode_only=decode_only and cascade_blocks == 0,
@@ -2484,14 +2250,10 @@ class ModelRunner:
             self._count_decode_tiles(seq_lens[:r_live], r_pad, b_pad)
         else:
             self._count_ragged_pairs(q_lens, seq_lens[:r_live], t_pad, b_pad)
-        # launch_sampled_tokens counts REALIZED emissions — finalize
-        # accumulates the per-row token runs it actually folds, which is
-        # exact for every path (fixed K, dynamic, spec, prefill).
         # Sampler-kernel routing accounting (the device decision is made
         # at trace time by dispatch_sample; this mirrors it host-side).
         # All-greedy launches are neither: the XLA argmax path is not a
         # fallback, it's the design for that shape.
-        self._dyn_sampler_acct = None
         if flags["needs_gumbel"]:
             use_kernel, _ = sampler_kernel_eligible(
                 self.model.vocab_size,
@@ -2506,14 +2268,10 @@ class ModelRunner:
                 rows = int(np.sum(nongreedy & do_sample[:r_live])) * blen
             else:
                 rows = int(np.sum(nongreedy))
-            if dynamic:
-                # Realized step count is unknown until finalize; stash
-                # the routing decision for deferred accounting.
-                self._dyn_sampler_acct = (use_kernel, rows)
-            elif use_kernel:
-                self.sampler_kernel_launches += flags["num_decode_steps"]
+            if use_kernel:
+                self.sampler_kernel_launches += 1
             else:
-                self.sampler_fallback_rows += rows * flags["num_decode_steps"]
+                self.sampler_fallback_rows += rows
         arrays = (jnp.asarray(ibuf), jnp.asarray(fbuf), counts, prompt_mask)
         mm_arrays = None
         if self.is_mm:
@@ -2649,8 +2407,8 @@ class ModelRunner:
         return failed
 
     def _single_pos_metadata(self, md, p, r_pad):
-        """Per-row single-position AttentionMetadata (decode chain /
-        EAGLE chain): query at position p[row], same block tables. One
+        """Per-row single-position AttentionMetadata (the in-jit draft
+        chains): query at position p[row], same block tables. One
         token per row by construction, so the decode-specialized kernel
         is eligible whenever the config allows it."""
         decode_ok = self.enable_decode_attention
@@ -2873,7 +2631,6 @@ class ModelRunner:
                 split["backend"], split["run"], split["cache"],
                 len(req_order), self.input_batch.num_reqs)
         is_spec = flags["num_spec"] > 0
-        is_dynamic = bool(flags.get("dynamic_decode"))
         if self.block_len:
             # Beside what goes to the host, the next pass's tokens and
             # flags, by this step's rows.
@@ -2882,32 +2639,17 @@ class ModelRunner:
                 state, ((0, self._max_r - state.shape[0]), (0, 0)))
             self._prev_rows = {rid: i for i, rid in enumerate(req_order)}
         elif not is_spec:
-            # Multi-step decode returns [R, K]; the feedback source for the
-            # next step is the LAST sampled column. Dynamic decode returns
-            # (out_tokens [R, Kmax], num_out [R]): gather each row's last
-            # REALIZED token (the scheduler never schedules a dynamic row
-            # into feedback, but keeping the source exact costs one [R]
-            # gather).
-            if is_dynamic:
-                out_t, n_t = sampled
-                last_col = out_t[
-                    jnp.arange(out_t.shape[0]),
-                    jnp.clip(n_t - 1, 0, out_t.shape[1] - 1),
-                ]
-            elif sampled.ndim == 2:
-                last_col = sampled[:, -1]
-            else:
-                last_col = sampled
+            # The next step's feedback source, by this step's rows.
             self._last_sampled = (
-                last_col
-                if last_col.shape[0] == self._max_r
-                else jnp.pad(last_col, (0, self._max_r - last_col.shape[0]))
+                sampled
+                if sampled.shape[0] == self._max_r
+                else jnp.pad(sampled, (0, self._max_r - sampled.shape[0]))
             )
             self._prev_rows = {rid: i for i, rid in enumerate(req_order)}
         # Kick off the D2H copy now: it runs as soon as the step completes,
         # so finalize()'s device_get is a no-op wait instead of paying the
         # full host<->device round trip on the critical path.
-        for x in sampled if (is_spec or is_dynamic) else (sampled,):
+        for x in sampled if is_spec else (sampled,):
             x.copy_to_host_async()
         if lp is not None:
             for x in lp:
@@ -2927,11 +2669,9 @@ class ModelRunner:
         handle = StepHandle(
             req_order=req_order, do_sample=do_sample, sampled=sampled, lp=lp,
             row_states=[self.input_batch.req_states[r] for r in req_order],
-            spec=is_spec, dynamic=is_dynamic,
+            spec=is_spec,
         )
         handle.prep_s = prep_s
-        handle.dyn_sampler_acct = self._dyn_sampler_acct
-        self._dyn_sampler_acct = None
         handle.spec_suspended = so.spec_suspended
         handle.spec_draft_budgets = so.spec_draft_budgets
         handle.drafts = drafts
@@ -2957,7 +2697,7 @@ class ModelRunner:
         req_order, do_sample = handle.req_order, handle.do_sample
         # The host waits for the device here and nowhere else in a step.
         with trace_span("fetch", "runner", reqs=len(req_order)):
-            if handle.spec or handle.dynamic:
+            if handle.spec:
                 out_tokens = np.asarray(jax.device_get(handle.sampled[0]))
                 num_out = np.asarray(jax.device_get(handle.sampled[1]))
             else:
@@ -2998,9 +2738,7 @@ class ModelRunner:
             bad_rows = np.ones(len(req_order), bool)
         if handle.moe_counts is not None:
             moe_counts = np.asarray(jax.device_get(handle.moe_counts))
-            # Experts with a live token, a layer (summed over the
-            # iterations of a multi-step decode, where an expert hit in
-            # several of them counts once).
+            # Experts with a live token, a layer.
             self.moe_experts_hit += int(np.count_nonzero(moe_counts))
             if self._moe_share:
                 self.moe_assignments += int(moe_counts.sum())
@@ -3058,12 +2796,9 @@ class ModelRunner:
                 out.sampled_token_ids.append([])
                 continue
             if do_sample[i]:
-                if handle.spec or handle.dynamic:
-                    # Variable-length run: spec accept length, or the
-                    # dynamic loop's realized per-row step count.
+                if handle.spec:
+                    # Variable-length run: the accepted drafts and one more.
                     toks = [int(x) for x in out_tokens[i, : num_out[i]]]
-                elif sampled_np.ndim == 2:  # multi-step decode [R, K]
-                    toks = [int(x) for x in sampled_np[i]]
                 else:
                     toks = [int(sampled_np[i])]
                 bad_kind = None
@@ -3126,23 +2861,11 @@ class ModelRunner:
                 out.sampled_token_ids.append(toks)
             else:
                 out.sampled_token_ids.append([])
-        # Realized emission count: exact for every path (fixed K,
-        # dynamic variable-length runs, spec accepts, prefill = 0) —
-        # vllm:sampled_tokens_per_launch reads this, so an estimate
-        # would skew it.
+        # Realized emission count (spec accepts, prefill = 0):
+        # vllm:sampled_tokens_per_launch reads this.
         self.launch_sampled_tokens += sum(
             len(toks) for toks in out.sampled_token_ids
         )
-        if handle.dyn_sampler_acct is not None:
-            # Dynamic launch sampler routing, deferred until the realized
-            # step count (the number of in-loop dispatch_sample calls =
-            # the longest row's run) is known.
-            use_kernel, n_nongreedy = handle.dyn_sampler_acct
-            steps = int(num_out.max()) if len(req_order) else 0
-            if use_kernel:
-                self.sampler_kernel_launches += steps
-            else:
-                self.sampler_fallback_rows += n_nongreedy * steps
         if lp_np is not None:
             from vllm_tpu.core.sched_output import LogprobsLists
 

@@ -133,16 +133,12 @@ class Worker:
                     f"max_model_len ({mc.max_model_len}) exceeds the "
                     f"decoder position table ({cap})"
                 )
-        if getattr(self.model, "needs_mrope", False):
-            self.config.scheduler_config.validate_decode_steps(
-                spec_enabled=self.config.speculative_config.enabled,
-                needs_mrope=True,
+        if (getattr(self.model, "needs_mrope", False)
+                and self.config.speculative_config.enabled):
+            raise ValueError(
+                "speculative decoding with m-rope models is not "
+                "supported yet"
             )
-            if self.config.speculative_config.enabled:
-                raise ValueError(
-                    "speculative decoding with m-rope models is not "
-                    "supported yet"
-                )
         if mc.quantize_embedding_layers:
             if not getattr(self.model, "supports_quantized_embedding", False):
                 raise ValueError(
