@@ -1150,3 +1150,239 @@ def test_mimo_step_compiles_and_reads_each_weight_in_place(
     ]
     assert not moved, (
         f"a run's scan moves weight-sized temporaries (>= {smallest} bytes): {moved}")
+
+
+# Falcon-H1-34B-Instruct (``falconh1-34b.long-answers``): in EVERY layer a
+# Mamba-2 mixer (32 heads of 128 over a state of 256, 2 groups: 4 MiB of
+# float32 a row a layer, in slots; a convolution over ONE stream of 5120
+# channels with a bias) beside rotary GQA attention (20 query heads over 4
+# KV heads of 128: 5 a KV head, new to both attention kernels), a vocabulary
+# of 261,120 (64 noise tiles, exactly the fused sampler's widest row); 6
+# layers, the cell's two step shapes at the whole page table of 12,288.
+FALCON_CONFIG = "benchmark/configs/falcon-h1-34b-instruct.json"
+FALCON_CELL = "benchmark/cells/falconh1-34b.long-answers.json"
+FALCON_PAGES, FALCON_BLOCKS, FALCON_LAYERS = 768, 8192, 6
+SSD_H, SSD_P, SSD_G, SSD_N, SSD_C = 32, 128, 2, 256, 5120
+
+
+def _falcon_rows():
+    import json
+    import pathlib
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    return int(json.loads((root / FALCON_CELL).read_text())["engine_args"]["max_num_seqs"])
+
+
+def test_ssd_one_token_kernel_compiles_and_updates_the_slots_in_place(one_chip):
+    """``ssd_decode_kernel`` at the cell's widths and rows: 8 heads of a
+    row's state a grid step (1 MiB in, 1 MiB out, double-buffered, inside
+    the scoped VMEM it asks for), the state stack of every slot of 6 layers
+    aliased to the output, nothing of the state's size beside it."""
+    from vllm_tpu.ops import mamba
+
+    r = _falcon_rows()
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = s((FALCON_LAYERS, r + 1, SSD_H, SSD_P, SSD_N))
+
+    def fn(x, dt, ln_a, b, c, state, slots, fresh, n):
+        return mamba._ssd_decode_pallas(x, dt, ln_a, b, c, state, jnp.int32(3), slots, fresh, n)
+
+    compiled = jax.jit(fn, donate_argnums=(5,)).lower(
+        s((r, SSD_H, SSD_P)), s((r, SSD_H)), s((r, SSD_H)), s((r, SSD_G, SSD_N)),
+        s((r, SSD_G, SSD_N)), state, s((r,), jnp.int32), s((r,), jnp.bool_),
+        s((), jnp.int32)).compile()
+    assert "ssd_decode_kernel" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    state_bytes = math.prod(state.shape) * 4
+    assert mem.alias_size_in_bytes >= state_bytes and mem.temp_size_in_bytes < 64 << 20
+
+
+def test_ssd_chunked_form_compiles_with_the_state_in_place(one_chip):
+    """The XLA form of the chunks at a 512-token step: the state stack is
+    carried through the rounds' ``while_loop`` and the places' scan without
+    a copy; its temporaries are the chunks' operands, not the slots."""
+    from vllm_tpu.ops import mamba
+
+    r, t = _falcon_rows(), 512
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = s((FALCON_LAYERS, r + 1, SSD_H, SSD_P, SSD_N))
+
+    def fn(x, dt, ln_a, b, c, state, slots, fresh, qsl, n):
+        return mamba.ssd_chunks(x, dt, ln_a, b, c, state, jnp.int32(3), slots, fresh, qsl, n)
+
+    mem = jax.jit(fn, donate_argnums=(5,)).lower(
+        s((t, SSD_H, SSD_P)), s((t, SSD_H)), s((t, SSD_H)), s((t, SSD_G, SSD_N)),
+        s((t, SSD_G, SSD_N)), state, s((r,), jnp.int32), s((r,), jnp.bool_),
+        s((r + 1,), jnp.int32), s((), jnp.int32)).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(state.shape) * 4
+    assert mem.temp_size_in_bytes < 512 << 20
+
+
+@pytest.mark.parametrize("t,decode_only", [(512, False), (0, True)],
+                         ids=["mixed_512_tokens", "decode_only"])
+def test_the_shared_convolution_compiles_at_one_stream_of_5120_channels_with_a_bias(
+        one_chip, monkeypatch, t, decode_only):
+    """``kda_conv_kernel`` as the state-space mixer calls it: one stream of
+    40 whole lane tiles (a count 32 does not divide: the heads in hand at
+    once are all 40), a bias as a fifth row of the taps, the tails ``[6,
+    rows + 1, 3, 40, 128]`` in bf16 through in place, nothing gathered,
+    scattered or looped over around it."""
+    from vllm_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_pallas_form", lambda state=None: (True, False))
+    r = _falcon_rows()
+    t = t or r
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stack = s((FALCON_LAYERS, r + 1, 3, SSD_C // 128, 128), jnp.bfloat16)
+
+    def fn(x, stack, taps, bias, slots, fresh, tok_req, qsl, n):
+        return kda.kda_short_conv((x,), stack, (taps,), jnp.int32(3), slots, fresh, tok_req,
+                                  qsl, n, decode_only=decode_only, bias=bias)
+
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        s((t, SSD_C), jnp.bfloat16), stack, s((4, SSD_C), jnp.bfloat16),
+        s((SSD_C,), jnp.bfloat16), s((r,), jnp.int32), s((r,), jnp.bool_),
+        s((t,), jnp.int32), s((r + 1,), jnp.int32), s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "kda_conv_kernel" in text
+    assert not any(op in text for op in (" gather(", " scatter(", " while("))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(stack.shape) * 2
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+def test_sampler_kernel_compiles_at_its_widest_row(one_chip):
+    """A vocabulary of 261,120 rounds up to 64 noise tiles, 262,144 lanes:
+    exactly ``MAX_KERNEL_WIDTH``, the widest row the fused sampler takes by
+    its own estimate. The cell's request bucket, top-p, the probability
+    returned, the live rows counted."""
+    from vllm_tpu.ops import sampler_kernel as sk
+
+    assert sk.compute_width(261120) == 262144 == sk.MAX_KERNEL_WIDTH
+    r, v = _falcon_rows(), 261120
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for return_prob in (False, True):
+        fn = functools.partial(
+            sk.fused_sample, needs_penalties=False, needs_top_k=False,
+            needs_top_p_min_p=True, return_prob=return_prob)
+        jax.jit(fn).lower(
+            s((r, v), jnp.float32), s((r, 128), jnp.float32), s((r, 128), jnp.int32),
+            s((1, 128), jnp.int32), s((1, 128), jnp.int8), s((), jnp.int32)).compile()
+
+
+def _falcon_attn_args(sharding, t, r, ragged):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    args = [
+        s((t, 20, D), jnp.bfloat16),
+        s((FALCON_LAYERS, FALCON_BLOCKS, PAGE, 2 * 4, D), jnp.bfloat16),
+        s((1,), jnp.int32), s((r,), jnp.int32), s((r, FALCON_PAGES), jnp.int32),
+    ]
+    if ragged:
+        args.append(s((r + 1,), jnp.int32))
+    return args + [s((1,), jnp.int32)]
+
+
+@pytest.mark.parametrize("kernel", ["ragged", "decode"])
+def test_both_attention_kernels_compile_at_five_query_heads_a_kv_head(one_chip, kernel):
+    """20 query heads over 4 KV heads of 128 (every accepted cell has 4, 6,
+    8 or 16 a KV head), at the cell's rows and the whole page table."""
+    from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
+    from vllm_tpu.ops.rpa_kernel import ragged_paged_attention, ragged_tiling
+
+    r = _falcon_rows()
+    if kernel == "ragged":
+        tiling = ragged_tiling(20, 8, D, PAGE, jnp.bfloat16, jnp.bfloat16, 512, FALCON_PAGES)
+        assert tiling.q_per_blk * 5 <= 512 and (tiling.vmem_limit_bytes or 0) <= 64 << 20
+        fn = functools.partial(ragged_paged_attention, sm_scale=D**-0.5)
+        args = _falcon_attn_args(one_chip, 512, r, True)
+    else:
+        fn = functools.partial(decode_paged_attention, sm_scale=D**-0.5)
+        args = _falcon_attn_args(one_chip, r, r, False) + [
+            jax.ShapeDtypeStruct((r,), jnp.int32, sharding=one_chip)]
+    jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("decode_only", [True, False], ids=["decode_only", "mixed_T512"])
+def test_falcon_h1_step_compiles_and_reads_each_weight_in_place(
+        one_chip, monkeypatch, decode_only):
+    """Both step programs of ``falconh1-34b.long-answers`` at the published
+    widths, 6 layers, compiled for the v5e with the Pallas forms on: the
+    one-token kernel of the rule, the shared convolution, the attention
+    kernel of the step's kind; and no iteration of the layer scan slices,
+    copies or transposes a WEIGHT out of its ``[L, ...]`` stack."""
+    import json
+    import pathlib
+
+    from transformers import PretrainedConfig
+
+    from vllm_tpu.layers.rotary import serving_positions
+    from vllm_tpu.models.falcon_h1 import FalconH1ForCausalLM
+    from vllm_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    config = json.loads((root / FALCON_CONFIG).read_text())
+    config.pop("bench")
+    with serving_positions(FALCON_PAGES * PAGE):  # as the worker does
+        model = FalconH1ForCausalLM(PretrainedConfig.from_dict(config), dtype=jnp.bfloat16)
+    r = _falcon_rows()
+    model.max_state_slots = r
+    t = r if decode_only else 512
+    assert (model.num_layers, model.conv_dim, model.in_dim) == (FALCON_LAYERS, SSD_C, 9248)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    on_chip = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(model.init_dummy_params, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.alloc_kv_cache(FALCON_BLOCKS, PAGE, jnp.bfloat16)))
+    assert cache["ssm"].shape == (FALCON_LAYERS, r + 1, SSD_H, SSD_P, SSD_N)
+    assert cache["conv"].shape == (FALCON_LAYERS, r + 1, 3, 40, 128)
+    assert cache["paged"].shape == (FALCON_LAYERS, FALCON_BLOCKS, PAGE, 8, D)
+    md = attention.AttentionMetadata(
+        positions=s((t,), jnp.int32), slot_mapping=s((t,), jnp.int32),
+        block_tables=s((r, FALCON_PAGES), jnp.int32),
+        seq_lens=s((r,), jnp.int32), query_start_loc=s((r + 1,), jnp.int32),
+        token_req_idx=s((t,), jnp.int32), logits_indices=s((r,), jnp.int32),
+        num_seqs=s((1,), jnp.int32), decode_only=decode_only,
+        state_slots=s((r,), jnp.int32),
+    )
+    compiled = jax.jit(model.apply, donate_argnums=(1,)).lower(
+        params, cache, s((t,), jnp.int32), md).compile()
+    text = compiled.as_text()
+    for kernel in ("ssd_decode_kernel", "kda_conv_kernel",
+                   "rpa_decode_kernel" if decode_only else "rpa_kernel"):
+        assert kernel in text
+    # The slots and the pages go through in place.
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+    squeezed = lambda dims: tuple(d for d in dims if d != 1)
+    shapes = {squeezed(a.shape[1:]) for a in jax.tree.leaves(params["layers"]) if a.ndim >= 3}
+    shapes |= {sh[::-1] for sh in shapes}
+    smallest = 5120 * 512 * 2  # wk, wv
+    moved = [
+        (name, opcode, dims, nbytes)
+        for name, opcode, nbytes, dims in _while_body_instructions(text)
+        if nbytes >= smallest
+        and ("dynamic-slice" in name or opcode == "dynamic-slice"
+             or (opcode in ("copy", "transpose") and squeezed(dims) in shapes))
+    ]
+    assert not moved, (
+        f"the layer scan moves weight-sized temporaries (>= {smallest} bytes): {moved}")

@@ -187,10 +187,11 @@ def _conv_kernel(
     layer_ref, total_ref, qsl_ref, slots_ref, fresh_ref, row_ref,  # prefetch
     *refs,
     part: int,
+    bias: bool = False,
 ):
     # The inputs [T, g, dk], their heads side by side making G; then:
     (*xs_hbm,
-     w_ref,  # [K, G, dk] f32
+     w_ref,  # [K, G, dk] f32; with ``bias`` a row more, the bias
      stack_hbm,  # [Lk, S + 1, K - 1, G, dk]; a slot is read before it is written
      y_ref,  # [tile, G, dk] f32: this step's tokens
      stack_out_hbm,  # the same stack
@@ -199,7 +200,7 @@ def _conv_kernel(
      out,  # [2, (K - 1) * tile, G, dk]: the tails of the rows that end in it
      x_sem, in_sem, out_sem) = refs
     tile, g = y_ref.shape[:2]
-    km1 = w_ref.shape[0] - 1
+    km1 = stack_hbm.shape[2]
     first_tail = km1 + tile
     i = pl.program_id(0)
     total = total_ref[0]
@@ -288,10 +289,11 @@ def _conv_kernel(
 
         t0, lo, _ = rows_of(i)
         for g0 in range(0, g, part):
-            gs = slice(g0, g0 + part)
+            gs = slice(g0, min(g0 + part, g))
             w = [w_ref[j, gs, :] for j in range(km1 + 1)]
+            b = w_ref[km1 + 1, gs, :] if bias else None
 
-            def token(ii, carry, gs=gs, w=w):
+            def token(ii, carry, gs=gs, w=w, b=b):
                 t = t0 + ii
 
                 @pl.when(t < total)
@@ -308,7 +310,7 @@ def _conv_kernel(
                             jnp.where(pos >= s, km1 + ii - s, tail + km1 - s + pos),
                             gs, :])
                         y = y + back[-1].astype(jnp.float32) * w[km1 - s]
-                    y_ref[ii, gs, :] = y
+                    y_ref[ii, gs, :] = y if b is None else y + b
 
                     @pl.when(t + 1 == qsl_ref[r + 1])
                     def _():  # the row's last token: its K - 1 last inputs
@@ -342,7 +344,7 @@ def _live_tokens(query_start_loc, n_live):
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def _conv_pallas(xs, stack, weights, layer, slots, fresh, query_start_loc,
-                 n_live, *, tile: int, interpret: bool = False):
+                 n_live, bias=None, *, tile: int, interpret: bool = False):
     t = xs[0].shape[0]
     km1, g, dk = stack.shape[2:]
     r = slots.shape[0]
@@ -351,6 +353,8 @@ def _conv_pallas(xs, stack, weights, layer, slots, fresh, query_start_loc,
     tp = -(-t // tile) * tile
     xs = [jnp.pad(x, ((0, tp - t), (0, 0))).reshape(tp, -1, dk) for x in xs]
     w = jnp.concatenate(weights, axis=-1).astype(jnp.float32)
+    if bias is not None:  # the taps' last row
+        w = jnp.concatenate([w, bias.astype(jnp.float32)[None]], axis=0)
     qsl = i32(query_start_loc)
     total = _live_tokens(qsl, n_live)
     ts = jnp.arange(tp, dtype=jnp.int32)
@@ -358,19 +362,21 @@ def _conv_pallas(xs, stack, weights, layer, slots, fresh, query_start_loc,
     row = jnp.sum(
         (qsl[None, 1:] <= ts[:, None]) & (jnp.arange(r) < n_live)[None, :],
         axis=1, dtype=jnp.int32)
-    # The heads in hand at once: [32, dk] f32 a tap.
-    part = 32 if all(x.shape[1] % 32 == 0 for x in xs) else g
+    # The heads in hand at once: [32, dk] f32 a tap; where 32 does not
+    # divide the inputs' heads (one stream of 40), 16 at a time and what is
+    # left: whole tiles of a 16-bit served dtype.
+    part = 32 if all(x.shape[1] % 32 == 0 for x in xs) else min(16, g)
 
     def last_tile(i, layer, total, *_):
         return (jnp.clip((total[0] + tile - 1) // tile - 1, 0, i), 0, 0)
 
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     y, stack = pl.pallas_call(
-        functools.partial(_conv_kernel, part=part),
+        functools.partial(_conv_kernel, part=part, bias=bias is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             in_specs=[anywhere] * len(xs) + [
-                pl.BlockSpec((km1 + 1, g, dk), lambda i, *_: (0, 0, 0)),
+                pl.BlockSpec((w.shape[0], g, dk), lambda i, *_: (0, 0, 0)),
                 anywhere,
             ],
             out_specs=[pl.BlockSpec((tile, g, dk), last_tile), anywhere],
@@ -397,7 +403,7 @@ def _conv_pallas(xs, stack, weights, layer, slots, fresh, query_start_loc,
         interpret=interpret,
     )(
         i32(layer).reshape(1), i32(total).reshape(1), qsl, i32(slots),
-        i32(fresh), row, *xs, w.reshape(km1 + 1, g, dk), stack,
+        i32(fresh), row, *xs, w.reshape(-1, g, dk), stack,
     )
     # The tokens past the live ones were never written.
     return jnp.where((ts < total)[:t, None, None], y[:t], 0.0), stack
@@ -416,10 +422,13 @@ def kda_short_conv(
     num_seqs: jnp.ndarray,  # scalar i32: rows [0, num_seqs) are live
     *,
     decode_only: bool = False,
+    bias: jnp.ndarray | None = None,  # [C], added to every live token's sum
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The depthwise causal convolution of a layer over each live row's
     segment, continued from the row's slot and stored back to it: ``(y [T,
-    G, dk] f32, stack)``; ``y`` is 0 past the live tokens.
+    G, dk] f32, stack)``; ``y`` is 0 past the live tokens. One stream or
+    several (the delta-rule mixer's q, k and v; the state-space mixer's one
+    of x, B and C, whose convolution carries a ``bias``).
 
     On the TPU one Pallas kernel (``kda_conv_kernel``) over the live
     tokens, ``CONV_TILE`` a step of its grid. A token is ``[G, dk]``, whole
@@ -454,7 +463,8 @@ def kda_short_conv(
     if kernel and all(x.dtype == stack.dtype for x in xs):
         return _conv_pallas(
             tuple(xs), stack, tuple(weights), layer, slots, fresh,
-            query_start_loc, n_live, tile=CONV_TILE, interpret=interpret)
+            query_start_loc, n_live, bias, tile=CONV_TILE,
+            interpret=interpret)
     r = slots.shape[0]
     flat = stack.reshape(lk, s1, km1 * g * dk)
     tail = jnp.where(
@@ -463,6 +473,8 @@ def kda_short_conv(
     y, new_tail = ragged_short_conv(
         jnp.concatenate(xs, axis=-1), tail, jnp.concatenate(weights, axis=-1),
         token_req_idx, query_start_loc, decode_only=decode_only)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     flat = write_slot_rows(flat, layer, slots, new_tail.reshape(r, -1), n_live)
     live = jnp.arange(t) < _live_tokens(query_start_loc, n_live)
     return (jnp.where(live[:, None, None], y.reshape(t, g, dk), 0.0),
@@ -1091,6 +1103,22 @@ def kda_chunks(
     return _chunks_xla(*args, n_chunks=nc)
 
 
+def rows_of_one_token(query_start_loc, num_seqs, slots, fresh, t: int, scratch: int):
+    """The live rows of ONE token gathered to the front of a mixed step, for
+    the one-token form of a slot-state rule: ``(tok [R] their tokens, slots
+    [R] with the scratch slot behind them, fresh [R] in their order, how
+    many, where [R] to put their outputs: ``t`` drops)``."""
+    r = slots.shape[0]
+    qlen = query_start_loc[1:] - query_start_loc[:-1]
+    one = (qlen == 1) & (jnp.arange(r) < num_seqs)
+    order = jnp.argsort(~one, stable=True)  # rows of one token first
+    n_one = jnp.sum(one.astype(jnp.int32))
+    front = jnp.arange(r) < n_one
+    tok = jnp.clip(query_start_loc[order], 0, t - 1)
+    return (tok, jnp.where(front, slots[order], scratch), fresh[order], n_one,
+            jnp.where(front, tok, t))
+
+
 def kda_attention(
     q, k, v, a, beta, state, layer, slots, fresh, query_start_loc,
     num_seqs, *, decode_only: bool,
@@ -1103,22 +1131,14 @@ def kda_attention(
     if decode_only:
         return kda_decode(q, k, v, a, beta, state, layer, slots, fresh,
                           num_seqs)
-    t = q.shape[0]
-    r = slots.shape[0]
-    qlen = query_start_loc[1:] - query_start_loc[:-1]
-    one = (qlen == 1) & (jnp.arange(r) < num_seqs)
-    order = jnp.argsort(~one, stable=True)  # rows of one token first
-    n_one = jnp.sum(one.astype(jnp.int32))
-    front = jnp.arange(r) < n_one
-    tok = jnp.clip(query_start_loc[order], 0, t - 1)
+    tok, slots_one, fresh_one, n_one, where = rows_of_one_token(
+        query_start_loc, num_seqs, slots, fresh, q.shape[0], state.shape[1] - 1)
     o_one, state = kda_decode(
-        q[tok], k[tok], v[tok], a[tok], beta[tok], state, layer,
-        jnp.where(front, slots[order], state.shape[1] - 1), fresh[order],
-        n_one,
+        q[tok], k[tok], v[tok], a[tok], beta[tok], state, layer, slots_one,
+        fresh_one, n_one,
     )
     o, state = kda_chunks(
         q, k, v, a, beta, state, layer, slots, fresh, query_start_loc,
         num_seqs,
     )
-    o = o.at[jnp.where(front, tok, t)].set(o_one, mode="drop")
-    return o, state
+    return o.at[where].set(o_one, mode="drop"), state

@@ -25,12 +25,44 @@ The O(T·H·P·N) materialization of ``dBx`` is the correctness-first
 choice; the chunked SSD matmul formulation (intra-chunk attention-like
 GEMMs + inter-chunk state scan) is the optimization seam for long
 prefills, same role the fused ``mamba_chunk_scan`` kernels play on CUDA.
+
+The rule over state SLOTS, in place (the end of this file; the functions
+above gather a step's rows of state out of the stack and scatter them
+back). ``ssd_attention`` sends the rows of one token to ``ssd_decode`` and
+the others to ``ssd_chunks``, as ``ops/kda.py::kda_attention`` does for the
+delta rule, over ``state [L, S + 1, H, P, N]`` float32 whose last slot is
+the padding rows' scratch:
+
+- ``ssd_decode``: one token a row, for the rows named live. On the TPU a
+  Pallas kernel (``ssd_decode_kernel``) whose grid walks (row, block of
+  heads); a block of a row's state (8 heads of ``[P, N]``, 1 MiB at 128 x
+  256) is read from its slot, updated (``a H + dt x B^T``, then ``H C``)
+  and written back through input-output aliasing; the steps past the live
+  rows map to the block already held and move nothing. Off the TPU (and as
+  the tests' oracle) the same update as einsums over every row.
+- ``ssd_chunks``: rows of more than one token, in chunks of ``SSD_CHUNK``
+  tokens inside a row's segment, ``n_chunks`` places a round of a
+  ``while_loop``. XLA: what does not depend on the incoming state for all
+  of a round's places at once (the chunk's pairwise decays and ``C B^T``),
+  then a scan over the places with three small matmuls each; a place reads
+  its row's slot (and only that) and writes the row's state after the
+  chunk back to it, so a row's slot holds its state at its last chunk and
+  no other row's slot is touched. Every ``exp`` is of a difference of
+  cumulated log-decays ``b_i - b_j`` with ``i >= j``, so of a number
+  ``<= 0``; nothing is divided by a decay.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from vllm_tpu.ops.kda import _chunk_places, _pallas_form, rows_of_one_token
 
 
 def ragged_causal_conv(
@@ -364,3 +396,269 @@ def ragged_ssd_scan(
     last = jnp.maximum(query_start_loc[1:] - 1, 0)
     new_state = h_all[last]  # [R, H, P, N]
     return y.astype(x.dtype), new_state.astype(h0.dtype)
+
+
+# ----------------------------------------------------------------------
+# The rule over state slots, in place
+# ----------------------------------------------------------------------
+
+SSD_CHUNK = 128  # tokens a chunk (the family's ``mamba_chunk_size``)
+SSD_HEADS = 8  # heads a step of the one-token kernel's grid
+_HI = lax.Precision.HIGH  # three bf16 passes: float32 to some 2**-21
+
+
+def _ssd_decode_kernel(
+    n_live_ref, slots_ref, fresh_ref, layer_ref,  # scalar prefetch
+    xt_ref,  # [P, hb]: dt * x, the head's channels on the sublanes
+    at_ref,  # [P, hb]: the head's decay on every sublane
+    b_ref, c_ref,  # [G, N]
+    s_ref,  # [hb, P, N]: these heads of this row's state
+    yt_ref,  # [P, hb]
+    s_out_ref,  # [hb, P, N], the same place of the same slot
+    *, heads_per_group: int,
+):
+    del slots_ref, layer_ref  # the index maps'
+    i, j = pl.program_id(0), pl.program_id(1)
+    hb = s_ref.shape[0]
+
+    @pl.when(i < n_live_ref[0])
+    def _():
+        fresh = fresh_ref[i] != 0
+        for h in range(hb):
+            g = (j * hb + h) // heads_per_group
+            # A request that starts reads nothing of what the slot held.
+            s = jnp.where(fresh, 0.0, s_ref[h])  # [P, N]
+            sn = s * at_ref[:, h : h + 1] + xt_ref[:, h : h + 1] * b_ref[pl.ds(g, 1), :]
+            s_out_ref[h] = sn
+            yt_ref[:, h : h + 1] = jnp.sum(
+                sn * c_ref[pl.ds(g, 1), :], axis=1, keepdims=True)
+
+
+def _heads_a_step(h: int) -> int:
+    return max(d for d in range(1, min(h, SSD_HEADS) + 1) if h % d == 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ssd_decode_pallas(x, dt, ln_a, b, c, state, layer, slots, fresh, n_live,
+                       *, interpret: bool = False):
+    r, h, p = x.shape
+    g, n = b.shape[1:]
+    hb = _heads_a_step(h)
+    nb = h // hb
+    # [R, H, P] -> [R, H / hb, P, hb]: a block's heads side by side on the
+    # lanes, so that a head's channels are a column beside its [P, N] state.
+    cols = lambda v: jnp.swapaxes(v.reshape(r, nb, hb, p), 2, 3)
+    xt = cols(x * dt[..., None])
+    at = cols(jnp.broadcast_to(jnp.exp(ln_a)[..., None], x.shape))
+
+    def place(i, j, n_):
+        """The step's (row, block of heads); past the live rows, the last
+        live row's last block: the block already held."""
+        dead = i >= n_[0]
+        row = jnp.maximum(jnp.minimum(i, n_[0] - 1), 0)
+        return row, jnp.where(dead, nb - 1, j)
+
+    def col_map(i, j, n_, *_):
+        return (*place(i, j, n_), 0, 0)
+
+    def row_map(i, j, n_, *_):
+        return (place(i, j, n_)[0], 0, 0)
+
+    def slot_map(i, j, n_, sl, fr, li):
+        row, blk = place(i, j, n_)
+        return (li[0], sl[row], blk, 0, 0)
+
+    col_spec = pl.BlockSpec((None, None, p, hb), col_map)
+    grp_spec = pl.BlockSpec((None, g, n), row_map)
+    slot_spec = pl.BlockSpec((None, None, hb, p, n), slot_map)
+    yt, new_state = pl.pallas_call(
+        functools.partial(_ssd_decode_kernel, heads_per_group=h // g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            in_specs=[col_spec, col_spec, grp_spec, grp_spec, slot_spec],
+            out_specs=[col_spec, slot_spec],
+            grid=(r, nb),
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((r, nb, p, hb), jnp.float32),
+            jax.ShapeDtypeStruct(state.shape, state.dtype),
+        ],
+        # Operand 8 (after the four prefetched) is the state stack.
+        input_output_aliases={8: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 << 20,
+        ),
+        name="ssd_decode_kernel",
+        interpret=interpret,
+    )(
+        n_live.astype(jnp.int32).reshape(1), slots.astype(jnp.int32),
+        fresh.astype(jnp.int32), layer.astype(jnp.int32).reshape(1),
+        xt, at, b, c, state,
+    )
+    return jnp.swapaxes(yt, 2, 3).reshape(r, h, p), new_state
+
+
+def _ssd_decode_xla(x, dt, ln_a, b, c, state, layer, slots, fresh, n_live):
+    r, h, p = x.shape
+    g = b.shape[1]
+    heads = lambda v: jnp.repeat(v, h // g, axis=1)  # [R, G, N] -> [R, H, N]
+    s0 = jnp.where(fresh[:, None, None, None], 0.0, state[layer, slots])
+    sn = s0 * jnp.exp(ln_a)[..., None, None] + (
+        (x * dt[..., None])[..., None] * heads(b)[:, :, None, :])
+    y = jnp.einsum("rhpn,rhn->rhp", sn, heads(c), precision=_HI)
+    live = jnp.arange(r) < n_live
+    # Rows past the live ones park in the last slot, the padding scratch.
+    where = jnp.where(live, slots, state.shape[1] - 1)
+    return y, state.at[layer, where].set(sn.astype(state.dtype))
+
+
+def ssd_decode(
+    x: jnp.ndarray,  # [R, H, P] f32: the convolved, activated inputs
+    dt: jnp.ndarray,  # [R, H] f32: the step, after its softplus
+    ln_a: jnp.ndarray,  # [R, H] f32: the log of the head's decay, <= 0
+    b: jnp.ndarray,  # [R, G, N] f32: a group serves H / G heads
+    c: jnp.ndarray,  # [R, G, N] f32
+    state: jnp.ndarray,  # [L, S, H, P, N] f32; slot S-1 is scratch
+    layer: jnp.ndarray,  # scalar i32
+    slots: jnp.ndarray,  # [R] i32; rows >= n_live must name the scratch slot
+    fresh: jnp.ndarray,  # [R] bool: the row starts its request
+    n_live: jnp.ndarray,  # scalar i32: rows [0, n_live) are updated
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One token of every live row, ``H_t = a H + dt x B^T``, ``y = H_t C``:
+    ``(y [R, H, P] f32, state)``. Rows past ``n_live`` are neither read nor
+    written; their ``y`` is 0. The skip ``D x`` is the caller's."""
+    args = (x, dt, ln_a, b, c, state, jnp.asarray(layer, jnp.int32), slots,
+            fresh, jnp.asarray(n_live, jnp.int32).reshape(()))
+    pallas, interpret = _pallas_form(state)
+    if pallas:
+        y, state = _ssd_decode_pallas(*args, interpret=interpret)
+    else:
+        y, state = _ssd_decode_xla(*args)
+    live = jnp.arange(x.shape[0]) < n_live
+    return jnp.where(live[:, None, None], y, 0.0), state
+
+
+def ssd_chunks(
+    x: jnp.ndarray,  # [T, H, P] f32
+    dt: jnp.ndarray,  # [T, H] f32
+    ln_a: jnp.ndarray,  # [T, H] f32 log-decay <= 0
+    b: jnp.ndarray,  # [T, G, N] f32
+    c: jnp.ndarray,  # [T, G, N] f32
+    state: jnp.ndarray,  # [L, S, H, P, N]; slot S-1 is scratch
+    layer: jnp.ndarray,  # scalar i32
+    slots: jnp.ndarray,  # [R] i32
+    fresh: jnp.ndarray,  # [R] bool: the row starts its request
+    query_start_loc: jnp.ndarray,  # [R+1]
+    num_seqs: jnp.ndarray,  # scalar i32
+    *,
+    n_chunks: int | None = None,
+    chunk: int | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Every live row with MORE than one token, by chunks. Returns ``(y
+    [T, H, P] f32, state)``; ``y`` is 0 at the tokens of other rows. Only
+    the slots of the rows that have chunks are read or written (the places
+    past the step's last chunk write the scratch slot)."""
+    t, h, p = x.shape
+    g, n = b.shape[1:]
+    hg = h // g
+    r = slots.shape[0]
+    cs = chunk or SSD_CHUNK
+    nc = n_chunks or (-(-t // cs) + 1)
+    layer = jnp.asarray(layer, jnp.int32)
+    num_seqs = jnp.asarray(num_seqs, jnp.int32).reshape(())
+    scratch = state.shape[1] - 1
+    qlen, per_row, ends, total = _chunk_places(query_start_loc, num_seqs, r, cs)
+    tril = jnp.tril(jnp.ones((cs, cs), bool))
+    zero = jnp.int32(0)
+
+    def one_round(carry):
+        rnd, s_in, state, out = carry
+        place = rnd * nc + jnp.arange(nc, dtype=jnp.int32)
+        row = jnp.clip(jnp.searchsorted(ends, place, side="right"), 0, r - 1)
+        j = place - (ends[row] - per_row[row])
+        live = place < total
+        n_valid = jnp.where(live, jnp.clip(qlen[row] - j * cs, 0, cs), 0)
+        offs = jnp.arange(cs, dtype=jnp.int32)
+        ok = offs[None, :] < n_valid[:, None]  # [N, C]
+        idx = jnp.clip(
+            query_start_loc[row][:, None] + j[:, None] * cs + offs, 0, t - 1)
+        take = lambda v: jnp.where(
+            ok.reshape(ok.shape + (1,) * (v.ndim - 1)), v[idx], 0.0)
+        xc, dtc, lac, bc, cc = take(x), take(dt), take(ln_a), take(b), take(c)
+        # What a chunk does without the state it meets. ``cum`` is the
+        # cumulated log-decay, falling; a token past the chunk's end holds
+        # dt = 0 and a log-decay of 0, so it adds and forgets nothing.
+        cum = jnp.cumsum(lac, axis=1)  # [N, C, H]
+        pair = jnp.exp(jnp.where(
+            tril[None, :, :, None], cum[:, :, None] - cum[:, None, :],
+            -jnp.inf)) * dtc[:, None]  # [N, i, j, H]
+        cb = jnp.einsum("kign,kjgn->kgij", cc, bc, precision=_HI)
+        w = cb[:, :, None] * jnp.moveaxis(pair, 3, 1).reshape(nc, g, hg, cs, cs)
+        y_in = jnp.einsum(
+            "kghij,kjghp->kighp", w, xc.reshape(nc, cs, g, hg, p),
+            precision=_HI)
+        e_b = jnp.exp(cum).reshape(nc, cs, g, hg)
+        u = xc.reshape(nc, cs, g, hg, p) * (
+            jnp.exp(cum[:, -1:] - cum) * dtc).reshape(nc, cs, g, hg)[..., None]
+        d_end = jnp.exp(cum[:, -1]).reshape(nc, g, hg)
+
+        def step(carry, xs):
+            s, state = carry
+            y_k, cc_k, bc_k, eb_k, u_k, dend_k, first_k, fresh_k, slot_k, live_k = xs
+            held = lax.dynamic_slice(
+                state, (layer, slot_k, zero, zero, zero), (1, 1, h, p, n)
+            )[0, 0].astype(jnp.float32)
+            s0 = jnp.where(first_k, jnp.where(fresh_k, 0.0, held), s)
+            s0 = s0.reshape(g, hg, p, n)
+            y_k = y_k + eb_k[..., None] * jnp.einsum(
+                "ign,ghpn->ighp", cc_k, s0, precision=_HI)
+            s1 = (dend_k[..., None, None] * s0 + jnp.einsum(
+                "jghp,jgn->ghpn", u_k, bc_k, precision=_HI)).reshape(h, p, n)
+            # The row's state after this chunk, to the row's own slot: its
+            # last chunk's is what stays.
+            state = lax.dynamic_update_slice(
+                state, s1[None, None].astype(state.dtype),
+                (layer, jnp.where(live_k, slot_k, scratch), zero, zero, zero))
+            return (s1, state), y_k.reshape(cs, h, p)
+
+        (s_out, state), y = lax.scan(
+            step, (s_in, state),
+            (y_in, cc, bc, e_b, u, d_end, live & (j == 0), fresh[row],
+             slots[row].astype(jnp.int32), live))
+        out = out.at[jnp.where(ok, idx, t).reshape(-1)].set(
+            y.reshape(nc * cs, h, p), mode="drop")
+        return rnd + 1, s_out, state, out
+
+    _, _, state, out = lax.while_loop(
+        lambda carry: carry[0] * nc < total,
+        one_round,
+        (jnp.int32(0), jnp.zeros((h, p, n), jnp.float32), state,
+         jnp.zeros((t, h, p), jnp.float32)),
+    )
+    return out, state
+
+
+def ssd_attention(
+    x, dt, ln_a, b, c, state, layer, slots, fresh, query_start_loc, num_seqs,
+    *, decode_only: bool,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The step's tokens through both forms. ``decode_only`` (static):
+    token ``i`` is row ``i`` and every live row holds one token, so only the
+    one-token form runs. Otherwise the rows of one token are gathered to
+    the front for it and the others go by chunks."""
+    num_seqs = jnp.asarray(num_seqs, jnp.int32).reshape(())
+    if decode_only:
+        return ssd_decode(x, dt, ln_a, b, c, state, layer, slots, fresh,
+                          num_seqs)
+    tok, slots_one, fresh_one, n_one, where = rows_of_one_token(
+        query_start_loc, num_seqs, slots, fresh, x.shape[0], state.shape[1] - 1)
+    y_one, state = ssd_decode(
+        x[tok], dt[tok], ln_a[tok], b[tok], c[tok], state, layer, slots_one,
+        fresh_one, n_one,
+    )
+    y, state = ssd_chunks(
+        x, dt, ln_a, b, c, state, layer, slots, fresh, query_start_loc,
+        num_seqs,
+    )
+    return y.at[where].set(y_one, mode="drop"), state

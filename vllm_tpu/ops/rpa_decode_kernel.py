@@ -535,6 +535,7 @@ def _validate(q, kv_pages, kv_lens, page_indices, num_seqs, head_major):
             raise ValueError(f"{name} must be int32, got {arr.dtype}")
     if num_q_heads % num_kv_heads != 0:
         raise ValueError(f"{num_q_heads=} % {num_kv_heads=} != 0")
+    return num_kv_heads
 
 
 def decode_tiling(num_rows, page_size, pages_per_seq,
@@ -631,9 +632,24 @@ def decode_paged_attention(
     output comes back in the step's own row order whatever it says, and a
     row's output does not depend on it; ``None`` walks the rows as given.
     """
-    _validate(q, kv_pages, kv_lens, page_indices, num_seqs, head_major)
+    num_kv_heads = _validate(
+        q, kv_pages, kv_lens, page_indices, num_seqs, head_major)
     if mask_value is None:
         mask_value = DEFAULT_MASK_VALUE
+    rows_in, heads_in, _ = q.shape
+    g_in = heads_in // num_kv_heads
+    g_whole = next(n for n in range(g_in, g_in + 8) if (num_kv_heads * n) % 8 == 0)
+    if g_whole != g_in:
+        # A row of q and of the output is [heads, lanes], tiles of 8 heads,
+        # and the kernel copies whole rows: Mosaic refuses 20 heads of a
+        # tile's 24. Each KV head's group is filled up with query heads of
+        # zeros (5 a KV head become 6), whose outputs are dropped below.
+        fill = ((0, 0), (0, g_whole - g_in))
+        q = jnp.pad(q.reshape(rows_in, num_kv_heads, g_in, -1),
+                    ((0, 0), *fill, (0, 0))).reshape(
+                        rows_in, num_kv_heads * g_whole, -1)
+        if sinks is not None:
+            sinks = jnp.pad(sinks.reshape(num_kv_heads, g_in), fill).reshape(-1)
     num_rows, num_q_heads, head_dim = q.shape
     _, _, page_size, kv_rows, kv_lanes = kv_pages.shape
     v_dim = head_dim
@@ -750,4 +766,8 @@ def decode_paged_attention(
         name=name,
         interpret=interpret,
     )
-    return kernel(*scalar_prefetches, *operands)[0][:num_rows]
+    out = kernel(*scalar_prefetches, *operands)[0][:num_rows]
+    if g_whole != g_in:
+        out = out.reshape(num_rows, num_kv_heads, g_whole, -1)[
+            :, :, :g_in].reshape(num_rows, heads_in, -1)
+    return out

@@ -703,9 +703,13 @@ class ModelRunner:
         split = getattr(m, "ragged_kernel_geometry", None)
         if split:
             return split(self.block_size), True
+        # (a cache of the model's own making counts where the model says
+        # that its pages are plain K/V under ``paged_attention``, beside
+        # whatever else it keeps: ``plain_kv_pages``)
         plain = all(hasattr(m, a) for a in
-                    ("num_heads", "num_kv_heads", "head_dim")) and not (
-            hasattr(m, "kv_cache_shape") or hasattr(m, "alloc_kv_cache"))
+                    ("num_heads", "num_kv_heads", "head_dim")) and (
+            getattr(m, "plain_kv_pages", False) or not (
+                hasattr(m, "kv_cache_shape") or hasattr(m, "alloc_kv_cache")))
         if not plain:
             return None
         return (m.num_heads, m.num_kv_heads, m.head_dim, self.block_size), False
@@ -718,7 +722,7 @@ class ModelRunner:
         if key not in self._ragged_tilings:
             geometry, split = self._attn_call_geometry or (None, False)
             self._ragged_tilings[key] = geometry and (ragged_kernel_tiling(
-                *geometry, self.model.dtype, self.kv_cache.dtype, t_pad,
+                *geometry, self.model.dtype, self._kv_dtype(), t_pad,
                 b_pad, block_len=block_len, tp_size=self.tp_size, split=split,
             ), geometry[3])
         tiling, page_size = self._ragged_tilings[key] or (None, 0)
