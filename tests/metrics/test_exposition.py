@@ -351,7 +351,8 @@ def test_diffusion_and_moe_counters_are_cumulative_and_never_decrease():
     reg.record(SchedulerStats(
         diffusion_passes_by_kind={"denoise": 20, "store": 10},
         diffusion_committed_tokens=40, moe_assignments=4480,
-        moe_experts_hit=896, attn_context_tokens_by_kind={"block": 16000}))
+        moe_experts_hit=896, moe_pad_pairs_skipped=20832,
+        attn_context_tokens_by_kind={"block": 16000}))
     reg.record(SchedulerStats())  # a respawned engine lowers nothing
     reg.record(SchedulerStats(diffusion_passes_by_kind={"denoise": 22},
                               moe_experts_hit=900))
@@ -361,11 +362,13 @@ def test_diffusion_and_moe_counters_are_cumulative_and_never_decrease():
                    "vllm:diffusion_committed_tokens_total 40.0",
                    "vllm:moe_assignments_total 4480.0",
                    "vllm:moe_experts_hit_total 900.0",
+                   "vllm:moe_pad_pairs_skipped_total 20832.0",
                    'vllm:attn_context_tokens_total{kind="block"} 16000.0'):
         assert sample in text, sample
     for family in ("vllm:diffusion_passes_total",
                    "vllm:diffusion_committed_tokens_total",
-                   "vllm:moe_assignments_total", "vllm:moe_experts_hit_total"):
+                   "vllm:moe_assignments_total", "vllm:moe_experts_hit_total",
+                   "vllm:moe_pad_pairs_skipped_total"):
         assert f"# TYPE {family} counter" in text
 
 
@@ -466,4 +469,36 @@ def test_runner_counts_passes_commits_and_experts_on_a_hand_made_batch(
     steps = 5 if room else 7
     assert runner.step_launches == steps
     assert steps * 2 * layers <= runner.moe_experts_hit <= steps * 8 * layers
+    llm.shutdown()
+
+
+def test_runner_counts_the_pad_tokens_pairs_apart_from_the_live_ones(tmp_path):
+    """A step of 140 live tokens in the one token bucket of 512, as
+    ``sdar30b.chat`` pins it: the 372 pad tokens' pairs are counted as
+    skipped, a layer with experts each, and the live tokens' pairs are
+    what they were."""
+    from tests.benchmark.tiny_sdar_moe import TINY_SDAR, model_dir
+    from vllm_tpu import LLM, SamplingParams
+
+    llm = LLM(model=model_dir(tmp_path), dtype="float32", load_format="dummy",
+              max_model_len=256, block_size=16, max_num_batched_tokens=512,
+              token_buckets="512", max_num_seqs=4, num_gpu_blocks_override=64,
+              async_scheduling=False)
+    runner = llm.llm_engine.engine_core.engine_core.executor.worker.runner
+    layers, top = TINY_SDAR["num_hidden_layers"], TINY_SDAR["num_experts_per_tok"]
+    llm.llm_engine.add_request(
+        "a", {"prompt_token_ids": list(range(1, 141))},
+        SamplingParams(temperature=0.0, max_tokens=4, ignore_eos=True))
+    llm.llm_engine.step()
+    assert runner.step_launches == 1
+    assert runner.step_tokens_by_kind == {"decode": 0, "mixed": 140}
+    assert runner.moe_assignments == 140 * top * layers
+    assert runner.moe_pad_pairs_skipped == 372 * top * layers
+    while llm.llm_engine.has_unfinished_requests():
+        llm.llm_engine.step()
+    # Every step of the request ran the one shape.
+    tokens = sum(runner.step_tokens_by_kind.values())
+    assert tokens > 140 and runner.moe_assignments == tokens * top * layers
+    assert runner.moe_pad_pairs_skipped == (
+        (runner.step_launches * 512 - tokens) * top * layers)
     llm.shutdown()

@@ -85,13 +85,42 @@ def test_dense_moe_matches_per_token_loop():
     np.testing.assert_allclose(np.asarray(out), expect, rtol=2e-4, atol=2e-4)
 
 
-def test_grouped_matches_dense_interpret():
-    """megablox grouped path (interpret mode on CPU) == dense path."""
+@pytest.fixture
+def gmm_calls(monkeypatch):
+    """megablox's ``gmm`` as ``_grouped_moe`` calls it, with every row past
+    the groups POISONED in its answer (the kernel does not write them; what
+    the buffer held is the caller's to keep out) and each call's
+    ``group_sizes`` recorded."""
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    real, sizes_seen = megablox.gmm, []
+
+    def poisoned(lhs, rhs, group_sizes, **kw):
+        sizes_seen.append(np.asarray(group_sizes))
+        out = real(lhs, rhs, group_sizes, **kw)
+        ran = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(ran[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(megablox, "gmm", poisoned)
+    return sizes_seen
+
+
+@pytest.mark.parametrize("n_live, held", [
+    (None, None), (11, None), (11, (1, 2))], ids=["every_token", "live_prefix",
+                                                   "live_prefix_and_held"])
+def test_grouped_matches_dense_interpret(gmm_calls, n_live, held):
+    """megablox grouped path (interpret mode on CPU) == dense path on the
+    live tokens, under a mask of live tokens and under a share; a pad
+    token's output is exactly 0, whatever the rows the kernel left out
+    hold."""
     from vllm_tpu.layers.moe import _grouped_moe, select_experts
 
     rng = np.random.default_rng(2)
     t, d, f, e, k = 16, 128, 128, 4, 2
-    hidden = jnp.asarray(rng.standard_normal((t, d)) * 0.3, jnp.float32)
+    hidden = rng.standard_normal((t, d)) * 0.3
+    if n_live is not None:
+        hidden[n_live:] = hidden[n_live]  # pad slots are all alike
+    hidden = jnp.asarray(hidden, jnp.float32)
     wg = jnp.asarray(rng.standard_normal((e, d, f)) * 0.05, jnp.float32)
     wu = jnp.asarray(rng.standard_normal((e, d, f)) * 0.05, jnp.float32)
     wd = jnp.asarray(rng.standard_normal((e, f, d)) * 0.05, jnp.float32)
@@ -99,11 +128,75 @@ def test_grouped_matches_dense_interpret():
 
     logits = hidden @ router
     w, ids = select_experts(logits, k)
-    dense = _dense_moe(hidden, wg, wu, wd, w, ids)
-    grouped = _grouped_moe(hidden, wg, wu, wd, w, ids, interpret=True)
+    live = None if n_live is None else jnp.arange(t) < n_live
+    first, count = held or (0, e)
+    dense = _dense_moe(
+        hidden, *(x[first:first + count] for x in (wg, wu, wd)), w, ids,
+        first_expert=first)
+    grouped = np.asarray(_grouped_moe(
+        hidden, wg, wu, wd, w, ids, interpret=True, held=held, live=live))
+    assert np.isfinite(grouped).all()
+    rows = slice(None, n_live)
     np.testing.assert_allclose(
-        np.asarray(dense), np.asarray(grouped), rtol=2e-4, atol=2e-4
+        np.asarray(dense)[rows], grouped[rows], rtol=2e-4, atol=2e-4
     )
+    assert np.abs(np.asarray(dense)[rows]).max() > 1e-3
+    if n_live is not None:
+        assert not grouped[n_live:].any()
+    # What the kernel was handed: the pairs of live tokens on held experts.
+    ids = np.asarray(ids)[rows]
+    want = np.bincount(ids.reshape(-1), minlength=e)
+    want[:first], want[first + count:] = 0, 0
+    assert len(gmm_calls) == 3
+    for sizes in gmm_calls:
+        np.testing.assert_array_equal(sizes, want)
+
+
+def test_the_mask_takes_the_pad_tiles_off_the_grouped_matmul(gmm_calls):
+    """At ``sdar30b.chat``'s shape, 512 tokens of which 140 live, 8 of 128
+    experts a token, the pad slots alike: without the mask their 372 x 8
+    pairs stand on eight experts as whole row tiles; with it megablox's own
+    metadata has only the live pairs' tiles left to visit. (Alike is the
+    kindest case: on the chip a pad slot holds what the attention kernel
+    left in its buffer, and the pad pairs spread over most experts.)"""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata,
+    )
+
+    from vllm_tpu.layers.moe import _grouped_moe, select_experts
+
+    rng = np.random.default_rng(5)
+    t, n_live, d, f, e, k = 512, 140, 128, 128, 128, 8
+    hidden = rng.standard_normal((t, d))
+    hidden[n_live:] = hidden[n_live]
+    hidden = jnp.asarray(hidden, jnp.float32)
+    router = jnp.asarray(rng.standard_normal((d, e)), jnp.float32)
+    w, ids = select_experts(hidden @ router, k)
+    # One expert's matrices for all: only the kernel's bookkeeping is read.
+    wg = jnp.zeros((e, d, f), jnp.float32)
+    wd = jnp.zeros((e, f, d), jnp.float32)
+
+    def tiles(live):
+        del gmm_calls[:]
+        _grouped_moe(hidden, wg, wg, wd, w, ids, interpret=True, live=live)
+        sizes = gmm_calls[0]
+        _, n = make_group_metadata(
+            group_sizes=jnp.asarray(sizes), m=t * k, tm=128,
+            start_group=jnp.int32(0), num_nonzero_groups=e,
+            visit_empty_groups=False)
+        return sizes, int(n)
+
+    all_sizes, all_tiles = tiles(None)
+    live_sizes, live_tiles = tiles(jnp.arange(t) < n_live)
+    assert all_sizes.sum() == t * k and live_sizes.sum() == n_live * k
+    pad_experts = np.flatnonzero(all_sizes - live_sizes)
+    assert len(pad_experts) == k
+    assert ((all_sizes - live_sizes)[pad_experts] == t - n_live).all()
+    # A visit is a (group, row tile) pair: groups hit plus row tiles at
+    # most, less one. 372 rows an expert are three or four tiles each.
+    assert live_tiles <= np.count_nonzero(live_sizes) + n_live * k // 128
+    assert all_tiles - live_tiles >= k * ((t - n_live) // 128 - 1)
+    assert live_tiles < all_tiles
 
 
 def test_mixtral_e2e_greedy_matches_hf(tiny_mixtral):

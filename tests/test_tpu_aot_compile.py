@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jax_core
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
@@ -609,6 +610,50 @@ def test_layer_scan_reads_each_weight_once_in_place(
     )
 
 
+def _gmm_group_sizes_come_from(jaxpr, source: int) -> list[bool]:
+    """For every call of megablox's ``gmm`` in the traced program, in order:
+    whether its ``group_sizes`` operand is computed from the program's flat
+    input ``source`` through integers and booleans ALONE. Activations are
+    floats, so what a token holds never counts; a count of live tokens
+    does, through a mask, a select on the expert ids and a bincount."""
+
+    def walk(jaxpr, from_source):
+        seen = {v for v, hit in zip(jaxpr.invars, from_source) if hit}
+        found = []
+
+        def hit(v):
+            return not isinstance(v, jax_core.Literal) and v in seen
+
+        for eqn in jaxpr.eqns:
+            ins = [hit(v) for v in eqn.invars]
+            name = eqn.primitive.name
+            if name in ("jit", "pjit") and eqn.params["name"] == "gmm":
+                found.append(ins[2])
+            inner = [getattr(p, "jaxpr", p) for p in eqn.params.values()
+                     if isinstance(p, (jax_core.Jaxpr, jax_core.ClosedJaxpr))]
+            inner = [j for j in inner if len(j.invars) == len(ins)
+                     and len(j.outvars) == len(eqn.outvars)]
+            if inner and name != "pallas_call":
+                outs, calls = walk(inner[0], ins)
+                if name == "scan":
+                    # A carry that picks it up on the way goes round again.
+                    lo = eqn.params["num_consts"]
+                    carry = slice(lo, lo + eqn.params["num_carry"])
+                    while any(o and not i for i, o in zip(ins[carry], outs)):
+                        ins[carry] = [i or o for i, o in zip(ins[carry], outs)]
+                        outs, calls = walk(inner[0], ins)
+                found += calls
+            else:
+                outs = [any(ins)] * len(eqn.outvars)
+            seen.update(
+                v for v, out in zip(eqn.outvars, outs)
+                if out and v.aval.dtype.kind in "iub")
+        return [hit(v) for v in jaxpr.outvars], found
+
+    flat = jaxpr.jaxpr
+    return walk(flat, [i == source for i in range(len(flat.invars))])[1]
+
+
 # SDAR-30B-A3B-Chat at the depth the benchmark's cell runs it (7 of 48
 # layers), with about the cell's KV cache.
 SDAR = dict(
@@ -670,11 +715,22 @@ def test_block_diffusion_step_compiles_and_reads_each_weight_in_place(
            jnp.bfloat16)
 
     model.return_expert_counts = True  # as the runner sets it
-    text = jax.jit(model.apply, donate_argnums=(1,)).lower(
-        params, kv, s((t,), jnp.int32), md
-    ).compile().as_text()
+    args = (params, kv, s((t,), jnp.int32), md)
+    traced = jax.jit(model.apply, donate_argnums=(1,)).trace(*args)
+    text = traced.lower().compile().as_text()
 
     assert "rpa_kernel" in text and "gmm" in text
+    # No pad row is booked on a group: the three grouped matmuls of a layer
+    # get ``group_sizes`` that are computed from the step's count of live
+    # rows (through the live mask). The check can tell: with the mask taken
+    # off the call they are the router's choices and nothing else.
+    num_seqs = [x is md.num_seqs for x in jax.tree.leaves(args)].index(True)
+    assert _gmm_group_sizes_come_from(traced.jaxpr, num_seqs) == [True] * 3
+    grouped = moe._grouped_moe
+    monkeypatch.setattr(
+        moe, "_grouped_moe", lambda *a, live, **kw: grouped(*a, **kw))
+    unmasked = jax.jit(model.apply).trace(*args).jaxpr
+    assert _gmm_group_sizes_come_from(unmasked, num_seqs) == [False] * 3
     # No result in the layer scan's body has the shape of a layer's weight
     # (or of its transpose): each is read where it lies, the three expert
     # stacks by the grouped matmul itself, whole.

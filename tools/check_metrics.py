@@ -103,6 +103,7 @@ REQUIRED_DIFFUSION_MOE_METRICS = {
     "vllm:diffusion_stores_fused_total",
     "vllm:moe_assignments_total",
     "vllm:moe_experts_hit_total",
+    "vllm:moe_pad_pairs_skipped_total",
 }
 DIFFUSION_PASS_KINDS = ("denoise", "store")
 REQUIRED_STEP_PHASES = ("schedule", "dispatch", "prepare", "finalize",
@@ -354,7 +355,7 @@ def check_step_exposition() -> list[str]:
 def check_diffusion_exposition() -> list[str]:
     """The runner's counters of generation by diffusion over blocks and of
     the mixture of experts through a fresh registry: both kinds of pass,
-    the block rows' context and the three plain counters must come out."""
+    the block rows' context and the plain counters must come out."""
     from vllm_tpu.core.sched_output import SchedulerStats
     from vllm_tpu.metrics.prometheus import PrometheusRegistry
 
@@ -363,7 +364,7 @@ def check_diffusion_exposition() -> list[str]:
         reg.record(SchedulerStats(
             diffusion_passes_by_kind={k: 2 for k in DIFFUSION_PASS_KINDS},
             diffusion_committed_tokens=3, diffusion_stores_fused=4,
-            moe_assignments=5, moe_experts_hit=7,
+            moe_assignments=5, moe_experts_hit=7, moe_pad_pairs_skipped=13,
             attn_context_tokens_by_kind={"block": 11}))
     except (TypeError, AttributeError) as e:
         return [f"the diffusion stats no longer reach the registry: {e}"]
@@ -374,6 +375,7 @@ def check_diffusion_exposition() -> list[str]:
                "vllm:diffusion_stores_fused_total 4.0",
                "vllm:moe_assignments_total 5.0",
                "vllm:moe_experts_hit_total 7.0",
+               "vllm:moe_pad_pairs_skipped_total 13.0",
                'vllm:attn_context_tokens_total{kind="block"} 11.0']
     return [f"/metrics lacks the sample {w!r}" for w in wanted
             if w not in text]

@@ -303,13 +303,15 @@ class SchedulerStats:
     # the next counts once, as "denoise"), positions committed by
     # denoising passes, and the stores that rode with a denoising pass.
     # Mixture of experts (cumulative, from the runner): token-expert pairs
-    # over live tokens and experts that got a live token, both summed over
+    # over live tokens, experts that got a live token, and the pad tokens'
+    # pairs that were kept out of the grouped matmul, all summed over
     # layers and steps.
     diffusion_passes_by_kind: dict[str, int] = field(default_factory=dict)
     diffusion_committed_tokens: int = 0
     diffusion_stores_fused: int = 0
     moe_assignments: int = 0
     moe_experts_hit: int = 0
+    moe_pad_pairs_skipped: int = 0
     # Linear attention over state slots (cumulative, from the runner, a
     # layer of that kind): rows whose state a step read and wrote, tokens
     # by the form that took them; and the slots held now.

@@ -434,7 +434,8 @@ class EngineCore:
                 getattr(runner, "diffusion_passes_by_kind", {}))
             for name in ("diffusion_committed_tokens",
                          "diffusion_stores_fused", "moe_assignments",
-                         "moe_experts_hit", "linear_attn_state_rows",
+                         "moe_experts_hit", "moe_pad_pairs_skipped",
+                         "linear_attn_state_rows",
                          "window_attn_rows", "window_attn_pairs",
                          "attn_sink_rows"):
                 setattr(stats, name, getattr(runner, name, 0))

@@ -134,24 +134,42 @@ def _grouped_moe(
     act_fn=None,
     biases=None,  # (b_gate [E,F], b_up [E,F], b_down [E,D]) or None
     held: tuple[int, int] | None = None,
+    live: jnp.ndarray | None = None,  # [T] bool, tokens that are not padding
 ) -> jnp.ndarray:
     """Sort-by-expert + megablox grouped matmul (single-device fast path).
 
-    ``held = (first, count)``: of the groups ``w_*`` stack, only ``count``
-    from ``first`` on can be routed to here (an expert-parallel share,
-    ``expert_ids`` already moved into the stack's numbering); a pair whose
-    id lies outside sorts behind every held one and belongs to no group,
-    so the kernel never visits its row and its part of the sum is 0."""
+    One rule for the pairs that are NOT run: such a pair gets the id ``e``,
+    sorts behind every group and is counted in no ``group_sizes``, so the
+    kernel never visits its row, and the combine takes 0 for it. Two things
+    make a pair one of these:
+
+    - ``live``: its token is padding. A step's pad slots are routed like
+      any token (the router is a dense matmul over ``T``), and what they
+      hold is whatever the attention kernel left in its buffer (it writes
+      no row past the live ones): run, they spread over most experts and
+      have the kernel fetch the matrices of experts that no live token hit.
+      They are counted nowhere and computed nowhere, and a pad token's
+      output is exactly 0. ``None`` (a caller that knows of no padding)
+      runs every token.
+    - ``held = (first, count)``: of the groups ``w_*`` stack, only ``count``
+      from ``first`` on can be routed to here (an expert-parallel share,
+      ``expert_ids`` already moved into the stack's numbering), and its id
+      lies outside."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     t, d = hidden.shape
     e = w_gate.shape[0]
     k = expert_ids.shape[1]
-    flat_experts = expert_ids.reshape(-1)  # [T*k]
+    run = None  # [T, k] bool, None = every pair
     if held is not None:
         first, count = held
-        mine = (flat_experts >= first) & (flat_experts < first + count)
-        flat_experts = jnp.where(mine, flat_experts, e)
+        run = (expert_ids >= first) & (expert_ids < first + count)
+    if live is not None:
+        run = live[:, None] if run is None else run & live[:, None]
+    if run is not None:
+        run = jnp.broadcast_to(run, expert_ids.shape)
+        expert_ids = jnp.where(run, expert_ids, e)
+    flat_experts = expert_ids.reshape(-1)  # [T*k]
     order = jnp.argsort(flat_experts)  # stable
     token_idx = order // k  # source token of each sorted slot
     x_sorted = hidden[token_idx]  # [T*k, D]
@@ -159,15 +177,13 @@ def _grouped_moe(
         jnp.int32
     )
 
-    # gmm tiles rows by 128: pad the row dim, book the pad rows on the last
-    # group (their garbage output is dropped by the unsort gather below).
-    # Under a share nothing is booked: rows past the groups are not run.
+    # gmm tiles rows by 128: pad the row dim. The pad rows lie past every
+    # group, like the pairs that are not run, and the unsort below never
+    # reads them.
     m = t * k
     m_pad = -(-m // 128) * 128
     if m_pad != m:
         x_sorted = jnp.pad(x_sorted, ((0, m_pad - m), (0, 0)))
-        if held is None:
-            group_sizes = group_sizes.at[e - 1].add(m_pad - m)
 
     def mm(lhs, rhs, sizes):
         return gmm(
@@ -178,8 +194,8 @@ def _grouped_moe(
     gate = mm(x_sorted, w_gate, group_sizes)
     up = mm(x_sorted, w_up, group_sizes)
     if biases is not None:
-        # Per-row expert ids of the SORTED layout (pad rows were booked
-        # on the last group; their biased garbage is dropped at unsort).
+        # Per-row expert ids of the SORTED layout (rows past the groups
+        # take the last expert's: their biased garbage is never read).
         sorted_e = jnp.minimum(flat_experts[order], e - 1)
         if m_pad != m:
             sorted_e = jnp.concatenate(
@@ -193,17 +209,17 @@ def _grouped_moe(
     out_sorted = mm(act, w_down, group_sizes).astype(jnp.float32)  # [M, D]
     if biases is not None:
         out_sorted = out_sorted + biases[2][sorted_e].astype(jnp.float32)
-    if held is not None:
-        # Rows the kernel did not run hold whatever the buffer held.
-        ran = jnp.arange(m_pad) < jnp.sum(group_sizes)
-        out_sorted = jnp.where(ran[:, None], out_sorted, 0.0)
 
-    # Unsort and combine with routing weights.
+    # Unsort and combine with routing weights. Rows the kernel did not run
+    # hold whatever the buffer held, and so may a pad token's weights (its
+    # input is no request's): the one place that keeps both out.
     inv = jnp.argsort(order)
     out = out_sorted[inv].reshape(t, k, d)
-    return jnp.einsum(
-        "tkd,tk->td", out, weights.astype(jnp.float32)
-    ).astype(hidden.dtype)
+    weights = weights.astype(jnp.float32)
+    if run is not None:
+        out = jnp.where(run[:, :, None], out, 0.0)
+        weights = jnp.where(run, weights, 0.0)
+    return jnp.einsum("tkd,tk->td", out, weights).astype(hidden.dtype)
 
 
 def _gmm_tiling(k: int, n: int) -> tuple[int, int, int]:
@@ -440,6 +456,7 @@ def fused_experts(
     act_fn=None,
     biases=None,
     first_expert: int | None = None,
+    live: jnp.ndarray | None = None,  # [T] bool, tokens that are not padding
 ) -> jnp.ndarray:
     """Experts + combine for pre-computed routing (custom gating schemes —
     DeepSeek group-limited / sigmoid-bias routing — share the expert
@@ -454,7 +471,12 @@ def fused_experts(
     share of an expert-parallel deployment, without its exchange). The
     ids stay the router's; pairs routed to experts held elsewhere are
     dropped before the expert compute and add nothing to the result,
-    which is this share's part of the layer's sum."""
+    which is this share's part of the layer's sum.
+
+    ``live``: the step's tokens that are not padding. The grouped path
+    runs no pair of the others and gives them exactly 0; the paths that
+    compute every row whatever is live (dense, expert-parallel) ignore
+    it. ``None`` = every token is live."""
     if first_expert is not None and ep_mesh is not None:
         raise NotImplementedError(
             "an expert share is one device's; a mesh shards the experts "
@@ -484,7 +506,7 @@ def fused_experts(
             hidden, w_gate, w_up, w_down, weights,
             expert_ids - first_expert if share else expert_ids,
             act_fn=act_fn, biases=biases,
-            held=(0, w_gate.shape[0]) if share else None,
+            held=(0, w_gate.shape[0]) if share else None, live=live,
         )
     return _dense_moe(
         hidden, w_gate, w_up, w_down, weights, expert_ids,
@@ -510,6 +532,7 @@ def fused_experts_of_layer(
     ep_mesh=None,
     ep_axis: str | None = None,
     first_expert: int | None = None,
+    live: jnp.ndarray | None = None,  # [T] bool, tokens that are not padding
 ) -> jnp.ndarray:
     """``fused_experts`` for a layer scan that holds its expert weights as
     ``[L, E, ...]`` stacks. On the grouped (megablox) path the stacks go to
@@ -527,7 +550,7 @@ def fused_experts_of_layer(
         return fused_experts(
             hidden, w_gate[layer], w_up[layer], w_down[layer], weights,
             expert_ids, ep_mesh=ep_mesh, ep_axis=ep_axis,
-            first_expert=first_expert,
+            first_expert=first_expert, live=live,
         )
     n_layers, e = w_gate.shape[:2]
     share = first_expert is not None
@@ -539,7 +562,7 @@ def fused_experts_of_layer(
         weights, expert_ids - (first_expert or 0) + layer * e,
         # Ids below the share's first fall into the layer before: the
         # held range keeps them out.
-        held=(layer * e, e) if share else None,
+        held=(layer * e, e) if share else None, live=live,
     )
 
 
@@ -595,13 +618,13 @@ def share_of_sparse_layer(
             out = fused_experts(
                 h2, experts["we_gate"], experts["we_up"],
                 experts["we_down"], weights, ids,
-                first_expert=first_expert,
+                first_expert=first_expert, live=live,
             )
         else:
             out = fused_experts_of_layer(
                 h2, experts["we_gate"], experts["we_up"],
                 experts["we_down"], row, weights, ids,
-                first_expert=first_expert,
+                first_expert=first_expert, live=live,
             )
     if shared:
         with jax.named_scope("moe_shared"):

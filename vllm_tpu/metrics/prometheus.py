@@ -388,6 +388,11 @@ class PrometheusRegistry:
             "vllm:moe_experts_hit_total",
             "Experts that got at least one live token, counted per layer "
             "and step and summed")
+        self.moe_pad_pairs_skipped = Counter(
+            "vllm:moe_pad_pairs_skipped_total",
+            "Token-expert pairs of the pad tokens of dispatched steps, "
+            "which are routed (to any expert, held here or not) and kept "
+            "out of the grouped matmul, summed over layers")
         self.linear_attn_state_rows = Counter(
             "vllm:linear_attn_state_rows_total",
             "Rows whose linear-attention state a dispatched step reads "
@@ -813,6 +818,7 @@ class PrometheusRegistry:
             self.diffusion_passes, self.diffusion_committed,
             self.diffusion_stores_fused,
             self.moe_assignments, self.moe_experts_hit,
+            self.moe_pad_pairs_skipped,
             self.linear_attn_state_rows, self.linear_attn_tokens,
             self.window_attn_rows, self.window_attn_pairs,
             self.kv_group_pages_in_use, self.kv_pages_allocated,
@@ -987,6 +993,8 @@ class PrometheusRegistry:
                 float(s.diffusion_stores_fused))
             self.moe_assignments.inc_to(float(s.moe_assignments))
             self.moe_experts_hit.inc_to(float(s.moe_experts_hit))
+            self.moe_pad_pairs_skipped.inc_to(
+                float(s.moe_pad_pairs_skipped))
             self.linear_attn_state_rows.inc_to(
                 float(s.linear_attn_state_rows))
             self.window_attn_rows.inc_to(float(s.window_attn_rows))

@@ -381,6 +381,7 @@ class DeepseekV2ForCausalLM:
 
         cos = self.rope.cos[md.positions][:, None, :]  # [T, 1, DR/2]
         sin = self.rope.sin[md.positions][:, None, :]
+        live = md.live_tokens()
 
         def attention(lp, x, kv, li):
             h = rms_norm(x, lp["input_norm"], self.rms_eps)
@@ -411,7 +412,7 @@ class DeepseekV2ForCausalLM:
                 h2, lp["we_gate"], lp["we_up"], lp["we_down"], weights, ids,
                 use_grouped=None if not self.expert_parallel else False,
                 ep_mesh=self.ep_mesh if self.expert_parallel else None,
-                ep_axis="tp",
+                ep_axis="tp", live=live,
             )
             out = routed
             if self.n_shared:

@@ -168,6 +168,7 @@ class GptOssForCausalLM(MixtralForCausalLM):
         H, KH, Dh = self.num_heads, self.num_kv_heads, self.head_dim
         rope_cos, rope_sin = self.rope.cos, self.rope.sin
         layer_windows = jnp.asarray(self._layer_window)
+        live = md.live_tokens()
 
         def layer_fn(carry, inputs):
             x, kv = carry
@@ -209,6 +210,7 @@ class GptOssForCausalLM(MixtralForCausalLM):
                 h2, lp["we_gate"], lp["we_up"], lp["we_down"], weights, ids,
                 act_fn=_clamped_glu,
                 biases=(lp["be_gate"], lp["be_up"], lp["be_down"]),
+                live=live,
             )
             return (x + moe_out, kv), None
 

@@ -297,6 +297,7 @@ class JambaForCausalLM:
         slots = md.state_slots  # [R]
         first_pos = md.positions[jnp.clip(md.query_start_loc[:-1], 0, t - 1)]
         fresh = first_pos == 0
+        live = md.live_tokens()
         kv_scale = kv_dequant_scale(paged)
 
         def attn_layer(x, lp, attn_li):
@@ -378,7 +379,7 @@ class JambaForCausalLM:
                 weights, ids = select_experts(logits, self.top_k, False)
                 ffn = fused_experts(
                     h2, lp["we_gate"], lp["we_up"], lp["we_down"],
-                    weights, ids,
+                    weights, ids, live=live,
                 )
             else:
                 gate_up = jnp.concatenate(

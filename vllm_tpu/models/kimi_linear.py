@@ -585,7 +585,7 @@ class KimiLinearForCausalLM:
             tok_req, qsl = md.token_req_idx, md.query_start_loc
         first_pos = md.positions[jnp.clip(qsl[:-1], 0, t - 1)]
         fresh = first_pos == 0
-        live = jnp.arange(t) < md.query_start_loc[md.num_seqs[0]]
+        live = md.live_tokens()
         mla_kw = dict(
             num_heads=self.num_heads, nope_dim=self.qk_nope_head_dim,
             rope_dim=self.qk_rope_head_dim, v_dim=self.v_head_dim,

@@ -469,7 +469,7 @@ class LagunaForCausalLM:
             "a block table a KV group", md.block_tables.shape)
         x = params["embed"][input_ids].astype(self.dtype)
         t = x.shape[0]
-        live = jnp.arange(t) < md.query_start_loc[md.num_seqs[0]]
+        live = md.live_tokens()
         experts = params["experts"]
         k = self.window_per_period
 

@@ -480,7 +480,7 @@ class MiMoV2ForCausalLM:
             "a block table a KV group", md.block_tables.shape)
         x = params["embed"][input_ids].astype(self.dtype)
         t = x.shape[0]
-        live = jnp.arange(t) < md.query_start_loc[md.num_seqs[0]]
+        live = md.live_tokens()
         experts = params.get("experts")
         groups = jnp.asarray([g for g, _ in self.kv_place], jnp.int32)
         places = jnp.asarray([p for _, p in self.kv_place], jnp.int32)

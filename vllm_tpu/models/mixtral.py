@@ -176,6 +176,11 @@ class MixtralForCausalLM(LlamaForCausalLM):
             k: v for k, v in params["layers"].items() if k not in expert_keys
         }
         count_experts = self.enable_eplb or self.return_expert_counts
+        # Pad slots are routed like any token (the router is a dense matmul
+        # over ``t``), wherever what the attention kernel left in them
+        # leads; they are counted nowhere and computed nowhere
+        # (layers/moe.py::_grouped_moe).
+        live = md.live_tokens()
 
         def layer_fn(carry, inputs):
             x, kv = carry
@@ -226,12 +231,7 @@ class MixtralForCausalLM(LlamaForCausalLM):
             counts_l = None
             if count_experts:
                 # Load statistics in LOGICAL expert ids over LIVE tokens
-                # only (pad slots all route identically and would drown
-                # the real signal).
-                live = (
-                    jnp.arange(t)
-                    < md.query_start_loc[md.num_seqs[0]]
-                )
+                # only.
                 contrib = jnp.broadcast_to(
                     live[:, None], ids.shape
                 ).astype(jnp.int32)
@@ -246,7 +246,7 @@ class MixtralForCausalLM(LlamaForCausalLM):
                 moe_out = fused_experts_of_layer(
                     h2, *expert_stacks, li, weights, ids,
                     ep_mesh=self.ep_mesh if self.expert_parallel else None,
-                    ep_axis="tp",
+                    ep_axis="tp", live=live,
                 )
             if self.shared_intermediate:
                 # Sigmoid-gated shared expert (Qwen2-MoE semantics).

@@ -308,6 +308,7 @@ class Qwen3NextForCausalLM:
         slots = md.state_slots
         first_pos = md.positions[jnp.clip(md.query_start_loc[:-1], 0, t - 1)]
         fresh = first_pos == 0
+        live = md.live_tokens()
         kv_scale = kv_dequant_scale(paged)
         rope_cos, rope_sin = self.rope.cos, self.rope.sin
 
@@ -401,6 +402,7 @@ class Qwen3NextForCausalLM:
             weights, ids = select_experts(logits, self.top_k, self.norm_topk)
             moe = fused_experts(
                 h2, lp["we_gate"], lp["we_up"], lp["we_down"], weights, ids,
+                live=live,
             )
             shared = silu_and_mul(jnp.concatenate(
                 [h2 @ lp["ws_gate"], h2 @ lp["ws_up"]], -1

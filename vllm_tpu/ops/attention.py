@@ -106,6 +106,13 @@ class AttentionMetadata:
     tree_window_start: jnp.ndarray | None = None
     tree_paged: "AttentionMetadata | None" = None
 
+    def live_tokens(self) -> jnp.ndarray:
+        """[T] bool: the step's tokens that are not padding."""
+        return (
+            jnp.arange(self.positions.shape[0])
+            < self.query_start_loc[self.num_seqs[0]]
+        )
+
 
 def packed_kv_layout(head_dim: int) -> bool:
     """True when K/V pair-pack on the lane axis instead of interleaving

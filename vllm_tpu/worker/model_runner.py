@@ -538,6 +538,7 @@ class ModelRunner:
         self.diffusion_committed_tokens = 0
         self.moe_assignments = 0
         self.moe_experts_hit = 0
+        self.moe_pad_pairs_skipped = 0
         # Linear attention over per-request state slots, cumulative, a
         # layer of that kind: rows whose state a step reads and writes,
         # and tokens by the form that takes them ("decode" = a row's one
@@ -2196,12 +2197,17 @@ class ModelRunner:
             block_len=blen,
         )
         num_experts = getattr(self.model, "num_experts", 0)
-        if num_experts and hasattr(self.model, "top_k") and not self._moe_share:
-            # Token-expert pairs over the step's live tokens, every
-            # layer that has experts.
-            self.moe_assignments += t_live * self.model.top_k * getattr(
+        if num_experts and hasattr(self.model, "top_k"):
+            # Token-expert pairs of the step, every layer that has
+            # experts: the live tokens' are assigned (under a share the
+            # device counts those routed HERE, below), the pad tokens' are
+            # kept out of the grouped matmul (layers/moe.py).
+            pairs_a_token = self.model.top_k * getattr(
                 self.model, "num_moe_layers", self.model.num_layers
             )
+            if not self._moe_share:
+                self.moe_assignments += t_live * pairs_a_token
+            self.moe_pad_pairs_skipped += (t_pad - t_live) * pairs_a_token
         q_lens = np.diff(query_start_loc[: r_live + 1])
         linear_layers = getattr(self.model, "num_linear_attn_layers", 0)
         if linear_layers:
