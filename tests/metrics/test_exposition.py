@@ -311,6 +311,12 @@ def test_runner_counts_decode_only_against_mixed_on_a_hand_made_batch(
     for phase in ("prepare", "fetch", "update", "output"):
         assert ('vllm:engine_step_duration_seconds_count'
                 f'{{phase="{phase}"}} 8') in text, phase
+    # A model that does not generate by blocks has no head over a rung of
+    # the request bucket: the families are there, without a sample.
+    assert runner.diffusion_head_positions == {"live": 0, "computed": 0}
+    assert "# TYPE vllm:diffusion_head_positions_total counter" in text
+    assert "vllm:diffusion_head_positions_total{" not in text
+    assert "vllm:diffusion_head_steps_total{" not in text
     # Cumulative: a second batch adds to every counter, and uses no new
     # step program.
     run_batch("two")
@@ -469,6 +475,60 @@ def test_runner_counts_passes_commits_and_experts_on_a_hand_made_batch(
     steps = 5 if room else 7
     assert runner.step_launches == steps
     assert steps * 2 * layers <= runner.moe_experts_hit <= steps * 8 * layers
+    llm.shutdown()
+
+
+def test_runner_counts_the_heads_positions_by_the_rung_a_step_took(tmp_path):
+    """A request bucket of 8 rows of a block of 4, one step at a time: the
+    head of a step runs over 2, 4 or 8 rows' positions, the least that
+    holds the live rows (``head_rungs``, the rule of the step program's
+    switch). One request in the first step, three in the second, six in
+    the third; the counters ride SchedulerStats to /metrics."""
+    from tests.benchmark.tiny_sdar_moe import model_dir
+    from vllm_tpu import LLM, SamplingParams
+    from vllm_tpu.worker.model_runner import head_rungs
+
+    assert head_rungs(8) == [2, 4, 8] and head_rungs(128) == [32, 64, 128]
+    assert head_rungs(2) == [1, 2] and head_rungs(1) == [1]
+    llm = LLM(model=model_dir(tmp_path), dtype="float32", load_format="dummy",
+              max_model_len=128, block_size=16, max_num_batched_tokens=64,
+              token_buckets="64", request_buckets="8", max_num_seqs=8,
+              num_gpu_blocks_override=64, async_scheduling=False)
+    engine = llm.llm_engine
+    runner = engine.engine_core.engine_core.executor.worker.runner
+    reg = PrometheusRegistry()
+    params = SamplingParams(temperature=0.0, max_tokens=16, ignore_eos=True)
+    want, live = {"live": 0, "computed": 0}, 0
+    for step, (new, rows, rung) in enumerate(
+            [(1, 2, "quarter"), (2, 4, "half"), (3, 8, "whole")]):
+        for i in range(new):
+            engine.add_request(f"{step}-{i}", {"prompt_token_ids": list(range(1, 8))}, params)
+        outputs = engine.engine_core.get_output()
+        reg.record(outputs.scheduler_stats,
+                   engine.output_processor.process_outputs(outputs.outputs).iteration_stats)
+        live += new
+        want = {"live": want["live"] + live * 4, "computed": want["computed"] + rows * 4}
+        assert runner.diffusion_head_positions == want, (step, rung)
+        assert runner.diffusion_head_steps_by_rung[rung] == 1
+    assert want == {"live": (1 + 3 + 6) * 4, "computed": (2 + 4 + 8) * 4}
+    text = reg.render()
+    for sample in ('vllm:diffusion_head_positions_total{kind="live"} 40.0',
+                   'vllm:diffusion_head_positions_total{kind="computed"} 56.0',
+                   'vllm:diffusion_head_steps_total{rung="quarter"} 1.0',
+                   'vllm:diffusion_head_steps_total{rung="half"} 1.0',
+                   'vllm:diffusion_head_steps_total{rung="whole"} 1.0'):
+        assert sample in text, sample
+    reg.record(SchedulerStats())  # a respawned engine lowers nothing
+    assert 'vllm:diffusion_head_positions_total{kind="computed"} 56.0' in reg.render()
+    while engine.has_unfinished_requests():
+        engine.step()
+    # To the end: every step on one rung, the whole bucket while six rows
+    # run, less again once the first requests have ended.
+    by_rung = runner.diffusion_head_steps_by_rung
+    assert sum(by_rung.values()) == runner.step_launches and by_rung["whole"] > 1
+    assert runner.diffusion_head_positions["computed"] == 4 * (
+        2 * by_rung["quarter"] + 4 * by_rung["half"] + 8 * by_rung["whole"])
+    assert runner.diffusion_head_positions["live"] < runner.diffusion_head_positions["computed"]
     llm.shutdown()
 
 

@@ -675,6 +675,66 @@ def test_the_block_pass_commits_what_sampling_every_position_commits(enable_samp
     assert not np.asarray(out[9]).any()  # no row flagged by the numeric guard
 
 
+@pytest.mark.parametrize("num_logprobs", [0, 5])
+@pytest.mark.parametrize("rule", ["sequential", "low_confidence_static", "low_confidence_dynamic"])
+@pytest.mark.parametrize("live", [8, 9, 16, 17, 32])
+def test_the_head_runs_over_the_least_rung_that_holds_the_live_rows(live, rule, num_logprobs):
+    """A request bucket of 32 rows of a block of 4: the head, the sampler
+    and the logprobs run over the first 8, 16 or 32 rows' positions,
+    whichever holds the live rows (``head_rungs``), and what a live row
+    gets is bit for bit what the head over all 128 positions gives it; a
+    pad row commits nothing. That the head stopped at the rung: every
+    position past it holds NaNs, and the program counts none."""
+    import types
+
+    from tests.sample.test_sampler_kernel import _make_md
+    from vllm_tpu.models.generation import REMASKING_RULES
+    from vllm_tpu.worker.model_runner import ModelRunner, head_rungs
+
+    r, d, v = 32, 16, 256
+    assert head_rungs(r) == [8, 16, 32]
+    rows = next(n for n in head_rungs(r) if live <= n)
+    rng = np.random.default_rng(100 * live + num_logprobs)
+    head = jnp.asarray(rng.standard_normal((d, v)), jnp.float32)
+    hidden = rng.standard_normal((r * B, d)).astype(np.float32) * 2
+    runner = types.SimpleNamespace(
+        model=types.SimpleNamespace(compute_logits=lambda params, h: h @ params),
+        tp_size=1, _nan_check=True, _guard_numerics=True)
+    md = _make_md(r, v, temperature=rng.choice([0.0, 0.7, 1.0], r), top_p=rng.choice([0.8, 1.0], r))
+    # Live rows of every kind (a prefill chunk, a denoising pass, a lone
+    # store, a row of two blocks) at every stage of a block; pad rows past.
+    kind = np.where(np.arange(r) < live, rng.integers(0, 4, r), 0).astype(np.int32)
+    blk_com = (rng.random((r, B)) < 0.4) & (kind != 3)[:, None]
+    blk_tok = rng.integers(0, v, (r, B)).astype(np.int32)
+    blk = tuple(jnp.asarray(a) for a in (
+        kind, np.full(r, REMASKING_RULES.index(rule), np.int32), rng.integers(1, 3, r).astype(np.int32),
+        blk_com.astype(np.int32), np.full(r, 0.5, np.float32)))
+    flat_idx = jnp.arange(r * B, dtype=jnp.int32).reshape(r, B)
+
+    def epilogue(hidden, num_live):
+        out = ModelRunner._block_epilogue(
+            runner, head, None, None, jnp.asarray(hidden), md, blk, flat_idx, jnp.asarray(blk_tok),
+            jnp.asarray(blk_com), None, block_len=B, r_pad=r, needs_top_k=False,
+            needs_top_p_min_p=True, needs_gumbel=True, num_logprobs=num_logprobs,
+            enable_sampler_kernel=True, num_live=jnp.asarray(num_live, jnp.int32))
+        return jax.tree.map(np.asarray, (out[2], out[3], out[6], out[9]))
+
+    poisoned = hidden.copy()
+    poisoned[rows * B:] = np.nan
+    (got, state), lp, nans, bad = epilogue(poisoned, live)
+    (want, want_state), want_lp, _, _ = epilogue(hidden, r)
+    assert nans == 0 and not bad.any()
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(state, want_state)
+    assert (lp is None) == (num_logprobs == 0)
+    for a, w in zip(lp or (), want_lp or ()):
+        np.testing.assert_array_equal(a[:live], w[:live])
+    # The rows past the live ones keep what they came with.
+    np.testing.assert_array_equal(got[live:, :B], blk_tok[live:])
+    np.testing.assert_array_equal(got[live:, B:2 * B] > 0, blk_com[live:])
+    assert not got[live:, 2 * B].any() and got[:live, 2 * B].sum() > 0
+
+
 def test_past_the_kernels_bound_the_epilogue_pads_to_noise_tiles_and_still_cuts_the_nucleus():
     """151936 tokens would pad to 262144 lanes as a power of two, so past
     131072 lanes the epilogue computes at whole noise tiles, 155648, the

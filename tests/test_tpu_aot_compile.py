@@ -755,6 +755,7 @@ def test_block_diffusion_step_compiles_and_reads_each_weight_in_place(
     # with them; before, the XLA epilogue did, a ``while`` of 31 bisection
     # passes over two [512, 155648] arrays in HBM.
     from vllm_tpu.sample.sampler import SamplingMetadata
+    from vllm_tpu.worker import model_runner
     from vllm_tpu.worker.model_runner import ModelRunner
 
     b = SDAR["block_length"]
@@ -768,29 +769,57 @@ def test_block_diffusion_step_compiles_and_reads_each_weight_in_place(
     )
     blk = (s((r,), jnp.int32),) * 3 + (s((r, b), jnp.int32), s((r,), jnp.float32))
 
-    def epilogue(enable_sampler_kernel):
+    def epilogue(enable_sampler_kernel, num_logprobs=0):
         def fn(params, hidden, sampling, blk, flat_idx, blk_tok, blk_com, live):
             out = ModelRunner._block_epilogue(
                 runner, params, None, None, hidden, sampling, blk, flat_idx,
                 blk_tok, blk_com, None, block_len=b, r_pad=r,
                 needs_top_k=False, needs_top_p_min_p=True, needs_gumbel=True,
-                num_logprobs=0, enable_sampler_kernel=enable_sampler_kernel,
-                num_live=live,
+                num_logprobs=num_logprobs,
+                enable_sampler_kernel=enable_sampler_kernel, num_live=live,
             )
-            return out[2], out[9]
+            return out[2], out[3], out[9]
 
         return jax.jit(fn).lower(
             params, s((t, model.hidden_size), jnp.bfloat16), sampling, blk,
             s((r, b), jnp.int32), s((r, b), jnp.int32), s((r, b), jnp.bool_),
             s((), jnp.int32),
-        ).compile().as_text()
+        ).compile()
 
     wide = f"[{r * b},155648]"
-    text = epilogue(True)
+    compiled = epilogue(True)
+    text = compiled.as_text()
     assert "fused_sampler_kernel" in text
     assert wide not in text and "while(" not in text
+    # The head runs over the least rung that holds the live rows, picked
+    # in the program: ONE conditional of three branches, each with the
+    # head's product at its own rows (and XLA did not turn the switch into
+    # selects, which would run all three).
+    assert model_runner.head_rungs(r) == [32, 64, 128]
+    conditionals = re.findall(r"conditional\(.*branch_computations=\{([^}]*)\}", text)
+    assert len(conditionals) == 1 and conditionals[0].count(",") == 2, conditionals
+    heads = sorted(
+        int(rows) for rows in re.findall(
+            rf"\[(\d+),{model.vocab_size}\]\S* convolution\(", text))
+    assert heads == [128, 256, 512], heads
+    # And it holds what the head over every position held, the float32
+    # logits of 512 positions (311 MB): a conditional's temporaries are
+    # its largest branch's. (Within a MiB: the compiler keeps a few of the
+    # sampler's [rows, 128] parameter blocks in another memory space, and
+    # reads 0.45 MB more, here and against the parent's own epilogue.)
+    with monkeypatch.context() as one_rung:
+        one_rung.setattr(model_runner, "head_rungs", lambda rows: [rows])
+        whole = epilogue(True)
+    assert "conditional(" not in whole.as_text()
+    logits_bytes = 4 * r * b * model.vocab_size
+    assert (logits_bytes < compiled.memory_analysis().temp_size_in_bytes
+            <= whole.memory_analysis().temp_size_in_bytes + (1 << 20))
+    # The program of a step with logprobs (the benchmark's check requests
+    # ask for 5) takes the same slabs: top-k and rank are a row's own.
+    text = epilogue(True, num_logprobs=5).as_text()
+    assert "conditional(" in text and wide not in text
     # The check can tell: with the kernel turned off the loop is there.
-    text = epilogue(False)
+    text = epilogue(False).as_text()
     assert "fused_sampler_kernel" not in text
     assert wide in text and "while(" in text
 

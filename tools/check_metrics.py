@@ -101,6 +101,8 @@ REQUIRED_DIFFUSION_MOE_METRICS = {
     "vllm:diffusion_passes_total",
     "vllm:diffusion_committed_tokens_total",
     "vllm:diffusion_stores_fused_total",
+    "vllm:diffusion_head_positions_total",
+    "vllm:diffusion_head_steps_total",
     "vllm:moe_assignments_total",
     "vllm:moe_experts_hit_total",
     "vllm:moe_pad_pairs_skipped_total",
@@ -364,6 +366,8 @@ def check_diffusion_exposition() -> list[str]:
         reg.record(SchedulerStats(
             diffusion_passes_by_kind={k: 2 for k in DIFFUSION_PASS_KINDS},
             diffusion_committed_tokens=3, diffusion_stores_fused=4,
+            diffusion_head_positions={"live": 8, "computed": 16},
+            diffusion_head_steps_by_rung={"quarter": 1},
             moe_assignments=5, moe_experts_hit=7, moe_pad_pairs_skipped=13,
             attn_context_tokens_by_kind={"block": 11}))
     except (TypeError, AttributeError) as e:
@@ -373,6 +377,9 @@ def check_diffusion_exposition() -> list[str]:
               for k in DIFFUSION_PASS_KINDS]
     wanted += ["vllm:diffusion_committed_tokens_total 3.0",
                "vllm:diffusion_stores_fused_total 4.0",
+               'vllm:diffusion_head_positions_total{kind="live"} 8.0',
+               'vllm:diffusion_head_positions_total{kind="computed"} 16.0',
+               'vllm:diffusion_head_steps_total{rung="quarter"} 1.0',
                "vllm:moe_assignments_total 5.0",
                "vllm:moe_experts_hit_total 7.0",
                "vllm:moe_pad_pairs_skipped_total 13.0",

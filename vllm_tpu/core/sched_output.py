@@ -302,6 +302,9 @@ class SchedulerStats:
     # row-passes by kind (a pass that stores a clean block and denoises
     # the next counts once, as "denoise"), positions committed by
     # denoising passes, and the stores that rode with a denoising pass.
+    # The head of a block step runs over the least rung (a quarter, a half
+    # or all of the request bucket) that holds the live rows: positions
+    # {"live", "computed"}, and steps by rung.
     # Mixture of experts (cumulative, from the runner): token-expert pairs
     # over live tokens, experts that got a live token, and the pad tokens'
     # pairs that were kept out of the grouped matmul, all summed over
@@ -309,6 +312,8 @@ class SchedulerStats:
     diffusion_passes_by_kind: dict[str, int] = field(default_factory=dict)
     diffusion_committed_tokens: int = 0
     diffusion_stores_fused: int = 0
+    diffusion_head_positions: dict[str, int] = field(default_factory=dict)
+    diffusion_head_steps_by_rung: dict[str, int] = field(default_factory=dict)
     moe_assignments: int = 0
     moe_experts_hit: int = 0
     moe_pad_pairs_skipped: int = 0

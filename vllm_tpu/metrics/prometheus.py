@@ -380,6 +380,18 @@ class PrometheusRegistry:
             "vllm:diffusion_committed_tokens_total",
             "Positions that denoising passes committed under generation "
             "by diffusion over blocks")
+        self.diffusion_head_positions = LabeledCounter(
+            "vllm:diffusion_head_positions_total",
+            "Positions of the open blocks in steps under generation by "
+            "diffusion over blocks: live (those of the step's live rows) "
+            "and computed (those the head and the sampler ran over: the "
+            "least of a quarter, a half or all of the request bucket "
+            "that holds the live rows)", "kind")
+        self.diffusion_head_steps = LabeledCounter(
+            "vllm:diffusion_head_steps_total",
+            "Steps under generation by diffusion over blocks, by the "
+            "share of the request bucket their head ran over: quarter, "
+            "half or whole", "rung")
         self.moe_assignments = Counter(
             "vllm:moe_assignments_total",
             "Token-expert pairs over the live tokens of dispatched steps, "
@@ -817,6 +829,7 @@ class PrometheusRegistry:
             self.attn_decode_tiles, self.pipeline_stall,
             self.diffusion_passes, self.diffusion_committed,
             self.diffusion_stores_fused,
+            self.diffusion_head_positions, self.diffusion_head_steps,
             self.moe_assignments, self.moe_experts_hit,
             self.moe_pad_pairs_skipped,
             self.linear_attn_state_rows, self.linear_attn_tokens,
@@ -977,6 +990,8 @@ class PrometheusRegistry:
                 (self.attn_ragged_pairs, s.attn_ragged_pairs),
                 (self.attn_decode_tiles, s.attn_decode_tiles),
                 (self.linear_attn_tokens, s.linear_attn_tokens_by_kind),
+                (self.diffusion_head_positions, s.diffusion_head_positions),
+                (self.diffusion_head_steps, s.diffusion_head_steps_by_rung),
             ):
                 for kind, n in by_kind.items():
                     counter.inc_to(kind, float(n))

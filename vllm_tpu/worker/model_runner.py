@@ -110,6 +110,19 @@ def window_live_pairs(q_lens, kv_lens, window: int) -> int:
     return int(np.sum(ramp_sum + (q_lens - ramp) * window))
 
 
+def head_rungs(r_pad: int) -> list[int]:
+    """The rows the block epilogue's head may run over under generation by
+    diffusion: a quarter, a half or all of the request bucket. A step
+    takes the least rung that holds its live rows, which come first."""
+    return sorted({r for r in (r_pad // 4, r_pad // 2, r_pad) if r})
+
+
+def head_rung(rungs: list[int], live):
+    """The place in ``rungs`` of the least that holds ``live`` rows: an int
+    on the host, a traced scalar in the step program."""
+    return sum((live > r) * 1 for r in rungs[:-1])
+
+
 def _bucket(value: int, buckets: list[int]) -> int:
     i = bisect.bisect_left(buckets, value)
     if i == len(buckets):
@@ -536,6 +549,10 @@ class ModelRunner:
         self.diffusion_passes_by_kind = {"denoise": 0, "store": 0}
         self.diffusion_stores_fused = 0
         self.diffusion_committed_tokens = 0
+        # The head of a block step: the positions of its live rows, those
+        # of the rung it ran over (head_rungs), and the steps a rung.
+        self.diffusion_head_positions = {"live": 0, "computed": 0}
+        self.diffusion_head_steps_by_rung = {}
         self.moe_assignments = 0
         self.moe_experts_hit = 0
         self.moe_pad_pairs_skipped = 0
@@ -1226,9 +1243,8 @@ class ModelRunner:
 
         kind, rule, n_pass, _, threshold = blk
         b = block_len
-        logits = self.model.compute_logits(
-            params, hidden[flat_idx.reshape(-1)]
-        )  # [R * B, V] f32: the logits AT a position are for its own token
+        i32 = jnp.int32
+        idx = flat_idx.reshape(-1)
 
         def rep(a):
             return jnp.repeat(a, b, axis=0)
@@ -1237,22 +1253,60 @@ class ModelRunner:
         keys = keys.at[:, 1].add(
             jnp.tile(jnp.arange(b, dtype=keys.dtype), r_pad)
         )
-        per_pos = _replace(
-            sampling, temperature=rep(sampling.temperature),
-            top_k=rep(sampling.top_k), top_p=rep(sampling.top_p),
-            min_p=rep(sampling.min_p),
-            presence_penalty=rep(sampling.presence_penalty),
-            frequency_penalty=rep(sampling.frequency_penalty),
-            repetition_penalty=rep(sampling.repetition_penalty),
-            prng_keys=keys,
+        per_pos = {f: rep(getattr(sampling, f)) for f in (
+            "temperature", "top_k", "top_p", "min_p", "presence_penalty",
+            "frequency_penalty", "repetition_penalty")}
+        per_pos["prng_keys"] = keys
+
+        def over(rows):
+            """The head, the sampler and whatever else reads the logits,
+            over the first ``rows`` rows' positions: the head's weights
+            are read once, the [n, V] logits stay in here, and what comes
+            out is a position's (or a row's) own, padded to all ``r_pad``
+            rows' with the zeros a row past the live ones reads from the
+            sampler's kernel."""
+            n = rows * b
+            logits = self.model.compute_logits(
+                params, hidden[idx[:n]]
+            )  # [n, V] f32: the logits AT a position are its own token's
+            drawn, raw_logprobs, prob = dispatch_sample(
+                logits,
+                _replace(sampling, **{k: a[:n] for k, a in per_pos.items()}),
+                needs_penalties=False, needs_top_k=needs_top_k,
+                needs_top_p_min_p=needs_top_p_min_p,
+                needs_gumbel=needs_gumbel,
+                enable_kernel=enable_sampler_kernel, allow_interpret=True,
+                tp_size=self.tp_size, return_prob=True,
+                num_active=num_live * b,  # live rows come first
+            )
+            res = {"drawn": drawn, "prob": prob}
+            if num_logprobs > 0:
+                topk_vals, topk_ids = jax.lax.top_k(raw_logprobs, num_logprobs)
+                drawn_lp = jnp.take_along_axis(
+                    raw_logprobs, drawn[:, None], axis=-1)
+                rank = jnp.sum(raw_logprobs > drawn_lp, axis=-1).astype(i32)
+                res["lp"] = (topk_vals, topk_ids, drawn_lp[:, 0], rank)
+            if self._guard_numerics:
+                res["row_bad"] = ~jnp.all(
+                    jnp.isfinite(logits).reshape(rows, -1), axis=-1)
+            res = jax.tree.map(
+                lambda a: jnp.pad(
+                    a, [(0, a.shape[0] * r_pad // rows - a.shape[0])]
+                    + [(0, 0)] * (a.ndim - 1)),
+                res)
+            if self._nan_check:
+                res["nan_count"] = jnp.isnan(logits).sum()
+            return res
+
+        # Live rows come first, so the rows that anybody reads are the
+        # first num_live: the step takes the least rung that holds them.
+        # One head a step on every rung.
+        rungs = head_rungs(r_pad)
+        res = jax.lax.switch(
+            head_rung(rungs, num_live),
+            [functools.partial(over, r) for r in rungs],
         )
-        drawn, raw_logprobs, prob = dispatch_sample(
-            logits, per_pos, needs_penalties=False, needs_top_k=needs_top_k,
-            needs_top_p_min_p=needs_top_p_min_p, needs_gumbel=needs_gumbel,
-            enable_kernel=enable_sampler_kernel, allow_interpret=True,
-            tp_size=self.tp_size, return_prob=True,
-            num_active=num_live * b,  # live rows come first
-        )
+        drawn, prob = res["drawn"], res["prob"]
         drawn = drawn.reshape(r_pad, b)
         with jax.named_scope("commit"):
             conf = jnp.where(blk_com, -jnp.inf, prob.reshape(r_pad, b))
@@ -1282,7 +1336,6 @@ class ModelRunner:
             ) & ((kind == 1) | (kind == 3))[:, None]
             tokens = jnp.where(picked, drawn, blk_tok)
             committed = blk_com | picked
-        i32 = jnp.int32
         # To the host: tokens, flags after the pass, how many were new.
         out = jnp.concatenate(
             [tokens, committed.astype(i32),
@@ -1293,17 +1346,8 @@ class ModelRunner:
             [tokens, (committed & (kind != 2)[:, None]).astype(i32)], axis=1)
         lp = None
         if num_logprobs > 0:
-            topk_vals, topk_ids = jax.lax.top_k(raw_logprobs, num_logprobs)
-            drawn_lp = jnp.take_along_axis(
-                raw_logprobs, drawn.reshape(-1, 1), axis=-1)
-            rank = jnp.sum(raw_logprobs > drawn_lp, axis=-1).astype(i32)
-            lp = tuple(x.reshape(r_pad, b, *x.shape[1:]) for x in (
-                topk_vals, topk_ids, drawn_lp[:, 0], rank))
-        nan_count = jnp.isnan(logits).sum() if self._nan_check else None
-        row_bad = (
-            ~jnp.all(jnp.isfinite(logits).reshape(r_pad, -1), axis=-1)
-            if self._guard_numerics else None
-        )
+            lp = tuple(x.reshape(r_pad, b, *x.shape[1:]) for x in res["lp"])
+        nan_count, row_bad = res.get("nan_count"), res.get("row_bad")
         return (kv_cache, draft_kv, (out, state), lp, None, None, nan_count,
                 None, moe_counts, row_bad)
 
@@ -2081,6 +2125,14 @@ class ModelRunner:
             self.attn_context_tokens_by_kind["block"] = (
                 self.attn_context_tokens_by_kind.get("block", 0) + block_ctx
             )
+            # The head, as _block_epilogue's switch takes its rung.
+            rungs = head_rungs(r_pad)
+            rung = head_rung(rungs, r_live)
+            self.diffusion_head_positions["live"] += r_live * blen
+            self.diffusion_head_positions["computed"] += rungs[rung] * blen
+            name = ("quarter", "half", "whole")[-len(rungs):][rung]
+            self.diffusion_head_steps_by_rung[name] = (
+                self.diffusion_head_steps_by_rung.get(name, 0) + 1)
         if num_adj:
             adj_vals = fbuf[6 * r :].reshape(r, num_adj)
             for i, lst in enumerate(adj_lists):
