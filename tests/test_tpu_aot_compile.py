@@ -1471,3 +1471,182 @@ def test_falcon_h1_step_compiles_and_reads_each_weight_in_place(
     ]
     assert not moved, (
         f"the layer scan moves weight-sized temporaries (>= {smallest} bytes): {moved}")
+
+
+# LFM2-8B-A1B (``lfm2-8b-a1b.chat``): a gated short convolution of THREE
+# taps over one stream of 2048 channels whose two-token tail is all the
+# state a request has (slots ``[12, rows + 1, 2, 16, 128]`` in bf16), GQA
+# of 32 query heads over 8 KV heads of 64 lanes in the PACKED page layout
+# (``[4, blocks, 16, 8, 128]``, k||v in one row: new to the chip in both
+# attention kernels), 32 experts of 2048 x 1792 all held; layers 0-15, the
+# cell's two step shapes at the whole page table of 12,288.
+LFM2_CONFIG = "benchmark/configs/lfm2-8b-a1b.json"
+LFM2_CELL = "benchmark/cells/lfm2-8b-a1b.chat.json"
+LFM2_PAGES, LFM2_BLOCKS, LFM2_CONV_LAYERS, LFM2_ATTN_LAYERS = 768, 16384, 12, 4
+LFM2_D, LFM2_HEADS, LFM2_KV_HEADS, LFM2_HEAD = 2048, 32, 8, 64
+
+
+def _lfm2_rows():
+    import json
+    import pathlib
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    return int(json.loads((root / LFM2_CELL).read_text())["engine_args"]["max_num_seqs"])
+
+
+@pytest.mark.parametrize("t,decode_only", [(512, False), (0, True)],
+                         ids=["mixed_512_tokens", "decode_only"])
+def test_the_shared_convolution_compiles_at_three_taps_and_a_two_row_tail(
+        one_chip, monkeypatch, t, decode_only):
+    """``kda_conv_kernel`` as the LFM2 mixer calls it, its first caller at K
+    = 3: one stream of 16 whole lane tiles, no bias, a slot of ``[2, 16,
+    128]`` bf16 (two whole tiles: the copy of a two-row tail is what Mosaic
+    had not been asked before), the stack through in place, nothing
+    gathered, scattered or looped over around it."""
+    from vllm_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_pallas_form", lambda state=None: (True, False))
+    r = _lfm2_rows()
+    t = t or r
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stack = s((LFM2_CONV_LAYERS, r + 1, 2, LFM2_D // 128, 128), jnp.bfloat16)
+
+    def fn(x, stack, taps, slots, fresh, tok_req, qsl, n):
+        return kda.kda_short_conv((x,), stack, (taps,), jnp.int32(7), slots, fresh, tok_req,
+                                  qsl, n, decode_only=decode_only)
+
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        s((t, LFM2_D), jnp.bfloat16), stack, s((3, LFM2_D), jnp.bfloat16),
+        s((r,), jnp.int32), s((r,), jnp.bool_), s((t,), jnp.int32), s((r + 1,), jnp.int32),
+        s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "kda_conv_kernel" in text
+    assert not any(op in text for op in (" gather(", " scatter(", " while("))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(stack.shape) * 2
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+def _lfm2_attn_args(sharding, t, r, ragged):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    args = [
+        s((t, LFM2_HEADS, LFM2_HEAD), jnp.bfloat16),
+        s((LFM2_ATTN_LAYERS, LFM2_BLOCKS, PAGE, LFM2_KV_HEADS, 2 * LFM2_HEAD), jnp.bfloat16),
+        s((1,), jnp.int32), s((r,), jnp.int32), s((r, LFM2_PAGES), jnp.int32),
+    ]
+    if ragged:
+        args.append(s((r + 1,), jnp.int32))
+    return args + [s((1,), jnp.int32)]
+
+
+@pytest.mark.parametrize("kernel", ["ragged", "decode"])
+def test_both_attention_kernels_compile_on_the_packed_page_of_64_lane_heads(one_chip, kernel):
+    """32 query heads over 8 KV heads of 64 lanes, a KV head's key and value
+    side by side in one 128-lane row of the page (every accepted cell has
+    heads of 128 or 192, or a latent row), at the cell's rows and the whole
+    page table."""
+    from vllm_tpu.ops.attention import kv_cache_shape, packed_kv_layout
+    from vllm_tpu.ops.rpa_decode_kernel import decode_paged_attention
+    from vllm_tpu.ops.rpa_kernel import ragged_paged_attention
+
+    assert packed_kv_layout(LFM2_HEAD) and kv_cache_shape(
+        LFM2_ATTN_LAYERS, LFM2_BLOCKS, PAGE, LFM2_KV_HEADS, LFM2_HEAD) == (
+        4, LFM2_BLOCKS, 16, 8, 128)
+    r = _lfm2_rows()
+    if kernel == "ragged":
+        fn = functools.partial(ragged_paged_attention, sm_scale=LFM2_HEAD**-0.5)
+        args = _lfm2_attn_args(one_chip, 512, r, True)
+    else:
+        fn = functools.partial(decode_paged_attention, sm_scale=LFM2_HEAD**-0.5)
+        args = _lfm2_attn_args(one_chip, r, r, False) + [
+            jax.ShapeDtypeStruct((r,), jnp.int32, sharding=one_chip)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert ("rpa_kernel" if kernel == "ragged" else "rpa_decode_kernel") in text
+
+
+@pytest.mark.parametrize("decode_only", [True, False], ids=["decode_only", "mixed_T512"])
+def test_lfm2_moe_step_compiles_and_reads_each_weight_in_place(
+        one_chip, monkeypatch, decode_only):
+    """Both step programs of ``lfm2-8b-a1b.chat`` at the published widths,
+    layers 0-15, compiled for the v5e with the Pallas forms on: the shared
+    convolution at three taps, the attention kernel of the step's kind on
+    the packed page, ``gmm`` over the 14 x 32 experts' stacks whole; the
+    pages and the slots go through in place; and no iteration of the scan
+    over the periods slices, copies or transposes a WEIGHT out of its
+    stack (an expert stack is 3.3 GB: one copy would not fit)."""
+    import json
+    import pathlib
+
+    from transformers import PretrainedConfig
+
+    from vllm_tpu.layers.rotary import serving_positions
+    from vllm_tpu.models.lfm2_moe import Lfm2MoeForCausalLM
+    from vllm_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    config = json.loads((root / LFM2_CONFIG).read_text())
+    config.pop("bench")
+    with serving_positions(LFM2_PAGES * PAGE):  # as the worker does
+        model = Lfm2MoeForCausalLM(PretrainedConfig.from_dict(config), dtype=jnp.bfloat16)
+    r = _lfm2_rows()
+    model.max_state_slots = r
+    t = r if decode_only else 512
+    assert (model.head_layers, model.period, model.reps, model.tail_layers) == (
+        [0, 1], 4, 3, [14, 15])
+    assert model.state_bytes_per_slot() == 12 * 2 * 2048 * 2 == 98_304
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    on_chip = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(model.init_dummy_params, jax.random.PRNGKey(0)))
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert weights == pytest.approx(10.80e9, rel=2e-3)
+    assert params["experts"]["we_gate"].shape == (14, 32, 2048, 1792)
+    cache = on_chip(jax.eval_shape(
+        lambda: model.alloc_kv_cache(LFM2_BLOCKS, PAGE, jnp.bfloat16)))
+    assert cache["conv"].shape == (12, r + 1, 2, 16, 128)
+    assert cache["paged"].shape == (4, LFM2_BLOCKS, PAGE, 8, 128)
+    md = attention.AttentionMetadata(
+        positions=s((t,), jnp.int32), slot_mapping=s((t,), jnp.int32),
+        block_tables=s((r, LFM2_PAGES), jnp.int32),
+        seq_lens=s((r,), jnp.int32), query_start_loc=s((r + 1,), jnp.int32),
+        token_req_idx=s((t,), jnp.int32), logits_indices=s((r,), jnp.int32),
+        num_seqs=s((1,), jnp.int32), decode_only=decode_only,
+        state_slots=s((r,), jnp.int32),
+    )
+    compiled = jax.jit(model.apply, donate_argnums=(1,)).lower(
+        params, cache, s((t,), jnp.int32), md).compile()
+    text = compiled.as_text()
+    for kernel in ("kda_conv_kernel", "gmm",
+                   "rpa_decode_kernel" if decode_only else "rpa_kernel"):
+        assert kernel in text
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 1 << 30  # no copy of an expert stack (3.3 GB)
+    squeezed = lambda dims: tuple(d for d in dims if d != 1)
+    shapes = {squeezed(a.shape[1:]) for kind in ("conv", "attn", "moe", "experts")
+              for a in jax.tree.leaves(params[kind]) if a.ndim >= 3}
+    shapes |= {sh[:-2] + sh[:-3:-1] for sh in shapes if len(sh) >= 2}
+    smallest = 2048 * 512 * 2  # wk, wv
+    # (a mixed step's [512 tokens, 2048] activations are wk's shape turned
+    # round: rows of the step's tokens are no weight)
+    moved = [
+        (name, opcode, dims, nbytes)
+        for name, opcode, nbytes, dims in _while_body_instructions(text)
+        if nbytes >= smallest and squeezed(dims)[0] != t
+        and ("dynamic-slice" in name or opcode == "dynamic-slice"
+             or (opcode in ("copy", "transpose") and squeezed(dims) in shapes))
+    ]
+    assert not moved, (
+        f"the scan over the periods moves weight-sized temporaries (>= {smallest} "
+        f"bytes): {moved}")

@@ -54,9 +54,12 @@ def select_experts_grouped(
     method: str = "greedy",
     renormalize: bool = False,
     scaling: float = 1.0,
+    renorm_guard: float = 1e-20,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """DeepSeek routing (HF DeepseekV2MoEGate / DeepseekV3TopkRouter
-    semantics). Returns (weights [T, k] f32, ids [T, k] i32)."""
+    semantics). Returns (weights [T, k] f32, ids [T, k] i32).
+    ``renorm_guard`` is added to the chosen scores' sum before they are
+    divided by it (a family states its own: LFM2's is 1e-6)."""
     t, e = logits.shape
     g, k = n_group, top_k
     if sigmoid:
@@ -81,7 +84,7 @@ def select_experts_grouped(
     _, ids = jax.lax.top_k(choice, k)
     weights = jnp.take_along_axis(scores, ids, axis=-1)
     if renormalize:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + renorm_guard)
     return weights * scaling, ids.astype(jnp.int32)
 
 

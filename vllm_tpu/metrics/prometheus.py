@@ -407,11 +407,12 @@ class PrometheusRegistry:
             "out of the grouped matmul, summed over layers")
         self.linear_attn_state_rows = Counter(
             "vllm:linear_attn_state_rows_total",
-            "Rows whose linear-attention state a dispatched step reads "
-            "and writes, summed over the layers of that kind")
+            "Rows whose slot state (a linear-attention matrix, a short "
+            "convolution's tail) a dispatched step reads and writes, "
+            "summed over the layers of that kind")
         self.linear_attn_tokens = LabeledCounter(
             "vllm:linear_attn_tokens_total",
-            "Tokens through the linear-attention layers, summed over "
+            "Tokens through the slot-state layers, summed over "
             "them: decode (a row's one token) or chunk (a row of "
             "several)", "kind")
         self.window_attn_rows = Counter(

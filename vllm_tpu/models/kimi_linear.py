@@ -93,7 +93,9 @@ LEAF_IDS = {
 def layer_plan(kinds: list[tuple[str, str]]) -> tuple[list[int], int, int, list[int]]:
     """Cut the layers' (mixer, ffn) kinds into leading single layers, ``reps``
     periods of ``period`` layers, and trailing single layers, so that the
-    periods cover as many layers as a pattern of at most 8 can."""
+    periods cover as many layers as a pattern of at most 8 can (the
+    earliest start among equals: two leading layers alike do not keep a
+    longer pattern behind them out of the scan)."""
     n = len(kinds)
     best = (0, 0, 0, 0)  # covered, start, period, reps
     for start in range(n):
@@ -107,8 +109,6 @@ def layer_plan(kinds: list[tuple[str, str]]) -> tuple[list[int], int, int, list[
                 reps += 1
             if reps > 1 and reps * period > best[0]:
                 best = (reps * period, start, period, reps)
-        if best[0]:
-            break  # the earliest start that repeats at all
     covered, start, period, reps = best
     if not covered:
         return list(range(n)), 0, 0, []

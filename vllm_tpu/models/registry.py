@@ -37,6 +37,7 @@ _REGISTRY: dict[str, tuple[str, str]] = {
     "Qwen3NextForCausalLM": ("vllm_tpu.models.qwen3_next", "Qwen3NextForCausalLM"),
     "KimiLinearForCausalLM": ("vllm_tpu.models.kimi_linear", "KimiLinearForCausalLM"),
     "FalconH1ForCausalLM": ("vllm_tpu.models.falcon_h1", "FalconH1ForCausalLM"),
+    "Lfm2MoeForCausalLM": ("vllm_tpu.models.lfm2_moe", "Lfm2MoeForCausalLM"),
     "LagunaForCausalLM": ("vllm_tpu.models.laguna", "LagunaForCausalLM"),
     "MiMoV2ForCausalLM": ("vllm_tpu.models.mimo_v2", "MiMoV2ForCausalLM"),
     "Phi3ForCausalLM": ("vllm_tpu.models.phi3", "Phi3ForCausalLM"),

@@ -165,7 +165,6 @@ def _decode_kernel(
     sink_ref = sink[0] if sink else None
     kv_bufs, sems, q_buf, o_buf, zero_buf, o_sems = refs[-6:]
     _, num_q_heads_per_blk, head_dim = q_buf.shape
-    v_dim = o_buf.shape[-1]  # head_dim but in the split layout
     pages_per_seq = page_indices_ref.shape[1]
     num_seqs = num_seqs_ref[0]
     layer = layer_ref[0]
@@ -177,6 +176,10 @@ def _decode_kernel(
     kv_tile = kp * page_size  # context tokens per sequence per tile
     # [.., KH, 2D] layout (head_dim 64), or the split one: K || V a row.
     packed = head_major or kv_lanes == 2 * head_dim
+    # A value is a key wide but in the split layout. A row of ``o_buf`` is
+    # whole lane tiles (a head of 64 fills half of one): Mosaic copies no
+    # row of 64 lanes out, so such a row leaves with lanes nobody reads.
+    v_dim = kv_lanes - head_dim if head_major else head_dim
     num_combined_kv_heads_per_blk = (
         2 * kv_rows_per_blk if packed else kv_rows_per_blk
     )
@@ -485,7 +488,7 @@ def _decode_kernel(
             for c in sent:
                 c.wait()
 
-        o_buf[...] = jnp.concatenate(outs, axis=1).astype(o_buf.dtype)
+        o_buf[:, :, :v_dim] = jnp.concatenate(outs, axis=1).astype(o_buf.dtype)
         for c in sent:
             c.start()
 
@@ -708,7 +711,10 @@ def decode_paged_attention(
         (kv_rows_per_blk, page_size) if head_major
         else (page_size, kv_rows_per_blk)
     )
-    out_rows = (s_blk, num_q_heads_per_blk, v_dim)
+    # The output's rows are whole lane tiles (the kernel says why); the
+    # lanes past ``v_dim`` are dropped below.
+    o_lanes = -(-v_dim // 128) * 128
+    out_rows = (s_blk, num_q_heads_per_blk, o_lanes)
     scratch_shapes = [
         pltpu.VMEM((2, s_blk * kp, *page_shape, kv_lanes), kv_pages.dtype),
         pltpu.SemaphoreType.DMA((2,)),
@@ -762,11 +768,11 @@ def decode_paged_attention(
             vmem_limit_bytes=vmem_limit_bytes,
         ),
         out_shape=[jax.ShapeDtypeStruct(
-            (num_rows + pad, num_q_heads, v_dim), q.dtype)],
+            (num_rows + pad, num_q_heads, o_lanes), q.dtype)],
         name=name,
         interpret=interpret,
     )
-    out = kernel(*scalar_prefetches, *operands)[0][:num_rows]
+    out = kernel(*scalar_prefetches, *operands)[0][:num_rows, :, :v_dim]
     if g_whole != g_in:
         out = out.reshape(num_rows, num_kv_heads, g_whole, -1)[
             :, :, :g_in].reshape(num_rows, heads_in, -1)
