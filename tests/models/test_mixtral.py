@@ -87,21 +87,22 @@ def test_dense_moe_matches_per_token_loop():
 
 @pytest.fixture
 def gmm_calls(monkeypatch):
-    """megablox's ``gmm`` as ``_grouped_moe`` calls it, with every row past
-    the groups POISONED in its answer (the kernel does not write them; what
-    the buffer held is the caller's to keep out) and each call's
+    """The grouped matmul as ``_grouped_moe`` calls it (``moe._gmm``: the
+    repo's kernel, ``ops/gmm_kernel.py``, at these widths), with every row
+    past the groups POISONED in its answer (the kernel does not write them;
+    what the buffer held is the caller's to keep out) and each call's
     ``group_sizes`` recorded."""
-    from jax.experimental.pallas.ops.tpu import megablox
+    from vllm_tpu.layers import moe
 
-    real, sizes_seen = megablox.gmm, []
+    real, sizes_seen = moe._gmm, []
 
-    def poisoned(lhs, rhs, group_sizes, **kw):
+    def poisoned(lhs, rhs, group_sizes, interpret=False):
         sizes_seen.append(np.asarray(group_sizes))
-        out = real(lhs, rhs, group_sizes, **kw)
+        out = real(lhs, rhs, group_sizes, interpret)
         ran = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
         return jnp.where(ran[:, None], out, jnp.nan)
 
-    monkeypatch.setattr(megablox, "gmm", poisoned)
+    monkeypatch.setattr(moe, "_gmm", poisoned)
     return sizes_seen
 
 
@@ -109,7 +110,7 @@ def gmm_calls(monkeypatch):
     (None, None), (11, None), (11, (1, 2))], ids=["every_token", "live_prefix",
                                                    "live_prefix_and_held"])
 def test_grouped_matches_dense_interpret(gmm_calls, n_live, held):
-    """megablox grouped path (interpret mode on CPU) == dense path on the
+    """The grouped path (interpret mode on CPU) == dense path on the
     live tokens, under a mask of live tokens and under a share; a pad
     token's output is exactly 0, whatever the rows the kernel left out
     hold."""

@@ -768,6 +768,12 @@ def test_the_grouped_matmul_fetches_an_experts_matrix_in_few_pieces():
     assert _gmm_tiling(768, 2048) == (128, 768, 2048)  # down
     assert _gmm_tiling(14336, 4096) == (128, 2048, 1024)
     assert _gmm_tiling(64, 32) == (128, 128, 128)  # the tiny presets of the tests
+    # Up to 4096 deep a block holds the depth whole and 8 MiB (the repo's
+    # kernel, PR 54); the depth tile is then the order of the sums alone.
+    assert _gmm_tiling(2048, 1792) == (128, 2048, 1792)  # lfm2: 7.3 MB
+    assert _gmm_tiling(2304, 1024) == (128, 1152, 1024)  # kimi-linear: 4.7 MB
+    assert _gmm_tiling(4096, 2048) == (128, 2048, 1024)  # mimo: 16 MiB in two
+    assert _gmm_tiling(2048, 4096) == (128, 2048, 2048)
 
 
 # ---------------------------------------------------------------------------

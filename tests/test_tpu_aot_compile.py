@@ -213,6 +213,66 @@ def test_tracing_the_ragged_kernel_once_is_cheap(one_chip):
     assert time.process_time() - t0 < 4.0
 
 
+# name -> (rows, groups of the [L * E] stack, k, n): the matrices of the
+# cells' sparse layers that ``layers/moe.py::_gmm`` sends to the repo's
+# kernel.
+GMM_SHAPES = {
+    "lfm2_gate": (2048, 14 * 32, 2048, 1792),
+    "lfm2_down": (2048, 14 * 32, 1792, 2048),
+    "sdar_gate": (4096, 7 * 128, 2048, 768),
+    "sdar_down": (4096, 7 * 128, 768, 2048),
+    "laguna_share_gate": (4096, 39 * 32, 2048, 512),
+    "kimi_share_gate": (4096, 12 * 32, 2304, 1024),
+    "kimi_share_down": (4096, 12 * 32, 1024, 2304),
+    "mimo_share_gate": (4096, 9 * 16, 4096, 2048),
+    "mimo_share_down": (4096, 9 * 16, 2048, 4096),
+}
+
+
+def _gmm_shapes(sharding, shape):
+    m, g, k, n = GMM_SHAPES[shape]
+    s = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=sharding)
+    return s((m, k), jnp.bfloat16), s((g, k, n), jnp.bfloat16), s((g,), jnp.int32)
+
+
+@pytest.mark.parametrize("shape", list(GMM_SHAPES))
+def test_grouped_matmul_kernel_compiles(one_chip, shape):
+    """``ops/gmm_kernel.py`` as ``layers/moe.py::_gmm`` calls it, on the
+    stacks whole: the weights' slots fit the scoped VMEM it asks for, the
+    column tile's slice of a matrix in HBM is one Mosaic takes, and the
+    program's text still holds a call named ``gmm`` (the name the trace
+    prints and the benchmark's three readers sum)."""
+    from vllm_tpu.layers import moe
+    from vllm_tpu.ops import gmm_kernel
+
+    _, _, k, n = GMM_SHAPES[shape]
+    _, tk, tn = moe._gmm_tiling(k, n)
+    assert moe._takes_gmm_kernel(k, n) and k % tk == 0 and tk <= 2048
+    slots = gmm_kernel.slots_for(2 * k * tn)
+    assert gmm_kernel.vmem_bytes(k, tn, slots) <= 24 << 20
+    text = jax.jit(moe._gmm).lower(*_gmm_shapes(one_chip, shape)).compile().as_text()
+    calls = [l.split(" = ")[0].split()[-1] for l in text.splitlines()
+             if "tpu_custom_call" in l]
+    assert len(calls) == 1 and calls[0].startswith("%gmm")
+
+
+def test_tracing_the_grouped_matmul_once_is_cheap(one_chip):
+    """The kernel is traced and lowered three times a sparse layer's body,
+    a body a step program: one dot and a masked store, no loop unrolled.
+    The bound is the ragged kernel's; it takes 0.1-0.2 s here."""
+    import time
+
+    from vllm_tpu.ops import gmm_kernel
+
+    args = _gmm_shapes(one_chip, "lfm2_gate")
+    jax.jit(functools.partial(gmm_kernel.gmm, tn=1792)).lower(*args)  # imports
+    gmm_kernel.gmm.clear_cache()
+    fn = jax.jit(functools.partial(gmm_kernel.gmm, tn=1792))
+    t0 = time.process_time()
+    fn.lower(*args)
+    assert time.process_time() - t0 < 4.0
+
+
 @pytest.mark.parametrize(
     "r,v,penalties,top_k,top_p,return_prob,active",
     [(128, V, False, False, False, False, False),
@@ -611,7 +671,8 @@ def test_layer_scan_reads_each_weight_once_in_place(
 
 
 def _gmm_group_sizes_come_from(jaxpr, source: int) -> list[bool]:
-    """For every call of megablox's ``gmm`` in the traced program, in order:
+    """For every call of the grouped matmul (``ops/gmm_kernel.py::gmm``, or
+    megablox's where a shape goes there) in the traced program, in order:
     whether its ``group_sizes`` operand is computed from the program's flat
     input ``source`` through integers and booleans ALONE. Activations are
     floats, so what a token holds never counts; a count of live tokens

@@ -106,6 +106,7 @@ REQUIRED_DIFFUSION_MOE_METRICS = {
     "vllm:moe_assignments_total",
     "vllm:moe_experts_hit_total",
     "vllm:moe_pad_pairs_skipped_total",
+    "vllm:moe_gmm_visits_total",
 }
 DIFFUSION_PASS_KINDS = ("denoise", "store")
 REQUIRED_STEP_PHASES = ("schedule", "dispatch", "prepare", "finalize",
@@ -369,6 +370,7 @@ def check_diffusion_exposition() -> list[str]:
             diffusion_head_positions={"live": 8, "computed": 16},
             diffusion_head_steps_by_rung={"quarter": 1},
             moe_assignments=5, moe_experts_hit=7, moe_pad_pairs_skipped=13,
+            moe_gmm_visits={"first": 17, "resident": 0},
             attn_context_tokens_by_kind={"block": 11}))
     except (TypeError, AttributeError) as e:
         return [f"the diffusion stats no longer reach the registry: {e}"]
@@ -383,6 +385,8 @@ def check_diffusion_exposition() -> list[str]:
                "vllm:moe_assignments_total 5.0",
                "vllm:moe_experts_hit_total 7.0",
                "vllm:moe_pad_pairs_skipped_total 13.0",
+               'vllm:moe_gmm_visits_total{fetch="first"} 17.0',
+               'vllm:moe_gmm_visits_total{fetch="resident"} 0.0',
                'vllm:attn_context_tokens_total{kind="block"} 11.0']
     return [f"/metrics lacks the sample {w!r}" for w in wanted
             if w not in text]

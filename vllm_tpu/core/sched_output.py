@@ -308,7 +308,8 @@ class SchedulerStats:
     # Mixture of experts (cumulative, from the runner): token-expert pairs
     # over live tokens, experts that got a live token, and the pad tokens'
     # pairs that were kept out of the grouped matmul, all summed over
-    # layers and steps.
+    # layers and steps; and the grouped matmul's visits, "first" (waits
+    # for its expert's matrix) and "resident" (runs on the one held).
     diffusion_passes_by_kind: dict[str, int] = field(default_factory=dict)
     diffusion_committed_tokens: int = 0
     diffusion_stores_fused: int = 0
@@ -317,6 +318,7 @@ class SchedulerStats:
     moe_assignments: int = 0
     moe_experts_hit: int = 0
     moe_pad_pairs_skipped: int = 0
+    moe_gmm_visits: dict[str, int] = field(default_factory=dict)
     # Linear attention over state slots (cumulative, from the runner, a
     # layer of that kind): rows whose state a step read and wrote, tokens
     # by the form that took them; and the slots held now.

@@ -428,7 +428,8 @@ class EngineCore:
             )
             for name in ("steps_by_kind", "step_tokens_by_kind",
                          "attn_context_tokens_by_kind", "attn_ragged_pairs",
-                         "attn_decode_tiles", "diffusion_head_positions",
+                         "attn_decode_tiles", "moe_gmm_visits",
+                         "diffusion_head_positions",
                          "diffusion_head_steps_by_rung"):
                 setattr(stats, name, dict(getattr(runner, name, {})))
             stats.diffusion_passes_by_kind = dict(

@@ -6,10 +6,11 @@ topk_softmax_kernels.cu``, token permute/align ``moe_align_sum_kernels.cu``,
 grouped GEMM experts, all2all dispatch managers). The TPU design collapses
 to two paths with one semantic:
 
-- **grouped path** (TPU): sort tokens by expert, megablox grouped matmul
-  (``jax.experimental.pallas.ops.tpu.megablox.gmm``) over the ragged groups,
-  unsort + weighted combine. This is the moe_align + grouped-GEMM pipeline
-  as one Pallas kernel family.
+- **grouped path** (TPU): sort tokens by expert, grouped matmul over the
+  ragged groups (``ops/gmm_kernel.py``, the repo's fork of
+  ``jax.experimental.pallas.ops.tpu.megablox.gmm``; megablox itself for the
+  shapes ``_gmm`` leaves to it), unsort + weighted combine. This is the
+  moe_align + grouped-GEMM pipeline as one Pallas kernel family.
 - **dense path** (any backend, and the multi-device GSPMD path): one-hot
   dispatch einsum over the expert axis. With experts sharded over a mesh
   axis XLA turns the combine into the EP psum — the reference's all2all
@@ -139,7 +140,7 @@ def _grouped_moe(
     held: tuple[int, int] | None = None,
     live: jnp.ndarray | None = None,  # [T] bool, tokens that are not padding
 ) -> jnp.ndarray:
-    """Sort-by-expert + megablox grouped matmul (single-device fast path).
+    """Sort-by-expert + grouped matmul, ``_gmm`` (single-device fast path).
 
     One rule for the pairs that are NOT run: such a pair gets the id ``e``,
     sorts behind every group and is counted in no ``group_sizes``, so the
@@ -158,8 +159,6 @@ def _grouped_moe(
       from ``first`` on can be routed to here (an expert-parallel share,
       ``expert_ids`` already moved into the stack's numbering), and its id
       lies outside."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
     t, d = hidden.shape
     e = w_gate.shape[0]
     k = expert_ids.shape[1]
@@ -189,10 +188,7 @@ def _grouped_moe(
         x_sorted = jnp.pad(x_sorted, ((0, m_pad - m), (0, 0)))
 
     def mm(lhs, rhs, sizes):
-        return gmm(
-            lhs, rhs, sizes, preferred_element_type=jnp.float32,
-            interpret=interpret, tiling=_gmm_tiling(*rhs.shape[1:]),
-        )
+        return _gmm(lhs, rhs, sizes, interpret)
 
     gate = mm(x_sorted, w_gate, group_sizes)
     up = mm(x_sorted, w_up, group_sizes)
@@ -225,14 +221,25 @@ def _grouped_moe(
     return jnp.einsum("tkd,tk->td", out, weights).astype(hidden.dtype)
 
 
+# The deepest matrix whose blocks hold the depth whole (the repo's kernel).
+_GMM_WHOLE_DEPTH = 4096
+
+
 def _gmm_tiling(k: int, n: int) -> tuple[int, int, int]:
-    """Tiles of the grouped matmul over an expert's ``[k, n]`` matrix.
+    """Tiles ``(rows, depth, columns)`` of the grouped matmul over an
+    expert's ``[k, n]`` matrix.
 
     megablox's default, (128, 128, 128), fetches a matrix in 32 KB pieces:
     at a few rows an expert the kernel is then bound by its own grid (16 x
     6 steps a visit of a 2048 x 768 expert; 9% of the byte roofline on the
-    v5e, PERF.md PR 29). Here a visit fetches the whole depth ``k`` and as
-    much width as 4 MiB of bf16 hold, in pieces that divide ``n``."""
+    v5e, PERF.md PR 29). The depth goes in tiles of at most 2048. Up to
+    ``_GMM_WHOLE_DEPTH`` a BLOCK holds them all (``ops/gmm_kernel.py``, for
+    which the depth tile is only the order of the float32 sums) and as much
+    width as 8 MiB of bf16 hold, in pieces that divide ``n``: every row
+    tile re-reads its rows a column tile, so the fewer the better (PERF.md
+    PR 54 has the sweep). A deeper matrix goes a depth tile a block of 4
+    MiB (megablox, whose two buffers a block must fit the default scoped
+    VMEM)."""
     def tile(dim: int, cap: int) -> int:
         if dim <= cap:
             return dim
@@ -242,7 +249,54 @@ def _gmm_tiling(k: int, n: int) -> tuple[int, int, int]:
     if k % 128 or n % 128:
         return (128, 128, 128)
     tk = tile(k, 2048)
-    return (128, tk, tile(n, max(128, (4 << 20) // (2 * tk))))
+    depth, block = (k, 8 << 20) if k <= _GMM_WHOLE_DEPTH else (tk, 4 << 20)
+    return (128, tk, tile(n, max(128, block // (2 * depth))))
+
+
+def _takes_gmm_kernel(k: int, n: int) -> bool:
+    """Whether ``_gmm`` sends an expert's ``[k, n]`` matrix to the repo's
+    kernel: whole tiles (the tests' tiny presets are not; of such a matrix
+    ``_gmm_tiling``'s tiles are whole too) of a depth that a block holds
+    whole."""
+    return k % 128 == 0 and n % 128 == 0 and k <= _GMM_WHOLE_DEPTH
+
+
+def _gmm(
+    lhs: jnp.ndarray,  # [M, k] rows sorted by group, M a multiple of 128
+    rhs: jnp.ndarray,  # [G, k, n]
+    group_sizes: jnp.ndarray,  # [G] i32, sum may be < M
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The grouped matmul at ``_gmm_tiling``'s tiles, float32 out: the
+    repo's kernel (``ops/gmm_kernel.py``, which keeps the next expert's
+    matrix in flight while a row tile revisits the one it holds) where a
+    block holds the depth whole; megablox where it does not (a depth past
+    4096, or the tests' tiny presets that are no whole tiles). The sums
+    are the same bit for bit on either."""
+    k, n = rhs.shape[1:]
+    tiling = _gmm_tiling(k, n)
+    if _takes_gmm_kernel(k, n):
+        from vllm_tpu.ops import gmm_kernel
+
+        return gmm_kernel.gmm(
+            lhs, rhs, group_sizes, tk=tiling[1], tn=tiling[2],
+            interpret=interpret)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    return gmm(
+        lhs, rhs, group_sizes, preferred_element_type=jnp.float32,
+        interpret=interpret, tiling=tiling,
+    )
+
+
+def gmm_column_tiles(hidden: int, intermediate: int) -> int:
+    """Column tiles of a sparse layer's three grouped matmuls (gate, up,
+    down) together, those that run the repo's kernel: what one (group, row
+    tile) visit of ``gmm_kernel.count_visits`` is repeated over."""
+    def tiles(k: int, n: int) -> int:
+        return n // _gmm_tiling(k, n)[2] if _takes_gmm_kernel(k, n) else 0
+
+    return 2 * tiles(hidden, intermediate) + tiles(intermediate, hidden)
 
 
 def _excl_cumsum(x: jnp.ndarray) -> jnp.ndarray:

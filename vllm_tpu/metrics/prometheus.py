@@ -405,6 +405,12 @@ class PrometheusRegistry:
             "Token-expert pairs of the pad tokens of dispatched steps, "
             "which are routed (to any expert, held here or not) and kept "
             "out of the grouped matmul, summed over layers")
+        self.moe_gmm_visits = LabeledCounter(
+            "vllm:moe_gmm_visits_total",
+            "Visits of the experts' grouped matmul, a (group, row tile) "
+            "pair a column tile a call: first (waits for the expert's "
+            "matrix and starts the copy of one further on) and resident "
+            "(runs on the matrix its group's first visit brought)", "fetch")
         self.linear_attn_state_rows = Counter(
             "vllm:linear_attn_state_rows_total",
             "Rows whose slot state (a linear-attention matrix, a short "
@@ -832,7 +838,7 @@ class PrometheusRegistry:
             self.diffusion_stores_fused,
             self.diffusion_head_positions, self.diffusion_head_steps,
             self.moe_assignments, self.moe_experts_hit,
-            self.moe_pad_pairs_skipped,
+            self.moe_pad_pairs_skipped, self.moe_gmm_visits,
             self.linear_attn_state_rows, self.linear_attn_tokens,
             self.window_attn_rows, self.window_attn_pairs,
             self.kv_group_pages_in_use, self.kv_pages_allocated,
@@ -1011,6 +1017,12 @@ class PrometheusRegistry:
             self.moe_experts_hit.inc_to(float(s.moe_experts_hit))
             self.moe_pad_pairs_skipped.inc_to(
                 float(s.moe_pad_pairs_skipped))
+            if any(s.moe_gmm_visits.values()):
+                # Both labels once a visit is counted: "resident" stays 0
+                # where no group straddles a row tile.
+                for fetch, n in s.moe_gmm_visits.items():
+                    self.moe_gmm_visits.inc(fetch, 0.0)
+                    self.moe_gmm_visits.inc_to(fetch, float(n))
             self.linear_attn_state_rows.inc_to(
                 float(s.linear_attn_state_rows))
             self.window_attn_rows.inc_to(float(s.window_attn_rows))

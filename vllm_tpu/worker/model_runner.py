@@ -36,6 +36,7 @@ from vllm_tpu.ops.attention import (
     decode_kernel_tiling,
     ragged_kernel_tiling,
 )
+from vllm_tpu.ops.gmm_kernel import count_visits as count_gmm_visits
 from vllm_tpu.ops.mla_kernel import count_latent_pairs, latent_blocks
 from vllm_tpu.ops.rpa_decode_kernel import decode_row_order, decode_tile_counts
 from vllm_tpu.ops.rpa_kernel import count_ragged_pairs
@@ -556,6 +557,11 @@ class ModelRunner:
         self.moe_assignments = 0
         self.moe_experts_hit = 0
         self.moe_pad_pairs_skipped = 0
+        # Visits of the grouped matmul (ops/gmm_kernel.py), cumulative, a
+        # (group, row tile) pair a column tile a call: "first" waits for
+        # its expert's matrix and starts the copy of one further on,
+        # "resident" runs on the matrix its group's first visit brought.
+        self.moe_gmm_visits = {"first": 0, "resident": 0}
         # Linear attention over per-request state slots, cumulative, a
         # layer of that kind: rows whose state a step reads and writes,
         # and tokens by the form that takes them ("decode" = a row's one
@@ -731,6 +737,17 @@ class ModelRunner:
         if not plain:
             return None
         return (m.num_heads, m.num_kv_heads, m.head_dim, self.block_size), False
+
+    @functools.cached_property
+    def _gmm_column_tiles(self) -> int:
+        """Column tiles of a sparse layer's grouped matmuls where they run
+        the repo's kernel (one chip, on a TPU), else 0."""
+        from vllm_tpu.layers import moe
+
+        m = self.model
+        if not (moe._grouped_is_the_path() and hasattr(m, "moe_intermediate")):
+            return 0
+        return moe.gmm_column_tiles(m.hidden_size, m.moe_intermediate)
 
     def _count_ragged_pairs(self, q_lens, kv_lens, t_pad: int, b_pad: int):
         """Add a step's pairs to ``attn_ragged_pairs`` where its attention
@@ -2802,6 +2819,11 @@ class ModelRunner:
             moe_counts = np.asarray(jax.device_get(handle.moe_counts))
             # Experts with a live token, a layer.
             self.moe_experts_hit += int(np.count_nonzero(moe_counts))
+            if self._gmm_column_tiles:
+                first, resident = count_gmm_visits(
+                    moe_counts, self._gmm_column_tiles)
+                self.moe_gmm_visits["first"] += first
+                self.moe_gmm_visits["resident"] += resident
             if self._moe_share:
                 self.moe_assignments += int(moe_counts.sum())
             if self.eplb_state is not None:
